@@ -198,6 +198,9 @@ def initialize(
 
     import jax
 
+    from .utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if mesh is None:
         axes = _mesh_axes_from_config(cfg, jax.device_count(), cfg.zero_optimization.stage)
         mesh = initialize_mesh(**axes)

@@ -13,7 +13,8 @@ differentiated by the measured trials instead.
 Constants come from one of two places, in preference order:
 
 1. **Calibration from bench artifacts** (:meth:`RooflineConstants.calibrate`)
-   — the repo's own ``BENCH_r0*.json`` / ``MULTICHIP_r0*.json`` runs carry
+   — ``BENCH_r0*.json`` / ``MULTICHIP_r0*.json`` artifacts in the directory
+   the caller names (the rounds <= 5 ``BENCH`` files left the repo) carry
    measured tokens/s + param counts (-> achieved compute rate) and, where
    present, ``effective_weight_gb_s`` (-> achieved HBM stream rate) and
    ``tp_allreduce_ms`` (-> interconnect rate).  Using achieved rates

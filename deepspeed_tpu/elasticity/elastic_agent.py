@@ -117,6 +117,12 @@ class ElasticAgent:
             env = dict(os.environ)
             env.update(self.env)
             env.update(base)
+            if world > 1:
+                # one local process per "rank" is the GPU launch shape; on
+                # a TPU host every rank would claim every chip
+                from ..utils.chip_owner import refuse_chip_children
+
+                refuse_chip_children(env, "elasticity.ElasticAgent")
             env["RANK"] = str(rank)
             env["LOCAL_RANK"] = str(rank)
             procs.append(subprocess.Popen(self.cmd, env=env))

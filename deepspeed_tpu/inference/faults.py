@@ -115,10 +115,32 @@ _TRANSIENT_MARKERS = (
 )
 
 
+# Messages of COMPILE failures (Mosaic or XLA, VMEM or HBM exhausted while
+# compiling, a kernel the compiler cannot lower).  XLA reports HBM/VMEM
+# exhaustion at compile as RESOURCE_EXHAUSTED too, so these are checked
+# before the transient markers.
+_COMPILE_MARKERS = (
+    "mosaic", "compil", "memory space", "vmem", "lowering",
+)
+
+
+def is_compile_error(exc: BaseException) -> bool:
+    """A dispatch that failed to COMPILE fails the same way for every
+    request and every retry: the scheduler re-raises it out of the serve
+    loop instead of retrying (a recompile each time), probing request by
+    request and quarantining the whole queue."""
+    if isinstance(exc, InjectedFault):
+        return False
+    msg = str(exc).lower()
+    return any(m in msg for m in _COMPILE_MARKERS)
+
+
 def is_transient(exc: BaseException) -> bool:
     """Single classifier for the scheduler's retry decision."""
     if isinstance(exc, InjectedFault):
         return exc.transient
+    if is_compile_error(exc):
+        return False
     msg = str(exc).lower()
     return any(m in msg for m in _TRANSIENT_MARKERS)
 

@@ -118,6 +118,7 @@ def prefill(
     blocks: jnp.ndarray,  # [n_pages] int32, -1 padded
     kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
     ctx=None,  # ops.quantizer.ServingContext — TP/fused serving policy
+    mesh=None,  # serve mesh: the flash kernel runs per shard under it
 ):
     """Run the prompt, write its KV pages, return (logits_at_last, caches).
 
@@ -153,7 +154,8 @@ def prefill(
         # (prompt >= 128, tile-divisible), else the fused XLA body — serving
         # prefill is exactly where the kernel's MXU efficiency pays
         attn = flash_attention(
-            q, k, v, causal=True, logits_soft_cap=cfg.logits_soft_cap
+            q, k, v, causal=True, logits_soft_cap=cfg.logits_soft_cap,
+            mesh=mesh,
         )
         attn = _attn_out(lw["attn"], attn.reshape(1, s, -1), ctx)
         x = x + attn.astype(x.dtype)
@@ -176,6 +178,7 @@ def prefill_packed(
     last_idx: jnp.ndarray,  # [N] int32 — buffer index of each prompt's last token (-1 pad)
     kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
     ctx=None,  # ops.quantizer.ServingContext — TP/fused serving policy
+    mesh=None,  # serve mesh: the flash kernel runs per shard under it
 ):
     """Batched multi-prompt prefill under one token budget (the Dynamic
     SplitFuse-shaped dispatch; reference ``inference/v2/ragged/
@@ -227,7 +230,7 @@ def prefill_packed(
         # so SplitFuse prefill runs on the MXU-tiled path on TPU
         attn = flash_attention(
             q, k, v, causal=True, segment_ids=seg,
-            logits_soft_cap=cfg.logits_soft_cap,
+            logits_soft_cap=cfg.logits_soft_cap, mesh=mesh,
         )
         attn = _attn_out(lw["attn"], attn.reshape(1, t, -1), ctx)
         x = x + attn.astype(x.dtype)

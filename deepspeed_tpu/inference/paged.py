@@ -158,11 +158,18 @@ def _use_ctx_kernel(fused, q, cache_k_layer, ctx_tables):
     the decode/flash kernels: on TPU (or under ``set_interpret``) and the
     shape is supported; ``fused=False`` (the ServingContext A/B lever) pins
     the jnp body."""
-    from ..ops.pallas import on_tpu
+    from ..ops.pallas import note_dispatch, on_tpu
     from ..ops.pallas import ctx_attention as ck
 
-    return (fused is not False and (on_tpu() or ck._INTERPRET)
-            and ck.supports(q, cache_k_layer, ctx_tables))
+    if fused is False or not (on_tpu() or ck._INTERPRET):
+        return False
+    ok = ck.supports(q, cache_k_layer, ctx_tables)
+    note_dispatch(
+        "packed_ctx", ok, q.shape, interpret=ck._INTERPRET,
+        reason="" if ok else "ctx_attention.supports() declined "
+        "(VMEM budget / head_dim); dense gather body ran",
+    )
+    return ok
 
 
 def _paged_attention_packed_ctx_local(
@@ -494,13 +501,21 @@ def _paged_attention_decode_local(
     q, cache_k_layer, cache_v_layer, block_table, seq_lens, scale=None,
     logits_soft_cap=None,
 ):
-    from ..ops.pallas import on_tpu
+    from ..ops.pallas import note_dispatch, on_tpu
     from ..ops.pallas import paged_attention as pk
 
-    if (on_tpu() or pk._INTERPRET) and pk.supports(q, cache_k_layer, logits_soft_cap):
-        return pk.paged_attention_decode_kernel(
-            q, cache_k_layer, cache_v_layer, block_table, seq_lens, scale=scale
+    if on_tpu() or pk._INTERPRET:
+        ok = pk.supports(q, cache_k_layer, logits_soft_cap)
+        note_dispatch(
+            "paged_decode", ok, q.shape, interpret=pk._INTERPRET,
+            reason="" if ok else "paged_attention.supports() declined "
+            "(soft cap / head_dim alignment); dense gather body ran",
         )
+        if ok:
+            return pk.paged_attention_decode_kernel(
+                q, cache_k_layer, cache_v_layer, block_table, seq_lens,
+                scale=scale,
+            )
     return _paged_attention_decode_dense(
         q, cache_k_layer, cache_v_layer, block_table, seq_lens, scale=scale,
         logits_soft_cap=logits_soft_cap,

@@ -88,7 +88,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from ..config.config import ServeConfig, _coerce
 from ..telemetry import NULL_REQUEST_TRACE, StatsView, Telemetry
-from .faults import is_transient
+from .faults import is_compile_error, is_transient
 from .sampling import SamplingParams
 
 WAITING, PREFILL, DECODE = "waiting", "prefill", "decode"
@@ -858,6 +858,8 @@ class ServeScheduler:
                 out.update(done)
                 return out
             except Exception as e:  # noqa: BLE001 — the tick-level guard
+                if is_compile_error(e):
+                    raise  # same for every request: stop the serve loop
                 last_err = e
                 if is_transient(e) and attempt < self.serve.max_retries:
                     attempt += 1
@@ -886,6 +888,8 @@ class ServeScheduler:
                         [(seq, seq.seen_tokens, end)], sampling))
                     break
                 except Exception as e:  # noqa: BLE001
+                    if is_compile_error(e):
+                        raise
                     if is_transient(e) and solo_attempt < self.serve.max_retries:
                         solo_attempt += 1
                         self._charge_retry([req])
@@ -1091,6 +1095,8 @@ class ServeScheduler:
             try:
                 return run(survivors)
             except Exception as e:  # noqa: BLE001
+                if is_compile_error(e):
+                    raise  # same for every request: stop the serve loop
                 if is_transient(e) and attempt < self.serve.max_retries:
                     attempt += 1
                     self._charge_retry(survivors)
@@ -1108,6 +1114,8 @@ class ServeScheduler:
                     runs.update(run([req]))
                     break
                 except Exception as e:  # noqa: BLE001
+                    if is_compile_error(e):
+                        raise
                     if is_transient(e) and solo_attempt < self.serve.max_retries:
                         solo_attempt += 1
                         self._charge_retry([req])

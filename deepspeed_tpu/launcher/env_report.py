@@ -11,9 +11,9 @@ def collect() -> dict:
         import jax
 
         info["jax"] = jax.__version__
-        info["backend"] = jax.default_backend()
+        info["platform"] = jax.devices()[0].platform
+        info["device_kind"] = jax.devices()[0].device_kind
         info["device_count"] = jax.device_count()
-        info["devices"] = [str(d) for d in jax.devices()]
         info["process_count"] = jax.process_count()
     except Exception as e:
         info["jax_error"] = str(e)
@@ -23,12 +23,12 @@ def collect() -> dict:
             info[mod] = getattr(m, "__version__", "present")
         except ImportError:
             info[mod] = "MISSING"
-    try:
-        from ..ops.pallas import on_tpu
-
-        info["pallas"] = "tpu kernels" if on_tpu() else "interpret-mode only"
-    except Exception:
-        info["pallas"] = "unknown"
+    if "platform" in info:
+        info["pallas"] = (
+            "Mosaic kernels" if info["platform"] == "tpu"
+            else "jnp bodies (kernels run only under an explicit "
+                 "set_interpret(True))"
+        )
     try:
         from ..ops.op_builder import op_report
 

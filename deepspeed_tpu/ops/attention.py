@@ -101,15 +101,14 @@ def get_attention_impl(name: str = "auto"):
     """Select an attention body by name — the analogue of the reference's
     op-builder ``is_compatible()`` dispatch (op_builder/builder.py).
 
-    names: 'reference' | 'flash' | 'auto' ('auto' = flash on TPU, reference
-    elsewhere).
+    names: 'reference' | 'flash' | 'auto'.  'flash' and 'auto' both return
+    the flash dispatcher, which itself takes the reference body off-TPU or
+    where the kernel's ``supports()`` declines the shape.
     """
     if name in ("reference", "math"):
         return dot_product_attention
     if name not in ("flash", "auto"):
         raise ValueError(f"unknown attention impl '{name}' (reference|flash|auto)")
-    from .pallas.flash_attention import flash_attention, is_compatible
+    from .pallas.flash_attention import flash_attention
 
-    if name == "flash":
-        return flash_attention
-    return flash_attention if is_compatible() else dot_product_attention
+    return flash_attention

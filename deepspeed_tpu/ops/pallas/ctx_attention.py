@@ -227,8 +227,8 @@ def _ctx_kernel(
             # by buffer index + the contiguous segment span is exact
             k_ok = (kj >= start) & (kj < start + slen) \
                 & (rows[:, :, None] >= kj)  # [T, 1, bkp]
-            kc = pl.load(kp_ref, (pl.dslice(j0, bkp), slice(None), slice(None)))
-            vc = pl.load(vp_ref, (pl.dslice(j0, bkp), slice(None), slice(None)))
+            kc = kp_ref[pl.ds(j0, bkp), :, :]
+            vc = vp_ref[pl.ds(j0, bkp), :, :]
             for h in range(hkv):
                 qh = q_ref[:, h * g:(h + 1) * g, :]
                 s3 = jax.lax.dot_general(

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import note_dispatch
+
 NEG_INF = -1e30
 
 # interpret mode lets the kernels run on the CPU test mesh (tests/conftest.py)
@@ -364,6 +366,7 @@ def _dkv_kernel(*refs, scale, bq, bk, has_seg, soft_cap):
 def _bwd(scale, soft_cap, res, do):
     q, k_rep, v_rep, qseg, kseg, out, lse = res  # kv repeated to hq heads
     bh, s, d = q.shape
+    note_dispatch("flash_bwd", True, q.shape, interpret=_INTERPRET)
     bq, bk = _blocks_bwd(s)
     has_seg = qseg is not None
     hq_pb = bh // qseg.shape[0] if has_seg else 1

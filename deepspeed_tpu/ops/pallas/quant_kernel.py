@@ -35,61 +35,74 @@ def _quant_int8_kernel(x_ref, q_ref, s_ref):
     scale = jnp.maximum(amax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(x / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale[..., 0]
+    s_ref[...] = scale
 
 
 def _dequant_int8_kernel(q_ref, s_ref, o_ref, *, out_dtype):
     q = q_ref[...].astype(jnp.float32)
-    s = s_ref[...][..., None]
-    o_ref[...] = (q * s).astype(out_dtype)
+    o_ref[...] = (q * s_ref[...]).astype(out_dtype)
+
+
+# fp32 working tile per grid step.  The kernel holds the input block, its
+# fp32 widening, the quantized block and the pipeline's double buffers at
+# once; 1 MiB of fp32 keeps that under Mosaic's 16 MiB default scoped VMEM.
+_TILE_BYTES = 1024 * 1024
+
+
+def _block_rows(g: int, n: int) -> int:
+    """Largest power-of-two row block (<= 256) that divides ``g`` and keeps
+    the fp32 tile inside ``_TILE_BYTES``; < 32 means no tile-aligned block
+    exists (int8/fp8 rows pack 32 to a sublane tile)."""
+    bm = 256
+    while bm > 1 and (g % bm or bm * n * 4 > _TILE_BYTES):
+        bm //= 2
+    return bm
 
 
 def supports(x2d) -> bool:
     g, n = x2d.shape
-    return n % 128 == 0 and g % 8 == 0
+    return n % 128 == 0 and _block_rows(g, n) >= 32
 
 
-def quantize_int8(x2d: jnp.ndarray, block_rows: int = 256):
-    """[G, N] -> (int8 [G, N], fp32 scales [G]); one scale per row/group."""
+def quantize_int8(x2d: jnp.ndarray):
+    """[G, N] -> (int8 [G, N], fp32 scales [G]); one scale per row/group.
+
+    Scales travel through the kernel as a [G, 1] column: Mosaic tiles a 1-D
+    fp32 operand differently from XLA (T(256) vs T(1024)) and refuses the
+    call, while a 2-D block with a full-extent minor dim is layout-exact."""
     g, n = x2d.shape
-    bm = min(block_rows, g)
-    while g % bm:
-        bm //= 2
-    grid = (g // bm,)
-    return pl.pallas_call(
+    bm = _block_rows(g, n)
+    q, s = pl.pallas_call(
         _quant_int8_kernel,
-        grid=grid,
+        grid=(g // bm,),
         in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
+            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, n), jnp.int8),
-            jax.ShapeDtypeStruct((g,), jnp.float32),
+            jax.ShapeDtypeStruct((g, 1), jnp.float32),
         ],
         interpret=_INTERPRET,
     )(x2d)
+    return q, s[:, 0]
 
 
-def dequantize_int8(q2d: jnp.ndarray, scales: jnp.ndarray, out_dtype=jnp.bfloat16,
-                    block_rows: int = 256):
+def dequantize_int8(q2d: jnp.ndarray, scales: jnp.ndarray, out_dtype=jnp.bfloat16):
     g, n = q2d.shape
-    bm = min(block_rows, g)
-    while g % bm:
-        bm //= 2
-    grid = (g // bm,)
+    bm = _block_rows(g, n)
     return pl.pallas_call(
         functools.partial(_dequant_int8_kernel, out_dtype=out_dtype),
-        grid=grid,
+        grid=(g // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
+            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g, n), out_dtype),
         interpret=_INTERPRET,
-    )(q2d, scales)
+    )(q2d, scales.astype(jnp.float32)[:, None])
 
 
 def _quant_fp8_kernel(x_ref, q_ref, s_ref, *, fp8_dtype, fp8_max):
@@ -97,27 +110,26 @@ def _quant_fp8_kernel(x_ref, q_ref, s_ref, *, fp8_dtype, fp8_max):
     amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-12) / fp8_max
     q_ref[...] = (x / scale).astype(fp8_dtype)
-    s_ref[...] = scale[..., 0]
+    s_ref[...] = scale
 
 
-def quantize_fp8(x2d: jnp.ndarray, dtype=jnp.float8_e4m3fn, block_rows: int = 256):
+def quantize_fp8(x2d: jnp.ndarray, dtype=jnp.float8_e4m3fn):
     """[G, N] -> (fp8 [G, N], fp32 scales [G])."""
     g, n = x2d.shape
-    bm = min(block_rows, g)
-    while g % bm:
-        bm //= 2
+    bm = _block_rows(g, n)
     fp8_max = float(jnp.finfo(dtype).max)
-    return pl.pallas_call(
+    q, s = pl.pallas_call(
         functools.partial(_quant_fp8_kernel, fp8_dtype=dtype, fp8_max=fp8_max),
         grid=(g // bm,),
         in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
+            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, n), dtype),
-            jax.ShapeDtypeStruct((g,), jnp.float32),
+            jax.ShapeDtypeStruct((g, 1), jnp.float32),
         ],
         interpret=_INTERPRET,
     )(x2d)
+    return q, s[:, 0]

@@ -10,7 +10,6 @@ failing, but keep full specs on real shapes so layout errors surface.
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -20,26 +19,12 @@ _CURRENT_MESH = None
 
 def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None,
                      check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: the top-level export (with its
-    ``check_vma``/``axis_names`` kwargs) on current jax, falling back to
-    ``jax.experimental.shard_map.shard_map`` (``check_rep``; ``axis_names``
-    expressed as its complement ``auto``) on older releases.  Every manual
-    region in the repo routes through here so a jax upgrade/downgrade is a
-    one-file concern."""
-    try:
-        from jax import shard_map as _sm
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _sm(f, **kw)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-        return _sm(f, **kw)
+    """``jax.shard_map`` with the repo's default of unchecked replication
+    (``check_vma=False``).  Every manual region in the repo routes through
+    here."""
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma, **kw)
 
 
 def set_current_mesh(mesh) -> None:
@@ -77,24 +62,10 @@ def axis_size(name: str) -> int:
 
 def collective_axis_size(axis_name) -> int:
     """World size of a collective axis (a name or a sequence of names) from
-    INSIDE a traced collective region: ``jax.lax.axis_size`` where this jax
-    has it, falling back to the ambient mesh's static sizes on older
-    releases (``initialize()`` installs the mesh, so the bound sizes answer
-    the query).  The one canonical copy of the fallback — comm/compressed,
-    comm/qcomm and runtime/zeropp all import it from here."""
-
-    def one(ax: str) -> int:
-        try:
-            return jax.lax.axis_size(ax)
-        except AttributeError:
-            return axis_size(ax)
-
+    INSIDE a traced collective region."""
     if isinstance(axis_name, str):
-        return one(axis_name)
-    size = 1
-    for ax in axis_name:
-        size *= one(ax)
-    return size
+        return jax.lax.axis_size(axis_name)
+    return math.prod(jax.lax.axis_size(ax) for ax in axis_name)
 
 
 def filter_spec(shape, spec: P, mesh=None) -> P:
@@ -119,10 +90,7 @@ def _drop_manual_axes(spec: P) -> P:
     inside a shard_map over them): with_sharding_constraint may only name
     non-manual axes there.  Makes model code usable both under plain jit
     (GSPMD) and inside whole-step shard_map optimizers (1-bit family)."""
-    try:
-        manual = set(jax.sharding.get_abstract_mesh().manual_axes)
-    except Exception:  # very old tracing contexts
-        manual = set()
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
     if not manual:
         return spec
 

@@ -141,10 +141,7 @@ def build_mesh(spec: MeshSpec, devices: Optional[Sequence] = None):
     # hpZ/MiCS secondary gathers ride the tightest ICI neighbourhood
     order = (STAGE_AXIS, DATA_AXIS, FSDP_AXIS, SUB_AXIS, EXPERT_AXIS, SEQ_AXIS, MODEL_AXIS)
     shape = tuple(spec.sizes[a] for a in order)
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(dev_array, order)
 
 

@@ -37,9 +37,9 @@ def memory_stats(device=None) -> Dict[str, Any]:
     """Device + host memory snapshot.
 
     Device figures come from ``memory_stats()`` of the first local device
-    (or the given one); backends without an instrumented allocator (the CPU
-    test platform) report zeros rather than raising — same graceful posture
-    as the reference on non-CUDA accelerators.
+    (or the given one).  A backend without an instrumented allocator (the
+    CPU test platform) returns ``None`` there and reports zeros; an error
+    from a backend that has one (TPU) propagates.
     """
     import jax
 
@@ -53,10 +53,7 @@ def memory_stats(device=None) -> Dict[str, Any]:
         local = jax.local_devices()
         dev = local[0] if local else None
     if dev is not None:
-        try:
-            raw = dev.memory_stats() or {}
-        except Exception:  # noqa: BLE001 — allocator stats are best-effort
-            raw = {}
+        raw = dev.memory_stats() or {}
         stats["device_bytes_in_use"] = int(raw.get("bytes_in_use", 0))
         stats["device_peak_bytes"] = int(
             raw.get("peak_bytes_in_use", raw.get("bytes_in_use", 0))

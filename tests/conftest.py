@@ -11,6 +11,14 @@ proxy).
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+# ds.initialize / InferenceEngineV2 place a persistent compile cache inside
+# the checkout (utils/compile_cache.py).  The test lane — this process and
+# every worker it spawns — compiles fresh instead: a result here must not
+# depend on what an earlier run left on disk.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
+# children the tests spawn (serving workers, elastic ranks, launcher
+# bootstraps) run on the CPU like this process does
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 

@@ -66,11 +66,19 @@ def _setup(segs, hq=8, hkv=2, hd=16, nb=32, bs=8, pad=0, seed=0,
 SEGS = [(10, 13), (6, 0), (6, 37)]  # mid-page, cold, multi-page
 
 
-@pytest.mark.parametrize("cap", [None, 20.0])
-@pytest.mark.parametrize("hq,hkv,hd", [
-    (8, 8, 64),    # 410M-proxy: MHA, hd 64
-    (8, 2, 128),   # 8B-proxy: GQA-narrow (hkv < tp at tp=4), hd 128
-    (4, 1, 16),    # MQA corner
+# tier-1 keeps every shape uncapped plus ONE soft-capped shape; the other
+# capped combinations ride the slow lane (the lane runs near its limit now
+# that these run in the interpreter instead of failing at trace)
+_SLOW = pytest.mark.slow
+
+
+@pytest.mark.parametrize("hq,hkv,hd,cap", [
+    (8, 8, 64, None),     # 410M-proxy: MHA, hd 64
+    (8, 2, 128, None),    # 8B-proxy: GQA-narrow (hkv < tp at tp=4), hd 128
+    (4, 1, 16, None),     # MQA corner
+    (8, 2, 128, 20.0),
+    pytest.param(8, 8, 64, 20.0, marks=_SLOW),
+    pytest.param(4, 1, 16, 20.0, marks=_SLOW),
 ])
 def test_kernel_parity_vs_dense(hq, hkv, hd, cap):
     q, k, v, seg, ckl, cvl, tb, ln = _setup(SEGS, hq=hq, hkv=hkv, hd=hd,
@@ -120,6 +128,7 @@ def test_kernel_ignores_garbage_in_dead_pages():
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=2e-5)
 
 
+@_SLOW  # engine-level, 5 s; chip_smoke.py checks prefix hits on the chip
 def test_prefix_hit_identical_to_cold_prefill():
     """A suffix prefill over cached context must be numerically the SAME
     reduction as the cold full-prompt prefill — the invariant prefix
@@ -152,6 +161,7 @@ def test_prefix_hit_identical_to_cold_prefill():
                                atol=2e-5)
 
 
+@_SLOW  # 3.5 s; the kernel's partial=True mode stays in the nightly lane
 def test_partial_mode_striped_ring_merge():
     """Seq-shard contract: stripe the pool over 2 shards, run the kernel in
     ``partial=True`` on each shard's locally-translated tables (pack keys

@@ -15,6 +15,7 @@ from deepspeed_tpu.inference import (
     finite_guard,
     is_transient,
 )
+from deepspeed_tpu.inference.faults import is_compile_error
 from deepspeed_tpu.inference import scheduler as S
 from deepspeed_tpu.models import get_preset
 from deepspeed_tpu.models.transformer import init_params
@@ -102,6 +103,49 @@ def test_transient_classifier():
     assert is_transient(RuntimeError("device_put transfer stalled"))
     assert not is_transient(RuntimeError("cannot allocate 3 blocks"))
     assert not is_transient(ValueError("bad prompt"))
+    # XLA reports HBM/VMEM exhaustion AT COMPILE as RESOURCE_EXHAUSTED too:
+    # deterministic, so never transient
+    hbm = RuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 17.2G of 15.48G hbm.")
+    mosaic = RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: "
+                          "scoped vmem limit exceeded")
+    for e in (hbm, mosaic):
+        assert is_compile_error(e) and not is_transient(e)
+    assert not is_compile_error(RuntimeError("RESOURCE_EXHAUSTED: out of semaphores"))
+    assert not is_compile_error(InjectedFault("runner_exception"))
+
+
+def test_compile_failure_stops_the_serve_loop(tiny):
+    """A dispatch that fails to COMPILE fails identically for every request
+    and every retry: ``run()`` raises it instead of retrying, probing each
+    request solo and quarantining the queue behind an exit code 0."""
+    cfg, params = tiny
+    eng = _engine(cfg, params)
+    sched = eng.scheduler
+    for u in (1, 2, 3):
+        sched.submit(u, [5, 6, 7, 8, 9], SamplingParams(max_new_tokens=4))
+
+    def boom(*a, **kw):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: "
+                           "Ran out of memory in memory space vmem")
+
+    def assert_untouched():
+        assert not any(r.state == "failed" for r in sched.requests.values())
+        assert eng.stats["retries"] == 0 and eng.stats["failed"] == 0
+        assert eng.stats["isolation_probes"] == 0
+
+    real_prefill = eng.prefill_entries
+    eng.prefill_entries = boom  # the prefill dispatch cannot compile
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        sched.run()
+    assert_untouched()
+    eng.prefill_entries = real_prefill
+    sched.tick()  # prefill completes; now the decode dispatch breaks
+    eng._decode_tick = boom
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        sched.run()
+    assert_untouched()
 
 
 def test_finite_guard_sentinels_nonfinite_rows():

@@ -328,3 +328,105 @@ def test_block_sparse_dispatcher_uses_kernel():
         assert not calls.get("hit")
     finally:
         fk.pallas_block_sparse_attention = orig
+
+
+# ---------------------------------------------------------------------------
+# partitioning rule: under a mesh the dispatcher runs the kernel per shard
+# (GSPMD cannot partition a Mosaic call; on CPU the interpreter hides that)
+# ---------------------------------------------------------------------------
+def _mesh_case(axes, b, hq, hkv, grads):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.ops.pallas import record_dispatch
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.parallel.topology import initialize_mesh
+
+    mesh = initialize_mesh(**axes).mesh
+    s, d = 128, 64
+    q, k, v = _rand((b, s, hq, d), 0), _rand((b, s, hkv, d), 1), _rand((b, s, hkv, d), 2)
+    seg = jnp.asarray(np.repeat([[0] * 64 + [1] * 64], b, axis=0), jnp.int32)
+    q, k, v, seg = (jax.device_put(x, NamedSharding(mesh, P()))
+                    for x in (q, k, v, seg))
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=True, segment_ids=seg, **kw) ** 2)
+
+    with record_dispatch() as log:
+        if grads:
+            l, g = jax.jit(jax.value_and_grad(
+                loss(flash_attention, mesh=mesh), argnums=(0, 1, 2)))(q, k, v)
+            l_ref, g_ref = jax.value_and_grad(
+                loss(dot_product_attention), argnums=(0, 1, 2))(q, k, v)
+            np.testing.assert_allclose(float(l), float(l_ref), rtol=1e-5)
+            for a, r in zip(g, g_ref):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=2e-4)
+        else:
+            out = jax.jit(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, segment_ids=seg, mesh=mesh))(q, k, v)
+            ref = dot_product_attention(q, k, v, causal=True, segment_ids=seg)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    assert all(e["ran"] and not e["mosaic"] for e in log)  # interpreted
+    return {e["shape"] for e in log if e["kernel"] == "flash_fwd"}
+
+
+def test_flash_dispatcher_partitions_batch_and_heads(devices):
+    """ZeRO x TP mesh: the kernel (fwd AND bwd) sees one shard's shape —
+    batch over fsdp, heads over model — and matches the jnp body."""
+    assert _mesh_case(dict(fsdp=4, model=2), 4, 4, 2, grads=True) == {
+        (1, 128, 2, 64)}
+
+
+def test_flash_dispatcher_replicates_what_does_not_divide(devices):
+    # hkv % tp != 0: q and kv heads stay whole together (GQA groups intact)
+    assert _mesh_case(dict(model=8), 1, 8, 2, grads=False) == {(1, 128, 8, 64)}
+
+
+@pytest.mark.slow
+def test_flash_dispatcher_partitions_more_meshes(devices):
+    assert _mesh_case(dict(data=2, fsdp=2, model=2), 4, 4, 4, grads=True) == {
+        (1, 128, 2, 64)}
+    # batch % fsdp != 0: the batch entry is dropped, the region replicates
+    assert _mesh_case(dict(fsdp=8), 4, 4, 2, grads=True) == {(4, 128, 4, 64)}
+
+
+def test_flash_dispatcher_uses_ambient_mesh_and_skips_manual_regions(devices):
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.ops.pallas import record_dispatch
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.parallel.sharding import (set_current_mesh,
+                                                 shard_map_compat)
+    from deepspeed_tpu.parallel.topology import initialize_mesh
+
+    mesh = initialize_mesh(fsdp=8).mesh
+    q = _rand((8, 128, 2, 64), 0)
+    ref = dot_product_attention(q, q, q, causal=True)
+    set_current_mesh(mesh)
+    try:
+        with record_dispatch() as log:
+            out = jax.jit(lambda q: flash_attention(q, q, q))(q)
+            # inside a caller's manual region the operands already are the
+            # shard: no nested region, the kernel runs on what it is given
+            inner = shard_map_compat(
+                lambda q: flash_attention(q, q, q), mesh,
+                in_specs=(P("fsdp"),), out_specs=P("fsdp"))
+            out2 = jax.jit(inner)(q)
+    finally:
+        set_current_mesh(None)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out2), np.asarray(ref), atol=2e-5)
+    assert [e["shape"] for e in log] == [(1, 128, 2, 64)] * 2
+
+
+def test_on_tpu_propagates_backend_errors(monkeypatch):
+    """A backend that cannot initialise must raise out of every kernel gate,
+    not answer 'no TPU' and send the run down the jnp bodies."""
+    from deepspeed_tpu.ops import pallas
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        pallas.on_tpu()

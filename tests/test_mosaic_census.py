@@ -1,0 +1,206 @@
+"""No-chip Mosaic compile census: every Pallas entry point in ``ops/pallas/``
+either compiles for ``TPU v5 lite`` at Mistral-7B widths (hq 32 / hkv 8 /
+hd 128, d 4096, ffn 14336) or its ``supports()`` gate declines the shape.
+
+``libtpu`` is installed in the CPU sandbox, so ``jax.experimental.topologies``
+hands out abstract v5e devices and ``.lower(lowering_platforms=("tpu",))
+.compile()`` runs the real Mosaic and XLA:TPU compilers with no chip attached.
+This is a PRE-FLIGHT for a chip run (it catches API drift, layout refusals and
+VMEM overflows in seconds instead of chip-minutes) — it is never evidence that
+a kernel runs or computes the right numbers; ``chip_smoke.py`` on the chip is.
+
+``slow``-marked: the packed-ctx kernel alone takes tens of seconds a shape.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+pytestmark = pytest.mark.slow
+
+HQ, HKV, HD, D, FFN = 32, 8, 128, 4096, 14336
+BS = 32  # KV page size the smoke serves with
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    # the tier-1 process may already hold libtpu's lockfile (first loader
+    # wins); the census only compiles, it never opens a device
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    topologies = pytest.importorskip("jax.experimental.topologies")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this environment
+        pytest.skip(f"no TPU compiler available off-chip: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _compile(fn, devices, *specs):
+    """Trace + lower for TPU + run the Mosaic/XLA:TPU compilers on one
+    abstract v5e device.  Returns the compiled executable's text."""
+    sh = jax.sharding.SingleDeviceSharding(devices[0])
+    specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
+             for s in specs]
+    compiled = jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).compile()
+    return compiled.as_text()
+
+
+def _spec(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _assert_mosaic(text):
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the executable"
+
+
+@pytest.mark.parametrize("hd,hq,hkv", [(128, HQ, HKV), (64, 16, 8)])
+def test_flash_fwd_and_bwd_compile(v5e, hd, hq, hkv):
+    from deepspeed_tpu.ops.pallas import flash_kernel as fk
+
+    q = _spec((1, 4096, hq, hd))
+    kv = _spec((1, 4096, hkv, hd))
+    seg = _spec((1, 4096), jnp.int32)
+    assert fk.supports(q, kv, kv, True, 0, seg, None)
+
+    def loss(q, k, v, seg):
+        out = fk.pallas_flash_attention(q, k, v, segment_ids=seg)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, q, kv, kv, seg)
+    # fwd + dq + dkv
+    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("hd,hq,hkv", [(128, HQ, HKV), (64, 16, 8)])
+def test_paged_decode_compiles(v5e, hd, hq, hkv):
+    from deepspeed_tpu.ops.pallas import paged_attention as pk
+
+    q = _spec((16, hq, hd))
+    pool = _spec((256, BS, hkv, hd))
+    tables = _spec((16, 64), jnp.int32)
+    lens = _spec((16,), jnp.int32)
+    assert pk.supports(q, pool, None)
+    _assert_mosaic(_compile(pk.paged_attention_decode_kernel, v5e,
+                            q, pool, pool, tables, lens))
+
+
+def test_paged_decode_gate_declines_unaligned_head_dim():
+    from deepspeed_tpu.ops.pallas import paged_attention as pk
+
+    # lone hd=64 with hkv*hd not a lane multiple: Mosaic refuses the DMA
+    # slice, so the gate must decline it
+    assert not pk.supports(_spec((4, 3, 64)), _spec((8, BS, 1, 64)), None)
+
+
+@pytest.mark.parametrize("t", [64, 256])
+def test_ctx_kernel_compiles_inside_its_gate(v5e, t):
+    from deepspeed_tpu.ops.pallas import ctx_attention as ck
+
+    q = _spec((t, HQ, HD))
+    kv = _spec((t, HKV, HD))
+    seg = _spec((t,), jnp.int32)
+    pool = _spec((256, BS, HKV, HD))
+    tables = _spec((16, 64), jnp.int32)
+    lens = _spec((16,), jnp.int32)
+    assert ck.supports(q, pool, tables)
+    _assert_mosaic(_compile(ck.paged_attention_packed_ctx_kernel, v5e,
+                            q, kv, kv, seg, pool, pool, tables, lens))
+
+
+def test_ctx_gate_declines_oversized_pack():
+    """The VMEM gate's verdict at Mistral-7B widths: a 512-token pack does
+    not fit the resident q/acc budget, so the dispatcher must route it to
+    the dense body (and say so — see ops.pallas.note_dispatch)."""
+    from deepspeed_tpu.ops.pallas import ctx_attention as ck
+
+    pool = _spec((256, BS, HKV, HD))
+    tables = _spec((16, 64), jnp.int32)
+    assert ck.supports(_spec((256, HQ, HD)), pool, tables)
+    assert not ck.supports(_spec((512, HQ, HD)), pool, tables)
+    # a quarter of the heads (TP=4 local shard) fits the same 512 pack
+    assert ck.supports(_spec((512, HQ // 4, HD)),
+                       _spec((256, BS, HKV // 4, HD)), tables)
+
+
+@pytest.mark.parametrize("m", [16, 256])
+def test_quant_matmul_int8_and_fp6_compile(v5e, m):
+    from deepspeed_tpu.ops.pallas import quant_matmul as qm
+
+    x = _spec((m, D))
+    q8 = _spec((D, FFN), jnp.int8)
+    planes = _spec((3, D // 4, FFN), jnp.uint8)
+    s = _spec((FFN,), jnp.float32)
+    _assert_mosaic(_compile(qm.quant_matmul, v5e, x, q8, s))
+    _assert_mosaic(_compile(
+        lambda x, p, s: qm.quant_matmul_fp6(x, p, s, in_dim=D),
+        v5e, x, planes, s))
+
+
+@pytest.mark.parametrize("shape", [(4096, 4096), (4096, FFN), (32, 8192),
+                                   (8, 128)])
+def test_quantize_kernels_compile_or_decline(v5e, shape):
+    from deepspeed_tpu.ops.pallas import quant_kernel as qk
+
+    x = _spec(shape)
+    if not qk.supports(x):
+        return  # declined: the jnp body in ops/quantizer.py serves it
+    _assert_mosaic(_compile(qk.quantize_int8, v5e, x))
+    _assert_mosaic(_compile(qk.quantize_fp8, v5e, x))
+    _assert_mosaic(_compile(
+        qk.dequantize_int8, v5e, _spec(shape, jnp.int8),
+        _spec((shape[0],), jnp.float32)))
+
+
+def test_quantize_gate_accepts_the_common_weight_shape():
+    from deepspeed_tpu.ops.pallas import quant_kernel as qk
+
+    # the census above must not pass by declining everything
+    assert qk.supports(_spec((4096, 4096)))
+    assert not qk.supports(_spec((7, 100)))
+
+
+def test_flash_partitions_on_four_chips(v5e, monkeypatch):
+    """The flash dispatcher under a 4-device mesh lowers (shard_map region)
+    where the bare kernel call raises 'Mosaic kernels cannot be
+    automatically partitioned'."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    from deepspeed_tpu.ops.pallas import flash_kernel as fk
+    from deepspeed_tpu.parallel.topology import MeshSpec, build_mesh
+
+    # the process's default backend is the CPU; the lowering target is not
+    monkeypatch.setattr(fa, "is_compatible", lambda: True)
+    q1 = jax.ShapeDtypeStruct(
+        (4, 1024, HQ, HD), jnp.bfloat16,
+        sharding=NamedSharding(build_mesh(MeshSpec(fsdp=4), v5e), P("fsdp")))
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        jax.jit(fk.pallas_flash_attention).trace(q1, q1, q1).lower(
+            lowering_platforms=("tpu",))
+
+    for axes, qspec in (({"fsdp": 4}, P("fsdp")),
+                        ({"model": 4}, P(None, None, "model"))):
+        mesh = build_mesh(MeshSpec(**axes), v5e)
+        b = 4 if "fsdp" in axes else 1
+        sh = NamedSharding(mesh, qspec)
+        q = jax.ShapeDtypeStruct((b, 1024, HQ, HD), jnp.bfloat16, sharding=sh)
+        kv = jax.ShapeDtypeStruct((b, 1024, HKV, HD), jnp.bfloat16,
+                                  sharding=sh)
+
+        def f(q, k, v):
+            return fa.flash_attention(q, k, v, mesh=mesh)
+
+        text = jax.jit(f).trace(q, kv, kv).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+        _assert_mosaic(text)
+        # per-chip slice, not the gathered batch/heads
+        local = "bf16[8,1024,128]" if "model" in axes else "bf16[32,1024,128]"
+        assert local in text, f"flash did not run on the {axes} local slice"
+
+
+if __name__ == "__main__":
+    raise SystemExit(pytest.main([__file__, "-q", "-m", "slow", "-x", "-s"]))
