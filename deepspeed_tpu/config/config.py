@@ -177,9 +177,10 @@ class TelemetryConfig:
     ``jsonl_path`` appends structured events (per-request summaries) as one
     JSON object per line.  ``chrome_trace_path`` writes the span + request
     timeline as Chrome trace-event JSON on engine close/exit — load it at
-    https://ui.perfetto.dev.  ``jax_profiler`` additionally wraps train /
-    serve dispatches in ``jax.profiler.StepTraceAnnotation`` so they label
-    a live ``jax.profiler.trace`` capture.  ``exact_quantiles`` is the raw
+    https://ui.perfetto.dev.  ``jax_profiler`` mirrors every
+    ``Telemetry.span()`` into a ``jax.profiler.TraceAnnotation`` of the same
+    name and ``span_id``, so a live ``jax.profiler`` capture holds the
+    program's span tree on the trace's clock next to the device's events.  ``exact_quantiles`` is the raw
     sample count histograms retain before degrading to the log-bucket
     estimate; ``max_spans`` bounds the span ring buffer."""
 

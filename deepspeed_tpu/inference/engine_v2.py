@@ -918,57 +918,58 @@ class InferenceEngineV2:
         At ``serve_replicas == 1`` this degenerates to the classic single-
         chunk layout byte-for-byte (one chunk, same bucket)."""
         self._maybe_fault("runner_exception", [s.uid for s, _, _ in entries])
-        bs = self.block_size
-        dp = self.serve_replicas
-        groups: List[List] = [[] for _ in range(dp)]
-        for e in entries:
-            groups[self.mgr.replica_of(e[0]) if dp > 1 else 0].append(e)
-        chunk_tokens = max(
-            sum(-(-(end - start) // bs) * bs for _, start, end in g)
-            for g in groups
-        )
-        C = _bucket(max(chunk_tokens, bs), self.prefill_buckets)
-        if C % bs:
-            raise ValueError(
-                f"prefill bucket {C} must be a multiple of block_size {bs}"
+        tel, ns = self.telemetry, self._ns
+        with tel.span("engine.pack_build", track=ns, entries=len(entries)):
+            bs = self.block_size
+            dp = self.serve_replicas
+            groups: List[List] = [[] for _ in range(dp)]
+            for e in entries:
+                groups[self.mgr.replica_of(e[0]) if dp > 1 else 0].append(e)
+            chunk_tokens = max(
+                sum(-(-(end - start) // bs) * bs for _, start, end in g)
+                for g in groups
             )
-        t_pad = C * dp
-        use_ctx = any(start > 0 for _, start, _ in entries)
-        tokens = np.zeros(t_pad, np.int32)
-        seg = np.zeros(t_pad, np.int32)
-        pos = np.zeros(t_pad, np.int32)
-        pack_pages = np.full(t_pad // bs, -1, np.int32)
-        last_idx = np.full(self.mgr.max_seqs, -1, np.int32)
-        ctx_tables = np.full((self.mgr.max_seqs, self.max_pages), -1, np.int32)
-        ctx_lens = np.zeros(self.mgr.max_seqs, np.int32)
-        for r, group in enumerate(groups):
-            cur = r * C
-            for s, start, end in group:
-                n = end - start
-                tokens[cur : cur + n] = s.tokens[start:end]
-                seg[cur : cur + n] = s.slot + 1
-                pos[cur : cur + n] = np.arange(start, end)
-                n_pages = -(-n // bs)
-                first_page = start // bs
-                pack_pages[cur // bs : cur // bs + n_pages] = np.asarray(
-                    s.blocks[first_page : first_page + n_pages]
+            C = _bucket(max(chunk_tokens, bs), self.prefill_buckets)
+            if C % bs:
+                raise ValueError(
+                    f"prefill bucket {C} must be a multiple of block_size {bs}"
                 )
-                if end == len(s.tokens):  # completes the prompt -> sample
-                    last_idx[s.slot] = cur + n - 1
-                ctx_tables[s.slot, : len(s.blocks)] = s.blocks
-                ctx_lens[s.slot] = start
-                cur += n_pages * bs  # next prompt starts page-aligned
-        self._rng, sub = jax.random.split(self._rng)
-        triple = (sampling.temperature, sampling.top_k, sampling.top_p)
-        n_real = sum(end - start for _, start, end in entries)
-        n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
-        sp = self.telemetry.recorder.start(
-            "prefill_pack", track=self._ns, hist=self._h["prefill_pack_ms"],
+            t_pad = C * dp
+            use_ctx = any(start > 0 for _, start, _ in entries)
+            tokens = np.zeros(t_pad, np.int32)
+            seg = np.zeros(t_pad, np.int32)
+            pos = np.zeros(t_pad, np.int32)
+            pack_pages = np.full(t_pad // bs, -1, np.int32)
+            last_idx = np.full(self.mgr.max_seqs, -1, np.int32)
+            ctx_tables = np.full((self.mgr.max_seqs, self.max_pages), -1, np.int32)
+            ctx_lens = np.zeros(self.mgr.max_seqs, np.int32)
+            for r, group in enumerate(groups):
+                cur = r * C
+                for s, start, end in group:
+                    n = end - start
+                    tokens[cur : cur + n] = s.tokens[start:end]
+                    seg[cur : cur + n] = s.slot + 1
+                    pos[cur : cur + n] = np.arange(start, end)
+                    n_pages = -(-n // bs)
+                    first_page = start // bs
+                    pack_pages[cur // bs : cur // bs + n_pages] = np.asarray(
+                        s.blocks[first_page : first_page + n_pages]
+                    )
+                    if end == len(s.tokens):  # completes the prompt -> sample
+                        last_idx[s.slot] = cur + n - 1
+                    ctx_tables[s.slot, : len(s.blocks)] = s.blocks
+                    ctx_lens[s.slot] = start
+                    cur += n_pages * bs  # next prompt starts page-aligned
+            self._rng, sub = jax.random.split(self._rng)
+            triple = (sampling.temperature, sampling.top_k, sampling.top_p)
+            n_real = sum(end - start for _, start, end in entries)
+            n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
+        finishing = [s for s, _, end in entries if end == len(s.tokens)]
+        with tel.span(
+            "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
             tokens=n_real, pad=t_pad, entries=len(entries), ctx=use_ctx,
-        )
-        with self.telemetry.step_annotation(
-            "prefill_pack", self._c["prefill_dispatches"].value + 1
-        ):
+            uids=[s.uid for s, _, _ in entries],
+        ) as sp:
             if use_ctx:
                 sampled, self.kv = self._packed_prefill_ctx_jit(
                     self.params, jnp.asarray(tokens), jnp.asarray(seg),
@@ -982,45 +983,44 @@ class InferenceEngineV2:
                     jnp.asarray(pos), jnp.asarray(pack_pages),
                     jnp.asarray(last_idx), self.kv, sub, triple,
                 )
-        sp.dispatched()
-        self._c["prefill_tokens_dispatched"].inc(n_real)
-        self._c["prefill_dispatches"].inc()
-        self._account_comm(t_pad, sample_rows=n_slots, ring=use_ctx)
-        poison = self._poisoned(
-            [s.uid for s, _, end in entries if end == len(s.tokens)]
-        )
-        next_tokens = None
-        for s, start, end in entries:
-            s.seen_tokens = end
-            if end == len(s.tokens):
-                if next_tokens is None:
-                    next_tokens = np.asarray(sampled)
-                tok = int(next_tokens[s.slot])
-                if s.uid in poison:
-                    tok = -1
-                if tok < 0:
-                    # finite_guard sentinel: the row's logits were non-finite.
-                    # No token is committed; the -1 in ``out`` tells the
-                    # scheduler to fail THIS request (others keep theirs).
-                    # Every key the sequence itself published — including
-                    # ones from EARLIER chunks of this prompt, whose KV the
-                    # same poisoned forward chain wrote — is retracted so
-                    # suspect pages stop serving prefix-cache hits.
-                    s.error = "non-finite logits in prefill"
-                    self.mgr.quarantine_written(s)
-                    out[s.uid] = -1
-                    continue
-                s.tokens.append(tok)
-                self._set_block_table(s)
-                out[s.uid] = tok
-            self.mgr.update_hashes(s)
-        if next_tokens is not None:
-            sp.end()  # host-complete: the sampled fetch above synced the pack
-        else:
-            # intermediate chunks only — nothing fetched, so on an async
-            # backend the pack is still in flight: defer the reading (the
-            # next host-synced tick on this track bounds and resolves it)
-            sp.end(sync_obj=sampled)
+            sp.dispatched()
+            self._c["prefill_tokens_dispatched"].inc(n_real)
+            self._c["prefill_dispatches"].inc()
+            self._account_comm(t_pad, sample_rows=n_slots, ring=use_ctx)
+            if finishing:
+                # host-complete: this fetch syncs the pack
+                next_tokens = np.asarray(sampled)
+            else:
+                # intermediate chunks only: nothing is fetched, so on an
+                # async backend the pack is still in flight when the span
+                # closes.  It is exported unsynced; the pack's device time is
+                # the trace's (one ``XLA Modules`` event per execution)
+                next_tokens = None
+                sp.end(sync_obj=sampled)
+        with tel.span("engine.pack_emit", track=ns, finishing=len(finishing)):
+            poison = self._poisoned([s.uid for s in finishing])
+            for s, start, end in entries:
+                s.seen_tokens = end
+                if end == len(s.tokens):
+                    tok = int(next_tokens[s.slot])
+                    if s.uid in poison:
+                        tok = -1
+                    if tok < 0:
+                        # finite_guard sentinel: the row's logits were non-finite.
+                        # No token is committed; the -1 in ``out`` tells the
+                        # scheduler to fail THIS request (others keep theirs).
+                        # Every key the sequence itself published — including
+                        # ones from EARLIER chunks of this prompt, whose KV the
+                        # same poisoned forward chain wrote — is retracted so
+                        # suspect pages stop serving prefix-cache hits.
+                        s.error = "non-finite logits in prefill"
+                        self.mgr.quarantine_written(s)
+                        out[s.uid] = -1
+                        continue
+                    s.tokens.append(tok)
+                    self._set_block_table(s)
+                    out[s.uid] = tok
+                self.mgr.update_hashes(s)
 
     def _set_block_table(self, seq) -> None:
         row = self._tables_np[seq.slot]
@@ -1320,40 +1320,42 @@ class InferenceEngineV2:
             return {u: [t] for u, t in
                     self._decode_tick(active_seqs, sampling).items()}
         self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
-        B, K = self.mgr.max_seqs, self.spec_max_draft
-        K1, bs = K + 1, self.block_size
-        tokens = np.zeros(B * K1, np.int32)
-        seg = np.zeros(B * K1, np.int32)
-        pos = np.zeros(B * K1, np.int32)
-        dst_pages = np.full(B * K1, -1, np.int32)
-        dst_offs = np.zeros(B * K1, np.int32)
-        draft = np.zeros((B, K), np.int32)
-        n_draft = np.zeros(B, np.int32)
-        ctx_lens = np.zeros(B, np.int32)
-        for s in active_seqs:
-            drafts = proposals.get(s.uid, [])
-            n = len(drafts)
-            L = s.cur_len
-            self._set_block_table(s)  # COW swaps ran in the capacity pre-pass
-            draft[s.slot, :n] = drafts
-            n_draft[s.slot] = n
-            ctx_lens[s.slot] = s.seen_tokens
-            for i in range(n + 1):
-                p_tok = L - 1 + i
-                row = s.slot * K1 + i
-                tokens[row] = s.tokens[-1] if i == 0 else drafts[i - 1]
-                seg[row] = s.slot + 1
-                pos[row] = p_tok
-                dst_pages[row] = s.blocks[p_tok // bs]
-                dst_offs[row] = p_tok % bs
-        self._rng, sub = jax.random.split(self._rng)
-        sp = self.telemetry.recorder.start(
-            "spec_tick", track=self._ns, hist=self._h["spec_tick_ms"],
+        tel, ns = self.telemetry, self._ns
+        with tel.span("engine.decode_build", track=ns, batch=len(active_seqs)):
+            B, K = self.mgr.max_seqs, self.spec_max_draft
+            K1, bs = K + 1, self.block_size
+            tokens = np.zeros(B * K1, np.int32)
+            seg = np.zeros(B * K1, np.int32)
+            pos = np.zeros(B * K1, np.int32)
+            dst_pages = np.full(B * K1, -1, np.int32)
+            dst_offs = np.zeros(B * K1, np.int32)
+            draft = np.zeros((B, K), np.int32)
+            n_draft = np.zeros(B, np.int32)
+            ctx_lens = np.zeros(B, np.int32)
+            for s in active_seqs:
+                drafts = proposals.get(s.uid, [])
+                n = len(drafts)
+                L = s.cur_len
+                self._set_block_table(s)  # COW swaps ran in the capacity pre-pass
+                draft[s.slot, :n] = drafts
+                n_draft[s.slot] = n
+                ctx_lens[s.slot] = s.seen_tokens
+                for i in range(n + 1):
+                    p_tok = L - 1 + i
+                    row = s.slot * K1 + i
+                    tokens[row] = s.tokens[-1] if i == 0 else drafts[i - 1]
+                    seg[row] = s.slot + 1
+                    pos[row] = p_tok
+                    dst_pages[row] = s.blocks[p_tok // bs]
+                    dst_offs[row] = p_tok % bs
+            self._rng, sub = jax.random.split(self._rng)
+        # spec_tick_ms is uploads + dispatch + fetch: the argument uploads
+        # belong inside the span
+        with tel.span(
+            "spec_tick", track=ns, hist=self._h["spec_tick_ms"],
             batch=len(active_seqs), drafted=int(n_draft.sum()),
-        )
-        with self.telemetry.step_annotation(
-            "spec_tick", self._c["spec_ticks"].value + 1
-        ):
+            ctx_tokens=int(ctx_lens.sum()),
+        ) as sp:
             out_dev, n_out_dev, self.kv = self._spec_jit(
                 self.params, jnp.asarray(tokens), jnp.asarray(seg),
                 jnp.asarray(pos), jnp.asarray(dst_pages), jnp.asarray(dst_offs),
@@ -1361,49 +1363,50 @@ class InferenceEngineV2:
                 jnp.asarray(n_draft), self._sampling_device(active_seqs, sampling),
                 self.kv, sub, sampling.top_k, sampling.temperature <= 0.0,
             )
-        sp.dispatched()
-        self._c["spec_ticks"].inc()
-        self._c["spec_seq_forwards"].inc(len(active_seqs))
-        self._account_comm(tokens.shape[0])
-        out_np, n_out = np.asarray(out_dev), np.asarray(n_out_dev)
-        sp.end()  # the fetch above is the tick's host sync
-        poison = self._poisoned([s.uid for s in active_seqs])
-        out: Dict[int, List[int]] = {}
-        for s in active_seqs:
-            n_emit = int(n_out[s.slot])
-            emitted = [int(t) for t in out_np[s.slot, :n_emit]]
-            if s.uid in poison or any(t < 0 for t in emitted):
-                # finite_guard poisoned the whole row (NaN anywhere in its
-                # k+1 verify positions): commit nothing, roll back the draft
-                # page reservations, retract its published keys, and
-                # surface the typed failure
-                s.error = "non-finite logits in verify"
-                self.mgr.quarantine_written(s)
+            sp.dispatched()
+            self._c["spec_ticks"].inc()
+            self._c["spec_seq_forwards"].inc(len(active_seqs))
+            self._account_comm(tokens.shape[0])
+            # the tick's host sync
+            out_np, n_out = np.asarray(out_dev), np.asarray(n_out_dev)
+        with tel.span("engine.decode_emit", track=ns, batch=len(active_seqs)):
+            poison = self._poisoned([s.uid for s in active_seqs])
+            out: Dict[int, List[int]] = {}
+            for s in active_seqs:
+                n_emit = int(n_out[s.slot])
+                emitted = [int(t) for t in out_np[s.slot, :n_emit]]
+                if s.uid in poison or any(t < 0 for t in emitted):
+                    # finite_guard poisoned the whole row (NaN anywhere in its
+                    # k+1 verify positions): commit nothing, roll back the draft
+                    # page reservations, retract its published keys, and
+                    # surface the typed failure
+                    s.error = "non-finite logits in verify"
+                    self.mgr.quarantine_written(s)
+                    if self.mgr.truncate_to_length(s):
+                        self._set_block_table(s)
+                    out[s.uid] = [-1]
+                    continue
+                n = int(n_draft[s.slot])
+                n_acc = n_emit - 1
+                s.tokens.extend(emitted)
+                s.seen_tokens = s.cur_len - 1
+                # rollback: free tail blocks the rejected drafts reserved (their
+                # garbage KV rows inside KEPT blocks are masked by length and
+                # overwritten as the sequence grows — the step_n rule)
                 if self.mgr.truncate_to_length(s):
                     self._set_block_table(s)
-                out[s.uid] = [-1]
-                continue
-            n = int(n_draft[s.slot])
-            n_acc = n_emit - 1
-            s.tokens.extend(emitted)
-            s.seen_tokens = s.cur_len - 1
-            # rollback: free tail blocks the rejected drafts reserved (their
-            # garbage KV rows inside KEPT blocks are masked by length and
-            # overwritten as the sequence grows — the step_n rule)
-            if self.mgr.truncate_to_length(s):
-                self._set_block_table(s)
-            self.mgr.update_hashes(s)
-            self._c["spec_drafted"].inc(n)
-            self._c["spec_accepted"].inc(n_acc)
-            self._c["spec_emitted"].inc(n_emit)
-            s.spec_drafted += n
-            s.spec_accepted += n_acc
-            rep = self._spec_by_replica[self.mgr.replica_of(s)]
-            rep[0] += n
-            rep[1] += n_acc
-            if n > 0:
-                self._spec_update_throttle(s, n, n_acc)
-            out[s.uid] = emitted
+                self.mgr.update_hashes(s)
+                self._c["spec_drafted"].inc(n)
+                self._c["spec_accepted"].inc(n_acc)
+                self._c["spec_emitted"].inc(n_emit)
+                s.spec_drafted += n
+                s.spec_accepted += n_acc
+                rep = self._spec_by_replica[self.mgr.replica_of(s)]
+                rep[0] += n
+                rep[1] += n_acc
+                if n > 0:
+                    self._spec_update_throttle(s, n, n_acc)
+                out[s.uid] = emitted
         return out
 
     def _spec_update_throttle(self, s, n: int, n_acc: int) -> None:
@@ -1424,61 +1427,64 @@ class InferenceEngineV2:
         its own running set without side-driving ``put()``-admitted ones).
         Appends the sampled token per sequence; stop/length handling is the
         caller's job."""
-        B = self.mgr.max_seqs
-        tokens = np.zeros(B, np.int32)
-        seq_lens = np.zeros(B, np.int32)
-        active = np.zeros(B, bool)
-        for s in active_seqs:
-            # grow pages for the token being written this tick; the COW
-            # guard clones the target page first if it is somehow shared
-            self.mgr.ensure_capacity(s, 1)
-            self.mgr.ensure_writable(s, s.cur_len - 1)
-            self._set_block_table(s)
-            tokens[s.slot] = s.tokens[-1]
-            seq_lens[s.slot] = s.cur_len - 1  # KV position of the new token
-            active[s.slot] = True
-        self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
-        self._rng, sub = jax.random.split(self._rng)
-        sp = self.telemetry.recorder.start(
-            "decode_tick", track=self._ns, hist=self._h["decode_tick_ms"],
-            batch=len(active_seqs),
-        )
-        with self.telemetry.step_annotation(
-            "decode_tick", self._c["decode_ticks"].value + 1
-        ):
+        tel, ns = self.telemetry, self._ns
+        with tel.span("engine.decode_build", track=ns, batch=len(active_seqs)):
+            B = self.mgr.max_seqs
+            tokens = np.zeros(B, np.int32)
+            seq_lens = np.zeros(B, np.int32)
+            active = np.zeros(B, bool)
+            ctx_tokens = 0
+            for s in active_seqs:
+                # grow pages for the token being written this tick; the COW
+                # guard clones the target page first if it is somehow shared
+                self.mgr.ensure_capacity(s, 1)
+                self.mgr.ensure_writable(s, s.cur_len - 1)
+                self._set_block_table(s)
+                tokens[s.slot] = s.tokens[-1]
+                seq_lens[s.slot] = s.cur_len - 1  # KV position of the new token
+                active[s.slot] = True
+                ctx_tokens += s.cur_len
+            self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
+            self._rng, sub = jax.random.split(self._rng)
+        # decode_tick_ms is uploads + dispatch + fetch: the argument uploads
+        # (tokens, lengths, tables, key) belong inside the span
+        with tel.span(
+            "decode_tick", track=ns, hist=self._h["decode_tick_ms"],
+            batch=len(active_seqs), ctx_tokens=ctx_tokens,
+        ) as sp:
             sampled, _, _, self.kv = self._decode_jit(
                 self.params, jnp.asarray(tokens), self._commit_rep(seq_lens),
                 self._tables_device(), jnp.asarray(active), self.kv,
                 self._commit_rep(sub),
                 (sampling.temperature, sampling.top_k, sampling.top_p),
             )
-        sp.dispatched()
-        self._c["decode_ticks"].inc()
-        self._c["decode_emitted"].inc(len(active_seqs))
-        self._account_comm(B)
-        next_tokens = np.asarray(sampled)
-        sp.end()  # the fetch above is the tick's host sync
-        poison = self._poisoned([s.uid for s in active_seqs])
-        out = {}
-        for s in active_seqs:
-            tok = int(next_tokens[s.slot])
-            if s.uid in poison:
-                tok = -1
-            if tok < 0:
-                # finite_guard sentinel: fail this row only — no token is
-                # committed, the growth block reserved for it above is
-                # returned, and the keys it published are retracted (its
-                # written KV is suspect) so nothing leaks or pollutes
-                s.error = "non-finite logits in decode"
-                self.mgr.quarantine_written(s)
-                if self.mgr.truncate_to_length(s):
-                    self._set_block_table(s)
-                out[s.uid] = -1
-                continue
-            s.tokens.append(tok)
-            s.seen_tokens = s.cur_len - 1
-            self.mgr.update_hashes(s)
-            out[s.uid] = tok
+            sp.dispatched()
+            self._c["decode_ticks"].inc()
+            self._c["decode_emitted"].inc(len(active_seqs))
+            self._account_comm(B)
+            next_tokens = np.asarray(sampled)  # the tick's host sync
+        with tel.span("engine.decode_emit", track=ns, batch=len(active_seqs)):
+            poison = self._poisoned([s.uid for s in active_seqs])
+            out = {}
+            for s in active_seqs:
+                tok = int(next_tokens[s.slot])
+                if s.uid in poison:
+                    tok = -1
+                if tok < 0:
+                    # finite_guard sentinel: fail this row only — no token is
+                    # committed, the growth block reserved for it above is
+                    # returned, and the keys it published are retracted (its
+                    # written KV is suspect) so nothing leaks or pollutes
+                    s.error = "non-finite logits in decode"
+                    self.mgr.quarantine_written(s)
+                    if self.mgr.truncate_to_length(s):
+                        self._set_block_table(s)
+                    out[s.uid] = -1
+                    continue
+                s.tokens.append(tok)
+                s.seen_tokens = s.cur_len - 1
+                self.mgr.update_hashes(s)
+                out[s.uid] = tok
         return out
 
     def step(self, sampling: SamplingParams = SamplingParams()) -> Dict[int, int]:
@@ -1551,66 +1557,68 @@ class InferenceEngineV2:
         keys are retracted.  A chaos-injected ``nan_logits`` poison applies
         at burst granularity: nothing commits, run = [-1].  Rows given no
         emission headroom return an empty run untouched."""
-        B = self.mgr.max_seqs
-        uids = [s.uid for s in active_seqs]
-        base_lens = np.zeros(B, np.int32)
-        tokens0 = np.zeros(B, np.int32)
-        active = np.zeros(B, bool)
-        stop_rows = np.full(B, -1, np.int32)
-        emit_cap = np.zeros(B, np.int32)
-        for s in active_seqs:
-            cap_i = min(n, self.max_seq_len - s.cur_len)
-            if max_emit is not None and s.uid in max_emit:
-                cap_i = min(cap_i, int(max_emit[s.uid]))
-            if cap_i < 1:
-                continue  # no headroom: empty run, row never enters the batch
-            # pre-reserve every page this row's burst can touch: the block
-            # tables are then static for all its ticks (one upload); rows
-            # stopping early hand the unused tail back after the fetch
-            self.mgr.ensure_capacity(s, cap_i)
-            self.mgr.ensure_writable(s, s.cur_len - 1)
-            self._set_block_table(s)
-            base_lens[s.slot] = s.cur_len - 1
-            tokens0[s.slot] = s.tokens[-1]
-            active[s.slot] = True
-            emit_cap[s.slot] = cap_i
-            st = sampling.stop_token if stop_tokens is None \
-                else stop_tokens.get(s.uid, sampling.stop_token)
-            stop_rows[s.slot] = -1 if st is None else int(st)
-        if not active.any():
-            return {u: [] for u in uids}
-        # no tick can emit once every row is past its cap — clamp the burst
-        n = min(n, int(emit_cap.max()))
-        self._maybe_fault("runner_exception", uids)
-        tables = self._tables_device()
-        tokens_dev = self._commit_rep(tokens0)
-        lens_dev = self._commit_rep(base_lens)
-        active_dev = self._commit_rep(active)
-        emitted_dev = self._commit_rep(np.zeros(B, np.int32))
-        stop_dev = self._commit_rep(stop_rows)
-        cap_dev = self._commit_rep(emit_cap)
-        self._rng, key_dev = jax.random.split(self._rng)
-        key_dev = self._commit_rep(key_dev)
-        triple = (sampling.temperature, sampling.top_k, sampling.top_p)
-        # fixed burst capacity -> one compiled program for every n
-        cap = self._burst_cap
-        while cap < n:
-            cap *= 2
-        self._burst_cap = cap
-        # [cap+1, B]: row 0 carries the per-slot emission counts, row 1+t
-        # tick t's emissions — counts and tokens come back in ONE fetch
-        buf = np.full((cap + 1, B), _BURST_PAD, np.int32)
-        buf[0] = 0
-        burst_dev = self._commit_rep(buf)
-        tick_dev = self._commit_rep(np.zeros((), np.int32))
+        tel, ns = self.telemetry, self._ns
+        with tel.span("engine.decode_build", track=ns, batch=len(active_seqs)):
+            B = self.mgr.max_seqs
+            uids = [s.uid for s in active_seqs]
+            base_lens = np.zeros(B, np.int32)
+            tokens0 = np.zeros(B, np.int32)
+            active = np.zeros(B, bool)
+            stop_rows = np.full(B, -1, np.int32)
+            emit_cap = np.zeros(B, np.int32)
+            for s in active_seqs:
+                cap_i = min(n, self.max_seq_len - s.cur_len)
+                if max_emit is not None and s.uid in max_emit:
+                    cap_i = min(cap_i, int(max_emit[s.uid]))
+                if cap_i < 1:
+                    continue  # no headroom: empty run, row never enters the batch
+                # pre-reserve every page this row's burst can touch: the block
+                # tables are then static for all its ticks (one upload); rows
+                # stopping early hand the unused tail back after the fetch
+                self.mgr.ensure_capacity(s, cap_i)
+                self.mgr.ensure_writable(s, s.cur_len - 1)
+                self._set_block_table(s)
+                base_lens[s.slot] = s.cur_len - 1
+                tokens0[s.slot] = s.tokens[-1]
+                active[s.slot] = True
+                emit_cap[s.slot] = cap_i
+                st = sampling.stop_token if stop_tokens is None \
+                    else stop_tokens.get(s.uid, sampling.stop_token)
+                stop_rows[s.slot] = -1 if st is None else int(st)
+            if not active.any():
+                return {u: [] for u in uids}
+            # no tick can emit once every row is past its cap — clamp the burst
+            n = min(n, int(emit_cap.max()))
+            self._maybe_fault("runner_exception", uids)
+            tables = self._tables_device()
+            tokens_dev = self._commit_rep(tokens0)
+            lens_dev = self._commit_rep(base_lens)
+            active_dev = self._commit_rep(active)
+            emitted_dev = self._commit_rep(np.zeros(B, np.int32))
+            stop_dev = self._commit_rep(stop_rows)
+            cap_dev = self._commit_rep(emit_cap)
+            self._rng, key_dev = jax.random.split(self._rng)
+            key_dev = self._commit_rep(key_dev)
+            triple = (sampling.temperature, sampling.top_k, sampling.top_p)
+            # fixed burst capacity -> one compiled program for every n
+            cap = self._burst_cap
+            while cap < n:
+                cap *= 2
+            self._burst_cap = cap
+            # [cap+1, B]: row 0 carries the per-slot emission counts, row 1+t
+            # tick t's emissions — counts and tokens come back in ONE fetch
+            buf = np.full((cap + 1, B), _BURST_PAD, np.int32)
+            buf[0] = 0
+            burst_dev = self._commit_rep(buf)
+            tick_dev = self._commit_rep(np.zeros((), np.int32))
         # ONE span for the whole burst — per-tick spans would retain one
         # device array per tick, the exact host-reference leak this design
         # removes; the per-tick figure is the burst average, observed once
         # per tick
-        sp = self.telemetry.recorder.start(
-            "decode_burst", track=self._ns, ticks=n, batch=len(active_seqs),
-        )
-        with self.telemetry.step_annotation("decode_burst", n):
+        with tel.span(
+            "decode_burst", track=ns, ticks=n, batch=len(active_seqs),
+            ctx_tokens=int(base_lens.sum()) + int(active.sum()),
+        ) as sp:
             for _ in range(n):
                 (tokens_dev, lens_dev, key_dev, self.kv, burst_dev,
                  tick_dev, active_dev, emitted_dev) = self._decode_burst_jit(
@@ -1618,51 +1626,51 @@ class InferenceEngineV2:
                     self.kv, key_dev, burst_dev, tick_dev, emitted_dev,
                     stop_dev, cap_dev, triple,
                 )
-        sp.dispatched()
-        # a burst is n decode dispatches: account their TP wire bytes —
-        # per-tick plan x n, ONE block-table upload (the same enumeration
-        # the Graft Auditor checks against the burst jit's compiled HLO)
-        self._account_comm(B, reps=n)
-        self._c["decode_bursts"].inc()
-        self._c["burst_ticks"].inc(n)
-        burst = np.asarray(burst_dev)[: n + 1]  # the ONE host sync
-        sp = sp.end()
+            sp.dispatched()
+            # a burst is n decode dispatches: account their TP wire bytes —
+            # per-tick plan x n, ONE block-table upload (the same enumeration
+            # the Graft Auditor checks against the burst jit's compiled HLO)
+            self._account_comm(B, reps=n)
+            self._c["decode_bursts"].inc()
+            self._c["burst_ticks"].inc(n)
+            burst = np.asarray(burst_dev)[: n + 1]  # the ONE host sync
         if sp.duration_ms is not None:
             per_tick = sp.duration_ms / n
             for _ in range(n):
                 self._h["burst_tick_ms"].observe(per_tick)
-        poison_inj = self._poisoned(uids)
-        out: Dict[int, List[int]] = {}
-        total = 0
-        for s in active_seqs:
-            if not active[s.slot]:
-                out[s.uid] = []
-                continue
-            m = int(burst[0, s.slot])
-            run = [int(t) for t in burst[1: 1 + m, s.slot]]
-            if s.uid in poison_inj:
-                # chaos-injected poison: same contract as a tick-0 device
-                # sentinel — nothing committed, the row quarantined
-                run, committed = [-1], []
-            elif run and run[-1] == -1:
-                committed = run[:-1]
-            else:
-                committed = run
-            s.tokens.extend(committed)
-            s.seen_tokens = s.cur_len - 1
-            if run and run[-1] < 0:
-                # the row deactivated at its first bad tick on device; its
-                # published keys are retracted (written KV is suspect)
-                s.error = "non-finite logits in decode burst"
-                self.mgr.quarantine_written(s)
-            else:
-                self.mgr.update_hashes(s)
-            # hand back the unused tail reservation (early-stopped rows) /
-            # the poisoned tick's growth block in one truncate
-            if self.mgr.truncate_to_length(s):
-                self._set_block_table(s)
-            total += len(committed)
-            out[s.uid] = run
+        with tel.span("engine.decode_emit", track=ns, batch=len(active_seqs)):
+            poison_inj = self._poisoned(uids)
+            out: Dict[int, List[int]] = {}
+            total = 0
+            for s in active_seqs:
+                if not active[s.slot]:
+                    out[s.uid] = []
+                    continue
+                m = int(burst[0, s.slot])
+                run = [int(t) for t in burst[1: 1 + m, s.slot]]
+                if s.uid in poison_inj:
+                    # chaos-injected poison: same contract as a tick-0 device
+                    # sentinel — nothing committed, the row quarantined
+                    run, committed = [-1], []
+                elif run and run[-1] == -1:
+                    committed = run[:-1]
+                else:
+                    committed = run
+                s.tokens.extend(committed)
+                s.seen_tokens = s.cur_len - 1
+                if run and run[-1] < 0:
+                    # the row deactivated at its first bad tick on device; its
+                    # published keys are retracted (written KV is suspect)
+                    s.error = "non-finite logits in decode burst"
+                    self.mgr.quarantine_written(s)
+                else:
+                    self.mgr.update_hashes(s)
+                # hand back the unused tail reservation (early-stopped rows) /
+                # the poisoned tick's growth block in one truncate
+                if self.mgr.truncate_to_length(s):
+                    self._set_block_table(s)
+                total += len(committed)
+                out[s.uid] = run
         self._c["burst_emitted"].inc(total)
         return out
 

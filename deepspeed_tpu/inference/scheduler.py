@@ -958,7 +958,7 @@ class ServeScheduler:
             if r is not None and r.state == PREFILL:
                 # chunks share the tick's pack dispatch(es); each request's
                 # chunk span carries the shared window + its own token count
-                r.trace.prefill_chunk(t0, t1, end - start)
+                r.trace.prefill_chunk(t0, t1, end - start, tick=self.tick_no)
         self._c["prefill_chunks"].inc(len(entries))
         for req in list(self._running):
             if req.state == PREFILL and req.uid in first:
@@ -970,7 +970,7 @@ class ServeScheduler:
                     continue
                 req.state = DECODE
                 req.generated.append(tok)
-                req.trace.tokens(1)
+                req.trace.tokens(1, tick=self.tick_no)
                 out[req.uid] = tok
                 self._maybe_finish(req)
         return out
@@ -1227,7 +1227,7 @@ class ServeScheduler:
                 # sequence releases its state
                 emitted = emitted[: emitted.index(stop) + 1]
             req.generated.extend(emitted)
-            req.trace.tokens(len(emitted))
+            req.trace.tokens(len(emitted), tick=self.tick_no)
             out[req.uid] = emitted[-1]
             self._maybe_finish(req)
         return out
@@ -1276,7 +1276,7 @@ class ServeScheduler:
             # one span covers the whole shed episode: visible as a block on
             # the engine's track in the Chrome trace
             self._shed_span = self.telemetry.recorder.start(
-                "shed_mode", track=self._eng_ns, reason=reason,
+                "shed_mode", track=self._eng_ns, detached=True, reason=reason,
                 queue_depth=len(self.waiting), tick=self.tick_no,
             )
         else:
@@ -1486,39 +1486,46 @@ class ServeScheduler:
         timed-out / cancelled requests never appear in the returned dict —
         read their terminal state off ``requests[uid]``."""
         self.tick_no += 1
-        self._in_tick = True  # single-owner write: cancels now defer
-        self._apply_pending_knobs()  # staged retunes land HERE, never mid-phase
-        t0 = self._clock()  # BEFORE the fault delay: an injected stall must
-        # land inside the watchdog's measured window or it cannot trip it
-        try:
-            if self.faults is not None:
-                d = self.faults.delay("slow_tick")
-                if d > 0:
-                    time.sleep(d)  # chaos harness: stalls the tick, trips the watchdog
-            self._expire_phase()
-            self._admit_phase()
-            decoding = [r for r in self._running if r.state == DECODE]
-            out = self._prefill_phase()
-            self._last_fused = 1
-            out.update(self._decode_phase(decoding))
-            # a megastep deliberately makes the tick n_fuse x longer —
-            # normalize the watchdog/EMA duration back to per-device-tick
-            # so fused decode cannot trip the slow-tick shed path
-            self._update_degradation(
-                (self._clock() - t0) * 1e3 / self._last_fused)
-            if self.engine.mgr.replicas > 1:
-                # per-replica hit/headroom/spec-accept gauges: cheap host
-                # math, refreshed at the tick boundary (engine doubles
-                # without the method — schedviz stubs — just skip)
-                up = getattr(self.engine, "update_replica_gauges", None)
-                if up is not None:
-                    up()
-            return out
-        finally:
-            self._in_tick = False
-            # releases from the phases (finish/fail/expire) fire their
-            # JSONL trace summaries here, outside every lock
-            self._flush_released()
+        tel, track = self.telemetry, self._eng_ns
+        with tel.span("sched.tick", track=track, tick=self.tick_no,
+                      running=len(self._running), waiting=len(self.waiting)):
+            self._in_tick = True  # single-owner write: cancels now defer
+            self._apply_pending_knobs()  # staged retunes land HERE, never mid-phase
+            t0 = self._clock()  # BEFORE the fault delay: an injected stall must
+            # land inside the watchdog's measured window or it cannot trip it
+            try:
+                if self.faults is not None:
+                    d = self.faults.delay("slow_tick")
+                    if d > 0:
+                        time.sleep(d)  # chaos harness: stalls the tick, trips the watchdog
+                with tel.span("sched.expire", track=track):
+                    self._expire_phase()
+                with tel.span("sched.admit", track=track):
+                    self._admit_phase()
+                decoding = [r for r in self._running if r.state == DECODE]
+                with tel.span("sched.prefill", track=track):
+                    out = self._prefill_phase()
+                self._last_fused = 1
+                with tel.span("sched.decode", track=track, batch=len(decoding)):
+                    out.update(self._decode_phase(decoding))
+                # a megastep deliberately makes the tick n_fuse x longer —
+                # normalize the watchdog/EMA duration back to per-device-tick
+                # so fused decode cannot trip the slow-tick shed path
+                self._update_degradation(
+                    (self._clock() - t0) * 1e3 / self._last_fused)
+                if self.engine.mgr.replicas > 1:
+                    # per-replica hit/headroom/spec-accept gauges: cheap host
+                    # math, refreshed at the tick boundary (engine doubles
+                    # without the method — schedviz stubs — just skip)
+                    up = getattr(self.engine, "update_replica_gauges", None)
+                    if up is not None:
+                        up()
+                return out
+            finally:
+                self._in_tick = False
+                # releases from the phases (finish/fail/expire) fire their
+                # JSONL trace summaries here, outside every lock
+                self._flush_released()
 
     def run(self, wait_for: Optional[Sequence[int]] = None,
             max_ticks: int = 1_000_000) -> Dict[int, List[int]]:

@@ -223,18 +223,24 @@ def init_params(rng: jax.Array, cfg: TransformerConfig, dtype=jnp.float32) -> Pa
 # ---------------------------------------------------------------------------
 # building blocks
 # ---------------------------------------------------------------------------
+# ``jax.named_scope`` at the layer boundaries (embed / attn / mlp / norm / head
+# / loss): metadata only, it names every HLO instruction's ``op_name`` so a
+# device trace can be attributed by layer (telemetry/programs.py).
+# The scopes are ``with`` blocks, never wrappers: one more Python frame under
+# every traced op slows JAX's tracing of a deep program by seconds (PERF.md).
 def norm(x: jnp.ndarray, w: Params, kind: str, eps: float) -> jnp.ndarray:
-    xf = x.astype(jnp.float32)
-    if kind == "rmsnorm":
-        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    else:
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-        xf = (xf - mu) * jax.lax.rsqrt(var + eps)
-    out = xf.astype(x.dtype) * w["scale"]
-    if "bias" in w:
-        out = out + w["bias"]
-    return out
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        if kind == "rmsnorm":
+            xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+        else:
+            mu = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+            xf = (xf - mu) * jax.lax.rsqrt(var + eps)
+        out = xf.astype(x.dtype) * w["scale"]
+        if "bias" in w:
+            out = out + w["bias"]
+        return out
 
 
 def alibi_slopes(num_heads: int) -> jnp.ndarray:
@@ -481,19 +487,21 @@ def decoder_layer(
         from ..compression.compress import quantize_activation
 
         attn_in = quantize_activation(attn_in, cfg.act_quant_bits)
-    h, new_cache = attention_block(
-        lw["attn"], tp_in(attn_in), cfg,
-        positions, attn_fn, segment_ids, cache, cache_index,
-    )
-    if tp_axis is not None:
-        h = _tp_psum_fn(tp_axis)(h)  # row-parallel wo partial sums
+    with jax.named_scope("attn"):
+        h, new_cache = attention_block(
+            lw["attn"], tp_in(attn_in), cfg,
+            positions, attn_fn, segment_ids, cache, cache_index,
+        )
+        if tp_axis is not None:
+            h = _tp_psum_fn(tp_axis)(h)  # row-parallel wo partial sums
     aux = jnp.asarray(0.0, jnp.float32)
     if cfg.parallel_block:
         # falcon/gptj/phi: both branches read the SAME normed input; one
         # residual add (reference containers' parallel attn+mlp layout)
-        m = mlp_block(lw["mlp"], tp_in(attn_in), cfg)
-        if tp_axis is not None:
-            m = _tp_psum_fn(tp_axis)(m)
+        with jax.named_scope("mlp"):
+            m = mlp_block(lw["mlp"], tp_in(attn_in), cfg)
+            if tp_axis is not None:
+                m = _tp_psum_fn(tp_axis)(m)
         x = shard_activation(x + h.astype(dtype) + m.astype(dtype), ACT_SPEC)
         return x, new_cache, aux
     x = shard_activation(x + h.astype(dtype), ACT_SPEC)
@@ -502,35 +510,36 @@ def decoder_layer(
         from ..compression.compress import quantize_activation
 
         y = quantize_activation(y, cfg.act_quant_bits)
-    if cfg.moe_num_experts > 0:
-        from ..parallel.sharding import axis_size, get_current_mesh
-        from ..parallel.topology import EXPERT_AXIS
+    with jax.named_scope("mlp"):
+        if cfg.moe_num_experts > 0:
+            from ..parallel.sharding import axis_size, get_current_mesh
+            from ..parallel.topology import EXPERT_AXIS
 
-        mesh = get_current_mesh()
-        if cache is not None:
-            # inference (KV-cache) path: dropless routing — capacity
-            # dropping is a training regularizer and would couple routing
-            # to batch/padding shape (moe/layer.py moe_block_dropless)
-            from ..moe.layer import moe_block_dropless as _moe
+            mesh = get_current_mesh()
+            if cache is not None:
+                # inference (KV-cache) path: dropless routing — capacity
+                # dropping is a training regularizer and would couple routing
+                # to batch/padding shape (moe/layer.py moe_block_dropless)
+                from ..moe.layer import moe_block_dropless as _moe
 
-            h, aux = _moe(lw["moe"], y, cfg)
-        elif (cfg.moe_qcomm is not None and mesh is not None
-                and axis_size(EXPERT_AXIS) > 1):
-            # explicit expert-parallel region: the dispatch/combine slabs
-            # travel through qcomm (quantized when asked) instead of
-            # GSPMD's full-width layout-change all-to-all
-            from ..moe.layer import routed_ffn_ep
+                h, aux = _moe(lw["moe"], y, cfg)
+            elif (cfg.moe_qcomm is not None and mesh is not None
+                    and axis_size(EXPERT_AXIS) > 1):
+                # explicit expert-parallel region: the dispatch/combine slabs
+                # travel through qcomm (quantized when asked) instead of
+                # GSPMD's full-width layout-change all-to-all
+                from ..moe.layer import routed_ffn_ep
 
-            h, aux = routed_ffn_ep(lw["moe"], y, cfg, mesh,
-                                   fmt=cfg.moe_qcomm)
+                h, aux = routed_ffn_ep(lw["moe"], y, cfg, mesh,
+                                       fmt=cfg.moe_qcomm)
+            else:
+                from ..moe.layer import moe_block as _moe
+
+                h, aux = _moe(lw["moe"], y, cfg)
         else:
-            from ..moe.layer import moe_block as _moe
-
-            h, aux = _moe(lw["moe"], y, cfg)
-    else:
-        h = mlp_block(lw["mlp"], tp_in(y), cfg)
-    if tp_axis is not None:
-        h = _tp_psum_fn(tp_axis)(h)  # row-parallel w_down partial sums
+            h = mlp_block(lw["mlp"], tp_in(y), cfg)
+        if tp_axis is not None:
+            h = _tp_psum_fn(tp_axis)(h)  # row-parallel w_down partial sums
     x = shard_activation(x + h.astype(dtype), ACT_SPEC)
     return x, new_cache, aux
 
@@ -596,13 +605,14 @@ def forward(
         base = cache_index if cache_index is not None else 0
         positions = jnp.arange(s)[None, :] + base
         positions = jnp.broadcast_to(positions, (b, s))
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    if cfg.position == "learned":
-        x = x + params["pos_embed"]["embedding"][positions].astype(cfg.dtype)
-    if cfg.embedding_norm:
-        # bloom word_embeddings_layernorm (module_inject containers/bloom)
-        x = norm(x, params["embed_norm"], cfg.norm, cfg.norm_eps)
-    x = shard_activation(x, ACT_SPEC)
+    with jax.named_scope("embed"):
+        x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+        if cfg.position == "learned":
+            x = x + params["pos_embed"]["embedding"][positions].astype(cfg.dtype)
+        if cfg.embedding_norm:
+            # bloom word_embeddings_layernorm (module_inject containers/bloom)
+            x = norm(x, params["embed_norm"], cfg.norm, cfg.norm_eps)
+        x = shard_activation(x, ACT_SPEC)
 
     if stack_apply is not None:
         if layer_keep is not None:
@@ -722,10 +732,11 @@ def forward(
     x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     if return_hidden:
         return x, new_caches, aux_loss
-    logits = x @ head_kernel(params, cfg)
-    hb = head_bias_vec(params)
-    if hb is not None:
-        logits = logits + hb
+    with jax.named_scope("head"):
+        logits = x @ head_kernel(params, cfg)
+        hb = head_bias_vec(params)
+        if hb is not None:
+            logits = logits + hb
     return logits, new_caches, aux_loss
 
 
@@ -819,17 +830,19 @@ class CausalLM:
                 return_hidden=True, stack_apply=self.stack_apply,
                 layer_keep=layer_keep,
             )
-            loss = chunked_cross_entropy(
-                hidden, head_kernel(params, self.cfg), labels,
-                chunk_size=self.cfg.loss_chunk_size,
-                head_bias=head_bias_vec(params),
-            )
+            with jax.named_scope("loss"):  # the head matmul rides the chunks
+                loss = chunked_cross_entropy(
+                    hidden, head_kernel(params, self.cfg), labels,
+                    chunk_size=self.cfg.loss_chunk_size,
+                    head_bias=head_bias_vec(params),
+                )
         else:
             logits, _, aux = forward(
                 params, inputs, self.cfg, segment_ids=segment_ids,
                 stack_apply=self.stack_apply, layer_keep=layer_keep,
             )
-            loss = cross_entropy_loss(logits, labels)
+            with jax.named_scope("loss"):
+                loss = cross_entropy_loss(logits, labels)
         if self.cfg.moe_num_experts > 0:
             loss = loss + self.cfg.moe_aux_loss_coef * aux / max(self.cfg.num_layers, 1)
         return loss

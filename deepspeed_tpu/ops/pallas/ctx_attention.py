@@ -296,6 +296,7 @@ def paged_attention_packed_ctx_kernel(
     )
     acc, m, l = pl.pallas_call(
         kernel,
+        name="packed_ctx",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n, p),

@@ -280,6 +280,7 @@ def _fwd(q, k, v, qseg, kseg, scale, soft_cap):
         operands += [qseg, kseg]
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -387,6 +388,7 @@ def _bwd(scale, soft_cap, res, do):
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
                           has_seg=has_seg, soft_cap=soft_cap),
+        name="flash_bwd_dq",
         grid=(bh, s // bq, s // bk),
         in_specs=in_specs,
         out_specs=[qspec],
@@ -410,6 +412,7 @@ def _bwd(scale, soft_cap, res, do):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
                           has_seg=has_seg, soft_cap=soft_cap),
+        name="flash_bwd_dkv",
         grid=(bh, s // bk, s // bq),
         in_specs=in_specs2,
         out_specs=[kspec, kspec],
@@ -615,6 +618,7 @@ def _fwd_sparse(q, k, v, qseg, kseg, scale, soft_cap, tables, block):
         operands += [qseg, kseg]
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_sparse_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, s // block, max_a),
@@ -665,6 +669,7 @@ def _bwd_sparse(scale, soft_cap, tables, block, res, do):
     dq = pl.pallas_call(
         functools.partial(_dq_sparse_kernel, scale=scale, bq=block, bk=block,
                           has_seg=has_seg, soft_cap=soft_cap),
+        name="flash_sparse_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, s // block, len(tbl[0])),
@@ -690,6 +695,7 @@ def _bwd_sparse(scale, soft_cap, tables, block, res, do):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_sparse_kernel, scale=scale, bq=block, bk=block,
                           has_seg=has_seg, soft_cap=soft_cap),
+        name="flash_sparse_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, s // block, len(tblT[0])),

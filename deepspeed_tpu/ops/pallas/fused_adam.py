@@ -59,6 +59,7 @@ def fused_adamw_flat(
     blk = pl.BlockSpec((bs,), lambda i: (i,))
     return pl.pallas_call(
         kernel,
+        name="fused_adam",
         grid=(n // bs,),
         in_specs=[blk, blk, blk, blk, scalar, scalar],
         out_specs=[blk, blk, blk],

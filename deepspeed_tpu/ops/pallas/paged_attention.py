@@ -238,6 +238,7 @@ def _paged_decode_packed(q, cache_k, cache_v, safe_tables, lens, scale):
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_packed",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
@@ -290,6 +291,7 @@ def paged_attention_decode_kernel(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),

@@ -74,6 +74,7 @@ def quantize_int8(x2d: jnp.ndarray):
     bm = _block_rows(g, n)
     q, s = pl.pallas_call(
         _quant_int8_kernel,
+        name="quant_int8",
         grid=(g // bm,),
         in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))],
         out_specs=[
@@ -94,6 +95,7 @@ def dequantize_int8(q2d: jnp.ndarray, scales: jnp.ndarray, out_dtype=jnp.bfloat1
     bm = _block_rows(g, n)
     return pl.pallas_call(
         functools.partial(_dequant_int8_kernel, out_dtype=out_dtype),
+        name="quant_dequant_int8",
         grid=(g // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
@@ -120,6 +122,7 @@ def quantize_fp8(x2d: jnp.ndarray, dtype=jnp.float8_e4m3fn):
     fp8_max = float(jnp.finfo(dtype).max)
     q, s = pl.pallas_call(
         functools.partial(_quant_fp8_kernel, fp8_dtype=dtype, fp8_max=fp8_max),
+        name="quant_fp8",
         grid=(g // bm,),
         in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))],
         out_specs=[

@@ -149,6 +149,7 @@ def quant_matmul(
         functools.partial(
             _qmm_kernel, out_dtype=x.dtype, has_bias=has_bias, n_k=k // bk
         ),
+        name="quant_matmul_int8",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -283,6 +284,7 @@ def quant_matmul_fp6(
         functools.partial(
             _fp6_mm_kernel, out_dtype=x.dtype, has_bias=has_bias, n_k=n_k
         ),
+        name="quant_matmul_fp6",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),

@@ -59,7 +59,7 @@ from ..utils.timer import (
 from . import precision, zero
 from .lr_schedules import LRScheduler, get_lr_schedule_fn
 from .prefetch import DevicePrefetcher, MetricsBuffer, host_scalar
-from ..telemetry import Telemetry
+from ..telemetry import Telemetry, track_program
 
 
 def _now() -> float:
@@ -173,12 +173,10 @@ class DeepSpeedTpuEngine:
             steps_per_output=config.steps_per_print,
         )
         self.monitor = None  # attached by initialize()
-        # unified telemetry (telemetry/): spans around train_batch with
-        # deferred device readings, registry snapshot fan-out to the
-        # monitor at flush boundaries; near-zero no-ops unless
-        # config.telemetry.enabled
+        # unified telemetry (telemetry/): a span around train_batch, closed
+        # at dispatch; registry snapshot fan-out to the monitor at flush
+        # boundaries; near-zero no-ops unless config.telemetry.enabled
         self.telemetry = Telemetry(config.telemetry)
-        self._h_step = self.telemetry.registry.histogram("train/step_ms")
         self.lr_schedule_fn = self._build_lr_schedule()
         self.lr_scheduler = LRScheduler(self.lr_schedule_fn)
         self._onebit = config.optimizer.type.lower().replace("_", "") in (
@@ -358,6 +356,7 @@ class DeepSpeedTpuEngine:
         )
 
         self._train_step = None  # built lazily (needs batch sharding)
+        self._step_program = None  # its TrackedProgram (telemetry/programs.py)
         self._grad_fn = None
         self._apply_fn = None
         self._eval_step = None
@@ -505,7 +504,7 @@ class DeepSpeedTpuEngine:
 
         def scaled_loss(p):
             cp = precision.cast_floating(p, self.compute_dtype)
-            cp = zero.constrain(cp, self.param_shardings)
+            cp = zero.constrain(cp, self.param_shardings, scope="zero/gather")
             if self._compression is not None and step is not None:
                 # QAT fake-quant / pruning via STE inside the traced step
                 # (compression/compress.py; reference init_compression)
@@ -524,7 +523,8 @@ class DeepSpeedTpuEngine:
             loss = self.loss_fn(cp, batch_, rng)
             return loss * scale
 
-        loss, grads = jax.value_and_grad(scaled_loss)(master_params)
+        with jax.named_scope("grad"):
+            loss, grads = jax.value_and_grad(scaled_loss)(master_params)
         return loss / scale, grads
 
     def _apply_grads(self, state: TrainState, grad_sum, divisor):
@@ -597,7 +597,8 @@ class DeepSpeedTpuEngine:
                 loss, grads = out[0], out[1]
                 # device-kind layout: grads live in HBM even when masters are
                 # offloaded (only the state pytree itself rides pinned_host)
-                grads = zero.constrain(grads, self.master_shardings_dev)
+                grads = zero.constrain(grads, self.master_shardings_dev,
+                                       scope="zero/reduce")
                 return loss, grads, (out[2] if loco else None)
 
             if gas == 1:
@@ -627,7 +628,8 @@ class DeepSpeedTpuEngine:
 
             # fp16 overflow handling (reference: fp16/loss_scaler.py overflow
             # path + engine.py skipped-step count) lives in _apply_grads.
-            new_state, grad_norm, finite = self._apply_grads(state, grads, divisor)
+            with jax.named_scope("optimizer"):
+                new_state, grad_norm, finite = self._apply_grads(state, grads, divisor)
             metrics = StepMetrics(
                 loss=loss,
                 grad_norm=grad_norm,
@@ -684,6 +686,10 @@ class DeepSpeedTpuEngine:
                 out_shardings=(self.state_shardings, metrics_shardings),
                 donate_argnums=(0,),
             )
+            # tracked: telemetry.program_scopes() can name the step's
+            # instructions after the fact (nothing is lowered for it here;
+            # train_batch notes the shapes of the call that compiled)
+            self._step_program = None if self._offload_cpu else track_program(jitted)
             if self._offload_cpu:
                 jitted = self._wrap_offload_step(jitted, step_fn, batch, metrics_shardings)
             self._train_step = jitted
@@ -1020,16 +1026,18 @@ class DeepSpeedTpuEngine:
         self.tput_timer.start()
         self.timers(STEP_GLOBAL_TIMER).start()
         rng = self._next_rng()
-        # deferred-device-read span (the PR 1 MetricsBuffer trick): the
-        # dispatch wall time lands now, the loss reading is blocked on only
-        # at the steps_per_print flush — no per-step host sync added
-        tb_span = self.telemetry.recorder.start(
-            "train_batch", track="train", hist=self._h_step,
-            step=self.global_steps + 1,
-        )
-        with self.telemetry.step_annotation("train_batch", self.global_steps + 1):
-            self.state, metrics = self._get_train_step(batch)(self.state, batch, rng)
-        tb_span.end(sync_obj=metrics.loss)
+        # no host read is added to the step: the span closes at dispatch and
+        # is exported unsynced (its duration is the enqueue; the step's
+        # device time is the trace's, one XLA Modules event per execution)
+        with self.telemetry.span(
+            "train_batch", track="train", step=self.global_steps + 1,
+        ) as tb_span:
+            args = (self.state, batch, rng)
+            self.state, metrics = self._get_train_step(batch)(*args)
+            if self._step_program is not None:
+                self._step_program.note(args)
+            del args  # the donated state is gone; hold no dead handle
+            tb_span.end(sync_obj=metrics.loss)
         self._last_metrics = metrics
         self.global_steps += 1
         async_metrics = self.config.train_data.async_metrics
@@ -1257,7 +1265,8 @@ class DeepSpeedTpuEngine:
 
             def apply(state: TrainState, grad_sum):
                 scale = state.loss_scale.scale if fp16 else jnp.asarray(1.0, jnp.float32)
-                new_state, _, finite = self._apply_grads(state, grad_sum, scale * gas)
+                with jax.named_scope("optimizer"):
+                    new_state, _, finite = self._apply_grads(state, grad_sum, scale * gas)
                 return new_state, jnp.logical_not(finite)
 
             self._apply_fn = self._jit(

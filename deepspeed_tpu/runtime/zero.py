@@ -29,6 +29,7 @@ This module is pure host-side planning: no collective is issued here.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Tuple
@@ -250,11 +251,16 @@ def plan_sharding(
     )
 
 
-def constrain(tree, specs):
-    """Apply sharding constraints leaf-wise inside jit."""
-    return jax.tree_util.tree_map(
-        lambda x, s: jax.lax.with_sharding_constraint(x, s), tree, specs
-    )
+def constrain(tree, specs, scope: Optional[str] = None):
+    """Apply sharding constraints leaf-wise inside jit.  ``scope`` names the
+    constraint in the HLO's ``op_name`` (``zero/gather`` where the sharded
+    masters become compute parameters, ``zero/reduce`` where gradients return
+    to the masters' layout): it is where the partitioner hangs the step's
+    collectives."""
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.lax.with_sharding_constraint(x, s), tree, specs
+        )
 
 
 def init_sharded_params(init_fn: Callable, rng, plan: ShardingPlan, mesh):

@@ -337,7 +337,7 @@ class Router:
             rec.phase = BACKLOG
             self._backlog.append(uid)
             rec.queue_span = self.telemetry.recorder.start(
-                "queued", track="router", uid=uid)
+                "queued", track="router", detached=True, uid=uid)
         return SubmitResult(uid, QUEUED)
 
     def submit(self, uid: int, tokens: Sequence[int],
@@ -446,7 +446,7 @@ class Router:
         self._backlog.append(rec.uid)
         if rec.queue_span is None:
             rec.queue_span = self.telemetry.recorder.start(
-                "queued", track="router", uid=rec.uid)
+                "queued", track="router", detached=True, uid=rec.uid)
 
     # -- prefill/decode migration -------------------------------------------
     def _maybe_migrate(self, rec: RouterRequest) -> None:

@@ -4,11 +4,14 @@
   buckets + exact small-count quantiles), ``snapshot()`` ->
   ``(label, value, step)`` events for the monitor fan-out, JSONL sink,
   ``StatsView`` compat mapping backing the engines' ``stats`` dicts.
-- ``tracing.py`` — ``TraceRecorder`` dispatch spans with deferred device
-  readings, ``RequestTrace`` serve-request lifecycles (TTFT / TBT / queue
-  wait / accept rate), Chrome trace-event export (Perfetto-loadable),
-  ``Telemetry`` facade with the optional ``jax.profiler`` step-annotation
-  hook.
+- ``tracing.py`` — ``TraceRecorder``'s span tree (ids, parents),
+  ``RequestTrace`` serve-request lifecycles (TTFT / TBT / queue wait /
+  accept rate), Chrome trace-event export (Perfetto-loadable), ``Telemetry``
+  facade whose ``span()`` also mirrors each span into a live
+  ``jax.profiler`` capture when the ``jax_profiler`` knob is on.
+- ``programs.py`` — the engines' jitted programs, tracked by shape;
+  ``program_scopes()`` / ``collective_bytes_per_step()`` read their compiled
+  text lazily (instruction -> ``jax.named_scope`` path, collective bytes).
 - ``fleet.py`` — the fleet observability plane: ``FleetRegistry`` merges
   per-worker registry snapshots (counter rollups + histogram merges with
   the documented quantile bound), ``SloMonitor`` computes availability
@@ -35,6 +38,11 @@ from .tracing import (  # noqa: F401
     Span,
     Telemetry,
     TraceRecorder,
+)
+from .programs import (  # noqa: F401
+    collective_bytes_per_step,
+    program_scopes,
+    track as track_program,
 )
 from .fleet import (  # noqa: F401
     FleetCollector,

@@ -3,18 +3,18 @@
 The timeline half of the unified telemetry layer (registry.py holds the
 aggregates).  Three pieces:
 
-- :class:`TraceRecorder` — named spans around engine dispatches
-  (``decode_tick``, ``spec_tick``, ``prefill_pack``, ``train_batch``).  A
-  span that ends with a host-side result fetch records an exact duration.
-  A span in an async loop (the PR 1 ``train_data.async_metrics`` contract:
-  no per-step host read) ends with ``sync_obj=`` instead: the dispatch
-  wall time is recorded NOW, the device reading is deferred to ``flush()``
-  — which blocks once per window, attributes the window's device time
-  across its spans (the same window-average rationale as
-  ``ThroughputTimer``), and emits one aggregated ``<track>-device`` event
-  per flush.  Per-span device times are NOT recoverable post-hoc without
-  hardware events (T3, arXiv:2401.16677, tracks them in NIC hardware; in
-  software the window total is the honest quantity).
+- :class:`TraceRecorder` — a TREE of named spans: every span has an id and
+  the id of the span that was open on its thread when it started
+  (``sched.tick`` -> ``sched.decode`` -> ``engine.decode_build`` /
+  ``decode_tick`` / ``engine.decode_emit``).  A span that ends after a
+  host-side result fetch records an exact duration.  A span in an async
+  loop (the ``train_data.async_metrics`` contract: no per-step host read)
+  ends with ``sync_obj=`` instead: it is exported with ``"synced": false``
+  and its dispatch-side duration, and observes nothing into its histogram.
+  The recorder invents no device time: what a program took on the device
+  is in the profiler's trace (one ``XLA Modules`` event per execution), and
+  ``Telemetry.span()`` mirrors every span into a live capture under the
+  same id so the two clocks can be laid over each other.
 - :class:`RequestTrace` — the host-side lifecycle of one serve request:
   submit -> admit (queue wait) -> prefill chunks -> token emissions ->
   preemptions -> finish.  TTFT / per-token TBT / queue wait / accept rate
@@ -26,13 +26,14 @@ aggregates).  Three pieces:
   or chrome://tracing.  Events are strictly ordered per track.
 
 :class:`Telemetry` is the facade the engines hold: registry + recorder +
-request-trace bookkeeping + the optional ``jax.profiler``
-``StepTraceAnnotation`` hook, with every path collapsing to shared no-op
+request-trace bookkeeping + ``span()``, the one way to annotate (recorder
+span, plus a ``jax.profiler.TraceAnnotation`` of the same name and id when
+the ``jax_profiler`` knob is on), with every path collapsing to shared no-op
 singletons when disabled.
 """
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import threading
 import time
@@ -43,26 +44,37 @@ from .registry import MetricsRegistry, StatsView  # noqa: F401 (re-export)
 
 
 class Span:
-    """One recorded dispatch.  ``t_end`` is set by a host-synced ``end()``;
-    deferred spans carry ``sync_obj`` until the recorder's ``flush()``
-    resolves them (``t_ready`` + ``device_ms``)."""
+    """One recorded span.  ``id`` is unique in the recorder, ``parent`` the
+    id of the span open on this thread when it started (None at the root).
+    ``t_end`` is set by a host-synced ``end()``; a span ended with
+    ``sync_obj=`` keeps its dispatch-side duration only (``synced`` False).
+    Usable as a context manager (``Telemetry.span``): leaving the block
+    ends the span unless ``end()`` was called inside it."""
 
-    __slots__ = ("name", "track", "t0", "t_dispatch", "t_end", "t_ready",
-                 "device_ms", "args", "_sync", "_hist", "_rec")
+    __slots__ = ("name", "track", "id", "parent", "t0", "t_dispatch", "t_end",
+                 "synced", "args", "_sync", "_hist", "_rec", "_stack", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, track: str,
-                 hist, args: Dict[str, Any]):
+                 hist, args: Dict[str, Any], detached: bool = False):
         self._rec = rec
         self.name = name
         self.track = track
         self._hist = hist
         self.args = args
-        self.t0 = rec._clock()
+        self.id = next(rec._ids)
+        stack = rec._stack()
+        self.parent: Optional[int] = stack[-1] if stack else None
+        # a detached span (one that outlives the call that opened it: a
+        # shed episode, a router queue wait) is nobody's parent
+        self._stack = None if detached else stack
+        if not detached:
+            stack.append(self.id)
+        self._ann = None
         self.t_dispatch: Optional[float] = None
         self.t_end: Optional[float] = None
-        self.t_ready: Optional[float] = None
-        self.device_ms: Optional[float] = None
+        self.synced = True
         self._sync = None
+        self.t0 = rec._clock()
 
     def dispatched(self) -> None:
         """Mark the async dispatch call as returned (host work continues —
@@ -70,26 +82,50 @@ class Span:
         if self.t_dispatch is None:
             self.t_dispatch = self._rec._clock()
 
+    @property
+    def closed(self) -> bool:
+        return self._rec is None
+
     def end(self, sync_obj=None, **args) -> "Span":
-        """Close the span.  With ``sync_obj`` the host read is DEFERRED:
-        only the dispatch time is taken now; ``flush()`` blocks on the
-        object later.  Without it the span is host-complete and its
-        duration (and ``hist`` observation) is exact."""
-        now = self._rec._clock()
+        """Close the span.  Without ``sync_obj`` the span is host-complete
+        and its duration (and ``hist`` observation) is exact.  With it the
+        host did NOT wait for the device: the span keeps the dispatch-side
+        duration, is exported ``"synced": false`` and observes nothing;
+        ``flush()`` blocks on the object later so an export follows the
+        device."""
+        rec = self._rec
+        if rec is None:  # already ended
+            return self
+        now = rec._clock()
         if args:
             self.args.update(args)
+        if self.t_dispatch is None:
+            self.t_dispatch = now
         if sync_obj is not None:
-            if self.t_dispatch is None:
-                self.t_dispatch = now
+            self.synced = False
             self._sync = sync_obj
         else:
-            if self.t_dispatch is None:
-                self.t_dispatch = now
             self.t_end = now
             if self._hist is not None:
-                self._hist.observe((self.t_end - self.t0) * 1e3)
-        self._rec._append(self, pending=sync_obj is not None)
+                self._hist.observe((now - self.t0) * 1e3)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        stack = self._stack
+        if stack:
+            if stack[-1] == self.id:
+                stack.pop()
+            elif self.id in stack:  # ended out of order: drop it and its orphans
+                del stack[stack.index(self.id):]
+        self._rec = self._stack = None
+        rec._append(self)
         return self
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()  # a no-op where the block ended the span itself
 
     @property
     def duration_ms(self) -> Optional[float]:
@@ -102,12 +138,20 @@ class Span:
 
 class _NullSpan:
     __slots__ = ()
+    id = parent = None
+    closed = True
 
     def dispatched(self) -> None:
         pass
 
     def end(self, sync_obj=None, **args) -> "_NullSpan":
         return self
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
 
     duration_ms = None
 
@@ -116,7 +160,7 @@ NULL_SPAN = _NullSpan()
 
 
 class TraceRecorder:
-    """Bounded span store + deferred device-reading resolver."""
+    """Bounded store of the span tree."""
 
     def __init__(self, enabled: bool = True, max_spans: int = 65536,
                  clock=time.perf_counter):
@@ -124,59 +168,58 @@ class TraceRecorder:
         self._clock = clock
         self._lock = threading.Lock()
         self._spans: "deque[Span]" = deque(maxlen=max_spans)
-        self._pending: List[Span] = []
-        # synthetic per-flush device-window events for the chrome export
-        self._device_windows: "deque[Dict[str, Any]]" = deque(maxlen=4096)
-        self._last_ready: Dict[str, float] = {}
+        self._pending: List[Span] = []   # unsynced spans still holding a sync object
+        self._ids = itertools.count(1)
+        self._local = threading.local()  # per-thread stack of open span ids
         self.dropped = 0
-        # incremental-export watermarks (the fleet metrics_pull drains span
+        # incremental-export watermark (the fleet metrics_pull drains span
         # events in batches without disturbing the full chrome export) plus
         # a PERSISTENT track->tid map so tids stay stable across batches
         self._appended_total = 0
         self._drained_spans = 0
-        self._windows_total = 0
-        self._drained_windows = 0
         self._drain_tids: Dict[str, int] = {}
 
-    def start(self, name: str, track: str = "default", hist=None, **args):
+    def _stack(self) -> List[int]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def start(self, name: str, track: str = "default", hist=None,
+              detached: bool = False, **args):
+        """Open a span under the one open on this thread; ``end()`` closes
+        it.  ``detached`` spans get a parent but never become one."""
         if not self.enabled:
             return NULL_SPAN
-        return Span(self, name, track, hist, args)
+        return Span(self, name, track, hist, args, detached)
 
-    def _append(self, span: Span, pending: bool) -> None:
+    def _append(self, span: Span) -> None:
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
-                self.dropped += 1  # no silent cap: surfaced in chrome args
+                self.dropped += 1  # no silent cap: chrome_events() says so
             self._spans.append(span)
             self._appended_total += 1
-            if pending:
+            if not span.synced:
                 self._pending.append(span)
-                return
-            # A host-complete end on this track bounds every deferred span
-            # dispatched before it: the device stream is serialized, so the
-            # fetch that just returned implies those dispatches finished.
-            # Resolve them NOW with a tick-tight window ending at the
-            # bounding span's START — its own [t0, t_end] is already
-            # attributed to its own histogram, and waiting for the
-            # end-of-run flush would smear the whole run across them.
-            if self._pending and span.t_end is not None:
-                same = [sp for sp in self._pending if sp.track == span.track]
-                if same:
-                    self._pending = [sp for sp in self._pending
-                                     if sp.track != span.track]
-                    self._resolve_locked(same, span.t0)
+            elif self._pending:
+                # a host-complete end on this track bounds every unsynced
+                # span dispatched before it (the device stream is serial):
+                # their sync objects need no later wait, so let them go
+                keep = []
+                for sp in self._pending:
+                    if sp.track == span.track:
+                        sp._sync = None
+                    else:
+                        keep.append(sp)
+                self._pending = keep
 
     def __len__(self) -> int:
         return len(self._spans)
 
     def flush(self) -> None:
-        """Resolve every deferred device reading still pending: block once
-        on each sync object (dispatch order), then spread the window's
-        device time evenly across its spans — the per-span figure is a
-        window average, same contract as the engine's async
-        ``ThroughputTimer`` window.  Spans a later host-complete span
-        already bounded (see ``_append``) are resolved there and never
-        reach this path."""
+        """Block once on every sync object still held (dispatch order), so
+        that what is exported next follows the device, and let them go."""
         with self._lock:
             pending, self._pending = self._pending, []
         if not pending:
@@ -188,89 +231,55 @@ class TraceRecorder:
                 jax.block_until_ready(sp._sync)
         except Exception:  # backend torn down mid-exit; keep wall times
             pass
-        now = self._clock()
-        with self._lock:
-            self._resolve_locked(pending, now)
-
-    def _resolve_locked(self, pending: List[Span], now: float) -> None:
-        """Settle deferred spans (caller holds the lock): window time since
-        the track's last resolution spreads evenly across its spans, one
-        synthetic ``<track>-device`` window event per track."""
-        by_track: Dict[str, List[Span]] = {}
         for sp in pending:
-            by_track.setdefault(sp.track, []).append(sp)
-        for track, group in by_track.items():
-            start = max(group[0].t0, self._last_ready.get(track, group[0].t0))
-            total_ms = max(now - start, 0.0) * 1e3
-            per_ms = total_ms / len(group)
-            for sp in group:
-                sp.t_ready = now
-                sp.device_ms = per_ms
-                sp._sync = None
-                if sp._hist is not None:
-                    sp._hist.observe(per_ms)
-            self._last_ready[track] = now
-            self._windows_total += 1
-            self._device_windows.append({
-                "name": f"{group[0].name} window ({len(group)} dispatches)",
-                "track": f"{track}-device",
-                "t0": start,
-                "dur": total_ms / 1e3,
-                "args": {"dispatches": len(group),
-                         "per_dispatch_ms": round(per_ms, 3)},
-            })
+            sp._sync = None
 
     @staticmethod
     def _span_event(s: Span, pid: int, tid: int) -> Dict[str, Any]:
         dur = s.duration_ms
         args = dict(s.args)
+        args["span_id"] = s.id
+        if s.parent is not None:
+            args["parent_id"] = s.parent
         if s.t_dispatch is not None:
             args["dispatch_ms"] = round((s.t_dispatch - s.t0) * 1e3, 3)
-        if s.device_ms is not None:
-            args["device_window_avg_ms"] = round(s.device_ms, 3)
+        if not s.synced:
+            args["synced"] = False
         return {
             "name": s.name, "ph": "X", "pid": pid, "tid": tid,
             "ts": s.t0 * 1e6, "dur": (dur or 0.0) * 1e3, "args": args,
         }
 
     def chrome_events(self, pid: int = 0) -> List[Dict[str, Any]]:
+        """Every span kept, oldest first.  Where the ring has let older ones
+        go, the oldest kept carries their number as ``spans_dropped``: a
+        reader of whole-run medians or of the tree must refuse such a set."""
         with self._lock:
-            spans = list(self._spans)
-            windows = list(self._device_windows)
-        tracks = sorted({s.track for s in spans} | {w["track"] for w in windows})
-        tid_of = {t: i + 1 for i, t in enumerate(tracks)}
+            spans, dropped = list(self._spans), self.dropped
+        tid_of = {t: i + 1 for i, t in enumerate(sorted({s.track for s in spans}))}
         events: List[Dict[str, Any]] = []
         for t, tid in tid_of.items():
             events.append({"name": "thread_name", "ph": "M", "pid": pid,
                            "tid": tid, "args": {"name": t}})
         for s in spans:
             events.append(self._span_event(s, pid, tid_of[s.track]))
-        for w in windows:
-            events.append({
-                "name": w["name"], "ph": "X", "pid": pid,
-                "tid": tid_of[w["track"]], "ts": w["t0"] * 1e6,
-                "dur": w["dur"] * 1e6, "args": w["args"],
-            })
+        if dropped and spans:
+            events[len(tid_of)]["args"]["spans_dropped"] = dropped
         return events
 
     def drain_chrome_events(self, pid: int = 0) -> List[Dict[str, Any]]:
-        """Span/window events appended since the LAST drain — the
-        incremental batch a fleet ``metrics_pull`` returns.  Non-
-        destructive (the full :meth:`chrome_events` export is unchanged);
-        watermarks track how many events each consumer has seen, and the
-        track->tid map is persistent so tids stay stable across batches.
-        A still-deferred span exports its dispatch-side wall duration (the
-        device window resolves later as its own additive event).  No
-        device sync and no I/O happen here — pure state under the lock."""
+        """Span events appended since the LAST drain — the incremental
+        batch a fleet ``metrics_pull`` returns.  Non-destructive (the full
+        :meth:`chrome_events` export is unchanged); a watermark tracks how
+        many events the consumer has seen, and the track->tid map is
+        persistent so tids stay stable across batches.  No device sync and
+        no I/O happen here — pure state under the lock."""
         with self._lock:
             new_spans = self._appended_total - self._drained_spans
             spans = list(self._spans)[-new_spans:] if new_spans else []
             self._drained_spans = self._appended_total
-            new_w = self._windows_total - self._drained_windows
-            windows = list(self._device_windows)[-new_w:] if new_w else []
-            self._drained_windows = self._windows_total
             events: List[Dict[str, Any]] = []
-            for t in {s.track for s in spans} | {w["track"] for w in windows}:
+            for t in {s.track for s in spans}:
                 if t not in self._drain_tids:
                     self._drain_tids[t] = len(self._drain_tids) + 1
                     events.append({"name": "thread_name", "ph": "M",
@@ -278,12 +287,6 @@ class TraceRecorder:
                                    "args": {"name": t}})
             for s in spans:
                 events.append(self._span_event(s, pid, self._drain_tids[s.track]))
-            for w in windows:
-                events.append({
-                    "name": w["name"], "ph": "X", "pid": pid,
-                    "tid": self._drain_tids[w["track"]], "ts": w["t0"] * 1e6,
-                    "dur": w["dur"] * 1e6, "args": w["args"],
-                })
         return events
 
 
@@ -297,8 +300,8 @@ class RequestTrace:
     __slots__ = ("uid", "_tel", "_h", "prompt_tokens", "submit_ts",
                  "admit_ts", "first_token_ts", "last_emit_ts", "finish_ts",
                  "readmits", "preemptions", "tokens_emitted", "drafted",
-                 "accepted", "chunks", "emissions", "preempt_ts", "outcome",
-                 "ns")
+                 "accepted", "chunks", "chunk_ticks", "emissions",
+                 "emission_ticks", "preempt_ts", "outcome", "ns")
 
     def __init__(self, tel: "Telemetry", uid: int, prompt_tokens: int = 0,
                  hists: Optional[Dict[str, Any]] = None, ns: str = "serve"):
@@ -319,6 +322,11 @@ class RequestTrace:
         self.accepted = 0
         self.chunks: List[Tuple[float, float, int]] = []
         self.emissions: List[Tuple[float, int]] = []
+        # the scheduler tick that made each chunk / emission, index for
+        # index (None where the caller named none): a request's gaps name
+        # the ``sched.tick`` spans they fell into
+        self.chunk_ticks: List[Optional[int]] = []
+        self.emission_ticks: List[Optional[int]] = []
         self.preempt_ts: List[float] = []
         self.outcome: str = "finished"  # terminal state label (typed)
 
@@ -337,16 +345,19 @@ class RequestTrace:
         else:
             self.readmits += 1
 
-    def prefill_chunk(self, t0: float, t1: float, n_tokens: int) -> None:
+    def prefill_chunk(self, t0: float, t1: float, n_tokens: int,
+                      tick: Optional[int] = None) -> None:
         self.chunks.append((t0, t1, n_tokens))
+        self.chunk_ticks.append(tick)
 
-    def tokens(self, n: int) -> None:
+    def tokens(self, n: int, tick: Optional[int] = None) -> None:
         """``n`` tokens emitted for this request in one tick."""
         if n <= 0:
             return
         now = self._tel.clock()
         self.tokens_emitted += n
         self.emissions.append((now, n))
+        self.emission_ticks.append(tick)
         if self.first_token_ts is None:
             self.first_token_ts = now
             if self.submit_ts is not None:
@@ -442,14 +453,20 @@ class RequestTrace:
                         "ts": self.submit_ts * 1e6,
                         "dur": (self.admit_ts - self.submit_ts) * 1e6,
                         "args": {"prompt_tokens": self.prompt_tokens}})
-        for t0, t1, n in self.chunks:
+        def with_tick(args, tick):
+            if tick is not None:
+                args["tick"] = tick
+            return args
+
+        for (t0, t1, n), tick in zip(self.chunks, self.chunk_ticks):
             evs.append({"name": "prefill_chunk", "ph": "X", "pid": pid,
                         "tid": tid, "ts": t0 * 1e6, "dur": (t1 - t0) * 1e6,
-                        "args": {"tokens": n}})
-        for i, (t, n) in enumerate(self.emissions):
+                        "args": with_tick({"tokens": n}, tick)})
+        for i, ((t, n), tick) in enumerate(zip(self.emissions,
+                                               self.emission_ticks)):
             evs.append({"name": "first_token" if i == 0 else "emit",
                         "ph": "X", "pid": pid, "tid": tid, "ts": t * 1e6,
-                        "dur": 0.0, "args": {"tokens": n}})
+                        "dur": 0.0, "args": with_tick({"tokens": n}, tick)})
         for t in self.preempt_ts:
             evs.append({"name": "preempted", "ph": "X", "pid": pid,
                         "tid": tid, "ts": t * 1e6, "dur": 0.0, "args": {}})
@@ -483,10 +500,10 @@ class _NullRequestTrace:
     def admitted(self) -> None:
         pass
 
-    def prefill_chunk(self, t0, t1, n_tokens) -> None:
+    def prefill_chunk(self, t0, t1, n_tokens, tick=None) -> None:
         pass
 
-    def tokens(self, n) -> None:
+    def tokens(self, n, tick=None) -> None:
         pass
 
     def preempted(self) -> None:
@@ -558,6 +575,11 @@ class Telemetry:
         self.jsonl_path = knob(jsonl_path, "jsonl_path", None)
         self.chrome_trace_path = knob(chrome_trace_path, "chrome_trace_path", None)
         self.jax_profiler = bool(knob(jax_profiler, "jax_profiler", False))
+        self._annotate = None  # the profiler-side mirror of span()
+        if self.enabled and self.jax_profiler:
+            import jax
+
+            self._annotate = jax.profiler.TraceAnnotation
         self.clock = clock
         self.registry = MetricsRegistry(
             enabled=self.enabled, jsonl_path=self.jsonl_path,
@@ -677,16 +699,28 @@ class Telemetry:
         with self._lock:
             return list(self._traces)
 
-    # -- jax profiler hook --------------------------------------------------
-    def step_annotation(self, name: str, step: int):
-        """``jax.profiler.StepTraceAnnotation`` context when the knob is on
-        (visible in a live ``jax.profiler.trace`` capture); nullcontext
-        otherwise."""
-        if not (self.enabled and self.jax_profiler):
-            return contextlib.nullcontext()
-        import jax
-
-        return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    # -- spans --------------------------------------------------------------
+    def span(self, name: str, track: str = "default", hist=None, **args):
+        """THE way to annotate a layer boundary: ``with tel.span("x"): ...``
+        opens a recorder span under the one open on this thread and ends it
+        on the way out (or earlier, at an explicit ``end()`` inside the
+        block).  With the ``jax_profiler`` knob on, the same call also opens
+        a ``jax.profiler.TraceAnnotation`` of that name carrying
+        ``span_id`` and the scalar args, so in a live ``jax.profiler``
+        capture every program span exists a second time on the trace's
+        clock, next to the device's events, under the same id.  Disabled
+        telemetry hands out the shared ``NULL_SPAN``."""
+        if not self.enabled:
+            return NULL_SPAN
+        sp = self.recorder.start(name, track=track, hist=hist, **args)
+        if self._annotate is not None:
+            ann = self._annotate(
+                name, span_id=sp.id,
+                **{k: v for k, v in args.items()
+                   if isinstance(v, (bool, int, float, str))})
+            ann.__enter__()
+            sp._ann = ann
+        return sp
 
     # -- export -------------------------------------------------------------
     def flush(self) -> None:

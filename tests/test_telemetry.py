@@ -6,6 +6,7 @@ train-engine span/snapshot wiring, monitor-writer coverage (CSV append
 semantics, Comet throttling, wandb step-grouped logging), the timer
 ``reset``/``last`` regression, and the tier-1 marker-hygiene audit."""
 import json
+import re
 import sys
 import threading
 import types
@@ -201,10 +202,10 @@ def test_claim_prefix_second_engine_does_not_alias(tiny):
 
 def test_chunked_prefill_spans_defer_and_resolve_tick_tight(tiny):
     """An intermediate prefill chunk completes no prompt, so nothing is
-    fetched host-side: its span takes the deferred (sync_obj) path, and the
-    NEXT host-complete span on the track resolves it with a tick-tight
-    window — NOT the end-of-run flush (which would smear the whole run
-    across it)."""
+    fetched host-side: its span closes at dispatch.  It is exported with
+    ``"synced": false`` and its dispatch-side duration, and observes nothing
+    into the pack histogram: the recorder invents no device time (a pack's
+    device time is the profiler trace's)."""
     cfg, params = tiny
     eng = InferenceEngineV2(
         params, cfg, max_seqs=2, num_blocks=16, block_size=8,
@@ -215,17 +216,25 @@ def test_chunked_prefill_spans_defer_and_resolve_tick_tight(tiny):
         temperature=0.0, max_new_tokens=4))
     sched.run()
     assert eng.stats["prefill_dispatches"] >= 2  # 20 tokens / 8-chunk
-    # all packs already observed, WITHOUT any explicit flush: the later
-    # host-synced ticks bounded the deferred chunks as the run progressed
-    h = eng.telemetry.registry.get("serve/prefill_pack_ms")
-    assert h.count == eng.stats["prefill_dispatches"]
-    # tick-tight: a deferred chunk's window is bounded by its neighboring
-    # ticks, nowhere near the full run's duration
-    run_ms = sum(t.e2e_ms for t in eng.telemetry.finished_traces)
-    assert h.max < max(run_ms / 2, 1.0), (h.max, run_ms)
     evs = eng.telemetry.chrome_trace()["traceEvents"]
-    # the deferred chunk resolved into a serve-device window event
-    assert any(e["ph"] == "X" and "window" in e["name"] for e in evs)
+    packs = [e for e in evs if e["ph"] == "X" and e["name"] == "prefill_pack"]
+    assert len(packs) == eng.stats["prefill_dispatches"]
+    unsynced = [e for e in packs if e["args"].get("synced") is False]
+    synced = [e for e in packs if "synced" not in e["args"]]
+    # only the chunk that finished the prompt fetched its sampled token
+    assert len(synced) == 1 and len(unsynced) == len(packs) - 1
+    for e in unsynced:
+        # dispatch-side duration: the span ended when the dispatch returned
+        assert e["dur"] == pytest.approx(e["args"]["dispatch_ms"] * 1e3, abs=1.0)
+    # the histogram holds synced packs only
+    h = eng.telemetry.registry.get("serve/prefill_pack_ms")
+    assert h.count == len(synced)
+    # no invented device events, no extra track
+    assert not any(e["ph"] == "X" and "window" in e["name"] for e in evs)
+    assert not any(e["ph"] == "M" and e["args"]["name"].endswith("-device")
+                   for e in evs)
+    # every pack names the requests in it
+    assert all(e["args"]["uids"] == [1] for e in packs)
 
 
 def test_stats_view_mapping_semantics():
@@ -298,7 +307,7 @@ def test_chrome_trace_schema_and_ordering():
     rec = tel.recorder
     for i in range(3):
         rec.start("tick", track="serve", i=i).end()
-    # deferred device reading: ends with a sync object, resolves at flush
+    # a span that ends with a sync object is exported unsynced
     x = jnp.zeros((4,))
     rec.start("train_batch", track="train").end(sync_obj=x)
     rec.start("train_batch", track="train").end(sync_obj=x)
@@ -323,11 +332,19 @@ def test_chrome_trace_schema_and_ordering():
         by_track.setdefault((e["pid"], e["tid"]), []).append(e["ts"])
     for key, ts in by_track.items():
         assert all(b > a for a, b in zip(ts, ts[1:])), key
-    # the deferred train spans resolved and produced a device-window event
-    names = {e["name"] for e in xs}
-    assert any("window" in n for n in names)
+    # the unsynced train spans carry the flag and no invented device time
+    trains = [e for e in xs if e["name"] == "train_batch"]
+    assert len(trains) == 2
+    assert all(e["args"]["synced"] is False for e in trains)
+    assert all("device_window_avg_ms" not in e["args"] for e in xs)
+    assert all("synced" not in e["args"] for e in xs if e["name"] == "tick")
+    assert not any("window" in e["name"] for e in xs)
     track_names = {e["args"]["name"] for e in evs if e["ph"] == "M"}
-    assert {"serve", "train", "train-device"} <= track_names
+    assert {"serve", "train"} <= track_names
+    assert not any(t.endswith("-device") for t in track_names)
+    # every span event carries its id; flush() let the sync objects go
+    assert len({e["args"]["span_id"] for e in xs if e["pid"] == 0}) == 5
+    assert not rec._pending
     assert any(e["pid"] == 1 and e["name"] == "queued" for e in xs)
 
 
@@ -393,7 +410,8 @@ def test_telemetry_disabled_twin_has_identical_stats(serve_pair):
 # ---------------------------------------------------------------------------
 # train engine wiring: spans, deferred flush, registry -> monitor fan-out
 # ---------------------------------------------------------------------------
-def test_train_engine_telemetry_spans_and_monitor_fanout():
+@pytest.mark.parametrize("async_metrics", [True, False])
+def test_train_engine_telemetry_spans_and_monitor_fanout(async_metrics):
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import CausalLM
 
@@ -407,12 +425,14 @@ def test_train_engine_telemetry_spans_and_monitor_fanout():
             "bf16": {"enabled": True},
             "steps_per_print": 2,
             "telemetry": {"enabled": True},
+            "train_data": {"async_metrics": async_metrics},
         },
     )
     captured = []
     engine.monitor = types.SimpleNamespace(
         enabled=True, write_events=captured.extend
     )
+    engine.telemetry.registry.counter("user/events").inc(3)
     rng = np.random.default_rng(0)
     # global batch = micro(1) x dp(8 virtual devices)
     dp = engine.config.dp_world_size
@@ -421,11 +441,18 @@ def test_train_engine_telemetry_spans_and_monitor_fanout():
         engine.train_batch(batch)
     engine.get_last_loss()
     assert len(engine.telemetry.recorder) == 4  # one span per step
-    h = engine.telemetry.registry.get("train/step_ms")
-    assert h.count == 4 and h.percentile(50) > 0
+    steps = [e for e in engine.telemetry.recorder.chrome_events()
+             if e["ph"] == "X"]
+    assert [e["name"] for e in steps] == ["train_batch"] * 4
+    assert [e["args"]["step"] for e in steps] == [1, 2, 3, 4]
+    # one path in both metric modes: the span adds no host read to the step,
+    # closes at dispatch and is exported unsynced; no step latency is invented
+    assert all(e["args"]["synced"] is False for e in steps)
+    assert engine.telemetry.registry.get("train/step_ms") is None
+    assert engine.telemetry.recorder._pending == []  # the flush let the losses go
     labels = {label for label, _, _ in captured}
     assert "Train/Samples/train_loss" in labels  # legacy rows intact
-    assert "train/step_ms/p50" in labels  # registry snapshot rode along
+    assert "user/events" in labels  # registry snapshot rode along
 
 
 # ---------------------------------------------------------------------------
@@ -731,3 +758,313 @@ def test_marker_hygiene_superset_rule():
     conftest.pytest_collection_modifyitems(None, items)
     assert "slow" in items[0].marks and "slow" in items[1].marks
     assert "slow" not in items[2].marks
+
+
+# ---------------------------------------------------------------------------
+# the span tree: ids, parents, self time, the profiler-side mirror
+# ---------------------------------------------------------------------------
+def _span_events(tel):
+    return [e for e in tel.recorder.chrome_events() if e["ph"] == "X"]
+
+
+def _tree(events):
+    by_id = {e["args"]["span_id"]: e for e in events}
+
+    def ancestors(e):
+        p = e["args"].get("parent_id")
+        while p is not None:
+            yield by_id[p]
+            p = by_id[p]["args"].get("parent_id")
+
+    return by_id, ancestors
+
+
+@pytest.fixture(scope="module")
+def plain_serve(tiny):
+    """A plain (no speculation) chunked-prefill run with telemetry on."""
+    cfg, params = tiny
+    eng = InferenceEngineV2(
+        params, cfg, max_seqs=3, num_blocks=32, block_size=8,
+        prefill_buckets=(16, 32), prefill_chunk=16, telemetry=True,
+    )
+    sched = eng.scheduler
+    samp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    for u in (1, 2):
+        sched.submit(u, list(range(u, u + 40)), samp)
+    sched.run()
+    return eng, sched
+
+
+def test_every_decode_tick_hangs_under_sched_decode_under_sched_tick(plain_serve):
+    eng, _ = plain_serve
+    events = _span_events(eng.telemetry)
+    by_id, ancestors = _tree(events)
+    assert len(by_id) == len(events)  # ids are unique
+    ticks = [e for e in events if e["name"] == "decode_tick"]
+    assert len(ticks) == eng.stats["decode_ticks"] > 0
+    for e in ticks:
+        names = [a["name"] for a in ancestors(e)]
+        assert names == ["sched.decode", "sched.tick"], names
+        assert e["args"]["ctx_tokens"] >= e["args"]["batch"] >= 1
+    for name, parent in (("engine.decode_build", "sched.decode"),
+                         ("engine.decode_emit", "sched.decode"),
+                         ("engine.pack_build", "sched.prefill"),
+                         ("prefill_pack", "sched.prefill"),
+                         ("engine.pack_emit", "sched.prefill"),
+                         ("sched.expire", "sched.tick"),
+                         ("sched.admit", "sched.tick")):
+        found = [e for e in events if e["name"] == name]
+        assert found, name
+        assert all(next(ancestors(e))["name"] == parent for e in found), name
+    roots = [e for e in events if "parent_id" not in e["args"]]
+    assert {e["name"] for e in roots} == {"sched.tick"}
+    assert [e["args"]["tick"] for e in roots] == list(range(1, len(roots) + 1))
+
+
+def test_children_lie_inside_parents_and_self_time_is_not_negative(plain_serve):
+    eng, _ = plain_serve
+    events = _span_events(eng.telemetry)
+    by_id, _ = _tree(events)
+    covered = {}
+    for e in events:
+        p = e["args"].get("parent_id")
+        if p is None:
+            continue
+        parent = by_id[p]
+        assert parent["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1e-3  # us
+        covered[p] = covered.get(p, 0.0) + e["dur"]
+    for e in events:
+        if e["name"] == "sched.tick":
+            assert e["dur"] - covered.get(e["args"]["span_id"], 0.0) >= -1e-3
+    # build -> dispatch -> emit follow one another inside one decode phase
+    for d in (e for e in events if e["name"] == "sched.decode"):
+        kids = sorted((e for e in events
+                       if e["args"].get("parent_id") == d["args"]["span_id"]),
+                      key=lambda e: e["ts"])
+        if kids:
+            assert [k["name"] for k in kids] == [
+                "engine.decode_build", "decode_tick", "engine.decode_emit"]
+            assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3
+                       for a, b in zip(kids, kids[1:]))
+
+
+def test_request_traces_name_the_tick_of_every_chunk_and_token(plain_serve):
+    eng, sched = plain_serve
+    ticks = {e["args"]["tick"]: e for e in _span_events(eng.telemetry)
+             if e["name"] == "sched.tick"}
+    for tr in eng.telemetry.finished_traces:
+        assert all(len(c) == 3 for c in tr.chunks)  # the shape readers rely on
+        assert len(tr.chunk_ticks) == len(tr.chunks) >= 2  # 40 tokens / 16
+        assert len(tr.emission_ticks) == len(tr.emissions) == 6
+        assert tr.emission_ticks == sorted(tr.emission_ticks)
+        for (t, _), tick in zip(tr.emissions, tr.emission_ticks):
+            span = ticks[tick]  # the emission fell inside the tick it names
+            assert span["ts"] <= t * 1e6 <= span["ts"] + span["dur"]
+        chrome = [e for e in tr.chrome_events() if e["name"] == "prefill_chunk"]
+        assert [e["args"]["tick"] for e in chrome] == tr.chunk_ticks
+
+
+class _FakeAnnotation:
+    log = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name, self.kw))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name, self.kw))
+
+
+def test_span_mirrors_into_the_profiler_only_with_the_knob_on():
+    off = Telemetry(enabled=True)
+    assert off._annotate is None
+    with off.span("x", track="t", n=1) as sp:
+        assert sp._ann is None
+    on = Telemetry(enabled=True, jax_profiler=True)
+    assert on._annotate is jax.profiler.TraceAnnotation
+    on._annotate, _FakeAnnotation.log = _FakeAnnotation, []
+    with on.span("outer", track="t", tick=3, uids=[1, 2], ctx=True) as outer:
+        with on.span("inner", track="t") as inner:
+            pass
+    log = _FakeAnnotation.log
+    assert [(a, n) for a, n, _ in log] == [
+        ("enter", "outer"), ("enter", "inner"), ("exit", "inner"), ("exit", "outer")]
+    # the mirror carries the recorder's id and the scalar args, no lists
+    assert log[0][2] == {"span_id": outer.id, "tick": 3, "ctx": True}
+    assert log[1][2] == {"span_id": inner.id}
+    assert inner.parent == outer.id and outer.parent is None
+    # an explicit end() inside the block closes the mirror there, once
+    _FakeAnnotation.log = []
+    with on.span("pack") as sp:
+        sp.end(sync_obj=jnp.zeros(()))
+        assert [a for a, _, _ in _FakeAnnotation.log] == ["enter", "exit"]
+    assert len(_FakeAnnotation.log) == 2 and sp.closed and not sp.synced
+    # disabled telemetry never touches the profiler
+    assert Telemetry(enabled=False, jax_profiler=True)._annotate is None
+
+
+def test_disabled_telemetry_hands_out_the_null_span():
+    from deepspeed_tpu.telemetry import NULL_SPAN
+
+    tel = Telemetry(enabled=False)
+    assert tel.span("x", track="t", hist=None, n=1) is NULL_SPAN
+    with tel.span("x") as sp:
+        sp.dispatched()
+        assert sp.end(sync_obj=object()) is NULL_SPAN
+    assert sp.duration_ms is None and len(tel.recorder) == 0
+    assert not hasattr(tel, "step_annotation")  # one way to annotate, not two
+
+
+def test_detached_and_out_of_order_spans_do_not_bend_the_tree():
+    tel = Telemetry(enabled=True)
+    rec = tel.recorder
+    with tel.span("tick") as tick:
+        shed = rec.start("shed_mode", detached=True)  # outlives the tick
+        with tel.span("phase") as phase:
+            pass
+    assert shed.parent == tick.id and phase.parent == tick.id
+    with tel.span("tick2") as tick2:
+        pass
+    assert tick2.parent is None  # the open detached span is nobody's parent
+    shed.end()
+    a = rec.start("a")
+    b = rec.start("b")
+    a.end()  # out of order: b was still open under a
+    assert rec.start("c").parent is None
+    b.end()
+    b.end()  # a second end() records nothing
+    assert [s.name for s in rec._spans].count("b") == 1
+    # two threads keep two stacks
+    seen = {}
+
+    def other():
+        with tel.span("other") as sp:
+            seen["parent"] = sp.parent
+
+    with tel.span("main"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    assert seen["parent"] is None
+
+
+def test_a_full_ring_says_how_many_spans_it_let_go():
+    tel = Telemetry(enabled=True, max_spans=4)
+    for i in range(4):
+        with tel.span("s", i=i):
+            pass
+    events = [e for e in tel.recorder.chrome_events() if e["ph"] == "X"]
+    assert len(events) == 4 and all("spans_dropped" not in e["args"] for e in events)
+    for i in range(4, 7):
+        with tel.span("s", i=i):
+            pass
+    events = [e for e in tel.recorder.chrome_events() if e["ph"] == "X"]
+    assert [e["args"]["i"] for e in events] == [3, 4, 5, 6]
+    # the oldest span kept carries the count; the export's metadata agrees
+    assert events[0]["args"]["spans_dropped"] == 3 == tel.recorder.dropped
+    assert all("spans_dropped" not in e["args"] for e in events[1:])
+    assert tel.chrome_trace()["metadata"]["spans_dropped"] == 3
+
+
+# ---------------------------------------------------------------------------
+# program_scopes / collective_bytes_per_step: lazy, from the compiled text
+# ---------------------------------------------------------------------------
+def test_program_scopes_names_optimizer_and_loss_and_computes_nothing_until_asked():
+    import deepspeed_tpu as ds
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.models import CausalLM
+    from jax import monitoring
+
+    cfg = get_preset("tiny", max_seq_len=32)
+    engine, _, _, _ = ds.initialize(
+        model=CausalLM(cfg),
+        config={
+            "train_micro_batch_size_per_gpu": 1,
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-4}},
+            "zero_optimization": {"stage": 3, "param_persistence_threshold": 0},
+            "bf16": {"enabled": True},
+            "steps_per_print": 1000,
+        },
+    )
+    assert not engine.telemetry.enabled  # tracking does not wait for telemetry
+    dp = engine.config.dp_world_size
+    rng = np.random.default_rng(0)
+    batch = {"input_ids": rng.integers(0, cfg.vocab_size, (dp, 33), dtype=np.int64)}
+    engine.train_batch(batch)
+    compiles = []
+    monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(event)
+        if "backend_compile" in event or "jaxpr_to_mlir" in event else None)
+    for _ in range(2):
+        engine.train_batch(batch)
+    jax.block_until_ready(engine.state.params)
+    step = engine._step_program
+    assert len(step.signatures) == 1  # one compiled signature, shapes only
+    assert not any(isinstance(leaf, jax.Array)
+                   for leaf in jax.tree_util.tree_leaves(step.signatures))
+    assert compiles == []  # nothing lowered or compiled by tracking itself
+    scopes = telemetry.program_scopes()["jit_train_step"]
+    assert compiles == []  # asking hits the caches of the step that ran
+    names = set(scopes.values())
+    assert any("/optimizer/" in n for n in names)
+    assert any("jvp(loss)" in n for n in names)
+    assert any("transpose(jvp(loss))" in n for n in names)
+    assert any(n.endswith("/attn/dot_general") and "transpose(" in n for n in names)
+    assert any("zero/gather" in n for n in names)
+    # every mapped instruction is named as the trace names it (base.number)
+    assert all(re.match(r"^[\w\-]+(\.\d+)*$", k) for k in scopes)
+    # the ZeRO-3 step gathers parameters and scatters gradients over 8 devices
+    moved = telemetry.collective_bytes_per_step()["jit_train_step"]
+    n_params = sum(int(np.prod(p.shape))
+                   for p in jax.tree_util.tree_leaves(engine.state.params))
+    assert moved >= 2 * n_params  # at least one bf16 gather of every parameter
+
+
+def test_collective_bytes_counts_loops_by_trip_count_and_async_pairs_once():
+    from deepspeed_tpu.telemetry.programs import collective_bytes
+
+    text = """HloModule jit_step, is_scheduled=true
+
+%body (p: (s32[], f32[8,4])) -> (s32[], f32[8,4]) {
+  %p = (s32[], f32[8,4]{1,0}) parameter(0)
+  %x = f32[8,4]{1,0} get-tuple-element(%p), index=1
+  %ag = f32[32,4]{1,0:T(8,128)} all-gather(%x), dimensions={0}
+  %rs-start = ((f32[32,4]{1,0}), f32[8,4]{1,0}) reduce-scatter-start(%ag)
+  %rs = f32[8,4]{1,0} reduce-scatter-done(%rs-start)
+  ROOT %t = (s32[], f32[8,4]{1,0}) tuple(%i, %rs)
+}
+
+%cond (p: (s32[], f32[8,4])) -> pred[] {
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+ENTRY %main (a: f32[8,4]) -> f32[8,4] {
+  %a = f32[8,4]{1,0} parameter(0)
+  %w = (s32[], f32[8,4]{1,0}) while(%init), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"3"}}
+  %ar = (bf16[16]{0}, bf16[2,2]{1,0}) all-reduce(%b, %c), to_apply=%add
+  ROOT %r = f32[8,4]{1,0} get-tuple-element(%w), index=1
+}
+"""
+    per_trip = 32 * 4 * 4 + 8 * 4 * 4      # the gather's result, the scatter's result
+    assert collective_bytes(text) == 3 * per_trip + (16 + 4) * 2
+    assert collective_bytes("HloModule empty\n") == 0
+    # the TPU writes no known_trip_count: the bound is the constant the loop's
+    # condition compares its counter with
+    tpu = text.replace(', backend_config={"known_trip_count":{"n":"3"}}', "").replace(
+        "  ROOT %lt = pred[] compare(%i, %n), direction=LT",
+        "  %n = s32[]{:T(128)} constant(5)\n"
+        "  ROOT %lt = pred[]{:T(512)} compare(%i, %n), direction=LT")
+    assert "known_trip_count" not in tpu
+    assert collective_bytes(tpu) == 5 * per_trip + (16 + 4) * 2
+    # a loop with collectives in its body and no trip count to be found is
+    # not counted once (a scanned layer stack would read 1/depth): None
+    uncounted = tpu.replace("direction=LT", "direction=NE")
+    assert collective_bytes(uncounted) is None
+    # the same loop with nothing to count in its body hides nothing
+    quiet = re.sub(r"\n  %(ag|rs-start|rs) = [^\n]*", "", uncounted)
+    assert "all-gather" not in quiet and "while(" in quiet
+    assert collective_bytes(quiet) == (16 + 4) * 2
