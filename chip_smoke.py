@@ -74,7 +74,7 @@ class Sizes:
 #  serve 16/32 layers: 7.5 GB of bf16 weights, which leaves > 6 GB for the KV
 #    pool (the pool below, 2 GiB, is sized for the traffic, not to fill the
 #    chip).  The runner unrolls layers in Python, so XLA's compile time grows
-#    with depth; the Mosaic compile of the packed-ctx kernel (~55 s a shape)
+#    with depth; the Mosaic compile of the packed-ctx kernel (~6-10 s a shape)
 #    does not, identical kernel instances compile once.
 FULL = Sizes(
     preset="mistral_7b", overrides={}, train_layers=2, serve_layers=16,

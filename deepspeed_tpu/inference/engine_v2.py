@@ -964,11 +964,13 @@ class InferenceEngineV2:
             triple = (sampling.temperature, sampling.top_k, sampling.top_p)
             n_real = sum(end - start for _, start, end in entries)
             n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
+            # live context pages the ctx kernel walks: its time over this
+            ctx_pages = int((-(-ctx_lens // bs)).sum())
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
             tokens=n_real, pad=t_pad, entries=len(entries), ctx=use_ctx,
-            uids=[s.uid for s, _, _ in entries],
+            ctx_pages=ctx_pages, uids=[s.uid for s, _, _ in entries],
         ) as sp:
             if use_ctx:
                 sampled, self.kv = self._packed_prefill_ctx_jit(

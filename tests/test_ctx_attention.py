@@ -10,7 +10,9 @@ contract (``include_pack`` charge-to-shard-0, log-sum-exp ring merge),
 the ``ServingContext.fused`` dispatch gate, greedy token identity through
 the full engine on tp/dp/seq-shard meshes, and the compiled
 memory-analysis proof that pack temporaries no longer scale with the
-block-table width (the dense body's O(T * P * bs) gather).
+block-table width (the dense body's O(T * P * bs) gather); since PR 25 the
+cases the kernel's own loops can get wrong (key-tile and row-tile edges,
+empty slots, off-shard table rows) and its step count.
 """
 import jax
 import jax.numpy as jnp
@@ -90,6 +92,120 @@ def test_kernel_parity_vs_dense(hq, hkv, hd, cap):
     valid = np.asarray(seg) > 0
     np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
                                atol=2e-5, err_msg=f"{hq}/{hkv}/{hd} cap={cap}")
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles the toy shapes overflow: key tile K*bs = 16 keys (K = 2 pages
+    of 8), row tile 16 rows — so the dynamic loops take several trips."""
+    monkeypatch.setattr(ck, "_KEY_TILE", 16)
+    monkeypatch.setattr(ck, "_ROW_TILE_M", 64)  # g = 4 -> tq = 16
+
+
+# what the loop structure can get wrong, each against the dense body:
+# (pack_len, ctx_len) per slot row; (0, 0) is an empty slot
+_LOOP_CASES = {
+    # context lengths around the key tile's edge (K*bs = 16): 1, bs-1,
+    # K*bs-1, K*bs, K*bs+1 keys, and two tiles and a bit
+    "ctx_tile_edges": [(4, 1), (4, 7), (4, 15), (4, 16), (4, 17), (4, 37)],
+    # 8 page-aligned segments, contexts all different, between empty slots
+    # at both ends of the table (the engine's chat pack)
+    "eight_aligned_between_empty_slots":
+        [(0, 0), (0, 0)] + [(8 * (1 + i % 2), 5 + 11 * i) for i in range(8)]
+        + [(0, 0), (0, 0), (0, 0)],
+    # a verify pack: 1 + n_draft rows a slot, every start mid row-tile, the
+    # last row tile clamped at the pack's end
+    "verify_starts_mid_row_tile": [(5, 13 + 9 * i) for i in range(7)],
+    # one segment over several row tiles: the causal stage crosses tiles
+    "one_segment_three_row_tiles": [(40, 37)],
+    # ctx_len 0 beside a full table row (ctx_len = P * bs) in one pack
+    "cold_beside_full_table": [(6, 0), (10, 64), (3, 0)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LOOP_CASES))
+def test_loop_structure_parity(case, small_tiles):
+    segs = _LOOP_CASES[case]
+    args = _setup(segs, hq=8, hkv=2, hd=16, nb=64, pad=3)
+    if case == "cold_beside_full_table":
+        assert args[6].shape[1] * 8 == 64  # the row is full: ctx_len = P * bs
+    out = ck.paged_attention_packed_ctx_kernel(*args)
+    ref = _paged_attention_packed_ctx_dense(*args)
+    valid = np.asarray(args[3]) > 0
+    np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
+                               atol=2e-5, err_msg=case)
+    assert (np.asarray(out)[~valid] == 0.0).all()
+
+
+def test_partial_row_of_out_of_range_ids(small_tiles):
+    """Under striping a slot's table row may hold only another shard's pages
+    (ids >= nb, or < 0 after the local translation): nothing is fetched for
+    it, its rows keep the pack's keys alone (or nothing, off shard 0), and
+    every slot's partial is what the jnp partial gives."""
+    q, k, v, seg, ckl, cvl, tb, ln = _setup(
+        [(6, 21), (5, 30), (4, 9)], hq=8, hkv=2, hd=16, nb=32, pad=1)
+    tb = np.asarray(tb).copy()
+    tb[1] = np.where(tb[1] >= 0, tb[1] + 32, -1)   # all of slot 1: off-shard
+    tb[2, 0] = -5                                  # slot 2: page 0 off-shard
+    tb = jnp.asarray(tb)
+    for inc in (True, False):
+        got = ck.paged_attention_packed_ctx_kernel(
+            q, k, v, seg, ckl, cvl, tb, ln, include_pack=jnp.asarray(inc),
+            partial=True)
+        want = _packed_ctx_partial(q, k, v, seg, ckl, cvl, tb, ln,
+                                   jnp.asarray(inc))
+        vrows = np.asarray(seg) > 0
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g)[vrows],
+                                       np.asarray(w)[vrows], atol=2e-4,
+                                       err_msg=f"include_pack={inc}")
+
+
+def _pallas_grids(jaxpr):
+    """Grids of every pallas_call under ``jaxpr``, nested jits included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_grids(sub)
+    return found
+
+
+def test_step_count_follows_the_pack_not_the_table():
+    """At the benchmark's shape (64 slots x 128 table pages, a 256-row pack)
+    the call has at most N grid steps: the table's page dimension is walked
+    by the loop inside the kernel, for live pages only."""
+    n, p, t = 64, 128, 256
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    args = (sds((t, 32, 128), bf), sds((t, 8, 128), bf), sds((t, 8, 128), bf),
+            sds((t,), jnp.int32), sds((2304, 32, 8, 128), bf),
+            sds((2304, 32, 8, 128), bf), sds((n, p), jnp.int32),
+            sds((n,), jnp.int32))
+    grids = _pallas_grids(
+        jax.make_jaxpr(ck.paged_attention_packed_ctx_kernel)(*args).jaxpr)
+    assert len(grids) == 1, grids
+    steps = int(np.prod(grids[0], dtype=np.int64))
+    assert steps <= n, grids
+
+
+def test_supports_takes_the_benchmark_shape_on_hardware():
+    """Pure shape arithmetic with the interpreter off: the one-chip serving
+    shape (T = 256 at Mistral-7B widths) and the TP = 4 shard's (T = 512 at a
+    quarter of the heads) stay on the kernel; only hd % 128 and VMEM decline."""
+    ck.set_interpret(False)
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    tables = sds((64, 128), jnp.int32)
+    assert ck.supports(sds((256, 32, 128), bf), sds((2304, 32, 8, 128), bf),
+                       tables)
+    assert ck.supports(sds((512, 8, 128), bf), sds((2304, 32, 2, 128), bf),
+                       tables)
+    assert not ck.supports(sds((256, 32, 64), bf), sds((2304, 32, 8, 64), bf),
+                           tables)
+    assert not ck.supports(sds((4096, 32, 128), bf),
+                           sds((2304, 32, 8, 128), bf), tables)
 
 
 def test_mid_page_verify_starts():
@@ -339,10 +455,11 @@ def test_engine_token_identity_kernel_vs_dense(tiny_model, tp, monkeypatch):
 # ---------------------------------------------------------------------------
 @pytest.mark.nightly  # compile-only, but heavy enough for the nightly lane
 def test_memory_analysis_pack_temps_bounded():
-    """The compiler's own accounting: widen the block table 12x (P=4 ->
-    P=48, the dense gather's O(T * P * bs) axis) and the dense program's
+    """The compiler's own accounting: widen the block table 12x (P=32 ->
+    P=384, the dense gather's O(T * P * bs) axis) and the dense program's
     temporaries must grow several-fold while the kernel program's stay
-    flat — its working set is one [T_pad, *] VMEM tile per grid step.
+    flat — its working set is one key tile of at most ``_KEY_TILE`` keys
+    (32 pages of 16 here, so both tables are past it) and one row tile.
     Traced ctx_lens keep the dense clamp out of the comparison."""
     t, hq, hkv, hd, nb, bs, n = 64, 8, 2, 64, 64, 16, 4
     sds = jax.ShapeDtypeStruct
@@ -357,11 +474,11 @@ def test_memory_analysis_pack_temps_bounded():
     dfn = jax.jit(_paged_attention_packed_ctx_dense)
     mem = {}
     for name, fn in (("kernel", kfn), ("dense", dfn)):
-        for p in (4, 48):
+        for p in (32, 384):
             m = fn.lower(*args(p)).compile().memory_analysis()
             if m is None:
                 pytest.skip("backend exposes no memory_analysis")
             mem[name, p] = m.temp_size_in_bytes
-    assert mem["dense", 48] > 3 * mem["dense", 4], mem
-    assert mem["kernel", 48] < 2 * mem["kernel", 4] + (1 << 20), mem
-    assert mem["kernel", 48] < mem["dense", 48] / 2, mem
+    assert mem["dense", 384] > 3 * mem["dense", 32], mem
+    assert mem["kernel", 384] < 2 * mem["kernel", 32] + (1 << 20), mem
+    assert mem["kernel", 384] < mem["dense", 384] / 2, mem

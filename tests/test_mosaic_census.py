@@ -9,7 +9,7 @@ This is a PRE-FLIGHT for a chip run (it catches API drift, layout refusals and
 VMEM overflows in seconds instead of chip-minutes) — it is never evidence that
 a kernel runs or computes the right numbers; ``chip_smoke.py`` on the chip is.
 
-``slow``-marked: the packed-ctx kernel alone takes tens of seconds a shape.
+``slow``-marked: a kernel takes up to ten seconds a shape here.
 """
 import os
 
@@ -96,34 +96,41 @@ def test_paged_decode_gate_declines_unaligned_head_dim():
     assert not pk.supports(_spec((4, 3, 64)), _spec((8, BS, 1, 64)), None)
 
 
-@pytest.mark.parametrize("t", [64, 256])
-def test_ctx_kernel_compiles_inside_its_gate(v5e, t):
+@pytest.mark.parametrize("t,hq,hkv", [
+    (64, HQ, HKV), (256, HQ, HKV), (512, HQ, HKV),
+    (8, HQ, HKV),            # a one-slot verify pack
+    (512, HQ // 4, HKV // 4),  # the TP=4 shard's pack
+])
+def test_ctx_kernel_compiles_inside_its_gate(v5e, t, hq, hkv):
     from deepspeed_tpu.ops.pallas import ctx_attention as ck
 
-    q = _spec((t, HQ, HD))
-    kv = _spec((t, HKV, HD))
+    q = _spec((t, hq, HD))
+    kv = _spec((t, hkv, HD))
     seg = _spec((t,), jnp.int32)
-    pool = _spec((256, BS, HKV, HD))
-    tables = _spec((16, 64), jnp.int32)
-    lens = _spec((16,), jnp.int32)
+    pool = _spec((2304, BS, hkv, HD))
+    tables = _spec((64, 128), jnp.int32)
+    lens = _spec((64,), jnp.int32)
     assert ck.supports(q, pool, tables)
     _assert_mosaic(_compile(ck.paged_attention_packed_ctx_kernel, v5e,
                             q, kv, kv, seg, pool, pool, tables, lens))
 
 
 def test_ctx_gate_declines_oversized_pack():
-    """The VMEM gate's verdict at Mistral-7B widths: a 512-token pack does
-    not fit the resident q/acc budget, so the dispatcher must route it to
-    the dense body (and say so — see ops.pallas.note_dispatch)."""
+    """The VMEM gate's verdict at Mistral-7B widths: packs of 256 and 512
+    tokens stay on the kernel (the resident q / pack kv / fp32 outputs plus
+    the tiles fit its budget), a 1024-token pack does not, so the
+    dispatcher must route it to the dense body (and say so — see
+    ops.pallas.note_dispatch)."""
     from deepspeed_tpu.ops.pallas import ctx_attention as ck
 
-    pool = _spec((256, BS, HKV, HD))
-    tables = _spec((16, 64), jnp.int32)
+    pool = _spec((2304, BS, HKV, HD))
+    tables = _spec((64, 128), jnp.int32)
     assert ck.supports(_spec((256, HQ, HD)), pool, tables)
-    assert not ck.supports(_spec((512, HQ, HD)), pool, tables)
-    # a quarter of the heads (TP=4 local shard) fits the same 512 pack
-    assert ck.supports(_spec((512, HQ // 4, HD)),
-                       _spec((256, BS, HKV // 4, HD)), tables)
+    assert ck.supports(_spec((512, HQ, HD)), pool, tables)
+    assert not ck.supports(_spec((1024, HQ, HD)), pool, tables)
+    # a quarter of the heads (TP=4 local shard) fits the 1024 pack too
+    assert ck.supports(_spec((1024, HQ // 4, HD)),
+                       _spec((2304, BS, HKV // 4, HD)), tables)
 
 
 @pytest.mark.parametrize("m", [16, 256])

@@ -237,6 +237,38 @@ def test_chunked_prefill_spans_defer_and_resolve_tick_tight(tiny):
     assert all(e["args"]["uids"] == [1] for e in packs)
 
 
+def test_prefill_pack_span_carries_ctx_pages(tiny):
+    """``ctx_pages`` on a ``prefill_pack`` span is the live context pages the
+    pack's segments bring: ceil(start / block_size) summed over its entries,
+    so a trace can divide the ctx kernel's time by the work it walked."""
+    cfg, params = tiny
+    bs = 8
+    eng = InferenceEngineV2(
+        params, cfg, max_seqs=4, num_blocks=32, block_size=bs,
+        prefill_buckets=(16, 32), prefill_chunk=16, prefill_budget=32,
+        telemetry=True,
+    )
+    seen = []
+    run = eng._run_packed_prefill
+
+    def spy(entries, sampling, out):
+        seen.append(sum(-(-start // bs) for _, start, _ in entries))
+        return run(entries, sampling, out)
+
+    eng._run_packed_prefill = spy
+    sched = eng.scheduler
+    samp = SamplingParams(temperature=0.0, max_new_tokens=2)
+    sched.submit(1, list(range(1, 41)), samp)    # 40 tokens: chunks at 0, 16, 32
+    sched.submit(2, list(range(50, 71)), samp)   # 21 tokens: chunks at 0, 16
+    sched.run()
+    evs = eng.telemetry.chrome_trace()["traceEvents"]
+    packs = [e for e in evs if e["ph"] == "X" and e["name"] == "prefill_pack"]
+    assert len(packs) == len(seen) >= 3
+    assert [e["args"]["ctx_pages"] for e in packs] == seen
+    assert max(seen) >= 4 and seen[0] == 0  # cold pack first, ctx packs after
+    eng.close()
+
+
 def test_stats_view_mapping_semantics():
     reg = MetricsRegistry(enabled=True)
     c = {k: reg.counter(f"p/{k}") for k in ("a", "b")}
