@@ -30,6 +30,21 @@ from .sampling import SamplingParams, finite_guard, sample
 # distinct from the -1 finite_guard poison sentinel (which is a real
 # emission — always a row's LAST — that the host must see to quarantine)
 _BURST_PAD = -2
+# extra ``stats`` keys of an engine whose model has layers of several kinds
+# (cfg.latent): what its selectors, windows and router did.  The causal keys
+# and the ring rows follow from positions and are counted on the host at
+# dispatch; the keys SELECTED and the routing four are counted on the device
+# (latent_runner's ``picks`` and ROUTING_STATS) and read by
+# ``refresh_routing_stats()``, which ``close()`` calls.
+LATENT_COUNTERS = (
+    "index_keys_scored",      # (query, key) pairs the indexers scored: causal keys
+    "index_keys_selected",    # ... and pairs the selectors took (device count)
+    "window_rows_discarded",  # ring rows that fell out of a window
+    "expert_pairs_routed",    # (token, expert) pairs the routers picked
+    "expert_pairs_held",      # ... that fell on experts held here
+    "expert_group_rows_max",  # rows of the largest held expert's group in a pack
+    "expert_group_rows_min",  # ... and of the smallest
+)
 
 
 def _bucket(n: int, buckets: Sequence[int]) -> int:
@@ -95,6 +110,26 @@ class InferenceEngineV2:
                 "(falcon/gptj/phi layout): the runner wires sequential "
                 "attn_norm/mlp_norm blocks — use init_inference instead"
             )
+        if cfg.latent is not None:
+            # layers of several kinds (models/latent.py) keep two kinds of
+            # state (latent pages, a window ring per slot): what would serve
+            # them wrongly is refused by the name of its option
+            from ..models.latent import refuse
+
+            for option, on, why in (
+                ("grid (a tensor-parallel / replica / seq-shard serve mesh)",
+                 grid is not None or int(serve_replicas) > 1 or int(seq_shards) > 1,
+                 "its weights and caches have no sharding rules yet"),
+                ("enable_speculation", enable_speculation,
+                 "a rejected draft's rows cannot be rolled back out of a ring"),
+                ("quantize_weights (and int8 / fp8 KV)", quantize_weights is not None,
+                 "its projections and latent rows have no quantized form yet"),
+                ("enable_prefix_caching", enable_prefix_caching,
+                 "a cached prefix would have to bring a window's ring with its pages"),
+                ("offload_weights", offload_weights, "untried"),
+            ):
+                if on:
+                    refuse(option, why)
         # 2-D batch x model serve mesh: ``serve_replicas`` > 1 partitions
         # slots and KV blocks into per-replica groups laid out over the
         # mesh's batch (data) axis — explicit opt-in, because leftover mesh
@@ -395,10 +430,21 @@ class InferenceEngineV2:
         self.prefill_budget = min(
             prefill_budget or self.prefill_buckets[-1], self.prefill_buckets[-1]
         )
-        self.kv = init_paged_cache(
-            cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads, cfg.hd,
-            dtype=cfg.dtype,
-        )
+        if cfg.latent is not None:
+            from . import latent_runner
+
+            self.kv = latent_runner.init_cache(
+                cfg, num_blocks, block_size, max_seqs, self.prefill_buckets[-1])
+            # host mirror of the rings: positions each slot's ring has taken
+            # (a ring is not allocated, so this is what ``close()`` audits)
+            self._ring_rows = np.zeros(max_seqs, np.int64)
+            self.mgr.release_hook = self._release_ring
+            self._c.update(self.telemetry.counters(self._ns, LATENT_COUNTERS))
+        else:
+            self.kv = init_paged_cache(
+                cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads, cfg.hd,
+                dtype=cfg.dtype,
+            )
         self._kv_shardings = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding
@@ -645,6 +691,16 @@ class InferenceEngineV2:
                         static_argnums=(13, 14)),
                 kv_rest_idx=10,
             )
+
+        self._tracked = {}
+        if cfg.latent is not None:
+            # the device trace is attributed to these programs' named scopes
+            # (telemetry.program_scopes): keep the shapes they compiled for
+            from ..telemetry import programs
+
+            self._tracked = {
+                name: programs.track(getattr(self, name))
+                for name in ("_packed_prefill_ctx_jit", "_decode_jit")}
 
         def _cow(src: int, dst: int) -> None:
             self.kv = self._cow_jit(self.kv, jnp.int32(src), jnp.int32(dst))
@@ -935,7 +991,10 @@ class InferenceEngineV2:
                     f"prefill bucket {C} must be a multiple of block_size {bs}"
                 )
             t_pad = C * dp
-            use_ctx = any(start > 0 for _, start, _ in entries)
+            # layers of several kinds read a pack's own rows back from the
+            # cache, so cold packs and context packs are ONE program
+            use_ctx = any(start > 0 for _, start, _ in entries) \
+                or self.cfg.latent is not None
             tokens = np.zeros(t_pad, np.int32)
             seg = np.zeros(t_pad, np.int32)
             pos = np.zeros(t_pad, np.int32)
@@ -966,19 +1025,28 @@ class InferenceEngineV2:
             n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
             # live context pages the ctx kernel walks: its time over this
             ctx_pages = int((-(-ctx_lens // bs)).sum())
+            extra = {}
+            if self.cfg.latent is not None:
+                extra = self._count_latent(
+                    [(start, end) for _, start, end in entries])
+                for s, _, end in entries:
+                    self._ring_rows[s.slot] = end
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
             tokens=n_real, pad=t_pad, entries=len(entries), ctx=use_ctx,
-            ctx_pages=ctx_pages, uids=[s.uid for s, _, _ in entries],
+            ctx_pages=ctx_pages, uids=[s.uid for s, _, _ in entries], **extra,
         ) as sp:
             if use_ctx:
-                sampled, self.kv = self._packed_prefill_ctx_jit(
+                args = (
                     self.params, jnp.asarray(tokens), jnp.asarray(seg),
                     jnp.asarray(pos), jnp.asarray(pack_pages),
                     jnp.asarray(last_idx), jnp.asarray(ctx_tables),
                     jnp.asarray(ctx_lens), self.kv, sub, triple,
                 )
+                sampled, self.kv = self._packed_prefill_ctx_jit(*args)
+                if self._tracked:
+                    self._tracked["_packed_prefill_ctx_jit"].note(args)
             else:
                 sampled, self.kv = self._packed_prefill_jit(
                     self.params, jnp.asarray(tokens), jnp.asarray(seg),
@@ -1023,6 +1091,48 @@ class InferenceEngineV2:
                     self._set_block_table(s)
                     out[s.uid] = tok
                 self.mgr.update_hashes(s)
+
+    # -- layers of several kinds (cfg.latent) ---------------------------------
+    def _count_latent(self, ranges) -> Dict[str, int]:
+        """What the selectors and windows are ASKED to do with queries at
+        positions ``[start, end)`` of each range, all layers: the dispatch's
+        span arguments.  The causal keys and the ring rows are counted into
+        ``stats`` here; the keys selected are counted where they are selected
+        (``refresh_routing_stats``), so that count moves if a selector
+        breaks, and a sound run's equals the sum of these arguments."""
+        s = self.cfg.latent
+        topk, win = s.index_topk, s.sliding.window
+        scored = selected = dropped = 0
+        for a, b in ranges:
+            scored += (b * (b + 1) - a * (a + 1)) // 2  # sum of p + 1
+            m = min(max(a, topk), b)  # from position m on, topk of p + 1 keys
+            selected += (m * (m + 1) - a * (a + 1)) // 2 + (b - m) * topk
+            dropped += max(b - max(a, win), 0)  # position p overwrites p - win
+        out = {"index_keys_scored": scored * s.count("full"),
+               "index_keys_selected": selected * s.count("full"),
+               "window_rows_discarded": dropped * s.count("sliding")}
+        for k in ("index_keys_scored", "window_rows_discarded"):
+            self._c[k].inc(out[k])
+        return out
+
+    def _release_ring(self, seq) -> None:
+        self._ring_rows[seq.slot] = 0
+
+    def refresh_routing_stats(self) -> None:
+        """Fetch the selectors' and routers' device-side counts into ``stats``
+        (two small device->host copies; call it outside a timed window's hot
+        loop)."""
+        if self.cfg.latent is None or self.kv is None:
+            return
+        from .latent_runner import picks_total
+
+        self._c["index_keys_selected"].set(picks_total(self.kv["picks"]))
+        st = np.asarray(self.kv["stats"]).astype(np.int64)
+        if st.size:
+            self._c["expert_pairs_routed"].set(int(st[:, 0].sum()))
+            self._c["expert_pairs_held"].set(int(st[:, 1].sum()))
+            self._c["expert_group_rows_max"].set(int(st[:, 2].max()))
+            self._c["expert_group_rows_min"].set(int(st[:, 3].min()))
 
     def _set_block_table(self, seq) -> None:
         row = self._tables_np[seq.slot]
@@ -1448,18 +1558,27 @@ class InferenceEngineV2:
                 ctx_tokens += s.cur_len
             self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
             self._rng, sub = jax.random.split(self._rng)
+            extra = {}
+            if self.cfg.latent is not None:
+                extra = self._count_latent(
+                    [(s.cur_len - 1, s.cur_len) for s in active_seqs])
+                for s in active_seqs:
+                    self._ring_rows[s.slot] = s.cur_len
         # decode_tick_ms is uploads + dispatch + fetch: the argument uploads
         # (tokens, lengths, tables, key) belong inside the span
         with tel.span(
             "decode_tick", track=ns, hist=self._h["decode_tick_ms"],
-            batch=len(active_seqs), ctx_tokens=ctx_tokens,
+            batch=len(active_seqs), ctx_tokens=ctx_tokens, **extra,
         ) as sp:
-            sampled, _, _, self.kv = self._decode_jit(
+            args = (
                 self.params, jnp.asarray(tokens), self._commit_rep(seq_lens),
                 self._tables_device(), jnp.asarray(active), self.kv,
                 self._commit_rep(sub),
                 (sampling.temperature, sampling.top_k, sampling.top_p),
             )
+            sampled, _, _, self.kv = self._decode_jit(*args)
+            if self._tracked:
+                self._tracked["_decode_jit"].note(args)
             sp.dispatched()
             self._c["decode_ticks"].inc()
             self._c["decode_emitted"].inc(len(active_seqs))
@@ -1895,6 +2014,7 @@ class InferenceEngineV2:
             return dict(self._close_audit)
         if self._scheduler is not None:
             self._scheduler.close()
+        self.refresh_routing_stats()
         for uid in list(self.mgr.seqs):
             self.mgr.release(uid)
         in_use = 0
@@ -1905,6 +2025,10 @@ class InferenceEngineV2:
             in_use += a.total_blocks - a.free_blocks - a.cached_blocks
             cached += a.cached_blocks
         self._close_audit = {"blocks_in_use": in_use, "cached_blocks": cached}
+        if self.cfg.latent is not None:
+            # rows of window state still owned by a sequence (a ring is
+            # nobody's once its slot is released)
+            self._close_audit["window_rows"] = int(self._ring_rows.sum())
         self.telemetry.flush()
         for ns in (self._ns, self._sched_ns, self._comm_ns):
             self.telemetry.release_prefix(ns)
@@ -1914,6 +2038,8 @@ class InferenceEngineV2:
         self.params = None
         self.kv = None
         self.mgr.cow_hook = None
+        self.mgr.release_hook = None
+        self._tracked = {}
         for attr in ("_packed_prefill_jit", "_packed_prefill_ctx_jit",
                      "_cow_jit", "_decode_jit", "_decode_burst_jit",
                      "_spec_jit", "_tables_dev", "_samp_dev",

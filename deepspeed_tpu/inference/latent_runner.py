@@ -1,0 +1,332 @@
+"""Serving runner for a model with layers of several kinds
+(``TransformerConfig.latent``, ``models/latent.py``): the prefill pack and
+the decode tick against a cache that holds, per KIND of layer, what that kind
+attends over.
+
+- a ``full`` layer keeps every position's latent row (``kv_rank + rope`` wide,
+  keys and values in one array) in PAGES, block ids and block tables the
+  allocator's own, and the indexer's key page beside it;
+- a ``sliding`` layer keeps a RING per slot, not pages: position ``p`` of slot
+  ``n`` lives in row ``p % R`` of ``win[n]``, ``R`` = one pack + the window's
+  look-back, so a pack's rows can be written before it attends without
+  touching a row its window still needs.  Nothing is allocated or freed: rows
+  behind the window are overwritten as the sequence grows, a slot's next
+  owner overwrites from position 0, and a ring row's position follows from
+  the reader's own (``decode``: the latest ``p <= pos`` with ``p % R == i``), so
+  a stale row is never taken for a key;
+- ``stats`` counts routing on the device (``ROUTING_STATS``) and ``picks`` the
+  keys the selectors took (``_tally``), fetched on demand.
+
+One layer body (``_layer``) serves the pack and the tick; the kind chooses how
+the rows are written and read.  A pack reads its own rows back from the cache
+it just wrote, so a cold pack and a pack over cached context are one program,
+as are chunks of one prompt and several prompts in one pack: work is laid out
+in groups of one page of one sequence.  Plain XLA bodies
+(``ops/latent_attention.py``); the expert layer's grouped matmul is a Pallas
+kernel on the chip (``moe/layer.py``).
+"""
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+
+from ..models import latent as lm
+from ..ops import latent_attention as la
+from ..ops.pallas import index_scores as index_kernel
+from ..ops.pallas import note_dispatch, on_tpu
+
+Cache = Dict[str, Any]
+# columns of cache["stats"], one row per expert layer: (token, expert) pairs
+# routed and pairs that fell on held experts (running sums), rows of the
+# largest and of the smallest held expert's group in any one pack so far
+ROUTING_STATS = ("pairs_routed", "pairs_held", "group_rows_max", "group_rows_min")
+_NO_MIN = 1 << 30
+_CARRY = 30  # bits of the low word of cache["picks"]
+
+
+def _lanes(width: int) -> int:
+    """A latent row as the pages keep it: padded with zeros to whole 128-lane
+    rows, which a row gather moves at twice the speed (576 -> 640)."""
+    return -(-width // 128) * 128
+
+
+def ring_rows(cfg, block_size: int, pack_tokens: int) -> int:
+    """Rows of a slot's ring: one pack and the window's look-back, in pages."""
+    back = -(-(cfg.latent.sliding.window - 1) // block_size) * block_size
+    return pack_tokens + back
+
+
+def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
+               pack_tokens: int, dtype=None) -> Cache:
+    s, dtype = cfg.latent, dtype or cfg.dtype
+    if pack_tokens % block_size:
+        raise ValueError(f"a pack of {pack_tokens} tokens is no whole number of "
+                         f"pages of {block_size}")
+    pages = lambda w, n: tuple(
+        jnp.zeros((num_blocks, block_size, w), dtype) for _ in range(n))
+    chunks = max_seqs * ring_rows(cfg, block_size, pack_tokens) // block_size
+    n_moe = max(cfg.num_layers - s.first_dense, 0)
+    stats = jnp.zeros((n_moe, len(ROUTING_STATS)), jnp.int32)
+    return {
+        "lat": pages(_lanes(s.full.row), s.count("full")),
+        "idx": pages(s.index_dim, s.count("full")),
+        "win": tuple(jnp.zeros((chunks, block_size, s.sliding.row), dtype)
+                     for _ in range(s.count("sliding"))),
+        "stats": stats.at[:, 3].set(_NO_MIN),
+        # keys selected so far, one row per full layer: a running count in two
+        # int32 words (high, low 30 bits), since a window's sum passes 2^31
+        "picks": jnp.zeros((s.count("full"), 2), jnp.int32),
+    }
+
+
+def _tally(picks, new):
+    """``picks`` [F, 2] with ``new`` [F] (each under 2^30) added in."""
+    lo = picks[:, 1] + new
+    return jnp.stack([picks[:, 0] + (lo >> _CARRY), lo & ((1 << _CARRY) - 1)], axis=1)
+
+
+def picks_total(picks) -> int:
+    """The count ``cache["picks"]`` holds, all layers (host side)."""
+    import numpy as np
+
+    p = np.asarray(picks).astype(np.int64)
+    return int((p[:, 0] << _CARRY).sum() + p[:, 1].sum())
+
+
+def _attend_selected(s, q_abs, q_i, w, q_pos, tables, lat, idx, real, picked, probe=None):
+    """Full layers: groups [G, C, ...] of queries, ``tables`` [G, P] each
+    group's block table.  Index scores over the group's pages, exact top-k,
+    attention over the selected rows of the latent pages.  ``picked`` (a list)
+    is handed how many keys the ``real`` [G, C] rows selected, ``probe`` (a
+    list) what was selected: positions and their scores, [G, C, k]."""
+    a, (g, c) = s.full, q_pos.shape
+    nb, bs, _ = idx.shape
+    kb = la.index_key_block(c, s.index_heads, tables.shape[1] * bs, bs)
+    kp = kb // bs
+    tables = jnp.pad(jnp.maximum(tables, 0), ((0, 0), (0, -tables.shape[1] % kp)))
+    k_pad = tables.shape[1] * bs
+    q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, lat.shape[-1] - a.row),))
+
+    def scores_of(q_i, w, q_pos, table):
+        live = (jnp.max(q_pos) + kb) // kb  # key blocks that hold a key <= a query
+        key_block = lambda b: idx[jax.lax.dynamic_slice_in_dim(table, b * kp, kp)
+                                  ].reshape(kb, s.index_dim)
+        return la.index_scores(q_i, w, q_pos, key_block, live, kb, k_pad, s.index_scale)
+
+    if c == 1:
+        # a decode tick: the groups are single rows, batched; a row's keys
+        # come straight out of the pages
+        def row(q_abs, q_i, w, q_pos, table, n_live):
+            vals, ix = la.select_topk(scores_of(q_i, w, q_pos, table), s.index_topk, n_live)
+            rows_of = lambda ix: lat[table[ix // bs], ix % bs]
+            o = la.sparse_attention(q_abs, ix, vals > -jnp.inf, rows_of, a.kv_rank, a.scale)
+            return o, ix, vals
+
+        o, ix, vals = jax.vmap(row, in_axes=(0, 0, 0, 0, 0, None))(
+            q_abs, q_i, w, q_pos, tables, jnp.max(q_pos) + 1)
+    else:
+        # a pack: each group is a page of one sequence's queries
+        if _index_kernel_takes(c, s.index_heads, s.index_dim, bs):
+            with jax.named_scope("indexer"):  # every group's scores in one call
+                raw = index_kernel.paged_index_scores(
+                    q_i, w, idx, tables, (jnp.max(q_pos, axis=1) + bs) // bs, s.index_scale)
+                scores = jnp.where(jnp.arange(k_pad)[None, None, :] <= q_pos[:, :, None],
+                                   raw, -jnp.inf)
+        else:
+            scores = jax.lax.map(lambda xs: scores_of(*xs), (q_i, w, q_pos, tables))
+
+        def group(xs):
+            sc, q_abs, q_pos, table = xs
+            vals, ix = la.select_topk(sc, s.index_topk, jnp.max(q_pos) + 1)
+            # the sequence's pages laid out once (whole pages move at the
+            # memory's speed), then rows by position; the barrier keeps the
+            # page lookup out of every row's fetch
+            own = jax.lax.optimization_barrier(lat[table].reshape(k_pad, lat.shape[-1]))
+            o = la.sparse_attention(q_abs, ix, vals > -jnp.inf, lambda r: own[r],
+                                    a.kv_rank, a.scale)
+            return o, ix, vals
+
+        o, ix, vals = jax.lax.map(group, (scores, q_abs, q_pos, tables))
+    picked.append(jnp.sum((vals > -jnp.inf) & real[..., None], dtype=jnp.int32))
+    if probe is not None:
+        probe.append({"index_picked": ix, "index_values": vals})
+    return o
+
+
+def _index_kernel_takes(c: int, j: int, d: int, bs: int) -> bool:
+    """The gate of the Pallas index-scores kernel (a page of queries at a
+    time; a decode tick's single rows stay on the XLA body by design)."""
+    interpret = index_kernel.interpret()
+    if not (interpret or on_tpu()):
+        reason = "not on a TPU"
+    elif not index_kernel.supports(c, j, d, bs):
+        reason = "queries, page and head size must be whole 128-lane tiles"
+    else:
+        note_dispatch("index_scores", True, (c, j, d, bs), interpret=interpret)
+        return True
+    note_dispatch("index_scores", False, (c, j, d, bs), reason=reason)
+    return False
+
+
+def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, probe):
+    """One layer on token rows ``x`` [T, d].  The seam: ``write(kind, arrays,
+    rows)`` returns the kind's cache arrays with the new rows in, ``read(kind,
+    arrays, queries)`` attends over them.  Returns (x, cache)."""
+    s = cfg.latent
+    kind, (n1, n2), aw, fw, is_moe = lm.layer_params(layers, l, s)
+    a, i = s.attn(kind), s.layer_kinds[:l].count(kind)
+    h = lm.rms(x, n1["scale"], cfg.norm_eps)
+    c_q, q_abs, row, gate = lm.attn_inputs(aw, h, pos, a, cfg)
+    keys, rows, queries = ("win",), (row,), (q_abs,)
+    if kind == "full":
+        q_i, k_i, w = lm.indexer_inputs(aw, h, c_q, pos, s, cfg)
+        keys, rows, queries = ("lat", "idx"), (row, k_i), (q_abs, q_i, w)
+    arrays = write(kind, tuple(cache[k][i] for k in keys), rows)
+    cache = {**cache, **{k: _put(cache[k], i, v) for k, v in zip(keys, arrays)}}
+    o = read(kind, arrays, queries)
+    x = x + lm.attn_output(aw, o, gate, a).astype(x.dtype)
+    h = lm.rms(x, n2["scale"], cfg.norm_eps)
+    y, routing = lm.ffn(fw, h, is_moe, cfg, valid)
+    if routing is not None:
+        routed, picked = routing
+        if probe is not None:
+            probe.append({"experts_picked": picked})
+        st, m = cache["stats"], l - s.first_dense
+        new = jnp.stack([st[m, 0] + routed[0], st[m, 1] + routed[1],
+                         jnp.maximum(st[m, 2], routed[2]) if track_groups else st[m, 2],
+                         jnp.minimum(st[m, 3], routed[3]) if track_groups else st[m, 3]])
+        cache = {**cache, "stats": st.at[m].set(new)}
+    return x + y.astype(x.dtype), cache
+
+
+def _fit(rows, pages):
+    """Rows [T, w] in the pages' dtype and width (zeros past ``w``)."""
+    return jnp.pad(rows.astype(pages.dtype), ((0, 0), (0, pages.shape[-1] - rows.shape[-1])))
+
+
+def _put(items: tuple, i: int, value) -> tuple:
+    return items[:i] + (value,) + items[i + 1:]
+
+
+def _logits(params, cfg, x):
+    x = lm.rms(x, params["final_norm"]["scale"], cfg.norm_eps)
+    return (x @ params["lm_head"]["kernel"]).astype(jnp.float32)
+
+
+def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_idx,
+                 tables, cache: Cache, probe=None):
+    """One prefill pack (the arguments of ``model_runner.prefill_packed_ctx``
+    less ``ctx_lens``: a token's position says where its context ends).
+    ``tables`` [N, P] are the block tables by slot, this pack's pages included.
+    ``probe`` (a list) collects, layer by layer, what the indexers and the
+    routers picked.  Returns (logits [N, vocab], cache)."""
+    s, t = cfg.latent, tokens.shape[0]
+    nb, bs, _ = cache["lat"][0].shape if cache["lat"] else cache["win"][0].shape
+    g = t // bs
+    ring = cache["win"][0].shape[0] * bs // tables.shape[0] if cache["win"] else 0
+    if ring and ring < ring_rows(cfg, bs, t):
+        raise ValueError(f"a pack of {t} tokens needs rings of {ring_rows(cfg, bs, t)} "
+                         f"rows; the cache was built with {ring}")
+    valid = segment_ids > 0
+    slot = jnp.maximum(segment_ids[::bs] - 1, 0)  # a page of the pack is one sequence's
+    live = segment_ids[::bs] > 0
+    page0 = positions[::bs] // bs                 # ... and starts on one of its pages
+    grouped = lambda a: a.reshape(g, bs, *a.shape[1:])
+    q_pos = grouped(positions)
+    safe_pages = jnp.where(pack_pages >= 0, pack_pages, nb)
+    rc = ring // bs
+    back = -(-(s.sliding.window - 1) // bs)
+
+    def write(kind, arrays, rows):
+        if kind == "full":
+            return tuple(a.at[safe_pages].set(grouped(_fit(r, a)), mode="drop")
+                         for a, r in zip(arrays, rows))
+        (win,), (row,) = arrays, rows
+        to = jnp.where(live, slot * rc + page0 % rc, win.shape[0])
+        return (win.at[to].set(grouped(row).astype(win.dtype), mode="drop"),)
+
+    def read(kind, arrays, queries):
+        if kind == "full":
+            q_abs, q_i, w = map(grouped, queries)
+            o = _attend_selected(s, q_abs, q_i, w, q_pos, tables[slot], *arrays,
+                                 grouped(valid), picked, probe=probe)
+        else:
+            # the group's own page and the ``back`` pages before it, from the ring
+            j = jnp.arange(back + 1)
+            pages = page0[:, None] - j[None, :]                       # [G, back+1]
+            keys = arrays[0][slot[:, None] * rc + pages % rc]         # [G, back+1, bs, W]
+            key_pos = jnp.where(pages[..., None] >= 0,
+                                pages[..., None] * bs + jnp.arange(bs), -1)
+            o = la.window_attention(
+                grouped(queries[0]), q_pos, keys.reshape(g, -1, keys.shape[-1]),
+                key_pos.reshape(g, -1), s.sliding.window, s.sliding.kv_rank,
+                s.sliding.scale)
+        return o.reshape(t, *o.shape[2:])
+
+    picked: list = []
+    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    for l in range(cfg.num_layers):
+        x, cache = _layer(cfg, l, params["layers"], x, positions, valid, cache,
+                          write, read, True, probe)
+    if picked:
+        cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
+    return _logits(params, cfg, x[jnp.clip(last_idx, 0, t - 1)]), cache
+
+
+def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cache,
+                probe=None):
+    """One batched decode tick (``model_runner.decode_step``'s arguments).
+    Returns (logits [B, vocab], cache)."""
+    s, b = cfg.latent, tokens.shape[0]
+    pos = seq_lens
+    rows = jnp.arange(b)
+
+    def write(kind, arrays, new):
+        if kind == "full":
+            nb, bs, _ = arrays[0].shape
+            page = jnp.take_along_axis(block_tables, (pos // bs)[:, None], axis=1)[:, 0]
+            page = jnp.where(active & (page >= 0), page, nb)
+            return tuple(a.at[page, pos % bs].set(_fit(r, a), mode="drop")
+                         for a, r in zip(arrays, new))
+        (win,), (row,) = arrays, new
+        ring = win.shape[0] * win.shape[1] // b
+        flat = win.reshape(-1, win.shape[-1])
+        to = jnp.where(active, rows * ring + pos % ring, flat.shape[0])
+        return (flat.at[to].set(row.astype(win.dtype), mode="drop").reshape(win.shape),)
+
+    def read(kind, arrays, queries):
+        one = lambda a: a[:, None]
+        if kind == "full":
+            q_abs, q_i, w = map(one, queries)
+            return _attend_selected(s, q_abs, q_i, w, one(pos), block_tables, *arrays,
+                                    one(active), picked, probe=probe)[:, 0]
+        win = arrays[0]
+        ring = win.shape[0] * win.shape[1] // b
+        keys = win.reshape(b, ring, win.shape[-1])
+        # row i of a ring holds the latest position p <= pos with p % ring == i
+        key_pos = pos[:, None] - (pos[:, None] - jnp.arange(ring)[None, :]) % ring
+        return la.window_attention(one(queries[0]), one(pos), keys, key_pos,
+                                   s.sliding.window, s.sliding.kv_rank,
+                                   s.sliding.scale)[:, 0]
+
+    picked: list = []
+    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    for l in range(cfg.num_layers):
+        x, cache = _layer(cfg, l, params["layers"], x, pos, active, cache,
+                          write, read, False, probe)
+    if picked:
+        cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
+    return _logits(params, cfg, x), cache
+
+
+def tables_of_pack(segment_ids, positions, pack_pages, n_slots: int, n_pages: int,
+                   block_size: int):
+    """Block tables [N, P] of a COLD pack, which is handed none: every page
+    of the pack is page ``position // block_size`` of its sequence."""
+    slot = segment_ids[::block_size] - 1
+    page = positions[::block_size] // block_size
+    at = jnp.where((slot >= 0) & (pack_pages >= 0), slot, n_slots)
+    return jnp.full((n_slots, n_pages), -1, jnp.int32).at[at, page].set(
+        pack_pages, mode="drop")

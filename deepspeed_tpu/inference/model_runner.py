@@ -63,6 +63,19 @@ def _qkv(lw, x, cfg: TransformerConfig, ctx=None):
     )
 
 
+def _latent_only(ctx, mesh, dp: int = 1, seq_shards: int = 1) -> None:
+    """A model with layers of several kinds runs on one chip, unsharded."""
+    from ..models.latent import refuse
+
+    if mesh is not None or (ctx is not None and ctx.size > 1):
+        refuse("a tensor-parallel serve mesh (grid)", "its weights and caches have "
+               "no sharding rules yet")
+    if dp > 1:
+        refuse("serve_replicas > 1", "its caches are not partitioned by replica")
+    if seq_shards > 1:
+        refuse("seq_shards > 1", "its caches are not striped over a seq axis")
+
+
 def _ffn(lw, x, cfg, ctx=None):
     if cfg.moe_num_experts > 0:
         # dropless at inference: capacity competition would make routing
@@ -125,6 +138,11 @@ def prefill(
     Dense causal attention over the padded prompt (padding masked by
     causality + the final gather at ``length - 1``).
     """
+    if cfg.latent is not None:
+        from ..models.latent import refuse
+
+        refuse("prefill (one padded prompt)", "the engine packs every prompt; "
+               "use prefill_packed")
     s = tokens.shape[0]
     x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,s,d]
     positions = jnp.arange(s)[None]
@@ -193,6 +211,19 @@ def prefill_packed(
     inside its last page carry garbage masked by sequence length, same as
     ``write_prefill_kv``.  Returns (logits [N, vocab], new caches).
     """
+    if cfg.latent is not None:
+        # layers of several kinds: their own cache and bodies; a cold pack is
+        # a pack whose block tables are its own pages
+        from . import latent_runner
+
+        _latent_only(ctx, mesh)
+        n_pages = pack_pages.shape[0]  # a cold pack's positions end inside it
+        tables = latent_runner.tables_of_pack(
+            segment_ids, positions, pack_pages, last_idx.shape[0], n_pages,
+            tokens.shape[0] // n_pages)
+        return latent_runner.prefill_pack(
+            params, cfg, tokens, segment_ids, positions, pack_pages, last_idx,
+            tables, kv_cache)
     t = tokens.shape[0]
     x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,T,d]
     if cfg.position == "learned":
@@ -271,6 +302,13 @@ def prefill_packed_ctx(
     (segment's prompt not yet complete — mid-chunk) yield garbage logits the
     engine never consumes.
     """
+    if cfg.latent is not None:
+        from . import latent_runner
+
+        _latent_only(ctx, mesh, dp, seq_shards)
+        return latent_runner.prefill_pack(
+            params, cfg, tokens, segment_ids, positions, pack_pages, last_idx,
+            ctx_tables, kv_cache)
     t = tokens.shape[0]
     x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,T,d]
     if cfg.position == "learned":
@@ -359,6 +397,11 @@ def verify_packed_ctx(
 
     Returns (logits [T, v], new caches).
     """
+    if cfg.latent is not None:
+        from ..models.latent import refuse
+
+        refuse("enable_speculation (verify_packed_ctx)", "a rejected draft's rows "
+               "cannot be rolled back out of a sliding layer's ring")
     t = tokens.shape[0]
     x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,T,d]
     if cfg.position == "learned":
@@ -410,6 +453,12 @@ def decode_step(
     seq_shards: int = 1,  # seq-axis pool slices (3-D mesh, ring-merged)
 ):
     """One batched decode tick: returns (logits [B, v], new caches)."""
+    if cfg.latent is not None:
+        from . import latent_runner
+
+        _latent_only(ctx, mesh, dp, seq_shards)
+        return latent_runner.decode_step(
+            params, cfg, tokens, seq_lens, block_tables, active, kv_cache)
     b = tokens.shape[0]
     x = params["embed"]["embedding"][tokens][:, None].astype(cfg.dtype)  # [B,1,d]
     positions = seq_lens[:, None]  # the new token's position

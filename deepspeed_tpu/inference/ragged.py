@@ -451,6 +451,9 @@ class StateManager:
         ]
         self.enable_prefix_caching = enable_prefix_caching
         self.cow_hook: Optional[Callable[[int, int], None]] = None
+        # ``release_hook(seq)``: the engine's per-SLOT state that is not blocks
+        # (a sliding layer's ring) is let go with the sequence
+        self.release_hook: Optional[Callable[[SequenceDescriptor], None]] = None
         # chaos-harness hook (inference/faults.py FaultInjector): when set,
         # ``ensure_capacity`` consults the ``alloc_exhaustion`` injection
         # point before touching the real pool — the scheduler's retry /
@@ -848,6 +851,8 @@ class StateManager:
         seq = self.seqs.pop(uid)
         if seq.blocks:
             self._alloc_of(seq).free(seq.blocks)
+        if self.release_hook is not None:
+            self.release_hook(seq)
         self._slot_groups[self.replica_of(seq)].append(seq.slot)
 
     @property

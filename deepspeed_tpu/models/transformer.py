@@ -106,6 +106,13 @@ class TransformerConfig:
     # can overlap one chunk's TP all-reduce with another's compute; 1 = off.
     # Wired from config tensor_parallel.domino_chunks by initialize().
     domino_chunks: int = 1
+    # layers of several kinds (models/latent.py LatentSpec): latent attention
+    # with a key selector or a window per layer, a dense or an expert
+    # feed-forward per layer, of which a share may be held here.  Set, it
+    # replaces the one-kind layer the fields above describe (the widths they
+    # share stay: hidden, dense intermediate, vocabulary, depth, norm_eps).
+    # Forward and serving only.
+    latent: Optional[Any] = None
 
     @property
     def hd(self) -> int:
@@ -116,6 +123,10 @@ class TransformerConfig:
 
     @property
     def param_count(self) -> int:
+        if self.latent is not None:
+            from .latent import param_count
+
+            return param_count(self)
         d, f, L, v = self.hidden_size, self.intermediate_size, self.num_layers, self.vocab_size
         hq, hkv, hd = self.num_heads, self.num_kv_heads, self.hd
         attn = d * hq * hd + 2 * d * hkv * hd + hq * hd * d
@@ -150,6 +161,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig, dtype=jnp.float32) -> Pa
     fp32 by default — the engine keeps fp32 masters and casts to
     ``cfg.dtype`` inside the train step (runtime/precision.py).
     """
+    if cfg.latent is not None:
+        from . import latent
+
+        return latent.init_params(rng, cfg, dtype)
     d, f, L, v = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers, cfg.vocab_size
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     ks = jax.random.split(rng, 12)
@@ -599,6 +614,17 @@ def forward(
     the decoder stack execution (the pipeline-parallel executor hooks in
     here); caches are unsupported on that path.
     """
+    if cfg.latent is not None:
+        from . import latent
+
+        for option, given in (("cache", cache), ("stack_apply", stack_apply),
+                              ("layer_keep", layer_keep), ("segment_ids", segment_ids),
+                              ("positions", positions)):
+            if given is not None:
+                latent.refuse(f"forward({option}=...)", "the uncached forward takes "
+                              "whole sequences from position 0; serving goes through "
+                              "InferenceEngineV2")
+        return latent.forward(params, tokens, cfg, return_hidden=return_hidden)
     attn_fn = _get_attn_fn(cfg)
     b, s = tokens.shape
     if positions is None:
@@ -858,6 +884,11 @@ class CausalLM:
     def flops_per_token(self, seq_len: int) -> float:
         """Approximate training FLOPs/token (6N + attention quadratic term)."""
         c = self.cfg
+        if c.latent is not None:
+            from .latent import refuse
+
+            refuse("CausalLM.flops_per_token", "6N + 12Lds is not this model's cost "
+                   "(a token touches a share of the experts, attends selected keys)")
         n = c.param_count
         attn = 12 * c.num_layers * c.hidden_size * seq_len
         return 6.0 * n + attn

@@ -235,3 +235,108 @@ class MoE:
             k=self.k, capacity_factor=self.capacity_factor,
             min_capacity=self.min_capacity,
         )
+
+
+# ---------------------------------------------------------------------------
+# a HELD share of sigmoid-routed experts (serving; models/latent.py)
+# ---------------------------------------------------------------------------
+_GMM_ROWS = 128  # the grouped matmul's row tile
+
+
+def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
+    """(tm, tk, tn) of the grouped-matmul kernel: 128 rows (a group is a few
+    dozen rows here, so a taller tile would be padding) and a weight tile of
+    up to 1.6 M elements (3 MB, double-buffered): (128, 1024, 1536) and (128,
+    512, 2560) at d 5120 x f 1536, the fastest of six tried on the chip, all
+    within 12% (0.89 and 0.90 ms a call against 0.61 ms for reading the
+    weights; my chip run, PR 29)."""
+    tk = next(t for t in (1024, 512, 256, 128) if k % t == 0)
+    tn = max(t for t in range(128, n + 1, 128) if n % t == 0 and tk * t <= 1600 * 1024)
+    return _GMM_ROWS, tk, tn
+
+
+def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
+    """``xs[rows of group g] @ w[g]`` for rows sorted by group: xs [M, k],
+    w [G, k, n], sizes [G] int32 (their sum may be under M: the rows past it
+    belong to no group and come back undefined).  On a TPU the megablox
+    Pallas kernel, which visits only the row tiles that hold a group's rows
+    and reads each group's weights once per tile; elsewhere, and for shapes
+    its tiles do not divide, ``lax.ragged_dot``."""
+    from ..ops.pallas import note_dispatch, on_tpu
+
+    m, k = xs.shape
+    n = w.shape[-1]
+    if not on_tpu():
+        note_dispatch("expert_gmm", False, (m, k, n), reason="not on a TPU")
+    elif m % 128 or k % 128 or n % 128:
+        note_dispatch("expert_gmm", False, (m, k, n),
+                      reason="m, k and n must be multiples of 128")
+    else:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        note_dispatch("expert_gmm", True, (m, k, n))
+        return gmm(xs, w, sizes, preferred_element_type=xs.dtype,
+                   tiling=_gmm_tiling(k, n))
+    return jax.lax.ragged_dot(xs, w, sizes, preferred_element_type=xs.dtype)
+
+
+def held_routing(lw: Any, x: jnp.ndarray, spec) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """DeepSeek-V3's ``noaux_tc`` routing without groups: sigmoid scores in
+    float32, the ``experts_per_tok`` largest of score + bias picked, weights
+    the picked scores normalised (the bias selects, it does not weigh).
+    x [T, d] -> (experts [T, k], weights [T, k] float32)."""
+    s = jax.nn.sigmoid(x.astype(jnp.float32) @ lw["router"].astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + lw["bias"], spec.experts_per_tok)
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, picked / jnp.sum(picked, -1, keepdims=True) * spec.routed_scale
+
+
+def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
+    """The expert layer as ONE member of an expert-parallel deployment sees
+    it, without the exchange: route every token over all ``n_routed`` experts,
+    compute the picks that fall on the ``n_held`` experts held here by a
+    grouped matmul over (token, expert) pairs sorted by expert, add the shared
+    expert.  The result is this member's PARTIAL sum; the members' results,
+    the shared expert counted once, add up to the uncut layer's output.
+
+    x [T, d]; ``valid`` [T] bool masks padding rows out of routing.  Returns
+    (y [T, d], (stats int32 [4]: pairs routed, pairs on held experts, rows of
+    the largest and of the smallest held expert's group; the experts picked
+    [T, k]))."""
+    t, d = x.shape
+    k, g = spec.experts_per_tok, spec.n_held
+    idx, wts = held_routing(lw, x, spec)
+    local = idx - spec.held_offset
+    held = (local >= 0) & (local < g)
+    if valid is not None:
+        held &= valid[:, None]
+    key = jnp.where(held, local, g).reshape(-1)  # pairs of no held expert sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
+    # Each group starts on a row tile of the kernel (its rows padded up to
+    # whole tiles): a group that straddled a tile boundary had its expert's
+    # weights streamed once per tile, half as many reads again at ~64 rows a
+    # group, and how many straddled followed the routing, so the layer's time
+    # did too.  Row r of the padded layout is row ``within`` of group ``of``.
+    tile = _GMM_ROWS
+    padded = -(-sizes // tile) * tile
+    start, pstart = jnp.cumsum(sizes) - sizes, jnp.cumsum(padded) - padded
+    r = jnp.arange(t * k + g * tile)
+    of = jnp.maximum(jnp.sum(r[:, None] >= pstart[None, :], axis=1) - 1, 0)
+    within = r - pstart[of]
+    source = jnp.where(within < sizes[of], start[of] + within, 0)  # a pair, sorted order
+    with jax.named_scope("expert_matmul"):
+        xs = x[order[source] // k]  # [T*k + g*tile, d]; padding rows repeat a live one
+        h = jax.nn.silu(grouped_matmul(xs, lw["w_gate"], padded)) \
+            * grouped_matmul(xs, lw["w_up"], padded)
+        ys = grouped_matmul(h, lw["w_down"], padded)
+    at = jnp.argsort(order).reshape(t, k)  # where each pair sorted to
+    mine = jnp.clip(local, 0, g - 1)
+    dest = jnp.where(held, pstart[mine] + at - start[mine], 0)  # ... and its padded row
+    pairs = ys[dest].astype(jnp.float32)  # [T, k, d]
+    y = jnp.sum(jnp.where(held[..., None], pairs * wts[..., None], 0.0), axis=1)
+    shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
+    n_valid = t if valid is None else jnp.sum(valid, dtype=jnp.int32)
+    stats = jnp.stack([jnp.asarray(n_valid * k, jnp.int32),
+                       jnp.sum(held, dtype=jnp.int32), jnp.max(sizes), jnp.min(sizes)])
+    return (y.astype(x.dtype) + shared), (stats, idx)

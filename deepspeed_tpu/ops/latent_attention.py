@@ -1,0 +1,135 @@
+"""Latent attention over a cache of ONE row per key (DeepSeek-V2's MLA in its
+absorbed form) with a learned key selector (DeepSeek-V3.2's indexer) or a
+sliding window.
+
+A key is one row ``[c_kv ; k_rope]`` (``r_kv + rope`` wide) that serves every
+head: the queries are folded through ``W_uk`` first (``q_abs = [q_nope W_uk ;
+q_rope]``), the softmax-weighted sum of the rows' first ``r_kv`` columns is
+folded through ``W_uv`` afterwards (``models/latent.py``).  What is here is
+the part between: index scores, the exact top-k, attention over the selected
+rows, attention over a window.
+
+Work is laid out in GROUPS of ``C`` consecutive queries of one sequence (a
+page of a prefill pack; one decode row), because a group shares its keys:
+``key_block(g, b)`` hands block ``b`` of group ``g``'s index keys, ``row_of(g,
+positions)`` the flat rows of its latent keys.  Plain XLA bodies, each under a
+``jax.named_scope`` (``indexer``, ``topk``, ``sparse_attn``, ``window_attn``)
+so that a device trace can be attributed; gathers and scores exist one query
+block at a time.
+"""
+from __future__ import annotations
+
+from typing import Callable
+
+import jax
+import jax.numpy as jnp
+
+_MASKED = -1e30  # finite: a fully masked row softmaxes to uniform, not NaN
+Q_BLOCK = 64     # queries whose selected rows are gathered at once
+KEY_BLOCK_BYTES = 96 << 20  # cap of one [C, J, KB] float32 index-score block
+
+
+def index_key_block(c: int, j: int, k_total: int, unit: int) -> int:
+    """Keys scored at once: whole ``unit``s (pages), at most 2048 keys, at most
+    what keeps the ``[c, j, kb]`` float32 scores under ``KEY_BLOCK_BYTES``, and
+    no more than the ``k_total`` there are."""
+    cap = min(2048, KEY_BLOCK_BYTES // (4 * c * j), k_total)
+    return max(cap // unit, 1) * unit
+
+
+def index_scores(q_i, w, q_pos, key_block: Callable, n_blocks, kb: int, k_pad: int,
+                 scale: float):
+    """One group's index scores ``I[c, s] = scale * sum_j w[c, j] *
+    relu(q_i[c, j] . k_i[s])`` for keys ``s <= q_pos[c]``, ``-inf`` elsewhere.
+
+    q_i [C, J, D], w [C, J] float32, q_pos [C]; ``key_block(b)`` -> [kb, D]
+    keys of positions ``b*kb ..``; ``n_blocks`` (traced) blocks hold a live
+    key, the rest of the ``k_pad`` columns stay ``-inf`` unscored."""
+    c = q_i.shape[0]
+    kpos = jnp.arange(kb)
+
+    def body(b, out):
+        k = key_block(b)
+        s = jnp.einsum("cjd,kd->cjk", q_i, k, preferred_element_type=jnp.float32)
+        s = jnp.sum(jax.nn.relu(s) * w[:, :, None], axis=1) * scale
+        live = (b * kb + kpos)[None, :] <= q_pos[:, None]
+        return jax.lax.dynamic_update_slice(
+            out, jnp.where(live, s, -jnp.inf), (0, b * kb))
+
+    with jax.named_scope("indexer"):
+        return jax.lax.fori_loop(
+            0, n_blocks, body, jnp.full((c, k_pad), -jnp.inf, jnp.float32))
+
+
+TOPK_STEP = 4096  # widths the selection sorts at: multiples of this
+
+
+def select_topk(scores, k: int, n_live=None):
+    """The exact ``k`` largest of each row, equal scores to the lower
+    position: (scores [C, k], positions [C, k]).  A row with fewer than ``k``
+    live keys selects them all; the rest of its entries score ``-inf``.
+
+    ``lax.top_k`` with a k in the thousands is a sort of the whole row on the
+    TPU, and its cost grows faster than the row.  ``n_live`` (traced: no key at
+    or past it scores above ``-inf`` in any row) picks the narrowest of a few
+    static widths that holds every live key, and no sort at all while they
+    all fit in ``k``: the same selection, since what is cut off is ``-inf``."""
+    width = scores.shape[-1]
+    k = min(k, width)
+    with jax.named_scope("topk"):
+        if n_live is None or width <= max(k, TOPK_STEP):
+            return jax.lax.top_k(scores, k)
+        widths = [k] + [w for w in range(TOPK_STEP, width, TOPK_STEP) if w > k] + [width]
+
+        def at(w):
+            if w == k:  # every live key is among the first k: all are selected
+                return lambda s: (s[:, :k], jnp.broadcast_to(
+                    jnp.arange(k, dtype=jnp.int32), (s.shape[0], k)))
+            return lambda s: tuple(jax.lax.top_k(s[:, :w], k))
+
+        which = jnp.sum(jnp.asarray(widths[:-1]) < n_live)
+        return jax.lax.switch(which, [at(w) for w in widths], scores)
+
+
+def sparse_attention(q_abs, idx, valid, rows_of: Callable, r_kv: int, scale: float):
+    """One group's attention over its selected rows, absorbed form.
+
+    q_abs [C, H, W]; idx, valid [C, k]; ``rows_of(idx)`` -> [.., k, W] latent
+    rows of those key positions.  Returns [C, H, r_kv] (before ``W_uv``).
+    The rows of ``Q_BLOCK`` queries are gathered at once: 64 x 2048 rows of
+    640 bf16 are 168 MB, and blocks of 256 ran the softmax six times slower
+    (my chip run, PR 29)."""
+    c = q_abs.shape[0]
+    qb = min(Q_BLOCK, c)
+    if c % qb:
+        qb = c
+
+    def block(args):
+        q, ix, ok = args
+        rows = rows_of(ix)  # [qb, k, W]
+        s = jnp.einsum("chw,ckw->chk", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(ok[:, None, :], s, _MASKED), axis=-1)
+        return jnp.einsum("chk,ckr->chr", p.astype(rows.dtype), rows[..., :r_kv],
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    with jax.named_scope("sparse_attn"):
+        if qb == c:
+            return block((q_abs, idx, valid))
+        split = lambda a: a.reshape(c // qb, qb, *a.shape[1:])
+        out = jax.lax.map(block, (split(q_abs), split(idx), split(valid)))
+        return out.reshape(c, *out.shape[2:])
+
+
+def window_attention(q_abs, q_pos, keys, key_pos, window: int, r_kv: int, scale: float):
+    """Groups' attention over the last ``window`` positions (the query's own
+    included), absorbed form.  q_abs [G, C, H, W], q_pos [G, C], keys
+    [G, K, W], key_pos [G, K] (negative = no key).  Returns [G, C, H, r_kv]."""
+    with jax.named_scope("window_attn"):
+        s = jnp.einsum("gchw,gkw->gchk", q_abs, keys,
+                       preferred_element_type=jnp.float32) * scale
+        d = q_pos[:, :, None] - key_pos[:, None, :]
+        ok = (d >= 0) & (d < window) & (key_pos[:, None, :] >= 0)
+        p = jax.nn.softmax(jnp.where(ok[:, :, None, :], s, _MASKED), axis=-1)
+        return jnp.einsum("gchk,gkr->gchr", p.astype(keys.dtype), keys[..., :r_kv],
+                          preferred_element_type=jnp.float32).astype(q_abs.dtype)

@@ -1,0 +1,112 @@
+"""The expert layer of one MEMBER of an expert-parallel deployment
+(``moe/layer.py:moe_block_held``): sigmoid routing over all experts, a grouped
+matmul over the pairs that fall on the experts held here."""
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.models.latent import LatentAttn, LatentSpec
+from deepspeed_tpu.moe.layer import grouped_matmul, held_routing, moe_block_held
+
+D, F, E, K = 32, 16, 16, 4
+_A = LatentAttn(2, 8, 8, 8, 4, 8, 1e4)
+SPEC = LatentSpec(layer_kinds=(), full=_A, sliding=_A, index_heads=1, index_dim=8,
+                  index_topk=4, first_dense=0, n_routed=E, n_held=E, held_offset=0,
+                  experts_per_tok=K, moe_width=F, n_shared=1)
+
+
+def _weights(key, bias_scale=0.02):
+    ks = jax.random.split(key, 8)
+    n = lambda k, *s: jax.random.normal(k, s, jnp.float32) / np.sqrt(s[-2])
+    return {"router": n(ks[0], D, E), "bias": bias_scale * jax.random.normal(ks[1], (E,)),
+            "w_gate": n(ks[2], E, D, F), "w_up": n(ks[3], E, D, F), "w_down": n(ks[4], E, F, D),
+            "s_gate": n(ks[5], D, F), "s_up": n(ks[6], D, F), "s_down": n(ks[7], F, D)}
+
+
+def _swiglu(x, g, u, d):
+    return (jax.nn.silu(x @ g) * (x @ u)) @ d
+
+
+def _dense_over_experts(lw, x, spec):
+    """Every expert on every token, masked by the routing: the plain form."""
+    idx, wts = held_routing(lw, x, spec)
+    y = jnp.zeros_like(x)
+    for e in range(spec.n_routed):
+        w_e = jnp.sum(jnp.where(idx == e, wts, 0.0), -1, keepdims=True)
+        y += w_e * _swiglu(x, lw["w_gate"][e], lw["w_up"][e], lw["w_down"][e])
+    return y + _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """The guide's share test: each of the members holds E/8 experts, routes
+    over all E and computes its own; their partial sums, the shared expert
+    counted ONCE, are the uncut layer's output."""
+    lw = _weights(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, D))
+    whole = _dense_over_experts(lw, x, SPEC)
+    shared = _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
+    members, held_pairs = 8, 0
+    total = jnp.zeros_like(x)
+    for r in range(members):
+        g = E // members
+        mine = dict(lw, **{k: lw[k][r * g:(r + 1) * g] for k in ("w_gate", "w_up", "w_down")})
+        y, (stats, _) = moe_block_held(mine, x, replace(SPEC, n_held=g, held_offset=r * g))
+        total += y - shared  # every member adds the shared expert: count it once
+        held_pairs += int(stats[1])
+        assert int(stats[0]) == 24 * K
+    assert held_pairs == 24 * K  # every pick fell on exactly one member
+    assert float(jnp.abs(total + shared - whole).max()) <= 1e-5
+
+
+def test_the_bias_selects_and_does_not_weigh():
+    lw = _weights(jax.random.PRNGKey(2), bias_scale=0.0)
+    x = jax.random.normal(jax.random.PRNGKey(3), (64, D))
+    idx0, w0 = held_routing(lw, x, SPEC)
+    np.testing.assert_allclose(np.asarray(w0.sum(-1)), 1.0, rtol=1e-6)
+    pushed = dict(lw, bias=jnp.zeros(E).at[5].set(10.0))
+    idx1, w1 = held_routing(pushed, x, SPEC)
+    assert bool(jnp.all(jnp.any(idx1 == 5, -1)))  # the bias decides the selection
+    s = jax.nn.sigmoid(x @ lw["router"])
+    picked = jnp.take_along_axis(s, idx1, -1)
+    np.testing.assert_allclose(np.asarray(w1), np.asarray(picked / picked.sum(-1, keepdims=True)),
+                               rtol=1e-6)  # ... and is no part of the weights
+
+
+@pytest.mark.parametrize("sizes", [[0, 50, 3, 0, 1, 10], [64, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0],
+                                   [11, 11, 11, 11, 10, 10]])
+def test_grouped_matmul_is_the_per_group_matmul(sizes):
+    """Skewed groups: experts with no rows, one with most; rows past the
+    groups' sum belong to nobody."""
+    g, m, k, n = len(sizes), 64, 8, 12
+    xs = jax.random.normal(jax.random.PRNGKey(4), (m, k))
+    w = jax.random.normal(jax.random.PRNGKey(5), (g, k, n))
+    got = np.asarray(grouped_matmul(xs, w, jnp.asarray(sizes, jnp.int32)))
+    row = 0
+    for e, size in enumerate(sizes):
+        np.testing.assert_allclose(got[row:row + size], np.asarray(xs[row:row + size] @ w[e]),
+                                   rtol=1e-5, atol=1e-5)
+        row += size
+
+
+def test_held_layer_under_skewed_routing_and_padding():
+    """A router pushed so that one held expert takes nearly every token and
+    one takes none, with padding rows masked out: the grouped form equals the
+    dense-over-experts form on the valid rows."""
+    lw = _weights(jax.random.PRNGKey(6))
+    lw["bias"] = lw["bias"].at[2].set(10.0).at[3].set(-10.0)
+    x = jax.random.normal(jax.random.PRNGKey(7), (40, D))
+    valid = jnp.arange(40) < 33
+    spec = replace(SPEC, n_held=8, held_offset=0)
+    mine = dict(lw, **{k: lw[k][:8] for k in ("w_gate", "w_up", "w_down")})
+    y, (stats, picked) = moe_block_held(mine, x, spec, valid)
+    idx, wts = held_routing(lw, x, SPEC)
+    want = _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
+    for e in range(8):
+        w_e = jnp.sum(jnp.where(idx == e, wts, 0.0), -1, keepdims=True)
+        want += w_e * _swiglu(x, lw["w_gate"][e], lw["w_up"][e], lw["w_down"][e])
+    assert float(jnp.abs(y - want)[:33].max()) <= 1e-5
+    assert int(stats[0]) == 33 * K and int(stats[2]) == 33 and int(stats[3]) == 0
+    assert bool(jnp.all(picked == idx))
