@@ -56,6 +56,15 @@ def _qkv(lw, x, cfg: TransformerConfig, ctx=None):
                    kind=kv_kind, ctx=ctx)
     v = serving_mm(x, lw["wv"], lw.get("bv") if cfg.qkv_bias else None,
                    kind=kv_kind, ctx=ctx)
+    # The projections' results exist as [rows, features] before they are
+    # split into heads.  XLA:TPU otherwise folds the reshape into the dot and
+    # wants the weight as [heads, hd, d]: under (8, 128) tiling that is no
+    # bitcast of the stored [d, heads * hd], so EVERY call of every serving
+    # program re-laid wq / wk / wv of every layer (a slice + a transposing
+    # copy each: ~4 ms of an 18 ms decode program at Mistral-7B widths,
+    # PERF.md §6, PR 30).  Behind the barrier the stacks are read in place,
+    # as wo and the MLP stacks (no reshape after their dots) always were.
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     return (
         q.reshape(b, s, hq, hd),
         k.reshape(b, s, hkv, hd),

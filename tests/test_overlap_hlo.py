@@ -2,7 +2,8 @@
 
 Multi-chip hardware isn't available in CI, but the TPU *compiler* is: these
 tests AOT-compile the ZeRO-3 training step, ring attention, the quantized
-TP transport and the pipelined executor against a virtual v5e 2x4 topology
+TP transport, the pipelined executor and (on one chip of it) the five serving
+bodies against a virtual v5e 2x4 topology
 (``jax.experimental.topologies``) and assert overlap/payload properties on
 the scheduled module — through the Graft Auditor's structured parser
 (``deepspeed_tpu.analysis``), NOT by regexing the HLO text.  The parser
@@ -13,13 +14,17 @@ one-module fix instead of a test-suite breakage (the PR 9 class of fix
 stays fixed).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_weight_layout import BODIES, _body, _qkv_plain
 
 from deepspeed_tpu.analysis import check_payload_dtypes, parse_scheduled_hlo
+from deepspeed_tpu.inference import model_runner
+from deepspeed_tpu.models.transformer import TransformerConfig
 
 def _probe_tpu_aot(timeout_s: float) -> bool:
     """Whether the TPU AOT compiler can initialize HERE, bounded in time.
@@ -45,23 +50,23 @@ def _probe_tpu_aot(timeout_s: float) -> bool:
         return False
 
 
-try:
-    from jax.experimental import topologies
-
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e 2x4 slice.  Made here and not while the module is
+    imported: only the worker that is handed this file loads the TPU's
+    library, and every worker collects the same tests."""
     if not _probe_tpu_aot(
             float(os.environ.get("DSTPU_TPU_AOT_PROBE_TIMEOUT_S", "60"))):
-        raise RuntimeError("TPU AOT topology probe failed or timed out")
-    _TOPO = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x4")
-except Exception as e:  # pragma: no cover - environment-dependent
-    _TOPO = None
-    _TOPO_ERR = str(e)
+        pytest.skip("TPU AOT topology probe failed or timed out")
+    try:
+        from jax.experimental import topologies
 
-pytestmark = pytest.mark.skipif(
-    _TOPO is None, reason="TPU AOT topology unavailable"
-)
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x4")
+    except Exception as e:  # pragma: no cover - environment-dependent
+        pytest.skip(f"TPU AOT topology unavailable: {e}")
 
 
-def test_zero3_param_gathers_async_with_compute_between():
+def test_zero3_param_gathers_async_with_compute_between(topo):
     import functools
 
     from deepspeed_tpu.config.config import ZeroConfig
@@ -72,7 +77,7 @@ def test_zero3_param_gathers_async_with_compute_between():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     spec = MeshSpec(fsdp=8)
-    mesh = build_mesh(spec, devices=_TOPO.devices)
+    mesh = build_mesh(spec, devices=topo.devices)
     cfg = get_preset("tiny", num_layers=8)
     model = CausalLM(cfg)
     shapes = jax.eval_shape(
@@ -107,13 +112,13 @@ def test_zero3_param_gathers_async_with_compute_between():
     )
 
 
-def test_ring_attention_permutes_overlap_compute():
+def test_ring_attention_permutes_overlap_compute(topo):
     from deepspeed_tpu.parallel.sharding import set_current_mesh
     from deepspeed_tpu.parallel.topology import MeshSpec, build_mesh
     from deepspeed_tpu.sequence.ring import ring_attention
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = build_mesh(MeshSpec(seq=8), devices=_TOPO.devices)
+    mesh = build_mesh(MeshSpec(seq=8), devices=topo.devices)
     set_current_mesh(mesh)
     try:
         def loss(q, k, v):
@@ -146,7 +151,7 @@ def test_ring_attention_permutes_overlap_compute():
 # ---------------------------------------------------------------------------
 # quantized-collective payloads + tiled-transport overlap (comm/qcomm.py)
 # ---------------------------------------------------------------------------
-def _tp_row_transport_facts(fmt, tiles, kd=4096, nd=4096, B=64):
+def _tp_row_transport_facts(topo, fmt, tiles, kd=4096, nd=4096, B=64):
     """Compile the serving row-parallel matmul region (ops/quantizer.py
     `_shard_mm` 'row') with the given qcomm transport against the virtual
     TPU topology; weights arrive as ARGUMENTS so nothing constant-folds."""
@@ -154,7 +159,7 @@ def _tp_row_transport_facts(fmt, tiles, kd=4096, nd=4096, B=64):
     from deepspeed_tpu.parallel.sharding import set_current_mesh
     from deepspeed_tpu.parallel.topology import MODEL_AXIS, MeshSpec, build_mesh
 
-    mesh = build_mesh(MeshSpec(model=8), devices=_TOPO.devices)
+    mesh = build_mesh(MeshSpec(model=8), devices=topo.devices)
     set_current_mesh(mesh)
     try:
         ctx = Q.ServingContext(mesh=mesh, axis=MODEL_AXIS, size=8,
@@ -180,13 +185,13 @@ def _tp_row_transport_facts(fmt, tiles, kd=4096, nd=4096, B=64):
 
 
 @pytest.mark.slow
-def test_tp_row_transport_int8_payload_on_wire():
+def test_tp_row_transport_int8_payload_on_wire(topo):
     """(a)-criterion, TP half: with ``comm_fmt='int8'`` the row-parallel
     partial-sum transport's wire ops — the EQuARX reduce-scatter
     (all-to-all) and re-quantized all-gather of EVERY tile — carry s8
     payloads, and no full-width f32 partial remains on the wire (any
     remaining f32 collective may only carry scale-sized 1-D operands)."""
-    facts = _tp_row_transport_facts("int8", 4, kd=1024, nd=1024, B=8)
+    facts = _tp_row_transport_facts(topo, "int8", 4, kd=1024, nd=1024, B=8)
     s8_a2a = facts.find(kind="all-to-all", dtype="s8")
     s8_ag = facts.find(kind="all-gather", dtype="s8")
     assert len(s8_a2a) >= 4, f"expected >=4 s8 all-to-alls, got {len(s8_a2a)}"
@@ -201,7 +206,7 @@ def test_tp_row_transport_int8_payload_on_wire():
 
 
 @pytest.mark.slow
-def test_zeropp_quantized_payloads_on_wire():
+def test_zeropp_quantized_payloads_on_wire(topo):
     """(a)-criterion, ZeRO-3 half: the ZeRO++ step's weight all-gathers
     (qwZ) and gradient reduce all_to_alls (qgZ), routed through
     comm/qcomm.py, carry s8 payloads — the weights are quantized at rest
@@ -214,7 +219,7 @@ def test_zeropp_quantized_payloads_on_wire():
     from deepspeed_tpu.runtime.zero import plan_sharding
 
     spec = MeshSpec(fsdp=8)
-    mesh = build_mesh(spec, devices=_TOPO.devices)
+    mesh = build_mesh(spec, devices=topo.devices)
 
     def loss_fn(params, batch, rng):
         h = batch["x"]
@@ -256,7 +261,7 @@ def test_zeropp_quantized_payloads_on_wire():
 
 
 @pytest.mark.slow
-def test_tp_tiled_matmul_collectives_overlap_compute():
+def test_tp_tiled_matmul_collectives_overlap_compute(topo):
     """(b)-criterion, TP half: with ``comm_tiles=4`` the row-parallel
     matmul decomposes into per-tile GEMMs with independent transports, and
     the scheduler asyncs a QUANTIZED wire hop (s8 payload inside an async
@@ -269,7 +274,7 @@ def test_tp_tiled_matmul_collectives_overlap_compute():
     version — the quantized transport is what actually decomposes into
     async-schedulable hops.  That is the EQuARX+T3 composition argument,
     not a regression.)"""
-    facts = _tp_row_transport_facts("int8", 4)
+    facts = _tp_row_transport_facts(topo, "int8", 4)
     assert facts.async_starts >= 1, (
         "no async collective fusion in the tiled int8 transport graph"
     )
@@ -282,7 +287,7 @@ def test_tp_tiled_matmul_collectives_overlap_compute():
     )
 
 
-def _domino_compile_stats(domino):
+def _domino_compile_stats(topo, domino):
     """Compile the TP-8 training graph and measure the synchronous
     all-reduce footprint: count + payload bytes of all-reduces OUTSIDE
     async fusions (those sit on the critical path), plus the async-start
@@ -297,7 +302,7 @@ def _domino_compile_stats(domino):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     spec = MeshSpec(model=8)
-    mesh = build_mesh(spec, devices=_TOPO.devices)
+    mesh = build_mesh(spec, devices=topo.devices)
     cfg = get_preset("tiny", num_layers=8).replace(domino_chunks=domino)
     model = CausalLM(cfg)
     shapes = jax.eval_shape(
@@ -329,7 +334,7 @@ def _domino_compile_stats(domino):
 
 
 @pytest.mark.slow  # heaviest in its area; nightly lane still runs it
-def test_domino_chunks_shrink_synchronous_allreduce_footprint():
+def test_domino_chunks_shrink_synchronous_allreduce_footprint(topo):
     """Domino evidence (r4 VERDICT next #8), RE-MEASURED honestly by the
     typed parser: with domino_chunks=2 the per-chunk dataflows are
     independent, so the scheduler asyncs strictly more collectives
@@ -344,13 +349,13 @@ def test_domino_chunks_shrink_synchronous_allreduce_footprint():
     bytes.  Whole-tuple accounting shows the synchronous payload is
     byte-identical across chunkings (the halves re-fuse); the honest
     guard is that chunking must not GROW the critical-path payload."""
-    base = _domino_compile_stats(1)
-    chunked = _domino_compile_stats(2)
+    base = _domino_compile_stats(topo, 1)
+    chunked = _domino_compile_stats(topo, 2)
     assert chunked["async"] > base["async"], (base, chunked)
     assert chunked["sync_bytes"] <= base["sync_bytes"], (base, chunked)
 
 
-def test_pipeline_permutes_overlap_stage_compute():
+def test_pipeline_permutes_overlap_stage_compute(topo):
     """The pipelined executor's activation ppermutes must compile to
     collective-permute-start/-done pairs with stage compute between (or
     spanning the scan back-edge): tick t+1's transfer overlaps tick t's
@@ -360,7 +365,7 @@ def test_pipeline_permutes_overlap_stage_compute():
     from deepspeed_tpu.parallel.topology import MeshSpec, build_mesh
     from deepspeed_tpu.runtime.pipeline.pipelined import pipeline_apply
 
-    mesh = build_mesh(MeshSpec(stage=8), devices=_TOPO.devices)
+    mesh = build_mesh(MeshSpec(stage=8), devices=topo.devices)
     set_current_mesh(mesh)
     try:
         L, B, s, d = 8, 8, 128, 512
@@ -396,3 +401,66 @@ def test_pipeline_permutes_overlap_stage_compute():
         "no pipeline collective-permute pair had stage compute scheduled "
         "between start and done"
     )
+
+
+# -- the serving programs read their attention weights in place (PR 30) -------
+# XLA:TPU folds ``_qkv``'s split into heads into the projection's dot and then
+# re-lays wq / wk / wv on every call; ``model_runner._qkv`` holds the
+# projections behind an ``optimization_barrier`` (tests/test_weight_layout.py
+# has the CPU half).  One chip of the slice, Mosaic bodies compiled for real.
+# Widths at which the (8, 128) tiling bites as it does at Mistral-7B's: head
+# size 128, d 1024, 8 query / 2 kv heads.  Rows (8 slots, a pack of 128) are
+# chosen so that no activation has as many elements as the smallest weight.
+AOT = dict(slots=8, pages=16, pack=128, bs=32, blocks=64)
+AOT_CFG = dict(vocab_size=1024, hidden_size=1024, intermediate_size=2048, num_layers=2,
+               num_heads=8, num_kv_heads=2, max_seq_len=512, dtype=jnp.bfloat16,
+               attn_impl="auto")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The dispatchers ask the default backend, which is the CPU here: the
+    test steers them to their Mosaic bodies, as the chip would."""
+    import deepspeed_tpu.ops.pallas as dpl
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(dpl, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+
+
+def _weight_copies(one_chip, name):
+    """2-D bf16 ``copy`` results at least as large as the smallest attention
+    weight, in the optimised HLO of ``name`` compiled for one v5e chip."""
+    cfg = TransformerConfig(**AOT_CFG)
+    fn, specs = _body(name, cfg, **AOT, spec=lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    text = jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    floor = cfg.hidden_size * cfg.num_kv_heads * cfg.hd
+    found = re.findall(r"= bf16\[(\d+),(\d+)\]\S* copy\(", text)
+    return [(int(a), int(b)) for a, b in found if int(a) * int(b) >= floor], text
+
+
+@pytest.mark.parametrize("name", BODIES)
+def test_no_serving_program_copies_a_weight(one_chip, as_on_tpu, name):
+    copies, text = _weight_copies(one_chip, name)
+    assert copies == [], copies
+    assert "slice_bitcast_fusion" not in text
+    if name != "prefill":  # one padded prompt of 128 rows: its own gates decide
+        assert "tpu_custom_call" in text, "the Mosaic bodies were not compiled"
+
+
+def test_without_the_barrier_the_compiler_relays_wq_wk_wv(one_chip, as_on_tpu,
+                                                        monkeypatch):
+    """The reason for the barrier, kept as a test: the day this fails the
+    compiler reads the stacks in place by itself and the barrier can go."""
+    monkeypatch.setattr(model_runner, "_qkv", _qkv_plain)
+    copies, _ = _weight_copies(one_chip, "decode_step")
+    cfg = TransformerConfig(**AOT_CFG)
+    d, kv = cfg.hidden_size, cfg.num_kv_heads * cfg.hd
+    assert sorted(set(copies)) == [(kv, d), (d, d)], copies
