@@ -9,6 +9,14 @@ padded to the next bucket) + one batched decode kernel over the fixed slot
 array — with host-side block bookkeeping (ragged.py) driving them, the
 Dynamic-SplitFuse-style fixed token budget replaced by one-prefill-per-put
 + batched decode ticks.
+
+The engine chooses its RUNNER once, at construction (``self.runner``:
+``model_runner.DenseRunner``, or ``latent_runner.LatentRunner`` for
+``cfg.latent``), and asks it for everything that depends on the kind of
+layer state: the cache, the pack / tick / verify entries its jitted programs
+call, whether a cold pack has a program of its own, and the kind's host
+accounting (extra ``stats`` counters, a dispatch's span arguments, what a
+released slot gives back, what ``close()`` audits).
 """
 from __future__ import annotations
 
@@ -21,7 +29,7 @@ import numpy as np
 from ..models.transformer import TransformerConfig
 from ..utils.logging import log_dist
 from . import model_runner
-from .paged import init_paged_cache, kv_pool_pspec
+from .paged import kv_pool_pspec
 from .ragged import StateManager
 from .sampling import SamplingParams, finite_guard, sample
 
@@ -30,21 +38,6 @@ from .sampling import SamplingParams, finite_guard, sample
 # distinct from the -1 finite_guard poison sentinel (which is a real
 # emission — always a row's LAST — that the host must see to quarantine)
 _BURST_PAD = -2
-# extra ``stats`` keys of an engine whose model has layers of several kinds
-# (cfg.latent): what its selectors, windows and router did.  The causal keys
-# and the ring rows follow from positions and are counted on the host at
-# dispatch; the keys SELECTED and the routing four are counted on the device
-# (latent_runner's ``picks`` and ROUTING_STATS) and read by
-# ``refresh_routing_stats()``, which ``close()`` calls.
-LATENT_COUNTERS = (
-    "index_keys_scored",      # (query, key) pairs the indexers scored: causal keys
-    "index_keys_selected",    # ... and pairs the selectors took (device count)
-    "window_rows_discarded",  # ring rows that fell out of a window
-    "expert_pairs_routed",    # (token, expert) pairs the routers picked
-    "expert_pairs_held",      # ... that fell on experts held here
-    "expert_group_rows_max",  # rows of the largest held expert's group in a pack
-    "expert_group_rows_min",  # ... and of the smallest
-)
 
 
 def _bucket(n: int, buckets: Sequence[int]) -> int:
@@ -110,11 +103,15 @@ class InferenceEngineV2:
                 "(falcon/gptj/phi layout): the runner wires sequential "
                 "attn_norm/mlp_norm blocks — use init_inference instead"
             )
+        # The ONE place that chooses the runner: everything below asks
+        # ``self.runner`` (the device entries its programs call, its cache,
+        # its host accounting), never ``cfg.latent``.
         if cfg.latent is not None:
             # layers of several kinds (models/latent.py) keep two kinds of
             # state (latent pages, a window ring per slot): what would serve
             # them wrongly is refused by the name of its option
             from ..models.latent import refuse
+            from .latent_runner import LatentRunner
 
             for option, on, why in (
                 ("grid (a tensor-parallel / replica / seq-shard serve mesh)",
@@ -130,6 +127,9 @@ class InferenceEngineV2:
             ):
                 if on:
                     refuse(option, why)
+            self.runner = LatentRunner(cfg)
+        else:
+            self.runner = model_runner.DenseRunner(cfg)
         # 2-D batch x model serve mesh: ``serve_replicas`` > 1 partitions
         # slots and KV blocks into per-replica groups laid out over the
         # mesh's batch (data) axis — explicit opt-in, because leftover mesh
@@ -430,21 +430,11 @@ class InferenceEngineV2:
         self.prefill_budget = min(
             prefill_budget or self.prefill_buckets[-1], self.prefill_buckets[-1]
         )
-        if cfg.latent is not None:
-            from . import latent_runner
-
-            self.kv = latent_runner.init_cache(
-                cfg, num_blocks, block_size, max_seqs, self.prefill_buckets[-1])
-            # host mirror of the rings: positions each slot's ring has taken
-            # (a ring is not allocated, so this is what ``close()`` audits)
-            self._ring_rows = np.zeros(max_seqs, np.int64)
-            self.mgr.release_hook = self._release_ring
-            self._c.update(self.telemetry.counters(self._ns, LATENT_COUNTERS))
-        else:
-            self.kv = init_paged_cache(
-                cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads, cfg.hd,
-                dtype=cfg.dtype,
-            )
+        runner = self.runner
+        self.kv = runner.init_cache(
+            num_blocks, block_size, max_seqs, self.prefill_buckets[-1])
+        self.mgr.release_hook = runner.released
+        self._c.update(self.telemetry.counters(self._ns, runner.counters))
         self._kv_shardings = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding
@@ -487,7 +477,7 @@ class InferenceEngineV2:
         # whole SamplingParams would recompile on max_new_tokens/stop_token
         def packed_impl(params, tokens, seg, pos, pack_pages, last_idx,
                         kv, rng, sampling_triple):
-            logits, kv = model_runner.prefill_packed(
+            logits, kv = runner.prefill_packed(
                 params, cfg_, tokens, seg, pos, pack_pages, last_idx, kv,
                 ctx=ctx_, mesh=mesh_,
             )
@@ -504,7 +494,7 @@ class InferenceEngineV2:
             """Context-aware variant: suffix tokens attend over each
             sequence's cached KV pages (prefix-cache hits, chunked-prefill
             continuation chunks).  Cold packs stay on ``packed_impl``."""
-            logits, kv = model_runner.prefill_packed_ctx(
+            logits, kv = runner.prefill_packed_ctx(
                 params, cfg_, tokens, seg, pos, pack_pages, last_idx,
                 ctx_tables, ctx_lens, kv, ctx=ctx_, mesh=mesh_, dp=dp_,
                 seq_shards=sq_,
@@ -521,14 +511,11 @@ class InferenceEngineV2:
             cv = tuple(c.at[dst].set(c[src]) for c in cv)
             return ck, cv
 
-        def decode_impl(params, tokens, seq_lens, block_tables, active, kv,
-                        rng, sampling_triple):
-            """One decode tick as a pure device-chained transition: tokens,
-            seq_lens and the rng key all arrive AND return as device arrays,
-            so a burst (step_n) enqueues n dispatches with ZERO per-tick
-            host->device uploads — the host's only per-tick work is the
-            dispatch call itself."""
-            logits, kv = model_runner.decode_step(
+        def decode_sample(params, tokens, seq_lens, block_tables, active, kv,
+                          rng, sampling_triple):
+            """The forward and the guarded sample a decode tick and a burst
+            tick share: (sampled, rng carried on, kv)."""
+            logits, kv = runner.decode_step(
                 params, cfg_, tokens, seq_lens, block_tables, active, kv,
                 ctx=ctx_, mesh=mesh_, dp=dp_, seq_shards=sq_,
             )
@@ -537,6 +524,18 @@ class InferenceEngineV2:
             sampled = finite_guard(
                 logits, sample(logits, SamplingParams(t, k, p), sub)
             )
+            return sampled, rng, kv
+
+        def decode_impl(params, tokens, seq_lens, block_tables, active, kv,
+                        rng, sampling_triple):
+            """One decode tick as a pure device-chained transition: tokens,
+            seq_lens and the rng key all arrive AND return as device arrays,
+            so a burst (step_n) enqueues n dispatches with ZERO per-tick
+            host->device uploads — the host's only per-tick work is the
+            dispatch call itself."""
+            sampled, rng, kv = decode_sample(
+                params, tokens, seq_lens, block_tables, active, kv, rng,
+                sampling_triple)
             return sampled, seq_lens + 1, rng, kv
 
         def decode_burst_impl(params, tokens, seq_lens, block_tables, active,
@@ -563,15 +562,9 @@ class InferenceEngineV2:
             poison sentinel can only ever be a row's LAST emission).  The
             host keeps references ONLY to the latest outputs, so earlier
             ticks' token arrays free as soon as their consumer ran."""
-            logits, kv = model_runner.decode_step(
-                params, cfg_, tokens, seq_lens, block_tables, active, kv,
-                ctx=ctx_, mesh=mesh_, dp=dp_, seq_shards=sq_,
-            )
-            t, k, p = sampling_triple
-            rng, sub = jax.random.split(rng)
-            sampled = finite_guard(
-                logits, sample(logits, SamplingParams(t, k, p), sub)
-            )
+            sampled, rng, kv = decode_sample(
+                params, tokens, seq_lens, block_tables, active, kv, rng,
+                sampling_triple)
             act_i = active.astype(jnp.int32)
             emit = jnp.where(active, sampled, jnp.int32(_BURST_PAD))
             burst = jax.lax.dynamic_update_index_in_dim(
@@ -606,7 +599,7 @@ class InferenceEngineV2:
             are rolled back host-side by the allocator's truncate path."""
             from .sampling import spec_verify_sample
 
-            logits, kv = model_runner.verify_packed_ctx(
+            logits, kv = runner.verify_packed_ctx(
                 params, cfg_, tokens, seg, pos, dst_pages, dst_offs,
                 ctx_tables, ctx_lens, kv, ctx=ctx_, mesh=mesh_, dp=dp_,
                 seq_shards=sq_,
@@ -622,6 +615,25 @@ class InferenceEngineV2:
             # by a garbage forward is not partially trustworthy
             return finite_guard(logits, out), n_out, kv
 
+        # The six programs, built ONCE from one table: (attribute, impl, its
+        # jax.jit options, the KV pool's place among n results, and among the
+        # arguments after ``params``: None for a program that takes no
+        # weights).  stop_rows / max_emit of the burst are NOT donated: the
+        # same device arrays feed every tick.
+        table = (
+            ("_packed_prefill_jit", packed_impl,
+             dict(donate_argnums=(6,), static_argnums=(8,)), 1, 2, 5),
+            ("_packed_prefill_ctx_jit", packed_ctx_impl,
+             dict(donate_argnums=(8,), static_argnums=(10,)), 1, 2, 7),
+            ("_cow_jit", cow_impl, dict(donate_argnums=(0,)), 0, 1, None),
+            ("_decode_jit", decode_impl,
+             dict(donate_argnums=(2, 5, 6), static_argnums=(7,)), 3, 4, 4),
+            ("_decode_burst_jit", decode_burst_impl,
+             dict(donate_argnums=(2, 4, 5, 6, 7, 8, 9), static_argnums=(12,)),
+             3, 8, 4),
+            ("_spec_jit", spec_impl,
+             dict(donate_argnums=(11,), static_argnums=(13, 14)), 2, 3, 10),
+        )
         if self._mesh is not None:
             # pin the result shardings so the KV pool STAYS sharded across
             # ticks (donation then reuses the buffers in place) and sampled
@@ -635,65 +647,19 @@ class InferenceEngineV2:
             # input layout (it propagates the 2-D mesh attention specs) and
             # the donor/output aliasing then fails on the size mismatch
             self._rep_sharding = rep
-            self._packed_prefill_jit = jax.jit(
-                packed_impl, donate_argnums=(6,), static_argnums=(8,),
-                out_shardings=(rep, self._kv_shardings),
-            )
-            self._packed_prefill_ctx_jit = jax.jit(
-                packed_ctx_impl, donate_argnums=(8,), static_argnums=(10,),
-                out_shardings=(rep, self._kv_shardings),
-            )
-            self._cow_jit = jax.jit(
-                cow_impl, donate_argnums=(0,), out_shardings=self._kv_shardings,
-            )
-            self._decode_jit = jax.jit(
-                decode_impl, donate_argnums=(2, 5, 6), static_argnums=(7,),
-                out_shardings=(rep, rep, rep, self._kv_shardings),
-            )
-            # stop_rows/max_emit are NOT donated: the same device arrays
-            # feed every tick of a burst
-            self._decode_burst_jit = jax.jit(
-                decode_burst_impl, donate_argnums=(2, 4, 5, 6, 7, 8, 9),
-                static_argnums=(12,),
-                out_shardings=(rep, rep, rep, self._kv_shardings, rep, rep,
-                               rep, rep),
-            )
-            self._spec_jit = jax.jit(
-                spec_impl, donate_argnums=(11,), static_argnums=(13, 14),
-                out_shardings=(rep, rep, self._kv_shardings),
-            )
-        else:
-            self._packed_prefill_jit = self._wrap_offload(
-                jax.jit(packed_impl, donate_argnums=(6,), static_argnums=(8,)),
-                kv_rest_idx=5,
-            )
-            self._packed_prefill_ctx_jit = self._wrap_offload(
-                jax.jit(packed_ctx_impl, donate_argnums=(8,),
-                        static_argnums=(10,)),
-                kv_rest_idx=7,
-            )
-            self._cow_jit = jax.jit(cow_impl, donate_argnums=(0,))
-            self._decode_jit = self._wrap_offload(
-                jax.jit(
-                    decode_impl, donate_argnums=(2, 5, 6), static_argnums=(7,)
-                ),
-                kv_rest_idx=4,
-            )
-            self._decode_burst_jit = self._wrap_offload(
-                jax.jit(
-                    decode_burst_impl, donate_argnums=(2, 4, 5, 6, 7, 8, 9),
-                    static_argnums=(12,),
-                ),
-                kv_rest_idx=4,
-            )
-            self._spec_jit = self._wrap_offload(
-                jax.jit(spec_impl, donate_argnums=(11,),
-                        static_argnums=(13, 14)),
-                kv_rest_idx=10,
-            )
+        for name, impl, opts, kv_out, n_out, kv_rest_idx in table:
+            if self._mesh is not None:
+                outs = (rep,) * kv_out + (self._kv_shardings,) \
+                    + (rep,) * (n_out - kv_out - 1)
+                opts["out_shardings"] = outs if n_out > 1 else outs[0]
+            jitted = jax.jit(impl, **opts)
+            if kv_rest_idx is not None:
+                # (no weights are host-resident under a mesh: a no-op there)
+                jitted = self._wrap_offload(jitted, kv_rest_idx)
+            setattr(self, name, jitted)
 
         self._tracked = {}
-        if cfg.latent is not None:
+        if runner.scoped_programs:
             # the device trace is attributed to these programs' named scopes
             # (telemetry.program_scopes): keep the shapes they compiled for
             from ..telemetry import programs
@@ -991,10 +957,8 @@ class InferenceEngineV2:
                     f"prefill bucket {C} must be a multiple of block_size {bs}"
                 )
             t_pad = C * dp
-            # layers of several kinds read a pack's own rows back from the
-            # cache, so cold packs and context packs are ONE program
             use_ctx = any(start > 0 for _, start, _ in entries) \
-                or self.cfg.latent is not None
+                or self.runner.packs_are_one_program
             tokens = np.zeros(t_pad, np.int32)
             seg = np.zeros(t_pad, np.int32)
             pos = np.zeros(t_pad, np.int32)
@@ -1025,12 +989,8 @@ class InferenceEngineV2:
             n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
             # live context pages the ctx kernel walks: its time over this
             ctx_pages = int((-(-ctx_lens // bs)).sum())
-            extra = {}
-            if self.cfg.latent is not None:
-                extra = self._count_latent(
-                    [(start, end) for _, start, end in entries])
-                for s, _, end in entries:
-                    self._ring_rows[s.slot] = end
+            extra = self.runner.dispatched(
+                self._c, ((s.slot, start, end) for s, start, end in entries))
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
@@ -1092,47 +1052,12 @@ class InferenceEngineV2:
                     out[s.uid] = tok
                 self.mgr.update_hashes(s)
 
-    # -- layers of several kinds (cfg.latent) ---------------------------------
-    def _count_latent(self, ranges) -> Dict[str, int]:
-        """What the selectors and windows are ASKED to do with queries at
-        positions ``[start, end)`` of each range, all layers: the dispatch's
-        span arguments.  The causal keys and the ring rows are counted into
-        ``stats`` here; the keys selected are counted where they are selected
-        (``refresh_routing_stats``), so that count moves if a selector
-        breaks, and a sound run's equals the sum of these arguments."""
-        s = self.cfg.latent
-        topk, win = s.index_topk, s.sliding.window
-        scored = selected = dropped = 0
-        for a, b in ranges:
-            scored += (b * (b + 1) - a * (a + 1)) // 2  # sum of p + 1
-            m = min(max(a, topk), b)  # from position m on, topk of p + 1 keys
-            selected += (m * (m + 1) - a * (a + 1)) // 2 + (b - m) * topk
-            dropped += max(b - max(a, win), 0)  # position p overwrites p - win
-        out = {"index_keys_scored": scored * s.count("full"),
-               "index_keys_selected": selected * s.count("full"),
-               "window_rows_discarded": dropped * s.count("sliding")}
-        for k in ("index_keys_scored", "window_rows_discarded"):
-            self._c[k].inc(out[k])
-        return out
-
-    def _release_ring(self, seq) -> None:
-        self._ring_rows[seq.slot] = 0
-
     def refresh_routing_stats(self) -> None:
-        """Fetch the selectors' and routers' device-side counts into ``stats``
-        (two small device->host copies; call it outside a timed window's hot
-        loop)."""
-        if self.cfg.latent is None or self.kv is None:
-            return
-        from .latent_runner import picks_total
-
-        self._c["index_keys_selected"].set(picks_total(self.kv["picks"]))
-        st = np.asarray(self.kv["stats"]).astype(np.int64)
-        if st.size:
-            self._c["expert_pairs_routed"].set(int(st[:, 0].sum()))
-            self._c["expert_pairs_held"].set(int(st[:, 1].sum()))
-            self._c["expert_group_rows_max"].set(int(st[:, 2].max()))
-            self._c["expert_group_rows_min"].set(int(st[:, 3].min()))
+        """Fetch the runner's device-side counts (a ``cfg.latent`` model's
+        selectors and routers) into ``stats``: small device->host copies;
+        call it outside a timed window's hot loop."""
+        if self.kv is not None:
+            self.runner.refresh_stats(self._c, self.kv)
 
     def _set_block_table(self, seq) -> None:
         row = self._tables_np[seq.slot]
@@ -1558,12 +1483,8 @@ class InferenceEngineV2:
                 ctx_tokens += s.cur_len
             self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
             self._rng, sub = jax.random.split(self._rng)
-            extra = {}
-            if self.cfg.latent is not None:
-                extra = self._count_latent(
-                    [(s.cur_len - 1, s.cur_len) for s in active_seqs])
-                for s in active_seqs:
-                    self._ring_rows[s.slot] = s.cur_len
+            extra = self.runner.dispatched(
+                self._c, ((s.slot, s.cur_len - 1, s.cur_len) for s in active_seqs))
         # decode_tick_ms is uploads + dispatch + fetch: the argument uploads
         # (tokens, lengths, tables, key) belong inside the span
         with tel.span(
@@ -2024,11 +1945,8 @@ class InferenceEngineV2:
             # post-audit identity: every block is free, cached, or held
             in_use += a.total_blocks - a.free_blocks - a.cached_blocks
             cached += a.cached_blocks
-        self._close_audit = {"blocks_in_use": in_use, "cached_blocks": cached}
-        if self.cfg.latent is not None:
-            # rows of window state still owned by a sequence (a ring is
-            # nobody's once its slot is released)
-            self._close_audit["window_rows"] = int(self._ring_rows.sum())
+        self._close_audit = {"blocks_in_use": in_use, "cached_blocks": cached,
+                             **self.runner.audit()}
         self.telemetry.flush()
         for ns in (self._ns, self._sched_ns, self._comm_ns):
             self.telemetry.release_prefix(ns)

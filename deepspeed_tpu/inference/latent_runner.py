@@ -24,6 +24,11 @@ as are chunks of one prompt and several prompts in one pack: work is laid out
 in groups of one page of one sequence.  Plain XLA bodies
 (``ops/latent_attention.py``); the expert layer's grouped matmul is a Pallas
 kernel on the chip (``moe/layer.py``).
+
+``LatentRunner`` is what ``InferenceEngineV2`` holds for such a model (as
+``model_runner.DenseRunner`` for a dense one): these entries under the names
+the engine's programs call, and the kind's host accounting beside the cache it
+describes (``COUNTERS``, the rings' host mirror).
 """
 from __future__ import annotations
 
@@ -31,6 +36,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models import latent as lm
 from ..ops import latent_attention as la
@@ -44,6 +50,20 @@ Cache = Dict[str, Any]
 ROUTING_STATS = ("pairs_routed", "pairs_held", "group_rows_max", "group_rows_min")
 _NO_MIN = 1 << 30
 _CARRY = 30  # bits of the low word of cache["picks"]
+# extra ``stats`` keys of an engine that serves such a model: what its
+# selectors, windows and router did.  The causal keys and the ring rows follow
+# from positions and are counted on the host at dispatch; the keys SELECTED
+# and the routing four are counted on the device (``picks``, ROUTING_STATS)
+# and read by ``refresh_routing_stats()``, which ``close()`` calls.
+COUNTERS = (
+    "index_keys_scored",      # (query, key) pairs the indexers scored: causal keys
+    "index_keys_selected",    # ... and pairs the selectors took (device count)
+    "window_rows_discarded",  # ring rows that fell out of a window
+    "expert_pairs_routed",    # (token, expert) pairs the routers picked
+    "expert_pairs_held",      # ... that fell on experts held here
+    "expert_group_rows_max",  # rows of the largest held expert's group in a pack
+    "expert_group_rows_min",  # ... and of the smallest
+)
 
 
 def _lanes(width: int) -> int:
@@ -89,8 +109,6 @@ def _tally(picks, new):
 
 def picks_total(picks) -> int:
     """The count ``cache["picks"]`` holds, all layers (host side)."""
-    import numpy as np
-
     p = np.asarray(picks).astype(np.int64)
     return int((p[:, 0] << _CARRY).sum() + p[:, 1].sum())
 
@@ -321,12 +339,94 @@ def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cach
     return _logits(params, cfg, x), cache
 
 
-def tables_of_pack(segment_ids, positions, pack_pages, n_slots: int, n_pages: int,
-                   block_size: int):
-    """Block tables [N, P] of a COLD pack, which is handed none: every page
-    of the pack is page ``position // block_size`` of its sequence."""
-    slot = segment_ids[::block_size] - 1
-    page = positions[::block_size] // block_size
-    at = jnp.where((slot >= 0) & (pack_pages >= 0), slot, n_slots)
-    return jnp.full((n_slots, n_pages), -1, jnp.int32).at[at, page].set(
-        pack_pages, mode="drop")
+def _one_chip_only(ctx, mesh, dp: int, seq_shards: int) -> None:
+    """A model with layers of several kinds runs on one chip, unsharded."""
+    if mesh is not None or (ctx is not None and ctx.size > 1):
+        lm.refuse("a tensor-parallel serve mesh (grid)", "its weights and caches have "
+                  "no sharding rules yet")
+    if dp > 1:
+        lm.refuse("serve_replicas > 1", "its caches are not partitioned by replica")
+    if seq_shards > 1:
+        lm.refuse("seq_shards > 1", "its caches are not striped over a seq axis")
+
+
+class LatentRunner:
+    """``model_runner.DenseRunner``'s surface for ``cfg.latent``: the entries
+    above under the dense entries' names and arguments, and the host side of
+    the kind's state."""
+
+    counters = COUNTERS
+    packs_are_one_program = True  # a pack reads its own rows back from the cache
+    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self._ring_rows = np.zeros(0, np.int64)
+
+    def init_cache(self, num_blocks, block_size, max_seqs, pack_tokens) -> Cache:
+        # host mirror of the rings: positions each slot's ring has taken (a
+        # ring is not allocated, so this is what ``close()`` audits)
+        self._ring_rows = np.zeros(max_seqs, np.int64)
+        return init_cache(self.cfg, num_blocks, block_size, max_seqs, pack_tokens)
+
+    def prefill_packed(self, *args, **kw):
+        lm.refuse("a cold pack's own program (prefill_packed)", "a pack reads its own "
+                  "rows back from the cache: use prefill_packed_ctx")
+
+    def prefill_packed_ctx(self, params, cfg, tokens, segment_ids, positions, pack_pages,
+                           last_idx, ctx_tables, ctx_lens, kv_cache, ctx=None, mesh=None,
+                           dp: int = 1, seq_shards: int = 1):
+        _one_chip_only(ctx, mesh, dp, seq_shards)
+        return prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages,
+                            last_idx, ctx_tables, kv_cache)
+
+    def verify_packed_ctx(self, *args, **kw):
+        lm.refuse("enable_speculation (verify_packed_ctx)", "a rejected draft's rows "
+                  "cannot be rolled back out of a sliding layer's ring")
+
+    def decode_step(self, params, cfg, tokens, seq_lens, block_tables, active, kv_cache,
+                    ctx=None, mesh=None, dp: int = 1, seq_shards: int = 1):
+        _one_chip_only(ctx, mesh, dp, seq_shards)
+        return decode_step(params, cfg, tokens, seq_lens, block_tables, active, kv_cache)
+
+    def dispatched(self, counters, work) -> Dict[str, int]:
+        """What the selectors and windows are ASKED to do with queries at
+        positions ``[start, end)`` of each (slot, start, end) of ``work``, all
+        layers: the dispatch's span arguments.  The causal keys and the ring
+        rows are counted into ``counters`` here; the keys selected are counted
+        where they are selected (``refresh_stats``), so that count moves if a
+        selector breaks, and a sound run's equals the sum of these arguments."""
+        s = self.cfg.latent
+        topk, win = s.index_topk, s.sliding.window
+        scored = selected = dropped = 0
+        for slot, a, b in work:
+            scored += (b * (b + 1) - a * (a + 1)) // 2  # sum of p + 1
+            m = min(max(a, topk), b)  # from position m on, topk of p + 1 keys
+            selected += (m * (m + 1) - a * (a + 1)) // 2 + (b - m) * topk
+            dropped += max(b - max(a, win), 0)  # position p overwrites p - win
+            self._ring_rows[slot] = b
+        out = {"index_keys_scored": scored * s.count("full"),
+               "index_keys_selected": selected * s.count("full"),
+               "window_rows_discarded": dropped * s.count("sliding")}
+        for k in ("index_keys_scored", "window_rows_discarded"):
+            counters[k].inc(out[k])
+        return out
+
+    def released(self, seq) -> None:
+        self._ring_rows[seq.slot] = 0
+
+    def audit(self) -> Dict[str, int]:
+        """Rows of window state still owned by a sequence (a ring is nobody's
+        once its slot is released)."""
+        return {"window_rows": int(self._ring_rows.sum())}
+
+    def refresh_stats(self, counters, kv: Cache) -> None:
+        """The selectors' and routers' device-side counts into ``counters``
+        (two small device->host copies)."""
+        counters["index_keys_selected"].set(picks_total(kv["picks"]))
+        st = np.asarray(kv["stats"]).astype(np.int64)
+        if st.size:
+            counters["expert_pairs_routed"].set(int(st[:, 0].sum()))
+            counters["expert_pairs_held"].set(int(st[:, 1].sum()))
+            counters["expert_group_rows_max"].set(int(st[:, 2].max()))
+            counters["expert_group_rows_min"].set(int(st[:, 3].min()))

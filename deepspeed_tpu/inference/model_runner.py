@@ -1,17 +1,29 @@
-"""Inference model runner: prefill + batched decode against the paged cache.
+"""Inference model runner for dense GQA models: the prefill packs, the
+speculative verify pack and the batched decode tick against the paged cache.
 
 The analogue of the reference's per-family inference model implementations
-(``inference/v2/model_implementations/llama_v2`` etc.) — but one generic
-runner covers every ``TransformerConfig`` family, because architecture
-switches live in the config, not in code.  Reuses the training model's
-building blocks (norm / rope / mlp_block / moe_block) with its own attention
-wiring, mirroring how the reference keeps training and inference model code
-separate (module_inject containers vs training nn.Modules).
+(``inference/v2/model_implementations/llama_v2`` etc.), but one generic
+runner covers every ``TransformerConfig`` family: architecture switches live
+in the config.  It reuses the training model's norm / rope with its own
+attention wiring.
+
+The decoder block is written ONCE (``_layer``) and looped over ONCE
+(``_layers``).  An entry is what differs and nothing else: how its tokens are
+embedded and positioned (``[1, T, d]`` for a pack, ``[B, 1, d]`` for the
+tick), how a layer's new K/V rows are written into its pools (``write``),
+which attention reads them (``read``), and which rows go to the head.
+``latent_runner.py`` has the same shape for ``cfg.latent``; the engine picks
+one of the two (``DenseRunner`` here, ``LatentRunner`` there) once.
+
+Every entry takes ``params`` (the STACKED tree), ``kv_cache`` ((K pools, V
+pools), per-layer tuples), ``ctx`` (ops.quantizer ``ServingContext``: the TP /
+fused serving policy), ``mesh`` (the serve mesh: the attention kernels run per
+shard under it, see paged.py), ``dp`` (batch-axis replicas: a pack arrives as
+``dp`` chunks, rows in slot order), ``seq_shards`` (seq-axis pool slices).
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -21,22 +33,19 @@ from ..models.transformer import (
     _activation,
     head_bias_vec,
     head_kernel,
-    mlp_block,
     norm,
     rope,
 )
 from ..ops.pallas.flash_attention import flash_attention
 from ..ops.quantizer import serving_mm
 from .paged import (
+    init_paged_cache,
     paged_attention_decode,
     paged_attention_packed_ctx,
     write_decode_kv,
-    write_prefill_kv,
+    write_pack_kv,
     write_spec_kv,
 )
-
-Params = Any
-
 
 def _qkv(lw, x, cfg: TransformerConfig, ctx=None):
     b, s, d = x.shape
@@ -70,19 +79,6 @@ def _qkv(lw, x, cfg: TransformerConfig, ctx=None):
         k.reshape(b, s, hkv, hd),
         v.reshape(b, s, hkv, hd),
     )
-
-
-def _latent_only(ctx, mesh, dp: int = 1, seq_shards: int = 1) -> None:
-    """A model with layers of several kinds runs on one chip, unsharded."""
-    from ..models.latent import refuse
-
-    if mesh is not None or (ctx is not None and ctx.size > 1):
-        refuse("a tensor-parallel serve mesh (grid)", "its weights and caches have "
-               "no sharding rules yet")
-    if dp > 1:
-        refuse("serve_replicas > 1", "its caches are not partitioned by replica")
-    if seq_shards > 1:
-        refuse("seq_shards > 1", "its caches are not striped over a seq axis")
 
 
 def _ffn(lw, x, cfg, ctx=None):
@@ -125,382 +121,232 @@ def _lm_logits(params, cfg, x, ctx=None):
     return logits.astype(jnp.float32)
 
 
-def _embed(params, cfg, x):
-    """Post-embedding layernorm (bloom-style ``embedding_norm``)."""
+def _embed(params, cfg, tokens, positions, axis: int):
+    """Token embeddings (+ learned positions, + bloom's ``embedding_norm``),
+    the rows' new axis at ``axis``: [1, T, d] for a pack, [B, 1, d] for the
+    tick (a reshape near a dot moves XLA:TPU's layouts: PERF.md §6, PR 30)."""
+    rows = lambda a: jnp.expand_dims(a, axis)
+    x = rows(params["embed"]["embedding"][tokens]).astype(cfg.dtype)
+    if cfg.position == "learned":
+        x = x + rows(params["pos_embed"]["embedding"][
+            jnp.clip(positions, 0, cfg.max_seq_len - 1)
+        ]).astype(cfg.dtype)
     if cfg.embedding_norm:
         x = norm(x, params["embed_norm"], cfg.norm, cfg.norm_eps)
     return x
 
 
-def prefill(
-    params: Params,
-    cfg: TransformerConfig,
-    tokens: jnp.ndarray,  # [s_pad] int32 (one sequence, padded)
-    length: jnp.ndarray,  # scalar — true prompt length
-    blocks: jnp.ndarray,  # [n_pages] int32, -1 padded
-    kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
-    ctx=None,  # ops.quantizer.ServingContext — TP/fused serving policy
-    mesh=None,  # serve mesh: the flash kernel runs per shard under it
-):
-    """Run the prompt, write its KV pages, return (logits_at_last, caches).
-
-    Dense causal attention over the padded prompt (padding masked by
-    causality + the final gather at ``length - 1``).
-    """
+def _dense_only(cfg, what: str, why: str = "latent_runner.py serves it"):
+    """``cfg.latent`` is refused here, never dispatched on: which runner
+    serves a model is the engine's choice."""
     if cfg.latent is not None:
         from ..models.latent import refuse
 
-        refuse("prefill (one padded prompt)", "the engine packs every prompt; "
-               "use prefill_packed")
-    s = tokens.shape[0]
-    x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,s,d]
-    positions = jnp.arange(s)[None]
-    if cfg.position == "learned":
-        x = x + params["pos_embed"]["embedding"][jnp.arange(s)][None].astype(cfg.dtype)
-    x = _embed(params, cfg, x)
-    ck, cv = kv_cache
-    # python loop over layers: each layer writes its cache page slab.
-    # (L is static; unrolled trace is fine for inference graphs).  The KV
-    # pools are per-layer tuples — updates replace one layer's buffer
-    # in-place under donation, never a stacked-pool slice copy.
-    new_ck, new_cv = list(ck), list(cv)
+        refuse(what, why)
+
+
+def _layer(cfg, lw, x, positions, kv_l, write, read, ctx):
+    """One decoder block on ``x`` ([1, T, d] or [B, 1, d]).  The seam:
+    ``write(kv_l, k, v)`` returns the layer's (K pool, V pool) with the new
+    rows in, ``read(q, k, v, kv_l)`` attends (K/V are written BEFORE the
+    attention reads the post-write pools).  Returns (x, kv_l)."""
+    h = norm(x, lw["attn_norm"], cfg.norm, cfg.norm_eps)
+    q, k, v = _qkv(lw["attn"], h, cfg, ctx)
+    if cfg.position == "rope":
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    kv_l = write(kv_l, k, v)
+    attn = read(q, k, v, kv_l)
+    attn = _attn_out(lw["attn"], attn.reshape(*x.shape[:2], -1), ctx)
+    x = x + attn.astype(x.dtype)
+    h = norm(x, lw["mlp_norm"], cfg.norm, cfg.norm_eps)
+    return x + _ffn(lw, h, cfg, ctx).astype(x.dtype), kv_l
+
+
+def _layers(params, cfg, x, positions, kv_cache, write, read, ctx):
+    """Every layer and the final norm: a python loop (L is static) over the
+    STACKED parameter tree; the KV pools are per-layer tuples, so an update
+    replaces one layer's buffer in place under donation, never a slice copy."""
+    new_ck, new_cv = map(list, kv_cache)
     for l in range(cfg.num_layers):
         lw = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-        h = norm(x, lw["attn_norm"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(lw["attn"], h, cfg, ctx)
-        if cfg.position == "rope":
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        new_ck[l] = write_prefill_kv(
-            new_ck[l], k[0].astype(new_ck[l].dtype), blocks, length
-        )
-        new_cv[l] = write_prefill_kv(
-            new_cv[l], v[0].astype(new_cv[l].dtype), blocks, length
-        )
-        # dispatcher: Pallas flash kernel on TPU when the shape qualifies
-        # (prompt >= 128, tile-divisible), else the fused XLA body — serving
-        # prefill is exactly where the kernel's MXU efficiency pays
-        attn = flash_attention(
-            q, k, v, causal=True, logits_soft_cap=cfg.logits_soft_cap,
-            mesh=mesh,
-        )
-        attn = _attn_out(lw["attn"], attn.reshape(1, s, -1), ctx)
-        x = x + attn.astype(x.dtype)
-        h = norm(x, lw["mlp_norm"], cfg.norm, cfg.norm_eps)
-        x = x + _ffn(lw, h, cfg, ctx).astype(x.dtype)
-
+        x, (new_ck[l], new_cv[l]) = _layer(
+            cfg, lw, x, positions, (new_ck[l], new_cv[l]), write, read, ctx)
     x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    last = x[0, jnp.clip(length - 1, 0, s - 1)]  # [d]
-    logits = _lm_logits(params, cfg, last, ctx)  # [v]
-    return logits, (tuple(new_ck), tuple(new_cv))
+    return x, (tuple(new_ck), tuple(new_cv))
+
+
+def _read_ctx(cfg, segment_ids, ctx_tables, ctx_lens, ctx, mesh, dp, seq_shards):
+    """A pack's attention over [cached context | in-pack causal segment],
+    shared by chunked prefill and verify.  Context positions (< ctx_lens) read
+    the pools; the pack's own fresh rows are masked out by ctx_lens and enter
+    through the in-pack half, so the post-write pools are safe to pass."""
+    return lambda q, k, v, kv_l: paged_attention_packed_ctx(
+        q[0], k[0], v[0], segment_ids, *kv_l, ctx_tables, ctx_lens,
+        logits_soft_cap=cfg.logits_soft_cap, mesh=mesh, dp=dp,
+        seq_shards=seq_shards, ctx=ctx)
+
+
+def _write_pages(pack_pages, kv_cache):
+    """A page-aligned pack's K/V rows land page by page (``write_pack_kv``);
+    padding chunks go to the out-of-bounds sentinel, found once a pack."""
+    pages = jnp.where(pack_pages >= 0, pack_pages, kv_cache[0][0].shape[0])
+    return lambda kv_l, k, v: (write_pack_kv(kv_l[0], k[0], pages),
+                               write_pack_kv(kv_l[1], v[0], pages))
 
 
 def prefill_packed(
-    params: Params,
-    cfg: TransformerConfig,
-    tokens: jnp.ndarray,  # [T] int32 — prompts packed at PAGE-aligned starts
-    segment_ids: jnp.ndarray,  # [T] int32 — 1-based per prompt, 0 = padding
-    positions: jnp.ndarray,  # [T] int32 — per-token position within its prompt
-    pack_pages: jnp.ndarray,  # [T/bs] int32 — destination page per bs-chunk (-1 pad)
-    last_idx: jnp.ndarray,  # [N] int32 — buffer index of each prompt's last token (-1 pad)
-    kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
-    ctx=None,  # ops.quantizer.ServingContext — TP/fused serving policy
-    mesh=None,  # serve mesh: the flash kernel runs per shard under it
+    params, cfg: TransformerConfig,
+    tokens,  # [T] int32 — prompts packed at PAGE-aligned starts
+    segment_ids,  # [T] int32 — 1-based per prompt, 0 = padding
+    positions,  # [T] int32 — per-token position within its prompt
+    pack_pages,  # [T/bs] int32 — destination page per bs-chunk (-1 pad)
+    last_idx,  # [N] int32 — buffer index of each prompt's last token (-1 pad)
+    kv_cache, ctx=None, mesh=None,
 ):
     """Batched multi-prompt prefill under one token budget (the Dynamic
     SplitFuse-shaped dispatch; reference ``inference/v2/ragged/
-    ragged_wrapper.py`` builds the same packed view as 'atoms').
-
-    All prompts share one dense causal pass; cross-prompt attention is
-    blocked by ``segment_ids`` masking.  Every prompt starts at a PAGE
-    boundary in the pack (the engine pads with segment-0 gaps), so KV
-    lands as ONE page-granular scatter per layer — a per-TOKEN scatter was
-    measured at ~100 ms/pack on v5e (TPU serializes row scatters); pages
-    cut the scatter index count by block_size.  Rows past a prompt's end
-    inside its last page carry garbage masked by sequence length, same as
-    ``write_prefill_kv``.  Returns (logits [N, vocab], new caches).
-    """
-    if cfg.latent is not None:
-        # layers of several kinds: their own cache and bodies; a cold pack is
-        # a pack whose block tables are its own pages
-        from . import latent_runner
-
-        _latent_only(ctx, mesh)
-        n_pages = pack_pages.shape[0]  # a cold pack's positions end inside it
-        tables = latent_runner.tables_of_pack(
-            segment_ids, positions, pack_pages, last_idx.shape[0], n_pages,
-            tokens.shape[0] // n_pages)
-        return latent_runner.prefill_pack(
-            params, cfg, tokens, segment_ids, positions, pack_pages, last_idx,
-            tables, kv_cache)
-    t = tokens.shape[0]
-    x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,T,d]
-    if cfg.position == "learned":
-        x = x + params["pos_embed"]["embedding"][
-            jnp.clip(positions, 0, cfg.max_seq_len - 1)
-        ][None].astype(cfg.dtype)
-    x = _embed(params, cfg, x)
-    ck, cv = kv_cache
-    nb = ck[0].shape[0]
-    bs = ck[0].shape[1]
-    n_chunks = t // bs
-    # padding chunks scatter out of bounds and are dropped
-    safe_pages = jnp.where(pack_pages >= 0, pack_pages, nb)
+    ragged_wrapper.py`` builds the same packed view as 'atoms'): one dense
+    causal pass, cross-prompt attention blocked by ``segment_ids``.  Every
+    prompt starts at a PAGE boundary of the pack (the engine pads with
+    segment-0 gaps), so KV lands page by page (``_write_pages``).  Returns
+    (logits [N, vocab], new caches)."""
+    _dense_only(cfg, "model_runner.prefill_packed")
+    x = _embed(params, cfg, tokens, positions, 0)  # [1,T,d]
+    write = _write_pages(pack_pages, kv_cache)
     seg = segment_ids[None]  # [1, T]
-    pos2 = positions[None]
-    new_ck, new_cv = list(ck), list(cv)
-    for l in range(cfg.num_layers):
-        lw = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-        h = norm(x, lw["attn_norm"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(lw["attn"], h, cfg, ctx)
-        if cfg.position == "rope":
-            q = rope(q, pos2, cfg.rope_theta)
-            k = rope(k, pos2, cfg.rope_theta)
-        new_ck[l] = new_ck[l].at[safe_pages].set(
-            k[0].reshape(n_chunks, bs, *k.shape[2:]).astype(new_ck[l].dtype),
-            mode="drop",
-        )
-        new_cv[l] = new_cv[l].at[safe_pages].set(
-            v[0].reshape(n_chunks, bs, *v.shape[2:]).astype(new_cv[l].dtype),
-            mode="drop",
-        )
-        # packed order == position order within each segment, so causal
-        # masking by buffer index + segment masking is exact.  The flash
-        # kernel handles packed segments natively (per-block int32 tiles),
-        # so SplitFuse prefill runs on the MXU-tiled path on TPU
-        attn = flash_attention(
-            q, k, v, causal=True, segment_ids=seg,
-            logits_soft_cap=cfg.logits_soft_cap, mesh=mesh,
-        )
-        attn = _attn_out(lw["attn"], attn.reshape(1, t, -1), ctx)
-        x = x + attn.astype(x.dtype)
-        h = norm(x, lw["mlp_norm"], cfg.norm, cfg.norm_eps)
-        x = x + _ffn(lw, h, cfg, ctx).astype(x.dtype)
 
-    x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    last = x[0, jnp.clip(last_idx, 0, t - 1)]  # [N, d]
-    logits = _lm_logits(params, cfg, last, ctx)  # [N, v]
-    return logits, (tuple(new_ck), tuple(new_cv))
+    def read(q, k, v, kv_l):
+        # packed order == position order within each segment, so causal
+        # masking by buffer index + segment masking is exact; the flash
+        # kernel takes packed segments natively (per-block int32 tiles)
+        return flash_attention(q, k, v, causal=True, segment_ids=seg,
+                               logits_soft_cap=cfg.logits_soft_cap, mesh=mesh)
+
+    x, kv = _layers(params, cfg, x, positions[None], kv_cache, write, read, ctx)
+    last = x[0, jnp.clip(last_idx, 0, tokens.shape[0] - 1)]  # [N, d]
+    return _lm_logits(params, cfg, last, ctx), kv  # [N, v]
 
 
 def prefill_packed_ctx(
-    params: Params,
-    cfg: TransformerConfig,
-    tokens: jnp.ndarray,  # [T] int32 — suffix tokens packed at PAGE-aligned starts
-    segment_ids: jnp.ndarray,  # [T] int32 — 1-based per prompt, 0 = padding
-    positions: jnp.ndarray,  # [T] int32 — ABSOLUTE position (start offset baked in)
-    pack_pages: jnp.ndarray,  # [T/bs] int32 — destination page per bs-chunk (-1 pad)
-    last_idx: jnp.ndarray,  # [N] int32 — buffer index of each prompt's last token (-1 pad)
-    ctx_tables: jnp.ndarray,  # [N, P] int32 — block table per segment (-1 pad)
-    ctx_lens: jnp.ndarray,  # [N] int32 — cached-context length per segment
-    kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
-    ctx=None,  # ops.quantizer.ServingContext — TP/fused serving policy
-    mesh=None,  # TP/2-D serving: shard_map the ctx attention (see paged.py)
-    dp: int = 1,  # batch-axis replicas — packs arrive as dp per-replica chunks
-    seq_shards: int = 1,  # seq-axis pool slices (3-D mesh, ring-merged)
+    params, cfg: TransformerConfig,
+    tokens, segment_ids,  # as prefill_packed's: suffix tokens, 1-based segments
+    positions,  # [T] int32 — ABSOLUTE position (start offset baked in)
+    pack_pages, last_idx,  # as prefill_packed's
+    ctx_tables,  # [N, P] int32 — block table per segment (-1 pad)
+    ctx_lens,  # [N] int32 — cached-context length per segment
+    kv_cache, ctx=None, mesh=None, dp: int = 1, seq_shards: int = 1,
 ):
-    """``prefill_packed`` generalized to token SUFFIXES: each packed segment
-    starts at a per-sequence offset (``ctx_lens``) and attends over its
-    pre-existing KV pages (``ctx_tables``) for positions below the offset
-    plus the causal in-pack segment.  RoPE/learned positions come from the
-    absolute ``positions``.  This is the one model-runner capability both
-    prefix-cache-hit prefill and Dynamic-SplitFuse chunked prefill ride on;
-    segments with offset 0 and the no-context pack stay byte-identical to
-    ``prefill_packed`` (the engine dispatches there for speed).  Returns
-    (logits [N, vocab], new caches); rows of ``last_idx`` that are -1
-    (segment's prompt not yet complete — mid-chunk) yield garbage logits the
-    engine never consumes.
-    """
-    if cfg.latent is not None:
-        from . import latent_runner
-
-        _latent_only(ctx, mesh, dp, seq_shards)
-        return latent_runner.prefill_pack(
-            params, cfg, tokens, segment_ids, positions, pack_pages, last_idx,
-            ctx_tables, kv_cache)
-    t = tokens.shape[0]
-    x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,T,d]
-    if cfg.position == "learned":
-        x = x + params["pos_embed"]["embedding"][
-            jnp.clip(positions, 0, cfg.max_seq_len - 1)
-        ][None].astype(cfg.dtype)
-    x = _embed(params, cfg, x)
-    ck, cv = kv_cache
-    nb = ck[0].shape[0]
-    bs = ck[0].shape[1]
-    n_chunks = t // bs
-    safe_pages = jnp.where(pack_pages >= 0, pack_pages, nb)
-    pos2 = positions[None]
-    new_ck, new_cv = list(ck), list(cv)
-    for l in range(cfg.num_layers):
-        lw = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-        h = norm(x, lw["attn_norm"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(lw["attn"], h, cfg, ctx)
-        if cfg.position == "rope":
-            q = rope(q, pos2, cfg.rope_theta)
-            k = rope(k, pos2, cfg.rope_theta)
-        new_ck[l] = new_ck[l].at[safe_pages].set(
-            k[0].reshape(n_chunks, bs, *k.shape[2:]).astype(new_ck[l].dtype),
-            mode="drop",
-        )
-        new_cv[l] = new_cv[l].at[safe_pages].set(
-            v[0].reshape(n_chunks, bs, *v.shape[2:]).astype(new_cv[l].dtype),
-            mode="drop",
-        )
-        # context positions (< ctx_lens) read from the written pools; the
-        # pack's own freshly-written pages are masked out by ctx_lens, so
-        # passing the post-write pool is safe and mirrors decode_step
-        attn = paged_attention_packed_ctx(
-            q[0], k[0], v[0], segment_ids, new_ck[l], new_cv[l],
-            ctx_tables, ctx_lens, logits_soft_cap=cfg.logits_soft_cap,
-            mesh=mesh, dp=dp, seq_shards=seq_shards, ctx=ctx,
-        )
-        attn = _attn_out(lw["attn"], attn.reshape(1, t, -1), ctx)
-        x = x + attn.astype(x.dtype)
-        h = norm(x, lw["mlp_norm"], cfg.norm, cfg.norm_eps)
-        x = x + _ffn(lw, h, cfg, ctx).astype(x.dtype)
-
-    x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    last = x[0, jnp.clip(last_idx, 0, t - 1)]  # [N, d]
-    logits = _lm_logits(params, cfg, last, ctx)  # [N, v]
-    return logits, (tuple(new_ck), tuple(new_cv))
+    """``prefill_packed`` generalized to token SUFFIXES: each segment starts
+    at a per-sequence offset (``ctx_lens``) and attends over its cached pages
+    (``ctx_tables``) below the offset plus the causal in-pack segment.  Both
+    prefix-cache-hit prefill and chunked prefill ride on it; a no-context
+    pack computes what ``prefill_packed`` does (the engine dispatches there
+    for speed).  Returns (logits [N, vocab], new caches); a ``last_idx`` row
+    of -1 (mid-chunk) yields garbage logits the engine never consumes."""
+    _dense_only(cfg, "model_runner.prefill_packed_ctx")
+    x = _embed(params, cfg, tokens, positions, 0)  # [1,T,d]
+    write = _write_pages(pack_pages, kv_cache)
+    read = _read_ctx(cfg, segment_ids, ctx_tables, ctx_lens, ctx, mesh, dp, seq_shards)
+    x, kv = _layers(params, cfg, x, positions[None], kv_cache, write, read, ctx)
+    last = x[0, jnp.clip(last_idx, 0, tokens.shape[0] - 1)]  # [N, d]
+    return _lm_logits(params, cfg, last, ctx), kv  # [N, v]
 
 
 def verify_packed_ctx(
-    params: Params,
-    cfg: TransformerConfig,
-    tokens: jnp.ndarray,  # [T] int32 — per slot: [last committed, d_0..d_{k-1}], padded
-    segment_ids: jnp.ndarray,  # [T] int32 — slot+1 per valid token, 0 = padding
-    positions: jnp.ndarray,  # [T] int32 — ABSOLUTE position of each token
-    dst_pages: jnp.ndarray,  # [T] int32 — KV destination page per token (-1 pad)
-    dst_offs: jnp.ndarray,  # [T] int32 — row within the destination page
-    ctx_tables: jnp.ndarray,  # [N, P] int32 — block table per slot (-1 pad)
-    ctx_lens: jnp.ndarray,  # [N] int32 — committed (KV-written) length per slot
-    kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
-    ctx=None,  # ops.quantizer.ServingContext — TP/fused serving policy
-    mesh=None,  # TP/2-D serving: shard_map the ctx attention (see paged.py)
-    dp: int = 1,  # batch-axis replicas (slot-ordered rows chunk naturally)
-    seq_shards: int = 1,  # seq-axis pool slices (3-D mesh, ring-merged)
+    params, cfg: TransformerConfig,
+    tokens,  # [T] int32 — per slot: [last committed, d_0..d_{k-1}], padded
+    segment_ids,  # [T] int32 — slot+1 per valid token, 0 = padding
+    positions,  # [T] int32 — ABSOLUTE position of each token
+    dst_pages, dst_offs,  # [T] int32 — KV destination per token: page (-1 pad), row
+    ctx_tables,  # [N, P] int32 — block table per slot (-1 pad)
+    ctx_lens,  # [N] int32 — committed (KV-written) length per slot
+    kv_cache, ctx=None, mesh=None, dp: int = 1, seq_shards: int = 1,
 ):
     """Speculative-decode verify: score k+1 positions per sequence in ONE
-    pass — the dispatch that amortizes the weight stream across several
-    emitted tokens (one weight read serves up to k+1 of them).
+    pass (one weight read serves up to k+1 emitted tokens).  A sequence's
+    segment is [its last committed token, then its k draft tokens] at
+    consecutive absolute positions; attention is chunked prefill's: a draft
+    attends over the cached pages plus the drafts before it.  KV writes are
+    per-ROW scatters (``write_spec_kv``: the pack starts mid-page); rejected
+    drafts leave garbage KV past the accepted length, masked by sequence
+    length everywhere, overwritten as the sequence grows (the ``step_n``
+    rule), their tail BLOCKS freed by the allocator's truncate path.  Returns
+    (logits [T, v], new caches): logits for ALL T pack rows, each verifying
+    the next draft or sampling the correction / bonus token; the fp32 buffer
+    is small at T = max_seqs * (k+1)."""
+    _dense_only(cfg, "enable_speculation (verify_packed_ctx)", "a rejected "
+                "draft's rows cannot be rolled back out of a sliding layer's ring")
+    x = _embed(params, cfg, tokens, positions, 0)  # [1,T,d]
 
-    Each sequence's pack segment is [its last committed token, then its k
-    draft tokens] at consecutive absolute positions; attention rides the
-    same machinery as chunked prefill (``paged_attention_packed_ctx``): one
-    softmax over [cached context | in-pack causal draft prefix], so a draft
-    token attends over the sequence's cached pages plus the drafts before
-    it.  Two differences from ``prefill_packed_ctx``:
+    def write(kv_l, k, v):
+        return (write_spec_kv(kv_l[0], k[0], dst_pages, dst_offs),
+                write_spec_kv(kv_l[1], v[0], dst_pages, dst_offs))
 
-    * KV writes are per-ROW scatters (``write_spec_kv``): the pack starts
-      mid-page at the decode head, where a page-granular scatter would
-      stomp live rows.  Rejected drafts leave garbage KV past the accepted
-      length — masked by sequence length everywhere, overwritten as the
-      sequence grows (the ``step_n`` rule), and their tail BLOCKS are freed
-      by the allocator's truncate path.
-    * Logits return for ALL T pack rows (each one verifies the next draft
-      or samples the correction/bonus token), not just a per-segment last
-      row.  The [T, vocab] fp32 buffer is the price of single-pass verify —
-      T = max_seqs * (k+1) stays small next to prefill packs.
-
-    Returns (logits [T, v], new caches).
-    """
-    if cfg.latent is not None:
-        from ..models.latent import refuse
-
-        refuse("enable_speculation (verify_packed_ctx)", "a rejected draft's rows "
-               "cannot be rolled back out of a sliding layer's ring")
-    t = tokens.shape[0]
-    x = params["embed"]["embedding"][tokens][None].astype(cfg.dtype)  # [1,T,d]
-    if cfg.position == "learned":
-        x = x + params["pos_embed"]["embedding"][
-            jnp.clip(positions, 0, cfg.max_seq_len - 1)
-        ][None].astype(cfg.dtype)
-    x = _embed(params, cfg, x)
-    ck, cv = kv_cache
-    pos2 = positions[None]
-    new_ck, new_cv = list(ck), list(cv)
-    for l in range(cfg.num_layers):
-        lw = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-        h = norm(x, lw["attn_norm"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(lw["attn"], h, cfg, ctx)
-        if cfg.position == "rope":
-            q = rope(q, pos2, cfg.rope_theta)
-            k = rope(k, pos2, cfg.rope_theta)
-        new_ck[l] = write_spec_kv(new_ck[l], k[0], dst_pages, dst_offs)
-        new_cv[l] = write_spec_kv(new_cv[l], v[0], dst_pages, dst_offs)
-        # context positions (< ctx_lens) read the cached pools; the pack's
-        # freshly written rows are masked out by ctx_lens and enter through
-        # the in-pack causal half — same split as prefill_packed_ctx
-        attn = paged_attention_packed_ctx(
-            q[0], k[0], v[0], segment_ids, new_ck[l], new_cv[l],
-            ctx_tables, ctx_lens, logits_soft_cap=cfg.logits_soft_cap,
-            mesh=mesh, dp=dp, seq_shards=seq_shards, ctx=ctx,
-        )
-        attn = _attn_out(lw["attn"], attn.reshape(1, t, -1), ctx)
-        x = x + attn.astype(x.dtype)
-        h = norm(x, lw["mlp_norm"], cfg.norm, cfg.norm_eps)
-        x = x + _ffn(lw, h, cfg, ctx).astype(x.dtype)
-
-    x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    logits = _lm_logits(params, cfg, x[0], ctx)  # [T, v]
-    return logits, (tuple(new_ck), tuple(new_cv))
+    read = _read_ctx(cfg, segment_ids, ctx_tables, ctx_lens, ctx, mesh, dp, seq_shards)
+    x, kv = _layers(params, cfg, x, positions[None], kv_cache, write, read, ctx)
+    return _lm_logits(params, cfg, x[0], ctx), kv  # [T, v]
 
 
 def decode_step(
-    params: Params,
-    cfg: TransformerConfig,
-    tokens: jnp.ndarray,  # [B] int32 — last sampled token per slot
-    seq_lens: jnp.ndarray,  # [B] int32 — length BEFORE this token
-    block_tables: jnp.ndarray,  # [B, P] int32
-    active: jnp.ndarray,  # [B] bool
-    kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
-    ctx=None,  # ops.quantizer.ServingContext — TP/fused serving policy
-    mesh=None,  # TP serving: shard_map the paged attention over 'model'
-    dp: int = 1,  # batch-axis replicas (2-D batch x model serve mesh)
-    seq_shards: int = 1,  # seq-axis pool slices (3-D mesh, ring-merged)
+    params, cfg: TransformerConfig,
+    tokens,  # [B] int32 — last sampled token per slot
+    seq_lens,  # [B] int32 — length BEFORE this token
+    block_tables,  # [B, P] int32
+    active,  # [B] bool
+    kv_cache, ctx=None, mesh=None, dp: int = 1, seq_shards: int = 1,
 ):
     """One batched decode tick: returns (logits [B, v], new caches)."""
-    if cfg.latent is not None:
-        from . import latent_runner
+    _dense_only(cfg, "model_runner.decode_step")
+    x = _embed(params, cfg, tokens, seq_lens, 1)  # [B,1,d]
 
-        _latent_only(ctx, mesh, dp, seq_shards)
-        return latent_runner.decode_step(
-            params, cfg, tokens, seq_lens, block_tables, active, kv_cache)
-    b = tokens.shape[0]
-    x = params["embed"]["embedding"][tokens][:, None].astype(cfg.dtype)  # [B,1,d]
-    positions = seq_lens[:, None]  # the new token's position
-    if cfg.position == "learned":
-        pe = params["pos_embed"]["embedding"][
-            jnp.clip(seq_lens, 0, cfg.max_seq_len - 1)
-        ]
-        x = x + pe[:, None].astype(cfg.dtype)
-    x = _embed(params, cfg, x)
-    ck, cv = kv_cache
-    new_ck, new_cv = list(ck), list(cv)
-    for l in range(cfg.num_layers):
-        lw = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-        h = norm(x, lw["attn_norm"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(lw["attn"], h, cfg, ctx)  # [B,1,h,hd]
-        if cfg.position == "rope":
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        new_ck[l] = write_decode_kv(
-            new_ck[l], k[:, 0], block_tables, seq_lens, active
-        )
-        new_cv[l] = write_decode_kv(
-            new_cv[l], v[:, 0], block_tables, seq_lens, active
-        )
-        attn = paged_attention_decode(
-            q[:, 0], new_ck[l], new_cv[l], block_tables, seq_lens + 1,
+    def write(kv_l, k, v):
+        return (write_decode_kv(kv_l[0], k[:, 0], block_tables, seq_lens, active),
+                write_decode_kv(kv_l[1], v[:, 0], block_tables, seq_lens, active))
+
+    def read(q, k, v, kv_l):
+        return paged_attention_decode(
+            q[:, 0], *kv_l, block_tables, seq_lens + 1,
             logits_soft_cap=cfg.logits_soft_cap, mesh=mesh, dp=dp,
-            seq_shards=seq_shards,
-        )
-        attn = _attn_out(lw["attn"], attn.reshape(b, 1, -1), ctx)
-        x = x + attn.astype(x.dtype)
-        h = norm(x, lw["mlp_norm"], cfg.norm, cfg.norm_eps)
-        x = x + _ffn(lw, h, cfg, ctx).astype(x.dtype)
-    x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    logits = _lm_logits(params, cfg, x[:, 0], ctx)
-    return logits, (tuple(new_ck), tuple(new_cv))
+            seq_shards=seq_shards)
+
+    x, kv = _layers(params, cfg, x, seq_lens[:, None], kv_cache, write, read, ctx)
+    return _lm_logits(params, cfg, x[:, 0], ctx), kv
+
+
+class DenseRunner:
+    """What ``InferenceEngineV2`` asks of the runner it picked, answered for
+    a dense model; ``latent_runner.LatentRunner`` answers the same for
+    ``cfg.latent``: the cache, the four device entries, and the kind's HOST
+    accounting, which is empty for pages the allocator already audits."""
+
+    counters = ()  # ``stats`` keys this kind adds
+    packs_are_one_program = False  # a cold pack has a program of its own
+    scoped_programs = False  # no named scope a trace reader looks up
+    prefill_packed = staticmethod(prefill_packed)
+    prefill_packed_ctx = staticmethod(prefill_packed_ctx)
+    verify_packed_ctx = staticmethod(verify_packed_ctx)
+    decode_step = staticmethod(decode_step)
+
+    def __init__(self, cfg: TransformerConfig):
+        self.cfg = cfg
+
+    def init_cache(self, num_blocks, block_size, max_seqs, pack_tokens):
+        cfg = self.cfg
+        return init_paged_cache(cfg.num_layers, num_blocks, block_size,
+                                cfg.num_kv_heads, cfg.hd, dtype=cfg.dtype)
+
+    def dispatched(self, counters, work) -> Dict[str, int]:
+        """Counts a dispatch over ``work`` = (slot, start, end) a sequence
+        into ``counters``; returns the dispatch span's extra arguments."""
+        return {}
+
+    def released(self, seq) -> None:
+        """``seq`` let go of its slot."""
+
+    def audit(self) -> Dict[str, int]:
+        """State a sequence still owns, for ``close()``."""
+        return {}
+
+    def refresh_stats(self, counters, kv) -> None:
+        """Device-side counts of ``kv`` into ``counters``."""

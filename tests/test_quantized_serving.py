@@ -86,13 +86,13 @@ def test_quantized_prefill_logits_track_dense(tiny_model, fmt):
     mk_kv = lambda: init_paged_cache(
         cfg.num_layers, 16, 8, cfg.num_kv_heads, cfg.hd, dtype=cfg.dtype
     )
-    dense_logits, _ = jax.jit(
-        lambda p, kv: model_runner.prefill(p, cfg, tokens, jnp.asarray(16), blocks, kv)
-    )(params, mk_kv())
-    quant_logits, _ = jax.jit(
-        lambda p, kv: model_runner.prefill(p, cfg, tokens, jnp.asarray(16), blocks, kv)
-    )(qp, mk_kv())
-    d, q = np.asarray(dense_logits), np.asarray(quant_logits)
+    # one prompt, one segment of a pack; its last token's row is scored
+    pack = jax.jit(lambda p, kv: model_runner.prefill_packed(
+        p, cfg, tokens, jnp.ones(16, jnp.int32), jnp.arange(16, dtype=jnp.int32),
+        blocks, jnp.asarray([15], jnp.int32), kv))
+    dense_logits, _ = pack(params, mk_kv())
+    quant_logits, _ = pack(qp, mk_kv())
+    d, q = np.asarray(dense_logits[0]), np.asarray(quant_logits[0])
     rel = np.abs(d - q).max() / (np.abs(d).max() + 1e-9)
     # e4m3's 3-bit mantissa is coarser than int8's 7 significant bits
     assert rel < (0.12 if fmt == "fp8" else 0.05), rel

@@ -9,7 +9,7 @@ the projections' results as ``[rows, features]`` behind one
 ``eng.params`` stays the stacked tree the benchmark's driver and plain
 reference read.
 
-Here, on the CPU: what the five bodies lower to; that the barrier is the
+Here, on the CPU: what the four bodies lower to; that the barrier is the
 identity on every path that shares ``_qkv`` (bitwise); the benchmark's seam.
 What the TPU's compiler makes of the bodies at tiled widths (no weight-sized
 ``copy`` is left) is in ``test_overlap_hlo.py``, beside the other compiles
@@ -39,8 +39,7 @@ from deepspeed_tpu.ops.quantizer import serving_mm  # noqa: E402
 
 from conftest import make_grid  # noqa: E402
 
-BODIES = ("prefill", "prefill_packed", "prefill_packed_ctx", "verify_packed_ctx",
-          "decode_step")
+BODIES = ("prefill_packed", "prefill_packed_ctx", "verify_packed_ctx", "decode_step")
 
 
 def _qkv_plain(lw, x, cfg, ctx=None):
@@ -58,7 +57,7 @@ def _qkv_plain(lw, x, cfg, ctx=None):
 
 
 def _body(name, cfg, *, slots, pages, pack, bs, blocks, spec=lambda a: a):
-    """One of the five dense bodies as ``fn(params, *args)`` with the shapes
+    """One of the four dense entries as ``fn(params, *args)`` with the shapes
     of its arguments (``spec`` decorates each ``ShapeDtypeStruct``)."""
     i32 = jnp.int32
     S = lambda shape, dt=i32: spec(jax.ShapeDtypeStruct(shape, dt))
@@ -70,7 +69,6 @@ def _body(name, cfg, *, slots, pages, pack, bs, blocks, spec=lambda a: a):
                              jax.random.PRNGKey(0)))
     fn = getattr(model_runner, name)
     args = {
-        "prefill": (S((pack,)), S(()), S((pack // bs,)), kv),
         "prefill_packed": (S((pack,)), S((pack,)), S((pack,)), S((pack // bs,)),
                            S((slots,)), kv),
         "prefill_packed_ctx": (S((pack,)), S((pack,)), S((pack,)), S((pack // bs,)),
@@ -87,7 +85,7 @@ def _body(name, cfg, *, slots, pages, pack, bs, blocks, spec=lambda a: a):
 @pytest.mark.parametrize("name", BODIES)
 def test_every_body_holds_its_projections_before_the_head_split(name):
     """One barrier a layer, from the one shared helper: between each
-    projection's dot and its reshape into heads, in all five bodies."""
+    projection's dot and its reshape into heads, in all four entries: the ONE layer body."""
     cfg = get_preset("tiny", max_seq_len=64, dtype=jnp.float32)
     fn, specs = _body(name, cfg, slots=4, pages=8, pack=16, bs=8, blocks=16)
     text = jax.jit(fn).lower(*specs).as_text()
