@@ -22,8 +22,10 @@ the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
 as are chunks of one prompt and several prompts in one pack: work is laid out
 in groups of one page of one sequence.  Plain XLA bodies
-(``ops/latent_attention.py``); the expert layer's grouped matmul is a Pallas
-kernel on the chip (``moe/layer.py``).
+(``ops/latent_attention.py``) but for three Pallas kernels on the chip: a
+pack's index scores (``ops/pallas/index_scores.py``), its shorter groups'
+attention over their picks (``ops/pallas/selected_attention.py``) and the
+expert layer's grouped matmul (``moe/layer.py``).
 
 ``LatentRunner`` is what ``InferenceEngineV2`` holds for such a model (as
 ``model_runner.DenseRunner`` for a dense one): these entries under the names
@@ -41,6 +43,7 @@ import numpy as np
 from ..models import latent as lm
 from ..ops import latent_attention as la
 from ..ops.pallas import index_scores as index_kernel
+from ..ops.pallas import selected_attention as selected_kernel
 from ..ops.pallas import note_dispatch, on_tpu
 
 Cache = Dict[str, Any]
@@ -51,14 +54,16 @@ ROUTING_STATS = ("pairs_routed", "pairs_held", "group_rows_max", "group_rows_min
 _NO_MIN = 1 << 30
 _CARRY = 30  # bits of the low word of cache["picks"]
 # extra ``stats`` keys of an engine that serves such a model: what its
-# selectors, windows and router did.  The causal keys and the ring rows follow
-# from positions and are counted on the host at dispatch; the keys SELECTED
+# selectors, windows and router did.  The causal keys, the ring rows and the
+# groups follow from positions and are counted on the host at dispatch; the keys SELECTED
 # and the routing four are counted on the device (``picks``, ROUTING_STATS)
 # and read by ``refresh_routing_stats()``, which ``close()`` calls.
 COUNTERS = (
     "index_keys_scored",      # (query, key) pairs the indexers scored: causal keys
     "index_keys_selected",    # ... and pairs the selectors took (device count)
     "window_rows_discarded",  # ring rows that fell out of a window
+    "selected_groups",        # a pack's groups (a page of queries) x full layers
+    "selected_groups_dense",  # ... whose context ends under DENSE_KEYS_MAX: walked, not gathered
     "expert_pairs_routed",    # (token, expert) pairs the routers picked
     "expert_pairs_held",      # ... that fell on experts held here
     "expert_group_rows_max",  # rows of the largest held expert's group in a pack
@@ -116,7 +121,14 @@ def picks_total(picks) -> int:
 def _attend_selected(s, q_abs, q_i, w, q_pos, tables, lat, idx, real, picked, probe=None):
     """Full layers: groups [G, C, ...] of queries, ``tables`` [G, P] each
     group's block table.  Index scores over the group's pages, exact top-k,
-    attention over the selected rows of the latent pages.  ``picked`` (a list)
+    attention over the selected rows of the latent pages, by one of two
+    schedules of the same softmax over the same picks: a pack's groups whose
+    last position is under ``la.DENSE_KEYS_MAX`` WALK their live pages in place
+    with the picks as a mask (the Pallas kernel ``selected_attn``: no row is
+    copied, no ``lat[table]`` laid out); the other groups, a decode tick's
+    rows and every shape the kernel's gate declines GATHER their picked rows
+    (``la.sparse_attention``).  The picks, their count and the probe come from
+    ``select_topk`` on either path.  ``picked`` (a list)
     is handed how many keys the ``real`` [G, C] rows selected, ``probe`` (a
     list) what was selected: positions and their scores, [G, C, k]."""
     a, (g, c) = s.full, q_pos.shape
@@ -155,37 +167,65 @@ def _attend_selected(s, q_abs, q_i, w, q_pos, tables, lat, idx, real, picked, pr
         else:
             scores = jax.lax.map(lambda xs: scores_of(*xs), (q_i, w, q_pos, tables))
 
-        def group(xs):
-            sc, q_abs, q_pos, table = xs
-            vals, ix = la.select_topk(sc, s.index_topk, jnp.max(q_pos) + 1)
+        last = jnp.max(q_pos, axis=1)  # a group's last position: its context ends there
+        vals, ix = jax.lax.map(lambda xs: la.select_topk(xs[0], s.index_topk, xs[1] + 1),
+                               (scores, last))
+
+        def gathered(q_abs, ix, vals, table):
             # the sequence's pages laid out once (whole pages move at the
             # memory's speed), then rows by position; the barrier keeps the
             # page lookup out of every row's fetch
             own = jax.lax.optimization_barrier(lat[table].reshape(k_pad, lat.shape[-1]))
-            o = la.sparse_attention(q_abs, ix, vals > -jnp.inf, lambda r: own[r],
-                                    a.kv_rank, a.scale)
-            return o, ix, vals
+            return la.sparse_attention(q_abs, ix, vals > -jnp.inf, lambda r: own[r],
+                                       a.kv_rank, a.scale)
 
-        o, ix, vals = jax.lax.map(group, (scores, q_abs, q_pos, tables))
+        if _selected_kernel_takes(c, q_abs.shape[2], lat.shape[-1], a.kv_rank, bs):
+            # a short context's groups walk their pages whole, the picks a mask
+            dense = last < la.DENSE_KEYS_MAX
+            with jax.named_scope("sparse_attn"):
+                walked = selected_kernel.selected_attention(
+                    q_abs, la.selected_mask(scores, vals, ix), lat, tables,
+                    jnp.where(dense, last // bs + 1, 0), a.kv_rank, a.scale)
+            o = jax.lax.map(lambda xs: jax.lax.cond(xs[0], lambda: xs[1],
+                                                    lambda: gathered(*xs[2:])),
+                            (dense, walked, q_abs, ix, vals, tables))
+        else:
+            o = jax.lax.map(lambda xs: gathered(*xs), (q_abs, ix, vals, tables))
     picked.append(jnp.sum((vals > -jnp.inf) & real[..., None], dtype=jnp.int32))
     if probe is not None:
         probe.append({"index_picked": ix, "index_values": vals})
     return o
 
 
-def _index_kernel_takes(c: int, j: int, d: int, bs: int) -> bool:
-    """The gate of the Pallas index-scores kernel (a page of queries at a
-    time; a decode tick's single rows stay on the XLA body by design)."""
-    interpret = index_kernel.interpret()
+def _kernel_takes(name: str, kernel, shape, declines: str) -> bool:
+    """A Pallas kernel's gate by shape (``kernel.supports(*shape)``, on a TPU
+    or interpreted), noted for ``record_dispatch()``.  Both kernels serve a
+    pack's pages of queries; a decode tick's single rows stay on the XLA
+    bodies by design and never ask."""
+    interpret = kernel.interpret()
     if not (interpret or on_tpu()):
         reason = "not on a TPU"
-    elif not index_kernel.supports(c, j, d, bs):
-        reason = "queries, page and head size must be whole 128-lane tiles"
+    elif not kernel.supports(*shape):
+        reason = declines
     else:
-        note_dispatch("index_scores", True, (c, j, d, bs), interpret=interpret)
+        note_dispatch(name, True, shape, interpret=interpret)
         return True
-    note_dispatch("index_scores", False, (c, j, d, bs), reason=reason)
+    note_dispatch(name, False, shape, reason=reason)
     return False
+
+
+def _selected_kernel_takes(c: int, h: int, w: int, r_kv: int, bs: int) -> bool:
+    """The gate of the Pallas selected-attention kernel, by shape.  Which of a
+    pack's groups it then serves is decided inside the program, by length
+    (``latent_attention.DENSE_KEYS_MAX``)."""
+    return _kernel_takes("selected_attn", selected_kernel, (c, h, w, r_kv, bs),
+                         "whole query tiles; page, row and value lanes whole 128-lane tiles")
+
+
+def _index_kernel_takes(c: int, j: int, d: int, bs: int) -> bool:
+    """The gate of the Pallas index-scores kernel (a page of queries at a time)."""
+    return _kernel_takes("index_scores", index_kernel, (c, j, d, bs),
+                         "queries, page and head size must be whole 128-lane tiles")
 
 
 def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, probe):
@@ -362,8 +402,10 @@ class LatentRunner:
     def __init__(self, cfg):
         self.cfg = cfg
         self._ring_rows = np.zeros(0, np.int64)
+        self._block = 1
 
     def init_cache(self, num_blocks, block_size, max_seqs, pack_tokens) -> Cache:
+        self._block = block_size
         # host mirror of the rings: positions each slot's ring has taken (a
         # ring is not allocated, so this is what ``close()`` audits)
         self._ring_rows = np.zeros(max_seqs, np.int64)
@@ -395,11 +437,20 @@ class LatentRunner:
         layers: the dispatch's span arguments.  The causal keys and the ring
         rows are counted into ``counters`` here; the keys selected are counted
         where they are selected (``refresh_stats``), so that count moves if a
-        selector breaks, and a sound run's equals the sum of these arguments."""
+        selector breaks, and a sound run's equals the sum of these arguments.
+        A pack's entry is one GROUP a page of queries, and a group whose last
+        position is under ``DENSE_KEYS_MAX`` walks its pages instead of gathering
+        rows (``_attend_selected``); a single position is a decode tick's row
+        and no group (nor is a pack's entry of one token, which the program
+        cannot tell apart here)."""
         s = self.cfg.latent
-        topk, win = s.index_topk, s.sliding.window
-        scored = selected = dropped = 0
+        topk, win, bs = s.index_topk, s.sliding.window, self._block
+        scored = selected = dropped = groups = dense = 0
         for slot, a, b in work:
+            if b - a > 1:
+                ends = [min(p + bs, b) for p in range(a, b, bs)]
+                groups += len(ends)
+                dense += sum(e <= la.DENSE_KEYS_MAX for e in ends)
             scored += (b * (b + 1) - a * (a + 1)) // 2  # sum of p + 1
             m = min(max(a, topk), b)  # from position m on, topk of p + 1 keys
             selected += (m * (m + 1) - a * (a + 1)) // 2 + (b - m) * topk
@@ -408,8 +459,13 @@ class LatentRunner:
         out = {"index_keys_scored": scored * s.count("full"),
                "index_keys_selected": selected * s.count("full"),
                "window_rows_discarded": dropped * s.count("sliding")}
-        for k in ("index_keys_scored", "window_rows_discarded"):
-            counters[k].inc(out[k])
+        if groups:
+            out.update(selected_groups=groups * s.count("full"),
+                       selected_groups_dense=dense * s.count("full"),
+                       selected_groups_dense_pct=100.0 * dense / groups)
+        for k in ("index_keys_scored", "window_rows_discarded", "selected_groups",
+                  "selected_groups_dense"):
+            counters[k].inc(out.get(k, 0))
         return out
 
     def released(self, seq) -> None:

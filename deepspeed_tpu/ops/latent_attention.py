@@ -7,7 +7,8 @@ head: the queries are folded through ``W_uk`` first (``q_abs = [q_nope W_uk ;
 q_rope]``), the softmax-weighted sum of the rows' first ``r_kv`` columns is
 folded through ``W_uv`` afterwards (``models/latent.py``).  What is here is
 the part between: index scores, the exact top-k, attention over the selected
-rows, attention over a window.
+rows (gathered, or for a pack's shorter contexts walked in place under a mask
+by the Pallas kernel ``selected_attn``), attention over a window.
 
 Work is laid out in GROUPS of ``C`` consecutive queries of one sequence (a
 page of a prefill pack; one decode row), because a group shares its keys:
@@ -27,6 +28,17 @@ import jax.numpy as jnp
 _MASKED = -1e30  # finite: a fully masked row softmaxes to uniform, not NaN
 Q_BLOCK = 64     # queries whose selected rows are gathered at once
 KEY_BLOCK_BYTES = 96 << 20  # cap of one [C, J, KB] float32 index-score block
+# A pack's group whose last position is below this walks its pages whole with
+# the picks as a mask (``ops/pallas/selected_attention.py``); from here on its
+# picked rows are gathered.  Where the two schedules meet on a v5e at
+# dots3-note-prev's widths (my chip runs, PR 32; ``tools/selected_attn_curves.py``),
+# ms a 128-query group of one layer: the kernel 0.65 at 2048 keys, 1.47 at 6144,
+# 2.71 at 12 288, 5.17 at 24 576 (0.25 + 0.2 a 1024, alone and inside the pack's
+# program alike); the gathered body 2.43 whatever the context: they meet at
+# ~11 000.  In the serving cell ``dots3_note_longdocs_closed`` (one seed, 45 s;
+# tokens/s with this constant at 0 / 8192 / 10 240 / 12 288 / 14 336 / 20 480 /
+# 28 672): 9999.0 / 11 231.5 / 11 333.6 / 11 274.9 / 11 230.1 / 10 669.6 / 10 561.3.
+DENSE_KEYS_MAX = 10240
 
 
 def index_key_block(c: int, j: int, k_total: int, unit: int) -> int:
@@ -91,6 +103,23 @@ def select_topk(scores, k: int, n_live=None):
         return jax.lax.switch(which, [at(w) for w in widths], scores)
 
 
+def selected_mask(scores, vals, idx):
+    """``select_topk``'s picks as a mask over the keys: scores [.., C, keys],
+    (vals, idx) [.., C, k] what it returned for them -> int8 [.., C, keys], 1 at
+    exactly the positions ``idx`` holds with a score above ``-inf``.
+
+    No scatter: a key is in iff it scores above the lowest picked score, or
+    equals it at a position no later than the last picked key that does
+    (``select_topk`` hands equal scores to the lower position, so the picked
+    among them are the first).  A row with fewer than ``k`` live keys has
+    ``-inf`` for its lowest, and takes every live key."""
+    thr = jnp.min(vals, axis=-1, keepdims=True)
+    last_tie = jnp.max(jnp.where(vals == thr, idx, -1), axis=-1, keepdims=True)
+    pos = jnp.arange(scores.shape[-1], dtype=idx.dtype)
+    take = (scores > thr) | ((scores == thr) & (pos <= last_tie))
+    return (take & (scores > -jnp.inf)).astype(jnp.int8)
+
+
 def sparse_attention(q_abs, idx, valid, rows_of: Callable, r_kv: int, scale: float):
     """One group's attention over its selected rows, absorbed form.
 
@@ -98,7 +127,10 @@ def sparse_attention(q_abs, idx, valid, rows_of: Callable, r_kv: int, scale: flo
     rows of those key positions.  Returns [C, H, r_kv] (before ``W_uv``).
     The rows of ``Q_BLOCK`` queries are gathered at once: 64 x 2048 rows of
     640 bf16 are 168 MB, and blocks of 256 ran the softmax six times slower
-    (my chip run, PR 29)."""
+    (my chip run, PR 29).  This is the path of a decode tick's rows, of a
+    pack's groups from ``DENSE_KEYS_MAX`` on and of every shape the Pallas
+    kernel declines, and the ground truth of that kernel's tests; a pack's
+    shorter groups never gather (``ops/pallas/selected_attention.py``)."""
     c = q_abs.shape[0]
     qb = min(Q_BLOCK, c)
     if c % qb:

@@ -187,6 +187,42 @@ def test_the_index_scores_kernel_serves_the_same_tokens(model):
     eng.close()
 
 
+@pytest.mark.parametrize("dense_max", [40, 1 << 30])
+def test_the_selected_attn_kernel_serves_the_same_tokens(model, monkeypatch, dense_max):
+    """The engine with the Pallas selected-attention kernel (interpret mode)
+    emits what the reference does, with every group walked and with the
+    groups past position 40 gathered; the groups it counts are the
+    positions' arithmetic: a group a page of each pack entry, a decode row
+    none, dense where the last position is under ``DENSE_KEYS_MAX``."""
+    from deepspeed_tpu.ops import latent_attention as la
+    from deepspeed_tpu.ops.pallas import record_dispatch
+    from deepspeed_tpu.ops.pallas import selected_attention as sk
+
+    m, arch, cfg, params, ref = model
+    monkeypatch.setattr(la, "DENSE_KEYS_MAX", dense_max)
+    with sk.interpreted(), record_dispatch() as log:
+        eng = _engine(cfg, params, telemetry=True)
+        sched = eng.scheduler
+        prompt = np.random.default_rng(6).integers(0, cfg.vocab_size, 70).tolist()
+        assert sched.try_submit(1, prompt, SamplingParams(temperature=0.0,
+                                                          max_new_tokens=6)).accepted
+        sched.run(wait_for=[1])
+        out = sched.pop_result(1)
+    took = [d for d in log if d["kernel"] == "selected_attn"]
+    assert took and all(d["ran"] and d["shape"][0] == 8 for d in took)  # packs only: c = 8
+    assert _agrees(ref, params, prompt, out) <= 1e-4
+    # chunks of 32: entries [0, 32) [32, 64) [64, 70), a group a page of 8
+    ends = [min(p + 8, b) for a, b in ((0, 32), (32, 64), (64, 70)) for p in range(a, b, 8)]
+    full = cfg.latent.count("full")
+    assert eng.stats["selected_groups"] == len(ends) * full == 9 * full
+    assert eng.stats["selected_groups_dense"] == sum(e <= dense_max for e in ends) * full
+    packs = [ev["args"] for ev in eng.telemetry.recorder.chrome_events()
+             if ev.get("ph") == "X" and ev["name"] == "prefill_pack"]
+    assert [a["selected_groups_dense_pct"] for a in packs] == (
+        [100.0, 25.0, 0.0] if dense_max == 40 else [100.0] * 3)
+    eng.close()
+
+
 def test_the_selected_keys_count_carries_past_32_bits():
     """A window's selections pass 2^31: the device keeps two words a layer."""
     from deepspeed_tpu.inference import latent_runner as lr

@@ -170,6 +170,26 @@ def test_quantize_gate_accepts_the_common_weight_shape():
     assert not qk.supports(_spec((7, 100)))
 
 
+def test_the_latent_kernels_compile_at_dots3_widths(v5e):
+    """``index_scores`` and ``selected_attn`` at the served widths: 16 groups
+    of 128 queries, 64 index heads x 128, 128 heads over rows of 640 lanes
+    (``kv_rank`` 512), pages of 128, tables of 272 pages."""
+    from deepspeed_tpu.ops.pallas import index_scores as ik
+    from deepspeed_tpu.ops.pallas import selected_attention as sk
+
+    g, c, nb, p = 16, 128, 2176, 272
+    assert ik.supports(c, 64, 128, 128) and sk.supports(c, 128, 640, 512, 128)
+    tables, live = _spec((g, p), jnp.int32), _spec((g,), jnp.int32)
+    _assert_mosaic(_compile(
+        lambda q, w, k, t, n: ik.paged_index_scores(q, w, k, t, n, 0.1), v5e,
+        _spec((g, c, 64, 128)), _spec((g, c, 64), jnp.float32), _spec((nb, 128, 128)),
+        tables, live))
+    _assert_mosaic(_compile(
+        lambda q, m, k, t, n: sk.selected_attention(q, m, k, t, n, 512, 0.07), v5e,
+        _spec((g, c, 128, 640)), _spec((g, c, p * 128), jnp.int8), _spec((nb, 128, 640)),
+        tables, live))
+
+
 def test_flash_partitions_on_four_chips(v5e, monkeypatch):
     """The flash dispatcher under a 4-device mesh lowers (shard_map region)
     where the bare kernel call raises 'Mosaic kernels cannot be
