@@ -113,15 +113,21 @@ class InferenceEngineV2:
             from ..models.latent import refuse
             from .latent_runner import LatentRunner
 
+            # a single-mixer model's per-slot state is a recurrence's, not a ring
+            states = cfg.latent.single
             for option, on, why in (
                 ("grid (a tensor-parallel / replica / seq-shard serve mesh)",
                  grid is not None or int(serve_replicas) > 1 or int(seq_shards) > 1,
                  "its weights and caches have no sharding rules yet"),
                 ("enable_speculation", enable_speculation,
-                 "a rejected draft's rows cannot be rolled back out of a ring"),
+                 "a rejected draft's state-space state cannot be rolled back" if states
+                 else "a rejected draft's rows cannot be rolled back out of a ring"),
                 ("quantize_weights (and int8 / fp8 KV)", quantize_weights is not None,
-                 "its projections and latent rows have no quantized form yet"),
+                 "its projections, states and pages have no quantized form yet" if states
+                 else "its projections and latent rows have no quantized form yet"),
                 ("enable_prefix_caching", enable_prefix_caching,
+                 "a cached prefix would have to bring a state snapshot with its pages"
+                 if states else
                  "a cached prefix would have to bring a window's ring with its pages"),
                 ("offload_weights", offload_weights, "untried"),
             ):
@@ -990,7 +996,7 @@ class InferenceEngineV2:
             # live context pages the ctx kernel walks: its time over this
             ctx_pages = int((-(-ctx_lens // bs)).sum())
             extra = self.runner.dispatched(
-                self._c, ((s.slot, start, end) for s, start, end in entries))
+                self._c, ((s.slot, start, end) for s, start, end in entries), pack=True)
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],

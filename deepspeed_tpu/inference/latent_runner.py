@@ -17,6 +17,21 @@ attends over.
 - ``stats`` counts routing on the device (``ROUTING_STATS``) and ``picks`` the
   keys the selectors took (``_tally``), fetched on demand.
 
+A model of single-mixer blocks (``models/latent.py:SINGLE``) keeps instead:
+
+- per state-space block and SLOT a state of FIXED size, overwritten every
+  token: the recurrence's state (``ssm``, float32) and the convolution's last
+  ``K - 1`` input rows (``conv``).  No pages and no positions: a sequence's
+  first chunk starts from zeros whatever the slot held (its position says so,
+  in the program: a stale state is never read), a prompt's later chunks and
+  its decode ticks carry the slot's state on, several prompts in one pack
+  each scan from their own, and a preempted sequence's state is simply left
+  behind: the resume starts at position 0 and recomputes it;
+- per attention block K / V pages on the allocator's block ids, written and
+  read by the paged GQA code of ``paged.py`` that a dense model uses;
+- an expert block keeps nothing but its routing counts (``touched``: held
+  experts with a row and the pairs on them, of packs and of ticks).
+
 One layer body (``_layer``) serves the pack and the tick; the kind chooses how
 the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
@@ -71,6 +86,19 @@ COUNTERS = (
 )
 
 
+# ... and of one whose blocks are single mixers
+STATE_COUNTERS = (
+    "ssm_states_reset",       # sequences that began from a zero state (admissions, resumes)
+    "ssm_states_recomputed",  # states a preemption discarded: the resume scans them again
+    "ssm_chunks_scanned",     # chunks (a page of one sequence) x state-space blocks, in packs
+    "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
+    "expert_group_rows_min",  # the routing four, as above
+    "experts_touched",        # held experts with at least one row, summed over dispatches
+    "experts_touched_decode",    # ... in decode ticks alone,
+    "expert_pairs_held_decode",  # and the pairs that fell on them there
+)
+
+
 def _lanes(width: int) -> int:
     """A latent row as the pages keep it: padded with zeros to whole 128-lane
     rows, which a row gather moves at twice the speed (576 -> 640)."""
@@ -89,6 +117,8 @@ def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
     if pack_tokens % block_size:
         raise ValueError(f"a pack of {pack_tokens} tokens is no whole number of "
                          f"pages of {block_size}")
+    if s.single:
+        return _init_state_cache(cfg, num_blocks, block_size, max_seqs, dtype)
     pages = lambda w, n: tuple(
         jnp.zeros((num_blocks, block_size, w), dtype) for _ in range(n))
     chunks = max_seqs * ring_rows(cfg, block_size, pack_tokens) // block_size
@@ -103,6 +133,28 @@ def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
         # keys selected so far, one row per full layer: a running count in two
         # int32 words (high, low 30 bits), since a window's sum passes 2^31
         "picks": jnp.zeros((s.count("full"), 2), jnp.int32),
+    }
+
+
+def _init_state_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
+                      dtype) -> Cache:
+    """The cache of a model of single-mixer blocks (module docstring)."""
+    from .paged import init_paged_cache
+
+    s, mb, g = cfg.latent, cfg.latent.mamba, cfg.latent.gqa
+    k, v = init_paged_cache(s.count("gqa"), num_blocks, block_size, g.num_kv_heads,
+                            g.head_dim, dtype=dtype)
+    per = lambda shape, dt: tuple(jnp.zeros((max_seqs, *shape), dt)
+                                  for _ in range(s.count("mamba")))
+    n_moe = s.count("experts")
+    return {
+        "ssm": per((mb.num_heads, mb.head_dim, mb.state), jnp.float32),
+        "conv": per((mb.conv - 1, mb.conv_width), dtype),
+        "k": k, "v": v,
+        "stats": jnp.zeros((n_moe, len(ROUTING_STATS)), jnp.int32).at[:, 3].set(_NO_MIN),
+        # per expert block, of packs [0] and of ticks [1]: (held experts with a
+        # row, pairs on held experts), running sums
+        "touched": jnp.zeros((n_moe, 2, 2), jnp.int32),
     }
 
 
@@ -233,6 +285,8 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, prob
     rows)`` returns the kind's cache arrays with the new rows in, ``read(kind,
     arrays, queries)`` attends over them.  Returns (x, cache)."""
     s = cfg.latent
+    if s.single:
+        return _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe)
     kind, (n1, n2), aw, fw, is_moe = lm.layer_params(layers, l, s)
     a, i = s.attn(kind), s.layer_kinds[:l].count(kind)
     h = lm.rms(x, n1["scale"], cfg.norm_eps)
@@ -251,11 +305,48 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, prob
         routed, picked = routing
         if probe is not None:
             probe.append({"experts_picked": picked})
-        st, m = cache["stats"], l - s.first_dense
-        new = jnp.stack([st[m, 0] + routed[0], st[m, 1] + routed[1],
-                         jnp.maximum(st[m, 2], routed[2]) if track_groups else st[m, 2],
-                         jnp.minimum(st[m, 3], routed[3]) if track_groups else st[m, 3]])
-        cache = {**cache, "stats": st.at[m].set(new)}
+        cache = {**cache, "stats": _routing_counted(
+            cache["stats"], l - s.first_dense, routed, track_groups)}
+    return x + y.astype(x.dtype), cache
+
+
+def _routing_counted(st, m: int, routed, track_groups: bool):
+    """``stats`` with expert layer ``m``'s ``routed`` (ROUTING_STATS) counted in."""
+    new = jnp.stack([st[m, 0] + routed[0], st[m, 1] + routed[1],
+                     jnp.maximum(st[m, 2], routed[2]) if track_groups else st[m, 2],
+                     jnp.minimum(st[m, 3], routed[3]) if track_groups else st[m, 3]])
+    return st.at[m].set(new)
+
+
+def _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe):
+    """One single-mixer block on token rows ``x`` [T, d], through the same
+    seam: a state-space block's ``write`` IS its read (the scan that carries
+    the state on yields the outputs: (state, conv tail, y)), an attention
+    block writes K / V rows and reads the pools, an expert block keeps nothing."""
+    s = cfg.latent
+    kind, scale, w = lm.block_params(layers, l, s)
+    i = s.layer_kinds[:l].count(kind)
+    h = lm.rms(x, scale, cfg.norm_eps)
+    if kind == "mamba":
+        ssm, conv, y = write(kind, (cache["ssm"][i], cache["conv"][i]), (w, h))
+        cache = {**cache, "ssm": _put(cache["ssm"], i, ssm), "conv": _put(cache["conv"], i, conv)}
+    elif kind == "gqa":
+        q, k, v = lm.gqa_inputs(w, h, s.gqa)
+        pools = write(kind, (cache["k"][i], cache["v"][i]), (k, v))
+        cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
+        with jax.named_scope("gqa_attn"):
+            o = read(kind, pools, (q, k, v))
+        y = o.reshape(x.shape[0], -1).astype(x.dtype) @ w["wo"]
+    else:
+        y, (routed, picked) = lm.ffn(w, h, True, cfg, valid)
+        if probe is not None:
+            probe.append({"experts_picked": picked})
+        local = picked - s.held_offset
+        rows = (local[..., None] == jnp.arange(s.n_held)) & valid[:, None, None]
+        cache = {**cache,
+                 "stats": _routing_counted(cache["stats"], i, routed, track_groups),
+                 "touched": cache["touched"].at[i, 0 if track_groups else 1].add(jnp.stack(
+                     [jnp.sum(jnp.any(rows, axis=(0, 1)), dtype=jnp.int32), routed[1]]))}
     return x + y.astype(x.dtype), cache
 
 
@@ -279,15 +370,33 @@ def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_i
     less ``ctx_lens``: a token's position says where its context ends).
     ``tables`` [N, P] are the block tables by slot, this pack's pages included.
     ``probe`` (a list) collects, layer by layer, what the indexers and the
-    routers picked.  Returns (logits [N, vocab], cache)."""
-    s, t = cfg.latent, tokens.shape[0]
+    routers picked and what a state-space block's recurrence consumed.
+    Returns (logits [N, vocab], cache)."""
+    t = tokens.shape[0]
+    valid = segment_ids > 0
+    picked: list = []
+    seam = _state_pack_seam if cfg.latent.single else _latent_pack_seam
+    write, read = seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache,
+                       picked, probe)
+    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    for l in range(cfg.num_layers):
+        x, cache = _layer(cfg, l, params["layers"], x, positions, valid, cache,
+                          write, read, True, probe)
+    if picked:
+        cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
+    return _logits(params, cfg, x[jnp.clip(last_idx, 0, t - 1)]), cache
+
+
+def _latent_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache, picked,
+                      probe):
+    """A pack's (write, read) for latent pages and window rings."""
+    s, t = cfg.latent, segment_ids.shape[0]
     nb, bs, _ = cache["lat"][0].shape if cache["lat"] else cache["win"][0].shape
     g = t // bs
     ring = cache["win"][0].shape[0] * bs // tables.shape[0] if cache["win"] else 0
     if ring and ring < ring_rows(cfg, bs, t):
         raise ValueError(f"a pack of {t} tokens needs rings of {ring_rows(cfg, bs, t)} "
                          f"rows; the cache was built with {ring}")
-    valid = segment_ids > 0
     slot = jnp.maximum(segment_ids[::bs] - 1, 0)  # a page of the pack is one sequence's
     live = segment_ids[::bs] > 0
     page0 = positions[::bs] // bs                 # ... and starts on one of its pages
@@ -323,22 +432,89 @@ def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_i
                 s.sliding.scale)
         return o.reshape(t, *o.shape[2:])
 
-    picked: list = []
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    for l in range(cfg.num_layers):
-        x, cache = _layer(cfg, l, params["layers"], x, positions, valid, cache,
-                          write, read, True, probe)
-    if picked:
-        cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
-    return _logits(params, cfg, x[jnp.clip(last_idx, 0, t - 1)]), cache
+    return write, read
+
+
+def _write_pages(pool, rows, pages):
+    """rows [G, bs, hkv, hd] into pages ``pages`` [G] (-1: none) of ``pool``,
+    a page at a time IN PLACE.  ``paged.write_pack_kv``'s one scatter makes
+    XLA:TPU re-lay a pool of 2 KV heads out and back around it (bs becomes the
+    tiled dim: four copies of the 0.4 GB pool a pack, seen in the program
+    compiled for the chip); a dynamic-update-slice keeps the pool's layout."""
+    for g in range(rows.shape[0]):
+        at = (jnp.maximum(pages[g], 0), 0, 0, 0)
+        old = jax.lax.dynamic_slice(pool, at, (1, *pool.shape[1:]))
+        new = jnp.where(pages[g] >= 0, rows[g][None].astype(pool.dtype), old)
+        pool = jax.lax.dynamic_update_slice(pool, new, at)
+    return pool
+
+
+def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache, picked,
+                     probe):
+    """A pack's (write, read) for single-mixer blocks.  The pack is chunks of
+    one page of one sequence (``bs`` tokens: the scan's chunk); a chunk whose
+    first position is 0 starts from ZEROS, a chunk that follows its own
+    sequence's chunk in the pack takes the state handed over inside the scan,
+    any other loads its slot's; the state after a sequence's last chunk in the
+    pack is kept.  Attention reads [the cached pages under the chunk's start |
+    the pack's own rows] as a dense model's chunked prefill does."""
+    from .paged import paged_attention_packed_ctx
+
+    s, t = cfg.latent, segment_ids.shape[0]
+    g = pack_pages.shape[0]
+    bs, n_slots = t // g, tables.shape[0]
+    slot = jnp.maximum(segment_ids[::bs] - 1, 0)  # a page of the pack is one sequence's
+    live = segment_ids[::bs] > 0
+    start = positions[::bs]
+    fresh = live & (start == 0)
+    same = jnp.concatenate([jnp.zeros((1,), bool), (slot[1:] == slot[:-1]) & live[:-1]])
+    cont = live & same & ~fresh
+    # the sequence's last chunk in this pack: its state is what the slot keeps
+    last = live & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
+    keep = jnp.where(last, slot, n_slots)
+    # the context under each sequence's first chunk here (``ctx_lens``)
+    first = live & ~cont
+    ctx_lens = jnp.zeros((n_slots,), jnp.int32).at[
+        jnp.where(first, slot, n_slots)].set(start, mode="drop")
+    grouped = lambda a: a.reshape(g, bs, *a.shape[1:])
+
+    def write(kind, arrays, rows):
+        if kind == "mamba":
+            (ssm, conv), (w, h) = arrays, rows
+            zero = lambda a: jnp.where(fresh.reshape(g, *(1,) * (a.ndim - 1)), 0, a)
+            y, states, tails = lm.mamba_chunks(
+                w, grouped(h), grouped(valid), cont, zero(conv[slot]), zero(ssm[slot]),
+                s.mamba, cfg.norm_eps, probe)
+            return (ssm.at[keep].set(states.astype(ssm.dtype), mode="drop"),
+                    conv.at[keep].set(tails.astype(conv.dtype), mode="drop"),
+                    y.reshape(t, -1))
+        return tuple(_write_pages(a, grouped(r), pack_pages) for a, r in zip(arrays, rows))
+
+    def read(kind, pools, qkv):
+        return paged_attention_packed_ctx(*qkv, segment_ids, *pools, tables, ctx_lens)
+
+    return write, read
 
 
 def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cache,
                 probe=None):
     """One batched decode tick (``model_runner.decode_step``'s arguments).
     Returns (logits [B, vocab], cache)."""
-    s, b = cfg.latent, tokens.shape[0]
-    pos = seq_lens
+    picked: list = []
+    seam = _state_tick_seam if cfg.latent.single else _latent_tick_seam
+    write, read = seam(cfg, seq_lens, block_tables, active, picked, probe)
+    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    for l in range(cfg.num_layers):
+        x, cache = _layer(cfg, l, params["layers"], x, seq_lens, active, cache,
+                          write, read, False, probe)
+    if picked:
+        cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
+    return _logits(params, cfg, x), cache
+
+
+def _latent_tick_seam(cfg, pos, block_tables, active, picked, probe):
+    """A decode tick's (write, read) for latent pages and window rings."""
+    s, b = cfg.latent, pos.shape[0]
     rows = jnp.arange(b)
 
     def write(kind, arrays, new):
@@ -369,14 +545,29 @@ def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cach
                                    s.sliding.window, s.sliding.kv_rank,
                                    s.sliding.scale)[:, 0]
 
-    picked: list = []
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    for l in range(cfg.num_layers):
-        x, cache = _layer(cfg, l, params["layers"], x, pos, active, cache,
-                          write, read, False, probe)
-    if picked:
-        cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
-    return _logits(params, cfg, x), cache
+    return write, read
+
+
+def _state_tick_seam(cfg, pos, block_tables, active, picked, probe):
+    """A decode tick's (write, read) for single-mixer blocks: the recurrence's
+    one step on every slot's state IN PLACE (idle slots keep their bits), one
+    new K / V row a live slot."""
+    from .paged import paged_attention_decode, write_decode_kv
+
+    s = cfg.latent
+
+    def write(kind, arrays, rows):
+        if kind == "mamba":
+            (ssm, conv), (w, h) = arrays, rows
+            y, ssm, conv = lm.mamba_step(w, h, active, conv, ssm, s.mamba, cfg.norm_eps, probe)
+            return ssm, conv, y
+        return tuple(write_decode_kv(a, r, block_tables, pos, active)
+                     for a, r in zip(arrays, rows))
+
+    def read(kind, pools, qkv):
+        return paged_attention_decode(qkv[0], *pools, block_tables, pos + 1)
+
+    return write, read
 
 
 def _one_chip_only(ctx, mesh, dp: int, seq_shards: int) -> None:
@@ -397,17 +588,21 @@ class LatentRunner:
 
     counters = COUNTERS
     packs_are_one_program = True  # a pack reads its own rows back from the cache
-    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul
+    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj
 
     def __init__(self, cfg):
         self.cfg = cfg
         self._ring_rows = np.zeros(0, np.int64)
         self._block = 1
+        if cfg.latent.single:
+            self.counters = STATE_COUNTERS
+            self._discarded = 0  # states a preemption left behind since the last dispatch
 
     def init_cache(self, num_blocks, block_size, max_seqs, pack_tokens) -> Cache:
         self._block = block_size
         # host mirror of the rings: positions each slot's ring has taken (a
         # ring is not allocated, so this is what ``close()`` audits)
+        # ... and of the state-space states: tokens each slot's state has taken in
         self._ring_rows = np.zeros(max_seqs, np.int64)
         return init_cache(self.cfg, num_blocks, block_size, max_seqs, pack_tokens)
 
@@ -431,7 +626,7 @@ class LatentRunner:
         _one_chip_only(ctx, mesh, dp, seq_shards)
         return decode_step(params, cfg, tokens, seq_lens, block_tables, active, kv_cache)
 
-    def dispatched(self, counters, work) -> Dict[str, int]:
+    def dispatched(self, counters, work, pack: bool = False) -> Dict[str, int]:
         """What the selectors and windows are ASKED to do with queries at
         positions ``[start, end)`` of each (slot, start, end) of ``work``, all
         layers: the dispatch's span arguments.  The causal keys and the ring
@@ -444,6 +639,8 @@ class LatentRunner:
         and no group (nor is a pack's entry of one token, which the program
         cannot tell apart here)."""
         s = self.cfg.latent
+        if s.single:
+            return self._states_dispatched(counters, work, pack)
         topk, win, bs = s.index_topk, s.sliding.window, self._block
         scored = selected = dropped = groups = dense = 0
         for slot, a, b in work:
@@ -468,18 +665,51 @@ class LatentRunner:
             counters[k].inc(out.get(k, 0))
         return out
 
+    def _states_dispatched(self, counters, work, pack: bool) -> Dict[str, int]:
+        """Single-mixer blocks: a pack's entry is ``ceil((end - start) / page)``
+        chunks a state-space block, from a zero state if it starts at 0; a
+        decode tick's is one step of a live slot's state."""
+        bs, n_ssm = self._block, self.cfg.latent.count("mamba")
+        segments = chunks = reset = steps = 0
+        for slot, a, b in work:
+            if pack:
+                segments += 1
+                chunks += -(-(b - a) // bs)
+                reset += a == 0
+            else:
+                steps += 1
+            self._ring_rows[slot] = b
+        counters["ssm_states_reset"].inc(reset)
+        counters["ssm_chunks_scanned"].inc(chunks * n_ssm)
+        counters["ssm_states_recomputed"].inc(self._discarded)
+        self._discarded = 0
+        if pack:
+            return {"ssm_segments": segments, "ssm_chunks": chunks}
+        return {"ssm_live_slots": steps}
+
     def released(self, seq) -> None:
+        if self.cfg.latent.single and seq.preempted and self._ring_rows[seq.slot]:
+            self._discarded += 1
         self._ring_rows[seq.slot] = 0
 
     def audit(self) -> Dict[str, int]:
-        """Rows of window state still owned by a sequence (a ring is nobody's
-        once its slot is released)."""
+        """State still owned by a sequence: rows of window state (a ring is
+        nobody's once its slot is released), or slots whose state-space state
+        is a live sequence's."""
+        if self.cfg.latent.single:
+            return {"ssm_states": int(np.count_nonzero(self._ring_rows))}
         return {"window_rows": int(self._ring_rows.sum())}
 
     def refresh_stats(self, counters, kv: Cache) -> None:
         """The selectors' and routers' device-side counts into ``counters``
         (two small device->host copies)."""
-        counters["index_keys_selected"].set(picks_total(kv["picks"]))
+        if "picks" in kv:
+            counters["index_keys_selected"].set(picks_total(kv["picks"]))
+        else:
+            touched = np.asarray(kv["touched"]).astype(np.int64).sum(0)  # [pack | tick, 2]
+            counters["experts_touched"].set(int(touched[:, 0].sum()))
+            counters["experts_touched_decode"].set(int(touched[1, 0]))
+            counters["expert_pairs_held_decode"].set(int(touched[1, 1]))
         st = np.asarray(kv["stats"]).astype(np.int64)
         if st.size:
             counters["expert_pairs_routed"].set(int(st[:, 0].sum()))

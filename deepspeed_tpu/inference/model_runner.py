@@ -336,9 +336,10 @@ class DenseRunner:
         return init_paged_cache(cfg.num_layers, num_blocks, block_size,
                                 cfg.num_kv_heads, cfg.hd, dtype=cfg.dtype)
 
-    def dispatched(self, counters, work) -> Dict[str, int]:
-        """Counts a dispatch over ``work`` = (slot, start, end) a sequence
-        into ``counters``; returns the dispatch span's extra arguments."""
+    def dispatched(self, counters, work, pack: bool = False) -> Dict[str, int]:
+        """Counts a dispatch (a prefill ``pack``, else a decode tick) over
+        ``work`` = (slot, start, end) a sequence into ``counters``; returns the
+        dispatch span's extra arguments."""
         return {}
 
     def released(self, seq) -> None:

@@ -312,6 +312,7 @@ class SequenceDescriptor:
     seen_tokens: int = 0  # tokens whose KV is already in the cache
     tokens: List[int] = field(default_factory=list)  # full token history
     done: bool = False
+    preempted: bool = False  # released by preemption: its per-slot state is recomputed
     cached_tokens: int = 0  # prefix tokens served from the block cache
     hashes: List[object] = field(default_factory=list)  # chained full-block keys
     # speculative-decoding state (engine_v2 drives these): accept-rate EMA

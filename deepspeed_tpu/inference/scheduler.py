@@ -1005,6 +1005,7 @@ class ServeScheduler:
             # descriptor — fold them into the request trace before release
             req.trace.add_spec(seq.spec_drafted, seq.spec_accepted)
             req.trace.preempted()
+            seq.preempted = True
             self.engine.mgr.release(req.uid)
             self._running.remove(req)
             req.state = WAITING

@@ -14,11 +14,20 @@ stacked ``[L, d]``); ``layer_params`` picks layer ``l``'s trees.  The per-token 
 ``forward`` here and by the serving runner (``inference/latent_runner.py``),
 which differ only in where a layer's keys live.  Forward only: there is no
 backward, pipeline or tensor-parallel path for these layers yet.
+
+A model may instead be made of blocks that are ONE norm and ONE mixer
+(``SINGLE``: ``x <- x + mixer(norm(x))``): a Mamba-2 state-space mixer
+(``mamba``, ``ops/ssm.py``), grouped-query attention without positions
+(``gqa``), or the expert layer alone (``experts``), whose experts may be of two
+matrices with ``relu(.)^2`` between and work in a latent (``expert_form``,
+``moe_latent``).  Their trees are ``layers/norm`` (stacked) and one tuple per
+kind; ``block_params`` picks block ``l``'s.  The two families do not mix in
+one model.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,9 +60,47 @@ class LatentAttn:
         return float(self.nope_dim + self.rope_dim) ** -0.5
 
 
+SINGLE = ("mamba", "gqa", "experts")  # kinds of a block that is one norm, one mixer
+
+
+@dataclass(frozen=True)
+class Mamba:
+    """A Mamba-2 mixer: ``num_heads`` heads of ``head_dim`` channels, a state
+    ``state`` wide per channel, ``B`` and ``C`` shared by the heads of each of
+    ``n_groups`` groups, a causal depthwise convolution over ``conv`` tokens."""
+
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    state: int
+    conv: int
+    chunk: int  # tokens a chunk of the uncached forward's scan (serving: a page)
+
+    @property
+    def d_in(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:  # x, B and C go through the convolution
+        return self.d_in + 2 * self.n_groups * self.state
+
+    @property
+    def in_width(self) -> int:  # [z | xBC | dt]
+        return self.d_in + self.conv_width + self.num_heads
+
+
+@dataclass(frozen=True)
+class Gqa:
+    """Grouped-query attention with no positional embedding."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+
+
 @dataclass(frozen=True)
 class LatentSpec:
-    layer_kinds: Tuple[str, ...]  # 'full' | 'sliding', one per layer held
+    layer_kinds: Tuple[str, ...]  # 'full' | 'sliding', or SINGLE's: one per layer held
     full: LatentAttn
     sliding: LatentAttn
     index_heads: int
@@ -68,6 +115,23 @@ class LatentSpec:
     n_shared: int
     routed_scale: float = 1.0
     rescale_lora: bool = True
+    mamba: Optional[Mamba] = None
+    gqa: Optional[Gqa] = None
+    expert_form: str = "swiglu"  # 'swiglu': gate, up, down | 'relu2': up, relu(.)^2, down
+    moe_latent: int = 0          # > 0: the routed experts work in a latent this wide
+    shared_width: int = 0        # the shared expert's width, if not moe_width * n_shared
+
+    @property
+    def single(self) -> bool:
+        """Blocks of one mixer each (``SINGLE``)."""
+        return bool(self.layer_kinds) and all(k in SINGLE for k in self.layer_kinds)
+
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        """The layers that hold experts, in order."""
+        if self.single:
+            return tuple(l for l, k in enumerate(self.layer_kinds) if k == "experts")
+        return tuple(range(self.first_dense, len(self.layer_kinds)))
 
     def attn(self, kind: str) -> LatentAttn:
         return self.full if kind == "full" else self.sliding
@@ -94,12 +158,39 @@ def _index_shapes(d: int, s: LatentSpec) -> Dict[str, tuple]:
             "w_ik": (d, s.index_dim), "w_iw": (d, s.index_heads)}
 
 
+def _single_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
+    """A single-mixer block's parameters by name (its one norm apart)."""
+    if kind == "mamba":
+        mb = s.mamba
+        return {"w_in": (d, mb.in_width), "conv_w": (mb.conv, mb.conv_width),
+                "conv_b": (mb.conv_width,), "dt_bias": (mb.num_heads,),
+                "a_log": (mb.num_heads,), "d_skip": (mb.num_heads,),
+                "norm": (mb.d_in,), "w_out": (mb.d_in, d)}
+    if kind == "gqa":
+        g = s.gqa
+        return {"wq": (d, g.num_heads * g.head_dim), "wk": (d, g.num_kv_heads * g.head_dim),
+                "wv": (d, g.num_kv_heads * g.head_dim), "wo": (g.num_heads * g.head_dim, d)}
+    r, fm = s.moe_latent or d, s.moe_width
+    fs = s.shared_width or s.moe_width * s.n_shared
+    gated = s.expert_form == "swiglu"
+    out = {"router": (d, s.n_routed), "bias": (s.n_routed,),
+           "w_up": (s.n_held, r, fm), "w_down": (s.n_held, fm, r),
+           "s_up": (d, fs), "s_down": (fs, d)}
+    if gated:
+        out.update(w_gate=(s.n_held, r, fm), s_gate=(d, fs))
+    if s.moe_latent:
+        out.update(w_lat_down=(d, r), w_lat_up=(r, d))
+    return out
+
+
 def param_count(cfg) -> int:
     """Parameters HELD here (a share of the experts and of the vocabulary
     where the configuration says so)."""
     s, d = cfg.latent, cfg.hidden_size
     size = lambda shapes: sum(int(np.prod(v)) for v in shapes.values())
     n = 2 * cfg.vocab_size * d + d
+    if s.single:
+        return n + sum(d + size(_single_shapes(d, s, kind)) for kind in s.layer_kinds)
     for l, kind in enumerate(s.layer_kinds):
         a = s.attn(kind)
         n += 2 * d + size(_attn_shapes(d, a)) + a.q_rank + a.kv_rank
@@ -122,6 +213,42 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
     def dense(shape, fan_in):
         w = jax.random.normal(next(keys), shape, jnp.float32) / np.sqrt(fan_in)
         return w.astype(dtype)
+
+    def single(kind):
+        """A single-mixer block: matrices N(0, 1 / fan-in); the router's bias
+        as below; a state-space mixer's ``dt_bias`` so that softplus lands
+        log-uniform in [0.001, 0.1], ``A = -exp(a_log)`` in -U(1, 16), ``D``
+        ones, and those three float32 as the recurrence reads them."""
+        w = {}
+        for name, sh in _single_shapes(d, s, kind).items():
+            if len(sh) >= 2:
+                w[name] = dense(sh, sh[-2])
+        if kind == "experts":
+            w["bias"] = (0.02 * jax.random.normal(next(keys), (s.n_routed,))
+                         ).astype(jnp.float32)
+        if kind == "mamba":
+            mb = s.mamba
+            u = lambda lo, hi: jax.random.uniform(next(keys), (mb.num_heads,), jnp.float32, lo, hi)
+            dt = jnp.exp(u(np.log(1e-3), np.log(1e-1)))
+            w.update(conv_b=dense((mb.conv_width,), mb.conv),
+                     dt_bias=dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+                     a_log=jnp.log(u(1.0, 16.0)), d_skip=jnp.ones((mb.num_heads,), jnp.float32),
+                     norm=jnp.ones((mb.d_in,), dtype))
+        return w
+
+    if s.single:
+        layers = {"norm": {"scale": jnp.ones((L, d), dtype)}}
+        for kind in SINGLE:
+            layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
+        return {
+            "embed": {"embedding": dense((cfg.vocab_size, d), d)},
+            "layers": layers,
+            "final_norm": {"scale": jnp.ones((d,), dtype)},
+            "lm_head": {"kernel": dense((d, cfg.vocab_size), d)},
+        }
+    if any(k in SINGLE for k in s.layer_kinds):
+        raise ValueError("single-mixer blocks and attention + feed-forward layers "
+                         "do not mix in one model")
 
     def attn(kind):
         a = s.attn(kind)
@@ -178,6 +305,12 @@ def layer_params(layers: Params, l: int, s: LatentSpec):
     if l < s.first_dense:
         return kind, norms, aw, layers["mlp"][l], False
     return kind, norms, aw, layers["moe"][l - s.first_dense], True
+
+
+def block_params(layers: Params, l: int, s: LatentSpec):
+    """(kind, the norm's scale, the mixer's weights) of single-mixer block ``l``."""
+    kind = s.layer_kinds[l]
+    return kind, layers["norm"]["scale"][l], layers[kind][s.layer_kinds[:l].count(kind)]
 
 
 # ---------------------------------------------------------------------------
@@ -239,6 +372,96 @@ def attn_output(aw, o_lat, gate, a: LatentAttn):
     return o.reshape(o.shape[0], -1) @ aw["wo"]
 
 
+def mamba_chunks(mw, h, valid, cont, conv_prev, state_prev, mb: Mamba, eps: float,
+                 probe=None):
+    """A state-space mixer over CHUNKS of tokens (``ops/ssm.py``: the chunked
+    scan).  h [G, L, d] the normed input; valid [G, L] (padding rows leave the
+    state as it was); cont [G]: the chunk continues the chunk before it, which
+    is then whole; ``conv_prev`` [G, K-1, C] and ``state_prev`` [G, H, P, N] what
+    each other chunk starts from (zeros for a sequence's first).  Returns
+    (out [G, L, d], the state after each chunk float32, the convolution's tail
+    after each chunk's last valid row [G, K-1, C]).  ``probe`` (a list) is
+    handed what the recurrence consumed, a row a token (``_probed``)."""
+    from ..ops import ssm
+
+    g, l, d = h.shape
+    z, xbc, dt = _mamba_inputs(mw, h.reshape(g * l, d), mb)
+    xbc = xbc.reshape(g, l, -1)
+    dt = jnp.where(valid[..., None], dt.reshape(g, l, -1), 0.0)
+    k1 = mb.conv - 1
+    before = jnp.roll(xbc[:, l - k1:], 1, axis=0)  # the chunk before's last rows
+    prev = jnp.where(cont[:, None, None], before, conv_prev.astype(xbc.dtype))
+    conv, ext = ssm.conv_chunks(prev, xbc, mw["conv_w"], mw["conv_b"])
+    x, b, c = _mamba_split(conv, mb)
+    y, states = ssm.ssm_scan(x, dt, -jnp.exp(mw["a_log"]), b, c, state_prev, cont)
+    _probed(probe, g * l, x, b, c, dt)
+    at = jnp.sum(valid, axis=1, dtype=jnp.int32)[:, None] + jnp.arange(k1)[None, :]
+    tails = jnp.take_along_axis(ext, at[..., None], axis=1)
+    out = _mamba_output(mw, y.reshape(g * l, *y.shape[2:]), x.reshape(g * l, *x.shape[2:]),
+                        z, mb, eps, h.dtype)
+    return out.reshape(g, l, d), states, tails
+
+
+def mamba_step(mw, h, active, conv_tail, state, mb: Mamba, eps: float, probe=None):
+    """The same mixer on ONE token a sequence (the recurrence itself).  h
+    [B, d]; ``conv_tail`` [B, K-1, C] and ``state`` [B, H, P, N] are carried on
+    where ``active`` and handed back bit-identical elsewhere.  Returns (out
+    [B, d], state, conv tail)."""
+    from ..ops import ssm
+
+    z, xbc, dt = _mamba_inputs(mw, h, mb)
+    conv, tail = ssm.conv_step(conv_tail, xbc, mw["conv_w"], mw["conv_b"])
+    x, b, c = _mamba_split(conv, mb)
+    y, state = ssm.ssm_step(state, x, dt, -jnp.exp(mw["a_log"]), b, c, active)
+    _probed(probe, h.shape[0], x, b, c, dt)
+    tail = jnp.where(active[:, None, None], tail, conv_tail)
+    return _mamba_output(mw, y, x, z, mb, eps, h.dtype), state, tail
+
+
+def _probed(probe, t: int, x, b, c, dt) -> None:
+    """What a state-space block's recurrence consumed, for a benchmark's check
+    of the state it leaves KEPT: x [t, H, P], B and C [t, R, N], the step
+    sizes [t, H] (0 at padding), float32, a row a token."""
+    if probe is not None:
+        rows = lambda a: a.reshape(t, *a.shape[a.ndim - 2:]).astype(jnp.float32)
+        probe.append({"ssm_x": rows(x), "ssm_b": rows(b), "ssm_c": rows(c),
+                      "ssm_dt": dt.reshape(t, -1).astype(jnp.float32)})
+
+
+def _mamba_inputs(mw, h, mb: Mamba):
+    """h [T, d] -> (gate z [T, d_in], xBC [T, C] before the convolution, the
+    step sizes softplus(dt + dt_bias) [T, H] float32)."""
+    zxd = h @ mw["w_in"]
+    z, xbc, dt = jnp.split(zxd, [mb.d_in, mb.d_in + mb.conv_width], axis=-1)
+    return z, xbc, jax.nn.softplus(dt.astype(jnp.float32) + mw["dt_bias"])
+
+
+def _mamba_split(conv, mb: Mamba):
+    """The convolution's output [..., C] -> (x [..., H, P], B and C [..., R, N])."""
+    x, b, c = jnp.split(conv, [mb.d_in, mb.d_in + mb.n_groups * mb.state], axis=-1)
+    lead = conv.shape[:-1]
+    return (x.reshape(*lead, mb.num_heads, mb.head_dim),
+            b.reshape(*lead, mb.n_groups, mb.state), c.reshape(*lead, mb.n_groups, mb.state))
+
+
+def _mamba_output(mw, y, x, z, mb: Mamba, eps: float, dtype):
+    """y, x [T, H, P] float32, z [T, d_in]: the skip ``D x``, the gate
+    ``silu(z)`` BEFORE the norm, RMSNorm over each group's channels, ``W_out``."""
+    t = y.shape[0]
+    y = (y + mw["d_skip"][:, None] * x).reshape(t, mb.d_in) * jax.nn.silu(z.astype(jnp.float32))
+    yg = y.reshape(t, mb.n_groups, -1)
+    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + eps)
+    return (yg.reshape(t, mb.d_in).astype(dtype) * mw["norm"]) @ mw["w_out"]
+
+
+def gqa_inputs(aw, h, g: Gqa):
+    """h [T, d] -> (q [T, Hq, hd], k, v [T, Hkv, hd]); no positions.  The
+    barrier keeps the head split out of the dots (``model_runner._qkv``)."""
+    q, k, v = jax.lax.optimization_barrier((h @ aw["wq"], h @ aw["wk"], h @ aw["wv"]))
+    heads = lambda a, n: a.reshape(a.shape[0], n, g.head_dim)
+    return heads(q, g.num_heads), heads(k, g.num_kv_heads), heads(v, g.num_kv_heads)
+
+
 def ffn(fw, h, is_moe: bool, cfg, valid=None):
     """(output [T, d], and of an expert layer (routing stats, experts picked
     [T, k]), else None)."""
@@ -258,6 +481,9 @@ def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
     pos = jnp.tile(jnp.arange(n), b)
     x = params["embed"]["embedding"][tokens.reshape(-1)].astype(cfg.dtype)
     grouped = lambda a: a.reshape(b, n, *a.shape[1:])
+    if s_.single:
+        return _head(params, _single_blocks(params["layers"], x, b, n, cfg), b, n, cfg,
+                     return_hidden)
     for l in range(cfg.num_layers):
         kind, (n1, n2), aw, fw, is_moe = layer_params(params["layers"], l, s_)
         a = s_.attn(kind)
@@ -282,10 +508,51 @@ def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
         x = x + attn_output(aw, o.reshape(b * n, *o.shape[2:]), gate, a).astype(x.dtype)
         h = rms(x, n2["scale"], cfg.norm_eps)
         x = x + ffn(fw, h, is_moe, cfg)[0].astype(x.dtype)
+    return _head(params, x, b, n, cfg, return_hidden)
+
+
+def _head(params: Params, x, b: int, n: int, cfg, return_hidden: bool):
+    """The final norm and the head on token rows x [b * n, d]."""
     x = rms(x, params["final_norm"]["scale"], cfg.norm_eps).reshape(b, n, -1)
     if return_hidden:
         return x, None, jnp.asarray(0.0, jnp.float32)
     return x @ params["lm_head"]["kernel"], None, jnp.asarray(0.0, jnp.float32)
+
+
+def _single_blocks(layers: Params, x, b: int, n: int, cfg):
+    """The uncached forward's blocks of one mixer each; x [b * n, d]."""
+    s_ = cfg.latent
+    grouped = lambda a: a.reshape(b, n, *a.shape[1:])
+    for l in range(cfg.num_layers):
+        kind, scale, w = block_params(layers, l, s_)
+        h = rms(x, scale, cfg.norm_eps)
+        if kind == "mamba":
+            y = _mamba_uncached(w, grouped(h), s_.mamba, cfg.norm_eps).reshape(b * n, -1)
+        elif kind == "gqa":
+            q, k, v = (grouped(a) for a in gqa_inputs(w, h, s_.gqa))
+            rep = s_.gqa.num_heads // s_.gqa.num_kv_heads
+            sc = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, n, -1, rep, q.shape[-1]), k
+                            ).astype(jnp.float32) * s_.gqa.head_dim ** -0.5
+            sc = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :], sc, -jnp.inf)
+            o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(sc, -1).astype(v.dtype), v)
+            y = o.reshape(b * n, -1) @ w["wo"]
+        else:
+            y = ffn(w, h, True, cfg)[0]
+        x = x + y.astype(x.dtype)
+    return x
+
+
+def _mamba_uncached(mw, h, mb: Mamba, eps: float):
+    """h [b, n, d], every sequence from a zero state, in chunks of ``mb.chunk``."""
+    b, n, d = h.shape
+    c = -(-n // mb.chunk)
+    h = jnp.pad(h, ((0, 0), (0, c * mb.chunk - n), (0, 0))).reshape(b * c, mb.chunk, d)
+    valid = jnp.tile(jnp.arange(c * mb.chunk) < n, b).reshape(b * c, mb.chunk)
+    cont = jnp.tile(jnp.arange(c) > 0, b)
+    out, _, _ = mamba_chunks(
+        mw, h, valid, cont, jnp.zeros((b * c, mb.conv - 1, mb.conv_width), h.dtype),
+        jnp.zeros((b * c, mb.num_heads, mb.head_dim, mb.state), jnp.float32), mb, eps)
+    return out.reshape(b, c * mb.chunk, d)[:, :n]
 
 
 def refuse(option: str, why: str):

@@ -260,23 +260,26 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.n
     w [G, k, n], sizes [G] int32 (their sum may be under M: the rows past it
     belong to no group and come back undefined).  On a TPU the megablox
     Pallas kernel, which visits only the row tiles that hold a group's rows
-    and reads each group's weights once per tile; elsewhere, and for shapes
-    its tiles do not divide, ``lax.ragged_dot``."""
+    and reads each group's weights once per tile (``M`` padded up to a whole
+    row tile); elsewhere, and for ``k`` / ``n`` its tiles do not divide,
+    ``lax.ragged_dot``."""
     from ..ops.pallas import note_dispatch, on_tpu
 
     m, k = xs.shape
     n = w.shape[-1]
     if not on_tpu():
         note_dispatch("expert_gmm", False, (m, k, n), reason="not on a TPU")
-    elif m % 128 or k % 128 or n % 128:
+    elif k % 128 or n % 128:
         note_dispatch("expert_gmm", False, (m, k, n),
-                      reason="m, k and n must be multiples of 128")
+                      reason="k and n must be multiples of 128")
     else:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         note_dispatch("expert_gmm", True, (m, k, n))
+        if m % _GMM_ROWS:  # rows of no group, up to a whole row tile
+            xs = jnp.pad(xs, ((0, -m % _GMM_ROWS), (0, 0)))
         return gmm(xs, w, sizes, preferred_element_type=xs.dtype,
-                   tiling=_gmm_tiling(k, n))
+                   tiling=_gmm_tiling(k, n))[:m]
     return jax.lax.ragged_dot(xs, w, sizes, preferred_element_type=xs.dtype)
 
 
@@ -299,12 +302,21 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     expert.  The result is this member's PARTIAL sum; the members' results,
     the shared expert counted once, add up to the uncut layer's output.
 
+    The experts' form comes from the spec: ``expert_form`` 'swiglu' (``w_gate``,
+    ``w_up``, ``w_down``) or 'relu2' (``w_up``, ``relu(.)^2``, ``w_down``: no gate
+    matrix, the shared expert alike); with ``moe_latent`` the routed experts
+    work on ``x @ w_lat_down`` and their weighted sum goes back through
+    ``w_lat_up`` (linear, so each member applies it to its own share), while
+    the router and the shared expert read ``x`` at full width.
+
     x [T, d]; ``valid`` [T] bool masks padding rows out of routing.  Returns
     (y [T, d], (stats int32 [4]: pairs routed, pairs on held experts, rows of
     the largest and of the smallest held expert's group; the experts picked
     [T, k]))."""
     t, d = x.shape
     k, g = spec.experts_per_tok, spec.n_held
+    gated = spec.expert_form == "swiglu"
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))
     idx, wts = held_routing(lw, x, spec)
     local = idx - spec.held_offset
     held = (local >= 0) & (local < g)
@@ -325,17 +337,30 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     of = jnp.maximum(jnp.sum(r[:, None] >= pstart[None, :], axis=1) - 1, 0)
     within = r - pstart[of]
     source = jnp.where(within < sizes[of], start[of] + within, 0)  # a pair, sorted order
+    x_in = x
+    if spec.moe_latent:
+        with jax.named_scope("latent_proj"):
+            x_in = x @ lw["w_lat_down"]
     with jax.named_scope("expert_matmul"):
-        xs = x[order[source] // k]  # [T*k + g*tile, d]; padding rows repeat a live one
-        h = jax.nn.silu(grouped_matmul(xs, lw["w_gate"], padded)) \
-            * grouped_matmul(xs, lw["w_up"], padded)
+        xs = x_in[order[source] // k]  # [T*k + g*tile, d]; padding rows repeat a live one
+        if gated:
+            h = jax.nn.silu(grouped_matmul(xs, lw["w_gate"], padded)) \
+                * grouped_matmul(xs, lw["w_up"], padded)
+        else:
+            h = relu2(grouped_matmul(xs, lw["w_up"], padded))
         ys = grouped_matmul(h, lw["w_down"], padded)
     at = jnp.argsort(order).reshape(t, k)  # where each pair sorted to
     mine = jnp.clip(local, 0, g - 1)
     dest = jnp.where(held, pstart[mine] + at - start[mine], 0)  # ... and its padded row
     pairs = ys[dest].astype(jnp.float32)  # [T, k, d]
     y = jnp.sum(jnp.where(held[..., None], pairs * wts[..., None], 0.0), axis=1)
-    shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
+    if spec.moe_latent:
+        with jax.named_scope("latent_proj"):
+            y = y.astype(x.dtype) @ lw["w_lat_up"]
+    if gated:
+        shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
+    else:
+        shared = relu2(x @ lw["s_up"]) @ lw["s_down"]
     n_valid = t if valid is None else jnp.sum(valid, dtype=jnp.int32)
     stats = jnp.stack([jnp.asarray(n_valid * k, jnp.int32),
                        jnp.sum(held, dtype=jnp.int32), jnp.max(sizes), jnp.min(sizes)])

@@ -26,8 +26,45 @@ def _weights(key, bias_scale=0.02):
             "s_gate": n(ks[5], D, F), "s_up": n(ks[6], D, F), "s_down": n(ks[7], F, D)}
 
 
+# the other expert form: two matrices with relu(.)^2 between, the routed experts
+# in a latent LAT wide behind a projection pair, the shared expert FS wide at
+# full width, top-K of E with the weights scaled by 5
+LAT, FS = 16, 24
+SPEC_RELU2 = replace(SPEC, expert_form="relu2", moe_latent=LAT, shared_width=FS,
+                     routed_scale=5.0)
+
+
+def _weights_relu2(key):
+    ks = jax.random.split(key, 8)
+    n = lambda k, *s: jax.random.normal(k, s, jnp.float32) / np.sqrt(s[-2])
+    return {"router": n(ks[0], D, E), "bias": 0.02 * jax.random.normal(ks[1], (E,)),
+            "w_up": n(ks[2], E, LAT, F), "w_down": n(ks[3], E, F, LAT),
+            "w_lat_down": n(ks[4], D, LAT), "w_lat_up": n(ks[5], LAT, D),
+            "s_up": n(ks[6], D, FS), "s_down": n(ks[7], FS, D)}
+
+
 def _swiglu(x, g, u, d):
     return (jax.nn.silu(x @ g) * (x @ u)) @ d
+
+
+def _relu2(x, u, d):
+    return jnp.square(jax.nn.relu(x @ u)) @ d
+
+
+def _uncut(lw, x, spec):
+    """(the uncut layer's output, its shared expert's), either form, every
+    expert on every token masked by the routing; the latent form applies
+    ``w_lat_up`` ONCE, to the whole weighted sum."""
+    if spec.expert_form == "swiglu":
+        return _dense_over_experts(lw, x, spec), _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
+    idx, wts = held_routing(lw, x, spec)
+    lat = x @ lw["w_lat_down"]
+    y = jnp.zeros_like(lat)
+    for e in range(spec.n_routed):
+        w_e = jnp.sum(jnp.where(idx == e, wts, 0.0), -1, keepdims=True)
+        y += w_e * _relu2(lat, lw["w_up"][e], lw["w_down"][e])
+    shared = _relu2(x, lw["s_up"], lw["s_down"])
+    return y @ lw["w_lat_up"] + shared, shared
 
 
 def _dense_over_experts(lw, x, spec):
@@ -40,20 +77,23 @@ def _dense_over_experts(lw, x, spec):
     return y + _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
 
 
-def test_eight_shares_add_up_to_the_uncut_layer():
-    """The guide's share test: each of the members holds E/8 experts, routes
-    over all E and computes its own; their partial sums, the shared expert
-    counted ONCE, are the uncut layer's output."""
-    lw = _weights(jax.random.PRNGKey(0))
+@pytest.mark.parametrize("spec,make,members", [
+    (SPEC, _weights, 8), (SPEC_RELU2, _weights_relu2, 4)], ids=["swiglu-8", "relu2_latent-4"])
+def test_the_shares_add_up_to_the_uncut_layer(spec, make, members):
+    """The guide's share test: each of the members holds its share of the
+    experts, routes over all E and computes its own; their partial sums, the
+    shared expert counted ONCE (and the latent form's ``w_lat_up`` applied to
+    EACH share), are the uncut layer's output."""
+    lw = make(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (24, D))
-    whole = _dense_over_experts(lw, x, SPEC)
-    shared = _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
-    members, held_pairs = 8, 0
+    whole, shared = _uncut(lw, x, spec)
+    held_pairs = 0
     total = jnp.zeros_like(x)
     for r in range(members):
         g = E // members
-        mine = dict(lw, **{k: lw[k][r * g:(r + 1) * g] for k in ("w_gate", "w_up", "w_down")})
-        y, (stats, _) = moe_block_held(mine, x, replace(SPEC, n_held=g, held_offset=r * g))
+        mine = dict(lw, **{k: lw[k][r * g:(r + 1) * g] for k in ("w_gate", "w_up", "w_down")
+                           if k in lw})
+        y, (stats, _) = moe_block_held(mine, x, replace(spec, n_held=g, held_offset=r * g))
         total += y - shared  # every member adds the shared expert: count it once
         held_pairs += int(stats[1])
         assert int(stats[0]) == 24 * K
@@ -61,18 +101,24 @@ def test_eight_shares_add_up_to_the_uncut_layer():
     assert float(jnp.abs(total + shared - whole).max()) <= 1e-5
 
 
-def test_the_bias_selects_and_does_not_weigh():
-    lw = _weights(jax.random.PRNGKey(2), bias_scale=0.0)
+@pytest.mark.parametrize("n_experts,k,scale", [(E, K, 1.0), (64, 22, 5.0)],
+                         ids=["top4_of_16", "top22_of_64_scale5"])
+def test_the_bias_selects_and_does_not_weigh(n_experts, k, scale):
+    spec = replace(SPEC, n_routed=n_experts, experts_per_tok=k, routed_scale=scale)
+    lw = {"router": jax.random.normal(jax.random.PRNGKey(2), (D, n_experts)) / np.sqrt(D),
+          "bias": jnp.zeros(n_experts)}
     x = jax.random.normal(jax.random.PRNGKey(3), (64, D))
-    idx0, w0 = held_routing(lw, x, SPEC)
-    np.testing.assert_allclose(np.asarray(w0.sum(-1)), 1.0, rtol=1e-6)
-    pushed = dict(lw, bias=jnp.zeros(E).at[5].set(10.0))
-    idx1, w1 = held_routing(pushed, x, SPEC)
+    idx0, w0 = held_routing(lw, x, spec)
+    assert idx0.shape == (64, k) and all(len(set(r)) == k for r in np.asarray(idx0).tolist())
+    np.testing.assert_allclose(np.asarray(w0.sum(-1)), scale, rtol=1e-6)
+    pushed = dict(lw, bias=jnp.zeros(n_experts).at[5].set(10.0))
+    idx1, w1 = held_routing(pushed, x, spec)
     assert bool(jnp.all(jnp.any(idx1 == 5, -1)))  # the bias decides the selection
     s = jax.nn.sigmoid(x @ lw["router"])
     picked = jnp.take_along_axis(s, idx1, -1)
-    np.testing.assert_allclose(np.asarray(w1), np.asarray(picked / picked.sum(-1, keepdims=True)),
-                               rtol=1e-6)  # ... and is no part of the weights
+    np.testing.assert_allclose(
+        np.asarray(w1), np.asarray(scale * picked / picked.sum(-1, keepdims=True)),
+        rtol=1e-6)  # ... and is no part of the weights
 
 
 @pytest.mark.parametrize("sizes", [[0, 50, 3, 0, 1, 10], [64, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0],
