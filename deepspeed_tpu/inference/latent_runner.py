@@ -565,7 +565,9 @@ def _state_tick_seam(cfg, pos, block_tables, active, picked, probe):
                      for a, r in zip(arrays, rows))
 
     def read(kind, pools, qkv):
-        return paged_attention_decode(qkv[0], *pools, block_tables, pos + 1)
+        # length 0 = no row in this slot: the kernel skips it
+        return paged_attention_decode(qkv[0], *pools, block_tables,
+                                      jnp.where(active, pos + 1, 0))
 
     return write, read
 

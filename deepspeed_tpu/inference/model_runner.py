@@ -305,8 +305,9 @@ def decode_step(
                 write_decode_kv(kv_l[1], v[:, 0], block_tables, seq_lens, active))
 
     def read(q, k, v, kv_l):
+        # length 0 = no row in this slot: the kernel skips it
         return paged_attention_decode(
-            q[:, 0], *kv_l, block_tables, seq_lens + 1,
+            q[:, 0], *kv_l, block_tables, jnp.where(active, seq_lens + 1, 0),
             logits_soft_cap=cfg.logits_soft_cap, mesh=mesh, dp=dp,
             seq_shards=seq_shards)
 

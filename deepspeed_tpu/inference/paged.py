@@ -450,7 +450,9 @@ def paged_attention_decode(
     """Single-token attention against paged KV.
 
     q [B, hq, hd]; cache_*_layer [num_blocks, bs, hkv, hd];
-    block_table [B, P]; seq_lens [B] (length INCLUDING the current token).
+    block_table [B, P]; seq_lens [B] (length INCLUDING the current token; 0
+    = no row in this slot: kernel and dense body both return finite zeros
+    for it, and the kernel spends a scalar compare on it).
     ``logits_soft_cap`` applies cap*tanh(logits/cap) before masking, matching
     prefill's ``dot_product_attention`` (gemma-2 style).  Returns [B, hq, hd].
 
@@ -509,7 +511,8 @@ def _paged_attention_decode_local(
         note_dispatch(
             "paged_decode", ok, q.shape, interpret=pk._INTERPRET,
             reason="" if ok else "paged_attention.supports() declined "
-            "(soft cap / head_dim alignment); dense gather body ran",
+            "(soft cap / head_dim alignment / VMEM); dense gather body ran",
+            tile_keys=pk.tile_keys(cache_k_layer, block_table) if ok else None,
         )
         if ok:
             return pk.paged_attention_decode_kernel(
@@ -732,4 +735,7 @@ def _paged_attention_decode_dense(
     logits = jnp.where(mask[:, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhk,bkhd->bhd", probs, v.astype(jnp.float32))
+    # a row of length 0 is no row (the kernel's contract): finite zeros,
+    # whatever the pages its table clips to hold
+    out = jnp.where((seq_lens > 0)[:, None, None], out, 0.0)
     return out.astype(q.dtype)

@@ -33,7 +33,7 @@ def on_tpu() -> bool:
 def record_dispatch() -> Iterator[List[dict]]:
     """Collect every kernel-vs-jnp dispatch decision traced inside the
     block (in this thread).  Entries: ``{"kernel", "ran", "mosaic",
-    "shape", "reason"}`` — ``mosaic`` is True only when the Pallas body was
+    "shape", "reason", "tile_keys"}`` — ``mosaic`` is True only when the Pallas body was
     chosen outside interpret mode, i.e. it lowers to a Mosaic custom call."""
     log: List[dict] = []
     token = _RECORDER.set(log)
@@ -44,12 +44,15 @@ def record_dispatch() -> Iterator[List[dict]]:
 
 
 def note_dispatch(kernel: str, ran: bool, shape, *, interpret: bool = False,
-                  reason: str = "") -> None:
-    """Trace-time note from a dispatcher gate (no-op with no recorder)."""
+                  reason: str = "", tile_keys: Optional[int] = None) -> None:
+    """Trace-time note from a dispatcher gate (no-op with no recorder).
+    ``tile_keys``: the keys a tile of the kernel holds, where its rule chose
+    them from the call's shapes (``paged_decode``)."""
     log = _RECORDER.get()
     if log is not None:
         log.append({
             "kernel": kernel, "ran": bool(ran),
             "mosaic": bool(ran) and not interpret,
             "shape": tuple(int(d) for d in shape), "reason": reason,
+            "tile_keys": tile_keys,
         })

@@ -75,17 +75,30 @@ def test_flash_fwd_and_bwd_compile(v5e, hd, hq, hkv):
     assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("hd,hq,hkv", [(128, HQ, HKV), (64, 16, 8)])
-def test_paged_decode_compiles(v5e, hd, hq, hkv):
+@pytest.mark.parametrize("slots,hq,hkv,hd,bs,blocks,pages", [
+    (16, HQ, HKV, 128, BS, 256, 64),
+    (16, 16, 8, 64, BS, 256, 64),       # the packed-lane kernel (hd < 128)
+    (64, HQ, HKV, 128, 32, 2304, 128),  # mistral7b_l16_serve_1chip, exactly
+    (128, 32, 2, 128, 128, 6272, 48),   # nemotron3_super_l11_e128_serve_1chip
+])
+def test_paged_decode_compiles(v5e, slots, hq, hkv, hd, bs, blocks, pages):
+    """A key tile that Mosaic refuses (a DMA slice off the tiling, a VMEM
+    overflow) fails here, on the CPU, not on the chip."""
     from deepspeed_tpu.ops.pallas import paged_attention as pk
 
-    q = _spec((16, hq, hd))
-    pool = _spec((256, BS, hkv, hd))
-    tables = _spec((16, 64), jnp.int32)
-    lens = _spec((16,), jnp.int32)
+    q = _spec((slots, hq, hd))
+    pool = _spec((blocks, bs, hkv, hd))
+    tables = _spec((slots, pages), jnp.int32)
+    lens = _spec((slots,), jnp.int32)
     assert pk.supports(q, pool, None)
-    _assert_mosaic(_compile(pk.paged_attention_decode_kernel, v5e,
-                            q, pool, pool, tables, lens))
+    text = _compile(pk.paged_attention_decode_kernel, v5e,
+                    q, pool, pool, tables, lens)
+    _assert_mosaic(text)
+    if hd % 128 == 0:
+        # the kernel reads a page's (key, kv head) rows in place: the view of
+        # the pool it is handed must cost no copy of the pool
+        assert not [l for l in text.splitlines()
+                    if f"bf16[{blocks}," in l.split("=")[0] and " copy(" in l]
 
 
 def test_paged_decode_gate_declines_unaligned_head_dim():

@@ -72,6 +72,100 @@ def test_kernel_ignores_garbage_in_dead_pages():
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=2e-5)
 
 
+def _tiled_setup(hq, hkv, bs, lens, poison=None, seed=0):
+    """The cells' head layouts (hd 128) over interleaved, non-contiguous
+    table ids with ``-1`` padding; ``poison`` overwrites every page no row
+    owns (K and V alike)."""
+    hd = 128
+    lens = np.asarray(lens, np.int32)
+    B = len(lens)
+    P = -(-int(lens.max()) // bs) + 2
+    need = sum(-(-int(n) // bs) for n in lens)
+    nb = need + 5
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(B, hq, hd)), jnp.float32)
+    ck = jnp.asarray(rng.normal(size=(nb, bs, hkv, hd)), jnp.float32)
+    cv = jnp.asarray(rng.normal(size=(nb, bs, hkv, hd)), jnp.float32)
+    table = np.full((B, P), -1, np.int32)
+    ids = iter(rng.permutation(nb))
+    for i in range(P):  # page i of every row in turn: a row's ids interleave
+        for b in range(B):
+            if i * bs < lens[b]:
+                table[b, i] = next(ids)
+    if poison is not None:
+        dead = jnp.asarray([x for x in range(nb) if x not in set(table[table >= 0].tolist())])
+        ck, cv = ck.at[dead].set(poison), cv.at[dead].set(poison)
+    return q, ck, cv, jnp.asarray(table), jnp.asarray(lens)
+
+
+# (hq, hkv, block): Mistral-7B's and Nemotron-3-Super's attention blocks
+_CELL_SHAPES = [(32, 8, 32), (32, 2, 128)]
+
+
+def _case_lens(bs, hkv, case):
+    kt = pk.tile_keys(jnp.zeros((1, bs, hkv, 128), jnp.float32), jnp.zeros((1, 64), jnp.int32))
+    return {
+        "dead": 0, "one": 1, "mid_page": bs + bs // 2 + 1,
+        "tile_last_key": kt, "tile_first_key": kt + 1,
+        "tiles_and_tail": 2 * kt + bs + 3,
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["dead", "one", "mid_page", "tile_last_key",
+                                  "tile_first_key", "tiles_and_tail"])
+@pytest.mark.parametrize("hq,hkv,bs", _CELL_SHAPES)
+def test_tiled_kernel_parity_at_the_cells_shapes(hq, hkv, bs, case, monkeypatch):
+    """One row of the length under test between two others: the kernel's key
+    tiles (several pages each) against the dense gather."""
+    # small tiles keep the interpreter quick; the rule is the module's
+    monkeypatch.setattr(pk, "_TILE_BYTES", 2 * bs * hkv * 128 * 4)
+    n = _case_lens(bs, hkv, case)
+    q, ck, cv, table, lens = _tiled_setup(hq, hkv, bs, (bs + 1, n, 3))
+    out_k = pk.paged_attention_decode_kernel(q, ck, cv, table, lens)
+    out_d = _paged_attention_decode_dense(q, ck, cv, table, lens)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_d), atol=2e-5)
+    if n == 0:
+        assert not np.asarray(out_k)[1].any()
+
+
+@pytest.mark.parametrize("hq,hkv,bs", _CELL_SHAPES)
+def test_kernel_never_reads_nan_in_pages_no_row_owns(hq, hkv, bs, monkeypatch):
+    """NaN in every page outside the rows' live tables: neither a fetched
+    tile's unfetched tail nor a dead slot may bring one to the output."""
+    monkeypatch.setattr(pk, "_TILE_BYTES", 2 * bs * hkv * 128 * 4)
+    lens = (0, 1, 2 * bs + 1, 5 * bs - 1)
+    q, ck, cv, table, lens = _tiled_setup(hq, hkv, bs, lens, poison=jnp.nan)
+    out_k = np.asarray(pk.paged_attention_decode_kernel(q, ck, cv, table, lens))
+    assert np.isfinite(out_k).all()
+    clean = _tiled_setup(hq, hkv, bs, lens, poison=0.0)
+    out_d = np.asarray(_paged_attention_decode_dense(*clean))
+    np.testing.assert_allclose(out_k, out_d, atol=2e-5)
+
+
+def test_dead_row_is_finite_zeros_from_kernel_and_dense_body():
+    """length 0 = no row: the runners hand it to inactive slots, and the two
+    bodies stay each other's ground truth (``finite_guard`` sees no NaN even
+    where the slot's table clips to poisoned pages)."""
+    q, ck, cv, table, lens = _tiled_setup(8, 2, 16, (0, 40, 0), poison=jnp.nan)
+    for body in (pk.paged_attention_decode_kernel, _paged_attention_decode_dense):
+        out = np.asarray(body(q, ck, cv, table, lens))
+        assert np.isfinite(out).all(), body.__name__
+        assert not out[0].any() and not out[2].any(), body.__name__
+        assert out[1].any(), body.__name__
+
+
+def test_dispatch_notes_the_tile_the_rule_chose():
+    from deepspeed_tpu.inference.paged import paged_attention_decode
+    from deepspeed_tpu.ops.pallas import record_dispatch
+
+    q, ck, cv, table, lens = _setup()
+    with record_dispatch() as log:
+        paged_attention_decode(q, ck, cv, table, lens)
+    (entry,) = [e for e in log if e["kernel"] == "paged_decode"]
+    assert entry["ran"] and entry["tile_keys"] == pk.tile_keys(ck, table)
+    assert entry["tile_keys"] % ck.shape[1] == 0  # whole pages
+
+
 def test_dispatch_routes_to_kernel_in_interpret_mode():
     from deepspeed_tpu.inference.paged import paged_attention_decode
 
