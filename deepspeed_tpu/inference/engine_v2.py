@@ -113,8 +113,8 @@ class InferenceEngineV2:
             from ..models.latent import refuse
             from .latent_runner import LatentRunner
 
-            # a single-mixer model's per-slot state is a recurrence's, not a ring
-            states = cfg.latent.single
+            # a slot's state is a recurrence's (state-space or delta rule), not a ring
+            states = cfg.latent.stateful
             for option, on, why in (
                 ("grid (a tensor-parallel / replica / seq-shard serve mesh)",
                  grid is not None or int(serve_replicas) > 1 or int(seq_shards) > 1,

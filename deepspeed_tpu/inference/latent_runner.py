@@ -32,6 +32,13 @@ A model of single-mixer blocks (``models/latent.py:SINGLE``) keeps instead:
 - an expert block keeps nothing but its routing counts (``touched``: held
   experts with a row and the pairs on them, of packs and of ticks).
 
+A model of two-norm blocks (``models/latent.py:HYBRID``: Gated DeltaNet beside
+gated GQA, an expert layer in every block) keeps the same three things under
+the same names: per ``gdn`` block and slot the delta rule's MATRIX state
+(``ssm``, [Hv, Dk, Dv] float32) and its convolution's tail (``conv``), zeroed by
+position, carried and recomputed as above; per ``gattn`` block K / V pages (the
+keys after their norm and rotation); per block its experts' routing counts.
+
 One layer body (``_layer``) serves the pack and the tick; the kind chooses how
 the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
@@ -86,7 +93,8 @@ COUNTERS = (
 )
 
 
-# ... and of one whose blocks are single mixers
+# ... and of one whose slots keep a recurrence's state (``LatentSpec.stateful``:
+# state-space or delta rule; the names say ``ssm`` for either)
 STATE_COUNTERS = (
     "ssm_states_reset",       # sequences that began from a zero state (admissions, resumes)
     "ssm_states_recomputed",  # states a preemption discarded: the resume scans them again
@@ -117,7 +125,7 @@ def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
     if pack_tokens % block_size:
         raise ValueError(f"a pack of {pack_tokens} tokens is no whole number of "
                          f"pages of {block_size}")
-    if s.single:
+    if s.stateful:
         return _init_state_cache(cfg, num_blocks, block_size, max_seqs, dtype)
     pages = lambda w, n: tuple(
         jnp.zeros((num_blocks, block_size, w), dtype) for _ in range(n))
@@ -138,17 +146,19 @@ def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
 
 def _init_state_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
                       dtype) -> Cache:
-    """The cache of a model of single-mixer blocks (module docstring)."""
+    """The cache of a model whose slots keep a recurrence's state and K / V
+    pages (``LatentSpec.stateful``; module docstring)."""
     from .paged import init_paged_cache
 
-    s, mb, g = cfg.latent, cfg.latent.mamba, cfg.latent.gqa
-    k, v = init_paged_cache(s.count("gqa"), num_blocks, block_size, g.num_kv_heads,
+    s = cfg.latent
+    (rec, mb), (att, g) = s.recurrence, s.attention
+    k, v = init_paged_cache(s.count(att), num_blocks, block_size, g.num_kv_heads,
                             g.head_dim, dtype=dtype)
     per = lambda shape, dt: tuple(jnp.zeros((max_seqs, *shape), dt)
-                                  for _ in range(s.count("mamba")))
-    n_moe = s.count("experts")
+                                  for _ in range(s.count(rec)))
+    n_moe = len(s.expert_layers)
     return {
-        "ssm": per((mb.num_heads, mb.head_dim, mb.state), jnp.float32),
+        "ssm": per(mb.state_shape, jnp.float32),
         "conv": per((mb.conv - 1, mb.conv_width), dtype),
         "k": k, "v": v,
         "stats": jnp.zeros((n_moe, len(ROUTING_STATS)), jnp.int32).at[:, 3].set(_NO_MIN),
@@ -287,6 +297,9 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, prob
     s = cfg.latent
     if s.single:
         return _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe)
+    if s.hybrid:
+        return _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_groups,
+                             probe)
     kind, (n1, n2), aw, fw, is_moe = lm.layer_params(layers, l, s)
     a, i = s.attn(kind), s.layer_kinds[:l].count(kind)
     h = lm.rms(x, n1["scale"], cfg.norm_eps)
@@ -328,8 +341,7 @@ def _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe):
     i = s.layer_kinds[:l].count(kind)
     h = lm.rms(x, scale, cfg.norm_eps)
     if kind == "mamba":
-        ssm, conv, y = write(kind, (cache["ssm"][i], cache["conv"][i]), (w, h))
-        cache = {**cache, "ssm": _put(cache["ssm"], i, ssm), "conv": _put(cache["conv"], i, conv)}
+        y, cache = _recurrence(kind, i, w, h, cache, write)
     elif kind == "gqa":
         q, k, v = lm.gqa_inputs(w, h, s.gqa)
         pools = write(kind, (cache["k"][i], cache["v"][i]), (k, v))
@@ -338,15 +350,53 @@ def _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe):
             o = read(kind, pools, (q, k, v))
         y = o.reshape(x.shape[0], -1).astype(x.dtype) @ w["wo"]
     else:
-        y, (routed, picked) = lm.ffn(w, h, True, cfg, valid)
-        if probe is not None:
-            probe.append({"experts_picked": picked})
-        local = picked - s.held_offset
-        rows = (local[..., None] == jnp.arange(s.n_held)) & valid[:, None, None]
-        cache = {**cache,
-                 "stats": _routing_counted(cache["stats"], i, routed, track_groups),
-                 "touched": cache["touched"].at[i, 0 if track_groups else 1].add(jnp.stack(
-                     [jnp.sum(jnp.any(rows, axis=(0, 1)), dtype=jnp.int32), routed[1]]))}
+        y, cache = _experts(cfg, i, w, h, valid, cache, track_groups, probe)
+    return x + y.astype(x.dtype), cache
+
+
+def _recurrence(kind, i, w, h, cache, write):
+    """A recurrence's block ``i`` of its kind through the seam: (y, cache with
+    the state and the convolution's tail it leaves)."""
+    ssm, conv, y = write(kind, (cache["ssm"][i], cache["conv"][i]), (w, h))
+    return y, {**cache, "ssm": _put(cache["ssm"], i, ssm), "conv": _put(cache["conv"], i, conv)}
+
+
+def _experts(cfg, i, w, h, valid, cache, track_groups, probe):
+    """Expert layer ``i`` on normed rows ``h``: (y, cache with its routing and
+    the held experts it touched counted, a pack's and a tick's apart)."""
+    s = cfg.latent
+    y, (routed, picked) = lm.ffn(w, h, True, cfg, valid)
+    if probe is not None:
+        probe.append({"experts_picked": picked})
+    local = picked - s.held_offset
+    rows = (local[..., None] == jnp.arange(s.n_held)) & valid[:, None, None]
+    return y, {**cache,
+               "stats": _routing_counted(cache["stats"], i, routed, track_groups),
+               "touched": cache["touched"].at[i, 0 if track_groups else 1].add(jnp.stack(
+                   [jnp.sum(jnp.any(rows, axis=(0, 1)), dtype=jnp.int32), routed[1]]))}
+
+
+def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, probe):
+    """One two-norm block (``models/latent.py:HYBRID``) on token rows ``x``
+    [T, d], through ``_block``'s seam: a Gated DeltaNet mixer's ``write`` IS its
+    read, gated attention writes K / V rows (the keys normed and rotated) and
+    reads the pools, with the q / k norms, the rotation and the output gate
+    outside the kernels; then the expert layer."""
+    s, eps = cfg.latent, cfg.norm_eps
+    kind, (n1, n2), mw, fw = lm.hybrid_params(layers, l, s)
+    i = s.layer_kinds[:l].count(kind)
+    h = lm.rms_centred(x, n1, eps)
+    if kind == "gdn":
+        y, cache = _recurrence(kind, i, mw, h, cache, write)
+    else:
+        q, k, v, gate = lm.gattn_inputs(mw, h, pos, s.gattn, eps)
+        pools = write(kind, (cache["k"][i], cache["v"][i]), (k, v))
+        cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
+        with jax.named_scope("gated_attn"):
+            o = read(kind, pools, (q, k, v))
+        y = lm.gattn_output(mw, o.astype(x.dtype), gate)
+    x = x + y.astype(x.dtype)
+    y, cache = _experts(cfg, l, fw, lm.rms_centred(x, n2, eps), valid, cache, track_groups, probe)
     return x + y.astype(x.dtype), cache
 
 
@@ -360,7 +410,7 @@ def _put(items: tuple, i: int, value) -> tuple:
 
 
 def _logits(params, cfg, x):
-    x = lm.rms(x, params["final_norm"]["scale"], cfg.norm_eps)
+    x = lm.norm(x, params["final_norm"]["scale"], cfg)
     return (x @ params["lm_head"]["kernel"]).astype(jnp.float32)
 
 
@@ -375,7 +425,7 @@ def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_i
     t = tokens.shape[0]
     valid = segment_ids > 0
     picked: list = []
-    seam = _state_pack_seam if cfg.latent.single else _latent_pack_seam
+    seam = _state_pack_seam if cfg.latent.stateful else _latent_pack_seam
     write, read = seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache,
                        picked, probe)
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
@@ -451,7 +501,8 @@ def _write_pages(pool, rows, pages):
 
 def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache, picked,
                      probe):
-    """A pack's (write, read) for single-mixer blocks.  The pack is chunks of
+    """A pack's (write, read) for blocks that keep a recurrence's state or K / V
+    pages (``LatentSpec.stateful``).  The pack is chunks of
     one page of one sequence (``bs`` tokens: the scan's chunk); a chunk whose
     first position is 0 starts from ZEROS, a chunk that follows its own
     sequence's chunk in the pack takes the state handed over inside the scan,
@@ -479,12 +530,13 @@ def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cac
     grouped = lambda a: a.reshape(g, bs, *a.shape[1:])
 
     def write(kind, arrays, rows):
-        if kind == "mamba":
+        if kind in lm.RECURRENCES:
             (ssm, conv), (w, h) = arrays, rows
             zero = lambda a: jnp.where(fresh.reshape(g, *(1,) * (a.ndim - 1)), 0, a)
-            y, states, tails = lm.mamba_chunks(
+            chunks, _ = lm.RECURRENCES[kind]
+            y, states, tails = chunks(
                 w, grouped(h), grouped(valid), cont, zero(conv[slot]), zero(ssm[slot]),
-                s.mamba, cfg.norm_eps, probe)
+                s.recurrence[1], cfg.norm_eps, probe)
             return (ssm.at[keep].set(states.astype(ssm.dtype), mode="drop"),
                     conv.at[keep].set(tails.astype(conv.dtype), mode="drop"),
                     y.reshape(t, -1))
@@ -501,7 +553,7 @@ def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cach
     """One batched decode tick (``model_runner.decode_step``'s arguments).
     Returns (logits [B, vocab], cache)."""
     picked: list = []
-    seam = _state_tick_seam if cfg.latent.single else _latent_tick_seam
+    seam = _state_tick_seam if cfg.latent.stateful else _latent_tick_seam
     write, read = seam(cfg, seq_lens, block_tables, active, picked, probe)
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
     for l in range(cfg.num_layers):
@@ -549,22 +601,32 @@ def _latent_tick_seam(cfg, pos, block_tables, active, picked, probe):
 
 
 def _state_tick_seam(cfg, pos, block_tables, active, picked, probe):
-    """A decode tick's (write, read) for single-mixer blocks: the recurrence's
+    """A decode tick's (write, read) for such blocks: the recurrence's
     one step on every slot's state IN PLACE (idle slots keep their bits), one
     new K / V row a live slot."""
-    from .paged import paged_attention_decode, write_decode_kv
+    from .paged import paged_attention_decode, paged_attention_packed_ctx, write_decode_kv
 
     s = cfg.latent
 
     def write(kind, arrays, rows):
-        if kind == "mamba":
+        if kind in lm.RECURRENCES:
             (ssm, conv), (w, h) = arrays, rows
-            y, ssm, conv = lm.mamba_step(w, h, active, conv, ssm, s.mamba, cfg.norm_eps, probe)
+            _, step = lm.RECURRENCES[kind]
+            y, ssm, conv = step(w, h, active, conv, ssm, s.recurrence[1], cfg.norm_eps, probe)
             return ssm, conv, y
         return tuple(write_decode_kv(a, r, block_tables, pos, active)
                      for a, r in zip(arrays, rows))
 
     def read(kind, pools, qkv):
+        if qkv[0].shape[-1] > 128:
+            # a head wider than one 128-lane tile: the decode kernel's view of a
+            # page as (key, kv head) rows is then not the pool's own bytes, and XLA
+            # re-lays the WHOLE pool out for it (18 ms a tick for 4 x 1 GiB, seen
+            # on the chip); the packed-ctx kernel reads the pool as it lies, and a
+            # tick is a pack of one-row segments over their cached context
+            segment_ids = jnp.where(active, jnp.arange(pos.shape[0]) + 1, 0)
+            return paged_attention_packed_ctx(*qkv, segment_ids, *pools, block_tables,
+                                              jnp.where(active, pos, 0))
         # length 0 = no row in this slot: the kernel skips it
         return paged_attention_decode(qkv[0], *pools, block_tables,
                                       jnp.where(active, pos + 1, 0))
@@ -590,13 +652,13 @@ class LatentRunner:
 
     counters = COUNTERS
     packs_are_one_program = True  # a pack reads its own rows back from the cache
-    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj
+    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn
 
     def __init__(self, cfg):
         self.cfg = cfg
         self._ring_rows = np.zeros(0, np.int64)
         self._block = 1
-        if cfg.latent.single:
+        if cfg.latent.stateful:
             self.counters = STATE_COUNTERS
             self._discarded = 0  # states a preemption left behind since the last dispatch
 
@@ -621,7 +683,8 @@ class LatentRunner:
 
     def verify_packed_ctx(self, *args, **kw):
         lm.refuse("enable_speculation (verify_packed_ctx)", "a rejected draft's rows "
-                  "cannot be rolled back out of a sliding layer's ring")
+                  "cannot be rolled back out of a sliding layer's ring, nor its tokens "
+                  "out of a recurrence's state")
 
     def decode_step(self, params, cfg, tokens, seq_lens, block_tables, active, kv_cache,
                     ctx=None, mesh=None, dp: int = 1, seq_shards: int = 1):
@@ -641,7 +704,7 @@ class LatentRunner:
         and no group (nor is a pack's entry of one token, which the program
         cannot tell apart here)."""
         s = self.cfg.latent
-        if s.single:
+        if s.stateful:
             return self._states_dispatched(counters, work, pack)
         topk, win, bs = s.index_topk, s.sliding.window, self._block
         scored = selected = dropped = groups = dense = 0
@@ -668,10 +731,10 @@ class LatentRunner:
         return out
 
     def _states_dispatched(self, counters, work, pack: bool) -> Dict[str, int]:
-        """Single-mixer blocks: a pack's entry is ``ceil((end - start) / page)``
-        chunks a state-space block, from a zero state if it starts at 0; a
-        decode tick's is one step of a live slot's state."""
-        bs, n_ssm = self._block, self.cfg.latent.count("mamba")
+        """A recurrence's blocks (state-space or delta rule): a pack's entry is
+        ``ceil((end - start) / page)`` chunks a block, from a zero state if it
+        starts at 0; a decode tick's is one step of a live slot's state."""
+        bs, n_ssm = self._block, self.cfg.latent.count(self.cfg.latent.recurrence[0])
         segments = chunks = reset = steps = 0
         for slot, a, b in work:
             if pack:
@@ -690,15 +753,15 @@ class LatentRunner:
         return {"ssm_live_slots": steps}
 
     def released(self, seq) -> None:
-        if self.cfg.latent.single and seq.preempted and self._ring_rows[seq.slot]:
+        if self.cfg.latent.stateful and seq.preempted and self._ring_rows[seq.slot]:
             self._discarded += 1
         self._ring_rows[seq.slot] = 0
 
     def audit(self) -> Dict[str, int]:
         """State still owned by a sequence: rows of window state (a ring is
-        nobody's once its slot is released), or slots whose state-space state
+        nobody's once its slot is released), or slots whose recurrence's state
         is a live sequence's."""
-        if self.cfg.latent.single:
+        if self.cfg.latent.stateful:
             return {"ssm_states": int(np.count_nonzero(self._ring_rows))}
         return {"window_rows": int(self._ring_rows.sum())}
 

@@ -21,8 +21,17 @@ A model may instead be made of blocks that are ONE norm and ONE mixer
 (``gqa``), or the expert layer alone (``experts``), whose experts may be of two
 matrices with ``relu(.)^2`` between and work in a latent (``expert_form``,
 ``moe_latent``).  Their trees are ``layers/norm`` (stacked) and one tuple per
-kind; ``block_params`` picks block ``l``'s.  The two families do not mix in
-one model.
+kind; ``block_params`` picks block ``l``'s.
+
+The families meet in a third (``HYBRID``): blocks of TWO norms whose mixer is a
+recurrence with a matrix state (``gdn``: Gated DeltaNet, ``ops/gdn.py``) or
+grouped-query attention with a wide head, q / k norms, rotary positions on part
+of the head and an output gate (``gattn``), and whose feed-forward is the expert
+layer in EVERY block, routed by a softmax over all experts (``routing``) with
+the shared expert behind a gate of its own (``shared_gate``); the norms'
+weights are zero-centred, ``x^ (1 + w)`` (``unit_offset``).  Trees:
+``layers/attn_norm``, ``layers/mlp_norm`` (stacked, float32) and one tuple per
+kind (``gdn``, ``gattn``, ``moe``); ``hybrid_params`` picks block ``l``'s.
 """
 from __future__ import annotations
 
@@ -88,6 +97,10 @@ class Mamba:
     def in_width(self) -> int:  # [z | xBC | dt]
         return self.d_in + self.conv_width + self.num_heads
 
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:  # what a sequence keeps, float32
+        return self.num_heads, self.head_dim, self.state
+
 
 @dataclass(frozen=True)
 class Gqa:
@@ -98,9 +111,56 @@ class Gqa:
     head_dim: int
 
 
+HYBRID = ("gdn", "gattn")  # mixers of a two-norm block whose feed-forward is the expert layer
+
+
+@dataclass(frozen=True)
+class Gdn:
+    """A Gated DeltaNet mixer: ``num_v_heads`` value heads of ``v_dim`` over
+    ``num_k_heads`` key heads of ``k_dim`` (value head ``j`` reads key head
+    ``j // (num_v_heads / num_k_heads)``), a matrix state ``k_dim x v_dim`` per
+    value head, a causal depthwise convolution over ``conv`` tokens."""
+
+    num_k_heads: int
+    k_dim: int
+    num_v_heads: int
+    v_dim: int
+    conv: int
+    chunk: int  # tokens a chunk of the uncached forward's scan (serving: a page)
+
+    @property
+    def key_width(self) -> int:
+        return self.num_k_heads * self.k_dim
+
+    @property
+    def d_in(self) -> int:
+        return self.num_v_heads * self.v_dim
+
+    @property
+    def conv_width(self) -> int:  # q, k and v go through the convolution
+        return 2 * self.key_width + self.d_in
+
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:  # what a sequence keeps, float32
+        return self.num_v_heads, self.k_dim, self.v_dim
+
+
+@dataclass(frozen=True)
+class GatedGqa:
+    """Grouped-query attention with RMSNorm on each head's q and k, rotary
+    positions (rotate-half) on the first ``rope_dim`` of ``head_dim`` and a
+    sigmoid gate on the output, projected beside q."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_dim: int
+    rope_theta: float
+
+
 @dataclass(frozen=True)
 class LatentSpec:
-    layer_kinds: Tuple[str, ...]  # 'full' | 'sliding', or SINGLE's: one per layer held
+    layer_kinds: Tuple[str, ...]  # 'full' | 'sliding', or SINGLE's, or HYBRID's: one per layer held
     full: LatentAttn
     sliding: LatentAttn
     index_heads: int
@@ -120,6 +180,11 @@ class LatentSpec:
     expert_form: str = "swiglu"  # 'swiglu': gate, up, down | 'relu2': up, relu(.)^2, down
     moe_latent: int = 0          # > 0: the routed experts work in a latent this wide
     shared_width: int = 0        # the shared expert's width, if not moe_width * n_shared
+    gdn: Optional[Gdn] = None
+    gattn: Optional[GatedGqa] = None
+    routing: str = "sigmoid"     # 'sigmoid': + bias, the picked normalised | 'softmax': over all, the picked renormalised
+    shared_gate: bool = False    # the shared expert's output times sigmoid(x . w_sg)
+    unit_offset: bool = False    # RMSNorm's weights are zero-centred: x^ (1 + w)
 
     @property
     def single(self) -> bool:
@@ -127,11 +192,32 @@ class LatentSpec:
         return bool(self.layer_kinds) and all(k in SINGLE for k in self.layer_kinds)
 
     @property
+    def hybrid(self) -> bool:
+        """Two-norm blocks of a recurrence or gated attention and experts (``HYBRID``)."""
+        return bool(self.layer_kinds) and all(k in HYBRID for k in self.layer_kinds)
+
+    @property
+    def stateful(self) -> bool:
+        """What a slot keeps is a recurrence's state of fixed size and K / V
+        pages (``single`` or ``hybrid``), not latent pages and window rings."""
+        return self.single or self.hybrid
+
+    @property
     def expert_layers(self) -> Tuple[int, ...]:
         """The layers that hold experts, in order."""
         if self.single:
             return tuple(l for l, k in enumerate(self.layer_kinds) if k == "experts")
         return tuple(range(self.first_dense, len(self.layer_kinds)))
+
+    @property
+    def recurrence(self):
+        """(kind, mixer) of a ``stateful`` model's recurrence."""
+        return ("mamba", self.mamba) if self.single else ("gdn", self.gdn)
+
+    @property
+    def attention(self):
+        """(kind, mixer) of a ``stateful`` model's attention over K / V pages."""
+        return ("gqa", self.gqa) if self.single else ("gattn", self.gattn)
 
     def attn(self, kind: str) -> LatentAttn:
         return self.full if kind == "full" else self.sliding
@@ -180,7 +266,25 @@ def _single_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
         out.update(w_gate=(s.n_held, r, fm), s_gate=(d, fs))
     if s.moe_latent:
         out.update(w_lat_down=(d, r), w_lat_up=(r, d))
+    if s.routing == "softmax":
+        del out["bias"]  # a softmax router selects by its scores alone
+    if s.shared_gate:
+        out["w_sg"] = (d, 1)
     return out
+
+
+def _hybrid_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
+    """A two-norm block's mixer parameters by name (``HYBRID``); its expert
+    layer's are ``_single_shapes(d, s, "experts")``."""
+    if kind == "gdn":
+        gd = s.gdn
+        return {"w_qkvz": (d, gd.conv_width + gd.d_in), "w_ba": (d, 2 * gd.num_v_heads),
+                "conv_w": (gd.conv, gd.conv_width), "dt_bias": (gd.num_v_heads,),
+                "a_log": (gd.num_v_heads,), "norm": (gd.v_dim,), "w_out": (gd.d_in, d)}
+    ga = s.gattn
+    return {"wq": (d, 2 * ga.num_heads * ga.head_dim), "wk": (d, ga.num_kv_heads * ga.head_dim),
+            "wv": (d, ga.num_kv_heads * ga.head_dim), "q_norm": (ga.head_dim,),
+            "k_norm": (ga.head_dim,), "wo": (ga.num_heads * ga.head_dim, d)}
 
 
 def param_count(cfg) -> int:
@@ -191,6 +295,9 @@ def param_count(cfg) -> int:
     n = 2 * cfg.vocab_size * d + d
     if s.single:
         return n + sum(d + size(_single_shapes(d, s, kind)) for kind in s.layer_kinds)
+    if s.hybrid:
+        return n + sum(2 * d + size(_hybrid_shapes(d, s, kind))
+                       + size(_single_shapes(d, s, "experts")) for kind in s.layer_kinds)
     for l, kind in enumerate(s.layer_kinds):
         a = s.attn(kind)
         n += 2 * d + size(_attn_shapes(d, a)) + a.q_rank + a.kv_rank
@@ -215,40 +322,58 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         return w.astype(dtype)
 
     def single(kind):
-        """A single-mixer block: matrices N(0, 1 / fan-in); the router's bias
-        as below; a state-space mixer's ``dt_bias`` so that softplus lands
-        log-uniform in [0.001, 0.1], ``A = -exp(a_log)`` in -U(1, 16), ``D``
-        ones, and those three float32 as the recurrence reads them."""
-        w = {}
-        for name, sh in _single_shapes(d, s, kind).items():
-            if len(sh) >= 2:
-                w[name] = dense(sh, sh[-2])
-        if kind == "experts":
+        """A single-mixer block, or a two-norm block's mixer: matrices N(0, 1 /
+        fan-in); the router's bias as below; a recurrence's ``dt_bias`` so that
+        softplus lands log-uniform in [0.001, 0.1], ``A = -exp(a_log)`` in
+        -U(1, 16), ``D`` ones, and those float32 as the recurrence reads them."""
+        shapes = _hybrid_shapes(d, s, kind) if kind in HYBRID else _single_shapes(d, s, kind)
+        w = {name: dense(sh, sh[-2]) for name, sh in shapes.items() if len(sh) >= 2}
+        if "bias" in shapes:
             w["bias"] = (0.02 * jax.random.normal(next(keys), (s.n_routed,))
                          ).astype(jnp.float32)
-        if kind == "mamba":
-            mb = s.mamba
-            u = lambda lo, hi: jax.random.uniform(next(keys), (mb.num_heads,), jnp.float32, lo, hi)
+        if kind in ("mamba", "gdn"):
+            heads = s.mamba.num_heads if kind == "mamba" else s.gdn.num_v_heads
+            u = lambda lo, hi: jax.random.uniform(next(keys), (heads,), jnp.float32, lo, hi)
             dt = jnp.exp(u(np.log(1e-3), np.log(1e-1)))
-            w.update(conv_b=dense((mb.conv_width,), mb.conv),
-                     dt_bias=dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
-                     a_log=jnp.log(u(1.0, 16.0)), d_skip=jnp.ones((mb.num_heads,), jnp.float32),
-                     norm=jnp.ones((mb.d_in,), dtype))
+            if kind == "mamba":
+                w["conv_b"] = dense((s.mamba.conv_width,), s.mamba.conv)
+            w.update(dt_bias=dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+                     a_log=jnp.log(u(1.0, 16.0)))
+        if kind == "mamba":
+            w.update(d_skip=jnp.ones((s.mamba.num_heads,), jnp.float32),
+                     norm=jnp.ones((s.mamba.d_in,), dtype))
+        if kind == "gdn":
+            w["norm"] = jnp.ones((s.gdn.v_dim,), jnp.float32)
+        if kind == "gattn":
+            w.update(q_norm=centred((s.gattn.head_dim,)), k_norm=centred((s.gattn.head_dim,)))
         return w
 
+    def centred(shape):
+        """A zero-centred norm's weight (``x^ (1 + w)``), float32: N(0, 0.1^2),
+        so that the offset is not the whole of it."""
+        return 0.1 * jax.random.normal(next(keys), shape, jnp.float32)
+
+    head = lambda layers, norm: {
+        "embed": {"embedding": dense((cfg.vocab_size, d), d)},
+        "layers": layers,
+        "final_norm": {"scale": norm},
+        "lm_head": {"kernel": dense((d, cfg.vocab_size), d)},
+    }
     if s.single:
         layers = {"norm": {"scale": jnp.ones((L, d), dtype)}}
         for kind in SINGLE:
             layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
-        return {
-            "embed": {"embedding": dense((cfg.vocab_size, d), d)},
-            "layers": layers,
-            "final_norm": {"scale": jnp.ones((d,), dtype)},
-            "lm_head": {"kernel": dense((d, cfg.vocab_size), d)},
-        }
-    if any(k in SINGLE for k in s.layer_kinds):
-        raise ValueError("single-mixer blocks and attention + feed-forward layers "
-                         "do not mix in one model")
+        return head(layers, jnp.ones((d,), dtype))
+    if s.hybrid:
+        layers = {"attn_norm": {"scale": centred((L, d))}, "mlp_norm": {"scale": centred((L, d))},
+                  "moe": tuple(single("experts") for _ in range(L))}
+        for kind in HYBRID:
+            layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
+        return head(layers, centred((d,)))
+    if any(k in SINGLE + HYBRID for k in s.layer_kinds):
+        raise ValueError("single-mixer blocks, two-norm blocks of a recurrence or gated "
+                         "attention, and latent-attention layers are three families: a "
+                         "model's layers are all of one")
 
     def attn(kind):
         a = s.attn(kind)
@@ -287,12 +412,7 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         {"w_gate": dense((d, f), d), "w_up": dense((d, f), d), "w_down": dense((f, d), f)}
         for _ in range(nd))
     layers["moe"] = tuple(experts() for _ in range(nm))
-    return {
-        "embed": {"embedding": dense((cfg.vocab_size, d), d)},
-        "layers": layers,
-        "final_norm": {"scale": jnp.ones((d,), dtype)},
-        "lm_head": {"kernel": dense((d, cfg.vocab_size), d)},
-    }
+    return head(layers, jnp.ones((d,), dtype))
 
 
 def layer_params(layers: Params, l: int, s: LatentSpec):
@@ -313,6 +433,14 @@ def block_params(layers: Params, l: int, s: LatentSpec):
     return kind, layers["norm"]["scale"][l], layers[kind][s.layer_kinds[:l].count(kind)]
 
 
+def hybrid_params(layers: Params, l: int, s: LatentSpec):
+    """(kind, the two norms' weights, the mixer's weights, the expert layer's)
+    of two-norm block ``l`` (``HYBRID``)."""
+    kind = s.layer_kinds[l]
+    norms = (layers["attn_norm"]["scale"][l], layers["mlp_norm"]["scale"][l])
+    return kind, norms, layers[kind][s.layer_kinds[:l].count(kind)], layers["moe"][l]
+
+
 # ---------------------------------------------------------------------------
 # the per-token halves of a layer: rows are tokens, [T, ...]
 # ---------------------------------------------------------------------------
@@ -320,6 +448,18 @@ def rms(x, scale, eps):
     xf = x.astype(jnp.float32)
     xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return xf.astype(x.dtype) * scale
+
+
+def rms_centred(x, w, eps):
+    """RMSNorm with a zero-centred weight, ``x^ (1 + w)``, scaled in float32."""
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def norm(x, scale, cfg):
+    """The model's RMSNorm by its spec (``unit_offset``)."""
+    return (rms_centred if cfg.latent.unit_offset else rms)(x, scale, cfg.norm_eps)
 
 
 def _rope(x, pos, theta: float):
@@ -462,6 +602,119 @@ def gqa_inputs(aw, h, g: Gqa):
     return heads(q, g.num_heads), heads(k, g.num_kv_heads), heads(v, g.num_kv_heads)
 
 
+def gdn_chunks(gw, h, valid, cont, conv_prev, state_prev, gd: Gdn, eps: float, probe=None):
+    """A Gated DeltaNet mixer over CHUNKS of tokens (``ops/gdn.py``: the chunked
+    delta rule), with ``mamba_chunks``' arguments and results: h [G, L, d]; valid
+    [G, L] (padding rows leave the state as it was); cont [G]; ``conv_prev``
+    [G, K-1, C] and ``state_prev`` [G, Hv, Dk, Dv] what each other chunk starts
+    from.  Returns (out [G, L, d], the state after each chunk float32, the
+    convolution's tail after each chunk's last valid row [G, K-1, C])."""
+    from ..ops import gdn, ssm
+
+    n, l, d = h.shape
+    qkv, z, g, beta = _gdn_inputs(gw, h.reshape(n * l, d), gd)
+    qkv = qkv.reshape(n, l, -1)
+    g, beta = (jnp.where(valid[..., None], a.reshape(n, l, -1), 0.0) for a in (g, beta))
+    k1 = gd.conv - 1
+    before = jnp.roll(qkv[:, l - k1:], 1, axis=0)  # the chunk before's last rows
+    prev = jnp.where(cont[:, None, None], before, conv_prev.astype(qkv.dtype))
+    with jax.named_scope("gdn_conv"):
+        conv, ext = ssm.conv_chunks(prev, qkv, gw["conv_w"], _no_bias(gd))
+    q, k, v = _gdn_split(conv, gd)
+    o, states = gdn.gdn_scan(q, k, v, g, beta, state_prev, cont)
+    _gdn_probed(probe, n * l, q, k, v, g, beta)
+    at = jnp.sum(valid, axis=1, dtype=jnp.int32)[:, None] + jnp.arange(k1)[None, :]
+    tails = jnp.take_along_axis(ext, at[..., None], axis=1)
+    out = _gdn_output(gw, o.reshape(n * l, *o.shape[2:]), z, gd, eps, h.dtype)
+    return out.reshape(n, l, d), states, tails
+
+
+def gdn_step(gw, h, active, conv_tail, state, gd: Gdn, eps: float, probe=None):
+    """The same mixer on ONE token a sequence (the recurrence itself), as
+    ``mamba_step``: h [B, d]; ``conv_tail`` [B, K-1, C] and ``state`` [B, Hv, Dk,
+    Dv] are carried on where ``active`` and handed back bit-identical elsewhere.
+    Returns (out [B, d], state, conv tail)."""
+    from ..ops import gdn, ssm
+
+    qkv, z, g, beta = _gdn_inputs(gw, h, gd)
+    with jax.named_scope("gdn_conv"):
+        conv, tail = ssm.conv_step(conv_tail, qkv, gw["conv_w"], _no_bias(gd))
+    q, k, v = _gdn_split(conv, gd)
+    o, state = gdn.gdn_step(state, q, k, v, g, beta, active)
+    _gdn_probed(probe, h.shape[0], q, k, v, g, beta)
+    tail = jnp.where(active[:, None, None], tail, conv_tail)
+    return _gdn_output(gw, o, z, gd, eps, h.dtype), state, tail
+
+
+RECURRENCES = {"mamba": (mamba_chunks, mamba_step), "gdn": (gdn_chunks, gdn_step)}  # (chunks, step)
+
+
+def _no_bias(gd: Gdn):
+    return jnp.zeros((gd.conv_width,), jnp.float32)  # this convolution has none
+
+
+def _gdn_probed(probe, t: int, q, k, v, g, beta) -> None:
+    """What a Gated DeltaNet block's recurrence consumed, for a benchmark's
+    check of the state it leaves KEPT: q, k [t, Hk, Dk] as normalised, v [t, Hv,
+    Dv], the log decays and the betas [t, Hv] (0 at padding), float32."""
+    if probe is not None:
+        rows = lambda a, nd: a.reshape(t, *a.shape[a.ndim - nd:]).astype(jnp.float32)
+        probe.append({"gdn_q": rows(q, 2), "gdn_k": rows(k, 2), "gdn_v": rows(v, 2),
+                      "gdn_g": rows(g, 1), "gdn_beta": rows(beta, 1)})
+
+
+def _gdn_inputs(gw, h, gd: Gdn):
+    """h [T, d] -> ([q | k | v] [T, C] before the convolution, the output gate
+    z [T, d_in], the log decay ``-exp(a_log) softplus(a + dt_bias)`` and
+    ``beta = sigmoid(b)`` [T, Hv] float32)."""
+    qkv, z = jnp.split(h @ gw["w_qkvz"], [gd.conv_width], axis=-1)
+    b, a = jnp.split((h @ gw["w_ba"]).astype(jnp.float32), 2, axis=-1)
+    g = -jnp.exp(gw["a_log"]) * jax.nn.softplus(a + gw["dt_bias"])
+    return qkv, z, g, jax.nn.sigmoid(b)
+
+
+def _gdn_split(conv, gd: Gdn):
+    """The convolution's output [..., C] float32 -> (q, k [..., Hk, Dk], v [...,
+    Hv, Dv]) as the recurrence consumes them: k of unit length, q of length
+    ``Dk^-1/2``."""
+    q, k, v = jnp.split(conv, [gd.key_width, 2 * gd.key_width], axis=-1)
+    lead = conv.shape[:-1]
+    unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+    q, k = (unit(a.reshape(*lead, gd.num_k_heads, gd.k_dim)) for a in (q, k))
+    return q * gd.k_dim ** -0.5, k, v.reshape(*lead, gd.num_v_heads, gd.v_dim)
+
+
+def _gdn_output(gw, o, z, gd: Gdn, eps: float, dtype):
+    """o [T, Hv, Dv] float32, z [T, d_in]: RMSNorm over each head's channels
+    (a plain weight) BEFORE the gate ``silu(z)``, then ``W_out``."""
+    t = o.shape[0]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * gw["norm"]
+    y = o.reshape(t, gd.d_in) * jax.nn.silu(z.astype(jnp.float32))
+    return y.astype(dtype) @ gw["w_out"]
+
+
+def gattn_inputs(aw, h, pos, ga: GatedGqa, eps: float):
+    """h [T, d] at positions ``pos`` [T] -> (q [T, Hq, hd], k, v [T, Hkv, hd],
+    the output gate [T, Hq * hd] float32): each query head's projection is [q |
+    gate]; q and k normed over the head (zero-centred weights) and rotated on
+    their first ``rope_dim`` dims.  The barrier as in ``gqa_inputs``."""
+    t = h.shape[0]
+    qg, k, v = jax.lax.optimization_barrier((h @ aw["wq"], h @ aw["wk"], h @ aw["wv"]))
+    q, gate = jnp.split(qg.reshape(t, ga.num_heads, 2 * ga.head_dim), 2, axis=-1)
+    heads = lambda a: a.reshape(t, ga.num_kv_heads, ga.head_dim)
+    rot = lambda a: jnp.concatenate(
+        [_rope(a[..., :ga.rope_dim], pos, ga.rope_theta), a[..., ga.rope_dim:]], axis=-1)
+    q = rot(rms_centred(q, aw["q_norm"], eps))
+    k = rot(rms_centred(heads(k), aw["k_norm"], eps))
+    return q, k, heads(v), jax.nn.sigmoid(gate.reshape(t, -1).astype(jnp.float32))
+
+
+def gattn_output(aw, o, gate):
+    """Attention's output o [T, Hq, hd] times the gate, through ``W_o``."""
+    y = o.reshape(o.shape[0], -1).astype(jnp.float32) * gate
+    return y.astype(o.dtype) @ aw["wo"]
+
+
 def ffn(fw, h, is_moe: bool, cfg, valid=None):
     """(output [T, d], and of an expert layer (routing stats, experts picked
     [T, k]), else None)."""
@@ -481,9 +734,9 @@ def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
     pos = jnp.tile(jnp.arange(n), b)
     x = params["embed"]["embedding"][tokens.reshape(-1)].astype(cfg.dtype)
     grouped = lambda a: a.reshape(b, n, *a.shape[1:])
-    if s_.single:
-        return _head(params, _single_blocks(params["layers"], x, b, n, cfg), b, n, cfg,
-                     return_hidden)
+    if s_.stateful:
+        blocks = _single_blocks if s_.single else _hybrid_blocks
+        return _head(params, blocks(params["layers"], x, b, n, cfg), b, n, cfg, return_hidden)
     for l in range(cfg.num_layers):
         kind, (n1, n2), aw, fw, is_moe = layer_params(params["layers"], l, s_)
         a = s_.attn(kind)
@@ -513,7 +766,7 @@ def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
 
 def _head(params: Params, x, b: int, n: int, cfg, return_hidden: bool):
     """The final norm and the head on token rows x [b * n, d]."""
-    x = rms(x, params["final_norm"]["scale"], cfg.norm_eps).reshape(b, n, -1)
+    x = norm(x, params["final_norm"]["scale"], cfg).reshape(b, n, -1)
     if return_hidden:
         return x, None, jnp.asarray(0.0, jnp.float32)
     return x @ params["lm_head"]["kernel"], None, jnp.asarray(0.0, jnp.float32)
@@ -527,35 +780,62 @@ def _single_blocks(layers: Params, x, b: int, n: int, cfg):
         kind, scale, w = block_params(layers, l, s_)
         h = rms(x, scale, cfg.norm_eps)
         if kind == "mamba":
-            y = _mamba_uncached(w, grouped(h), s_.mamba, cfg.norm_eps).reshape(b * n, -1)
+            y = _chunked_uncached(mamba_chunks, w, grouped(h), s_.mamba, cfg.norm_eps)
+            y = y.reshape(b * n, -1)
         elif kind == "gqa":
-            q, k, v = (grouped(a) for a in gqa_inputs(w, h, s_.gqa))
-            rep = s_.gqa.num_heads // s_.gqa.num_kv_heads
-            sc = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, n, -1, rep, q.shape[-1]), k
-                            ).astype(jnp.float32) * s_.gqa.head_dim ** -0.5
-            sc = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :], sc, -jnp.inf)
-            o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(sc, -1).astype(v.dtype), v)
-            y = o.reshape(b * n, -1) @ w["wo"]
+            y = _causal_gqa(*gqa_inputs(w, h, s_.gqa), b, n).reshape(b * n, -1) @ w["wo"]
         else:
             y = ffn(w, h, True, cfg)[0]
         x = x + y.astype(x.dtype)
     return x
 
 
-def _mamba_uncached(mw, h, mb: Mamba, eps: float):
-    """h [b, n, d], every sequence from a zero state, in chunks of ``mb.chunk``."""
+def _causal_gqa(q, k, v, b: int, n: int):
+    """Dense causal attention of ``b`` sequences of ``n`` rows each: q [b * n,
+    Hq, hd], k and v [b * n, Hkv, hd] -> [b * n, Hq, hd]."""
+    hd, rep = q.shape[-1], q.shape[1] // k.shape[1]
+    q, k, v = (a.reshape(b, n, *a.shape[1:]) for a in (q, k, v))
+    sc = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, n, -1, rep, hd), k
+                    ).astype(jnp.float32) * hd ** -0.5
+    sc = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :], sc, -jnp.inf)
+    o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(sc, -1).astype(v.dtype), v)
+    return o.reshape(b * n, -1, hd)
+
+
+def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
+    """The uncached forward's two-norm blocks (``HYBRID``); x [b * n, d]."""
+    s_, eps = cfg.latent, cfg.norm_eps
+    pos = jnp.tile(jnp.arange(n), b)
+    for l in range(cfg.num_layers):
+        kind, (n1, n2), mw, fw = hybrid_params(layers, l, s_)
+        h = rms_centred(x, n1, eps)
+        if kind == "gdn":
+            y = _chunked_uncached(gdn_chunks, mw, h.reshape(b, n, -1), s_.gdn, eps)
+            y = y.reshape(b * n, -1)
+        else:
+            q, k, v, gate = gattn_inputs(mw, h, pos, s_.gattn, eps)
+            y = gattn_output(mw, _causal_gqa(q, k, v, b, n), gate)
+        x = x + y.astype(x.dtype)
+        x = x + ffn(fw, rms_centred(x, n2, eps), True, cfg)[0].astype(x.dtype)
+    return x
+
+
+def _chunked_uncached(chunks, w, h, mixer, eps: float):
+    """A recurrence's ``chunks`` (``mamba_chunks`` | ``gdn_chunks``) on h [b, n,
+    d], every sequence from a zero state in chunks of ``mixer.chunk``."""
     b, n, d = h.shape
-    c = -(-n // mb.chunk)
-    h = jnp.pad(h, ((0, 0), (0, c * mb.chunk - n), (0, 0))).reshape(b * c, mb.chunk, d)
-    valid = jnp.tile(jnp.arange(c * mb.chunk) < n, b).reshape(b * c, mb.chunk)
+    size, c = mixer.chunk, -(-n // mixer.chunk)
+    h = jnp.pad(h, ((0, 0), (0, c * size - n), (0, 0))).reshape(b * c, size, d)
+    valid = jnp.tile(jnp.arange(c * size) < n, b).reshape(b * c, size)
     cont = jnp.tile(jnp.arange(c) > 0, b)
-    out, _, _ = mamba_chunks(
-        mw, h, valid, cont, jnp.zeros((b * c, mb.conv - 1, mb.conv_width), h.dtype),
-        jnp.zeros((b * c, mb.num_heads, mb.head_dim, mb.state), jnp.float32), mb, eps)
-    return out.reshape(b, c * mb.chunk, d)[:, :n]
+    out, _, _ = chunks(
+        w, h, valid, cont, jnp.zeros((b * c, mixer.conv - 1, mixer.conv_width), h.dtype),
+        jnp.zeros((b * c, *mixer.state_shape), jnp.float32), mixer, eps)
+    return out.reshape(b, c * size, d)[:, :n]
 
 
 def refuse(option: str, why: str):
     raise NotImplementedError(
         f"{option} is not supported for a model with layers of several kinds "
-        f"(TransformerConfig.latent): {why}")
+        f"(TransformerConfig.latent: latent attention, single-mixer blocks, or two-norm "
+        f"blocks of a recurrence / gated attention): {why}")

@@ -238,7 +238,7 @@ class MoE:
 
 
 # ---------------------------------------------------------------------------
-# a HELD share of sigmoid-routed experts (serving; models/latent.py)
+# a HELD share of routed experts (serving; models/latent.py)
 # ---------------------------------------------------------------------------
 _GMM_ROWS = 128  # the grouped matmul's row tile
 
@@ -284,11 +284,20 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.n
 
 
 def held_routing(lw: Any, x: jnp.ndarray, spec) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """DeepSeek-V3's ``noaux_tc`` routing without groups: sigmoid scores in
-    float32, the ``experts_per_tok`` largest of score + bias picked, weights
-    the picked scores normalised (the bias selects, it does not weigh).
+    """The routing in the form the spec names (``routing``, chosen when the
+    program is traced).  'sigmoid': DeepSeek-V3's ``noaux_tc`` without groups:
+    sigmoid scores in float32, the ``experts_per_tok`` largest of score + bias
+    picked, weights the picked scores normalised (the bias selects, it does not
+    weigh), times ``routed_scale``.  'softmax': below.
     x [T, d] -> (experts [T, k], weights [T, k] float32)."""
-    s = jax.nn.sigmoid(x.astype(jnp.float32) @ lw["router"].astype(jnp.float32))
+    scores = x.astype(jnp.float32) @ lw["router"].astype(jnp.float32)
+    if spec.routing == "softmax":
+        # softmax over ALL experts in float32, the largest picked and
+        # renormalised (``norm_topk_prob``); no bias, no scale
+        s = jax.nn.softmax(scores, axis=-1)
+        picked, idx = jax.lax.top_k(s, spec.experts_per_tok)
+        return idx, picked / jnp.sum(picked, -1, keepdims=True)
+    s = jax.nn.sigmoid(scores)
     _, idx = jax.lax.top_k(s + lw["bias"], spec.experts_per_tok)
     picked = jnp.take_along_axis(s, idx, axis=-1)
     return idx, picked / jnp.sum(picked, -1, keepdims=True) * spec.routed_scale
@@ -307,7 +316,8 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     matrix, the shared expert alike); with ``moe_latent`` the routed experts
     work on ``x @ w_lat_down`` and their weighted sum goes back through
     ``w_lat_up`` (linear, so each member applies it to its own share), while
-    the router and the shared expert read ``x`` at full width.
+    the router and the shared expert read ``x`` at full width.  With
+    ``shared_gate`` the shared expert's output is scaled by ``sigmoid(x . w_sg)``.
 
     x [T, d]; ``valid`` [T] bool masks padding rows out of routing.  Returns
     (y [T, d], (stats int32 [4]: pairs routed, pairs on held experts, rows of
@@ -361,6 +371,9 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
         shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
     else:
         shared = relu2(x @ lw["s_up"]) @ lw["s_down"]
+    if spec.shared_gate:  # the shared expert behind a gate of its own
+        gate = jax.nn.sigmoid((x @ lw["w_sg"]).astype(jnp.float32))
+        shared = (shared.astype(jnp.float32) * gate).astype(x.dtype)
     n_valid = t if valid is None else jnp.sum(valid, dtype=jnp.int32)
     stats = jnp.stack([jnp.asarray(n_valid * k, jnp.int32),
                        jnp.sum(held, dtype=jnp.int32), jnp.max(sizes), jnp.min(sizes)])

@@ -1,8 +1,10 @@
-"""A model of single-mixer blocks (``models/latent.py:SINGLE``: Mamba-2 state
-beside paged GQA, a held share of latent-space relu^2 experts) through
-``InferenceEngineV2`` and its scheduler, against the benchmark's plain
-reference, at the rehearsal size of the benchmark's configuration of it:
-float32, CPU, seeded weights."""
+"""The two families whose slots keep a recurrence's state beside K / V pages,
+through ``InferenceEngineV2`` and its scheduler, against the benchmark's plain
+reference, at the rehearsal size of the benchmark's configuration of each
+(float32, CPU, seeded weights): single-mixer blocks (``models/latent.py:SINGLE``:
+Mamba-2 state beside paged GQA, a held share of latent-space relu^2 experts)
+and two-norm blocks (``HYBRID``: a Gated DeltaNet matrix state beside gated GQA
+with partial rotary, a held share of softmax-routed experts in every block)."""
 import sys
 from pathlib import Path
 
@@ -19,17 +21,26 @@ from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.inference.sampling import SamplingParams  # noqa: E402
 from deepspeed_tpu.models.transformer import init_params  # noqa: E402
 
-CONFIG = "benchmark/configs/nemotron3_super_l11_e128_serve_1chip.json"
+# configuration, its family, its recurrence's kind and how many blocks run it
+CONFIGS = {
+    "nemotron_h": ("benchmark/configs/nemotron3_super_l11_e128_serve_1chip.json",
+                   "single", "mamba", 5),
+    "qwen3_next": ("benchmark/configs/qwen3_next_l8_e128_serve_1chip.json",
+                   "hybrid", "gdn", 6),
+}
 PAGE, CHUNK = 8, 32  # the engine's page (= the scan's chunk) and pack here
 GREEDY = lambda n: SamplingParams(temperature=0.0, max_new_tokens=n)
 
 
-@pytest.fixture(scope="module")
-def model():
-    m = harness.rehearsed(harness.load_json(ROOT / CONFIG), True)
+@pytest.fixture(scope="module", params=sorted(CONFIGS))
+def model(request):
+    path, family, rec, n_rec = CONFIGS[request.param]
+    m = harness.rehearsed(harness.load_json(ROOT / path), True)
     arch = harness.module("models", m["model_type"])
     cfg = arch.transformer_config(m, max_seq_len=m["engine"]["max_seq_len"])
-    assert cfg.latent.single and cfg.latent.layer_kinds.count("mamba") == 5
+    s = cfg.latent
+    assert getattr(s, family) and s.stateful and s.single != s.hybrid
+    assert s.recurrence[0] == rec and s.count(rec) == n_rec
     params = init_params(jax.random.PRNGKey(7), cfg)
     ref = jax.jit(lambda p, t: arch.logits(p, t, m))
     return m, arch, cfg, params, ref
@@ -71,10 +82,10 @@ def test_chunked_prefill_and_decode_match_the_reference(model):
         assert len(out) == 12 and _short(ref, params, p, out) <= 1e-4, u
     assert eng.stats["prefill_dispatches"] < sum(-(-len(p) // CHUNK) for p in prompts.values())
     # the host's count of chunks is the positions' arithmetic: every chunk of a
-    # prompt is ceil(tokens / page) pages, in each of the 5 state-space blocks
+    # prompt is ceil(tokens / page) pages, in each of the recurrence's blocks
     chunks = sum(-(-min(CHUNK, len(p) - a) // PAGE)
                  for p in prompts.values() for a in range(0, len(p), CHUNK))
-    assert eng.stats["ssm_chunks_scanned"] == 5 * chunks
+    assert eng.stats["ssm_chunks_scanned"] == cfg.latent.count(cfg.latent.recurrence[0]) * chunks
     assert eng.stats["ssm_states_reset"] == 4 and eng.stats["ssm_states_recomputed"] == 0
     audit = eng.close()
     assert audit == {"blocks_in_use": 0, "cached_blocks": 0, "ssm_states": 0}
@@ -82,7 +93,8 @@ def test_chunked_prefill_and_decode_match_the_reference(model):
     assert eng.stats["expert_pairs_routed"] > eng.stats["expert_pairs_held"] > 0
     share = eng.stats["expert_pairs_held"] / eng.stats["expert_pairs_routed"]
     assert 0.1 < share < 0.45  # 4 of 16 experts held: about a quarter
-    assert 0 < eng.stats["experts_touched"] <= eng.stats["expert_pairs_held"]
+    assert 0 < eng.stats["experts_touched_decode"] < eng.stats["experts_touched"] \
+        <= eng.stats["expert_pairs_held"]
 
 
 def test_a_slots_next_owner_starts_from_zero(model):
@@ -128,6 +140,10 @@ def test_the_tick_leaves_idle_slots_state_bit_identical(model):
     m, arch, cfg, params, ref = model
     rng = np.random.default_rng(5)
     cache = latent_runner.init_cache(cfg, 16, PAGE, 4, CHUNK)
+    (rec, mixer), (att, _) = cfg.latent.recurrence, cfg.latent.attention
+    assert len(cache["ssm"]) == cfg.latent.count(rec) and len(cache["k"]) == cfg.latent.count(att)
+    assert cache["ssm"][0].shape == (4, *mixer.state_shape)
+    assert cache["ssm"][0].dtype == jax.numpy.float32
     noise = lambda a: jax.numpy.asarray(rng.standard_normal(a.shape), a.dtype)
     cache = {**cache, "ssm": tuple(map(noise, cache["ssm"])),
              "conv": tuple(map(noise, cache["conv"]))}

@@ -1,6 +1,6 @@
 """The expert layer of one MEMBER of an expert-parallel deployment
-(``moe/layer.py:moe_block_held``): sigmoid routing over all experts, a grouped
-matmul over the pairs that fall on the experts held here."""
+(``moe/layer.py:moe_block_held``): routing over all experts (sigmoid + bias, or
+a softmax), a grouped matmul over the pairs that fall on the experts held here."""
 from dataclasses import replace
 
 import jax
@@ -43,6 +43,17 @@ def _weights_relu2(key):
             "s_up": n(ks[6], D, FS), "s_down": n(ks[7], FS, D)}
 
 
+# the other routing: a softmax over all E, the K largest renormalised, no bias,
+# and the shared expert behind a sigmoid gate of its own
+SPEC_SOFTMAX = replace(SPEC, routing="softmax", shared_gate=True)
+
+
+def _weights_softmax(key):
+    lw = _weights(key)
+    del lw["bias"]
+    return dict(lw, w_sg=jax.random.normal(jax.random.fold_in(key, 9), (D, 1)) / np.sqrt(D))
+
+
 def _swiglu(x, g, u, d):
     return (jax.nn.silu(x @ g) * (x @ u)) @ d
 
@@ -56,7 +67,10 @@ def _uncut(lw, x, spec):
     expert on every token masked by the routing; the latent form applies
     ``w_lat_up`` ONCE, to the whole weighted sum."""
     if spec.expert_form == "swiglu":
-        return _dense_over_experts(lw, x, spec), _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
+        shared = _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
+        if spec.shared_gate:
+            shared = jax.nn.sigmoid(x @ lw["w_sg"]) * shared
+        return _dense_over_experts(lw, x, spec, shared), shared
     idx, wts = held_routing(lw, x, spec)
     lat = x @ lw["w_lat_down"]
     y = jnp.zeros_like(lat)
@@ -67,18 +81,19 @@ def _uncut(lw, x, spec):
     return y @ lw["w_lat_up"] + shared, shared
 
 
-def _dense_over_experts(lw, x, spec):
+def _dense_over_experts(lw, x, spec, shared):
     """Every expert on every token, masked by the routing: the plain form."""
     idx, wts = held_routing(lw, x, spec)
     y = jnp.zeros_like(x)
     for e in range(spec.n_routed):
         w_e = jnp.sum(jnp.where(idx == e, wts, 0.0), -1, keepdims=True)
         y += w_e * _swiglu(x, lw["w_gate"][e], lw["w_up"][e], lw["w_down"][e])
-    return y + _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
+    return y + shared
 
 
 @pytest.mark.parametrize("spec,make,members", [
-    (SPEC, _weights, 8), (SPEC_RELU2, _weights_relu2, 4)], ids=["swiglu-8", "relu2_latent-4"])
+    (SPEC, _weights, 8), (SPEC_RELU2, _weights_relu2, 4), (SPEC_SOFTMAX, _weights_softmax, 4)],
+    ids=["swiglu-8", "relu2_latent-4", "softmax_gated_shared-4"])
 def test_the_shares_add_up_to_the_uncut_layer(spec, make, members):
     """The guide's share test: each of the members holds its share of the
     experts, routes over all E and computes its own; their partial sums, the
@@ -119,6 +134,25 @@ def test_the_bias_selects_and_does_not_weigh(n_experts, k, scale):
     np.testing.assert_allclose(
         np.asarray(w1), np.asarray(scale * picked / picked.sum(-1, keepdims=True)),
         rtol=1e-6)  # ... and is no part of the weights
+
+
+@pytest.mark.parametrize("n_experts,k", [(E, K), (64, 10)], ids=["top4_of_16", "top10_of_64"])
+def test_softmax_routing_is_over_all_experts_and_renormalised(n_experts, k):
+    """The softmax form against plain numpy: probabilities over ALL experts,
+    the k largest picked, their weights the picked probabilities over their
+    sum; a bias, were one there, is not read."""
+    spec = replace(SPEC_SOFTMAX, n_routed=n_experts, experts_per_tok=k, routed_scale=7.0)
+    lw = {"router": jax.random.normal(jax.random.PRNGKey(8), (D, n_experts)) / np.sqrt(D) * 3}
+    x = jax.random.normal(jax.random.PRNGKey(9), (64, D))
+    idx, w = held_routing(lw, x, spec)
+    logits = np.asarray(x, np.float64) @ np.asarray(lw["router"], np.float64)
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.argsort(-p, axis=-1)[:, :k]
+    assert np.array_equal(np.sort(np.asarray(idx), -1), np.sort(want, -1))
+    picked = np.take_along_axis(p, np.asarray(idx), -1)
+    np.testing.assert_allclose(np.asarray(w), picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)  # no routed_scale
 
 
 @pytest.mark.parametrize("sizes", [[0, 50, 3, 0, 1, 10], [64, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0],
