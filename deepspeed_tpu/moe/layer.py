@@ -303,6 +303,29 @@ def held_routing(lw: Any, x: jnp.ndarray, spec) -> tuple[jnp.ndarray, jnp.ndarra
     return idx, picked / jnp.sum(picked, -1, keepdims=True) * spec.routed_scale
 
 
+def _padded_source(sizes: jnp.ndarray, rows: int, tile: int) -> jnp.ndarray:
+    """The map from the rows handed to the grouped matmul to the (token, pick)
+    pairs in sorted order.  Each group starts on a row tile of the kernel (its
+    rows padded up to whole tiles): a group that straddled a tile boundary had
+    its expert's weights streamed once per tile, half as many reads again at
+    ~64 rows a group, and how many straddled followed the routing, so the
+    layer's time did too.  sizes int32 [g], the groups' rows -> int32 [rows]:
+    the sorted pair a row holds, 0 for a padding row.
+
+    A tile lies in ONE group, so the group, its first pair and its size are
+    looked up a TILE at a time and a row adds its place in the tile: a lookup
+    a row is a gather of one index a row, which the chip walks an index at a
+    time, 0.17 ms each at 21 504 rows (my chip run, PR 37), and three of them
+    with the pairs' gather cost as much as the matmuls they prepared."""
+    ptiles = -(-sizes // tile)
+    start, tstart = jnp.cumsum(sizes) - sizes, jnp.cumsum(ptiles) - ptiles
+    tiles = jnp.arange(-(-rows // tile))
+    of = jnp.maximum(jnp.sum(tiles[:, None] >= tstart[None, :], axis=1) - 1, 0)
+    within = ((tiles - tstart[of]) * tile)[:, None] + jnp.arange(tile)
+    source = jnp.where(within < sizes[of][:, None], start[of][:, None] + within, 0)
+    return source.reshape(-1)[:rows]  # the last tile may reach past the rows
+
+
 def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     """The expert layer as ONE member of an expert-parallel deployment sees
     it, without the exchange: route every token over all ``n_routed`` experts,
@@ -332,21 +355,14 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     held = (local >= 0) & (local < g)
     if valid is not None:
         held &= valid[:, None]
-    key = jnp.where(held, local, g).reshape(-1)  # pairs of no held expert sort last
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
-    # Each group starts on a row tile of the kernel (its rows padded up to
-    # whole tiles): a group that straddled a tile boundary had its expert's
-    # weights streamed once per tile, half as many reads again at ~64 rows a
-    # group, and how many straddled followed the routing, so the layer's time
-    # did too.  Row r of the padded layout is row ``within`` of group ``of``.
-    tile = _GMM_ROWS
-    padded = -(-sizes // tile) * tile
-    start, pstart = jnp.cumsum(sizes) - sizes, jnp.cumsum(padded) - padded
-    r = jnp.arange(t * k + g * tile)
-    of = jnp.maximum(jnp.sum(r[:, None] >= pstart[None, :], axis=1) - 1, 0)
-    within = r - pstart[of]
-    source = jnp.where(within < sizes[of], start[of] + within, 0)  # a pair, sorted order
+    with jax.named_scope("expert_layout"):
+        key = jnp.where(held, local, g).reshape(-1)  # pairs of no held expert sort last
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
+        tile = _GMM_ROWS
+        padded = -(-sizes // tile) * tile
+        start, pstart = jnp.cumsum(sizes) - sizes, jnp.cumsum(padded) - padded
+        source = _padded_source(sizes, t * k + g * tile, tile)  # a pair, sorted order
     x_in = x
     if spec.moe_latent:
         with jax.named_scope("latent_proj"):

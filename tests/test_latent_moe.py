@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models.latent import LatentAttn, LatentSpec
+from deepspeed_tpu.moe import layer
 from deepspeed_tpu.moe.layer import grouped_matmul, held_routing, moe_block_held
 
 D, F, E, K = 32, 16, 16, 4
@@ -190,3 +191,75 @@ def test_held_layer_under_skewed_routing_and_padding():
     assert float(jnp.abs(y - want)[:33].max()) <= 1e-5
     assert int(stats[0]) == 33 * K and int(stats[2]) == 33 and int(stats[3]) == 0
     assert bool(jnp.all(picked == idx))
+
+
+# -- the padded layout's map (PR 37) ---------------------------------------------
+def _source_per_row(sizes, rows, tile):
+    """``_padded_source`` as ``moe_block_held`` computed it before PR 37, a ROW
+    at a time: the plain reference the per-tile form is held to."""
+    padded = -(-sizes // tile) * tile
+    start, pstart = jnp.cumsum(sizes) - sizes, jnp.cumsum(padded) - padded
+    r = jnp.arange(rows)
+    of = jnp.maximum(jnp.sum(r[:, None] >= pstart[None, :], axis=1) - 1, 0)
+    within = r - pstart[of]
+    return jnp.where(within < sizes[of], start[of] + within, 0)
+
+
+def _keys(case, t, k, g, rng):
+    """The sort key of ``t * k`` (token, pick) pairs: the held expert, or ``g``
+    for a pair no held expert takes."""
+    local = np.stack([rng.choice(4 * g, size=k, replace=False) for _ in range(t)])
+    if case == "empty_groups":  # the even experts take nothing
+        local = np.where(local % 2 == 0, g, local)
+    elif case == "one_group_holds_all":
+        local = np.full((t, k), g - 1)
+    elif case == "nothing_held":
+        local = np.full((t, k), g)
+    held = local < g
+    if case == "valid_mask":  # padding rows are masked out of routing
+        held &= (rng.random(t) < 0.6)[:, None]
+    return jnp.asarray(np.where(held, local, g).reshape(-1), jnp.int32)
+
+
+@pytest.mark.parametrize("case", ["empty_groups", "one_group_holds_all", "nothing_held",
+                                  "valid_mask"])
+@pytest.mark.parametrize("t,k,g", [(512, 10, 128), (16, 10, 128), (128, 22, 128), (2048, 8, 32),
+                                   (3, 2, 6)],
+                         ids=["pack_512x10_of_128", "tick_16x10_not_a_whole_tile",
+                              "tick_128x22_of_128", "pack_2048x8_of_32", "tiny_3x2_of_6"])
+def test_the_map_a_tile_at_a_time_is_the_map_a_row_at_a_time(t, k, g, case):
+    """The sorted pair every padded row holds, and the (token, pick) pair
+    gathered through it, element for element."""
+    key = _keys(case, t, k, g, np.random.default_rng(t + k))
+    tile, rows = layer._GMM_ROWS, t * k + g * layer._GMM_ROWS
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
+    want = _source_per_row(sizes, rows, tile)
+    got = jax.jit(layer._padded_source, static_argnums=(1, 2))(sizes, rows, tile)
+    assert got.shape == (rows,) and got.dtype == want.dtype
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert np.array_equal(np.asarray(order[got]), np.asarray(order[want]))
+    live = np.flatnonzero(np.asarray(got))  # every held pair but the first sorted has ONE row
+    assert np.array_equal(np.sort(np.asarray(got)[live]), np.arange(1, int(sizes.sum())))
+
+
+@pytest.mark.parametrize("spec,make,valid", [
+    (SPEC, _weights, False), (SPEC_RELU2, _weights_relu2, False),
+    (SPEC_SOFTMAX, _weights_softmax, False), (replace(SPEC, n_held=8), _weights, True)],
+    ids=["swiglu", "relu2_latent", "softmax_gated_shared", "half_held_skewed_valid_mask"])
+def test_held_layer_is_bit_equal_under_either_map(monkeypatch, spec, make, valid):
+    """The same body over the per-row map hands the grouped matmul the same
+    rows: the layer's output does not move by a bit."""
+    lw = make(jax.random.PRNGKey(10))
+    mask = None
+    if valid:
+        lw["bias"] = lw["bias"].at[2].set(10.0).at[3].set(-10.0)
+        lw = dict(lw, **{k: lw[k][:8] for k in ("w_gate", "w_up", "w_down")})
+        mask = jnp.arange(40) < 33
+    x = jax.random.normal(jax.random.PRNGKey(11), (40, D))
+    y, (stats, picked) = moe_block_held(lw, x, spec, mask)
+    monkeypatch.setattr(layer, "_padded_source", _source_per_row)
+    y0, (stats0, picked0) = moe_block_held(lw, x, spec, mask)
+    assert np.array_equal(np.asarray(y), np.asarray(y0))
+    assert np.array_equal(np.asarray(stats), np.asarray(stats0))
+    assert np.array_equal(np.asarray(picked), np.asarray(picked0))

@@ -463,3 +463,30 @@ def test_without_the_barrier_the_compiler_relays_wq_wk_wv(one_chip, as_on_tpu,
     cfg = TransformerConfig(**AOT_CFG)
     d, kv = cfg.hidden_size, cfg.num_kv_heads * cfg.hd
     assert sorted(set(copies)) == [(kv, d), (d, d)], copies
+
+
+# -- the held experts' padded layout (PR 37) ---------------------------------
+def test_held_experts_layout_looks_its_groups_up_a_tile_at_a_time(one_chip, as_on_tpu):
+    """``moe_block_held`` at the Qwen3-Next pack's shape (512 tokens x 2048,
+    top 10, 128 held of 512) compiled for a v5e: ONE gather whose result is
+    an int32 a ROW of the padded layout (R = 21 504) is left, the pairs'
+    ``order[source]``.  The chip walks such a gather an index at a time, 0.17
+    ms each, and the map from rows to sorted pairs had three more a layer
+    (``pstart[of]``, ``sizes[of]``, ``start[of]``: now one index a TILE)."""
+    from deepspeed_tpu.models.latent import LatentAttn, LatentSpec
+    from deepspeed_tpu.moe import layer
+
+    t, d, f, k, g, n = 512, 2048, 512, 10, 128, 512
+    a = LatentAttn(2, 8, 8, 8, 4, 8, 1e4)
+    spec = LatentSpec(layer_kinds=(), full=a, sliding=a, index_heads=1, index_dim=8,
+                      index_topk=4, first_dense=0, n_routed=n, n_held=g, held_offset=0,
+                      experts_per_tok=k, moe_width=f, n_shared=1, routing="softmax",
+                      shared_gate=True)
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    lw = {"router": S(d, n), "w_gate": S(g, d, f), "w_up": S(g, d, f), "w_down": S(g, f, d),
+          "s_gate": S(d, f), "s_up": S(d, f), "s_down": S(f, d), "w_sg": S(d, 1)}
+    text = jax.jit(lambda lw, x: layer.moe_block_held(lw, x, spec)[0]).trace(
+        lw, S(t, d)).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text, "the grouped matmul's Mosaic body was not compiled"
+    rows = t * k + g * layer._GMM_ROWS
+    assert len(re.findall(rf"= s32\[{rows}\]\S* gather\(", text)) <= 1
