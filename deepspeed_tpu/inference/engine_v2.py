@@ -409,7 +409,6 @@ class InferenceEngineV2:
         self._h = {
             k: reg.histogram(f"{self._ns}/{k}")
             for k in ("prefill_pack_ms", "decode_tick_ms", "spec_tick_ms",
-                      "burst_tick_ms", "spec_draft_len", "spec_match_distance",
                       "tp_allreduce_ms")
         }
         # eagerly register this engine's request-latency group so the
@@ -947,7 +946,7 @@ class InferenceEngineV2:
         chunk layout byte-for-byte (one chunk, same bucket)."""
         self._maybe_fault("runner_exception", [s.uid for s, _, _ in entries])
         tel, ns = self.telemetry, self._ns
-        with tel.span("engine.pack_build", track=ns, entries=len(entries)):
+        with tel.span("engine.pack_build", track=ns) as bsp:
             bs = self.block_size
             dp = self.serve_replicas
             groups: List[List] = [[] for _ in range(dp)]
@@ -989,6 +988,7 @@ class InferenceEngineV2:
                     ctx_tables[s.slot, : len(s.blocks)] = s.blocks
                     ctx_lens[s.slot] = start
                     cur += n_pages * bs  # next prompt starts page-aligned
+            bsp.mark("rows")  # what is left of the span: the rng's programs
             self._rng, sub = jax.random.split(self._rng)
             triple = (sampling.temperature, sampling.top_k, sampling.top_p)
             n_real = sum(end - start for _, start, end in entries)
@@ -1000,8 +1000,8 @@ class InferenceEngineV2:
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
-            tokens=n_real, pad=t_pad, entries=len(entries), ctx=use_ctx,
-            ctx_pages=ctx_pages, uids=[s.uid for s, _, _ in entries], **extra,
+            tokens=n_real, ctx_pages=ctx_pages,
+            uids=[s.uid for s, _, _ in entries], **extra,
         ) as sp:
             if use_ctx:
                 args = (
@@ -1010,15 +1010,18 @@ class InferenceEngineV2:
                     jnp.asarray(last_idx), jnp.asarray(ctx_tables),
                     jnp.asarray(ctx_lens), self.kv, sub, triple,
                 )
+                sp.mark("upload")  # every argument handed over; next: enqueue
                 sampled, self.kv = self._packed_prefill_ctx_jit(*args)
                 if self._tracked:
                     self._tracked["_packed_prefill_ctx_jit"].note(args)
             else:
-                sampled, self.kv = self._packed_prefill_jit(
+                args = (
                     self.params, jnp.asarray(tokens), jnp.asarray(seg),
                     jnp.asarray(pos), jnp.asarray(pack_pages),
                     jnp.asarray(last_idx), self.kv, sub, triple,
                 )
+                sp.mark("upload")
+                sampled, self.kv = self._packed_prefill_jit(*args)
             sp.dispatched()
             self._c["prefill_tokens_dispatched"].inc(n_real)
             self._c["prefill_dispatches"].inc()
@@ -1033,7 +1036,7 @@ class InferenceEngineV2:
                 # the trace's (one ``XLA Modules`` event per execution)
                 next_tokens = None
                 sp.end(sync_obj=sampled)
-        with tel.span("engine.pack_emit", track=ns, finishing=len(finishing)):
+        with tel.span("engine.pack_emit", track=ns):
             poison = self._poisoned([s.uid for s in finishing])
             for s, start, end in entries:
                 s.seen_tokens = end
@@ -1305,18 +1308,12 @@ class InferenceEngineV2:
                 cap = min(cap, max_emit[s.uid] - 1)
             if cap <= 0:
                 continue
-            drafts, match_start = speculative.propose_detail(
+            drafts = speculative.propose(
                 s.tokens, self.spec_min_match, cap, self.spec_lookup_window
             )
             if drafts:
                 out[s.uid] = drafts
                 budget -= len(drafts)
-                self._h["spec_draft_len"].observe(len(drafts))
-                # tail -> matched-n-gram distance: ~0 = repetition loop,
-                # large = prompt-copy workload (drafter diagnostics)
-                self._h["spec_match_distance"].observe(
-                    len(s.tokens) - self.spec_min_match - match_start
-                )
         return out
 
     def _spec_tick(
@@ -1364,7 +1361,7 @@ class InferenceEngineV2:
                     self._decode_tick(active_seqs, sampling).items()}
         self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
         tel, ns = self.telemetry, self._ns
-        with tel.span("engine.decode_build", track=ns, batch=len(active_seqs)):
+        with tel.span("engine.decode_build", track=ns) as bsp:
             B, K = self.mgr.max_seqs, self.spec_max_draft
             K1, bs = K + 1, self.block_size
             tokens = np.zeros(B * K1, np.int32)
@@ -1391,6 +1388,7 @@ class InferenceEngineV2:
                     pos[row] = p_tok
                     dst_pages[row] = s.blocks[p_tok // bs]
                     dst_offs[row] = p_tok % bs
+            bsp.mark("rows")
             self._rng, sub = jax.random.split(self._rng)
         # spec_tick_ms is uploads + dispatch + fetch: the argument uploads
         # belong inside the span
@@ -1399,20 +1397,22 @@ class InferenceEngineV2:
             batch=len(active_seqs), drafted=int(n_draft.sum()),
             ctx_tokens=int(ctx_lens.sum()),
         ) as sp:
-            out_dev, n_out_dev, self.kv = self._spec_jit(
+            args = (
                 self.params, jnp.asarray(tokens), jnp.asarray(seg),
                 jnp.asarray(pos), jnp.asarray(dst_pages), jnp.asarray(dst_offs),
                 self._tables_device(), jnp.asarray(ctx_lens), jnp.asarray(draft),
                 jnp.asarray(n_draft), self._sampling_device(active_seqs, sampling),
                 self.kv, sub, sampling.top_k, sampling.temperature <= 0.0,
             )
+            sp.mark("upload")
+            out_dev, n_out_dev, self.kv = self._spec_jit(*args)
             sp.dispatched()
             self._c["spec_ticks"].inc()
             self._c["spec_seq_forwards"].inc(len(active_seqs))
             self._account_comm(tokens.shape[0])
             # the tick's host sync
             out_np, n_out = np.asarray(out_dev), np.asarray(n_out_dev)
-        with tel.span("engine.decode_emit", track=ns, batch=len(active_seqs)):
+        with tel.span("engine.decode_emit", track=ns):
             poison = self._poisoned([s.uid for s in active_seqs])
             out: Dict[int, List[int]] = {}
             for s in active_seqs:
@@ -1471,7 +1471,7 @@ class InferenceEngineV2:
         Appends the sampled token per sequence; stop/length handling is the
         caller's job."""
         tel, ns = self.telemetry, self._ns
-        with tel.span("engine.decode_build", track=ns, batch=len(active_seqs)):
+        with tel.span("engine.decode_build", track=ns) as bsp:
             B = self.mgr.max_seqs
             tokens = np.zeros(B, np.int32)
             seq_lens = np.zeros(B, np.int32)
@@ -1488,6 +1488,7 @@ class InferenceEngineV2:
                 active[s.slot] = True
                 ctx_tokens += s.cur_len
             self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
+            bsp.mark("rows")  # what is left of the span: the rng's programs
             self._rng, sub = jax.random.split(self._rng)
             extra = self.runner.dispatched(
                 self._c, ((s.slot, s.cur_len - 1, s.cur_len) for s in active_seqs))
@@ -1503,6 +1504,7 @@ class InferenceEngineV2:
                 self._commit_rep(sub),
                 (sampling.temperature, sampling.top_k, sampling.top_p),
             )
+            sp.mark("upload")  # every argument handed over; next: enqueue
             sampled, _, _, self.kv = self._decode_jit(*args)
             if self._tracked:
                 self._tracked["_decode_jit"].note(args)
@@ -1511,7 +1513,7 @@ class InferenceEngineV2:
             self._c["decode_emitted"].inc(len(active_seqs))
             self._account_comm(B)
             next_tokens = np.asarray(sampled)  # the tick's host sync
-        with tel.span("engine.decode_emit", track=ns, batch=len(active_seqs)):
+        with tel.span("engine.decode_emit", track=ns):
             poison = self._poisoned([s.uid for s in active_seqs])
             out = {}
             for s in active_seqs:
@@ -1606,7 +1608,7 @@ class InferenceEngineV2:
         at burst granularity: nothing commits, run = [-1].  Rows given no
         emission headroom return an empty run untouched."""
         tel, ns = self.telemetry, self._ns
-        with tel.span("engine.decode_build", track=ns, batch=len(active_seqs)):
+        with tel.span("engine.decode_build", track=ns) as bsp:
             B = self.mgr.max_seqs
             uids = [s.uid for s in active_seqs]
             base_lens = np.zeros(B, np.int32)
@@ -1638,6 +1640,7 @@ class InferenceEngineV2:
             # no tick can emit once every row is past its cap — clamp the burst
             n = min(n, int(emit_cap.max()))
             self._maybe_fault("runner_exception", uids)
+            bsp.mark("rows")  # this body commits its buffers inside the build
             tables = self._tables_device()
             tokens_dev = self._commit_rep(tokens0)
             lens_dev = self._commit_rep(base_lens)
@@ -1661,12 +1664,12 @@ class InferenceEngineV2:
             tick_dev = self._commit_rep(np.zeros((), np.int32))
         # ONE span for the whole burst — per-tick spans would retain one
         # device array per tick, the exact host-reference leak this design
-        # removes; the per-tick figure is the burst average, observed once
-        # per tick
+        # removes
         with tel.span(
             "decode_burst", track=ns, ticks=n, batch=len(active_seqs),
             ctx_tokens=int(base_lens.sum()) + int(active.sum()),
         ) as sp:
+            sp.mark("upload")  # nothing left to upload: the names of a tick
             for _ in range(n):
                 (tokens_dev, lens_dev, key_dev, self.kv, burst_dev,
                  tick_dev, active_dev, emitted_dev) = self._decode_burst_jit(
@@ -1682,11 +1685,7 @@ class InferenceEngineV2:
             self._c["decode_bursts"].inc()
             self._c["burst_ticks"].inc(n)
             burst = np.asarray(burst_dev)[: n + 1]  # the ONE host sync
-        if sp.duration_ms is not None:
-            per_tick = sp.duration_ms / n
-            for _ in range(n):
-                self._h["burst_tick_ms"].observe(per_tick)
-        with tel.span("engine.decode_emit", track=ns, batch=len(active_seqs)):
+        with tel.span("engine.decode_emit", track=ns):
             poison_inj = self._poisoned(uids)
             out: Dict[int, List[int]] = {}
             total = 0

@@ -52,7 +52,8 @@ class Span:
     ends the span unless ``end()`` was called inside it."""
 
     __slots__ = ("name", "track", "id", "parent", "t0", "t_dispatch", "t_end",
-                 "synced", "args", "_sync", "_hist", "_rec", "_stack", "_ann")
+                 "marks", "synced", "args", "_sync", "_hist", "_rec", "_stack",
+                 "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, track: str,
                  hist, args: Dict[str, Any], detached: bool = False):
@@ -72,15 +73,32 @@ class Span:
         self._ann = None
         self.t_dispatch: Optional[float] = None
         self.t_end: Optional[float] = None
+        self.marks: Optional[Dict[str, float]] = None
         self.synced = True
         self._sync = None
         self.t0 = rec._clock()
 
+    def mark(self, name: str) -> Optional[float]:
+        """One reading of the recorder's clock kept under ``name`` on the
+        open span: a PHASE inside one dispatch (``upload``, ``rows``), where
+        a boundary between layers is a span of its own.  Exported as
+        ``<name>_ms``, the offset from the span's start."""
+        rec = self._rec
+        if rec is None:  # already ended
+            return None
+        t = rec._clock()
+        if self.marks is None:
+            self.marks = {name: t}
+        else:
+            self.marks[name] = t
+        return t
+
     def dispatched(self) -> None:
-        """Mark the async dispatch call as returned (host work continues —
-        e.g. a result fetch — before ``end()``)."""
+        """The mark named ``dispatch``, first call only: the async dispatch
+        call has returned (host work continues — e.g. a result fetch —
+        before ``end()``)."""
         if self.t_dispatch is None:
-            self.t_dispatch = self._rec._clock()
+            self.t_dispatch = self.mark("dispatch")
 
     @property
     def closed(self) -> bool:
@@ -140,6 +158,9 @@ class _NullSpan:
     __slots__ = ()
     id = parent = None
     closed = True
+
+    def mark(self, name: str) -> None:
+        pass
 
     def dispatched(self) -> None:
         pass
@@ -241,6 +262,8 @@ class TraceRecorder:
         args["span_id"] = s.id
         if s.parent is not None:
             args["parent_id"] = s.parent
+        for name, t in (s.marks or {}).items():
+            args[f"{name}_ms"] = round((t - s.t0) * 1e3, 3)
         if s.t_dispatch is not None:
             args["dispatch_ms"] = round((s.t_dispatch - s.t0) * 1e3, 3)
         if not s.synced:

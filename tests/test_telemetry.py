@@ -951,6 +951,160 @@ def test_disabled_telemetry_hands_out_the_null_span():
     assert not hasattr(tel, "step_annotation")  # one way to annotate, not two
 
 
+def _marked_span(marks, unsynced=False):
+    """One span on a hand clock: opened at 1.0, a mark every 0.5 s after,
+    ended 0.5 s after the last; its Chrome event."""
+    clock = _Clock()
+    tel = Telemetry(enabled=True, clock=clock)
+    clock.t = 1.0
+    sp = tel.span("decode_tick", track="serve")
+    for name in marks:
+        clock.t += 0.5
+        sp.dispatched() if name == "dispatch" else sp.mark(name)
+    clock.t += 0.5
+    sp.end(sync_obj=object()) if unsynced else sp.end()
+    return sp, tel.recorder.chrome_events()[-1]
+
+
+def test_marks_export_in_order_and_inside_the_duration():
+    sp, ev = _marked_span(["upload", "dispatch", "fetched"])
+    args = ev["args"]
+    assert [k for k in args if k.endswith("_ms")] == [
+        "upload_ms", "dispatch_ms", "fetched_ms"]
+    assert (args["upload_ms"], args["dispatch_ms"], args["fetched_ms"]) == (
+        500.0, 1000.0, 1500.0)
+    assert 0 <= args["upload_ms"] <= args["dispatch_ms"] <= ev["dur"] * 1e-3 == 2000.0
+    # a mark on a span that has ended reads no clock and keeps nothing
+    assert sp.mark("late") is None and "late" not in sp.marks
+
+
+@pytest.mark.parametrize("marks", [["dispatch"], ["upload", "dispatch"]],
+                         ids=["plain", "after_an_upload_mark"])
+def test_dispatched_is_the_dispatch_mark_and_dispatch_ms_is_what_it_was(marks):
+    sp, ev = _marked_span(marks)
+    at = 1.0 + 0.5 * len(marks)
+    assert sp.t_dispatch == sp.marks["dispatch"] == at
+    assert ev["args"]["dispatch_ms"] == round((at - 1.0) * 1e3, 3)
+    sp2, ev2 = _marked_span(marks[:-1])  # never dispatched: end() stands in
+    assert "dispatch" not in (sp2.marks or {})
+    assert ev2["args"]["dispatch_ms"] == ev2["dur"] * 1e-3
+    # the first call wins, as it always did
+    clock = _Clock()
+    tel = Telemetry(enabled=True, clock=clock)
+    with tel.span("x") as sp3:
+        clock.t = 2.0
+        sp3.dispatched()
+        clock.t = 3.0
+        sp3.dispatched()
+    assert sp3.t_dispatch == sp3.marks["dispatch"] == 2.0
+
+
+def test_an_unsynced_span_keeps_its_marks():
+    sp, ev = _marked_span(["upload", "dispatch"], unsynced=True)
+    assert ev["args"]["synced"] is False and not sp.synced
+    assert (ev["args"]["upload_ms"], ev["args"]["dispatch_ms"]) == (500.0, 1000.0)
+    assert ev["dur"] == 1000.0 * 1e3  # the dispatch-side duration
+
+
+def test_null_span_mark_is_a_no_op_and_disabled_telemetry_allocates_nothing():
+    from deepspeed_tpu.telemetry import NULL_SPAN
+
+    tel = Telemetry(enabled=False)
+    with tel.span("decode_tick", track="serve", batch=3) as sp:
+        assert sp is NULL_SPAN
+        assert sp.mark("upload") is None
+        sp.dispatched()
+    assert not hasattr(NULL_SPAN, "marks") and NULL_SPAN.__slots__ == ()
+    assert len(tel.recorder) == 0 and tel.recorder.chrome_events() == []
+    # ... and a span that is never marked carries no dict of its own
+    on = Telemetry(enabled=True)
+    with on.span("sched.tick") as plain:
+        pass
+    assert plain.marks is None
+
+
+def _phase_engine(kind):
+    if kind == "dense":
+        cfg = get_preset("tiny", max_seq_len=128, dtype=jnp.float32)
+        params = init_params(jax.random.PRNGKey(0), cfg=cfg, dtype=jnp.float32)
+    else:
+        from pathlib import Path
+
+        root = Path(__file__).resolve().parents[1]
+        sys.path.insert(0, str(root))
+        from benchmark import harness
+
+        m = harness.rehearsed(harness.load_json(
+            root / "benchmark/configs/dots3_note_l5_e32_serve_1chip.json"), True)
+        cfg = harness.module("models", m["model_type"]).transformer_config(
+            m, max_seq_len=m["engine"]["max_seq_len"])
+        params = init_params(jax.random.PRNGKey(7), cfg)
+        assert cfg.latent is not None
+    return InferenceEngineV2(
+        params, cfg, max_seqs=4, num_blocks=64, block_size=8, max_seq_len=128,
+        prefill_buckets=(32,), prefill_chunk=32, telemetry=True), cfg
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_dispatch_spans_carry_their_phase_marks(kind):
+    """Every dispatch span of a short scheduler run reads open -> ``upload_ms``
+    (arguments handed over) -> ``dispatch_ms`` (the jitted call returned) ->
+    end (the fetch), every build span ``rows_ms`` before the rng's programs:
+    chunked packs closed unsynced among them."""
+    eng, cfg = _phase_engine(kind)
+    sched = eng.scheduler
+    rng = np.random.default_rng(3)
+    for uid, n in ((1, 70), (2, 9), (3, 41)):
+        sched.submit(uid, rng.integers(1, cfg.vocab_size, n).tolist(),
+                     SamplingParams(temperature=0.0, max_new_tokens=5))
+    sched.run()
+    evs = [e for e in eng.telemetry.recorder.chrome_events() if e["ph"] == "X"]
+    eng.close()
+    by_name = {}
+    for e in evs:
+        by_name.setdefault(e["name"], []).append(e)
+    assert len(by_name["decode_tick"]) >= 4 and len(by_name["prefill_pack"]) >= 3
+    assert any(e["args"].get("synced") is False for e in by_name["prefill_pack"])
+    for name in ("decode_tick", "prefill_pack"):
+        for e in by_name[name]:
+            a = e["args"]
+            assert 0 <= a["upload_ms"] <= a["dispatch_ms"] <= e["dur"] * 1e-3 + 1e-3, (name, a)
+    for name in ("engine.decode_build", "engine.pack_build"):
+        assert len(by_name[name]) == len(by_name[
+            "decode_tick" if "decode" in name else "prefill_pack"])
+        for e in by_name[name]:
+            assert 0 <= e["args"]["rows_ms"] <= e["dur"] * 1e-3 + 1e-3, (name, e["args"])
+    # a boundary between layers stays a span: the marks added none
+    assert set(by_name) <= {
+        "sched.tick", "sched.expire", "sched.admit", "sched.prefill", "sched.decode",
+        "engine.pack_build", "prefill_pack", "engine.pack_emit",
+        "engine.decode_build", "decode_tick", "engine.decode_emit"}
+
+
+def test_spec_and_burst_bodies_export_the_names_of_a_tick(serve_pair, tiny):
+    """The two dispatch bodies no benchmark cell runs have a tick's shape and
+    its marks; a burst commits its buffers inside its build span, so its
+    ``rows`` mark stands before them and ``upload`` at the span's opening."""
+    (eng, _, _), _ = serve_pair
+    evs = [e for e in eng.telemetry.recorder.chrome_events() if e["ph"] == "X"]
+    spec = [e for e in evs if e["name"] == "spec_tick"]
+    assert spec
+    for e in spec:
+        a = e["args"]
+        assert 0 <= a["upload_ms"] <= a["dispatch_ms"] <= e["dur"] * 1e-3 + 1e-3
+    cfg, params = tiny
+    burst = InferenceEngineV2(params, cfg, max_seqs=4, num_blocks=32,
+                              block_size=16, telemetry=True)
+    burst.put([1, 2], [[3, 4, 5, 6, 7], [9, 8, 7]], SamplingParams(temperature=0.0))
+    burst.step_n(4, SamplingParams(temperature=0.0))
+    evs = {e["name"]: e for e in burst.telemetry.recorder.chrome_events()
+           if e["ph"] == "X"}
+    a = evs["decode_burst"]["args"]
+    assert 0 <= a["upload_ms"] <= a["dispatch_ms"] <= evs["decode_burst"]["dur"] * 1e-3 + 1e-3
+    build = evs["engine.decode_build"]
+    assert 0 <= build["args"]["rows_ms"] <= build["dur"] * 1e-3 + 1e-3
+
+
 def test_detached_and_out_of_order_spans_do_not_bend_the_tree():
     tel = Telemetry(enabled=True)
     rec = tel.recorder
