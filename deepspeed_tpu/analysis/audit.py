@@ -89,10 +89,12 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
     lens = jnp.ones(B, jnp.int32)
     bt = jnp.zeros((B, eng.max_pages), jnp.int32)
     act = jnp.ones(B, bool)
+    # a tick's ONE upload: tokens, KV positions, 0 / 1 for a live slot
+    rows = jnp.stack([toks, lens, act.astype(jnp.int32)])
     specs["decode"] = dict(
         jit=eng._decode_jit,
-        args=(eng.params, toks, lens, bt, act, eng.kv, key, tr),
-        donated={"seq_lens": 2, "kv": 5, "rng": 6}, static=(7,),
+        args=(eng.params, rows, bt, eng.kv, key, tr),
+        donated={"kv": 3}, static=(5,),
         n_tokens=B, sample_rows=B,
     )
 
@@ -109,35 +111,31 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
               jnp.zeros((), jnp.int32), jnp.zeros(B, jnp.int32),
               jnp.full(B, -1, jnp.int32), jnp.full(B, n_burst, jnp.int32),
               tr),
-        donated={"seq_lens": 2, "active": 4, "kv": 5, "rng": 6, "burst": 7,
+        donated={"seq_lens": 2, "active": 4, "kv": 5, "burst": 7,
                  "tick": 8, "emitted": 9},
         static=(12,),
         n_tokens=B, sample_rows=B,
     )
 
-    p_tokens = jnp.zeros(t_pad, jnp.int32)
-    p_seg = jnp.zeros(t_pad, jnp.int32)
-    p_pos = jnp.zeros(t_pad, jnp.int32)
-    p_pages = jnp.full(t_pad // bs, -1, jnp.int32)
-    p_last = jnp.full(B, -1, jnp.int32)
+    # a pack's ONE buffer, as the dispatch site lays it out
+    from ..inference.engine_v2 import new_pack
+
     specs["prefill_packed"] = dict(
         jit=eng._packed_prefill_jit,
-        args=(eng.params, p_tokens, p_seg, p_pos, p_pages, p_last, eng.kv,
-              key, tr),
-        donated={"kv": 6}, static=(8,),
+        args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, False)[0]),
+              eng.kv, key, tr),
+        donated={"kv": 2}, static=(4,),
         n_tokens=t_pad, sample_rows=B,
         # cold pack: dense attention only, never reads the paged pool — no
         # seq-shard ring in this dispatch
         ring=False,
     )
 
-    ctx_tables = jnp.full((B, eng.max_pages), -1, jnp.int32)
-    ctx_lens = jnp.zeros(B, jnp.int32)
     specs["prefill_packed_ctx"] = dict(
         jit=eng._packed_prefill_ctx_jit,
-        args=(eng.params, p_tokens, p_seg, p_pos, p_pages, p_last,
-              ctx_tables, ctx_lens, eng.kv, key, tr),
-        donated={"kv": 8}, static=(10,),
+        args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, True)[0]),
+              eng.kv, key, tr),
+        donated={"kv": 2}, static=(4,),
         n_tokens=t_pad, sample_rows=B,
     )
 
@@ -150,7 +148,8 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
             args=(eng.params, jnp.zeros(t, jnp.int32),
                   jnp.zeros(t, jnp.int32), jnp.zeros(t, jnp.int32),
                   jnp.full(t, -1, jnp.int32), jnp.zeros(t, jnp.int32),
-                  ctx_tables, ctx_lens, jnp.zeros((B, K), jnp.int32),
+                  jnp.full((B, eng.max_pages), -1, jnp.int32),
+                  jnp.zeros(B, jnp.int32), jnp.zeros((B, K), jnp.int32),
                   jnp.zeros(B, jnp.int32), jnp.zeros((B, 2), jnp.float32),
                   eng.kv, key, 0, True),
             donated={"kv": 11}, static=(13, 14),

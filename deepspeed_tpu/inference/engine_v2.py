@@ -47,6 +47,46 @@ def _bucket(n: int, buckets: Sequence[int]) -> int:
     raise ValueError(f"prompt length {n} exceeds max bucket {buckets[-1]}")
 
 
+# A prefill pack hands its program ONE flat int32 buffer.  ``_pack_sizes`` IS
+# the layout, for both sides: on the host ``unpack_pack`` returns numpy VIEWS
+# of a buffer from ``new_pack`` (the build fills them in place), in a traced
+# program the same static slices of the uploaded array.
+def _pack_sizes(t_pad: int, block_size: int, max_seqs: int, max_pages: int,
+                ctx: bool) -> List[int]:
+    """Lengths of tokens, seg, pos, pack_pages, last_idx (a cold pack) and
+    ctx_tables, ctx_lens (a context pack), in the order they lie."""
+    sizes = [t_pad, t_pad, t_pad, t_pad // block_size, max_seqs]
+    return sizes + [max_seqs * max_pages, max_seqs] if ctx else sizes
+
+
+def unpack_pack(buf, block_size: int, max_seqs: int, max_pages: int, ctx: bool):
+    """(tokens, seg, pos, pack_pages, last_idx) of a cold pack's buffer, and
+    for a context pack also (ctx_tables, ctx_lens)."""
+    per_slot = sum(_pack_sizes(0, block_size, max_seqs, max_pages, ctx))
+    t_pad = (buf.shape[0] - per_slot) * block_size // (3 * block_size + 1)
+    sizes = _pack_sizes(t_pad, block_size, max_seqs, max_pages, ctx)
+    if sum(sizes) != buf.shape[0]:
+        raise ValueError(f"no pack of {buf.shape[0]} int32 at block_size "
+                         f"{block_size}, {max_seqs} slots, {max_pages} pages")
+    at = np.cumsum([0] + sizes)
+    parts = [buf[a:b] for a, b in zip(at[:-1], at[1:])]
+    if ctx:
+        parts[5] = parts[5].reshape(max_seqs, max_pages)
+    return tuple(parts)
+
+
+def new_pack(t_pad: int, block_size: int, max_seqs: int, max_pages: int,
+             ctx: bool):
+    """An empty pack of ``t_pad`` tokens: (buffer, its views by
+    ``unpack_pack``): no token, no page, no row to sample, no context."""
+    buf = np.zeros(
+        sum(_pack_sizes(t_pad, block_size, max_seqs, max_pages, ctx)), np.int32)
+    views = unpack_pack(buf, block_size, max_seqs, max_pages, ctx)
+    for v in views[3:6]:  # pack_pages, last_idx, ctx_tables: -1 = none
+        v.fill(-1)
+    return buf, views
+
+
 class InferenceEngineV2:
     """Paged-KV continuous-batching engine for one model replica."""
 
@@ -378,6 +418,8 @@ class InferenceEngineV2:
             "prefill_dispatches",
             "table_uploads",  # H2D copies of the block-table mirror
             "sampling_uploads",  # H2D copies of the per-slot sampling rows
+            "dispatch_uploads",  # host arrays the dispatch bodies handed over
+            # (the two above among them): one a decode tick, one a pack
             "decode_ticks",
             "decode_emitted",  # tokens emitted by plain decode dispatches
             "decode_bursts",  # device-resident bursts (ONE host sync each)
@@ -449,6 +491,8 @@ class InferenceEngineV2:
             )
             self._kv_shardings = (kv_sh, kv_sh)
             self.kv = jax.device_put(self.kv, self._kv_shardings)
+        # The key LIVES ON THE DEVICE: every program takes it, splits it inside
+        # and hands the carried key back; the host only keeps the reference.
         self._rng = jax.random.PRNGKey(seed)
         self._burst_cap = 64  # step_n accumulator rows (doubles on demand)
         # host-side block-table mirror: rows update as pure numpy writes and
@@ -478,35 +522,42 @@ class InferenceEngineV2:
         sq_ = self.seq_shards
         mesh_ = self._mesh
 
+        B_, mp_, bs_ = max_seqs, self.max_pages, block_size
+
+        def sampled_pack(logits, rng, sampling_triple):
+            """Sampling fused into the dispatch: the decode loop never makes a
+            second device round trip per tick.  finite_guard folds NaN/inf
+            detection into the same fetch: a poisoned row samples -1 and
+            the host fails THAT request instead of trusting garbage.  The
+            key is split HERE and carried on as a result: the host never
+            holds, splits or uploads one."""
+            t, k, p = sampling_triple
+            rng, sub = jax.random.split(rng)
+            sampled = sample(logits, SamplingParams(t, k, p), sub)
+            return finite_guard(logits, sampled), rng
+
         # only the device-relevant sampling triple is static — hashing the
         # whole SamplingParams would recompile on max_new_tokens/stop_token
-        def packed_impl(params, tokens, seg, pos, pack_pages, last_idx,
-                        kv, rng, sampling_triple):
+        def packed_impl(params, pack, kv, rng, sampling_triple):
+            """A cold pack: ``pack`` is the ONE int32 buffer of
+            ``new_pack(..., ctx=False)``."""
             logits, kv = runner.prefill_packed(
-                params, cfg_, tokens, seg, pos, pack_pages, last_idx, kv,
+                params, cfg_, *unpack_pack(pack, bs_, B_, mp_, False), kv,
                 ctx=ctx_, mesh=mesh_,
             )
-            # sampling fused into the dispatch: the decode loop never makes a
-            # second device round trip per tick.  finite_guard folds NaN/inf
-            # detection into the same fetch: a poisoned row samples -1 and
-            # the host fails THAT request instead of trusting garbage.
-            t, k, p = sampling_triple
-            sampled = sample(logits, SamplingParams(t, k, p), rng)
-            return finite_guard(logits, sampled), kv
+            sampled, rng = sampled_pack(logits, rng, sampling_triple)
+            return sampled, kv, rng
 
-        def packed_ctx_impl(params, tokens, seg, pos, pack_pages, last_idx,
-                            ctx_tables, ctx_lens, kv, rng, sampling_triple):
+        def packed_ctx_impl(params, pack, kv, rng, sampling_triple):
             """Context-aware variant: suffix tokens attend over each
             sequence's cached KV pages (prefix-cache hits, chunked-prefill
             continuation chunks).  Cold packs stay on ``packed_impl``."""
             logits, kv = runner.prefill_packed_ctx(
-                params, cfg_, tokens, seg, pos, pack_pages, last_idx,
-                ctx_tables, ctx_lens, kv, ctx=ctx_, mesh=mesh_, dp=dp_,
-                seq_shards=sq_,
+                params, cfg_, *unpack_pack(pack, bs_, B_, mp_, True), kv,
+                ctx=ctx_, mesh=mesh_, dp=dp_, seq_shards=sq_,
             )
-            t, k, p = sampling_triple
-            sampled = sample(logits, SamplingParams(t, k, p), rng)
-            return finite_guard(logits, sampled), kv
+            sampled, rng = sampled_pack(logits, rng, sampling_triple)
+            return sampled, kv, rng
 
         def cow_impl(kv, src, dst):
             """Copy-on-write page clone: dst pages get src's contents in
@@ -531,17 +582,15 @@ class InferenceEngineV2:
             )
             return sampled, rng, kv
 
-        def decode_impl(params, tokens, seq_lens, block_tables, active, kv,
-                        rng, sampling_triple):
-            """One decode tick as a pure device-chained transition: tokens,
-            seq_lens and the rng key all arrive AND return as device arrays,
-            so a burst (step_n) enqueues n dispatches with ZERO per-tick
-            host->device uploads — the host's only per-tick work is the
-            dispatch call itself."""
+        def decode_impl(params, rows, block_tables, kv, rng, sampling_triple):
+            """One decode tick.  ``rows`` is the tick's ONE upload, int32
+            ``[3, max_seqs]``: the slots' input tokens, their KV positions
+            and 0 / 1 for a live slot; the key arrives from the last
+            dispatch's results and is carried on to the next."""
             sampled, rng, kv = decode_sample(
-                params, tokens, seq_lens, block_tables, active, kv, rng,
+                params, rows[0], rows[1], block_tables, rows[2] != 0, kv, rng,
                 sampling_triple)
-            return sampled, seq_lens + 1, rng, kv
+            return sampled, rng, kv
 
         def decode_burst_impl(params, tokens, seq_lens, block_tables, active,
                               kv, rng, burst, tick, emitted, stop_rows,
@@ -611,33 +660,35 @@ class InferenceEngineV2:
             )
             k1 = draft.shape[1] + 1
             logits = logits.reshape(draft.shape[0], k1, -1)
+            rng, sub = jax.random.split(rng)
             out, n_out = spec_verify_sample(
                 logits, draft, n_draft, samp_rows[:, 0], samp_rows[:, 1],
-                top_k, rng, all_greedy=all_greedy,
+                top_k, sub, all_greedy=all_greedy,
             )
             # one non-finite logit anywhere in a row's k+1 verify positions
             # poisons the whole row (-1 sentinel): accepting drafts scored
             # by a garbage forward is not partially trustworthy
-            return finite_guard(logits, out), n_out, kv
+            return finite_guard(logits, out), n_out, kv, rng
 
         # The six programs, built ONCE from one table: (attribute, impl, its
         # jax.jit options, the KV pool's place among n results, and among the
         # arguments after ``params``: None for a program that takes no
         # weights).  stop_rows / max_emit of the burst are NOT donated: the
-        # same device arrays feed every tick.
+        # same device arrays feed every tick.  The key is donated by none:
+        # a dispatch that raises must leave ``self._rng`` alive.
         table = (
             ("_packed_prefill_jit", packed_impl,
-             dict(donate_argnums=(6,), static_argnums=(8,)), 1, 2, 5),
+             dict(donate_argnums=(2,), static_argnums=(4,)), 1, 3, 1),
             ("_packed_prefill_ctx_jit", packed_ctx_impl,
-             dict(donate_argnums=(8,), static_argnums=(10,)), 1, 2, 7),
+             dict(donate_argnums=(2,), static_argnums=(4,)), 1, 3, 1),
             ("_cow_jit", cow_impl, dict(donate_argnums=(0,)), 0, 1, None),
             ("_decode_jit", decode_impl,
-             dict(donate_argnums=(2, 5, 6), static_argnums=(7,)), 3, 4, 4),
+             dict(donate_argnums=(3,), static_argnums=(5,)), 2, 3, 2),
             ("_decode_burst_jit", decode_burst_impl,
-             dict(donate_argnums=(2, 4, 5, 6, 7, 8, 9), static_argnums=(12,)),
+             dict(donate_argnums=(2, 4, 5, 7, 8, 9), static_argnums=(12,)),
              3, 8, 4),
             ("_spec_jit", spec_impl,
-             dict(donate_argnums=(11,), static_argnums=(13, 14)), 2, 3, 10),
+             dict(donate_argnums=(11,), static_argnums=(13, 14)), 2, 4, 10),
         )
         if self._mesh is not None:
             # pin the result shardings so the KV pool STAYS sharded across
@@ -646,12 +697,15 @@ class InferenceEngineV2:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             rep = NamedSharding(self._mesh, P())
-            # donated per-tick inputs (seq_lens, rng, burst buffers) must be
-            # COMMITTED to the replicated sharding their pinned outputs
-            # carry: left uncommitted, GSPMD may choose a batch-sharded
-            # input layout (it propagates the 2-D mesh attention specs) and
-            # the donor/output aliasing then fails on the size mismatch
+            # per-tick inputs (a tick's rows, the key, the burst's donated
+            # buffers) must be COMMITTED to the replicated sharding the pinned
+            # outputs carry: left uncommitted, GSPMD may choose a
+            # batch-sharded input layout (it propagates the 2-D mesh
+            # attention specs) and the donor/output aliasing then fails on
+            # the size mismatch; the key would also compile every program
+            # twice (its first call uncommitted, every later one not)
             self._rep_sharding = rep
+            self._rng = jax.device_put(self._rng, rep)
         for name, impl, opts, kv_out, n_out, kv_rest_idx in table:
             if self._mesh is not None:
                 outs = (rep,) * kv_out + (self._kv_shardings,) \
@@ -964,13 +1018,12 @@ class InferenceEngineV2:
             t_pad = C * dp
             use_ctx = any(start > 0 for _, start, _ in entries) \
                 or self.runner.packs_are_one_program
-            tokens = np.zeros(t_pad, np.int32)
-            seg = np.zeros(t_pad, np.int32)
-            pos = np.zeros(t_pad, np.int32)
-            pack_pages = np.full(t_pad // bs, -1, np.int32)
-            last_idx = np.full(self.mgr.max_seqs, -1, np.int32)
-            ctx_tables = np.full((self.mgr.max_seqs, self.max_pages), -1, np.int32)
-            ctx_lens = np.zeros(self.mgr.max_seqs, np.int32)
+            # the pack's ONE buffer, filled through its views
+            pack, views = new_pack(t_pad, bs, self.mgr.max_seqs,
+                                   self.max_pages, use_ctx)
+            tokens, seg, pos, pack_pages, last_idx = views[:5]
+            ctx_tables, ctx_lens = views[5:] if use_ctx else (None, None)
+            ctx_pages = 0  # live context pages the ctx kernel walks: its time over this
             for r, group in enumerate(groups):
                 cur = r * C
                 for s, start, end in group:
@@ -985,16 +1038,15 @@ class InferenceEngineV2:
                     )
                     if end == len(s.tokens):  # completes the prompt -> sample
                         last_idx[s.slot] = cur + n - 1
-                    ctx_tables[s.slot, : len(s.blocks)] = s.blocks
-                    ctx_lens[s.slot] = start
+                    if use_ctx:
+                        ctx_tables[s.slot, : len(s.blocks)] = s.blocks
+                        ctx_lens[s.slot] = start
+                        ctx_pages += first_page
                     cur += n_pages * bs  # next prompt starts page-aligned
-            bsp.mark("rows")  # what is left of the span: the rng's programs
-            self._rng, sub = jax.random.split(self._rng)
+            bsp.mark("rows")  # what is left of the span: the runner's counts
             triple = (sampling.temperature, sampling.top_k, sampling.top_p)
             n_real = sum(end - start for _, start, end in entries)
             n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
-            # live context pages the ctx kernel walks: its time over this
-            ctx_pages = int((-(-ctx_lens // bs)).sum())
             extra = self.runner.dispatched(
                 self._c, ((s.slot, start, end) for s, start, end in entries), pack=True)
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
@@ -1003,25 +1055,13 @@ class InferenceEngineV2:
             tokens=n_real, ctx_pages=ctx_pages,
             uids=[s.uid for s, _, _ in entries], **extra,
         ) as sp:
-            if use_ctx:
-                args = (
-                    self.params, jnp.asarray(tokens), jnp.asarray(seg),
-                    jnp.asarray(pos), jnp.asarray(pack_pages),
-                    jnp.asarray(last_idx), jnp.asarray(ctx_tables),
-                    jnp.asarray(ctx_lens), self.kv, sub, triple,
-                )
-                sp.mark("upload")  # every argument handed over; next: enqueue
-                sampled, self.kv = self._packed_prefill_ctx_jit(*args)
-                if self._tracked:
-                    self._tracked["_packed_prefill_ctx_jit"].note(args)
-            else:
-                args = (
-                    self.params, jnp.asarray(tokens), jnp.asarray(seg),
-                    jnp.asarray(pos), jnp.asarray(pack_pages),
-                    jnp.asarray(last_idx), self.kv, sub, triple,
-                )
-                sp.mark("upload")
-                sampled, self.kv = self._packed_prefill_jit(*args)
+            args = (self.params, self._upload(pack, held=False), self.kv,
+                    self._rng, triple)
+            sp.mark("upload")  # the one argument handed over; next: enqueue
+            name = "_packed_prefill_ctx_jit" if use_ctx else "_packed_prefill_jit"
+            sampled, self.kv, self._rng = getattr(self, name)(*args)
+            if name in self._tracked:
+                self._tracked[name].note(args)
             sp.dispatched()
             self._c["prefill_tokens_dispatched"].inc(n_real)
             self._c["prefill_dispatches"].inc()
@@ -1081,10 +1121,10 @@ class InferenceEngineV2:
         where some sequence grew or swapped a page (dirty tracking) — the
         [max_seqs, max_blocks] H2D copy every tick was pure waste on
         steady-state decode.  Safe to cache: no decode jit donates the
-        tables argument, and jnp.array always copies (the numpy mirror
-        mutates in place)."""
+        tables argument, and the transfer is handed a COPY (the numpy mirror
+        mutates in place, and a transfer may read its source late)."""
         if self._tables_dirty or self._tables_dev is None:
-            self._tables_dev = jnp.array(self._tables_np)
+            self._tables_dev = self._upload(self._tables_np.copy())
             self._tables_dirty = False
             self._c["table_uploads"].inc()
         return self._tables_dev
@@ -1106,18 +1146,25 @@ class InferenceEngineV2:
                 row[1] = sampling.top_p
                 dirty = True
         if dirty or self._samp_dev is None:
-            self._samp_dev = jnp.array(self._samp_np)
+            self._samp_dev = self._upload(self._samp_np.copy())
             self._c["sampling_uploads"].inc()
         return self._samp_dev
 
-    def _commit_rep(self, x):
-        """Upload/commit ``x`` replicated on the mesh (identity transfer on
-        single-device engines).  Required for arrays the decode jits DONATE:
-        their outputs are pinned replicated, so the donated input must be
-        committed to the same layout (see ``_rep_sharding``)."""
-        if self._mesh is None:
-            return jnp.asarray(x)
-        return jax.device_put(x, self._rep_sharding)
+    def _upload(self, x: np.ndarray, held: bool = True):
+        """Hand ONE host array to the device.  Every host array of a dispatch
+        body goes through here, so ``dispatch_uploads`` counts them: each
+        costs an allocation and a transfer of its own whatever its size
+        (0.24 ms of host for 192 or 9096 integers: my chip run, PR 40).
+        Over a serve mesh the array is committed replicated (the programs'
+        pinned results are, and a donated input must be committed to its
+        result's layout).  ``held=False``: the array feeds ONE call and is
+        kept by nobody, so without a mesh the jitted call takes the numpy
+        array itself (its own transfer costs 0.10 ms where a transfer ahead
+        of the call costs 0.25: same run)."""
+        self._c["dispatch_uploads"].inc()
+        if self._mesh is not None:
+            return jax.device_put(x, self._rep_sharding)
+        return jax.device_put(x) if held else x
 
     def _account_comm(self, n_tokens: int, reps: int = 1,
                       sample_rows: Optional[int] = None,
@@ -1389,7 +1436,6 @@ class InferenceEngineV2:
                     dst_pages[row] = s.blocks[p_tok // bs]
                     dst_offs[row] = p_tok % bs
             bsp.mark("rows")
-            self._rng, sub = jax.random.split(self._rng)
         # spec_tick_ms is uploads + dispatch + fetch: the argument uploads
         # belong inside the span
         with tel.span(
@@ -1397,15 +1443,15 @@ class InferenceEngineV2:
             batch=len(active_seqs), drafted=int(n_draft.sum()),
             ctx_tokens=int(ctx_lens.sum()),
         ) as sp:
+            up = self._upload
             args = (
-                self.params, jnp.asarray(tokens), jnp.asarray(seg),
-                jnp.asarray(pos), jnp.asarray(dst_pages), jnp.asarray(dst_offs),
-                self._tables_device(), jnp.asarray(ctx_lens), jnp.asarray(draft),
-                jnp.asarray(n_draft), self._sampling_device(active_seqs, sampling),
-                self.kv, sub, sampling.top_k, sampling.temperature <= 0.0,
+                self.params, up(tokens), up(seg), up(pos), up(dst_pages),
+                up(dst_offs), self._tables_device(), up(ctx_lens), up(draft),
+                up(n_draft), self._sampling_device(active_seqs, sampling),
+                self.kv, self._rng, sampling.top_k, sampling.temperature <= 0.0,
             )
             sp.mark("upload")
-            out_dev, n_out_dev, self.kv = self._spec_jit(*args)
+            out_dev, n_out_dev, self.kv, self._rng = self._spec_jit(*args)
             sp.dispatched()
             self._c["spec_ticks"].inc()
             self._c["spec_seq_forwards"].inc(len(active_seqs))
@@ -1473,9 +1519,9 @@ class InferenceEngineV2:
         tel, ns = self.telemetry, self._ns
         with tel.span("engine.decode_build", track=ns) as bsp:
             B = self.mgr.max_seqs
-            tokens = np.zeros(B, np.int32)
-            seq_lens = np.zeros(B, np.int32)
-            active = np.zeros(B, bool)
+            # the tick's ONE upload: tokens, KV positions, 0 / 1 for a live slot
+            rows = np.zeros((3, B), np.int32)
+            tokens, seq_lens, active = rows
             ctx_tokens = 0
             for s in active_seqs:
                 # grow pages for the token being written this tick; the COW
@@ -1485,27 +1531,26 @@ class InferenceEngineV2:
                 self._set_block_table(s)
                 tokens[s.slot] = s.tokens[-1]
                 seq_lens[s.slot] = s.cur_len - 1  # KV position of the new token
-                active[s.slot] = True
+                active[s.slot] = 1
                 ctx_tokens += s.cur_len
             self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
-            bsp.mark("rows")  # what is left of the span: the rng's programs
-            self._rng, sub = jax.random.split(self._rng)
+            bsp.mark("rows")  # what is left of the span: the runner's counts
             extra = self.runner.dispatched(
                 self._c, ((s.slot, s.cur_len - 1, s.cur_len) for s in active_seqs))
         # decode_tick_ms is uploads + dispatch + fetch: the argument uploads
-        # (tokens, lengths, tables, key) belong inside the span
+        # (the tick's rows, and the tables when a page moved) belong inside
+        # the span
         with tel.span(
             "decode_tick", track=ns, hist=self._h["decode_tick_ms"],
             batch=len(active_seqs), ctx_tokens=ctx_tokens, **extra,
         ) as sp:
             args = (
-                self.params, jnp.asarray(tokens), self._commit_rep(seq_lens),
-                self._tables_device(), jnp.asarray(active), self.kv,
-                self._commit_rep(sub),
+                self.params, self._upload(rows, held=False),
+                self._tables_device(), self.kv, self._rng,
                 (sampling.temperature, sampling.top_k, sampling.top_p),
             )
             sp.mark("upload")  # every argument handed over; next: enqueue
-            sampled, _, _, self.kv = self._decode_jit(*args)
+            sampled, self._rng, self.kv = self._decode_jit(*args)
             if self._tracked:
                 self._tracked["_decode_jit"].note(args)
             sp.dispatched()
@@ -1642,14 +1687,12 @@ class InferenceEngineV2:
             self._maybe_fault("runner_exception", uids)
             bsp.mark("rows")  # this body commits its buffers inside the build
             tables = self._tables_device()
-            tokens_dev = self._commit_rep(tokens0)
-            lens_dev = self._commit_rep(base_lens)
-            active_dev = self._commit_rep(active)
-            emitted_dev = self._commit_rep(np.zeros(B, np.int32))
-            stop_dev = self._commit_rep(stop_rows)
-            cap_dev = self._commit_rep(emit_cap)
-            self._rng, key_dev = jax.random.split(self._rng)
-            key_dev = self._commit_rep(key_dev)
+            tokens_dev = self._upload(tokens0)
+            lens_dev = self._upload(base_lens)
+            active_dev = self._upload(active)
+            emitted_dev = self._upload(np.zeros(B, np.int32))
+            stop_dev = self._upload(stop_rows)
+            cap_dev = self._upload(emit_cap)
             triple = (sampling.temperature, sampling.top_k, sampling.top_p)
             # fixed burst capacity -> one compiled program for every n
             cap = self._burst_cap
@@ -1660,8 +1703,8 @@ class InferenceEngineV2:
             # tick t's emissions — counts and tokens come back in ONE fetch
             buf = np.full((cap + 1, B), _BURST_PAD, np.int32)
             buf[0] = 0
-            burst_dev = self._commit_rep(buf)
-            tick_dev = self._commit_rep(np.zeros((), np.int32))
+            burst_dev = self._upload(buf)
+            tick_dev = self._upload(np.zeros((), np.int32))
         # ONE span for the whole burst — per-tick spans would retain one
         # device array per tick, the exact host-reference leak this design
         # removes
@@ -1671,10 +1714,10 @@ class InferenceEngineV2:
         ) as sp:
             sp.mark("upload")  # nothing left to upload: the names of a tick
             for _ in range(n):
-                (tokens_dev, lens_dev, key_dev, self.kv, burst_dev,
+                (tokens_dev, lens_dev, self._rng, self.kv, burst_dev,
                  tick_dev, active_dev, emitted_dev) = self._decode_burst_jit(
                     self.params, tokens_dev, lens_dev, tables, active_dev,
-                    self.kv, key_dev, burst_dev, tick_dev, emitted_dev,
+                    self.kv, self._rng, burst_dev, tick_dev, emitted_dev,
                     stop_dev, cap_dev, triple,
                 )
             sp.dispatched()

@@ -487,9 +487,8 @@ def test_decode_and_verify_donate_kv_no_copy(tiny):
     rng = jax.random.PRNGKey(0)
     lowered = {
         "decode": eng._decode_jit.lower(
-            eng.params, jnp.zeros(B, i32), jnp.ones(B, i32),
-            jnp.zeros((B, eng.max_pages), i32), jnp.ones(B, bool), eng.kv,
-            rng, (0.0, 0, 1.0)),
+            eng.params, jnp.ones((3, B), i32),
+            jnp.zeros((B, eng.max_pages), i32), eng.kv, rng, (0.0, 0, 1.0)),
         "verify": eng._spec_jit.lower(
             eng.params, jnp.zeros(B * K1, i32), jnp.zeros(B * K1, i32),
             jnp.zeros(B * K1, i32), jnp.full(B * K1, -1, i32),
