@@ -90,7 +90,6 @@ def run(*, config, traffic, chips, seed, seconds, trace, rehearse, workload,
     gc.disable()
     gen = engine.train_on_loader(batches)
     pending = collections.deque()
-    enqueue_ms: List[float] = []
     steps = 0
     t0 = clock()
     t1 = t0 + seconds
@@ -101,10 +100,8 @@ def run(*, config, traffic, chips, seed, seconds, trace, rehearse, workload,
             if now >= t1:
                 break
             cap.poll(now)
-            a = clock()
             with cap.annotate("bench.step", step=steps):
                 pending.append(next(gen))
-            enqueue_ms.append(1e3 * (clock() - a))
             steps += 1
             if len(pending) > LAG:
                 jax.block_until_ready(pending.popleft())
@@ -122,7 +119,7 @@ def run(*, config, traffic, chips, seed, seconds, trace, rehearse, workload,
     return {
         "kind": "train", "correct": correct, "attempted": steps, "failed": 0,
         "window": (t0, t_end), "t_process": t_process, "steps": steps,
-        "tokens_per_step": rows * plan.seq, "chips": chips, "enqueue_ms": enqueue_ms,
+        "tokens_per_step": rows * plan.seq, "chips": chips,
         "fallbacks": fallbacks, "compiles_in_window": watch.within(t0, t_end),
         "trace": obs_trace, "model": model, "seq": plan.seq, "micro": plan.micro,
         "notes": notes,

@@ -17,6 +17,10 @@ ROOT = Path(__file__).resolve().parents[1]
 HERE = Path(__file__).resolve().parent
 OUT_DIR = ROOT / ".bench_out"          # traces and scratch, inside the checkout
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# libtpu pins a host staging buffer of 4 GiB a chip when it starts, and
+# without transparent hugepages that takes 8.7-11.7 s by the machine; a
+# configuration's ``runtime.tpu_premapped_buffer_bytes`` sets its size
+PREMAP_ENV = ("TPU_PREMAPPED_BUFFER_SIZE", "TPU_PREMAPPED_BUFFER_TRANSFER_THRESHOLD_BYTES")
 
 
 class BenchError(SystemExit):
@@ -87,10 +91,12 @@ def module(kind: str, name: str):
 # ---------------------------------------------------------------------------
 # device, cache, compile accounting
 # ---------------------------------------------------------------------------
-def prepare_environment(chips: int, rehearse: bool) -> None:
+def prepare_environment(chips: int, rehearse: bool,
+                        premapped_bytes: Optional[int] = None) -> None:
     """Before JAX is imported.  A rehearsal pins the CPU backend with as many
     virtual devices as the cell asks chips; a real run leaves the platform to
-    JAX, which fails at start-up where it finds no accelerator."""
+    JAX, which fails at start-up where it finds no accelerator, and gives the
+    TPU runtime the configuration's size of its premapped host buffer."""
     if rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
@@ -100,6 +106,9 @@ def prepare_environment(chips: int, rehearse: bool) -> None:
         # the program's rule (utils/compile_cache.py): an operator's directory
         # wins; otherwise one fixed path inside this checkout
         os.environ.setdefault(CACHE_ENV, str(ROOT / ".jax_cache"))
+        if premapped_bytes is not None:
+            for name in PREMAP_ENV:
+                os.environ.setdefault(name, str(int(premapped_bytes)))
 
 
 def device_gate(chips: int, rehearse: bool) -> dict:
@@ -177,6 +186,58 @@ def peak_bytes() -> int:
         s = d.memory_stats() or {}
         peaks.append(int(s.get("peak_bytes_in_use", 0)))
     return max(peaks)
+
+
+def observe(*, workload: str, seed: int, seconds: Optional[float], trace: bool,
+            rehearse: bool, overrides: Dict[str, Any], t_process: float):
+    """Run ONE cell ONCE through its driver: what ``run.py`` and the builder's
+    tools share.  ``overrides`` replace keys of the traffic file (a builder's
+    sweep; the driver's command never passes any).  Returns the manifest, the
+    cell's entry and the driver's observations, ``obs["device"]`` filled in."""
+    man = manifest()
+    cell = find_cell(man, workload)
+    config = rehearsed(config_of(man, cell["config"]), rehearse)
+    traffic = {**rehearsed(traffic_of(cell["traffic"]), rehearse), **overrides}
+    if seconds is None:
+        seconds = float(traffic.get("rehearsal_seconds", 2.0) if rehearse
+                        else man["run_seconds"])
+    prepare_environment(cell["chips"], rehearse,
+                        config.get("runtime", {}).get("tpu_premapped_buffer_bytes"))
+    device = device_gate(cell["chips"], rehearse)
+    t_started = time.perf_counter()
+    watch = CompileWatch().install()
+    obs = module("drivers", config["driver"]).run(
+        config=config, traffic=traffic, chips=cell["chips"], seed=seed,
+        seconds=seconds, trace=trace, rehearse=rehearse, workload=cell["name"],
+        t_process=t_process, watch=watch, device=device)
+    device["memory_peak_bytes"] = 0 if rehearse else peak_bytes()
+    obs["device"] = device
+    obs.setdefault("notes", []).insert(
+        0, f"setup: process start to the driver (imports, the runtime's start) "
+           f"{t_started - t_process:.2f} s")
+    obs["notes"].extend(stall_notes(obs))
+    return man, cell, obs
+
+
+def stall_notes(obs: Dict[str, Any]) -> List[str]:
+    """Where a serving window lost its time, if it lost any: its longest tick
+    with the program's spans inside it, longest first, and the longest pause
+    between two ticks (the host outside the loop).  From the drivers' tick
+    stamps ``(begin, end, ...)`` and the span tree, both on one clock."""
+    t0, t1 = obs.get("window", (0.0, 0.0))
+    ticks = [t for t in obs.get("ticks", ()) if t0 <= t[1] < t1]
+    if len(ticks) < 2:
+        return []
+    tb, te = max(((t[0], t[1]) for t in ticks), key=lambda t: t[1] - t[0])
+    inside = sorted(((b - a, name) for name, a, b, _ in obs.get("spans", ())
+                     if tb <= a and b <= te), reverse=True)[:4]
+    median = sorted(t[1] - t[0] for t in ticks)[len(ticks) // 2]
+    pause, at = max((b[0] - a[1], a[1]) for a, b in zip(ticks, ticks[1:]))
+    return [f"load: longest tick {1e3 * (te - tb):.1f} ms (median {1e3 * median:.1f}) "
+            f"at {tb - t0:.1f} s of the window; inside it: "
+            + (", ".join(f"{name} {1e3 * d:.1f}" for d, name in inside) or "no span kept"),
+            f"load: longest pause between two ticks {1e3 * pause:.1f} ms "
+            f"at {at - t0:.1f} s of the window"]
 
 
 # ---------------------------------------------------------------------------

@@ -39,30 +39,15 @@ def main(argv=None) -> int:
                          "traffic file for this run (the driver never passes it)")
     args = ap.parse_args(argv)
 
-    man = harness.manifest()
-    cell = harness.find_cell(man, args.workload)
-    config = harness.rehearsed(harness.config_of(man, cell["config"]), args.rehearse)
-    traffic = harness.rehearsed(harness.traffic_of(cell["traffic"]), args.rehearse)
+    overrides = {}
     for item in args.set:
         key, _, value = item.partition("=")
-        traffic[key] = json.loads(value)
-    seconds = args.seconds if args.seconds is not None else float(man["run_seconds"])
-    if args.rehearse and args.seconds is None:
-        seconds = float(traffic.get("rehearsal_seconds", 2.0))
-
-    harness.prepare_environment(cell["chips"], args.rehearse)
-    device = harness.device_gate(cell["chips"], args.rehearse)
-    watch = harness.CompileWatch().install()
-
-    driver = harness.module("drivers", config["driver"])
-    obs = driver.run(
-        config=config, traffic=traffic, chips=cell["chips"], seed=args.seed,
-        seconds=seconds, trace=bool(args.trace), rehearse=args.rehearse,
-        workload=cell["name"], t_process=T_PROCESS, watch=watch, device=device,
-    )
-
-    device["memory_peak_bytes"] = harness.peak_bytes() if not args.rehearse else 0
-    obs["device"] = device
+        overrides[key] = json.loads(value)
+    man, cell, obs = harness.observe(
+        workload=args.workload, seed=args.seed, seconds=args.seconds,
+        trace=bool(args.trace), rehearse=args.rehearse, overrides=overrides,
+        t_process=T_PROCESS)
+    device = obs["device"]
     trace = obs.get("trace")
     breakdown = None
     if trace is not None and not args.rehearse:

@@ -113,21 +113,10 @@ def main(argv=None) -> int:
                     help="the CPU pre-flight of run.py: the flow, and nothing to read")
     args = ap.parse_args(argv)
 
-    man = harness.manifest()
-    cell = harness.find_cell(man, args.workload)
-    config = harness.rehearsed(harness.config_of(man, cell["config"]), args.rehearse)
-    traffic = harness.rehearsed(harness.traffic_of(cell["traffic"]), args.rehearse)
-    seconds = args.seconds if args.seconds is not None else float(
-        traffic.get("rehearsal_seconds", 2.0) if args.rehearse else man["run_seconds"])
-    harness.prepare_environment(cell["chips"], args.rehearse)
-    device = harness.device_gate(cell["chips"], args.rehearse)
-    watch = harness.CompileWatch().install()
-    obs = harness.module("drivers", config["driver"]).run(
-        config=config, traffic=traffic, chips=cell["chips"], seed=args.seed,
-        seconds=seconds, trace=True, rehearse=args.rehearse, workload=cell["name"],
-        t_process=T_PROCESS, watch=watch, device=device)
-    device["memory_peak_bytes"] = 0 if args.rehearse else harness.peak_bytes()
-    obs["device"] = device
+    man, cell, obs = harness.observe(
+        workload=args.workload, seed=args.seed, seconds=args.seconds, trace=True,
+        rehearse=args.rehearse, overrides={}, t_process=T_PROCESS)
+    device = obs["device"]
 
     progs, spans = xprograms.of(obs), obs.get("spans") or ()
     secs = idle_by_phase.of(obs)

@@ -27,12 +27,19 @@ def cells_of(metric):
     return metric.get("workloads", CELLS)
 
 
+def pairs_of(metrics):
+    """(metric, cell) for every cell a metric lists: what a run of that cell
+    loads through ``harness.metrics_of``."""
+    return [pytest.param(m, c, id=f"{m['name']}-{c}") for m in metrics for c in cells_of(m)]
+
+
 def test_top_level_keys_and_limits():
     assert set(MAN) == {"command", "paths", "run_seconds", "configs", "workloads",
                         "end_to_end", "per_layer"}
     assert isinstance(MAN["run_seconds"], int) and 1 <= MAN["run_seconds"] <= 51
     assert (ROOT / "BENCHMARK.json").stat().st_size <= 64 * 1024
     assert 1 <= len(MAN["workloads"]) <= 24 and 1 <= len(MAN["end_to_end"]) <= 16
+    assert 1 <= len(MAN["per_layer"]) <= 128  # the driver's cap, not this PR's count
     assert sum(w["chips"] == 4 for w in MAN["workloads"]) <= max(1, len(CELLS) // 4)
     assert all(w["chips"] in (1, 4) for w in MAN["workloads"])
     pairs = [(w["config"], w["traffic"]) for w in MAN["workloads"]]
@@ -84,19 +91,78 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(cell):
     assert any(cell in cells_of(m) for m in MAN["per_layer"])
 
 
-@pytest.mark.parametrize("metric", MAN["per_layer"], ids=lambda m: m["name"])
-def test_moves_names_an_end_to_end_metric_its_cells_report(metric):
+@pytest.mark.parametrize("metric,cell", pairs_of(MAN["per_layer"]))
+def test_moves_names_an_end_to_end_metric_its_cells_report(metric, cell):
     target = next(m for m in MAN["end_to_end"] if m["name"] == metric["moves"])
-    assert set(cells_of(metric)) <= set(cells_of(target))
-    assert set(cells_of(metric)) <= set(CELLS)
+    assert cell in CELLS and cell in cells_of(target)
 
 
-@pytest.mark.parametrize("metric", ALL_METRICS, ids=lambda m: m["name"])
-def test_every_metric_has_its_own_file_and_a_reader(metric):
+@pytest.mark.parametrize("metric,cell", pairs_of(ALL_METRICS))
+def test_every_metric_has_its_own_file_and_a_reader(metric, cell):
+    assert metric in harness.metrics_of(MAN, cell, metric in MAN["per_layer"])
     spec = harness.load_json(harness.HERE / "metrics" / f"{metric['name']}.json")
     assert spec["unit"] == metric["unit"]
     reader = harness.module("readers", spec["reader"])
     assert callable(reader.read)
+
+
+def test_no_metric_file_is_left_without_an_entry():
+    on_disk = {p.stem for p in (harness.HERE / "metrics").glob("*.json")}
+    assert on_disk == {m["name"] for m in ALL_METRICS}
+    used = {harness.load_json(harness.HERE / "metrics" / f"{n}.json")["reader"] for n in on_disk}
+    # a reader no metric names is dead code (a module without ``read`` is a helper)
+    readers = {p.stem for p in (harness.HERE / "readers").glob("[!_]*.py")
+               if hasattr(harness.module("readers", p.stem), "read")}
+    assert used == readers
+
+
+# PR 41 merged the families below: one entry ``<family>.serve`` whose
+# ``workloads`` are the cells that each had an entry ``<family>.<suffix>`` with
+# the same reader and parameters.  What the per-cell files held, kept here:
+SUFFIX = {"docs": "mistral7b_docs_closed", "dots": "dots3_note_longdocs_closed",
+          "nemo": "nemotron3_super_reasoning_closed", "qnext": "qwen3_next_longctx_qa_closed"}
+FOUR = ("docs", "dots", "nemo", "qnext")
+MERGED = [
+    ("kv_preemptions", FOUR, "counter", {"key": "preemptions"}),
+    ("kernel_fallbacks", FOUR, "kernel_fallbacks", {}),
+    ("compiles_in_window", FOUR, "field", {"key": "compiles_in_window"}),
+    ("device_idle_share", FOUR, "device_idle_share", {}),
+    ("peak_hbm_gib", FOUR, "peak_hbm_gib", {}),
+    ("prefill_pack_device_p50_ms", FOUR, "module_device_percentile",
+     {"module": "^jit_packed(_ctx)?_impl$", "q": 50}),
+    ("pack_build_p50_ms", FOUR[:3], "span_percentile", {"span": "engine.pack_build", "q": 50}),
+    ("host_device_skew_ms", FOUR[:3], "host_device_skew",
+     {"span": "decode_tick", "module": "^jit_decode_impl$"}),
+    ("routed_here_share", FOUR[1:], "counter_ratio",
+     {"num": "expert_pairs_held", "den": "expert_pairs_routed", "scale": 100.0}),
+    ("decode_batch_mean", FOUR[2:], "counter_ratio",
+     {"num": "decode_emitted", "den": "decode_ticks"}),
+    ("decode_device_p50_ms", FOUR[2:], "module_device_percentile",
+     {"module": "^jit_decode_impl$", "q": 50}),
+]
+RETIRED = ("tick_p50_ms.docs", "host_enqueue_ms.train", "decode_dispatch_p50_ms.chat")
+
+
+@pytest.mark.parametrize("family,suffixes,reader,params", MERGED, ids=[m[0] for m in MERGED])
+def test_a_merged_family_reads_what_its_per_cell_files_read(family, suffixes, reader, params):
+    entry = next(m for m in MAN["per_layer"] if m["name"] == f"{family}.serve")
+    assert entry["workloads"][:len(suffixes)] == [SUFFIX[s] for s in suffixes]
+    assert entry["moves"] == "serve_tokens_per_s"
+    spec = harness.load_json(harness.HERE / "metrics" / f"{family}.serve.json")
+    assert spec["reader"] == reader and spec.get("params", {}) == params
+    names = {m["name"] for m in MAN["per_layer"]}
+    assert not names & {f"{family}.{s}" for s in suffixes}
+    # the cells that move another end-to-end metric keep an entry of their own
+    # on the same reader and parameters
+    for other in sorted(n for n in names if n.rpartition(".")[0] == family and n != entry["name"]):
+        kept = harness.load_json(harness.HERE / "metrics" / f"{other}.json")
+        assert (kept["reader"], kept.get("params", {})) == (reader, params)
+
+
+def test_the_retired_metrics_are_gone_from_the_manifest_and_the_harness():
+    text = (ROOT / "BENCHMARK.json").read_text() + "".join(
+        p.read_text() for p in harness.HERE.rglob("*") if p.suffix in (".py", ".json", ".md"))
+    assert [n for n in RETIRED if n in text] == []
 
 
 def test_layers_of_one_module_are_spelled_alike():
@@ -139,6 +205,39 @@ def test_configuration_files_state_source_reduced_assumed_and_widths(config):
     check_configuration(
         config, c, harness.load_json(harness.HERE / "published" / f"{c['published']}.json"))
     assert any(w["config"] == config["name"] for w in MAN["workloads"])
+
+
+@pytest.mark.parametrize("config", MAN["configs"], ids=lambda c: c["name"])
+def test_a_configurations_runtime_group_sizes_the_premapped_buffer_and_says_why(config):
+    """``runtime`` holds what the TPU runtime is started with, not the model:
+    today only the size of its pinned host staging buffer (PR 41's refusal:
+    pinning the default 4 GiB was 8.7-11.7 s of an 18-22 s set-up)."""
+    runtime = harness.load_json(ROOT / config["file"]).get("runtime")
+    if runtime is None:
+        return
+    assert set(runtime) == {"tpu_premapped_buffer_bytes", "why"} and runtime["why"]
+    size = runtime["tpu_premapped_buffer_bytes"]
+    assert isinstance(size, int) and size % 2**20 == 0 and 2**26 <= size <= 2**32
+
+
+@pytest.mark.parametrize("rehearse,given,already,expect", [
+    (False, 2**28, None, str(2**28)),      # the configuration's size reaches libtpu
+    (False, 2**28, "1024", "1024"),        # an operator's value wins, as with the cache
+    (False, None, None, None),             # no key: the runtime's default
+    (True, 2**28, None, None),             # a rehearsal starts no TPU runtime
+])
+def test_prepare_environment_hands_the_runtime_its_premapped_size(
+        monkeypatch, rehearse, given, already, expect):
+    for name in (harness.CACHE_ENV, "JAX_PLATFORMS", "XLA_FLAGS",
+                 "JAX_ENABLE_COMPILATION_CACHE"):
+        monkeypatch.setenv(name, os.environ.get(name, ""))
+    for name in harness.PREMAP_ENV:
+        if already is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, already)
+    harness.prepare_environment(1, rehearse, given)
+    assert [os.environ.get(name) for name in harness.PREMAP_ENV] == [expect, expect]
 
 
 def _expert_configuration():

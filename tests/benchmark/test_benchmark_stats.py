@@ -8,8 +8,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from benchmark.readers import (counter, counter_ratio, itl_percentile,  # noqa: E402
                                request_percentile, serve_rate, setup_seconds,
-                               span_percentile, stall_share, tick_percentile,
-                               train_rate)
+                               span_percentile, stall_share, train_rate)
 from benchmark.stats import all_gaps_ms, percentile, token_gaps_ms  # noqa: E402
 
 
@@ -88,11 +87,38 @@ def test_serve_rate_follows_the_curve_of_completed_prefill():
     assert serve_rate.read({"window": (0, 1), "requests": [request()]}) is None
 
 
-def test_tick_percentile_reads_the_harness_span():
-    obs = {"window": (0.0, 10.0), "ticks": [(1.0, 1.1, 0, 0, 1, 0), (2.0, 2.3, 0, 0, 1, 0),
-                                            (9.9, 10.2, 0, 0, 1, 0)]}
-    assert tick_percentile.read(obs, q=50) == pytest.approx(200.0)
-    assert tick_percentile.read({"window": (0, 1)}, q=50) is None
+def test_in_flight_is_the_mean_over_the_ticks_of_one_part_of_the_window():
+    """The sweep tool's queue reading, from the stamps a serving driver keeps
+    for every tick: (begin, end, decoding, context tokens, in flight, waiting)."""
+    from benchmark.tools import sweep_traffic
+
+    obs = {"window": (0.0, 10.0), "ticks": [(1.0, 1.1, 0, 0, 4, 0), (1.5, 1.9, 0, 0, 6, 1),
+                                            (2.0, 2.3, 0, 0, 9, 0), (9.9, 10.2, 0, 0, 30, 0),
+                                            (8.5, 8.6, 0, 0, 12, 3)]}
+    assert sweep_traffic.in_flight(obs, 0) == pytest.approx(5.0)   # ticks ending in [0, 2)
+    assert sweep_traffic.in_flight(obs, 1) == pytest.approx(9.0)
+    assert sweep_traffic.in_flight(obs, 4) == pytest.approx(12.0)  # the tick past the end is out
+    assert sweep_traffic.in_flight(obs, 2) is None
+    assert sweep_traffic.in_flight({"window": (0, 1)}, 0) is None
+
+
+def test_stall_notes_name_the_longest_tick_its_spans_and_the_longest_pause():
+    """``harness.observe`` adds them to a serving run's notes: a window that
+    lost seconds says whether ONE tick held them (and which span) or the host
+    outside the loop.  Ticks and spans outside the window do not count."""
+    from benchmark import harness
+
+    obs = {"window": (10.0, 20.0),
+           "ticks": [(9.0, 9.9, 0, 0, 1, 0), (10.0, 10.1, 0, 0, 1, 0), (10.1, 10.2, 0, 0, 1, 0),
+                     (10.5, 13.0, 0, 0, 1, 0), (13.0, 13.1, 0, 0, 1, 0), (19.0, 25.0, 0, 0, 1, 0)],
+           "spans": [("sched.tick", 10.5, 13.0, {}), ("prefill_pack", 10.6, 12.9, {}),
+                     ("engine.pack_build", 10.5, 10.6, {}), ("decode_tick", 10.0, 10.1, {})]}
+    tick, pause = harness.stall_notes(obs)
+    assert tick.startswith("load: longest tick 2500.0 ms (median 100.0) at 0.5 s of the window")
+    assert tick.endswith("sched.tick 2500.0, prefill_pack 2300.0, engine.pack_build 100.0")
+    assert pause == "load: longest pause between two ticks 300.0 ms at 0.2 s of the window"
+    assert harness.stall_notes({"window": (0.0, 1.0), "ticks": [(0.1, 0.2)]}) == []
+    assert harness.stall_notes({"kind": "train"}) == []
 
 
 @pytest.mark.parametrize("quantity,q,expect", [

@@ -46,10 +46,44 @@ def _order(p):
     return list(p.lengths)
 
 
+def _every_round_fixed(name):
+    t = harness.traffic_of(name)
+    return "fixed_rounds" in t and t["fixed_rounds"] * t["strata"] == t["pool"]
+
+
 @pytest.mark.parametrize("name", [n for n in TRAFFIC if n != "train_fixed_4k"])
 def test_other_seed_other_order(name):
-    a, b = _order(plan(name, 1)), _order(plan(name, 2))
-    assert sorted(a) == sorted(b) and a != b
+    pa, pb = plan(name, 1), plan(name, 2)
+    a, b = _order(pa), _order(pb)
+    assert sorted(a) == sorted(b)
+    if _every_round_fixed(name):  # the lengths are the cell's; the seed draws the content
+        assert a == b
+        assert [r.prompt for _, r in pa.initial()] != [r.prompt for _, r in pb.initial()]
+    else:
+        assert a != b
+
+
+@pytest.mark.parametrize("name,tasks", [("longctx_qa_closed", 64), ("longdocs_closed", 64)])
+def test_the_tasks_a_faster_program_would_reach_are_the_same_for_every_seed(name, tasks):
+    """Cells 7 and 5 (PR 41): the first ``tasks`` tasks, lengths AND their order,
+    hold for three seeds; a window reaches ~45 of cell 7's and ~43 of cell 5's."""
+    plans = [plan(name, seed) for seed in (1, 7, BIG_SEED)]
+    t = harness.traffic_of(name)
+    assert t["fixed_rounds"] * t["strata"] >= tasks
+    heads = [(p.lengths[:tasks], getattr(p, "answers", [])[:tasks]) for p in plans]
+    assert heads[0] == heads[1] == heads[2]
+    assert sorted(heads[0][0]) != heads[0][0]  # dealt, not ascending
+    if tasks < t["pool"]:  # the rounds after them are the seed's
+        assert plans[0].lengths != plans[1].lengths
+
+
+def test_chat_rate_is_a_whole_number_of_session_starts_a_stratum():
+    t = harness.traffic_of("chat_sessions")
+    per = t["session_starts_per_s"] * t["stratum_s"]
+    assert abs(per - round(per)) < 1e-3 and round(per) >= 1
+    assert (t["ramp_s"] / t["stratum_s"]).is_integer()
+    assert {"knee_session_starts_per_s", "rate_note", "swept_on"} <= set(t)
+    assert t["session_starts_per_s"] <= 0.8 * t["knee_session_starts_per_s"] + 1e-3
 
 
 def test_chat_sessions_schedule_is_stratified():
