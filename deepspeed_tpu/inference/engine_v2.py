@@ -154,7 +154,7 @@ class InferenceEngineV2:
             from .latent_runner import LatentRunner
 
             # a slot's state is a recurrence's (state-space or delta rule), not a ring
-            states = cfg.latent.stateful
+            states = cfg.latent.stateful and not cfg.latent.ringed
             for option, on, why in (
                 ("grid (a tensor-parallel / replica / seq-shard serve mesh)",
                  grid is not None or int(serve_replicas) > 1 or int(seq_shards) > 1,

@@ -32,12 +32,22 @@ A model of single-mixer blocks (``models/latent.py:SINGLE``) keeps instead:
 - an expert block keeps nothing but its routing counts (``touched``: held
   experts with a row and the pairs on them, of packs and of ticks).
 
-A model of two-norm blocks (``models/latent.py:HYBRID``: Gated DeltaNet beside
-gated GQA, an expert layer in every block) keeps the same three things under
-the same names: per ``gdn`` block and slot the delta rule's MATRIX state
-(``ssm``, [Hv, Dk, Dv] float32) and its convolution's tail (``conv``), zeroed by
-position, carried and recomputed as above; per ``gattn`` block K / V pages (the
-keys after their norm and rotation); per block its experts' routing counts.
+A model of two-norm blocks (``models/latent.py:HYBRID``: Gated DeltaNet and
+gated GQA of up to two kinds, a dense SwiGLU or the expert layer behind them)
+keeps the same three things under the same names: per ``gdn`` block and slot
+the delta rule's MATRIX state (``ssm``, [Hv, Dk, Dv] float32) and its
+convolution's tail (``conv``), zeroed by position, carried and recomputed as
+above; per ``gattn`` block K / V pages (the keys after their norm and
+rotation); per expert block its routing counts.  And a FIFTH kind of state
+beside the pages:
+
+- per ``wattn`` block (gated GQA over a window) a K / V RING a slot (``wk``,
+  ``wv``), with the ring arithmetic of the ``sliding`` layers above (``p % R``,
+  ``R`` = ``ring_rows``: one pack + the look-back in pages) and the rows of the
+  K / V pages: a ring is ``R / page`` pages of the pool ``[slots * R / page,
+  page, Hkv, hd]``, written a page at a time in place like the pages.  Nothing
+  is allocated or freed, a slot's next owner and a resume overwrite from 0, and
+  the host mirror that ``close()`` audits is the ``sliding`` rings' own.
 
 One layer body (``_layer``) serves the pack and the tick; the kind chooses how
 the rows are written and read.  A pack reads its own rows back from the cache
@@ -93,6 +103,19 @@ COUNTERS = (
 )
 
 
+# ... of one whose layers are gated GQA over every key (pages) and over a window
+# (rings), with the expert layer's counts of the models below
+WINDOW_COUNTERS = (
+    "full_keys_attended",     # (query, key) pairs of the layers over every key: causal keys
+    "window_keys_attended",   # ... of the layers over a window: min(position + 1, window)
+    "causal_keys",            # what the window layers would attend if they were full
+    "window_rows_discarded",  # ring rows that fell out of a window
+    "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
+    "expert_group_rows_min", "experts_touched", "experts_touched_decode",
+    "expert_pairs_held_decode",
+)
+
+
 # ... and of one whose slots keep a recurrence's state (``LatentSpec.stateful``:
 # state-space or delta rule; the names say ``ssm`` for either)
 STATE_COUNTERS = (
@@ -114,9 +137,11 @@ def _lanes(width: int) -> int:
 
 
 def ring_rows(cfg, block_size: int, pack_tokens: int) -> int:
-    """Rows of a slot's ring: one pack and the window's look-back, in pages."""
-    back = -(-(cfg.latent.sliding.window - 1) // block_size) * block_size
-    return pack_tokens + back
+    """Rows of a slot's ring (a ``sliding`` layer's or a ``wattn`` layer's): one
+    pack and the window's look-back, in pages."""
+    s = cfg.latent
+    window = (s.wattn if s.hybrid else s.sliding).window
+    return pack_tokens + -(-(window - 1) // block_size) * block_size
 
 
 def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
@@ -126,7 +151,7 @@ def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
         raise ValueError(f"a pack of {pack_tokens} tokens is no whole number of "
                          f"pages of {block_size}")
     if s.stateful:
-        return _init_state_cache(cfg, num_blocks, block_size, max_seqs, dtype)
+        return _init_state_cache(cfg, num_blocks, block_size, max_seqs, pack_tokens, dtype)
     pages = lambda w, n: tuple(
         jnp.zeros((num_blocks, block_size, w), dtype) for _ in range(n))
     chunks = max_seqs * ring_rows(cfg, block_size, pack_tokens) // block_size
@@ -145,9 +170,9 @@ def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
 
 
 def _init_state_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
-                      dtype) -> Cache:
-    """The cache of a model whose slots keep a recurrence's state and K / V
-    pages (``LatentSpec.stateful``; module docstring)."""
+                      pack_tokens: int, dtype) -> Cache:
+    """The cache of a model whose slots keep a recurrence's state, K / V pages
+    and K / V rings (``LatentSpec.stateful``; module docstring)."""
     from .paged import init_paged_cache
 
     s = cfg.latent
@@ -157,10 +182,17 @@ def _init_state_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
     per = lambda shape, dt: tuple(jnp.zeros((max_seqs, *shape), dt)
                                   for _ in range(s.count(rec)))
     n_moe = len(s.expert_layers)
+    rings = {}
+    if s.count("wattn"):
+        # a slot's ring is R / page pages of one pool, slot n's from page n * R / page
+        chunks = max_seqs * ring_rows(cfg, block_size, pack_tokens) // block_size
+        rings["wk"], rings["wv"] = init_paged_cache(
+            s.count("wattn"), chunks, block_size, s.wattn.num_kv_heads, s.wattn.head_dim,
+            dtype=dtype)
     return {
-        "ssm": per(mb.state_shape, jnp.float32),
-        "conv": per((mb.conv - 1, mb.conv_width), dtype),
-        "k": k, "v": v,
+        "ssm": per(mb.state_shape, jnp.float32) if mb else (),
+        "conv": per((mb.conv - 1, mb.conv_width), dtype) if mb else (),
+        "k": k, "v": v, **rings,
         "stats": jnp.zeros((n_moe, len(ROUTING_STATS)), jnp.int32).at[:, 3].set(_NO_MIN),
         # per expert block, of packs [0] and of ticks [1]: (held experts with a
         # row, pairs on held experts), running sums
@@ -380,24 +412,40 @@ def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_group
     """One two-norm block (``models/latent.py:HYBRID``) on token rows ``x``
     [T, d], through ``_block``'s seam: a Gated DeltaNet mixer's ``write`` IS its
     read, gated attention writes K / V rows (the keys normed and rotated) and
-    reads the pools, with the q / k norms, the rotation and the output gate
-    outside the kernels; then the expert layer."""
+    reads the pools (its kind's: pages, or a ring a slot), with the q / k norms,
+    the rotation and the output gate outside the kernels; then the feed-forward
+    (a leading layer's dense SwiGLU, else the expert layer)."""
     s, eps = cfg.latent, cfg.norm_eps
-    kind, (n1, n2), mw, fw = lm.hybrid_params(layers, l, s)
+    kind, (n1, n2), mw, fw, is_moe = lm.hybrid_params(layers, l, s)
     i = s.layer_kinds[:l].count(kind)
-    h = lm.rms_centred(x, n1, eps)
+    h = lm.norm(x, n1, cfg)
     if kind == "gdn":
         y, cache = _recurrence(kind, i, mw, h, cache, write)
     else:
-        q, k, v, gate = lm.gattn_inputs(mw, h, pos, s.gattn, eps)
-        pools = write(kind, (cache["k"][i], cache["v"][i]), (k, v))
-        cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
-        with jax.named_scope("gated_attn"):
+        q, k, v, gate = lm.gattn_inputs(mw, h, pos, s.mixer(kind), eps, s.unit_offset)
+        # pages for the kind over every key, a ring a slot for the kind over a window
+        kk, kv = ("wk", "wv") if kind == "wattn" else ("k", "v")
+        pools = write(kind, (cache[kk][i], cache[kv][i]), (k, v))
+        cache = {**cache, kk: _put(cache[kk], i, pools[0]), kv: _put(cache[kv], i, pools[1])}
+        with jax.named_scope(_attn_scope(s, kind)):
             o = read(kind, pools, (q, k, v))
         y = lm.gattn_output(mw, o.astype(x.dtype), gate)
     x = x + y.astype(x.dtype)
-    y, cache = _experts(cfg, l, fw, lm.rms_centred(x, n2, eps), valid, cache, track_groups, probe)
+    h = lm.norm(x, n2, cfg)
+    if is_moe:
+        y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, track_groups, probe)
+    else:
+        y = lm.ffn(fw, h, False, cfg)[0]
     return x + y.astype(x.dtype), cache
+
+
+def _attn_scope(s, kind: str) -> str:
+    """The named scope of a two-norm block's attention: a model of ONE kind of
+    gated attention calls it ``gated_attn``; where two kinds stand side by side
+    each has its own name, so that a trace tells their times apart."""
+    if s.wattn is None:
+        return "gated_attn"
+    return "window_attn" if kind == "wattn" else "full_attn"
 
 
 def _fit(rows, pages):
@@ -420,7 +468,8 @@ def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_i
     less ``ctx_lens``: a token's position says where its context ends).
     ``tables`` [N, P] are the block tables by slot, this pack's pages included.
     ``probe`` (a list) collects, layer by layer, what the indexers and the
-    routers picked and what a state-space block's recurrence consumed.
+    routers picked, what a state-space block's recurrence consumed and what a
+    window's mask let each query see.
     Returns (logits [N, vocab], cache)."""
     t = tokens.shape[0]
     valid = segment_ids > 0
@@ -501,14 +550,17 @@ def _write_pages(pool, rows, pages):
 
 def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache, picked,
                      probe):
-    """A pack's (write, read) for blocks that keep a recurrence's state or K / V
-    pages (``LatentSpec.stateful``).  The pack is chunks of
+    """A pack's (write, read) for blocks that keep a recurrence's state, K / V
+    pages or a K / V ring (``LatentSpec.stateful``).  The pack is chunks of
     one page of one sequence (``bs`` tokens: the scan's chunk); a chunk whose
     first position is 0 starts from ZEROS, a chunk that follows its own
     sequence's chunk in the pack takes the state handed over inside the scan,
     any other loads its slot's; the state after a sequence's last chunk in the
     pack is kept.  Attention reads [the cached pages under the chunk's start |
-    the pack's own rows] as a dense model's chunked prefill does."""
+    the pack's own rows] as a dense model's chunked prefill does; attention over a
+    window reads a chunk's own page and the look-back's out of its slot's ring
+    (``ops/gated_attention.py``), which the whole pack's rows were written to first."""
+    from ..ops import gated_attention as ga
     from .paged import paged_attention_packed_ctx
 
     s, t = cfg.latent, segment_ids.shape[0]
@@ -540,12 +592,46 @@ def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cac
             return (ssm.at[keep].set(states.astype(ssm.dtype), mode="drop"),
                     conv.at[keep].set(tails.astype(conv.dtype), mode="drop"),
                     y.reshape(t, -1))
-        return tuple(_write_pages(a, grouped(r), pack_pages) for a, r in zip(arrays, rows))
+        pages = pack_pages
+        if kind == "wattn":  # the ring's page of this page of the sequence
+            rc = arrays[0].shape[0] // n_slots
+            if rc * bs < ring_rows(cfg, bs, t):
+                raise ValueError(f"a pack of {t} tokens needs rings of {ring_rows(cfg, bs, t)} "
+                                 f"rows; the cache was built with {rc * bs}")
+            pages = jnp.where(live, slot * rc + start // bs % rc, -1)
+        return tuple(_write_pages(a, grouped(r), pages) for a, r in zip(arrays, rows))
 
     def read(kind, pools, qkv):
-        return paged_attention_packed_ctx(*qkv, segment_ids, *pools, tables, ctx_lens)
+        if kind == "wattn":
+            # a page of the pack starts on one of its sequence's pages: start // bs
+            o = ga.ring_attention(grouped(qkv[0]), grouped(positions), *pools, slot,
+                                  start // bs, pools[0].shape[0] // n_slots, s.wattn.window,
+                                  probe)
+            return o.reshape(t, *o.shape[2:])
+        return _by_whole_groups(
+            lambda q: paged_attention_packed_ctx(q, *qkv[1:], segment_ids, *pools, tables,
+                                                 ctx_lens), qkv[0], qkv[1].shape[1])
 
     return write, read
+
+
+def _by_whole_groups(attend, q, hkv: int):
+    """``attend(q)`` for q [T, Hq, hd] whose ``Hq / hkv`` query heads a K / V head
+    are no power of two (6 = 4 + 2), as one call a power of two: the packed-ctx
+    kernel tiles its rows by the group, and Mosaic declines the layouts of a
+    group of 6 (``Not implemented: Lane broadcast``, seen compiling the pack
+    for the chip with no chip attached).  The calls read the same keys; a
+    power of two is one call, as it was."""
+    t, hq, hd = q.shape
+    g = hq // hkv
+    if g & (g - 1) == 0:
+        return attend(q)
+    q, at, outs = q.reshape(t, hkv, g, hd), 0, []
+    for n in (1 << i for i in reversed(range(g.bit_length())) if g >> i & 1):
+        o = attend(q[:, :, at:at + n].reshape(t, hkv * n, hd))
+        outs.append(o.reshape(t, hkv, n, hd))
+        at += n
+    return jnp.concatenate(outs, axis=2).reshape(t, hq, hd)
 
 
 def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cache,
@@ -603,7 +689,8 @@ def _latent_tick_seam(cfg, pos, block_tables, active, picked, probe):
 def _state_tick_seam(cfg, pos, block_tables, active, picked, probe):
     """A decode tick's (write, read) for such blocks: the recurrence's
     one step on every slot's state IN PLACE (idle slots keep their bits), one
-    new K / V row a live slot."""
+    new K / V row a live slot, in its page or in row ``pos % R`` of its ring."""
+    from ..ops import gated_attention as ga
     from .paged import paged_attention_decode, paged_attention_packed_ctx, write_decode_kv
 
     s = cfg.latent
@@ -614,10 +701,17 @@ def _state_tick_seam(cfg, pos, block_tables, active, picked, probe):
             _, step = lm.RECURRENCES[kind]
             y, ssm, conv = step(w, h, active, conv, ssm, s.recurrence[1], cfg.norm_eps, probe)
             return ssm, conv, y
+        if kind == "wattn":
+            return tuple(ga.ring_write_rows(a, r, pos, active) for a, r in zip(arrays, rows))
         return tuple(write_decode_kv(a, r, block_tables, pos, active)
                      for a, r in zip(arrays, rows))
 
     def read(kind, pools, qkv):
+        if kind == "wattn":  # a row is a group of one query, its slot's own
+            b, bs = pos.shape[0], pools[0].shape[1]
+            return ga.ring_attention(qkv[0][:, None], pos[:, None], *pools, jnp.arange(b),
+                                     pos // bs, pools[0].shape[0] // b, s.wattn.window,
+                                     probe)[:, 0]
         if qkv[0].shape[-1] > 128:
             # a head wider than one 128-lane tile: the decode kernel's view of a
             # page as (key, kv head) rows is then not the pool's own bytes, and XLA
@@ -652,14 +746,14 @@ class LatentRunner:
 
     counters = COUNTERS
     packs_are_one_program = True  # a pack reads its own rows back from the cache
-    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn
+    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn; full_attn
 
     def __init__(self, cfg):
         self.cfg = cfg
         self._ring_rows = np.zeros(0, np.int64)
         self._block = 1
         if cfg.latent.stateful:
-            self.counters = STATE_COUNTERS
+            self.counters = WINDOW_COUNTERS if cfg.latent.ringed else STATE_COUNTERS
             self._discarded = 0  # states a preemption left behind since the last dispatch
 
     def init_cache(self, num_blocks, block_size, max_seqs, pack_tokens) -> Cache:
@@ -704,6 +798,8 @@ class LatentRunner:
         and no group (nor is a pack's entry of one token, which the program
         cannot tell apart here)."""
         s = self.cfg.latent
+        if s.count("wattn"):
+            return self._windows_dispatched(counters, work)
         if s.stateful:
             return self._states_dispatched(counters, work, pack)
         topk, win, bs = s.index_topk, s.sliding.window, self._block
@@ -730,6 +826,29 @@ class LatentRunner:
             counters[k].inc(out.get(k, 0))
         return out
 
+    def _windows_dispatched(self, counters, work) -> Dict[str, int]:
+        """Gated GQA of two kinds: a query at position ``p`` attends ``p + 1``
+        keys in each layer over every key and ``min(p + 1, window)`` in each
+        layer over a window; ``causal_keys`` is what the window layers would
+        attend if they were full (what the rings save is the difference)."""
+        s = self.cfg.latent
+        win, n_full, n_win = s.wattn.window, s.count("gattn"), s.count("wattn")
+        causal = windowed = dropped = under = 0
+        for slot, a, b in work:
+            causal += (b * (b + 1) - a * (a + 1)) // 2  # sum of p + 1
+            m = min(max(a, win), b)  # from position m on, ``win`` of p + 1 keys
+            windowed += (m * (m + 1) - a * (a + 1)) // 2 + (b - m) * win
+            dropped += max(b - max(a, win), 0)  # position p overwrites p - win
+            under += min(a, win - 1)  # ring rows under the first query's window
+            self._ring_rows[slot] = b
+        out = {"full_keys": causal * n_full, "window_keys": windowed * n_win,
+               "window_ctx": under * n_win}
+        counters["full_keys_attended"].inc(out["full_keys"])
+        counters["window_keys_attended"].inc(out["window_keys"])
+        counters["causal_keys"].inc(causal * n_win)
+        counters["window_rows_discarded"].inc(dropped * n_win)
+        return out
+
     def _states_dispatched(self, counters, work, pack: bool) -> Dict[str, int]:
         """A recurrence's blocks (state-space or delta rule): a pack's entry is
         ``ceil((end - start) / page)`` chunks a block, from a zero state if it
@@ -753,7 +872,8 @@ class LatentRunner:
         return {"ssm_live_slots": steps}
 
     def released(self, seq) -> None:
-        if self.cfg.latent.stateful and seq.preempted and self._ring_rows[seq.slot]:
+        s = self.cfg.latent
+        if s.stateful and not s.ringed and seq.preempted and self._ring_rows[seq.slot]:
             self._discarded += 1
         self._ring_rows[seq.slot] = 0
 
@@ -761,7 +881,8 @@ class LatentRunner:
         """State still owned by a sequence: rows of window state (a ring is
         nobody's once its slot is released), or slots whose recurrence's state
         is a live sequence's."""
-        if self.cfg.latent.stateful:
+        s = self.cfg.latent
+        if s.stateful and not s.ringed:
             return {"ssm_states": int(np.count_nonzero(self._ring_rows))}
         return {"window_rows": int(self._ring_rows.sum())}
 

@@ -25,13 +25,20 @@ kind; ``block_params`` picks block ``l``'s.
 
 The families meet in a third (``HYBRID``): blocks of TWO norms whose mixer is a
 recurrence with a matrix state (``gdn``: Gated DeltaNet, ``ops/gdn.py``) or
-grouped-query attention with a wide head, q / k norms, rotary positions on part
-of the head and an output gate (``gattn``), and whose feed-forward is the expert
-layer in EVERY block, routed by a softmax over all experts (``routing``) with
-the shared expert behind a gate of its own (``shared_gate``); the norms'
-weights are zero-centred, ``x^ (1 + w)`` (``unit_offset``).  Trees:
-``layers/attn_norm``, ``layers/mlp_norm`` (stacked, float32) and one tuple per
-kind (``gdn``, ``gattn``, ``moe``); ``hybrid_params`` picks block ``l``'s.
+gated grouped-query attention (``GatedGqa``: q / k norms, rotary positions on
+part of the head, a sigmoid output gate) of up to TWO kinds in one model, each
+with its own head count and rotary table: ``gattn`` over every key (K / V
+pages) and ``wattn`` over the last ``window`` keys (a K / V ring a slot).  What
+differs between the models of this family is VALUES of the spec: the gate one
+value a channel or a head (``GatedGqa.gate``), YaRN on the rotary table
+(``GatedGqa.rope_scaling``), the feed-forward the expert layer in every block
+or a dense SwiGLU on the ``first_dense`` leading ones, routed by a softmax over
+all experts or by sigmoid scores with a selection bias and ``routed_scale``
+(``routing``), the shared expert behind a gate of its own or not
+(``shared_gate``), the norms' weights zero-centred, ``x^ (1 + w)``, or plain
+(``unit_offset``).  Trees: ``layers/attn_norm``, ``layers/mlp_norm`` (stacked)
+and one tuple per kind (``gdn``, ``gattn``, ``wattn``, ``moe``, and ``mlp`` where
+``first_dense`` > 0); ``hybrid_params`` picks block ``l``'s.
 """
 from __future__ import annotations
 
@@ -111,7 +118,8 @@ class Gqa:
     head_dim: int
 
 
-HYBRID = ("gdn", "gattn")  # mixers of a two-norm block whose feed-forward is the expert layer
+# mixers of a two-norm block: a recurrence, gated attention over every key, ... over a window
+HYBRID = ("gdn", "gattn", "wattn")
 
 
 @dataclass(frozen=True)
@@ -146,16 +154,34 @@ class Gdn:
 
 
 @dataclass(frozen=True)
+class Yarn:
+    """YaRN's scaling of a rotary table: frequencies that turn fewer than
+    ``beta_slow`` times over ``original_max`` positions are divided by
+    ``factor``, those that turn more than ``beta_fast`` times are left, a
+    linear ramp between; cos and sin times ``attention_factor``."""
+
+    factor: float
+    original_max: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+@dataclass(frozen=True)
 class GatedGqa:
     """Grouped-query attention with RMSNorm on each head's q and k, rotary
     positions (rotate-half) on the first ``rope_dim`` of ``head_dim`` and a
-    sigmoid gate on the output, projected beside q."""
+    sigmoid gate on the output: one value a CHANNEL, projected beside q, or one
+    a HEAD, from a projection of its own (``w_g``)."""
 
     num_heads: int
     num_kv_heads: int
     head_dim: int
     rope_dim: int
     rope_theta: float
+    window: int = 0              # 0: every key; n: the last n positions, its own included
+    gate: str = "channel"        # 'channel' | 'head'
+    rope_scaling: Optional[Yarn] = None
 
 
 @dataclass(frozen=True)
@@ -185,6 +211,7 @@ class LatentSpec:
     routing: str = "sigmoid"     # 'sigmoid': + bias, the picked normalised | 'softmax': over all, the picked renormalised
     shared_gate: bool = False    # the shared expert's output times sigmoid(x . w_sg)
     unit_offset: bool = False    # RMSNorm's weights are zero-centred: x^ (1 + w)
+    wattn: Optional[GatedGqa] = None  # a second kind of gated attention, over a window
 
     @property
     def single(self) -> bool:
@@ -211,13 +238,24 @@ class LatentSpec:
 
     @property
     def recurrence(self):
-        """(kind, mixer) of a ``stateful`` model's recurrence."""
+        """(kind, mixer) of a ``stateful`` model's recurrence (the mixer None
+        for a model of attention alone)."""
         return ("mamba", self.mamba) if self.single else ("gdn", self.gdn)
+
+    def mixer(self, kind: str):
+        """The spec of a ``stateful`` model's mixer of ``kind`` (SINGLE's, HYBRID's)."""
+        return getattr(self, kind)
 
     @property
     def attention(self):
-        """(kind, mixer) of a ``stateful`` model's attention over K / V pages."""
-        return ("gqa", self.gqa) if self.single else ("gattn", self.gattn)
+        """(kind, mixer) of a ``stateful`` model's attention over K / V PAGES."""
+        kind = "gqa" if self.single else "gattn"
+        return kind, self.mixer(kind)
+
+    @property
+    def ringed(self) -> bool:
+        """Some layer keeps a ring of its window's rows per slot."""
+        return any(k in ("sliding", "wattn") for k in self.layer_kinds)
 
     def attn(self, kind: str) -> LatentAttn:
         return self.full if kind == "full" else self.sliding
@@ -281,10 +319,15 @@ def _hybrid_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
         return {"w_qkvz": (d, gd.conv_width + gd.d_in), "w_ba": (d, 2 * gd.num_v_heads),
                 "conv_w": (gd.conv, gd.conv_width), "dt_bias": (gd.num_v_heads,),
                 "a_log": (gd.num_v_heads,), "norm": (gd.v_dim,), "w_out": (gd.d_in, d)}
-    ga = s.gattn
-    return {"wq": (d, 2 * ga.num_heads * ga.head_dim), "wk": (d, ga.num_kv_heads * ga.head_dim),
-            "wv": (d, ga.num_kv_heads * ga.head_dim), "q_norm": (ga.head_dim,),
-            "k_norm": (ga.head_dim,), "wo": (ga.num_heads * ga.head_dim, d)}
+    ga = s.mixer(kind)
+    per_channel = ga.gate == "channel"  # each head's projection is then [q | gate]
+    out = {"wq": (d, (1 + per_channel) * ga.num_heads * ga.head_dim),
+           "wk": (d, ga.num_kv_heads * ga.head_dim),
+           "wv": (d, ga.num_kv_heads * ga.head_dim), "q_norm": (ga.head_dim,),
+           "k_norm": (ga.head_dim,), "wo": (ga.num_heads * ga.head_dim, d)}
+    if not per_channel:
+        out["w_g"] = (d, ga.num_heads)
+    return out
 
 
 def param_count(cfg) -> int:
@@ -296,8 +339,10 @@ def param_count(cfg) -> int:
     if s.single:
         return n + sum(d + size(_single_shapes(d, s, kind)) for kind in s.layer_kinds)
     if s.hybrid:
-        return n + sum(2 * d + size(_hybrid_shapes(d, s, kind))
-                       + size(_single_shapes(d, s, "experts")) for kind in s.layer_kinds)
+        ffn_of = lambda l: 3 * d * cfg.intermediate_size if l < s.first_dense \
+            else size(_single_shapes(d, s, "experts"))
+        return n + sum(2 * d + size(_hybrid_shapes(d, s, kind)) + ffn_of(l)
+                       for l, kind in enumerate(s.layer_kinds))
     for l, kind in enumerate(s.layer_kinds):
         a = s.attn(kind)
         n += 2 * d + size(_attn_shapes(d, a)) + a.q_rank + a.kv_rank
@@ -344,14 +389,19 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
                      norm=jnp.ones((s.mamba.d_in,), dtype))
         if kind == "gdn":
             w["norm"] = jnp.ones((s.gdn.v_dim,), jnp.float32)
-        if kind == "gattn":
-            w.update(q_norm=centred((s.gattn.head_dim,)), k_norm=centred((s.gattn.head_dim,)))
+        if kind in ("gattn", "wattn"):
+            hd = s.mixer(kind).head_dim
+            w.update(q_norm=norm_weight((hd,)), k_norm=norm_weight((hd,)))
         return w
 
     def centred(shape):
         """A zero-centred norm's weight (``x^ (1 + w)``), float32: N(0, 0.1^2),
         so that the offset is not the whole of it."""
         return 0.1 * jax.random.normal(next(keys), shape, jnp.float32)
+
+    def norm_weight(shape):
+        """A two-norm block's norm by the spec's ``unit_offset``: plain weights are 1."""
+        return centred(shape) if s.unit_offset else jnp.ones(shape, dtype)
 
     head = lambda layers, norm: {
         "embed": {"embedding": dense((cfg.vocab_size, d), d)},
@@ -365,11 +415,17 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
             layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
         return head(layers, jnp.ones((d,), dtype))
     if s.hybrid:
-        layers = {"attn_norm": {"scale": centred((L, d))}, "mlp_norm": {"scale": centred((L, d))},
-                  "moe": tuple(single("experts") for _ in range(L))}
+        layers = {"attn_norm": {"scale": norm_weight((L, d))},
+                  "mlp_norm": {"scale": norm_weight((L, d))},
+                  "moe": tuple(single("experts") for _ in range(L - s.first_dense))}
         for kind in HYBRID:
             layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
-        return head(layers, centred((d,)))
+        if s.first_dense:
+            f = cfg.intermediate_size
+            layers["mlp"] = tuple(
+                {"w_gate": dense((d, f), d), "w_up": dense((d, f), d), "w_down": dense((f, d), f)}
+                for _ in range(s.first_dense))
+        return head(layers, norm_weight((d,)))
     if any(k in SINGLE + HYBRID for k in s.layer_kinds):
         raise ValueError("single-mixer blocks, two-norm blocks of a recurrence or gated "
                          "attention, and latent-attention layers are three families: a "
@@ -434,11 +490,14 @@ def block_params(layers: Params, l: int, s: LatentSpec):
 
 
 def hybrid_params(layers: Params, l: int, s: LatentSpec):
-    """(kind, the two norms' weights, the mixer's weights, the expert layer's)
-    of two-norm block ``l`` (``HYBRID``)."""
+    """(kind, the two norms' weights, the mixer's weights, the feed-forward's,
+    whether that is the expert layer) of two-norm block ``l`` (``HYBRID``)."""
     kind = s.layer_kinds[l]
     norms = (layers["attn_norm"]["scale"][l], layers["mlp_norm"]["scale"][l])
-    return kind, norms, layers[kind][s.layer_kinds[:l].count(kind)], layers["moe"][l]
+    mw = layers[kind][s.layer_kinds[:l].count(kind)]
+    if l < s.first_dense:
+        return kind, norms, mw, layers["mlp"][l], False
+    return kind, norms, mw, layers["moe"][l - s.first_dense], True
 
 
 # ---------------------------------------------------------------------------
@@ -462,12 +521,28 @@ def norm(x, scale, cfg):
     return (rms_centred if cfg.latent.unit_offset else rms)(x, scale, cfg.norm_eps)
 
 
-def _rope(x, pos, theta: float):
+def yarn_ramp(r: int, theta: float, y: Yarn) -> np.ndarray:
+    """The share of each of a rotary table's ``r / 2`` frequencies that YaRN
+    divides by ``factor`` (0: left as it is, 1: wholly): a linear ramp from
+    the dim that turns ``beta_fast`` times over ``original_max`` positions to
+    the one that turns ``beta_slow`` times."""
+    dim_of = lambda turns: r * np.log(y.original_max / (turns * 2 * np.pi)) / (2 * np.log(theta))
+    lo = max(np.floor(dim_of(y.beta_fast)), 0)
+    hi = min(np.ceil(dim_of(y.beta_slow)), r - 1)
+    return np.clip((np.arange(r // 2) - lo) / max(hi - lo, 1e-3), 0.0, 1.0).astype(np.float32)
+
+
+def _rope(x, pos, theta: float, scaling: Optional[Yarn] = None):
     """x [T, h, r] rotated in the half-split layout at ``pos`` [T]."""
     r = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    if scaling is not None:
+        ramp = yarn_ramp(r, theta, scaling)
+        inv = inv / scaling.factor * ramp + inv * (1.0 - ramp)
     ang = pos.astype(jnp.float32)[:, None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        cos, sin = cos * scaling.attention_factor, sin * scaling.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
 
@@ -693,25 +768,41 @@ def _gdn_output(gw, o, z, gd: Gdn, eps: float, dtype):
     return y.astype(dtype) @ gw["w_out"]
 
 
-def gattn_inputs(aw, h, pos, ga: GatedGqa, eps: float):
+def gattn_inputs(aw, h, pos, ga: GatedGqa, eps: float, unit_offset: bool = True):
     """h [T, d] at positions ``pos`` [T] -> (q [T, Hq, hd], k, v [T, Hkv, hd],
-    the output gate [T, Hq * hd] float32): each query head's projection is [q |
-    gate]; q and k normed over the head (zero-centred weights) and rotated on
-    their first ``rope_dim`` dims.  The barrier as in ``gqa_inputs``."""
+    the output gate float32: [T, Hq * hd] where each query head's projection is
+    [q | gate], [T, Hq, 1] where the gate is one value a head, ``h @ w_g``); q
+    and k normed over the head (``unit_offset``: zero-centred weights) and
+    rotated on their first ``rope_dim`` dims.  The barrier as in ``gqa_inputs``."""
     t = h.shape[0]
     qg, k, v = jax.lax.optimization_barrier((h @ aw["wq"], h @ aw["wk"], h @ aw["wv"]))
-    q, gate = jnp.split(qg.reshape(t, ga.num_heads, 2 * ga.head_dim), 2, axis=-1)
+    per_channel = ga.gate == "channel"
+    if per_channel:
+        q, gate = jnp.split(qg.reshape(t, ga.num_heads, 2 * ga.head_dim), 2, axis=-1)
+    else:
+        q, gate = qg.reshape(t, ga.num_heads, ga.head_dim), (h @ aw["w_g"])[..., None]
     heads = lambda a: a.reshape(t, ga.num_kv_heads, ga.head_dim)
-    rot = lambda a: jnp.concatenate(
-        [_rope(a[..., :ga.rope_dim], pos, ga.rope_theta), a[..., ga.rope_dim:]], axis=-1)
-    q = rot(rms_centred(q, aw["q_norm"], eps))
-    k = rot(rms_centred(heads(k), aw["k_norm"], eps))
-    return q, k, heads(v), jax.nn.sigmoid(gate.reshape(t, -1).astype(jnp.float32))
+    if ga.rope_dim == ga.head_dim:
+        rot = lambda a: _rope(a, pos, ga.rope_theta, ga.rope_scaling)
+    else:
+        rot = lambda a: jnp.concatenate(
+            [_rope(a[..., :ga.rope_dim], pos, ga.rope_theta, ga.rope_scaling),
+             a[..., ga.rope_dim:]], axis=-1)
+    normed = rms_centred if unit_offset else rms
+    q = rot(normed(q, aw["q_norm"], eps))
+    k, v = rot(normed(heads(k), aw["k_norm"], eps)), heads(v)
+    if per_channel:
+        gate = gate.reshape(t, -1)
+    return q, k, v, jax.nn.sigmoid(gate.astype(jnp.float32))
 
 
 def gattn_output(aw, o, gate):
-    """Attention's output o [T, Hq, hd] times the gate, through ``W_o``."""
-    y = o.reshape(o.shape[0], -1).astype(jnp.float32) * gate
+    """Attention's output o [T, Hq, hd] times the gate (a channel's [T, Hq *
+    hd] or a head's [T, Hq, 1]), through ``W_o``."""
+    if gate.ndim == 3:
+        y = (o.astype(jnp.float32) * gate).reshape(o.shape[0], -1)
+    else:
+        y = o.reshape(o.shape[0], -1).astype(jnp.float32) * gate
     return y.astype(o.dtype) @ aw["wo"]
 
 
@@ -790,14 +881,16 @@ def _single_blocks(layers: Params, x, b: int, n: int, cfg):
     return x
 
 
-def _causal_gqa(q, k, v, b: int, n: int):
+def _causal_gqa(q, k, v, b: int, n: int, window: int = 0):
     """Dense causal attention of ``b`` sequences of ``n`` rows each: q [b * n,
-    Hq, hd], k and v [b * n, Hkv, hd] -> [b * n, Hq, hd]."""
+    Hq, hd], k and v [b * n, Hkv, hd] -> [b * n, Hq, hd]; with ``window``, over
+    the last ``window`` keys, a row's own included."""
     hd, rep = q.shape[-1], q.shape[1] // k.shape[1]
     q, k, v = (a.reshape(b, n, *a.shape[1:]) for a in (q, k, v))
     sc = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, n, -1, rep, hd), k
                     ).astype(jnp.float32) * hd ** -0.5
-    sc = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :], sc, -jnp.inf)
+    back = jnp.arange(n)[:, None] - jnp.arange(n)[None, :]
+    sc = jnp.where((back >= 0) & (back < window) if window else back >= 0, sc, -jnp.inf)
     o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(sc, -1).astype(v.dtype), v)
     return o.reshape(b * n, -1, hd)
 
@@ -807,16 +900,17 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
     s_, eps = cfg.latent, cfg.norm_eps
     pos = jnp.tile(jnp.arange(n), b)
     for l in range(cfg.num_layers):
-        kind, (n1, n2), mw, fw = hybrid_params(layers, l, s_)
-        h = rms_centred(x, n1, eps)
+        kind, (n1, n2), mw, fw, is_moe = hybrid_params(layers, l, s_)
+        h = norm(x, n1, cfg)
         if kind == "gdn":
             y = _chunked_uncached(gdn_chunks, mw, h.reshape(b, n, -1), s_.gdn, eps)
             y = y.reshape(b * n, -1)
         else:
-            q, k, v, gate = gattn_inputs(mw, h, pos, s_.gattn, eps)
-            y = gattn_output(mw, _causal_gqa(q, k, v, b, n), gate)
+            ga = s_.mixer(kind)
+            q, k, v, gate = gattn_inputs(mw, h, pos, ga, eps, s_.unit_offset)
+            y = gattn_output(mw, _causal_gqa(q, k, v, b, n, ga.window), gate)
         x = x + y.astype(x.dtype)
-        x = x + ffn(fw, rms_centred(x, n2, eps), True, cfg)[0].astype(x.dtype)
+        x = x + ffn(fw, norm(x, n2, cfg), is_moe, cfg)[0].astype(x.dtype)
     return x
 
 
