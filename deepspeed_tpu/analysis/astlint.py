@@ -55,6 +55,11 @@ HOT_PATHS: Dict[str, Set[str]] = {
         # is the whole design — any other sync inside it would re-pay the
         # host round trip the burst exists to amortize
         "_decode_burst",
+        # one ahead (PR 43): the split bodies under ``_run_packed_prefill`` /
+        # ``_decode_tick``.  The dispatch halves may not sync at all when
+        # split; the ONE designed fetch of a program is ``_fetched``'s
+        "pack_dispatch", "pack_collect", "decode_dispatch", "decode_collect",
+        "prefill_dispatch", "_packs_of", "_fetched",
         # the KV-handoff seam (PR 12): np.asarray is the designed host
         # copy; any OTHER sync primitive mid-migration stalls the tick
         "extract_kv_blocks", "inject_kv_blocks",
@@ -73,6 +78,11 @@ HOT_PATHS: Dict[str, Set[str]] = {
         # fetch happens inside the engine's _decode_burst, nowhere else
         "_plan_megastep", "_remaining_emit", "_decode_phase",
         "_dispatch_decode",
+        # one ahead (PR 43): planning and enqueueing the next execution is
+        # what the device's time is hidden behind; the wait is the engine's
+        "_tick_ahead", "_enqueue", "_collect", "_drain", "_drain_outside",
+        "_back_to_back", "_plan_prefill", "_book_first", "_book_runs",
+        "_due_locked", "_ends_enqueued", "settle",
     },
     # the router front end's control loop + its load-signal reads: router
     # instrumentation must never add a device round trip to a worker's tick

@@ -89,12 +89,15 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
     lens = jnp.ones(B, jnp.int32)
     bt = jnp.zeros((B, eng.max_pages), jnp.int32)
     act = jnp.ones(B, bool)
-    # a tick's ONE upload: tokens, KV positions, 0 / 1 for a live slot
-    rows = jnp.stack([toks, lens, act.astype(jnp.int32)])
+    # a tick's ONE upload: tokens, KV positions, 0 / 1 for a live slot,
+    # 0 / 1 for an input token read from the chain (the newest token a slot
+    # sampled, carried from program to program like the key)
+    rows = jnp.stack([toks, lens, act.astype(jnp.int32), jnp.zeros(B, jnp.int32)])
+    chain = jnp.zeros(B, jnp.int32)
     specs["decode"] = dict(
         jit=eng._decode_jit,
-        args=(eng.params, rows, bt, eng.kv, key, tr),
-        donated={"kv": 3}, static=(5,),
+        args=(eng.params, rows, bt, eng.kv, key, chain, tr),
+        donated={"kv": 3}, static=(6,),
         n_tokens=B, sample_rows=B,
     )
 
@@ -123,8 +126,8 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
     specs["prefill_packed"] = dict(
         jit=eng._packed_prefill_jit,
         args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, False)[0]),
-              eng.kv, key, tr),
-        donated={"kv": 2}, static=(4,),
+              eng.kv, key, chain, tr),
+        donated={"kv": 2}, static=(5,),
         n_tokens=t_pad, sample_rows=B,
         # cold pack: dense attention only, never reads the paged pool — no
         # seq-shard ring in this dispatch
@@ -134,8 +137,8 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
     specs["prefill_packed_ctx"] = dict(
         jit=eng._packed_prefill_ctx_jit,
         args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, True)[0]),
-              eng.kv, key, tr),
-        donated={"kv": 2}, static=(4,),
+              eng.kv, key, chain, tr),
+        donated={"kv": 2}, static=(5,),
         n_tokens=t_pad, sample_rows=B,
     )
 

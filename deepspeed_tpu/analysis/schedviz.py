@@ -552,6 +552,7 @@ class _StubSeq:
         self.spec_drafted = 0
         self.spec_accepted = 0
         self.error: Optional[str] = None
+        self.pending = 0  # this double's programs are fetched where they run
 
     @property
     def cur_len(self) -> int:

@@ -87,6 +87,23 @@ def new_pack(t_pad: int, block_size: int, max_seqs: int, max_pages: int,
     return buf, views
 
 
+class Enqueued:
+    """One program of a tick, enqueued and not collected yet: what its
+    collect needs.  ``rows``: a pack's [(seq, start, end)] or a step's
+    sequences; ``finishing``: the sequences whose prompt a pack completes;
+    ``sampled``: the device's ``[slots]`` tokens while nobody has fetched
+    them (a split dispatch), ``tokens`` once somebody has; ``split``: the
+    fetch is not inside the dispatch span."""
+
+    __slots__ = ("span", "rows", "finishing", "split", "sampled", "tokens")
+
+    def __init__(self, span: str, rows: List, finishing: Optional[List],
+                 split: bool):
+        self.span, self.rows, self.finishing = span, rows, finishing
+        self.split = split
+        self.sampled = self.tokens = None
+
+
 class InferenceEngineV2:
     """Paged-KV continuous-batching engine for one model replica."""
 
@@ -445,6 +462,14 @@ class InferenceEngineV2:
             "shed_transitions",  # shed-mode flips (both directions)
             "shed_rejections",  # try_submit calls rejected RETRY_LATER
             "watchdog_trips",  # tick-duration watchdog firings
+            # a tick dispatched one ahead (the dispatch bodies count the
+            # first, the paired scheduler the second, the collects the third):
+            "dispatched_ahead",  # programs enqueued while the execution
+            # before them was not yet fetched
+            "ahead_drains",  # ticks that collected first and ran in the
+            # back-to-back order (the reason: the ``sched.drain`` span's)
+            "ahead_rows_dropped",  # rows whose result was thrown away: their
+            # request ended (a stop token, a failure) while they were enqueued
         ))
         self.stats = StatsView(self._c)
         reg = self.telemetry.registry
@@ -494,6 +519,9 @@ class InferenceEngineV2:
         # The key LIVES ON THE DEVICE: every program takes it, splits it inside
         # and hands the carried key back; the host only keeps the reference.
         self._rng = jax.random.PRNGKey(seed)
+        # ...and so does the chain (``packed_impl`` below): the newest token
+        # of every slot, handed from program to program
+        self._chain = jnp.zeros((max_seqs,), jnp.int32)
         self._burst_cap = 64  # step_n accumulator rows (doubles on demand)
         # host-side block-table mirror: rows update as pure numpy writes and
         # upload ONCE per tick — per-sequence device .at[].set calls cost one
@@ -538,26 +566,34 @@ class InferenceEngineV2:
 
         # only the device-relevant sampling triple is static — hashing the
         # whole SamplingParams would recompile on max_new_tokens/stop_token
-        def packed_impl(params, pack, kv, rng, sampling_triple):
+        # THE CHAIN: every program of a tick hands on one int32 ``[slots]``
+        # array, the newest token each slot sampled (a pack writes the slots
+        # whose prompt it completed, a step its live slots, every other slot
+        # keeps what it held).  It IS the result the host fetches, and the
+        # next step reads a slot's input token from it wherever the host has
+        # not seen that token yet (a tick dispatched one ahead): like the
+        # key, it goes from program to program and is uploaded by nobody.
+        def packed_impl(params, pack, kv, rng, chain, sampling_triple):
             """A cold pack: ``pack`` is the ONE int32 buffer of
             ``new_pack(..., ctx=False)``."""
+            views = unpack_pack(pack, bs_, B_, mp_, False)
             logits, kv = runner.prefill_packed(
-                params, cfg_, *unpack_pack(pack, bs_, B_, mp_, False), kv,
-                ctx=ctx_, mesh=mesh_,
+                params, cfg_, *views, kv, ctx=ctx_, mesh=mesh_,
             )
             sampled, rng = sampled_pack(logits, rng, sampling_triple)
-            return sampled, kv, rng
+            return jnp.where(views[4] >= 0, sampled, chain), kv, rng
 
-        def packed_ctx_impl(params, pack, kv, rng, sampling_triple):
+        def packed_ctx_impl(params, pack, kv, rng, chain, sampling_triple):
             """Context-aware variant: suffix tokens attend over each
             sequence's cached KV pages (prefix-cache hits, chunked-prefill
             continuation chunks).  Cold packs stay on ``packed_impl``."""
+            views = unpack_pack(pack, bs_, B_, mp_, True)
             logits, kv = runner.prefill_packed_ctx(
-                params, cfg_, *unpack_pack(pack, bs_, B_, mp_, True), kv,
+                params, cfg_, *views, kv,
                 ctx=ctx_, mesh=mesh_, dp=dp_, seq_shards=sq_,
             )
             sampled, rng = sampled_pack(logits, rng, sampling_triple)
-            return sampled, kv, rng
+            return jnp.where(views[4] >= 0, sampled, chain), kv, rng
 
         def cow_impl(kv, src, dst):
             """Copy-on-write page clone: dst pages get src's contents in
@@ -582,15 +618,23 @@ class InferenceEngineV2:
             )
             return sampled, rng, kv
 
-        def decode_impl(params, rows, block_tables, kv, rng, sampling_triple):
+        def decode_impl(params, rows, block_tables, kv, rng, chain,
+                        sampling_triple):
             """One decode tick.  ``rows`` is the tick's ONE upload, int32
-            ``[3, max_seqs]``: the slots' input tokens, their KV positions
-            and 0 / 1 for a live slot; the key arrives from the last
-            dispatch's results and is carried on to the next."""
+            ``[4, max_seqs]``: the slots' input tokens, their KV positions,
+            0 / 1 for a live slot, and 0 / 1 for a slot whose input token is
+            the chain's (the host enqueued this step before it fetched the
+            program that sampled it); the key and the chain arrive from the
+            last dispatch's results and are carried on to the next.  A
+            ``-1`` read from the chain (the finite guard's sentinel) is
+            clamped: that row's request has failed and its result is thrown
+            away at collect."""
+            tokens = jnp.where(rows[3] != 0, jnp.maximum(chain, 0), rows[0])
+            active = rows[2] != 0
             sampled, rng, kv = decode_sample(
-                params, rows[0], rows[1], block_tables, rows[2] != 0, kv, rng,
+                params, tokens, rows[1], block_tables, active, kv, rng,
                 sampling_triple)
-            return sampled, rng, kv
+            return jnp.where(active, sampled, chain), rng, kv
 
         def decode_burst_impl(params, tokens, seq_lens, block_tables, active,
                               kv, rng, burst, tick, emitted, stop_rows,
@@ -674,16 +718,17 @@ class InferenceEngineV2:
         # jax.jit options, the KV pool's place among n results, and among the
         # arguments after ``params``: None for a program that takes no
         # weights).  stop_rows / max_emit of the burst are NOT donated: the
-        # same device arrays feed every tick.  The key is donated by none:
-        # a dispatch that raises must leave ``self._rng`` alive.
+        # same device arrays feed every tick.  The key and the chain are
+        # donated by none: a dispatch that raises must leave them alive, and
+        # the chain is also what the host fetches.
         table = (
             ("_packed_prefill_jit", packed_impl,
-             dict(donate_argnums=(2,), static_argnums=(4,)), 1, 3, 1),
+             dict(donate_argnums=(2,), static_argnums=(5,)), 1, 3, 1),
             ("_packed_prefill_ctx_jit", packed_ctx_impl,
-             dict(donate_argnums=(2,), static_argnums=(4,)), 1, 3, 1),
+             dict(donate_argnums=(2,), static_argnums=(5,)), 1, 3, 1),
             ("_cow_jit", cow_impl, dict(donate_argnums=(0,)), 0, 1, None),
             ("_decode_jit", decode_impl,
-             dict(donate_argnums=(3,), static_argnums=(5,)), 2, 3, 2),
+             dict(donate_argnums=(3,), static_argnums=(6,)), 2, 3, 2),
             ("_decode_burst_jit", decode_burst_impl,
              dict(donate_argnums=(2, 4, 5, 7, 8, 9), static_argnums=(12,)),
              3, 8, 4),
@@ -706,6 +751,7 @@ class InferenceEngineV2:
             # twice (its first call uncommitted, every later one not)
             self._rep_sharding = rep
             self._rng = jax.device_put(self._rng, rep)
+            self._chain = jax.device_put(self._chain, rep)
         for name, impl, opts, kv_out, n_out, kv_rest_idx in table:
             if self._mesh is not None:
                 outs = (rep,) * kv_out + (self._kv_shardings,) \
@@ -951,6 +997,20 @@ class InferenceEngineV2:
         appears at most once per call, so deferral cannot reorder a
         sequence's own chunks)."""
         out: Dict[int, int] = {}
+        for pack in self._packs_of(entries):
+            self._run_packed_prefill(pack, sampling, out)
+        return out
+
+    def prefill_dispatch(self, entries, sampling: SamplingParams,
+                         ahead: bool = False) -> List["Enqueued"]:
+        """``prefill_entries``' packs ENQUEUED and nothing fetched: one
+        handle a pack, for ``pack_collect``, in dispatch order."""
+        return [self.pack_dispatch(pack, sampling, split=True, ahead=ahead)
+                for pack in self._packs_of(entries)]
+
+    def _packs_of(self, entries):
+        """``entries`` cut into packs under ``prefill_budget`` (a generator:
+        a pack is laid out once the one before it has been handed on)."""
         bs = self.block_size
         dp = self.serve_replicas
         per_budget = self.mgr.per_replica_token_budget(self.prefill_budget)
@@ -969,18 +1029,29 @@ class InferenceEngineV2:
                 n = -(-(end - start) // bs) * bs
                 r = self.mgr.replica_of(seq) if dp > 1 else 0
                 # an oversized single entry (> per_budget) rides an empty
-                # chunk — _run_packed_prefill buckets the pack up to fit
+                # chunk — pack_dispatch buckets the pack up to fit
                 if pack_len[r] and pack_len[r] + n > per_budget:
                     deferred.append(entry)
                     continue
                 pack.append(entry)
                 pack_len[r] += n
-            self._run_packed_prefill(pack, sampling, out)
+            yield pack
             pending = deferred
-        return out
 
     def _run_packed_prefill(self, entries, sampling, out: Dict[int, int]) -> None:
-        """One packed-prefill dispatch for ``entries`` = [(seq, start, end)].
+        """One packed-prefill dispatch for ``entries`` = [(seq, start, end)],
+        fetched and emitted at once: ``pack_dispatch`` and ``pack_collect``
+        back to back."""
+        self.pack_collect(self.pack_dispatch(entries, sampling), out)
+
+    def pack_dispatch(self, entries, sampling, split: bool = False,
+                      ahead: bool = False) -> "Enqueued":
+        """Build, upload and ENQUEUE one packed prefill for ``entries`` =
+        [(seq, start, end)]; ``pack_collect`` fetches and emits it.  With
+        ``split`` the span closes at the enqueue and the fetch is the
+        collect's (``ahead``: the execution before this one is not fetched
+        yet; the span says so); without, the fetch lies inside the span as it
+        always did.
 
         Each suffix starts at a PAGE boundary of the pack buffer (segment-0
         gap padding between prompts): KV then writes as one page-granular
@@ -1053,34 +1124,90 @@ class InferenceEngineV2:
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
             tokens=n_real, ctx_pages=ctx_pages,
-            uids=[s.uid for s, _, _ in entries], **extra,
+            uids=[s.uid for s, _, _ in entries], ahead=int(ahead), **extra,
         ) as sp:
             args = (self.params, self._upload(pack, held=False), self.kv,
-                    self._rng, triple)
+                    self._rng, self._chain, triple)
             sp.mark("upload")  # the one argument handed over; next: enqueue
             name = "_packed_prefill_ctx_jit" if use_ctx else "_packed_prefill_jit"
             sampled, self.kv, self._rng = getattr(self, name)(*args)
+            self._chain = sampled
             if name in self._tracked:
                 self._tracked[name].note(args)
             sp.dispatched()
             self._c["prefill_tokens_dispatched"].inc(n_real)
             self._c["prefill_dispatches"].inc()
+            self._c["dispatched_ahead"].inc(int(ahead))
             self._account_comm(t_pad, sample_rows=n_slots, ring=use_ctx)
-            if finishing:
+            done = Enqueued("prefill_pack", list(entries), finishing, split)
+            if finishing and not split:
                 # host-complete: this fetch syncs the pack
-                next_tokens = np.asarray(sampled)
+                done.tokens = np.asarray(sampled)
             else:
-                # intermediate chunks only: nothing is fetched, so on an
-                # async backend the pack is still in flight when the span
-                # closes.  It is exported unsynced; the pack's device time is
-                # the trace's (one ``XLA Modules`` event per execution)
-                next_tokens = None
+                # nothing is fetched HERE (intermediate chunks only: nothing
+                # ever is), so on an async backend the pack is still in
+                # flight when the span closes.  It is exported unsynced; the
+                # pack's device time is the trace's (one ``XLA Modules``
+                # event per execution)
                 sp.end(sync_obj=sampled)
+                if finishing:
+                    # the device -> host copy starts HERE, behind the program:
+                    # the collect waits for the program, not for a transfer
+                    # started late
+                    sampled.copy_to_host_async()
+                    done.sampled = sampled
+        if split:
+            # what the plan of the NEXT execution reads before this one is
+            # collected: the chunk's KV is written (in device order), its
+            # full blocks hold prompt tokens the host knows, and a prompt
+            # completed here has one token on the way
+            for s, _start, end in entries:
+                s.seen_tokens = end
+                self.mgr.update_hashes(s)
+            for s in finishing:
+                s.pending += 1
+        return done
+
+    def _fetched(self, done: "Enqueued"):
+        """The tokens of ``done``, waiting for its program where they were
+        not fetched inside its dispatch span (``tick_collect``: the wait and
+        the fetch, named after what it collects)."""
+        if done.sampled is not None:
+            with self.telemetry.span("tick_collect", track=self._ns,
+                                     what=done.span):
+                done.tokens = np.asarray(done.sampled)
+            done.sampled = None
+        return done.tokens
+
+    def _dropped(self, s, done: "Enqueued", dead) -> bool:
+        """Whether ``s``'s row of ``done`` is dead: its request ended while
+        the program was enqueued (the scheduler names it in ``dead``), or the
+        sequence is released already.  Nothing of a dead row is appended,
+        hashed or returned."""
+        if not done.split or (
+                s.uid not in dead and self.mgr.seqs.get(s.uid) is s):
+            return False
+        self._c["ahead_rows_dropped"].inc()
+        return True
+
+    def pack_collect(self, done: "Enqueued", out: Dict[int, int],
+                     dead=()) -> None:
+        """Fetch and emit a pack ``pack_dispatch`` enqueued: first tokens
+        into ``out`` ({uid: token}, -1 for a row the finite guard failed)."""
+        tel, ns = self.telemetry, self._ns
+        entries, finishing = done.rows, done.finishing
+        next_tokens = self._fetched(done)
         with tel.span("engine.pack_emit", track=ns):
             poison = self._poisoned([s.uid for s in finishing])
+            completing = {id(s) for s in finishing}
             for s, start, end in entries:
-                s.seen_tokens = end
-                if end == len(s.tokens):
+                completes = id(s) in completing
+                if completes and done.split:
+                    s.pending -= 1
+                if self._dropped(s, done, dead):
+                    continue
+                s.seen_tokens = max(s.seen_tokens, end)
+                if completes:
                     tok = int(next_tokens[s.slot])
                     if s.uid in poison:
                         tok = -1
@@ -1149,6 +1276,15 @@ class InferenceEngineV2:
             self._samp_dev = self._upload(self._samp_np.copy())
             self._c["sampling_uploads"].inc()
         return self._samp_dev
+
+    @property
+    def programs_may_queue(self) -> bool:
+        """Whether a program may be enqueued while the one before it is not
+        fetched yet (a scheduler's tick dispatched one ahead).  Over a serve
+        mesh and with offloaded weights it is untried (their commit and
+        donation rules were written for one program at a time): there the
+        scheduler keeps dispatch and fetch back to back."""
+        return self._mesh is None and not self._offload_weights
 
     def _upload(self, x: np.ndarray, held: bool = True):
         """Hand ONE host array to the device.  Every host array of a dispatch
@@ -1513,15 +1649,26 @@ class InferenceEngineV2:
     def _decode_tick(self, active_seqs, sampling: SamplingParams) -> Dict[int, int]:
         """One batched decode dispatch over ``active_seqs`` only (other
         tracked sequences keep their KV untouched — the scheduler decodes
-        its own running set without side-driving ``put()``-admitted ones).
-        Appends the sampled token per sequence; stop/length handling is the
-        caller's job."""
+        its own running set without side-driving ``put()``-admitted ones),
+        fetched and emitted at once: ``decode_dispatch`` and
+        ``decode_collect`` back to back.  Appends the sampled token per
+        sequence; stop/length handling is the caller's job."""
+        return self.decode_collect(self.decode_dispatch(active_seqs, sampling))
+
+    def decode_dispatch(self, active_seqs, sampling: SamplingParams,
+                        split: bool = False, ahead: bool = False) -> "Enqueued":
+        """Build, upload and ENQUEUE one decode tick over ``active_seqs``;
+        ``decode_collect`` fetches and emits it.  ``split`` / ``ahead`` as
+        ``pack_dispatch``'s.  A sequence with a token on the way
+        (``pending``) reads its input token from the chain on the device:
+        the host knows its position and its pages, not its value."""
         tel, ns = self.telemetry, self._ns
         with tel.span("engine.decode_build", track=ns) as bsp:
             B = self.mgr.max_seqs
-            # the tick's ONE upload: tokens, KV positions, 0 / 1 for a live slot
-            rows = np.zeros((3, B), np.int32)
-            tokens, seq_lens, active = rows
+            # the tick's ONE upload: tokens, KV positions, 0 / 1 for a live
+            # slot, 0 / 1 for an input token that is the chain's
+            rows = np.zeros((4, B), np.int32)
+            tokens, seq_lens, active, chained = rows
             ctx_tokens = 0
             for s in active_seqs:
                 # grow pages for the token being written this tick; the COW
@@ -1529,7 +1676,10 @@ class InferenceEngineV2:
                 self.mgr.ensure_capacity(s, 1)
                 self.mgr.ensure_writable(s, s.cur_len - 1)
                 self._set_block_table(s)
-                tokens[s.slot] = s.tokens[-1]
+                if s.pending:
+                    chained[s.slot] = 1
+                else:
+                    tokens[s.slot] = s.tokens[-1]
                 seq_lens[s.slot] = s.cur_len - 1  # KV position of the new token
                 active[s.slot] = 1
                 ctx_tokens += s.cur_len
@@ -1542,26 +1692,50 @@ class InferenceEngineV2:
         # the span
         with tel.span(
             "decode_tick", track=ns, hist=self._h["decode_tick_ms"],
-            batch=len(active_seqs), ctx_tokens=ctx_tokens, **extra,
+            batch=len(active_seqs), ctx_tokens=ctx_tokens, ahead=int(ahead),
+            **extra,
         ) as sp:
             args = (
                 self.params, self._upload(rows, held=False),
-                self._tables_device(), self.kv, self._rng,
+                self._tables_device(), self.kv, self._rng, self._chain,
                 (sampling.temperature, sampling.top_k, sampling.top_p),
             )
             sp.mark("upload")  # every argument handed over; next: enqueue
             sampled, self._rng, self.kv = self._decode_jit(*args)
+            self._chain = sampled
             if self._tracked:
                 self._tracked["_decode_jit"].note(args)
             sp.dispatched()
             self._c["decode_ticks"].inc()
             self._c["decode_emitted"].inc(len(active_seqs))
+            self._c["dispatched_ahead"].inc(int(ahead))
             self._account_comm(B)
-            next_tokens = np.asarray(sampled)  # the tick's host sync
+            done = Enqueued("decode_tick", list(active_seqs), None, split)
+            if split:
+                # the fetch is the collect's: the span ends at the enqueue
+                sp.end(sync_obj=sampled)
+                sampled.copy_to_host_async()  # (as a split pack's)
+                done.sampled = sampled
+            else:
+                done.tokens = np.asarray(sampled)  # the tick's host sync
+        for s in active_seqs:
+            s.pending += 1  # a token on the way: lengths count it from here
+        return done
+
+    def decode_collect(self, done: "Enqueued", dead=()) -> Dict[int, int]:
+        """Fetch and emit a tick ``decode_dispatch`` enqueued: {uid: token},
+        -1 for a row the finite guard failed; a dead row (``dead``: its
+        request ended while the tick was enqueued) is in it nowhere."""
+        tel, ns = self.telemetry, self._ns
+        active_seqs = done.rows
+        next_tokens = self._fetched(done)
         with tel.span("engine.decode_emit", track=ns):
             poison = self._poisoned([s.uid for s in active_seqs])
             out = {}
             for s in active_seqs:
+                s.pending -= 1
+                if self._dropped(s, done, dead):
+                    continue
                 tok = int(next_tokens[s.slot])
                 if s.uid in poison:
                     tok = -1
@@ -1577,7 +1751,7 @@ class InferenceEngineV2:
                     out[s.uid] = -1
                     continue
                 s.tokens.append(tok)
-                s.seen_tokens = s.cur_len - 1
+                s.seen_tokens = len(s.tokens) - 1
                 self.mgr.update_hashes(s)
                 out[s.uid] = tok
         return out
@@ -1589,6 +1763,7 @@ class InferenceEngineV2:
         sequence (drafts accepted by the verify pass) — all are appended to
         the descriptor, the newest is returned, and a stop token inside the
         emitted run truncates the sequence there."""
+        self._settle()
         active_seqs = [s for s in self.mgr.active if not s.done]
         if not active_seqs:
             return {}
@@ -1777,6 +1952,7 @@ class InferenceEngineV2:
         ``n-1`` tokens past a stop) is retired.  Returns
         {uid: last kept token} (-1 for a poisoned row, same as ``step()``).
         """
+        self._settle()
         active_seqs = [s for s in self.mgr.active if not s.done]
         if not active_seqs or n <= 0:
             return {}
@@ -1813,6 +1989,13 @@ class InferenceEngineV2:
                 s.done = True
             out[s.uid] = run[-1]
         return out
+
+    def _settle(self) -> None:
+        """A direct ``step()`` / ``step_n()`` drives EVERY tracked sequence:
+        a scheduler that has a tick enqueued ahead collects it first (every
+        token of its sequences is then the host's)."""
+        if self._scheduler is not None:
+            self._scheduler.settle("direct_step")
 
     def flush(self, uids: Sequence[int]) -> None:
         for uid in uids:
@@ -2008,7 +2191,7 @@ class InferenceEngineV2:
         self._tracked = {}
         for attr in ("_packed_prefill_jit", "_packed_prefill_ctx_jit",
                      "_cow_jit", "_decode_jit", "_decode_burst_jit",
-                     "_spec_jit", "_tables_dev", "_samp_dev",
+                     "_spec_jit", "_tables_dev", "_samp_dev", "_chain",
                      "_kv_gather_jit", "_kv_scatter_jit"):
             setattr(self, attr, None)
         self._closed = True

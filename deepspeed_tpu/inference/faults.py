@@ -195,6 +195,13 @@ class FaultInjector:
         ))
         return self
 
+    def armed(self) -> bool:
+        """Whether any rule can still fire: a scheduler that dispatches one
+        tick ahead asks this and, while it holds, keeps dispatch and fetch
+        back to back (an injected failure is retried and isolated there)."""
+        return self.enabled and any(
+            not s.exhausted() for specs in self._specs.values() for s in specs)
+
     @property
     def injected_uids(self) -> frozenset:
         """Uids explicitly TARGETED by any armed spec — the population a

@@ -327,10 +327,17 @@ class SequenceDescriptor:
     # logits (finite_guard sentinel) — the scheduler converts it into a
     # typed FAILED terminal state; direct put()/step() callers read it here
     error: Optional[str] = None
+    # tokens sampled for this sequence by programs that are enqueued and not
+    # yet collected (a tick dispatched one ahead): their VALUES are on the
+    # device only, their COUNT is known, and every position, page and length
+    # cap follows the count.  0 wherever dispatch and fetch are back to back.
+    pending: int = 0
 
     @property
     def cur_len(self) -> int:
-        return len(self.tokens)
+        """The sequence's length once every enqueued program has been
+        collected: what positions and page growth are planned from."""
+        return len(self.tokens) + self.pending
 
 
 class _AllocatorGroupView:

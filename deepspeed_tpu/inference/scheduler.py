@@ -22,6 +22,31 @@ The FastGen serve-loop analogue (reference ``mii``/DeepSpeed-FastGen blog +
 * **Decode** runs one batched tick over the scheduler's running set only.
   When page growth finds the pool truly exhausted, the youngest running
   request is preempted by recompute.
+* **One ahead.**  An EXECUTION is one tick's programs on the device (a pack
+  and a step).  On an engine that can enqueue a program without fetching it
+  (``decode_dispatch`` / ``prefill_dispatch``), call k of ``tick()`` runs
+  expire and admission, builds and ENQUEUES execution k + 1 from what the
+  host knows without execution k's tokens (positions, pages and counts are
+  known ahead; a step's input tokens stay on the device, in the engine's
+  chain), and only then waits for execution k, books its tokens and returns
+  THEM: the device runs k + 1 while the host does all of that.  A call that
+  finds nothing enqueued enqueues k as well, so a caller that submits
+  everything and then ticks gets every token in the call it always did; a
+  request submitted while an execution is enqueued gets its first token one
+  call later.  What is not known ahead (a stop token, a non-finite row)
+  leaves a DEAD row in the execution already enqueued: its result is thrown
+  away, nothing past the stop is appended or returned, and its pages go back
+  once that execution is collected.  Whatever cannot be planned without the
+  tokens DRAINS instead: what is enqueued is collected first and the tick
+  runs dispatch and fetch back to back (speculation, a megastep burst, pool
+  pressure that needs a victim, an armed fault point, a failed dispatch, a
+  serve mesh or weight offload, a staged retune, and from outside a tick
+  ``cancel`` / a deadline of a running request, a direct ``_preempt``,
+  ``engine.step()``, ``close``).  ``detach`` leaves a dead row instead (the
+  handoff carries exactly the one token the host holds), and
+  ``adopt_prefilled`` needs nothing: its request joins the next plan with a
+  token the host knows.  A call returns the tokens of at most ONE
+  execution: one that drained collects nothing else.
 
 Fault tolerance (the robustness layer on top):
 
@@ -175,6 +200,27 @@ class ServeRequest:
     cancel_requested: bool = False
 
 
+class _Drain(Exception):
+    """Planning an execution ahead met something that needs the tokens of
+    the one enqueued: collect first (``reason`` names what)."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
+
+
+class _Execution:
+    """One tick's programs, enqueued and not collected: the engine's handles
+    (``packs``, ``step``) and the requests riding the step."""
+
+    __slots__ = ("packs", "step", "decoding")
+
+    def __init__(self):
+        self.packs: List[Any] = []
+        self.step: Any = None
+        self.decoding: List[ServeRequest] = []
+
+
 class ServeScheduler:
     def __init__(
         self,
@@ -269,6 +315,14 @@ class ServeScheduler:
         # per-tick decode; read by tick() to normalize the watchdog's
         # measured duration back to a per-device-tick figure
         self._last_fused = 1
+        # one ahead: executions enqueued and not collected (oldest first; two
+        # only between an enqueue and the collect that follows it), the uids
+        # whose release waits for a dead row to be collected, and tokens a
+        # drain outside a tick collected (the next tick returns them)
+        self._inflight: List[_Execution] = []
+        self._dead: List[int] = []
+        self._undelivered: Dict[int, int] = {}
+        self.drains: Dict[str, int] = {}  # reason -> ticks / calls that drained
         # fault-tolerance transitions count in the paired SERVE namespace
         # (they are serve-level events; the engine's stats view lists them
         # too — registry counters are memoized by name, so these are the
@@ -276,7 +330,7 @@ class ServeScheduler:
         self._flt = self.telemetry.counters(self._eng_ns, (
             "failed", "timed_out", "cancelled", "retries", "nan_failures",
             "isolation_probes", "shed_transitions", "shed_rejections",
-            "watchdog_trips",
+            "watchdog_trips", "ahead_drains",
         ))
         self.stats = StatsView(self._c)
 
@@ -437,7 +491,13 @@ class ServeScheduler:
             req.trace.add_spec(seq.spec_drafted, seq.spec_accepted)
             if error is None and seq.error is not None:
                 error = seq.error
-            self.engine.mgr.release(req.uid)
+            if seq.pending:
+                # a DEAD row: an execution enqueued ahead still carries this
+                # sequence.  The request ends here and now; its slot and its
+                # pages go back once that execution is collected
+                self._dead.append(req.uid)
+            else:
+                self.engine.mgr.release(req.uid)
         if req in self._running:
             self._running.remove(req)
         try:
@@ -497,6 +557,9 @@ class ServeScheduler:
             if self._in_tick and req in self._running:
                 req.cancel_requested = True
             else:
+                if req in self._running:
+                    # an execution enqueued ahead may carry it: collect first
+                    self._drain_outside("cancel")
                 self._release_locked(req, CANCELLED, None)
         self._flush_released()
         return True
@@ -645,6 +708,11 @@ class ServeScheduler:
             req = self.requests.get(uid)
             if req is None or req.state in TERMINAL:
                 return False
+            # (an execution enqueued ahead may still carry the sequence: its
+            # row is then DEAD, the token it samples is the destination's to
+            # sample, and the pages go back once it is collected.  NOT a
+            # drain: that would hand every other request of this worker a
+            # token before ITS migration, and a handoff carries exactly one)
             if req.cancel_requested:
                 self._release_locked(req, CANCELLED, None)
                 migrated = False
@@ -662,7 +730,9 @@ class ServeScheduler:
         allocator would then double-own.  Idempotent.  Releases directly
         (never the mid-tick deferral): teardown must not leave a deferred
         cancel holding pages after the queues are cleared."""
+        self._drain_outside("close")
         with self._lock:
+            self._undelivered.clear()
             for uid in list(self.requests):
                 req = self.requests[uid]
                 if req.state not in TERMINAL:
@@ -680,31 +750,46 @@ class ServeScheduler:
         return req.ttft_deadline_ms if req.ttft_deadline_ms is not None \
             else self.serve.ttft_deadline_ms
 
-    def _expire_phase(self) -> None:
+    def _expire_phase(self) -> Dict[int, int]:
         """Tick-boundary deadline check over every live request (queued AND
         running): e2e deadline always applies; the TTFT deadline only until
         the first token lands.  Runs FIRST so an expired request's pages are
-        back in the pool before this tick's admission."""
+        back in the pool before this tick's admission.  Where one of them is
+        RUNNING and an execution is enqueued ahead, that is collected first
+        (a drain): returns its tokens."""
+        out: Dict[int, int] = {}
+        if self._inflight:
+            with self._lock:
+                due = self._due_locked()
+            if any(req in self._running for req, _, _ in due):
+                out = self._drain("expire")
         with self._lock:
-            now = self._clock()
-            for req in list(self.waiting) + list(self._running):
-                if req.state in TERMINAL:
-                    continue
-                if req.cancel_requested:
-                    # a cancel deferred from mid-tick lands here, at the
-                    # first safe boundary of the NEXT tick
-                    self._release_locked(req, CANCELLED, None)
-                    continue
-                waited_ms = (now - req.submit_time) * 1e3
-                dl = self._deadline_of(req)
-                if dl is not None and waited_ms > dl:
-                    self._release_locked(
-                        req, TIMED_OUT, f"e2e deadline {dl}ms exceeded")
-                    continue
-                tdl = self._ttft_deadline_of(req)
-                if tdl is not None and not req.generated and waited_ms > tdl:
-                    self._release_locked(
-                        req, TIMED_OUT, f"ttft deadline {tdl}ms exceeded")
+            for req, state, error in self._due_locked():
+                self._release_locked(req, state, error)
+        return out
+
+    def _due_locked(self) -> List[tuple]:
+        """(request, terminal state, error) of every live request whose
+        deferred cancel or deadline lands at this tick's boundary."""
+        due = []
+        now = self._clock()
+        for req in list(self.waiting) + list(self._running):
+            if req.state in TERMINAL:
+                continue
+            if req.cancel_requested:
+                # a cancel deferred from mid-tick lands here, at the
+                # first safe boundary of the NEXT tick
+                due.append((req, CANCELLED, None))
+                continue
+            waited_ms = (now - req.submit_time) * 1e3
+            dl = self._deadline_of(req)
+            if dl is not None and waited_ms > dl:
+                due.append((req, TIMED_OUT, f"e2e deadline {dl}ms exceeded"))
+                continue
+            tdl = self._ttft_deadline_of(req)
+            if tdl is not None and not req.generated and waited_ms > tdl:
+                due.append((req, TIMED_OUT, f"ttft deadline {tdl}ms exceeded"))
+        return due
 
     # -- transient-failure retry --------------------------------------------
     def _backoff(self, attempt: int) -> None:
@@ -900,7 +985,19 @@ class ServeScheduler:
         return out
 
     def _prefill_phase(self) -> Dict[int, int]:
-        out: Dict[int, int] = {}
+        entries = self._plan_prefill()
+        if not entries:
+            return {}
+        clock = self.telemetry.clock
+        t0 = clock()
+        first = self._dispatch_prefill(entries, self._base_sampling())
+        self._note_chunks(entries, t0, clock(), self.tick_no)
+        return self._book_first(first)
+
+    def _plan_prefill(self) -> List[tuple]:
+        """This tick's prompt chunks, [(seq, start, end)], under the chunk
+        budget.  A prompt whose last chunk is enqueued already (one ahead:
+        its first token is on the way) has nothing left to plan."""
         bs = self.engine.block_size
         mgr = self.engine.mgr
         R = mgr.replicas
@@ -918,7 +1015,7 @@ class ServeScheduler:
                 continue
             seq = mgr.seqs[req.uid]
             r = mgr.replica_of(seq)
-            if budgets[r] < bs:
+            if budgets[r] < bs or seq.pending:
                 continue
             # pick up prefix blocks published since admission (a request
             # queued behind the cold request that is WRITING its prefix
@@ -947,19 +1044,24 @@ class ServeScheduler:
         # speculates less, an idle-prefill tick speculates up to the chunk.
         # Per replica, like the chunk budget it is the remainder of.
         self._spec_budget = {r: max(0, b) for r, b in budgets.items()}
-        if not entries:
-            return out
-        clock = self.telemetry.clock
-        t0 = clock()
-        first = self._dispatch_prefill(entries, self._base_sampling())
-        t1 = clock()
+        return entries
+
+    def _note_chunks(self, entries, t0: float, t1: float, tick: int) -> None:
+        """``tick``: the call that returns the tokens of the execution these
+        chunks ride (a request's trace names an execution by that call)."""
         for seq, start, end in entries:
             r = self.requests.get(seq.uid)
             if r is not None and r.state == PREFILL:
                 # chunks share the tick's pack dispatch(es); each request's
                 # chunk span carries the shared window + its own token count
-                r.trace.prefill_chunk(t0, t1, end - start, tick=self.tick_no)
+                r.trace.prefill_chunk(t0, t1, end - start, tick=tick)
         self._c["prefill_chunks"].inc(len(entries))
+
+    def _book_first(self, first: Dict[int, int]) -> Dict[int, int]:
+        """The first tokens a pack sampled (``first``: {uid: token}, -1 for a
+        row the finite guard failed) into their requests."""
+        out: Dict[int, int] = {}
+        mgr = self.engine.mgr
         for req in list(self._running):
             if req.state == PREFILL and req.uid in first:
                 tok = first[req.uid]
@@ -997,8 +1099,11 @@ class ServeScheduler:
     def _preempt(self, req: ServeRequest) -> None:
         """Preemption by recompute: drop the sequence's pages (full ones
         stay in the prefix-cache LRU) and requeue at the FRONT with prompt =
-        all tokens so far — re-prefill is then mostly cache hits."""
+        all tokens so far — re-prefill is then mostly cache hits.  The
+        requeued prompt needs every token's value: an execution enqueued
+        ahead is collected first."""
         with self._lock:
+            self._drain_outside("preempt")
             seq = self.engine.mgr.seqs[req.uid]
             req.tokens = list(seq.tokens)
             # this incarnation's draft/accept totals die with the
@@ -1210,6 +1315,14 @@ class ServeScheduler:
             return out
         runs = self._dispatch_decode(survivors, proposals, n_fuse)
         self._last_fused = max(1, n_fuse)
+        return self._book_runs(survivors, runs)
+
+    def _book_runs(self, survivors: List[ServeRequest],
+                   runs: Dict[int, List[int]]) -> Dict[int, int]:
+        """A decode dispatch's emissions (``runs``: {uid: tokens}, a run
+        ending in -1 for a row the finite guard failed) into their requests."""
+        out: Dict[int, int] = {}
+        mgr = self.engine.mgr
         for req in survivors:
             if req.state != DECODE or req.uid not in runs:
                 continue  # failed in isolation (already released)
@@ -1237,14 +1350,23 @@ class ServeScheduler:
     def _maybe_finish(self, req: ServeRequest) -> None:
         samp = req.sampling
         seq = self.engine.mgr.seqs[req.uid]
+        # (the tokens the host HOLDS: one on the way, ``seq.pending``, is a
+        # dead row's if the request ends here)
         done = (
             (samp.stop_token is not None
              and req.generated[-1] == samp.stop_token)
             or len(req.generated) >= samp.max_new_tokens
-            or seq.cur_len >= self.engine.max_seq_len
+            or len(seq.tokens) >= self.engine.max_seq_len
         )
         if done:
             self._release(req, FINISHED)
+
+    def _ends_enqueued(self, req: ServeRequest, seq) -> bool:
+        """Whether ``req`` ends BY A COUNT once the tokens on their way to it
+        are collected (``max_new_tokens`` or ``max_seq_len``: known ahead,
+        whatever the tokens are): such a row is left out of the next step."""
+        return (len(req.generated) + seq.pending >= req.sampling.max_new_tokens
+                or seq.cur_len >= self.engine.max_seq_len)
 
     def result(self, uid: int) -> List[int]:
         """Generated tokens with ``generate()`` semantics: trailing stop
@@ -1477,7 +1599,166 @@ class ServeScheduler:
     # -- the loop -----------------------------------------------------------
     @property
     def idle(self) -> bool:
-        return not self.waiting and not self._running
+        return not self.waiting and not self._running and not self._inflight
+
+    # -- one ahead ----------------------------------------------------------
+    def _back_to_back(self) -> Optional[str]:
+        """Why this tick cannot be planned without the tokens of the one
+        before it (None: it can).  What the tick OBSERVES, each tick anew."""
+        eng = self.engine
+        if getattr(eng, "decode_dispatch", None) is None:
+            return "engine"  # offers no split (an engine double)
+        if not eng.programs_may_queue:
+            return "mesh"  # a serve mesh or offloaded weights: untried
+        if self._staged_knobs:
+            return "retune"
+        if self.faults is not None and self.faults.armed():
+            return "fault"  # a failed dispatch is retried and isolated
+        if self._speculating:
+            return "speculation"  # proposals are read off the tokens
+        if self.serve.decode_megastep > 1 and not self.waiting \
+                and not any(r.state == PREFILL for r in self._running):
+            return "megastep"  # a burst hands its tokens over n at a time
+        return None
+
+    def _note_drain(self, reason: str) -> None:
+        self.drains[reason] = self.drains.get(reason, 0) + 1
+        self._flt["ahead_drains"].inc()
+
+    def _drain(self, reason: str) -> Dict[int, int]:
+        """Collect every execution enqueued, oldest first: {uid: newest
+        token}.  The scheduler is then where the back-to-back order leaves
+        it: every token is the host's."""
+        out: Dict[int, int] = {}
+        if not self._inflight:
+            return out
+        self._note_drain(reason)
+        with self.telemetry.span("sched.drain", track=self._eng_ns,
+                                 reason=reason, executions=len(self._inflight)):
+            while self._inflight:
+                out.update(self._collect(self._inflight.pop(0)))
+        return out
+
+    def _drain_outside(self, reason: str) -> None:
+        """A drain from outside ``tick()`` (cancel, the handoff calls, a
+        direct preemption, close): the next tick returns what it collected."""
+        if self._inflight:
+            out = self._drain(reason)
+            with self._lock:
+                self._undelivered.update(out)
+
+    def settle(self, reason: str = "settle") -> None:
+        """Collect whatever is enqueued ahead, so that every token sampled
+        so far is the host's and every sequence's ``tokens`` / ``seen_tokens``
+        say what the device holds: what a KV handoff reads before it extracts,
+        and a direct ``engine.step()`` before it drives the same sequences.
+        OWNER-THREAD only, between ticks; a no-op with nothing enqueued."""
+        self._drain_outside(reason)
+        self._flush_released()
+
+    def _enqueue(self, ahead: bool, tick: int) -> None:
+        """Plan ONE execution from what the host knows without the tokens of
+        whatever is enqueued, and enqueue it: this tick's prompt chunks as a
+        pack, then a step over every request that decodes after the packs
+        enqueued BEFORE this call (a prompt completed by this pack joins the
+        next step, as it always did).  ``tick``: the call that will collect
+        it.  Raises ``_Drain`` where the plan needs the tokens; leaves
+        ``_inflight`` alone where there is nothing to run."""
+        eng, mgr = self.engine, self.engine.mgr
+        decoding = []
+        for req in self._running:
+            seq = mgr.seqs[req.uid]
+            if (req.state == DECODE or (req.state == PREFILL and seq.pending)) \
+                    and not self._ends_enqueued(req, seq):
+                decoding.append(req)
+        # page growth FIRST, before anything is enqueued: a pool that cannot
+        # grow a row needs a victim, and preemption is the back-to-back order's
+        for req in decoding:
+            seq = mgr.seqs[req.uid]
+            try:
+                mgr.ensure_capacity(seq, 1)
+                mgr.ensure_writable(seq, seq.cur_len - 1)
+            except RuntimeError as e:
+                raise _Drain("pool") from e
+        entries = self._plan_prefill()
+        if not entries and not decoding:
+            return
+        ex = _Execution()
+        samp = self._base_sampling()
+        tel, track = self.telemetry, self._eng_ns
+        try:
+            if entries:
+                with tel.span("sched.prefill", track=track):
+                    clock = self.telemetry.clock
+                    t0 = clock()
+                    ex.packs = eng.prefill_dispatch(entries, samp, ahead=ahead)
+                    self._note_chunks(entries, t0, clock(), tick)
+            if decoding:
+                with tel.span("sched.decode", track=track, batch=len(decoding)):
+                    ex.decoding = decoding
+                    ex.step = eng.decode_dispatch(
+                        [mgr.seqs[r.uid] for r in decoding], samp,
+                        split=True, ahead=ahead)
+        except Exception as e:  # noqa: BLE001 — retried where it is isolated
+            if is_compile_error(e):
+                raise  # same for every request: stop the serve loop
+            raise _Drain("dispatch_error") from e
+        finally:
+            if ex.packs or ex.step is not None:
+                self._inflight.append(ex)  # what DID go out is collected
+
+    def _collect(self, ex: _Execution) -> Dict[int, int]:
+        """Wait for one enqueued execution, fetch it and book its tokens:
+        the pack's first tokens, then the step's.  A row whose request has
+        ended meanwhile is DEAD: the engine drops its result, and the
+        sequence's pages go back here, once nothing enqueued carries it."""
+        eng, mgr = self.engine, self.engine.mgr
+        out: Dict[int, int] = {}
+        tel, track = self.telemetry, self._eng_ns
+
+        def ended(uids) -> frozenset:
+            return frozenset(
+                u for u in uids if u not in self.requests
+                or self.requests[u].state in TERMINAL)
+
+        if ex.packs:
+            first: Dict[int, int] = {}
+            with tel.span("sched.prefill", track=track):
+                for pack in ex.packs:
+                    eng.pack_collect(pack, first, dead=ended(
+                        s.uid for s, _, _ in pack.rows))
+                out.update(self._book_first(first))
+        if ex.step is not None:
+            with tel.span("sched.decode", track=track, batch=len(ex.decoding)):
+                toks = eng.decode_collect(
+                    ex.step, dead=ended(r.uid for r in ex.decoding))
+                out.update(self._book_runs(
+                    ex.decoding, {u: [t] for u, t in toks.items()}))
+        with self._lock:
+            waiting = []
+            for uid in self._dead:
+                seq = mgr.seqs.get(uid)
+                if seq is not None and seq.pending:
+                    waiting.append(uid)  # the NEXT execution carries it too
+                elif seq is not None:
+                    mgr.release(uid)
+            self._dead = waiting
+        return out
+
+    def _tick_ahead(self, drained: bool) -> Dict[int, int]:
+        """The tick's dispatch phases, one ahead: enqueue the NEXT execution,
+        then collect the one enqueued by the call before.  A call that finds
+        nothing enqueued enqueues one execution more; a call that drained
+        enqueues and collects nothing else."""
+        if drained:  # (which left nothing enqueued)
+            self._enqueue(ahead=False, tick=self.tick_no + 1)
+            return {}
+        if not self._inflight:
+            self._enqueue(ahead=False, tick=self.tick_no)
+            if not self._inflight:
+                return {}  # nothing to run
+        self._enqueue(ahead=True, tick=self.tick_no + 1)
+        return self._collect(self._inflight.pop(0))
 
     def tick(self) -> Dict[int, int]:
         """One scheduler tick: expire -> admission -> chunked prefill ->
@@ -1485,12 +1766,23 @@ class ServeScheduler:
         that emitted one (a request finishing its prefill emits its first
         token; it joins the decode batch from the NEXT tick).  Failed /
         timed-out / cancelled requests never appear in the returned dict —
-        read their terminal state off ``requests[uid]``."""
+        read their terminal state off ``requests[uid]``.
+
+        One ahead (the module's docstring has the contract): the prefill and
+        the decode ENQUEUED by this call are the next execution's, the tokens
+        returned are those of the execution the call before enqueued."""
         self.tick_no += 1
         tel, track = self.telemetry, self._eng_ns
         with tel.span("sched.tick", track=track, tick=self.tick_no,
                       running=len(self._running), waiting=len(self.waiting)):
             self._in_tick = True  # single-owner write: cancels now defer
+            with self._lock:
+                out, self._undelivered = self._undelivered, {}
+            drained = bool(out)  # a call returns ONE execution's tokens
+            reason = self._back_to_back()
+            if reason is not None and self._inflight:
+                out.update(self._drain(reason))
+                drained = True
             self._apply_pending_knobs()  # staged retunes land HERE, never mid-phase
             t0 = self._clock()  # BEFORE the fault delay: an injected stall must
             # land inside the watchdog's measured window or it cannot trip it
@@ -1500,15 +1792,28 @@ class ServeScheduler:
                     if d > 0:
                         time.sleep(d)  # chaos harness: stalls the tick, trips the watchdog
                 with tel.span("sched.expire", track=track):
-                    self._expire_phase()
+                    expired = self._expire_phase()
+                    drained = drained or bool(expired)
+                    out.update(expired)
                 with tel.span("sched.admit", track=track):
                     self._admit_phase()
-                decoding = [r for r in self._running if r.state == DECODE]
-                with tel.span("sched.prefill", track=track):
-                    out = self._prefill_phase()
                 self._last_fused = 1
-                with tel.span("sched.decode", track=track, batch=len(decoding)):
-                    out.update(self._decode_phase(decoding))
+                if reason is None:
+                    try:
+                        out.update(self._tick_ahead(drained))
+                    except _Drain as d:
+                        reason = d.reason
+                        if self._inflight:
+                            out.update(self._drain(reason))
+                            drained = True
+                if reason is not None and not drained:
+                    if reason != "engine":  # (a double offers no other order)
+                        self._note_drain(reason)  # a tick in today's order
+                    decoding = [r for r in self._running if r.state == DECODE]
+                    with tel.span("sched.prefill", track=track):
+                        out.update(self._prefill_phase())
+                    with tel.span("sched.decode", track=track, batch=len(decoding)):
+                        out.update(self._decode_phase(decoding))
                 # a megastep deliberately makes the tick n_fuse x longer —
                 # normalize the watchdog/EMA duration back to per-device-tick
                 # so fused decode cannot trip the slow-tick shed path

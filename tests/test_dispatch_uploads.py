@@ -232,7 +232,7 @@ def test_a_mesh_engine_takes_the_one_buffer_replicated(mesh, tiny, monkeypatch):
     assert _serve(eng, prompts, samp) == want
     assert eng._rng.committed and eng._rng.sharding.is_fully_replicated
     shapes = {shape for shape, _ in handed}
-    assert (3, 4) in shapes  # a tick's rows
+    assert (4, 4) in shapes  # a tick's rows (PR 43: + the chain flags)
     assert any(len(shape) == 1 for shape in shapes)  # a pack's flat buffer
     for _, dev in handed:
         assert isinstance(dev, jax.Array) and dev.committed

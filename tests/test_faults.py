@@ -135,16 +135,18 @@ def test_compile_failure_stops_the_serve_loop(tiny):
         assert eng.stats["retries"] == 0 and eng.stats["failed"] == 0
         assert eng.stats["isolation_probes"] == 0
 
-    real_prefill = eng.prefill_entries
-    eng.prefill_entries = boom  # the prefill dispatch cannot compile
+    # (PR 43: the scheduler enqueues through the split bodies on an engine
+    # that offers them, and through the back-to-back ones when it drains: a
+    # program that cannot compile fails under both names)
+    real_prefill, real_dispatch = eng.prefill_entries, eng.prefill_dispatch
+    eng.prefill_entries = eng.prefill_dispatch = boom  # the pack cannot compile
     with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
         sched.run()
     assert_untouched()
-    eng.prefill_entries = real_prefill
-    sched.tick()  # prefill completes; now the decode dispatch breaks
-    eng._decode_tick = boom
+    eng.prefill_entries, eng.prefill_dispatch = real_prefill, real_dispatch
+    eng._decode_tick = eng.decode_dispatch = boom  # now the decode program
     with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
-        sched.run()
+        sched.run()  # (its first call enqueues the pack, then the step)
     assert_untouched()
 
 

@@ -487,8 +487,9 @@ def test_decode_and_verify_donate_kv_no_copy(tiny):
     rng = jax.random.PRNGKey(0)
     lowered = {
         "decode": eng._decode_jit.lower(
-            eng.params, jnp.ones((3, B), i32),
-            jnp.zeros((B, eng.max_pages), i32), eng.kv, rng, (0.0, 0, 1.0)),
+            eng.params, jnp.ones((4, B), i32),
+            jnp.zeros((B, eng.max_pages), i32), eng.kv, rng,
+            jnp.zeros(B, i32), (0.0, 0, 1.0)),  # (PR 43: + the chain)
         "verify": eng._spec_jit.lower(
             eng.params, jnp.zeros(B * K1, i32), jnp.zeros(B * K1, i32),
             jnp.zeros(B * K1, i32), jnp.full(B * K1, -1, i32),

@@ -221,8 +221,14 @@ def test_chunked_prefill_spans_defer_and_resolve_tick_tight(tiny):
     assert len(packs) == eng.stats["prefill_dispatches"]
     unsynced = [e for e in packs if e["args"].get("synced") is False]
     synced = [e for e in packs if "synced" not in e["args"]]
-    # only the chunk that finished the prompt fetched its sampled token
-    assert len(synced) == 1 and len(unsynced) == len(packs) - 1
+    # one ahead (PR 43): NO pack is fetched inside its dispatch span; the
+    # chunk that finished the prompt is waited for and fetched by ONE
+    # ``tick_collect`` span of its own (in today's order, ``_back_to_back``,
+    # that chunk's span held its fetch and was the one synced pack)
+    assert len(synced) == 0 and len(unsynced) == len(packs)
+    collects = [e for e in evs if e["ph"] == "X" and e["name"] == "tick_collect"
+                and e["args"]["what"] == "prefill_pack"]
+    assert len(collects) == 1 and collects[0]["ts"] >= packs[-1]["ts"]
     for e in unsynced:
         # dispatch-side duration: the span ended when the dispatch returned
         assert e["dur"] == pytest.approx(e["args"]["dispatch_ms"] * 1e3, abs=1.0)
@@ -249,13 +255,13 @@ def test_prefill_pack_span_carries_ctx_pages(tiny):
         telemetry=True,
     )
     seen = []
-    run = eng._run_packed_prefill
+    run = eng.pack_dispatch  # (the body under ``_run_packed_prefill`` too)
 
-    def spy(entries, sampling, out):
+    def spy(entries, sampling, **kw):
         seen.append(sum(-(-start // bs) for _, start, _ in entries))
-        return run(entries, sampling, out)
+        return run(entries, sampling, **kw)
 
-    eng._run_packed_prefill = spy
+    eng.pack_dispatch = spy
     sched = eng.scheduler
     samp = SamplingParams(temperature=0.0, max_new_tokens=2)
     sched.submit(1, list(range(1, 41)), samp)    # 40 tokens: chunks at 0, 16, 32
@@ -869,16 +875,31 @@ def test_children_lie_inside_parents_and_self_time_is_not_negative(plain_serve):
     for e in events:
         if e["name"] == "sched.tick":
             assert e["dur"] - covered.get(e["args"]["span_id"], 0.0) >= -1e-3
-    # build -> dispatch -> emit follow one another inside one decode phase
+    # one ahead (PR 43): a decode phase is EITHER the enqueue of the next
+    # step (build -> dispatch) or the collect of the step before it (the wait
+    # and fetch -> emit), each in order; a tick holds one of each, the
+    # enqueue first (in today's order one phase held build -> dispatch -> emit)
+    halves = []
     for d in (e for e in events if e["name"] == "sched.decode"):
         kids = sorted((e for e in events
                        if e["args"].get("parent_id") == d["args"]["span_id"]),
                       key=lambda e: e["ts"])
         if kids:
-            assert [k["name"] for k in kids] == [
-                "engine.decode_build", "decode_tick", "engine.decode_emit"]
+            names = [k["name"] for k in kids]
+            assert names in (["engine.decode_build", "decode_tick"],
+                             ["tick_collect", "engine.decode_emit"])
             assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3
                        for a, b in zip(kids, kids[1:]))
+            halves.append((d["args"]["parent_id"], names[0]))
+    assert sum(n == "tick_collect" for _, n in halves) == eng.stats["decode_ticks"]
+    by_tick = {}
+    for tick, first in halves:
+        by_tick.setdefault(tick, []).append(first)
+    assert ["engine.decode_build", "tick_collect"] in by_tick.values()
+    assert all(v in (["engine.decode_build"], ["tick_collect"],
+                     ["engine.decode_build", "tick_collect"],
+                     ["engine.decode_build", "engine.decode_build", "tick_collect"])
+               for v in by_tick.values())
 
 
 def test_request_traces_name_the_tick_of_every_chunk_and_token(plain_serve):
@@ -1064,7 +1085,16 @@ def test_dispatch_spans_carry_their_phase_marks(kind):
     for e in evs:
         by_name.setdefault(e["name"], []).append(e)
     assert len(by_name["decode_tick"]) >= 4 and len(by_name["prefill_pack"]) >= 3
-    assert any(e["args"].get("synced") is False for e in by_name["prefill_pack"])
+    # one ahead (PR 43): every dispatch span covers ITS program's build ->
+    # upload -> dispatch and ends there, unsynced; the wait and the fetch are
+    # ``tick_collect``'s, one for every program that sampled something
+    for name in ("decode_tick", "prefill_pack"):
+        assert all(e["args"].get("synced") is False for e in by_name[name])
+        assert all(e["args"]["ahead"] in (0, 1) for e in by_name[name])
+    assert sum(e["args"]["what"] == "decode_tick" for e in by_name["tick_collect"]) \
+        == len(by_name["decode_tick"])
+    assert 1 <= sum(e["args"]["what"] == "prefill_pack"
+                    for e in by_name["tick_collect"]) <= 3  # prompts may share a pack
     for name in ("decode_tick", "prefill_pack"):
         for e in by_name[name]:
             a = e["args"]
@@ -1078,7 +1108,7 @@ def test_dispatch_spans_carry_their_phase_marks(kind):
     assert set(by_name) <= {
         "sched.tick", "sched.expire", "sched.admit", "sched.prefill", "sched.decode",
         "engine.pack_build", "prefill_pack", "engine.pack_emit",
-        "engine.decode_build", "decode_tick", "engine.decode_emit"}
+        "engine.decode_build", "decode_tick", "engine.decode_emit", "tick_collect"}
 
 
 def test_spec_and_burst_bodies_export_the_names_of_a_tick(serve_pair, tiny):
