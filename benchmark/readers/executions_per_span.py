@@ -3,8 +3,8 @@ many executions on device 0's ``XLA Modules`` line started inside one whose
 module does NOT match ``excluding``: the small programs a tick runs beside
 the engine's own (the rng's split, a tuple's unstacking), each of which costs
 a launch and cuts an idle gap in two.  Device times are shifted onto the host
-clock as ``tick_host_gap`` shifts them (``xprograms.skew`` over ``dispatch``
-spans and the ``module`` they run); None where that interval is empty."""
+clock by ``xprograms.skew`` over ``dispatch`` spans and the ``module`` they
+run (its tight edge); None where that interval is empty."""
 import bisect
 import re
 
@@ -26,7 +26,8 @@ def read(obs, span, excluding, dispatch, module, q):
     progs = xprograms.of(obs)
     if progs is None:
         return None
-    iv = xprograms.skew(progs, dispatch, module)
+    iv = xprograms.skew(progs, dispatch, module, spans=obs.get("spans") or ())
     if iv is None:
         return None
-    return percentile([len(runs) for runs in inside(progs, span, excluding, iv[0])], q)
+    shift = xprograms.tight_edge(iv)
+    return percentile([len(runs) for runs in inside(progs, span, excluding, shift)], q)

@@ -1,7 +1,8 @@
 """What to add to the trace's device times to put them on its host clock
-(ms): the lower edge of the interval causality allows over every mirrored
-``span`` in the capture paired with the execution of ``module`` it
-dispatched (``xprograms.skew_interval``).  None where the interval is empty."""
+(ms): the tight edge (``xprograms.tight_edge``) of the interval causality
+allows over every ``span`` in the capture paired with the execution of
+``module`` it dispatched.  None where the interval is empty or nothing pairs:
+the instrument's own check."""
 from .. import xprograms
 
 
@@ -9,5 +10,5 @@ def read(obs, span, module):
     progs = xprograms.of(obs)
     if progs is None:
         return None
-    iv = xprograms.skew(progs, span, module)
-    return None if iv is None else 1e3 * iv[0]
+    iv = xprograms.skew(progs, span, module, spans=obs.get("spans") or ())
+    return None if iv is None else 1e3 * xprograms.tight_edge(iv)

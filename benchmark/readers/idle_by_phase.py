@@ -1,32 +1,37 @@
-"""Share (%) of the capture in which device 0 ran no op, charged INSTANT BY
-INSTANT to what the scheduler's thread was doing then, in the program's own
-terms.  The idle time is ``device_idle_share``'s (the window less the union of
-the ``XLA Ops`` intervals), so the phases add up to it.
+"""Device 0's idle time of the capture charged INSTANT BY INSTANT to what the
+scheduler's thread was doing then, in the program's own terms.  A helper, not
+a reader (no ``read``): ``tools/describe_idle.py`` prints it (PR 39's
+instrument; its classes were manifest metrics until PR 44, when one-ahead
+dispatch had left them nothing to cut).  The idle time is
+``device_idle_share``'s (the window less the union of the ``XLA Ops``
+intervals), so the phases add up to it.
 
-The program's spans are laid on the trace's clock by their mirrors (a span the
-session did not see: by ``xprograms.recorder_offset``), a mark as ``start +
-<mark>_ms``.  Device times are shifted by the lower edge of the causality
-interval (``xprograms.skew_interval``) over every dispatch span paired with
-the execution it dispatched, the lower bound tightened from "the span opened"
+The program's spans are laid on the trace's clock by their mirrors
+(``xprograms.on_trace_clock``), a mark as ``start + <mark>_ms``.  Device times
+are shifted by the tight edge of the causality interval (``xprograms.tight_edge``)
+over every dispatch span paired with the execution it dispatched
+(``xprograms.dispatched``), the lower bound tightened from "the span opened"
 to "its ``upload`` mark": a program cannot start before its enqueue began.
 
 - ``in_program``: between two ops of ONE execution (the device's own bubbles;
   cut on the device's clock, so the shift does not touch it);
 - ``launch``: from a dispatch span's ``dispatch`` mark (the jitted call has
-  returned) to the start of ITS execution, whatever span is open by then;
+  returned) to the start of ITS execution, whatever span is open by then (one
+  ahead that is the rest of the execution before it, so what falls here is the
+  device's own gap between two programs);
 - otherwise the innermost span open: a dispatch span before ``upload_ms`` is
   ``upload``, from there to ``dispatch_ms`` ``enqueue``, after it
   ``fetch_tail`` (its execution has ended, the host is not back from the
-  fetch); a build span before ``rows_ms`` is ``build_rows``, after it
+  fetch), as is a ``tick_collect`` (the wait for a program enqueued one
+  ahead); a build span before ``rows_ms`` is ``build_rows``, after it
   ``build_rng``; ``emit``; ``sched`` (the scheduler's spans' self time);
   ``outside`` where none is open (the driver's loop, the load generator).
 
 ``launch`` and ``fetch_tail`` trade against each other by exactly the shift:
-the interval's width (``seconds()["width"]``, which ``tools/describe_idle.py``
-prints) is their error bar.  None
-where the recorder dropped spans, where no dispatch span carries an
-``upload_ms`` (a program without the marks), or where no shift satisfies
-every pair."""
+the interval's width (``seconds()["width"]``, which the tool prints) is their
+error bar.  None where the recorder dropped spans, where no dispatch span
+carries an ``upload_ms`` (a program without the marks), or where no shift
+satisfies every pair."""
 import math
 
 from .. import xplane, xprograms
@@ -39,6 +44,7 @@ PHASES_OF = {
     "decode_tick": _TICK, "prefill_pack": _TICK, "spec_tick": _TICK, "decode_burst": _TICK,
     "engine.decode_build": _BUILD, "engine.pack_build": _BUILD,
     "engine.decode_emit": _EMIT, "engine.pack_emit": _EMIT,
+    xprograms.COLLECT: _TICK[-1:],
     "sched.tick": _SCHED, "sched.expire": _SCHED, "sched.admit": _SCHED,
     "sched.prefill": _SCHED, "sched.decode": _SCHED,
 }
@@ -49,30 +55,14 @@ DISPATCH = {
 }
 PHASES = ("in_program", "launch", "fetch_tail", "upload", "enqueue", "build_rows",
           "build_rng", "emit", "sched", "outside")
-SUMS = {"bookkeeping": ("emit", "sched")}
-PAIR_SLACK_S = 0.010  # as ``xprograms.skew``
 
 
 def on_trace_clock(progs, spans):
     """The spans of ``PHASES_OF`` that touch the capture, as host events on the
-    trace's clock, start order, outer first; ``stats`` are the recorder's
-    arguments (marks included)."""
-    off = xprograms.recorder_offset(progs, spans)
+    trace's clock (``xprograms.on_trace_clock``)."""
     w0, w1 = progs.window
-    out = []
-    for name, a, b, args in spans:
-        if name not in PHASES_OF:
-            continue
-        m = progs.mirrors.get(args.get(xprograms.SPAN_ID))
-        if m is not None:
-            a, b = m.start, m.end
-        elif off is not None:
-            a, b = a + off, b + off
-        else:
-            continue
-        if b > w0 and a < w1:
-            out.append(xplane.HostEvent(name, a, b, args))
-    return sorted(out, key=lambda h: (h.start, -h.end))
+    return [h for h in xprograms.on_trace_clock(progs, spans, PHASES_OF)
+            if h.end > w0 and h.start < w1]
 
 
 def mark_at(h, arg):
@@ -81,25 +71,14 @@ def mark_at(h, arg):
     return h.end if ms is None else min(h.start + 1e-3 * ms, h.end)
 
 
-def dispatch_pairs(progs, hosts):
-    """Each dispatch span with THE execution of its program that it dispatched
-    (``xprograms.pair``); a span with none or several is left out."""
-    out = []
-    for name, module in DISPATCH.items():
-        out += xprograms.pair([h for h in hosts if h.name == name],
-                              progs.of_module(module), PAIR_SLACK_S)
-    return out
-
-
 def causality(pairs, mark=None):
-    """The shifts causality allows (``xprograms.skew_interval``): an execution
-    starts after its span opened, or after the span's ``mark`` where one is
-    named, and ends before a fetch returned (a span closed unsynced fetched
-    nothing and bounds no end)."""
+    """The shifts causality allows (``xprograms.skew_interval``) over ``pairs``
+    of ``xprograms.dispatched``: an execution starts after its span opened, or
+    after the span's ``mark`` where one is named, and ends before the host held
+    its result (a result never fetched bounds no end)."""
     return xprograms.skew_interval(
-        (mark_at(h, f"{mark}_ms") if mark else h.start,
-         h.end if h.stats.get("synced", True) else math.inf, e.start, e.end)
-        for h, e in pairs)
+        (mark_at(h, f"{mark}_ms") if mark else h.start, at, e.start, e.end)
+        for h, e, at in pairs)
 
 
 def host_phases(hosts):
@@ -170,17 +149,17 @@ def seconds(progs, spans):
     hosts = on_trace_clock(progs, spans)
     if not any("upload_ms" in h.stats for h in hosts if h.name in DISPATCH):
         return None
-    pairs = dispatch_pairs(progs, hosts)
+    pairs = xprograms.dispatched(progs, spans, DISPATCH)
     iv = causality(pairs, "upload")
     if iv is None:
         return None
-    shift, device = iv[0], min(progs.ops)
+    shift, device = xprograms.tight_edge(iv), min(progs.ops)
     out = dict.fromkeys(PHASES, 0.0)
     runs = [(e.start, e.end) for e in progs.executions.get(device, ())]
     inside, rest = cut(idle_intervals(progs, device), xplane.merge(runs))
     out["in_program"] = sum(b - a for a, b, _ in inside)
     rest = [(a + shift, b + shift) for a, b in rest]
-    launches = [(mark_at(h, "dispatch_ms"), e.start + shift) for h, e in pairs]
+    launches = [(mark_at(h, "dispatch_ms"), e.start + shift) for h, e, _ in pairs]
     inside, rest = cut(rest, xplane.merge([(a, b) for a, b in launches if b > a]))
     out["launch"] = sum(b - a for a, b, _ in inside)
     phases = host_phases(hosts)
@@ -196,11 +175,3 @@ def of(obs):
     if "_idle_by_phase" not in obs:
         obs["_idle_by_phase"] = seconds(xprograms.of(obs), obs.get("spans") or ())
     return obs["_idle_by_phase"]
-
-
-def read(obs, phase):
-    secs = of(obs)
-    if secs is None:
-        return None
-    w0, w1 = xprograms.of(obs).window
-    return 100.0 * sum(secs[p] for p in SUMS.get(phase, (phase,))) / (w1 - w0)

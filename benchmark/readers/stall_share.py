@@ -1,6 +1,11 @@
-"""Share (%) of in-window token gaps that contain a span of the given name:
-with ``prefill_pack`` it is the share of gaps stalled behind someone else's
-prefill.  ``itl_p95_ms`` reads the stall only while this is well above 5%."""
+"""Share (%) of in-window token gaps that contain the START of a span of the
+given name.  With ``engine.pack_emit`` it is the share of gaps stalled behind
+someone else's prefill: a pack's result is booked, fetched or not, in the call
+that returns the tokens of the execution the pack ran in, so the booking
+starts inside the gap that execution made longer, one ahead or back to back
+(the ``prefill_pack`` span itself opens one call EARLIER since PR 43: it marked
+the gap before).  ``itl_p95_ms`` reads the stall only while this is well above
+5%."""
 import bisect
 
 

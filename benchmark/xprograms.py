@@ -20,8 +20,11 @@ which of the program's spans:
 Three clocks meet here: the recorder's (``obs["spans"]``), the trace's host
 side, and the device's, which runs a few ms off the host's inside one trace.
 ``recorder_offset`` is the first difference, ``skew_interval`` bounds the
-second by causality.  Everything is in seconds; a device time is on the
-device's clock until the shift of ``skew`` is added.
+second by causality over the dispatch spans paired with the executions they
+enqueued (``dispatched``: back to back the execution starts inside its span;
+one ahead, since PR 43, it ends before the span's ``tick_collect`` returns).
+Everything is in seconds; a device time is on the device's clock until the
+shift of ``skew`` (``tight_edge``) is added.
 
 One process runs one cell and ``harness.Capture`` clears that cell's
 directory before it records, so the newest ``.xplane.pb`` under
@@ -31,7 +34,9 @@ directory before it records, so the newest ``.xplane.pb`` under
 from __future__ import annotations
 
 import bisect
+import collections
 import dataclasses
+import math
 import re
 import statistics
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -206,19 +211,107 @@ def recorder_offset(progs: Programs, spans: Sequence[tuple]) -> Optional[float]:
 # ---------------------------------------------------------------------------
 # device clock against host clock
 # ---------------------------------------------------------------------------
-def pair(hosts: Sequence[xplane.HostEvent], runs: Sequence[Execution],
-         slack_s: float) -> List[Tuple[xplane.HostEvent, Execution]]:
-    """Each host span with THE execution that starts inside it, give or take
-    ``slack_s`` (the clocks' disagreement is a few ms, dispatches of one
-    program are a tick apart); a span with none or several is left out."""
-    starts = [e.start for e in runs]
+COLLECT = "tick_collect"
+# dispatch span -> the span that BOOKS its result.  Every dispatch is followed
+# by exactly one booking, the oldest dispatch first (``ServeScheduler._collect``;
+# back to back it follows at once), whether or not the result was fetched: a
+# pack that completes no prompt never is
+BOOKED_BY = {"prefill_pack": "engine.pack_emit", "decode_tick": "engine.decode_emit",
+             "spec_tick": "engine.decode_emit", "decode_burst": "engine.decode_emit"}
+# a collect returns a transfer (a ms or so) after its program ended and the
+# clocks differ by -2.0 to +1.2 ms (PERF.md section 3); a program is longer
+COLLECT_SLACK_S = 0.003
+
+
+def tight_edge(iv: Interval) -> float:
+    """The edge of a causality interval to take as THE shift: the one nearer
+    to zero.  The two clocks of one machine differ by a ms or two; an edge is
+    loose by what lies between cause and effect.  Back to back a program
+    starts a launch after its dispatch (the lower edge is tight) and its fetch
+    returns a transfer after its end; one ahead it starts a whole step after
+    its dispatch, when the one before it ends (the lower edge is off by a
+    program's length), while its collect returns a transfer after its end (the
+    upper edge is tight) - unless the host sets the pace, and then the lower
+    edge is tight again.  Either way the loose edge is the far one, and the
+    edge taken is off by a launch or a transfer at most (0.3-2 ms)."""
+    lo, hi = iv
+    return lo if abs(lo) <= abs(hi) else hi
+
+
+def on_trace_clock(progs: Programs, spans: Sequence[tuple],
+                   names: Optional[Iterable[str]] = None) -> List[xplane.HostEvent]:
+    """The recorder's spans (those of ``names``) as host events on the trace's
+    clock, by their mirrors (a span the session did not see: by
+    ``recorder_offset``), start order, outer first; ``stats`` are the
+    recorder's arguments (marks, ``ahead``, ``synced``, ``what``)."""
+    off = recorder_offset(progs, spans)
     out = []
+    for name, a, b, args in spans:
+        if names is not None and name not in names:
+            continue
+        m = progs.mirrors.get(args.get(SPAN_ID))
+        if m is not None:
+            a, b = m.start, m.end
+        elif off is not None:
+            a, b = a + off, b + off
+        else:
+            continue
+        out.append(xplane.HostEvent(name, a, b, args))
+    return sorted(out, key=lambda h: (h.start, -h.end))
+
+
+def returned(hosts: Iterable[xplane.HostEvent]) -> Dict[int, float]:
+    """span_id of each dispatch span whose result was fetched AFTER it closed
+    -> the end of the ``tick_collect`` that fetched it, on the clock of
+    ``hosts`` (every span of the run, start order).  A dispatch and its
+    collect are matched through the span that BOOKS the result
+    (``BOOKED_BY``): bookings come one a dispatch and oldest first, collects
+    do not (only a pack that completes a prompt is fetched)."""
+    queued: Dict[str, collections.deque] = {
+        booking: collections.deque() for booking in BOOKED_BY.values()}
+    fetched: Dict[str, float] = {}
+    out: Dict[int, float] = {}
     for h in hosts:
-        i = bisect.bisect_left(starts, h.start - slack_s)
-        j = bisect.bisect_right(starts, h.end + slack_s)
-        if j - i == 1:
-            out.append((h, runs[i]))
+        if h.name in BOOKED_BY:
+            queued[BOOKED_BY[h.name]].append(h)
+        elif h.name == COLLECT:
+            fetched[BOOKED_BY.get(h.stats.get("what"))] = h.end
+        elif h.name in queued and queued[h.name]:
+            d = queued[h.name].popleft()
+            if h.name in fetched:
+                out[int(d.stats[SPAN_ID])] = fetched.pop(h.name)
     return out
+
+
+def pair(hosts: Sequence[xplane.HostEvent], runs: Sequence[Execution], slack_s: float,
+         returned_at: Optional[Dict[int, float]] = None,
+         ) -> List[Tuple[xplane.HostEvent, Execution]]:
+    """Each dispatch span with THE execution it enqueued (``runs``: one
+    program's, by start; the clocks disagree by a few ms).  A span dispatched
+    back to back (``ahead`` 0 or absent): the execution that starts inside it,
+    give or take ``slack_s`` (dispatches of one program are a tick apart).  A
+    span dispatched one ahead: the last execution that ended by the time its
+    collect returned (``returned_at``, give or take ``COLLECT_SLACK_S``) and
+    started after it opened; the one before it is still running while it is
+    dispatched and starts, by the clocks, about when it opens.  A span with no
+    candidate or several, one never fetched, and two spans that claim one
+    execution are left out, not guessed."""
+    starts = [e.start for e in runs]
+    ends = [e.end for e in runs]
+    found = []
+    for h in hosts:
+        if int(h.stats.get("ahead", 0)):
+            at = (returned_at or {}).get(int(h.stats[SPAN_ID]), math.inf)
+            j = bisect.bisect_right(ends, at + min(slack_s, COLLECT_SLACK_S)) - 1
+            if at < math.inf and j >= 0 and runs[j].start >= h.start - slack_s:
+                found.append((h, j))
+        else:
+            i = bisect.bisect_left(starts, h.start - slack_s)
+            j = bisect.bisect_right(starts, h.end + slack_s)
+            if j - i == 1:
+                found.append((h, i))
+    claims = collections.Counter(j for _, j in found)
+    return [(h, runs[j]) for h, j in found if claims[j] == 1]
 
 
 def skew_interval(pairs: Iterable[Tuple[float, float, float, float]]) -> Optional[Interval]:
@@ -228,22 +321,49 @@ def skew_interval(pairs: Iterable[Tuple[float, float, float, float]]) -> Optiona
     times lies in [max(open - start), min(returned - end)].  None where no
     shift satisfies every pair (or there is none)."""
     lo, hi = float("-inf"), float("inf")
-    for opened, returned, start, end in pairs:
-        lo, hi = max(lo, opened - start), min(hi, returned - end)
+    for opened, returned_at, start, end in pairs:
+        lo, hi = max(lo, opened - start), min(hi, returned_at - end)
     return (lo, hi) if lo <= hi and lo > float("-inf") else None
 
 
-def skew(progs: Programs, span: str, module: str, slack_s: float = 0.010) -> Optional[Interval]:
-    """The interval of ``skew_interval`` over every mirrored ``span`` in the
-    capture paired with the execution of ``module`` it dispatched."""
-    pairs = pair(progs.mirrored(span), progs.of_module(module), slack_s)
-    return skew_interval((h.start, h.end, e.start, e.end) for h, e in pairs)
+def dispatched(progs: Programs, spans: Sequence[tuple], names: Dict[str, str],
+               slack_s: float = 0.010,
+               ) -> List[Tuple[xplane.HostEvent, Execution, float]]:
+    """(dispatch span, the execution it enqueued, when the host held its
+    result) for every span of ``names`` (span name -> its program's module
+    regex) that lies inside the capture and pairs (``pair``), on the trace's
+    clock: the span's own end where it fetched inside (synced), its collect's
+    end, ``inf`` for a result never fetched (a pack that completes no prompt).
+    With the recorder's ``spans`` a span carries their arguments and
+    its collect is found (``returned``); without them (a program that mirrors
+    its spans and keeps no record) the mirrors alone pair back to back.  An
+    execution at either edge of the capture is left out: the profiler's
+    session begins and ends in the middle of a program, whose stamp is cut."""
+    w0, w1 = progs.window
+    if spans_dropped(spans):
+        return []   # bookings are counted from the record's start: the count is lost
+    if spans:
+        hosts = on_trace_clock(progs, spans, {COLLECT, *names, *BOOKED_BY, *BOOKED_BY.values()})
+        at = returned(hosts)
+    else:
+        hosts, at = sorted(progs.mirrors.values(), key=lambda h: h.start), {}
+    out = []
+    for name, module in names.items():
+        inside = [h for h in hosts if h.name == name and h.start >= w0 and h.end <= w1]
+        for h, e in pair(inside, progs.of_module(module), slack_s, at):
+            if e.start < w0 + COLLECT_SLACK_S or e.end > w1 - COLLECT_SLACK_S:
+                continue
+            synced = h.stats.get("synced", True)
+            out.append((h, e, h.end if synced else at.get(int(h.stats[SPAN_ID]), math.inf)))
+    return out
 
 
-def busy_inside(runs: Sequence[Execution], a: float, b: float, shift: float) -> float:
-    """Seconds of [a, b] (host clock) in which some execution ran."""
-    iv = [(max(e.start + shift, a), min(e.end + shift, b)) for e in runs]
-    return xplane.union_len(xplane.merge([(x, y) for x, y in iv if y > x]))
+def skew(progs: Programs, span: str, module: str, slack_s: float = 0.010,
+         spans: Sequence[tuple] = ()) -> Optional[Interval]:
+    """The interval of ``skew_interval`` over every ``span`` in the capture
+    paired with the execution of ``module`` it dispatched (``dispatched``)."""
+    return skew_interval((h.start, at, e.start, e.end)
+                         for h, e, at in dispatched(progs, spans, {span: module}, slack_s))
 
 
 # ---------------------------------------------------------------------------

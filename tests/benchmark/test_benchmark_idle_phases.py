@@ -9,7 +9,10 @@ from pathlib import Path
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
+import one_ahead_scenes as scenes  # noqa: E402
+from benchmark import xprograms  # noqa: E402
 from benchmark.readers import executions_per_span, idle_by_phase  # noqa: E402
 from benchmark.xplane import HostEvent  # noqa: E402
 from benchmark.xprograms import Execution, Programs, RawOp  # noqa: E402
@@ -102,12 +105,9 @@ def test_every_idle_instant_goes_to_the_phase_open_then():
     # the parts add up to what device_idle_share reads of the same ops
     busy = sum(b - a for _, parts in RUNS for a, b in parts)
     assert sum(secs[p] for p in idle_by_phase.PHASES) == pytest.approx(1.0 - busy, abs=1e-9)
+    # ``of`` computes the same once a run
     obs = {"trace": object(), "_xprograms": progs, "spans": spans}
-    assert idle_by_phase.read(obs, "launch") == pytest.approx(1.6, abs=1e-7)
-    assert idle_by_phase.read(obs, "bookkeeping") == pytest.approx(1.5 + 4.2, abs=1e-7)
-    assert sum(idle_by_phase.read(obs, p) for p in (
-        "in_program", "launch", "fetch_tail", "upload", "enqueue", "build_rows",
-        "build_rng", "bookkeeping", "outside")) == pytest.approx(100 * (1.0 - busy), abs=1e-7)
+    assert idle_by_phase.of(obs) == secs and obs["_idle_by_phase"] is idle_by_phase.of(obs)
 
 
 def test_an_unsynced_packs_launch_is_launch_and_a_fetch_splits_at_its_execution():
@@ -123,7 +123,8 @@ def test_an_unsynced_packs_launch_is_launch_and_a_fetch_splits_at_its_execution(
     # execution (0.304-0.310, queued behind the pack) and fetch_tail AFTER it
     hosts = idle_by_phase.on_trace_clock(*scene())
     step = next(h for h in hosts if h.stats["span_id"] == 18)
-    pairs = dict((h.stats["span_id"], e) for h, e in idle_by_phase.dispatch_pairs(scene()[0], hosts))
+    pairs = dict((h.stats["span_id"], e) for h, e, _ in xprograms.dispatched(
+        *scene(), idle_by_phase.DISPATCH))
     assert pairs[18].start + D == pytest.approx(T + 0.310)
     assert idle_by_phase.mark_at(step, "dispatch_ms") == pytest.approx(T + 0.270)
     assert pairs[14].module == "jit_packed_ctx_impl" and set(pairs) == {4, 14, 18, 24}
@@ -134,7 +135,7 @@ def test_nothing_to_read_without_marks_with_dropped_spans_or_without_a_shift():
     progs, spans = scene(strip=("upload_ms", "rows_ms"))
     assert idle_by_phase.seconds(progs, spans) is None      # the parent's spans
     obs = {"trace": object(), "_xprograms": progs, "spans": spans}
-    assert idle_by_phase.read(obs, "launch") is None
+    assert idle_by_phase.of(obs) is None
     assert idle_by_phase.seconds(*scene(dropped=7)) is None
     assert idle_by_phase.seconds(None, spans) is None        # a run with no trace
     # an execution that ends after its fetch returned: no shift satisfies both
@@ -153,7 +154,7 @@ def test_a_span_the_session_did_not_mirror_is_placed_by_the_clocks_offset():
 
 def test_skew_width_with_and_without_the_tightened_bound():
     progs, spans = scene()
-    pairs = idle_by_phase.dispatch_pairs(progs, idle_by_phase.on_trace_clock(progs, spans))
+    pairs = xprograms.dispatched(progs, spans, idle_by_phase.DISPATCH)
 
     def width(mark):
         lo, hi = idle_by_phase.causality(pairs, mark)
@@ -202,6 +203,46 @@ def test_the_tool_prints_its_tables_of_the_same_scene():
     phases = idle_by_phase.host_phases(idle_by_phase.on_trace_clock(progs, spans))
     (row,) = describe_idle.traceme_table(trace, phases, ["^Allocate$"])
     assert "(1 events, 0.0040 s): upload 0.0020, enqueue 0.0020" in row
+
+
+@pytest.mark.parametrize("name", sorted(scenes.SCENES))
+def test_the_phases_add_up_whatever_the_order_of_dispatch(name):
+    """One ahead (PR 43) a dispatch span closes at the enqueue and the wait is
+    a ``tick_collect``: the cut still charges every idle instant once, at a
+    shift within a launch or a fetch of the truth."""
+    s = scenes.SCENES[name]()
+    progs, spans = s.programs()
+    secs = idle_by_phase.seconds(progs, spans)
+    assert secs is not None and secs["shift"] == pytest.approx(s.shift, abs=1e-3)
+    w0, w1 = progs.window
+    busy = sum(o.end - o.start for o in progs.ops[0])
+    assert sum(secs[p] for p in idle_by_phase.PHASES) == pytest.approx(w1 - w0 - busy, abs=1e-9)
+    assert secs["in_program"] == pytest.approx(1e-5 * len(s.runs), abs=1e-9)   # the bubbles
+    assert secs["outside"] >= 0.019            # the capture's two margins, give or take the shift
+    if name == "host_bound":
+        # the device waits while the scheduler works: its idle time is the host's
+        assert secs["sched"] > 0.5 * (w1 - w0 - busy - secs["outside"])
+    elif name != "old_order":
+        # the device sets the pace: next to nothing of a tick is idle
+        host = sum(secs[p] for p in idle_by_phase.PHASES if p not in ("in_program", "outside"))
+        assert host < 0.05 * busy
+
+
+def test_the_tool_prints_its_tables_of_a_one_ahead_scene():
+    from benchmark.tools import describe_idle
+
+    s = scenes.pack_and_step()
+    progs, spans = s.programs()
+    secs = idle_by_phase.seconds(progs, spans)
+    ticks = len(progs.mirrored("sched.tick"))
+    rows = describe_idle.phase_table(secs, progs.window[1] - progs.window[0], ticks)
+    assert rows[-2].split()[0] == "all" and f"({ticks} ticks" in rows[-2]
+    assert f"shift {1e3 * secs['shift']:+.4f} ms" in rows[-1]
+    t0 = min(a for _, a, _, _ in spans)
+    marks = describe_idle.mark_table(spans, (t0, t0 + 10.0))
+    # a dispatch span closed at the enqueue: upload + enqueue, and no fetch left in it
+    assert any(r.startswith("decode_tick") and r.rstrip().endswith("(sum 0.600)") for r in marks)
+    assert describe_idle.aux_table(progs, AUX, secs["shift"])[-1] == "median a tick: 0.0"
 
 
 def test_the_manifest_holds_no_more_per_layer_metrics_than_the_driver_takes():

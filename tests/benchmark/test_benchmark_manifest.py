@@ -21,6 +21,7 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 MODEL_TYPES = sorted(p.stem for p in (harness.HERE / "models").glob("[!_]*.py"))
+CAP = 128   # entries of ``per_layer`` the driver takes
 
 
 def cells_of(metric):
@@ -39,7 +40,8 @@ def test_top_level_keys_and_limits():
     assert isinstance(MAN["run_seconds"], int) and 1 <= MAN["run_seconds"] <= 51
     assert (ROOT / "BENCHMARK.json").stat().st_size <= 64 * 1024
     assert 1 <= len(MAN["workloads"]) <= 24 and 1 <= len(MAN["end_to_end"]) <= 16
-    assert 1 <= len(MAN["per_layer"]) <= 128  # the driver's cap, not this PR's count
+    # the driver's cap, not this PR's count (benchmark/README.md, "The cap")
+    assert 1 <= len(MAN["per_layer"]) <= CAP, f"{len(MAN['per_layer'])} of {CAP} used"
     assert sum(w["chips"] == 4 for w in MAN["workloads"]) <= max(1, len(CELLS) // 4)
     assert all(w["chips"] in (1, 4) for w in MAN["workloads"])
     pairs = [(w["config"], w["traffic"]) for w in MAN["workloads"]]
@@ -116,37 +118,60 @@ def test_no_metric_file_is_left_without_an_entry():
     assert used == readers
 
 
-# PR 41 merged the families below: one entry ``<family>.serve`` whose
+# PR 41 and PR 44 merged the families below: one entry ``<family>.serve`` whose
 # ``workloads`` are the cells that each had an entry ``<family>.<suffix>`` with
 # the same reader and parameters.  What the per-cell files held, kept here:
 SUFFIX = {"docs": "mistral7b_docs_closed", "dots": "dots3_note_longdocs_closed",
-          "nemo": "nemotron3_super_reasoning_closed", "qnext": "qwen3_next_longctx_qa_closed"}
+          "nemo": "nemotron3_super_reasoning_closed", "qnext": "qwen3_next_longctx_qa_closed",
+          "laguna": "laguna_xs2_mixed_len_closed"}
 FOUR = ("docs", "dots", "nemo", "qnext")
+FIVE = FOUR + ("laguna",)
+PACKS = "^jit_packed(_ctx)?_impl$"
+PACK_EXPERTS = {"num": "expert_pairs_held", "num_less": "expert_pairs_held_decode",
+                "den": "experts_touched", "den_less": "experts_touched_decode"}
 MERGED = [
-    ("kv_preemptions", FOUR, "counter", {"key": "preemptions"}),
-    ("kernel_fallbacks", FOUR, "kernel_fallbacks", {}),
-    ("compiles_in_window", FOUR, "field", {"key": "compiles_in_window"}),
-    ("device_idle_share", FOUR, "device_idle_share", {}),
-    ("peak_hbm_gib", FOUR, "peak_hbm_gib", {}),
-    ("prefill_pack_device_p50_ms", FOUR, "module_device_percentile",
-     {"module": "^jit_packed(_ctx)?_impl$", "q": 50}),
+    ("kv_preemptions", FIVE, "counter", {"key": "preemptions"}),
+    ("kernel_fallbacks", FIVE, "kernel_fallbacks", {}),
+    ("compiles_in_window", FIVE, "field", {"key": "compiles_in_window"}),
+    ("device_idle_share", FIVE, "device_idle_share", {}),
+    ("peak_hbm_gib", FIVE, "peak_hbm_gib", {}),
+    ("prefill_pack_device_p50_ms", FIVE, "module_device_percentile", {"module": PACKS, "q": 50}),
     ("pack_build_p50_ms", FOUR[:3], "span_percentile", {"span": "engine.pack_build", "q": 50}),
-    ("host_device_skew_ms", FOUR[:3], "host_device_skew",
+    ("host_device_skew_ms", FIVE, "host_device_skew",
      {"span": "decode_tick", "module": "^jit_decode_impl$"}),
     ("routed_here_share", FOUR[1:], "counter_ratio",
      {"num": "expert_pairs_held", "den": "expert_pairs_routed", "scale": 100.0}),
-    ("decode_batch_mean", FOUR[2:], "counter_ratio",
+    ("decode_batch_mean", FIVE[2:], "counter_ratio",
      {"num": "decode_emitted", "den": "decode_ticks"}),
-    ("decode_device_p50_ms", FOUR[2:], "module_device_percentile",
+    ("decode_device_p50_ms", FIVE[2:], "module_device_percentile",
      {"module": "^jit_decode_impl$", "q": 50}),
+    # PR 44: the expert layer of the cells whose PACK program runs it (cell 5's
+    # files named ``jit_packed_ctx_impl`` alone: a ``cfg.latent`` runner packs
+    # through no other program, so the wider pattern finds the same executions)
+    ("expert_layout_call_ms", ("dots", "qnext", "laguna"), "scope_call_ms",
+     {"module": PACKS, "scope": "(^|/)expert_layout(/|$)", "q": 50}),
+    ("expert_matmul_call_ms", ("dots", "qnext", "laguna"), "scope_call_ms",
+     {"module": PACKS, "scope": "(^|/)expert_matmul(/|$)", "q": 50}),
+    ("expert_matmul_roofline", FIVE[3:], "gdn_roofline",
+     {"module": PACKS, "scope": "(^|/)expert_matmul(/|$)", "cost": "expert_matmul"}),
+    ("expert_rows_mean", FIVE[3:], "counter_difference_ratio", PACK_EXPERTS),
 ]
-RETIRED = ("tick_p50_ms.docs", "host_enqueue_ms.train", "decode_dispatch_p50_ms.chat")
+# a family's cells that keep an entry of their own on OTHER parameters (their
+# decode program runs the expert layer, or another reader counts its need)
+OTHER_PARAMETERS = {"expert_layout_call_ms": {"nemo"}, "expert_matmul_call_ms": {"nemo"},
+                    "expert_matmul_roofline": {"dots", "nemo"}, "expert_rows_mean": {"nemo"}}
+IDLE = ("fetch_tail", "upload", "build_rng", "bookkeeping", "enqueue", "build_rows")
+RETIRED = ["tick_p50_ms.docs", "host_enqueue_ms.train", "decode_dispatch_p50_ms.chat",   # PR 41
+           # PR 44: what one-ahead dispatch left nothing to read, or something else
+           "tick_host_gap_p50_ms", "decode_only_tick_p50_ms", "decode_tick_p50_ms.chat",
+           "prefill_pack_p50_ms.chat"] + [f"idle_{phase}_share" for phase in IDLE]
+FOLDED = [f"{family}.{suffix}" for family, suffixes, _, _ in MERGED for suffix in suffixes]
 
 
 @pytest.mark.parametrize("family,suffixes,reader,params", MERGED, ids=[m[0] for m in MERGED])
 def test_a_merged_family_reads_what_its_per_cell_files_read(family, suffixes, reader, params):
     entry = next(m for m in MAN["per_layer"] if m["name"] == f"{family}.serve")
-    assert entry["workloads"][:len(suffixes)] == [SUFFIX[s] for s in suffixes]
+    assert entry["workloads"] == [SUFFIX[s] for s in suffixes]
     assert entry["moves"] == "serve_tokens_per_s"
     spec = harness.load_json(harness.HERE / "metrics" / f"{family}.serve.json")
     assert spec["reader"] == reader and spec.get("params", {}) == params
@@ -155,14 +180,44 @@ def test_a_merged_family_reads_what_its_per_cell_files_read(family, suffixes, re
     # the cells that move another end-to-end metric keep an entry of their own
     # on the same reader and parameters
     for other in sorted(n for n in names if n.rpartition(".")[0] == family and n != entry["name"]):
+        if other.rpartition(".")[2] in OTHER_PARAMETERS.get(family, ()):
+            continue
         kept = harness.load_json(harness.HERE / "metrics" / f"{other}.json")
         assert (kept["reader"], kept.get("params", {})) == (reader, params)
 
 
-def test_the_retired_metrics_are_gone_from_the_manifest_and_the_harness():
-    text = (ROOT / "BENCHMARK.json").read_text() + "".join(
-        p.read_text() for p in harness.HERE.rglob("*") if p.suffix in (".py", ".json", ".md"))
-    assert [n for n in RETIRED if n in text] == []
+@pytest.mark.parametrize("family,suffix", [(f, s) for f, ss, _, _ in MERGED for s in ss],
+                         ids=FOLDED)
+def test_a_folded_cell_loads_its_familys_one_file(family, suffix):
+    """What a traced run of the cell loads under the family's name is the
+    ``.serve`` entry, once, and no per-cell file of that family is left."""
+    loaded = [m["name"] for m in harness.metrics_of(MAN, SUFFIX[suffix], True)
+              if m["name"].rpartition(".")[0] == family]
+    keeps = suffix in OTHER_PARAMETERS.get(family, ())
+    assert loaded == [f"{family}.serve"] + ([f"{family}.{suffix}"] if keeps else [])
+    assert not (harness.HERE / "metrics" / f"{family}.{suffix}.json").exists() or keeps
+
+
+@pytest.mark.parametrize("name", RETIRED + FOLDED)
+def test_the_retired_metrics_are_gone_from_the_manifest_and_the_harness(name):
+    """A retired NAME is in no entry, no metric file and no line of the
+    harness (a folded per-cell name: in no entry and no file)."""
+    assert not [m["name"] for m in ALL_METRICS if m["name"].startswith(name)]
+    assert not list((harness.HERE / "metrics").glob(f"{name}*"))
+    if name in RETIRED:
+        text = "".join(p.read_text() for p in harness.HERE.rglob("*")
+                       if p.suffix in (".py", ".json", ".md"))
+        assert name not in text
+
+
+def test_the_readmes_count_is_one_the_list_has_reached():
+    """``benchmark/README.md`` ("The cap") states what the last ``benchmark``
+    PR left used and free; cells added since only add to it (they may not
+    edit the README).  The running count is this test's message."""
+    text = (harness.HERE / "README.md").read_text()
+    stated, free = map(int, re.search(r"holds (\d+): (\d+) are free", text).groups())
+    used = len(MAN["per_layer"])
+    assert stated + free == CAP and stated <= used <= CAP, f"{used} of {CAP} used"
 
 
 def test_layers_of_one_module_are_spelled_alike():

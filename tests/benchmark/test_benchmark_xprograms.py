@@ -4,13 +4,16 @@ a small trace recorded on the chip (``tools/record_programs_trace.py``: three
 ticks, two tracked programs, one Pallas call named ``toy_double``, every span
 mirrored into the trace)."""
 import json
+import math
 import sys
 from pathlib import Path
 
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
+import one_ahead_scenes as scenes  # noqa: E402
 from benchmark import harness, xplane, xprograms  # noqa: E402
 from benchmark.xplane import HostEvent  # noqa: E402
 from benchmark.xprograms import Execution, Programs, RawOp  # noqa: E402
@@ -73,29 +76,22 @@ def test_span_readers_read_the_tree():
                                      "sched.tick", 50) is None
 
 
-@pytest.mark.parametrize("reader", ["span_self", "span_arg", "tick_host_gap"])
-def test_readers_refuse_a_span_set_the_recorder_dropped_from(reader, monkeypatch):
+@pytest.mark.parametrize("reader", ["span_self", "span_arg", "span_sum"])
+def test_readers_refuse_a_span_set_the_recorder_dropped_from(reader):
     """The recorder's ring drops its oldest spans; the oldest one kept then
     carries ``spans_dropped``.  A whole-window median of such a set is of the
     window's end, and a parent in it may have lost children."""
     from benchmark.readers import (span_arg_percentile, span_self_percentile,
-                                   tick_host_gap)
+                                   span_sum_percentile)
 
     def read(spans):
-        obs = {"spans": spans, "window": (0.0, 20.0), "trace": object(),
-               "_xprograms": programs()}
+        obs = {"spans": spans, "window": (0.0, 20.0)}
         if reader == "span_self":
             return span_self_percentile.read(obs, "sched.tick", 50)
         if reader == "span_arg":
             return span_arg_percentile.read(obs, "decode_tick", "dispatch_ms", 50)
-        return tick_host_gap.read(
-            obs, tick="sched.tick", holding=["decode_tick"], lacking=["prefill_pack"],
-            module="^jit_decode_impl$", q=50, what="span")
+        return span_sum_percentile.read(obs, "sched.tick", "decode_tick", 50)
 
-    # the toy programs' clocks differ by seconds: pair them with as much slack
-    monkeypatch.setattr(xprograms, "skew", lambda progs, s, m: xprograms.skew_interval(
-        (h.start, h.end, e.start, e.end) for h, e in xprograms.pair(
-            progs.mirrored(s), progs.of_module(m), 3.0)))
     whole = tick_tree()
     assert xprograms.spans_dropped(whole) == 0 and xprograms.spans_dropped([]) == 0
     assert read(whole) is not None
@@ -159,34 +155,20 @@ def test_pairing_and_skew_on_programs():
     assert xprograms.skew(p, "decode_tick", "no_such_module") is None
 
 
-def test_busy_inside_shifts_and_clips():
-    runs = programs().of_module("")
-    # tick [9.8, 10.6] with device times +1.95: decode [10.05, 10.35], split [10.40, 10.41]
-    assert xprograms.busy_inside(runs, 9.8, 10.6, 1.95) == pytest.approx(0.31)
-    # the pack [13.95, 15.95] clipped at the span's start 14.0... and its end
-    assert xprograms.busy_inside(runs, 14.0, 15.0, 1.95) == pytest.approx(1.0)
-    assert xprograms.busy_inside(runs, 30.0, 31.0, 1.95) == 0.0
+def test_host_device_skew_reads_the_edge_nearer_to_zero(monkeypatch):
+    from benchmark.readers import host_device_skew
 
-
-def test_tick_host_gap_takes_decode_only_ticks(monkeypatch):
-    from benchmark.readers import host_device_skew, tick_host_gap
-
-    spans = [span("sched.tick", 0.0, 0.8, 1), span("decode_tick", 0.2, 0.7, 5, 1),
-             span("sched.tick", 4.1, 10.8, 11), span("prefill_pack", 4.2, 6.2, 12, 11),
-             span("decode_tick", 10.2, 10.7, 15, 11)]
-    obs = {"spans": spans, "trace": object(), "_xprograms": programs()}
-    params = dict(tick="sched.tick", holding=["decode_tick"], lacking=["prefill_pack"],
-                  module="^jit_decode_impl$", q=50)
-    # only tick 1 qualifies: 0.8 s of span minus 0.31 s of device inside it
-    monkeypatch.setattr(xprograms, "skew",
-                        lambda progs, s, m, slack_s=3.0: xprograms.skew_interval(
-                            (h.start, h.end, e.start, e.end) for h, e in xprograms.pair(
-                                progs.mirrored(s), progs.of_module(m), 3.0)))
-    assert tick_host_gap.read(obs, **params) == pytest.approx(490.0)
+    obs = {"spans": [], "trace": object(), "_xprograms": programs()}
+    # the toy programs' clocks differ by seconds: pair them with as much slack
+    wide = xprograms.skew
+    monkeypatch.setattr(xprograms, "skew", lambda progs, s, m, spans=(): wide(progs, s, m, 3.0, spans))
     assert host_device_skew.read(obs, "decode_tick", "^jit_decode_impl$") == pytest.approx(1950.0)
+    assert xprograms.tight_edge((-0.0073, 0.0016)) == 0.0016   # one ahead: the upper edge
+    assert xprograms.tight_edge((0.0008, 0.0051)) == 0.0008    # the host sets the pace
+    assert xprograms.tight_edge((-0.0002, math.inf)) == -0.0002  # nothing was fetched
     # no trace, no device plane: nothing to read
-    assert tick_host_gap.read({"spans": spans, "trace": None}, **params) is None
     assert host_device_skew.read({"_xprograms": None}, "decode_tick", "x") is None
+    assert host_device_skew.read(obs, "decode_tick", "no_such_module") is None
 
 
 # -- hand-made: scope classes ------------------------------------------------
@@ -288,11 +270,30 @@ def test_recorded_skew_interval_is_not_empty(recorded):
     # shifted, every execution lies inside the span that dispatched it
     for h, e in pairs:
         assert h.start <= e.start + lo and e.end + lo <= h.end + 1e-9
-        busy = xprograms.busy_inside([e], h.start, h.end, lo)
-        assert busy == pytest.approx(e.end - e.start)
     # the other program, against its own spans, allows an overlapping interval
     lo2, hi2 = xprograms.skew(progs, "train_tick", "^jit_train_step$", slack_s=0.003)
     assert max(lo, lo2) <= min(hi, hi2)
+
+
+@pytest.mark.parametrize("span,module", [("decode_tick", "^jit_serve_step$"),
+                                         ("train_tick", "^jit_train_step$")])
+def test_recorded_spans_pair_as_their_mirrors_do(recorded, span, module):
+    """The recorder's spans of the recorded run (back to back: no ``ahead``, no
+    collect, every fetch inside its span) laid on the trace's clock give the
+    interval their mirrors give, and it is not empty."""
+    progs, spans, _ = recorded
+    iv = xprograms.skew(progs, span, module, slack_s=0.003, spans=spans)
+    assert iv is not None and iv[0] <= iv[1] < math.inf
+    assert iv == pytest.approx(xprograms.skew(progs, span, module, slack_s=0.003), abs=1e-9)
+    lo, hi = xprograms.skew_interval(
+        (h.start, h.end, e.start, e.end) for h, e in xprograms.pair(
+            progs.mirrored(span), progs.of_module(module), 0.003))   # all three spans
+    assert iv[0] <= lo <= hi <= iv[1]
+    assert abs(xprograms.tight_edge(iv)) < 10e-3
+    pairs = xprograms.dispatched(progs, spans, {span: module}, 0.003)
+    # (a span whose execution lies within 3 ms of the capture's edge is left out)
+    assert 2 <= len(pairs) <= 3 and all(at == h.end for h, _, at in pairs)
+    assert xprograms.returned(xprograms.on_trace_clock(progs, spans)) == {}   # nothing is booked
 
 
 def test_recorded_ops_classify_by_scope_and_name_the_kernel(recorded):
@@ -353,3 +354,192 @@ def test_another_runs_trace_file_is_refused(tmp_path, monkeypatch):
     with pytest.raises(RuntimeError, match="another capture"):
         xprograms.of({"trace": tr})
     assert xprograms.of({"trace": None}) is None
+
+
+# -- hand-made: one ahead (PR 43) ---------------------------------------------
+ENGINE = {"decode_tick": r"^jit_decode_impl$", "prefill_pack": r"^jit_packed(_ctx)?_impl$"}
+SCENES = sorted(scenes.SCENES)
+
+
+@pytest.fixture(scope="module", params=SCENES)
+def scene(request):
+    s = scenes.SCENES[request.param]()
+    return (s,) + s.programs()
+
+
+def test_a_dispatch_pairs_with_the_execution_it_enqueued(scene):
+    """Whatever the order (the pipeline full, a drain, a pack beside a step,
+    pack-only and step-only executions by turns, back to back, the host
+    setting the pace): a pair is the TRUE one, and all but the scene's edges
+    pair (the call that enqueues two, what is never collected)."""
+    s, progs, spans = scene
+    pairs = xprograms.dispatched(progs, spans, ENGINE)
+    got = {h.stats["span_id"]: e.run_id for h, e, _ in pairs}
+    assert got and all(s.truth[i] == run for i, run in got.items())
+    fetched = xprograms.returned(xprograms.on_trace_clock(progs, spans))
+    assert len(got) >= len(fetched) - 2
+    assert len(set(got.values())) == len(got)
+
+
+@pytest.mark.parametrize("span", sorted(ENGINE))
+def test_the_causality_interval_holds_the_true_shift(scene, span):
+    s, progs, spans = scene
+    iv = xprograms.skew(progs, span, ENGINE[span], spans=spans)
+    if iv is None:   # a scene without this kind of dispatch
+        assert not any(n == span for n, _, _, _ in spans)
+        return
+    assert iv[0] <= s.shift <= iv[1]
+    # the edge taken is within a launch or a fetch of the truth
+    assert xprograms.tight_edge(iv) == pytest.approx(s.shift, abs=1e-3)
+
+
+def test_a_collect_returns_one_dispatchs_result_oldest_first(scene):
+    s, progs, spans = scene
+    hosts = xprograms.on_trace_clock(progs, spans)
+    at = xprograms.returned(hosts)
+    by_id = {h.stats["span_id"]: h for h in hosts}
+    collects = sorted(h.end for h in hosts if h.name == xprograms.COLLECT)
+    assert set(at) <= set(s.truth) and sorted(at.values()) == collects
+    for i, t in at.items():   # a result fetched after its span closed, by a collect of its kind
+        assert not by_id[i].stats.get("synced", True) and t > by_id[i].end
+    for kind in ENGINE:
+        ends = [at[h.stats["span_id"]] for h in hosts if h.name == kind and h.stats["span_id"] in at]
+        assert ends == sorted(ends)
+    # what the pairs carry: a fetch inside the span, its collect, or never
+    for h, _, held in xprograms.dispatched(progs, spans, ENGINE):
+        i = h.stats["span_id"]
+        assert held == (h.end if h.stats.get("synced", True) else at.get(i, math.inf))
+
+
+def test_mirrors_alone_pair_only_what_was_dispatched_back_to_back(scene):
+    """Without the recorder's spans no collect is known: a span dispatched one
+    ahead is left out, not paired by the old rule with the execution that
+    happens to start while it is open."""
+    s, progs, _ = scene
+    pairs = xprograms.dispatched(progs, (), ENGINE)
+    assert all(int(h.stats.get("ahead", 0)) == 0 for h, _, _ in pairs)
+    assert all(s.truth[int(h.stats["span_id"])] == e.run_id for h, e, _ in pairs)
+
+
+def test_a_record_that_lost_its_oldest_spans_pairs_nothing(scene):
+    """Bookings are COUNTED against dispatches from the start of the record:
+    one that begins in the middle has lost the count."""
+    _, progs, spans = scene
+    name, a, b, args = spans[0]
+    short = [(name, a, b, dict(args, spans_dropped=5))] + list(spans[1:])
+    assert xprograms.dispatched(progs, short, ENGINE) == []
+    assert xprograms.skew(progs, "decode_tick", ENGINE["decode_tick"], spans=short) is None
+
+
+def test_a_span_whose_mirror_is_missing_is_placed_by_the_clocks_offset(scene):
+    s, progs, spans = scene
+    some = s.programs(unmirrored=set(range(1, 40)))[0]
+    whole = xprograms.skew(progs, "decode_tick", ENGINE["decode_tick"], spans=spans)
+    assert xprograms.skew(some, "decode_tick", ENGINE["decode_tick"], spans=spans) \
+        == pytest.approx(whole, abs=1e-9)
+
+
+def test_two_spans_that_claim_one_execution_are_left_out():
+    """A pause of the host between an enqueue and the collect of the execution
+    before it: by the time that collect returns BOTH have ended, and the last
+    one ended is not the collected one.  Two spans then claim it; neither is
+    paired."""
+    runs = [Execution("jit_decode_impl", n, 10.0 + 0.01 * n, 10.009 + 0.01 * n) for n in range(4)]
+    hosts = [HostEvent("decode_tick", 9.99 + 0.01 * n, 9.991 + 0.01 * n,
+                       {"span_id": n, "ahead": 1}) for n in range(4)]
+    at = {0: 10.0095, 1: 10.0295, 2: 10.0296, 3: 10.0395}   # span 1's collect came 10 ms late
+    pairs = xprograms.pair(hosts, runs, 0.010, at)
+    assert [(h.stats["span_id"], e.run_id) for h, e in pairs] == [(0, 0), (3, 3)]
+    # a span never fetched, and one whose collect nothing had ended by, pair with nothing
+    assert xprograms.pair(hosts[:1], runs, 0.010, {}) == []
+    assert xprograms.pair(hosts[:1], runs, 0.010, {0: 9.995}) == []
+
+
+def test_the_hosts_slack_is_the_time_inside_a_ticks_collects():
+    from benchmark.readers import span_sum_percentile
+
+    s = scenes.pack_and_step()
+    _, spans = s.programs()
+    t0, t1 = min(a for _, a, _, _ in spans), max(b for _, _, b, _ in spans) + 1.0
+    obs = {"spans": spans, "window": (t0, t1)}
+    ticks = [t for t in spans if t[0] == "sched.tick"]
+    below = xprograms.descendants(spans)
+    want = [sum(b - a for n, a, b, _ in below[t[3]["span_id"]] if n == "tick_collect")
+            for t in ticks]
+    assert sum(want) == pytest.approx(sum(s.waits)) and all(w > 0 for w in want)
+    got = span_sum_percentile.read(obs, "sched.tick", "tick_collect", 50)
+    assert got == pytest.approx(1e3 * sorted(want)[len(want) // 2], rel=0.2)
+    assert 0 < got < 1e3 * (scenes.STEP + scenes.PACK)   # a wait is shorter than what it waits for
+    # the device sets the pace: the slack is most of a tick; the host: next to nothing
+    tick_ms = 1e3 * (ticks[-1][2] - ticks[-1][1])
+    assert got > 0.8 * tick_ms
+    bound = scenes.host_bound()
+    _, spans = bound.programs()
+    obs = {"spans": spans, "window": (t0, t1)}
+    assert span_sum_percentile.read(obs, "sched.tick", "tick_collect", 50) < 0.1
+    # a program that never waits apart from its dispatch: nothing to read
+    _, spans = scenes.old_order().programs()
+    assert span_sum_percentile.read({"spans": spans, "window": (t0, t1)},
+                                    "sched.tick", "tick_collect", 50) is None
+
+
+def test_a_stalled_gap_is_the_one_its_packs_execution_made_longer():
+    """One ahead a ``prefill_pack`` span opens one call BEFORE the call that
+    returns its execution's tokens; the booking of its result lies in that
+    call.  Tokens are stamped where a call returns."""
+    from benchmark.readers import stall_share
+
+    s = scenes.Scene()
+    step, pack = [("decode_tick", scenes.STEP, True)], ("prefill_pack", scenes.PACK, False)
+    stamps, held = [], []
+    plan = [step, step, [pack] + step, step, step, [pack] + step, step, step]
+    s.call(plan[1], first=plan[0])
+    collected = 0
+    stamps.append(s.t)
+    for ex in plan[2:] + [None, None]:
+        s.call(ex)
+        collected += 1
+        stamps.append(s.t)
+        if collected < len(plan) and len(plan[collected]) == 2:
+            held.append(len(stamps) - 1)      # the gap that ENDS at this stamp
+    _, spans = s.programs()
+    stamps = [t + scenes.RECORDER for t in stamps]
+    obs = {"spans": spans, "window": (stamps[0], stamps[-1] + 1.0),
+           "requests": [{"token_times": stamps}]}
+    gaps = [b - a for a, b in zip(stamps, stamps[1:])]
+    long = [i + 1 for i, g in enumerate(gaps) if g > 0.015]
+    assert long == held and len(held) == 2            # the gaps a pack made longer
+    assert stall_share.read(obs, "engine.pack_emit") == pytest.approx(100 * 2 / len(gaps))
+    # the dispatch span's start marks as many gaps, each one too early
+    starts = sorted(a for n, a, _, _ in spans if n == "prefill_pack")
+    marked = [next(i + 1 for i, (a, b) in enumerate(zip(stamps, stamps[1:])) if a <= t < b)
+              for t in starts]
+    assert marked == [i - 1 for i in held]
+    assert stall_share.read(obs, "prefill_pack") == pytest.approx(100 * 2 / len(gaps))
+    # back to back the two coincide
+    old = scenes.old_order()
+    _, spans = old.programs()
+    ticks = [t[2] for t in spans if t[0] == "sched.tick"]
+    obs = {"spans": spans, "window": (ticks[0], ticks[-1] + 1.0),
+           "requests": [{"token_times": ticks}]}
+    assert stall_share.read(obs, "engine.pack_emit") == stall_share.read(obs, "prefill_pack") == 100.0
+
+
+def test_small_programs_are_counted_in_the_tick_they_start_in_one_ahead():
+    from benchmark.readers import executions_per_span
+
+    s = scenes.Scene()
+    step = [("decode_tick", scenes.STEP, True)]
+    s.call(step, first=step)
+    for k in range(6):
+        if k % 2:
+            s.aux("jit__threefry_split")
+        s.call(step)
+    progs, spans = s.programs()
+    obs = {"trace": object(), "_xprograms": progs, "spans": spans}
+    params = dict(span="sched.tick", dispatch="decode_tick", module="^jit_decode_impl$",
+                  excluding="^jit_(packed|packed_ctx|decode|decode_burst|spec|cow)_impl$")
+    assert executions_per_span.read(obs, q=50, **params) == 0
+    assert executions_per_span.read(obs, q=100, **params) == 1
+    # with the record the parent's pairing saw (no collect known): nothing to read
+    assert executions_per_span.read(dict(obs, spans=[]), q=50, **params) is None
