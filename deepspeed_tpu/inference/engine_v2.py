@@ -172,6 +172,10 @@ class InferenceEngineV2:
 
             # a slot's state is a recurrence's (state-space or delta rule), not a ring
             states = cfg.latent.stateful and not cfg.latent.ringed
+            # latent PAGES alone (no ring, no recurrence, no index keys beside
+            # them) are shared as a dense model's K / V pages are: a pack's
+            # chunk that starts at a position > 0 reads the pages under it
+            pages_alone = not (cfg.latent.ringed or cfg.latent.stateful or cfg.latent.indexed)
             for option, on, why in (
                 ("grid (a tensor-parallel / replica / seq-shard serve mesh)",
                  grid is not None or int(serve_replicas) > 1 or int(seq_shards) > 1,
@@ -182,7 +186,7 @@ class InferenceEngineV2:
                 ("quantize_weights (and int8 / fp8 KV)", quantize_weights is not None,
                  "its projections, states and pages have no quantized form yet" if states
                  else "its projections and latent rows have no quantized form yet"),
-                ("enable_prefix_caching", enable_prefix_caching,
+                ("enable_prefix_caching", enable_prefix_caching and not pages_alone,
                  "a cached prefix would have to bring a state snapshot with its pages"
                  if states else
                  "a cached prefix would have to bring a window's ring with its pages"),

@@ -6,6 +6,11 @@ attends over.
 - a ``full`` layer keeps every position's latent row (``kv_rank + rope`` wide,
   keys and values in one array) in PAGES, block ids and block tables the
   allocator's own, and the indexer's key page beside it;
+- an ``every`` layer (latent attention over EVERY cached row) keeps the same
+  latent pages and nothing beside them: no index keys, no ring.  A model of
+  such layers alone is one whose blocks a prefix cache may share (a pack's
+  chunk that starts at a position > 0 reads the pages under it, whoever wrote
+  them);
 - a ``sliding`` layer keeps a RING per slot, not pages: position ``p`` of slot
   ``n`` lives in row ``p % R`` of ``win[n]``, ``R`` = one pack + the window's
   look-back, so a pack's rows can be written before it attends without
@@ -54,9 +59,11 @@ the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
 as are chunks of one prompt and several prompts in one pack: work is laid out
 in groups of one page of one sequence.  Plain XLA bodies
-(``ops/latent_attention.py``) but for three Pallas kernels on the chip: a
+(``ops/latent_attention.py``) but for four Pallas kernels on the chip: a
 pack's index scores (``ops/pallas/index_scores.py``), its shorter groups'
-attention over their picks (``ops/pallas/selected_attention.py``) and the
+attention over their picks (``ops/pallas/selected_attention.py``: an ``every``
+layer's pack walks its pages through the same kernel, its causal positions
+the mask), an ``every`` layer's tick (``ops/pallas/latent_decode.py``) and the
 expert layer's grouped matmul (``moe/layer.py``).
 
 ``LatentRunner`` is what ``InferenceEngineV2`` holds for such a model (as
@@ -75,6 +82,7 @@ import numpy as np
 from ..models import latent as lm
 from ..ops import latent_attention as la
 from ..ops.pallas import index_scores as index_kernel
+from ..ops.pallas import latent_decode as decode_kernel
 from ..ops.pallas import selected_attention as selected_kernel
 from ..ops.pallas import note_dispatch, on_tpu
 
@@ -110,6 +118,17 @@ WINDOW_COUNTERS = (
     "window_keys_attended",   # ... of the layers over a window: min(position + 1, window)
     "causal_keys",            # what the window layers would attend if they were full
     "window_rows_discarded",  # ring rows that fell out of a window
+    "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
+    "expert_group_rows_min", "experts_touched", "experts_touched_decode",
+    "expert_pairs_held_decode",
+)
+
+
+# ... of one whose layers attend EVERY cached latent row (pages alone): all counted
+# on the host at dispatch but the routing four
+MLA_COUNTERS = (
+    "mla_keys_attended",         # (query, key) pairs of the layers over every row: causal keys
+    "mla_keys_attended_decode",  # ... of them, those of decode ticks
     "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
     "expert_group_rows_min", "experts_touched", "experts_touched_decode",
     "expert_pairs_held_decode",
@@ -154,18 +173,26 @@ def init_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
         return _init_state_cache(cfg, num_blocks, block_size, max_seqs, pack_tokens, dtype)
     pages = lambda w, n: tuple(
         jnp.zeros((num_blocks, block_size, w), dtype) for _ in range(n))
-    chunks = max_seqs * ring_rows(cfg, block_size, pack_tokens) // block_size
     n_moe = max(cfg.num_layers - s.first_dense, 0)
     stats = jnp.zeros((n_moe, len(ROUTING_STATS)), jnp.int32)
+    paged = "full" if s.indexed else "every"  # the kind whose rows live in pages
+    win = ()
+    if s.ringed:
+        chunks = max_seqs * ring_rows(cfg, block_size, pack_tokens) // block_size
+        win = tuple(jnp.zeros((chunks, block_size, s.sliding.row), dtype)
+                    for _ in range(s.count("sliding")))
+    # a model of pages alone counts the held experts it touched, of packs [0]
+    # and of ticks [1], as the stateful models do (``_init_state_cache``)
+    touched = {} if s.indexed or s.ringed else {"touched": jnp.zeros((n_moe, 2, 2), jnp.int32)}
     return {
-        "lat": pages(_lanes(s.full.row), s.count("full")),
+        "lat": pages(_lanes(s.attn(paged).row), s.count(paged)),
         "idx": pages(s.index_dim, s.count("full")),
-        "win": tuple(jnp.zeros((chunks, block_size, s.sliding.row), dtype)
-                     for _ in range(s.count("sliding"))),
+        "win": win,
         "stats": stats.at[:, 3].set(_NO_MIN),
         # keys selected so far, one row per full layer: a running count in two
         # int32 words (high, low 30 bits), since a window's sum passes 2^31
         "picks": jnp.zeros((s.count("full"), 2), jnp.int32),
+        **touched,
     }
 
 
@@ -291,11 +318,35 @@ def _attend_selected(s, q_abs, q_i, w, q_pos, tables, lat, idx, real, picked, pr
     return o
 
 
+def _attend_every(a, q_abs, lat, tables, live, q_pos):
+    """An ``every`` layer's pack: groups [G, C, ...] of absorbed queries, each
+    over EVERY cached row of its sequence (``tables`` [G, P]) up to its own
+    position.  Two schedules of the same softmax, under one scope
+    (``mla_prefill``): where the Pallas kernel ``selected_attn`` takes the
+    shape, each group WALKS its sequence's live pages in place with the causal
+    positions as its mask (no row is copied, no score block reaches HBM);
+    every other shape, and the CPU, takes the XLA body
+    (``la.dense_attention_pack``).  Returns latent rows [G, C, H, r_kv]."""
+    _, c, h, _ = q_abs.shape
+    bs, lanes = lat.shape[1], lat.shape[2]
+    q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, lanes - a.row),))
+    if not _selected_kernel_takes(c, h, lanes, a.kv_rank, bs):
+        return la.dense_attention_pack(q_abs, lat, tables, live, q_pos, a)
+    with jax.named_scope("mla_prefill"):
+        own = jnp.maximum(tables, 0)
+        keys = jnp.arange(own.shape[1] * bs)
+        mask = (keys[None, None, :] <= q_pos[:, :, None]).astype(jnp.int8)
+        last = jnp.max(q_pos, axis=1)
+        o = selected_kernel.selected_attention(
+            q_abs, mask, lat, own, jnp.where(live, last // bs + 1, 0), a.kv_rank, a.scale)
+        return jnp.where(live[:, None, None, None], o, 0)  # a dead page of the pack is left unwritten
+
+
 def _kernel_takes(name: str, kernel, shape, declines: str) -> bool:
     """A Pallas kernel's gate by shape (``kernel.supports(*shape)``, on a TPU
-    or interpreted), noted for ``record_dispatch()``.  Both kernels serve a
-    pack's pages of queries; a decode tick's single rows stay on the XLA
-    bodies by design and never ask."""
+    or interpreted), noted for ``record_dispatch()``.  The selector's two
+    kernels serve a pack's pages of queries (a decode tick's single rows stay on
+    its XLA bodies by design and never ask); ``latent_decode`` serves a tick's."""
     interpret = kernel.interpret()
     if not (interpret or on_tpu()):
         reason = "not on a TPU"
@@ -314,6 +365,12 @@ def _selected_kernel_takes(c: int, h: int, w: int, r_kv: int, bs: int) -> bool:
     (``latent_attention.DENSE_KEYS_MAX``)."""
     return _kernel_takes("selected_attn", selected_kernel, (c, h, w, r_kv, bs),
                          "whole query tiles; page, row and value lanes whole 128-lane tiles")
+
+
+def _decode_kernel_takes(h: int, w: int, r_kv: int, bs: int) -> bool:
+    """The gate of the Pallas kernel for a tick's rows over every cached row."""
+    return _kernel_takes("latent_decode", decode_kernel, (h, w, r_kv, bs),
+                         "page, row and value lanes whole 128-lane tiles, heads whole sublane tiles")
 
 
 def _index_kernel_takes(c: int, j: int, d: int, bs: int) -> bool:
@@ -340,11 +397,16 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, prob
     if kind == "full":
         q_i, k_i, w = lm.indexer_inputs(aw, h, c_q, pos, s, cfg)
         keys, rows, queries = ("lat", "idx"), (row, k_i), (q_abs, q_i, w)
+    elif kind == "every":
+        keys = ("lat",)
     arrays = write(kind, tuple(cache[k][i] for k in keys), rows)
     cache = {**cache, **{k: _put(cache[k], i, v) for k, v in zip(keys, arrays)}}
     o = read(kind, arrays, queries)
     x = x + lm.attn_output(aw, o, gate, a).astype(x.dtype)
     h = lm.rms(x, n2["scale"], cfg.norm_eps)
+    if is_moe and "touched" in cache:
+        y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, track_groups, probe)
+        return x + y.astype(x.dtype), cache
     y, routing = lm.ffn(fw, h, is_moe, cfg, valid)
     if routing is not None:
         routed, picked = routing
@@ -503,10 +565,10 @@ def _latent_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, ca
     q_pos = grouped(positions)
     safe_pages = jnp.where(pack_pages >= 0, pack_pages, nb)
     rc = ring // bs
-    back = -(-(s.sliding.window - 1) // bs)
+    back = -(-(s.sliding.window - 1) // bs) if ring else 0
 
     def write(kind, arrays, rows):
-        if kind == "full":
+        if kind != "sliding":  # pages: a full layer's two arrays, an every layer's one
             return tuple(a.at[safe_pages].set(grouped(_fit(r, a)), mode="drop")
                          for a, r in zip(arrays, rows))
         (win,), (row,) = arrays, rows
@@ -518,6 +580,8 @@ def _latent_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, ca
             q_abs, q_i, w = map(grouped, queries)
             o = _attend_selected(s, q_abs, q_i, w, q_pos, tables[slot], *arrays,
                                  grouped(valid), picked, probe=probe)
+        elif kind == "every":
+            o = _attend_every(s.every, grouped(queries[0]), arrays[0], tables[slot], live, q_pos)
         else:
             # the group's own page and the ``back`` pages before it, from the ring
             j = jnp.arange(back + 1)
@@ -656,7 +720,7 @@ def _latent_tick_seam(cfg, pos, block_tables, active, picked, probe):
     rows = jnp.arange(b)
 
     def write(kind, arrays, new):
-        if kind == "full":
+        if kind != "sliding":
             nb, bs, _ = arrays[0].shape
             page = jnp.take_along_axis(block_tables, (pos // bs)[:, None], axis=1)[:, 0]
             page = jnp.where(active & (page >= 0), page, nb)
@@ -674,6 +738,15 @@ def _latent_tick_seam(cfg, pos, block_tables, active, picked, probe):
             q_abs, q_i, w = map(one, queries)
             return _attend_selected(s, q_abs, q_i, w, one(pos), block_tables, *arrays,
                                     one(active), picked, probe=probe)[:, 0]
+        if kind == "every":
+            a, lat = s.every, arrays[0]
+            q_abs = jnp.pad(queries[0], ((0, 0), (0, 0), (0, lat.shape[-1] - a.row)))
+            lens = jnp.where(active, pos + 1, 0)
+            if _decode_kernel_takes(a.num_heads, lat.shape[-1], a.kv_rank, lat.shape[1]):
+                with jax.named_scope("mla_decode"):  # each slot walks its own pages in place
+                    return decode_kernel.latent_decode(q_abs, lat, jnp.maximum(block_tables, 0),
+                                                       lens, a.kv_rank, a.scale)
+            return la.dense_attention_step(q_abs, lat, block_tables, lens, a)
         win = arrays[0]
         ring = win.shape[0] * win.shape[1] // b
         keys = win.reshape(b, ring, win.shape[-1])
@@ -755,6 +828,8 @@ class LatentRunner:
         if cfg.latent.stateful:
             self.counters = WINDOW_COUNTERS if cfg.latent.ringed else STATE_COUNTERS
             self._discarded = 0  # states a preemption left behind since the last dispatch
+        elif cfg.latent.every is not None:
+            self.counters = MLA_COUNTERS
 
     def init_cache(self, num_blocks, block_size, max_seqs, pack_tokens) -> Cache:
         self._block = block_size
@@ -802,6 +877,8 @@ class LatentRunner:
             return self._windows_dispatched(counters, work)
         if s.stateful:
             return self._states_dispatched(counters, work, pack)
+        if s.every is not None:
+            return self._every_dispatched(counters, work, pack)
         topk, win, bs = s.index_topk, s.sliding.window, self._block
         scored = selected = dropped = groups = dense = 0
         for slot, a, b in work:
@@ -825,6 +902,16 @@ class LatentRunner:
                   "selected_groups_dense"):
             counters[k].inc(out.get(k, 0))
         return out
+
+    def _every_dispatched(self, counters, work, pack: bool) -> Dict[str, int]:
+        """Latent attention over every cached row: a query at position ``p``
+        attends ``p + 1`` keys a layer."""
+        n = self.cfg.latent.count("every")
+        keys = sum((hi * (hi + 1) - lo * (lo + 1)) // 2 for _, lo, hi in work)  # sum of p + 1
+        counters["mla_keys_attended"].inc(keys * n)
+        if not pack:
+            counters["mla_keys_attended_decode"].inc(keys * n)
+        return {"mla_keys": keys * n}
 
     def _windows_dispatched(self, counters, work) -> Dict[str, int]:
         """Gated GQA of two kinds: a query at position ``p`` attends ``p + 1``
@@ -889,9 +976,9 @@ class LatentRunner:
     def refresh_stats(self, counters, kv: Cache) -> None:
         """The selectors' and routers' device-side counts into ``counters``
         (two small device->host copies)."""
-        if "picks" in kv:
+        if self.cfg.latent.indexed:
             counters["index_keys_selected"].set(picks_total(kv["picks"]))
-        else:
+        if "touched" in kv:
             touched = np.asarray(kv["touched"]).astype(np.int64).sum(0)  # [pack | tick, 2]
             counters["experts_touched"].set(int(touched[:, 0].sum()))
             counters["experts_touched_decode"].set(int(touched[1, 0]))

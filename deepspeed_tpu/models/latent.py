@@ -1,12 +1,18 @@
 """Decoder whose layers are of several KINDS (``TransformerConfig.latent``):
 latent attention (MLA) on every layer, either over the keys a learned indexer
-selects (``full``) or over a sliding window with its own ranks and head count
-(``sliding``); a dense SwiGLU on the leading layers and sigmoid-routed experts
-with a shared expert after them, of which this process may hold a SHARE
-(``moe/layer.py:moe_block_held``).
+selects (``full``), over a sliding window with its own ranks and head count
+(``sliding``) or over EVERY cached row (``every``: DeepSeek-V2's layer, which
+keeps latent pages and nothing else); a dense SwiGLU on the leading layers and
+routed experts with a shared expert after them, of which this process may hold
+a SHARE (``moe/layer.py:moe_block_held``).  What differs between the models of
+this family is VALUES of the spec too: YaRN on the rope dims and the softmax
+scale's factor that goes with it (``LatentAttn.rope_scaling``,
+``scale_factor``), the headwise output gate there or not (``LatentAttn.gate``),
+the routing sigmoid + bias or a softmax limited to a few groups of experts
+(``routing``, ``n_group``, ``topk_group``).
 
 Parameters are grouped per kind (``layers/full``, ``layers/sliding``,
-``layers/mlp``, ``layers/moe``: a tuple with one tree per layer of the kind,
+``layers/every``, ``layers/mlp``, ``layers/moe``: a tuple with one tree per layer of the kind,
 NOT one stacked array, because a slice of a stacked array handed to a Pallas
 kernel is copied first: 6 GB a dispatch for the experts; the two norms are
 stacked ``[L, d]``); ``layer_params`` picks layer ``l``'s trees.  The per-token halves of a layer (``attn_inputs``,
@@ -65,7 +71,10 @@ class LatentAttn:
     rope_dim: int
     v_dim: int
     rope_theta: float
-    window: int = 0  # 0: the indexer selects the keys; n: the last n positions
+    window: int = 0  # 0: the layer's kind says which keys; n: the last n positions
+    rope_scaling: Optional["Yarn"] = None
+    scale_factor: float = 1.0  # the softmax scale times this (YaRN's mscale^2)
+    gate: bool = True          # a sigmoid scalar a head on the output (``w_g``)
 
     @property
     def row(self) -> int:  # what the cache keeps per key
@@ -73,7 +82,7 @@ class LatentAttn:
 
     @property
     def scale(self) -> float:
-        return float(self.nope_dim + self.rope_dim) ** -0.5
+        return float(self.nope_dim + self.rope_dim) ** -0.5 * self.scale_factor
 
 
 SINGLE = ("mamba", "gqa", "experts")  # kinds of a block that is one norm, one mixer
@@ -186,7 +195,7 @@ class GatedGqa:
 
 @dataclass(frozen=True)
 class LatentSpec:
-    layer_kinds: Tuple[str, ...]  # 'full' | 'sliding', or SINGLE's, or HYBRID's: one per layer held
+    layer_kinds: Tuple[str, ...]  # 'full' | 'sliding' | 'every', or SINGLE's, or HYBRID's: one per layer held
     full: LatentAttn
     sliding: LatentAttn
     index_heads: int
@@ -208,10 +217,16 @@ class LatentSpec:
     shared_width: int = 0        # the shared expert's width, if not moe_width * n_shared
     gdn: Optional[Gdn] = None
     gattn: Optional[GatedGqa] = None
-    routing: str = "sigmoid"     # 'sigmoid': + bias, the picked normalised | 'softmax': over all, the picked renormalised
+    # 'sigmoid': + bias, the picked normalised | 'softmax': over all, the picked renormalised
+    # | 'group_limited': softmax over all, the picked from the ``topk_group`` groups (of
+    # ``n_group``) of largest maximum, NOT renormalised, times ``routed_scale``
+    routing: str = "sigmoid"
     shared_gate: bool = False    # the shared expert's output times sigmoid(x . w_sg)
     unit_offset: bool = False    # RMSNorm's weights are zero-centred: x^ (1 + w)
     wattn: Optional[GatedGqa] = None  # a second kind of gated attention, over a window
+    every: Optional[LatentAttn] = None  # latent attention over every cached row
+    n_group: int = 0
+    topk_group: int = 0
 
     @property
     def single(self) -> bool:
@@ -257,8 +272,13 @@ class LatentSpec:
         """Some layer keeps a ring of its window's rows per slot."""
         return any(k in ("sliding", "wattn") for k in self.layer_kinds)
 
+    @property
+    def indexed(self) -> bool:
+        """Some layer keeps an index key a position beside its latent row."""
+        return "full" in self.layer_kinds
+
     def attn(self, kind: str) -> LatentAttn:
-        return self.full if kind == "full" else self.sliding
+        return getattr(self, kind)  # 'full' | 'sliding' | 'every'
 
     def count(self, kind: str) -> int:
         return sum(k == kind for k in self.layer_kinds)
@@ -270,11 +290,14 @@ class LatentSpec:
 
 def _attn_shapes(d: int, a: LatentAttn) -> Dict[str, tuple]:
     h = a.num_heads
-    return {
+    out = {
         "w_dq": (d, a.q_rank), "w_uq": (a.q_rank, h * (a.nope_dim + a.rope_dim)),
         "w_dkv": (d, a.row), "w_uk": (a.kv_rank, h * a.nope_dim),
         "w_uv": (a.kv_rank, h * a.v_dim), "w_g": (d, h), "wo": (h * a.v_dim, d),
     }
+    if not a.gate:
+        del out["w_g"]
+    return out
 
 
 def _index_shapes(d: int, s: LatentSpec) -> Dict[str, tuple]:
@@ -351,7 +374,7 @@ def param_count(cfg) -> int:
         if l < s.first_dense:
             n += 3 * d * cfg.intermediate_size
         else:
-            n += d * s.n_routed + s.n_routed
+            n += d * s.n_routed + (s.n_routed if s.routing == "sigmoid" else 0)
             n += 3 * d * s.moe_width * (s.n_held + s.n_shared)
     return n
 
@@ -443,12 +466,13 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         return w
 
     def experts():
+        # the selection bias: small and non-zero, so that it decides some
+        # selections and no expert's score drowns in it (a softmax router has none)
+        # (drawn AFTER the router's matrix: the order of the keys is the seed's weights)
+        bias = lambda: {"bias": (0.02 * jax.random.normal(next(keys), (s.n_routed,))
+                                 ).astype(jnp.float32)} if s.routing == "sigmoid" else {}
         return {
-            "router": dense((d, s.n_routed), d),
-            # the selection bias: small and non-zero, so that it decides some
-            # selections and no expert's score drowns in it
-            "bias": (0.02 * jax.random.normal(next(keys), (s.n_routed,))
-                     ).astype(jnp.float32),
+            "router": dense((d, s.n_routed), d), **bias(),
             "w_gate": dense((s.n_held, d, fm), d),
             "w_up": dense((s.n_held, d, fm), d),
             "w_down": dense((s.n_held, fm, d), fm),
@@ -462,7 +486,7 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
     }
     nd, nm = min(s.first_dense, L), max(L - s.first_dense, 0)
     f, fm, fs = cfg.intermediate_size, s.moe_width, s.moe_width * s.n_shared
-    for kind in ("full", "sliding"):
+    for kind in ("full", "sliding", "every"):
         layers[kind] = tuple(attn(kind) for _ in range(s.count(kind)))
     layers["mlp"] = tuple(
         {"w_gate": dense((d, f), d), "w_up": dense((d, f), d), "w_down": dense((f, d), f)}
@@ -549,19 +573,20 @@ def _rope(x, pos, theta: float, scaling: Optional[Yarn] = None):
 
 def attn_inputs(aw, h, pos, a: LatentAttn, cfg):
     """Normed input ``h`` [T, d] -> (c_q [T, r_q], absorbed queries [T, H,
-    row], the key's cache row [T, row], gate [T, H])."""
+    row], the key's cache row [T, row], gate [T, H] or None where the kind has
+    none)."""
     t, d, eps = h.shape[0], cfg.hidden_size, cfg.norm_eps
     up = lambda r: (d / r) ** 0.5 if cfg.latent.rescale_lora else 1.0
     c_q = rms(h @ aw["w_dq"], aw["q_norm"], eps) * jnp.asarray(up(a.q_rank), h.dtype)
     q = (c_q @ aw["w_uq"]).reshape(t, a.num_heads, a.nope_dim + a.rope_dim)
-    q_r = _rope(q[..., a.nope_dim:], pos, a.rope_theta)
+    q_r = _rope(q[..., a.nope_dim:], pos, a.rope_theta, a.rope_scaling)
     kv = h @ aw["w_dkv"]
     c_kv = rms(kv[:, :a.kv_rank], aw["kv_norm"], eps) * jnp.asarray(up(a.kv_rank), h.dtype)
-    k_r = _rope(kv[:, None, a.kv_rank:], pos, a.rope_theta)[:, 0]
+    k_r = _rope(kv[:, None, a.kv_rank:], pos, a.rope_theta, a.rope_scaling)[:, 0]
     w_uk = aw["w_uk"].reshape(a.kv_rank, a.num_heads, a.nope_dim)
     q_abs = jnp.concatenate(
         [jnp.einsum("thn,rhn->thr", q[..., :a.nope_dim], w_uk), q_r], axis=-1)
-    gate = jax.nn.sigmoid((h @ aw["w_g"]).astype(jnp.float32))
+    gate = jax.nn.sigmoid((h @ aw["w_g"]).astype(jnp.float32)) if a.gate else None
     return c_q, q_abs, jnp.concatenate([c_kv, k_r], axis=-1), gate
 
 
@@ -581,9 +606,11 @@ def indexer_inputs(aw, h, c_q, pos, s: LatentSpec, cfg):
 
 def attn_output(aw, o_lat, gate, a: LatentAttn):
     """Attention over latent rows [T, H, r_kv] -> the sublayer's output [T, d]:
-    through ``W_uv`` per head, the headwise gate, ``W_o``."""
+    through ``W_uv`` per head, the headwise gate where the kind has one, ``W_o``."""
     w_uv = aw["w_uv"].reshape(a.kv_rank, a.num_heads, a.v_dim)
-    o = jnp.einsum("thr,rhv->thv", o_lat, w_uv) * gate[..., None].astype(o_lat.dtype)
+    o = jnp.einsum("thr,rhv->thv", o_lat, w_uv)
+    if gate is not None:
+        o = o * gate[..., None].astype(o_lat.dtype)
     return o.reshape(o.shape[0], -1) @ aw["wo"]
 
 
@@ -847,7 +874,8 @@ def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
             o = jax.vmap(group)(grouped(q_i), grouped(w), grouped(k_i), q_pos,
                                 q_abs, rows)
         else:
-            o = la.window_attention(q_abs, q_pos, rows, q_pos, a.window, a.kv_rank,
+            # 'every' is a window no shorter than the sequence: every key s <= t
+            o = la.window_attention(q_abs, q_pos, rows, q_pos, a.window or n, a.kv_rank,
                                     a.scale)
         x = x + attn_output(aw, o.reshape(b * n, *o.shape[2:]), gate, a).astype(x.dtype)
         h = rms(x, n2["scale"], cfg.norm_eps)

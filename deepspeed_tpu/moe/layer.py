@@ -288,9 +288,25 @@ def held_routing(lw: Any, x: jnp.ndarray, spec) -> tuple[jnp.ndarray, jnp.ndarra
     program is traced).  'sigmoid': DeepSeek-V3's ``noaux_tc`` without groups:
     sigmoid scores in float32, the ``experts_per_tok`` largest of score + bias
     picked, weights the picked scores normalised (the bias selects, it does not
-    weigh), times ``routed_scale``.  'softmax': below.
+    weigh), times ``routed_scale``.  'softmax' and 'group_limited': below.
     x [T, d] -> (experts [T, k], weights [T, k] float32)."""
     scores = x.astype(jnp.float32) @ lw["router"].astype(jnp.float32)
+    if spec.routing == "group_limited":
+        # DeepSeek-V2's ``group_limited_greedy``: softmax over ALL experts in
+        # float32; the experts lie in ``n_group`` groups of consecutive ones, a
+        # group scores its largest member, the ``topk_group`` best groups are
+        # kept (equal scores: the lower group) and the picks are the largest
+        # inside them; weights the picked scores as they are (NOT renormalised)
+        # times ``routed_scale``.  A score outside the kept groups reads -1,
+        # under every softmax value, so no pick ever falls there.
+        s = jax.nn.softmax(scores, axis=-1)
+        t, e = s.shape
+        best = jnp.max(s.reshape(t, spec.n_group, e // spec.n_group), axis=-1)
+        _, groups = jax.lax.top_k(best, spec.topk_group)
+        kept = jnp.any(groups[:, :, None] == jnp.arange(spec.n_group)[None, None, :], axis=1)
+        inside = jnp.repeat(kept, e // spec.n_group, axis=1)
+        picked, idx = jax.lax.top_k(jnp.where(inside, s, -1.0), spec.experts_per_tok)
+        return idx, picked * spec.routed_scale
     if spec.routing == "softmax":
         # softmax over ALL experts in float32, the largest picked and
         # renormalised (``norm_topk_prob``); no bias, no scale

@@ -1,6 +1,7 @@
 """Latent attention over a cache of ONE row per key (DeepSeek-V2's MLA in its
-absorbed form) with a learned key selector (DeepSeek-V3.2's indexer) or a
-sliding window.
+absorbed form) with a learned key selector (DeepSeek-V3.2's indexer), a
+sliding window, or over EVERY cached row (DeepSeek-V2's own layer:
+``dense_attention_pack`` / ``dense_attention_step``).
 
 A key is one row ``[c_kv ; k_rope]`` (``r_kv + rope`` wide) that serves every
 head: the queries are folded through ``W_uk`` first (``q_abs = [q_nope W_uk ;
@@ -14,8 +15,8 @@ Work is laid out in GROUPS of ``C`` consecutive queries of one sequence (a
 page of a prefill pack; one decode row), because a group shares its keys:
 ``key_block(g, b)`` hands block ``b`` of group ``g``'s index keys, ``row_of(g,
 positions)`` the flat rows of its latent keys.  Plain XLA bodies, each under a
-``jax.named_scope`` (``indexer``, ``topk``, ``sparse_attn``, ``window_attn``)
-so that a device trace can be attributed; gathers and scores exist one query
+``jax.named_scope`` (``indexer``, ``topk``, ``sparse_attn``, ``window_attn``,
+``mla_prefill``, ``mla_decode``) so that a device trace can be attributed; gathers and scores exist one query
 block at a time.
 """
 from __future__ import annotations
@@ -165,3 +166,92 @@ def window_attention(q_abs, q_pos, keys, key_pos, window: int, r_kv: int, scale:
         p = jax.nn.softmax(jnp.where(ok[:, :, None, :], s, _MASKED), axis=-1)
         return jnp.einsum("gchk,gkr->gchr", p.astype(keys.dtype), keys[..., :r_kv],
                           preferred_element_type=jnp.float32).astype(q_abs.dtype)
+
+
+# ---------------------------------------------------------------------------
+# attention over EVERY cached row
+# ---------------------------------------------------------------------------
+DENSE_KEY_BLOCK = 512  # keys scored at once: whole pages
+
+
+def _online(carry, s, v, ok, eq: str):
+    """One block of an online softmax: carry (m, l, acc) of the queries, scores
+    ``s`` [.., q, k] float32, mask ``ok`` (broadcasts to ``s``), values ``v``
+    contracted by ``eq`` (p, v -> acc's shape)."""
+    m, l, acc = carry
+    s = jnp.where(ok, s, _MASKED)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+    fade = jnp.exp(m - m_new)
+    acc = acc * fade[..., None] + jnp.einsum(eq, p.astype(v.dtype), v,
+                                             preferred_element_type=jnp.float32)
+    return m_new, l * fade + jnp.sum(p, axis=-1), acc
+
+
+def _key_blocks(tables, c: int):
+    """(keys a block, pages a block, the tables padded to whole blocks)."""
+    kp = max(DENSE_KEY_BLOCK // c, 1)
+    return kp * c, kp, jnp.pad(jnp.maximum(tables, 0), ((0, 0), (0, -tables.shape[1] % kp)))
+
+
+def dense_attention_pack(q_abs, lat, tables, live, q_pos, a):
+    """A pack's attention over EVERY cached row of each query's sequence, its
+    own rows included (they are in the pages already): the layer of a model
+    whose cache is latent pages alone.  ABSORBED, as every body of this file.
+
+    q_abs [G, C, H, lanes] (``[q_nope W_uk ; q_rope]``, zeros past the row), a
+    page of one sequence's queries a group; ``lat`` [blocks, C, lanes] the
+    layer's pages; ``tables`` [G, P] each group's block table; ``live`` [G]
+    (a dead page of the pack comes back zeros); ``q_pos`` [G, C]; ``a`` the
+    kind (``LatentAttn``).  A group walks its sequence's pages a block of
+    ``DENSE_KEY_BLOCK`` keys at a time, never the whole context, as far as its
+    last position, with an online softmax over the blocks, causal by
+    position.  Returns [G, C, H, r_kv] (before ``W_uv``)."""
+    _, c, h, _ = q_abs.shape
+    r = a.kv_rank
+    kb, kp, tables = _key_blocks(tables, c)
+    kpos = jnp.arange(kb)
+
+    def group(xs):
+        q, table, pos, n = xs  # [C, H, lanes], [P], [C], the keys it reaches (0: dead)
+
+        def key_block(b, state):
+            rows = lat[jax.lax.dynamic_slice_in_dim(table, b * kp, kp)].reshape(kb, -1)
+            s = jnp.einsum("chw,kw->hck", q, rows, preferred_element_type=jnp.float32)
+            ok = (b * kb + kpos)[None, :] <= pos[:, None]
+            return _online(state, s * a.scale, rows[:, :r], ok[None], "hck,kr->hcr")
+
+        state = (jnp.full((h, c), _MASKED, jnp.float32), jnp.zeros((h, c), jnp.float32),
+                 jnp.zeros((h, c, r), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, (n + kb - 1) // kb, key_block, state)
+        return jnp.moveaxis(acc / jnp.maximum(l, 1e-30)[..., None], 0, 1).astype(q.dtype)
+
+    with jax.named_scope("mla_prefill"):
+        reach = jnp.where(live, jnp.max(q_pos, axis=1) + 1, 0)
+        return jax.lax.map(group, (q_abs, tables, q_pos, reach))
+
+
+def dense_attention_step(q_abs, lat, tables, lens, a):
+    """A decode tick's attention over every cached row: one query a slot.
+    q_abs [B, H, lanes] (``[q_nope W_uk ; q_rope]``, zeros past the row);
+    ``lat`` [blocks, C, lanes]; ``tables`` [B, P]; ``lens`` [B] keys a slot
+    attends (0: an idle slot, whose row comes back zeros).  The slots' pages
+    are walked a block of ``DENSE_KEY_BLOCK`` keys at a time, as far as the
+    longest slot reaches.  Returns [B, H, r_kv] (before ``W_uv``)."""
+    b, h, _ = q_abs.shape
+    r = a.kv_rank
+    kb, kp, tables = _key_blocks(tables, lat.shape[1])
+    kpos = jnp.arange(kb)
+
+    def key_block(i, state):
+        pages = jax.lax.dynamic_slice_in_dim(tables, i * kp, kp, axis=1)   # [B, kp]
+        rows = lat[pages].reshape(b, kb, -1)
+        s = jnp.einsum("bhw,bkw->bhk", q_abs, rows, preferred_element_type=jnp.float32)
+        ok = (i * kb + kpos)[None, :] < lens[:, None]
+        return _online(state, s * a.scale, rows[..., :r], ok[:, None, :], "bhk,bkr->bhr")
+
+    with jax.named_scope("mla_decode"):
+        state = (jnp.full((b, h), _MASKED, jnp.float32), jnp.zeros((b, h), jnp.float32),
+                 jnp.zeros((b, h, r), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, (jnp.max(lens) + kb - 1) // kb, key_block, state)
+        return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_abs.dtype)

@@ -1,6 +1,7 @@
 """The expert layer of one MEMBER of an expert-parallel deployment
-(``moe/layer.py:moe_block_held``): routing over all experts (sigmoid + bias, or
-a softmax), a grouped matmul over the pairs that fall on the experts held here."""
+(``moe/layer.py:moe_block_held``): routing over all experts (sigmoid + bias, a
+softmax, or a softmax limited to a few groups of experts), a grouped matmul
+over the pairs that fall on the experts held here."""
 from dataclasses import replace
 
 import jax
@@ -55,6 +56,17 @@ def _weights_softmax(key):
     return dict(lw, w_sg=jax.random.normal(jax.random.fold_in(key, 9), (D, 1)) / np.sqrt(D))
 
 
+# the third routing: a softmax over all E in 8 groups of 2, the 3 groups of
+# largest maximum kept, the K largest inside them, NOT renormalised, x 16
+SPEC_GROUPS = replace(SPEC, routing="group_limited", n_group=8, topk_group=3, routed_scale=16.0)
+
+
+def _weights_groups(key):
+    lw = _weights(key)
+    del lw["bias"]
+    return lw
+
+
 def _swiglu(x, g, u, d):
     return (jax.nn.silu(x @ g) * (x @ u)) @ d
 
@@ -93,8 +105,9 @@ def _dense_over_experts(lw, x, spec, shared):
 
 
 @pytest.mark.parametrize("spec,make,members", [
-    (SPEC, _weights, 8), (SPEC_RELU2, _weights_relu2, 4), (SPEC_SOFTMAX, _weights_softmax, 4)],
-    ids=["swiglu-8", "relu2_latent-4", "softmax_gated_shared-4"])
+    (SPEC, _weights, 8), (SPEC_RELU2, _weights_relu2, 4), (SPEC_SOFTMAX, _weights_softmax, 4),
+    (SPEC_GROUPS, _weights_groups, 4)],
+    ids=["swiglu-8", "relu2_latent-4", "softmax_gated_shared-4", "group_limited-4x2_groups"])
 def test_the_shares_add_up_to_the_uncut_layer(spec, make, members):
     """The guide's share test: each of the members holds its share of the
     experts, routes over all E and computes its own; their partial sums, the
@@ -154,6 +167,69 @@ def test_softmax_routing_is_over_all_experts_and_renormalised(n_experts, k):
     picked = np.take_along_axis(p, np.asarray(idx), -1)
     np.testing.assert_allclose(np.asarray(w), picked / picked.sum(-1, keepdims=True), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)  # no routed_scale
+
+
+def _group_limited_by_hand(logits, n_group, topk_group, k):
+    """DeepSeek-V2's ``group_limited_greedy`` in numpy float64, a token at a
+    time: (experts picked, their softmax scores, the kept groups)."""
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    per = p.shape[-1] // n_group
+    out = []
+    for row in p:
+        best = row.reshape(n_group, per).max(-1)
+        kept = sorted(sorted(range(n_group), key=lambda g: (-best[g], g))[:topk_group])
+        inside = [e for g in kept for e in range(g * per, (g + 1) * per)]
+        picks = sorted(inside, key=lambda e: (-row[e], e))[:k]
+        out.append((picks, [row[e] for e in picks], kept))
+    return out
+
+
+@pytest.mark.parametrize("n_experts,n_group,topk_group,k", [(16, 8, 3, 4), (160, 8, 3, 6)],
+                         ids=["top4_of_16_in_3_of_8", "top6_of_160_in_3_of_8"])
+def test_group_limited_routing_against_the_formula_written_out(n_experts, n_group, topk_group, k):
+    """Softmax over ALL experts, the groups of largest maximum kept, the picks
+    the largest inside them and never outside, weights the picked scores as they
+    are (not renormalised) times ``routed_scale``."""
+    spec = replace(SPEC_GROUPS, n_routed=n_experts, n_group=n_group, topk_group=topk_group,
+                   experts_per_tok=k)
+    lw = {"router": jax.random.normal(jax.random.PRNGKey(4), (D, n_experts)) / np.sqrt(D) * 3}
+    x = jax.random.normal(jax.random.PRNGKey(5), (96, D))
+    idx, w = held_routing(lw, x, spec)
+    logits = np.asarray(x, np.float64) @ np.asarray(lw["router"], np.float64)
+    per = n_experts // n_group
+    for t, (picks, scores, kept) in enumerate(_group_limited_by_hand(logits, n_group, topk_group, k)):
+        assert sorted(np.asarray(idx[t]).tolist()) == sorted(picks)
+        assert {e // per for e in np.asarray(idx[t]).tolist()} <= set(kept)
+        order = np.argsort(np.asarray(idx[t]))
+        np.testing.assert_allclose(np.asarray(w[t])[order],
+                                   16.0 * np.asarray(scores)[np.argsort(picks)], rtol=1e-5)
+    assert float(jnp.max(jnp.sum(w, -1))) < 16.0  # not renormalised: a sum of 6 of 160 scores x 16
+
+
+def test_group_limited_routing_under_ties_and_few_groups():
+    """Equal group maxima go to the LOWER group and equal scores to the lower
+    expert; a token whose best experts all lie in one or two groups still picks
+    ``k`` experts, the rest from the other kept groups, and none outside them;
+    a score that underflows to 0 is still above a masked one."""
+    spec = replace(SPEC_GROUPS, experts_per_tok=4)  # 16 experts: 8 groups of 2, 3 kept
+    eye = jnp.eye(D)[:, :E]                         # router: logit e = x[e]
+    rows = np.zeros((4, D), np.float32)
+    # token 0: all logits equal -> groups 0, 1, 2 kept, experts 0..3 picked
+    # token 1: groups 5 and 7 hold the two largest, every other group ties: 0 is the third
+    rows[1, [10, 11, 14]] = [9.0, 8.0, 7.0]
+    # token 2: one group towers (both its experts), the rest tie: groups 3, 0, 1; picks 6, 7, 0, 1
+    rows[2, [6, 7]] = [50.0, 49.0]
+    # token 3: the softmax underflows to exactly 0 outside group 4: still groups 4, 0, 1
+    rows[3, [8, 9]] = [200.0, 199.0]
+    idx, w = held_routing({"router": eye}, jnp.asarray(rows), spec)
+    assert sorted(np.asarray(idx[0]).tolist()) == [0, 1, 2, 3]
+    assert sorted(np.asarray(idx[1]).tolist()) == [0, 10, 11, 14]
+    assert sorted(np.asarray(idx[2]).tolist()) == [0, 1, 6, 7]
+    assert sorted(np.asarray(idx[3]).tolist()) == [0, 1, 8, 9]
+    assert float(w[3].min()) == 0.0 and np.all(np.asarray(w) >= 0)  # weights are scores, never the mask's -1
+    for t in range(4):
+        assert len({e // 2 for e in np.asarray(idx[t]).tolist()}) <= 3
 
 
 @pytest.mark.parametrize("sizes", [[0, 50, 3, 0, 1, 10], [64, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0],
