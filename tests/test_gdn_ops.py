@@ -20,18 +20,23 @@ HK, HV, DK, DV = 2, 4, 16, 8
 TOL = 2e-5
 
 
-def _inputs(key, n, alike: float = 0.0):
+def _inputs(key, n, alike: float = 0.0, dims=(HK, HV, DK, DV), identical: bool = False):
     """What the recurrence consumes for ``n`` tokens: unit keys (``alike``: the
-    share of one common direction in every key, the case forward substitution
-    is there for), queries of length Dk^-1/2, log decays from ~0 to ~-3."""
+    share of one common direction in every key, the case the solve has to be
+    stable in), queries of length Dk^-1/2, log decays from ~0 to ~-3.
+    ``identical``: every key the SAME direction, beta 0.999, decay ~1 (``I + A``
+    is then ~all ones under its diagonal: the solve's worst case)."""
+    hk, hv, dk, dv = dims
     ks = jax.random.split(key, 6)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    common = jax.random.normal(ks[5], (1, HK, DK))
-    q = unit(jax.random.normal(ks[0], (n, HK, DK))) * DK ** -0.5
-    k = unit((1 - alike) * jax.random.normal(ks[1], (n, HK, DK)) + alike * 4 * common)
-    v = jax.random.normal(ks[2], (n, HV, DV))
-    g = -jnp.exp(jax.random.uniform(ks[3], (n, HV), minval=-6.0, maxval=1.0))
-    beta = jax.nn.sigmoid(2.0 * jax.random.normal(ks[4], (n, HV)))
+    common = jax.random.normal(ks[5], (1, hk, dk))
+    q = unit(jax.random.normal(ks[0], (n, hk, dk))) * dk ** -0.5
+    k = unit((1 - alike) * jax.random.normal(ks[1], (n, hk, dk)) + alike * 4 * common)
+    v = jax.random.normal(ks[2], (n, hv, dv))
+    g = -jnp.exp(jax.random.uniform(ks[3], (n, hv), minval=-6.0, maxval=1.0))
+    beta = jax.nn.sigmoid(2.0 * jax.random.normal(ks[4], (n, hv)))
+    if identical:
+        k, g, beta = jnp.broadcast_to(unit(common), k.shape), jnp.full_like(g, -1e-4), jnp.full_like(beta, 0.999)
     return q, k, v, g, beta
 
 
@@ -53,6 +58,24 @@ def _chunked(seqs, chunk, loaded=None):
     return [jnp.concatenate(p) for p in parts], jnp.concatenate(valid), jnp.asarray(cont), ends
 
 
+def _scan_against_the_recurrence(seqs, chunk):
+    """Sequences sharing one ``gdn_scan`` call: the largest distance of its
+    outputs and of its last states from the reference's recurrence."""
+    (_, hv, dv), dk = seqs[0][2].shape, seqs[0][1].shape[-1]
+    arrays, valid, cont, ends = _chunked(seqs, chunk)
+    zeros = jnp.zeros((len(cont), hv, dk, dv))
+    o, states = gdn.gdn_scan(*arrays, zeros, cont)
+    o = np.asarray(o).reshape(-1, hv, dv)
+    at, far_o, far_s = 0, 0.0, 0.0
+    for seq, end in zip(seqs, ends):
+        n = seq[0].shape[0]
+        want_o, want_s = recurrence(*(a[None] for a in seq))
+        far_o = max(far_o, np.abs(o[at:at + n] - np.asarray(want_o[0])).max())
+        far_s = max(far_s, np.abs(np.asarray(states[end]) - np.asarray(want_s[0])).max())
+        at += -(-n // chunk) * chunk
+    return far_o, far_s
+
+
 @pytest.mark.parametrize("chunk", [4, 8, 16])
 @pytest.mark.parametrize("alike", [0.0, 0.9], ids=["keys_random", "keys_alike"])
 def test_chunked_scan_is_the_recurrence(chunk, alike):
@@ -60,17 +83,34 @@ def test_chunked_scan_is_the_recurrence(chunk, alike):
     both, padding rows at both ends): outputs and last states equal the
     reference's token-by-token recurrence."""
     seqs = [_inputs(jax.random.PRNGKey(i), n, alike) for i, n in enumerate((37, 21))]
-    arrays, valid, cont, ends = _chunked(seqs, chunk)
-    zeros = jnp.zeros((len(cont), HV, DK, DV))
-    o, states = gdn.gdn_scan(*arrays, zeros, cont)
-    o = np.asarray(o).reshape(-1, HV, DV)
-    at = 0
-    for seq, end in zip(seqs, ends):
-        n = seq[0].shape[0]
-        want_o, want_s = recurrence(*(a[None] for a in seq))
-        assert np.abs(o[at:at + n] - np.asarray(want_o[0])).max() <= TOL
-        assert np.abs(np.asarray(states[end]) - np.asarray(want_s[0])).max() <= TOL
-        at += -(-n // chunk) * chunk
+    assert max(_scan_against_the_recurrence(seqs, chunk)) <= TOL
+
+
+SERVED = (1, 2, 128, 128)  # the served head widths (Dk = Dv = 128), two value heads on one key head
+
+
+@pytest.mark.parametrize("chunk", [32, 64, 128])
+@pytest.mark.parametrize("keys", ["keys_random", "keys_alike", "keys_identical"])
+def test_chunked_scan_by_blocks_is_the_recurrence(chunk, keys):
+    """Chunks of more than ``SOLVE_BLOCK`` rows (the inverse merged from
+    inverted diagonal blocks: 1, 2 and 3 levels) at the served widths, two
+    sequences with chunk edges inside both and padding at both ends."""
+    assert chunk // gdn.SOLVE_BLOCK in (2, 4, 8)
+    kw = {"keys_random": {}, "keys_alike": {"alike": 0.9}, "keys_identical": {"identical": True}}[keys]
+    seqs = [_inputs(jax.random.PRNGKey(i), n, dims=SERVED, **kw) for i, n in enumerate((300, 150))]
+    assert max(_scan_against_the_recurrence(seqs, chunk)) <= TOL
+
+
+def test_the_inverse_by_blocks_solves_the_triangular_system():
+    """``(I + A)^-1 @ rhs`` by blocks is ``jax.lax.linalg.triangular_solve``'s answer on
+    the same ``A`` and ``rhs``, for a chunk of 128 alike keys that do not decay."""
+    _, k, v, _, beta = _inputs(jax.random.PRNGKey(9), 128, alike=0.9, dims=SERVED)
+    k = jnp.moveaxis(k, 0, 1)                                          # [Hk, L, Dk]
+    a = jnp.tril(beta.T[..., None] * (k @ jnp.swapaxes(k, 1, 2)), -1)  # [Hv, L, L], at decay 1
+    rhs = beta.T[..., None] * jnp.moveaxis(v, 0, 1)
+    want = jax.lax.linalg.triangular_solve(a, rhs, left_side=True, lower=True, unit_diagonal=True)
+    got = gdn._solve_unit_lower(a, rhs)
+    assert np.abs(np.asarray(got - want)).max() <= 1e-6 * np.abs(np.asarray(want)).max()
 
 
 def test_a_state_handed_from_pack_to_pack():

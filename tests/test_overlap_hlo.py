@@ -490,3 +490,30 @@ def test_held_experts_layout_looks_its_groups_up_a_tile_at_a_time(one_chip, as_o
     assert "tpu_custom_call" in text, "the grouped matmul's Mosaic body was not compiled"
     rows = t * k + g * layer._GMM_ROWS
     assert len(re.findall(rf"= s32\[{rows}\]\S* gather\(", text)) <= 1
+
+
+# -- the chunked delta rule's triangular inverse (PR 46) ----------------------
+def test_gdn_scan_builds_its_inverse_by_blocks_and_not_by_a_row_loop(one_chip):
+    """``gdn_scan`` at the Qwen3-Next pack's shape (4 chunks of 128 tokens, 16
+    key / 32 value heads x 128, float32 states) compiled for a v5e: no
+    ``InvertDiagBlocksLowerTriangular`` custom call and no loop of 128 trips.
+    The parent's text held ``custom-call ... f32[4,32,1,128,128] ...
+    InvertDiagBlocksLowerTriangular``: ``triangular_solve`` ran there as an
+    explicit inverse built a row a step, the first device op of cell 7's
+    capture (0.773 s of 3.97; ledger, PR 45).  Two loops are left: the
+    ``SOLVE_BLOCK`` - 1 rows of the diagonal blocks' substitution and the
+    hand-over of one state a chunk."""
+    from deepspeed_tpu.ops import gdn
+
+    g, l, hk, hv, d = 4, 128, 16, 32, 128
+    S = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(gdn.gdn_scan).trace(
+        S(g, l, hk, d), S(g, l, hk, d), S(g, l, hv, d), S(g, l, hv), S(g, l, hv),
+        S(g, hv, d, d), S(g, dtype=jnp.bool_)).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert not re.search(r"f32\[4,32,1,128,128\]\S* custom-call\(", text)
+    trips = []
+    for cond in re.findall(r" while\(.*?condition=%([\w.\-]+)", text):
+        body = text[text.index(f"\n%{cond} ("):]
+        trips.append(int(re.search(r"s32\[\]\S* constant\((\d+)\)", body[:body.index("\n}")]).group(1)))
+    assert sorted(trips) == [g, gdn.SOLVE_BLOCK - 1], trips
