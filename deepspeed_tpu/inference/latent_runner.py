@@ -10,7 +10,11 @@ attends over.
   latent pages and nothing beside them: no index keys, no ring.  A model of
   such layers alone is one whose blocks a prefix cache may share (a pack's
   chunk that starts at a position > 0 reads the pages under it, whoever wrote
-  them);
+  them).  Its queries cross the seam BEFORE ``W_uk`` and its heads' values
+  come back after ``W_uv``, because its one softmax has two forms and the
+  pack picks by length (``_attend_every``): a run of a sequence's pages from
+  ``latent_attention.run_groups`` on attends DECOMPRESSED, shorter ones and a
+  tick's single rows ABSORBED as the other kinds do;
 - a ``sliding`` layer keeps a RING per slot, not pages: position ``p`` of slot
   ``n`` lives in row ``p % R`` of ``win[n]``, ``R`` = one pack + the window's
   look-back, so a pack's rows can be written before it attends without
@@ -59,12 +63,13 @@ the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
 as are chunks of one prompt and several prompts in one pack: work is laid out
 in groups of one page of one sequence.  Plain XLA bodies
-(``ops/latent_attention.py``) but for four Pallas kernels on the chip: a
+(``ops/latent_attention.py``) but for five Pallas kernels on the chip: a
 pack's index scores (``ops/pallas/index_scores.py``), its shorter groups'
 attention over their picks (``ops/pallas/selected_attention.py``: an ``every``
-layer's pack walks its pages through the same kernel, its causal positions
-the mask), an ``every`` layer's tick (``ops/pallas/latent_decode.py``) and the
-expert layer's grouped matmul (``moe/layer.py``).
+layer's short runs walk their pages through the same kernel, the causal
+positions the mask), an ``every`` layer's long runs in the decompressed form
+(``ops/pallas/latent_prefill.py``), its tick (``ops/pallas/latent_decode.py``)
+and the expert layer's grouped matmul (``moe/layer.py``).
 
 ``LatentRunner`` is what ``InferenceEngineV2`` holds for such a model (as
 ``model_runner.DenseRunner`` for a dense one): these entries under the names
@@ -73,6 +78,7 @@ describes (``COUNTERS``, the rings' host mirror).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -83,6 +89,7 @@ from ..models import latent as lm
 from ..ops import latent_attention as la
 from ..ops.pallas import index_scores as index_kernel
 from ..ops.pallas import latent_decode as decode_kernel
+from ..ops.pallas import latent_prefill as prefill_kernel
 from ..ops.pallas import selected_attention as selected_kernel
 from ..ops.pallas import note_dispatch, on_tpu
 
@@ -129,6 +136,7 @@ WINDOW_COUNTERS = (
 MLA_COUNTERS = (
     "mla_keys_attended",         # (query, key) pairs of the layers over every row: causal keys
     "mla_keys_attended_decode",  # ... of them, those of decode ticks
+    "mla_keys_decompressed",     # ... and those of a pack's runs from ``la.run_groups`` pages on
     "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
     "expert_group_rows_min", "experts_touched", "experts_touched_decode",
     "expert_pairs_held_decode",
@@ -318,28 +326,79 @@ def _attend_selected(s, q_abs, q_i, w, q_pos, tables, lat, idx, real, picked, pr
     return o
 
 
-def _attend_every(a, q_abs, lat, tables, live, q_pos):
-    """An ``every`` layer's pack: groups [G, C, ...] of absorbed queries, each
-    over EVERY cached row of its sequence (``tables`` [G, P]) up to its own
-    position.  Two schedules of the same softmax, under one scope
-    (``mla_prefill``): where the Pallas kernel ``selected_attn`` takes the
-    shape, each group WALKS its sequence's live pages in place with the causal
-    positions as its mask (no row is copied, no score block reaches HBM);
-    every other shape, and the CPU, takes the XLA body
-    (``la.dense_attention_pack``).  Returns latent rows [G, C, H, r_kv]."""
-    _, c, h, _ = q_abs.shape
+def _attend_every(a, q, w_uk, w_uv, lat, tables, slot, live, q_pos):
+    """An ``every`` layer's pack: queries ``q`` [T, H, nope + rope] BEFORE
+    ``W_uk`` (rotated), a group a page of one sequence (``slot``, ``live`` [G];
+    ``q_pos`` [G, C]), each over EVERY cached row of its sequence (``tables`` by
+    slot) up to its own position.  ONE softmax under one scope
+    (``mla_prefill``), in the form its length makes the cheaper one, decided
+    here from what the program sees (``la.pack_runs``): a RUN of at least
+    ``la.run_groups`` consecutive pages of one sequence (171 queries -> 2
+    pages at DeepSeek-V2's widths: a document's chunk) attends DECOMPRESSED
+    through the Pallas kernel ``latent_prefill`` (``W_uk`` / ``W_uv`` on a
+    block of rows once for all of the run's queries); every other live group
+    (a question behind a prefix hit, a document's last odd page) is folded
+    through ``W_uk``, WALKS its sequence's pages through ``selected_attn`` with
+    the causal positions as its mask, and is folded through ``W_uv``: the
+    absorbed form.  Each kernel is handed 0 pages for the other's groups, and a
+    pack with no group of a form skips that form altogether (its folds
+    too).  A shape either kernel declines, and the CPU, takes the absorbed XLA
+    body (``la.dense_attention_pack``) or the walk for every group.  Returns
+    the heads' values [T, H, v] (after ``W_uv``)."""
+    (g, c), (t, h, _) = q_pos.shape, q.shape
     bs, lanes = lat.shape[1], lat.shape[2]
-    q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, lanes - a.row),))
+
+    def fold():  # the absorbed queries on the pages' lanes, by group
+        q_abs = lm.absorbed_queries(w_uk, q, q[..., a.nope_dim:], a)
+        return jnp.pad(q_abs, ((0, 0), (0, 0), (0, lanes - a.row))).reshape(g, c, h, lanes)
+
+    unfold = lambda o: lm.latent_values(w_uv, o.reshape(t, h, a.kv_rank), a)
     if not _selected_kernel_takes(c, h, lanes, a.kv_rank, bs):
-        return la.dense_attention_pack(q_abs, lat, tables, live, q_pos, a)
-    with jax.named_scope("mla_prefill"):
-        own = jnp.maximum(tables, 0)
-        keys = jnp.arange(own.shape[1] * bs)
-        mask = (keys[None, None, :] <= q_pos[:, :, None]).astype(jnp.int8)
-        last = jnp.max(q_pos, axis=1)
-        o = selected_kernel.selected_attention(
-            q_abs, mask, lat, own, jnp.where(live, last // bs + 1, 0), a.kv_rank, a.scale)
-        return jnp.where(live[:, None, None, None], o, 0)  # a dead page of the pack is left unwritten
+        return unfold(la.dense_attention_pack(fold(), lat, tables[slot], live, q_pos, a))
+
+    def walked(which):
+        q_abs = fold()
+        with jax.named_scope("mla_prefill"):
+            own = jnp.maximum(tables[slot], 0)
+            keys = jnp.arange(own.shape[1] * bs)
+            mask = (keys[None, None, :] <= q_pos[:, :, None]).astype(jnp.int8)
+            last = jnp.max(q_pos, axis=1)
+            o = _walk(q_abs, mask, lat, own, jnp.where(which, last // bs + 1, 0), r_kv=a.kv_rank,
+                      scale=a.scale, traced_at=(selected_kernel.TQ, selected_kernel.KP,
+                                                selected_kernel.interpret()))
+        return unfold(o)
+
+    rows = lambda of: jnp.repeat(of, c)[:, None, None]  # a group's flag on its queries
+    if not _prefill_kernel_takes(t, h, lanes, a.kv_rank, a.nope_dim, a.v_dim, bs):
+        return jnp.where(rows(live), walked(live), 0)  # a dead page of the pack is left unwritten
+    long, runs, run_slot = la.pack_runs(slot, live, q_pos[:, 0], c, la.run_groups(a, c))
+
+    def decompressed():
+        with jax.named_scope("mla_prefill"):
+            # head-major: a head's queries on the lanes of [k_nope ; row[r_kv:]], its [W_uk | W_uv]
+            q_rows = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - a.row))).transpose(1, 0, 2)
+            w_ukv = jnp.concatenate([w_uk.reshape(a.kv_rank, h, a.nope_dim),
+                                     w_uv.reshape(a.kv_rank, h, a.v_dim)], axis=-1)
+            o = prefill_kernel.latent_prefill(
+                q_rows, w_ukv.transpose(1, 0, 2), lat, jnp.maximum(tables[run_slot], 0), runs,
+                a.kv_rank, a.scale)
+            return o.transpose(1, 0, 2)
+
+    short = live & ~long
+    absorbed = lambda others: jnp.where(rows(short), walked(short), others)
+    return jax.lax.switch(  # by the forms the pack holds: none, long runs, short ones, both
+        jnp.any(long) + 2 * jnp.any(short),
+        [lambda: jnp.zeros((t, h, a.v_dim), q.dtype), decompressed,
+         lambda: absorbed(0), lambda: absorbed(decompressed())])
+
+
+@functools.partial(jax.jit, static_argnames=("r_kv", "scale", "traced_at"))
+def _walk(q_abs, mask, lat, tables, live_pages, *, r_kv: int, scale: float, traced_at):
+    """``selected_attention``, traced and lowered ONCE for the layers of a
+    program and the branches that call it alike (``latent_prefill`` does the
+    same for itself); ``traced_at``: what that kernel reads when it is traced,
+    its tiles and the interpret switch."""
+    return selected_kernel.selected_attention(q_abs, mask, lat, tables, live_pages, r_kv, scale)
 
 
 def _kernel_takes(name: str, kernel, shape, declines: str) -> bool:
@@ -367,6 +426,15 @@ def _selected_kernel_takes(c: int, h: int, w: int, r_kv: int, bs: int) -> bool:
                          "whole query tiles; page, row and value lanes whole 128-lane tiles")
 
 
+def _prefill_kernel_takes(t: int, h: int, w: int, r_kv: int, nope: int, v: int, bs: int) -> bool:
+    """The gate of the Pallas kernel for a pack's runs in the decompressed form,
+    by shape.  Which groups it then serves is decided inside the program, by
+    the length of their run (``latent_attention.run_groups``)."""
+    return _kernel_takes("latent_prefill", prefill_kernel, (t, h, w, r_kv, nope, v, bs),
+                         "whole query tiles and head blocks; page, latent, rope, key and value "
+                         "lanes whole 128-lane tiles")
+
+
 def _decode_kernel_takes(h: int, w: int, r_kv: int, bs: int) -> bool:
     """The gate of the Pallas kernel for a tick's rows over every cached row."""
     return _kernel_takes("latent_decode", decode_kernel, (h, w, r_kv, bs),
@@ -392,17 +460,20 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, prob
     kind, (n1, n2), aw, fw, is_moe = lm.layer_params(layers, l, s)
     a, i = s.attn(kind), s.layer_kinds[:l].count(kind)
     h = lm.rms(x, n1["scale"], cfg.norm_eps)
-    c_q, q_abs, row, gate = lm.attn_inputs(aw, h, pos, a, cfg)
+    # the kind over every row hands its queries BEFORE ``W_uk`` and takes the heads'
+    # values back: its read folds where the form it chooses needs it
+    every = kind == "every"
+    c_q, q_abs, row, gate = lm.attn_inputs(aw, h, pos, a, cfg, absorbed=not every)
     keys, rows, queries = ("win",), (row,), (q_abs,)
     if kind == "full":
         q_i, k_i, w = lm.indexer_inputs(aw, h, c_q, pos, s, cfg)
         keys, rows, queries = ("lat", "idx"), (row, k_i), (q_abs, q_i, w)
-    elif kind == "every":
-        keys = ("lat",)
+    elif every:
+        keys, queries = ("lat",), (q_abs, aw["w_uk"], aw["w_uv"])
     arrays = write(kind, tuple(cache[k][i] for k in keys), rows)
     cache = {**cache, **{k: _put(cache[k], i, v) for k, v in zip(keys, arrays)}}
     o = read(kind, arrays, queries)
-    x = x + lm.attn_output(aw, o, gate, a).astype(x.dtype)
+    x = x + lm.attn_output(aw, o, gate, a, values=every).astype(x.dtype)
     h = lm.rms(x, n2["scale"], cfg.norm_eps)
     if is_moe and "touched" in cache:
         y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, track_groups, probe)
@@ -581,7 +652,7 @@ def _latent_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, ca
             o = _attend_selected(s, q_abs, q_i, w, q_pos, tables[slot], *arrays,
                                  grouped(valid), picked, probe=probe)
         elif kind == "every":
-            o = _attend_every(s.every, grouped(queries[0]), arrays[0], tables[slot], live, q_pos)
+            return _attend_every(s.every, *queries, arrays[0], tables, slot, live, q_pos)
         else:
             # the group's own page and the ``back`` pages before it, from the ring
             j = jnp.arange(back + 1)
@@ -739,14 +810,18 @@ def _latent_tick_seam(cfg, pos, block_tables, active, picked, probe):
             return _attend_selected(s, q_abs, q_i, w, one(pos), block_tables, *arrays,
                                     one(active), picked, probe=probe)[:, 0]
         if kind == "every":
-            a, lat = s.every, arrays[0]
-            q_abs = jnp.pad(queries[0], ((0, 0), (0, 0), (0, lat.shape[-1] - a.row)))
+            # one query a slot: under the crossing, so absorbed whatever the context
+            a, lat, (q, w_uk, w_uv) = s.every, arrays[0], queries
+            q_abs = jnp.pad(lm.absorbed_queries(w_uk, q, q[..., a.nope_dim:], a),
+                            ((0, 0), (0, 0), (0, lat.shape[-1] - a.row)))
             lens = jnp.where(active, pos + 1, 0)
             if _decode_kernel_takes(a.num_heads, lat.shape[-1], a.kv_rank, lat.shape[1]):
                 with jax.named_scope("mla_decode"):  # each slot walks its own pages in place
-                    return decode_kernel.latent_decode(q_abs, lat, jnp.maximum(block_tables, 0),
-                                                       lens, a.kv_rank, a.scale)
-            return la.dense_attention_step(q_abs, lat, block_tables, lens, a)
+                    o = decode_kernel.latent_decode(q_abs, lat, jnp.maximum(block_tables, 0),
+                                                    lens, a.kv_rank, a.scale)
+            else:
+                o = la.dense_attention_step(q_abs, lat, block_tables, lens, a)
+            return lm.latent_values(w_uv, o, a)
         win = arrays[0]
         ring = win.shape[0] * win.shape[1] // b
         keys = win.reshape(b, ring, win.shape[-1])
@@ -905,13 +980,24 @@ class LatentRunner:
 
     def _every_dispatched(self, counters, work, pack: bool) -> Dict[str, int]:
         """Latent attention over every cached row: a query at position ``p``
-        attends ``p + 1`` keys a layer."""
-        n = self.cfg.latent.count("every")
-        keys = sum((hi * (hi + 1) - lo * (lo + 1)) // 2 for _, lo, hi in work)  # sum of p + 1
+        attends ``p + 1`` keys a layer.  A pack's entry is one RUN (its pages of
+        queries follow one another), and a run of ``la.run_groups`` pages or
+        more attends in the decompressed form (``_attend_every``'s rule)."""
+        s = self.cfg.latent
+        n, bs = s.count("every"), self._block
+        shortest = la.run_groups(s.every, bs)
+        keys = long = 0
+        for _, lo, hi in work:
+            pairs = (hi * (hi + 1) - lo * (lo + 1)) // 2  # sum of p + 1
+            keys += pairs
+            if pack and -(-(hi - lo) // bs) >= shortest:
+                long += pairs
         counters["mla_keys_attended"].inc(keys * n)
         if not pack:
             counters["mla_keys_attended_decode"].inc(keys * n)
-        return {"mla_keys": keys * n}
+            return {"mla_keys": keys * n}
+        counters["mla_keys_decompressed"].inc(long * n)
+        return {"mla_keys": keys * n, "mla_keys_decompressed_pct": 100.0 * long / max(keys, 1)}
 
     def _windows_dispatched(self, counters, work) -> Dict[str, int]:
         """Gated GQA of two kinds: a query at position ``p`` attends ``p + 1``

@@ -571,10 +571,28 @@ def _rope(x, pos, theta: float, scaling: Optional[Yarn] = None):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
 
 
-def attn_inputs(aw, h, pos, a: LatentAttn, cfg):
+def absorbed_queries(w_uk, q, q_rope, a: LatentAttn):
+    """The heads' queries folded through ``W_uk``: q [T, H, nope + ..] (its
+    first ``nope`` dims count), q_rope [T, H, rope] (rotated) -> ``[q_nope W_uk
+    ; q_rope]`` [T, H, row], what scores a cache row as it lies."""
+    w_uk = w_uk.reshape(a.kv_rank, a.num_heads, a.nope_dim)
+    return jnp.concatenate(
+        [jnp.einsum("thn,rhn->thr", q[..., :a.nope_dim], w_uk), q_rope], axis=-1)
+
+
+def latent_values(w_uv, o_lat, a: LatentAttn):
+    """Attention over latent rows [T, H, r_kv] folded through ``W_uv``: the
+    heads' values [T, H, v]."""
+    w_uv = w_uv.reshape(a.kv_rank, a.num_heads, a.v_dim)
+    return jnp.einsum("thr,rhv->thv", o_lat, w_uv)
+
+
+def attn_inputs(aw, h, pos, a: LatentAttn, cfg, absorbed: bool = True):
     """Normed input ``h`` [T, d] -> (c_q [T, r_q], absorbed queries [T, H,
     row], the key's cache row [T, row], gate [T, H] or None where the kind has
-    none)."""
+    none).  ``absorbed=False`` (the kind over EVERY row, whose pack chooses the
+    form by the length of a run: ``latent_runner._attend_every``) hands the
+    queries BEFORE ``W_uk`` instead, ``[q_nope ; q_rope]`` [T, H, nope + rope]."""
     t, d, eps = h.shape[0], cfg.hidden_size, cfg.norm_eps
     up = lambda r: (d / r) ** 0.5 if cfg.latent.rescale_lora else 1.0
     c_q = rms(h @ aw["w_dq"], aw["q_norm"], eps) * jnp.asarray(up(a.q_rank), h.dtype)
@@ -583,11 +601,12 @@ def attn_inputs(aw, h, pos, a: LatentAttn, cfg):
     kv = h @ aw["w_dkv"]
     c_kv = rms(kv[:, :a.kv_rank], aw["kv_norm"], eps) * jnp.asarray(up(a.kv_rank), h.dtype)
     k_r = _rope(kv[:, None, a.kv_rank:], pos, a.rope_theta, a.rope_scaling)[:, 0]
-    w_uk = aw["w_uk"].reshape(a.kv_rank, a.num_heads, a.nope_dim)
-    q_abs = jnp.concatenate(
-        [jnp.einsum("thn,rhn->thr", q[..., :a.nope_dim], w_uk), q_r], axis=-1)
+    if absorbed:
+        q = absorbed_queries(aw["w_uk"], q, q_r, a)
+    else:
+        q = jnp.concatenate([q[..., :a.nope_dim], q_r], axis=-1)
     gate = jax.nn.sigmoid((h @ aw["w_g"]).astype(jnp.float32)) if a.gate else None
-    return c_q, q_abs, jnp.concatenate([c_kv, k_r], axis=-1), gate
+    return c_q, q, jnp.concatenate([c_kv, k_r], axis=-1), gate
 
 
 def indexer_inputs(aw, h, c_q, pos, s: LatentSpec, cfg):
@@ -604,11 +623,12 @@ def indexer_inputs(aw, h, c_q, pos, s: LatentSpec, cfg):
     return rot(q_i), rot(k_i)[:, 0], (h @ aw["w_iw"]).astype(jnp.float32)
 
 
-def attn_output(aw, o_lat, gate, a: LatentAttn):
+def attn_output(aw, o_lat, gate, a: LatentAttn, values: bool = False):
     """Attention over latent rows [T, H, r_kv] -> the sublayer's output [T, d]:
-    through ``W_uv`` per head, the headwise gate where the kind has one, ``W_o``."""
-    w_uv = aw["w_uv"].reshape(a.kv_rank, a.num_heads, a.v_dim)
-    o = jnp.einsum("thr,rhv->thv", o_lat, w_uv)
+    through ``W_uv`` per head, the headwise gate where the kind has one, ``W_o``.
+    ``values=True``: ``o_lat`` is the heads' values [T, H, v] already (the kind
+    over every row folds through ``W_uv`` where its form needs it)."""
+    o = o_lat if values else latent_values(aw["w_uv"], o_lat, a)
     if gate is not None:
         o = o * gate[..., None].astype(o_lat.dtype)
     return o.reshape(o.shape[0], -1) @ aw["wo"]

@@ -9,7 +9,14 @@ q_rope]``), the softmax-weighted sum of the rows' first ``r_kv`` columns is
 folded through ``W_uv`` afterwards (``models/latent.py``).  What is here is
 the part between: index scores, the exact top-k, attention over the selected
 rows (gathered, or for a pack's shorter contexts walked in place under a mask
-by the Pallas kernel ``selected_attn``), attention over a window.
+by the Pallas kernel ``selected_attn``), attention over a window.  That is
+the ABSORBED form, and every body of this file has it.  The same softmax has
+a DECOMPRESSED form (``W_uk`` / ``W_uv`` applied to a key's row once, shared by
+all the queries of one sequence in a dispatch: the cheaper one from
+``crossing()`` queries on); it exists as a Pallas kernel alone
+(``ops/pallas/latent_prefill.py``), for the RUNS of a pack over every row that
+``pack_runs()`` finds long enough, and has no XLA body: its score blocks would
+go through HBM between the two matmuls (10% of the roofline, PR 45).
 
 Work is laid out in GROUPS of ``C`` consecutive queries of one sequence (a
 page of a prefill pack; one decode row), because a group shares its keys:
@@ -194,10 +201,53 @@ def _key_blocks(tables, c: int):
     return kp * c, kp, jnp.pad(jnp.maximum(tables, 0), ((0, 0), (0, -tables.shape[1] % kp)))
 
 
+def crossing(a) -> int:
+    """Queries of one sequence in a dispatch from which the DECOMPRESSED form
+    (``W_uk`` / ``W_uv`` applied to a key's row once, shared by those queries:
+    ``2 (nope + rope) + 2 v`` FLOPs a pair and head after ``2 r (nope + v)`` a key
+    and head) needs fewer FLOPs over a long context than the ABSORBED one (``2
+    (2 r + rope)`` a pair and head), from the kind's own widths: 171 at
+    DeepSeek-V2's.  A kind whose absorbed form is never the dearer: none."""
+    r = a.kv_rank
+    saved = (2 * r + a.rope_dim) - (a.nope_dim + a.rope_dim + a.v_dim)
+    return -(-r * (a.nope_dim + a.v_dim) // saved) if saved > 0 else 1 << 30
+
+
+def run_groups(a, c: int) -> int:
+    """``crossing()`` in whole groups of ``c`` queries: the shortest RUN of a
+    pack (consecutive groups of one sequence) that attends decompressed."""
+    return -(-crossing(a) // c)
+
+
+def pack_runs(slot, live, first, c: int, min_groups: int):
+    """The RUNS of a pack: consecutive live groups of one sequence on
+    consecutive pages of it.  slot, live, first [G]: each group's sequence,
+    whether it holds a query, and its first query's position; ``c`` queries a
+    group.  Returns (long [G] bool: the group is in a run of at least
+    ``min_groups``; runs [R, 3] int32: such a run's first group, its groups
+    and its first position, rows of zeros behind the last; run_slot [R]: its
+    sequence), ``R = max(G // min_groups, 1)`` the most a pack can hold."""
+    g = slot.shape[0]
+    follows = jnp.concatenate([jnp.zeros((1,), bool), live[1:] & live[:-1]
+                               & (slot[1:] == slot[:-1]) & (first[1:] == first[:-1] + c)])
+    starts = live & ~follows
+    run = jnp.cumsum(starts)  # a live group's run, counted from 1
+    groups = jnp.sum((run[:, None] == run[None, :]) & live[None, :], axis=1, dtype=jnp.int32)
+    long = live & (groups >= min_groups)
+    n_runs = max(g // min_groups, 1)
+    heads = starts & long
+    at = jnp.where(heads, jnp.cumsum(heads) - 1, n_runs)
+    runs = jnp.stack([jnp.arange(g, dtype=jnp.int32), groups, first.astype(jnp.int32)], axis=1)
+    return (long, jnp.zeros((n_runs, 3), jnp.int32).at[at].set(runs, mode="drop"),
+            jnp.zeros((n_runs,), slot.dtype).at[at].set(slot, mode="drop"))
+
+
 def dense_attention_pack(q_abs, lat, tables, live, q_pos, a):
     """A pack's attention over EVERY cached row of each query's sequence, its
     own rows included (they are in the pages already): the layer of a model
-    whose cache is latent pages alone.  ABSORBED, as every body of this file.
+    whose cache is latent pages alone.  ABSORBED, as every body of this file:
+    the fallback, the CPU's path and the ground truth of both kernels that
+    serve such a pack on the chip (``latent_runner._attend_every``).
 
     q_abs [G, C, H, lanes] (``[q_nope W_uk ; q_rope]``, zeros past the row), a
     page of one sequence's queries a group; ``lat`` [blocks, C, lanes] the

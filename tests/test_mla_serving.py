@@ -168,12 +168,14 @@ def test_a_preempted_sequence_is_resumed_and_a_slots_second_owner_reads_its_own(
 
 
 def test_a_pack_that_walks_its_pages_through_the_kernel_serves_the_same_tokens(model):
-    """Where the Pallas kernel ``selected_attn`` takes the shape (here in
-    interpret mode), an ``every`` layer's pack walks its pages in place,
-    ABSORBED, the causal positions its mask, and a tick's rows walk theirs
-    through ``latent_decode``: the same tokens as the reference's,
-    behind a prefix hit too (a dead page of the pack between two prompts is left
-    unwritten by the kernel and zeroed)."""
+    """Where the Pallas kernels take the shape (here in interpret mode), an
+    ``every`` layer's pack attends in place in the form its runs' lengths
+    choose: a run of 3 pages or more (the rehearsal's crossing: 22 queries)
+    DECOMPRESSED through ``latent_prefill``, a shorter one (a prompt's last 2
+    pages) ABSORBED through ``selected_attn``, the causal positions its mask;
+    a tick's rows walk theirs through ``latent_decode``: the same tokens as
+    the reference's, behind a prefix hit too (a dead page of the pack between
+    two prompts comes back zeros)."""
     from deepspeed_tpu.ops.pallas import record_dispatch
     from deepspeed_tpu.ops.pallas import selected_attention as sk
 
@@ -193,11 +195,15 @@ def test_a_pack_that_walks_its_pages_through_the_kernel_serves_the_same_tokens(m
         assert eng.mgr.cached_prompt_tokens == 64
     took = [d for d in log if d["kernel"] == "selected_attn"]
     assert took and all(d["ran"] and d["shape"][0] == PAGE for d in took)  # packs only: c = 8
+    long = [d for d in log if d["kernel"] == "latent_prefill"]  # ... beside the long runs' kernel
+    assert long and all(d["ran"] and d["shape"][0] == CHUNK for d in long)
     ticks = [d for d in log if d["kernel"] == "latent_decode"]  # ... and the ticks' own kernel
     assert ticks and all(d["ran"] and d["shape"] == (4, 128, 64, PAGE) for d in ticks)
     for u, p in asks.items():
         assert _short(ref, params, p, outs[u]) <= 1e-4, u
-    assert eng.stats["mla_keys_attended"] > 0
+    # both forms ran: 73 = 32 + 32 + 9 tokens end on a run of 2 pages, which walks
+    assert 0 < eng.stats["mla_keys_decompressed"] < (
+        eng.stats["mla_keys_attended"] - eng.stats["mla_keys_attended_decode"])
     assert eng.close()["blocks_in_use"] == 0
 
 

@@ -203,6 +203,26 @@ def test_the_latent_kernels_compile_at_dots3_widths(v5e):
         tables, live))
 
 
+def test_the_every_row_kernels_compile_at_deepseek_v2_widths(v5e):
+    """``latent_prefill`` and ``latent_decode`` at the served widths: a pack of
+    2048 queries x 128 heads of 128 + 64 (padded to the 256 lanes of ``[k_nope ;
+    row[512:]]``), ``[W_uk | W_uv]`` 512 x 256 a head, rows of 640 lanes, pages of
+    128, tables of 392 pages, up to 8 runs a pack; 24 slots a tick."""
+    from deepspeed_tpu.ops.pallas import latent_decode as dk
+    from deepspeed_tpu.ops.pallas import latent_prefill as pk
+
+    t, h, nb, p = 2048, 128, 3840, 392
+    assert pk.supports(t, h, 640, 512, 128, 128, 128) and dk.supports(h, 640, 512, 128)
+    _assert_mosaic(_compile(
+        lambda q, w, k, tab, runs: pk.latent_prefill(q, w, k, tab, runs, 512, 0.11), v5e,
+        _spec((h, t, 256)), _spec((h, 512, 256)), _spec((nb, 128, 640)),
+        _spec((8, p), jnp.int32), _spec((8, 3), jnp.int32)))
+    _assert_mosaic(_compile(
+        lambda q, k, tab, lens: dk.latent_decode(q, k, tab, lens, 512, 0.11), v5e,
+        _spec((24, h, 640)), _spec((nb, 128, 640)), _spec((24, p), jnp.int32),
+        _spec((24,), jnp.int32)))
+
+
 def test_flash_partitions_on_four_chips(v5e, monkeypatch):
     """The flash dispatcher under a 4-device mesh lowers (shard_map region)
     where the bare kernel call raises 'Mosaic kernels cannot be
