@@ -28,8 +28,8 @@ prove-don't-regex stance to the HOST-side concurrency seam:
   submit/tick/cancel, shed vs watchdog, worker-kill vs route — as pure
   functions of their seed.
 
-Entry points: ``bench.py --audit`` (JSON report) and the pytest gates in
-``tests/test_analysis.py`` / ``tests/test_racelint.py`` (tier-1 fast lane).
+Entry points: the pytest gates in ``tests/test_analysis.py`` /
+``tests/test_racelint.py`` (tier-1).
 """
 from .astlint import LintViolation, lint_package, lint_source
 from .racelint import (
@@ -39,7 +39,7 @@ from .racelint import (
     stale_race_baseline,
     unbaselined,
 )
-from .schedviz import Schedule, checkpoint, explore, run_scenarios
+from .schedviz import Schedule, checkpoint, explore
 from .audit import audit_serve_engine, audit_train_step, serve_jit_specs
 from .checks import (
     CheckResult,
@@ -88,7 +88,6 @@ __all__ = [
     "lint_source",
     "parse_scheduled_hlo",
     "program_facts",
-    "run_scenarios",
     "stale_race_baseline",
     "stablehlo_collectives",
     "unbaselined",

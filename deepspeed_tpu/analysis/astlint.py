@@ -180,7 +180,7 @@ _CONTROLLER_NAMES = {"OnlineController", "attach_controller"}
 # the fleet observability plane gets the same layering rule: it OBSERVES
 # the data plane (its collector thread pulls workers over sockets), so no
 # tick-path module may import it — attachment is duck-typed
-# (Router.attach_fleet), wired by the launcher/bench
+# (Router.attach_fleet), wired by the launcher
 _FLEET_MODULE = "telemetry.fleet"
 _FLEET_NAMES = {"FleetRegistry", "FleetCollector", "SloMonitor",
                 "attach_fleet_collector", "fleet_chrome_trace"}
@@ -270,7 +270,7 @@ class _Visitor(ast.NodeVisitor):
             f"({what}) — the collector thread does socket I/O and is "
             "excluded from HOT_PATHS precisely because nothing on the "
             "tick path may call it; attachment is duck-typed "
-            "(Router.attach_fleet), wired by the launcher/bench",
+            "(Router.attach_fleet), wired by the launcher",
         )
 
     def visit_Import(self, node: ast.Import) -> None:

@@ -8,8 +8,7 @@ engine's own dispatch sites, lowers the engine's actual compiled callables
 (donation flags, out-shardings and all), and ``audit_serve_engine`` runs
 the donation / collective-budget / dtype / sharding passes over each.
 ``audit_train_step`` does the training half (the fused train-step jit).
-``bench.py --audit`` and ``tests/test_analysis.py`` both consume the
-returned JSON-able report.
+``tests/test_analysis.py`` consumes the returned JSON-able report.
 """
 from __future__ import annotations
 

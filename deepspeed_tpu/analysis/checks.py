@@ -2,9 +2,9 @@
 
 Each checker returns a :class:`CheckResult` — ``passed`` plus typed
 :class:`Violation` records and a JSON-able ``facts`` summary — so the same
-pass serves pytest assertions, the ``bench.py --audit`` report, and ad-hoc
-debugging.  Checkers never raise on a failed invariant; they raise only on
-caller errors (e.g. an argument name absent from the arg table).
+pass serves pytest assertions and ad-hoc debugging.  Checkers never raise
+on a failed invariant; they raise only on caller errors (e.g. an argument
+name absent from the arg table).
 """
 from __future__ import annotations
 

@@ -38,8 +38,7 @@ convention.  Four rules:
 Same ergonomics as :mod:`astlint`: a trailing ``# lint: allow(<rule>)``
 comment suppresses that line (measured-and-documented exceptions only);
 :data:`RACE_BASELINE` grandfathers pre-existing violations and may only
-shrink.  ``tests/test_racelint.py`` is the tier-1 gate; ``bench.py
---audit`` runs the pass and exits non-zero on baseline growth.
+shrink.  ``tests/test_racelint.py`` is the tier-1 gate.
 """
 from __future__ import annotations
 
@@ -611,7 +610,7 @@ def lint_race_package(root: Optional[str] = None,
 
 def unbaselined(violations: Sequence[RaceViolation]) -> List[RaceViolation]:
     """Violations not grandfathered in :data:`RACE_BASELINE` — the set the
-    tier-1 gate and ``bench.py --audit`` require to be empty."""
+    tier-1 gate requires to be empty."""
     return [v for v in violations if v.baseline_key not in RACE_BASELINE]
 
 

@@ -33,8 +33,8 @@ Pieces:
   submit-vs-tick-vs-cancel, shed-mode entry/exit vs watchdog,
   worker-kill-vs-route, and cancel-vs-megastep (ISSUE 16: cancels landing
   while the scheduler fuses decode ticks into one burst).  Each raises
-  ``AssertionError`` on an invariant violation; :func:`run_scenarios`
-  aggregates them for ``bench.py --audit`` and the tier-1 gate.
+  ``AssertionError`` on an invariant violation; ``tests/test_racelint.py``
+  sweeps :data:`SCENARIOS` over seeds (the tier-1 gate).
 """
 from __future__ import annotations
 
@@ -517,7 +517,7 @@ class CoopThread:
 def explore(scenario: Callable[..., Any], seeds: Iterable[int] = range(16),
             **kw) -> Dict[str, Any]:
     """Run ``scenario(seed, **kw)`` over every seed; collect failures.
-    The report is JSON-able for ``bench.py --audit``."""
+    The report is JSON-able."""
     seeds = list(seeds)
     failures: Dict[int, str] = {}
     for seed in seeds:
@@ -1547,14 +1547,3 @@ SCENARIOS = (
     scenario_metrics_pull_vs_death,
 )
 
-
-def run_scenarios(seeds: Iterable[int] = range(8)) -> Dict[str, Any]:
-    """Sweep every hot scenario over ``seeds``; JSON-able aggregate for
-    ``bench.py --audit`` and the tier-1 gate."""
-    seeds = list(seeds)
-    reports = [explore(s, seeds=seeds) for s in SCENARIOS]
-    return {
-        "passed": all(r["passed"] for r in reports),
-        "schedules_total": sum(r["schedules"] for r in reports),
-        "scenarios": {r["scenario"]: r for r in reports},
-    }

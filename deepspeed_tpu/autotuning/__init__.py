@@ -1,5 +1,5 @@
 """Autotuning: roofline-seeded config search over training AND serving
-knobs, scored by the bench's own metrics (see autotuner.py)."""
+knobs, scored by measured trials (see autotuner.py)."""
 from .autotuner import (  # noqa: F401
     Autotuner,
     Trial,

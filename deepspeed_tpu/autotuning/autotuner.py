@@ -24,10 +24,8 @@ Automap, arXiv:2112.02958, are the cost-model-guided-search precedents):
    ``incumbent`` candidate (the current hand-tuned config) is always
    carried to the final rung, so the search can never return something it
    measured worse than the config you already have;
-5. the **winner** is the measured-score argmax of the final rung, scored
-   by the same metrics the bench emits (``tokens_per_sec`` /
-   ``serve_effective_tokens_per_sec``) so tuner numbers and bench numbers
-   are directly comparable.
+5. the **winner** is the measured-score argmax of the final rung
+   (``tokens_per_sec`` / ``serve_effective_tokens_per_sec``).
 
 Every candidate — pruned, errored, skipped or measured — lands in the
 per-trial leaderboard (:func:`leaderboard` / :func:`write_leaderboard`)
@@ -65,7 +63,7 @@ class Trial:
     candidate: Dict[str, Any]
     predicted_cost: Optional[float] = None   # roofline s/token (lower=better)
     verdict: str = PENDING           # ok | pruned:* | error:* | not_run
-    score: Optional[float] = None    # bench-metric units (higher=better)
+    score: Optional[float] = None    # tokens/s (higher=better)
     metrics: Dict[str, Any] = field(default_factory=dict)
     rung: int = -1                   # highest rung measured at
     run_order: List[int] = field(default_factory=list)  # global launch seq
@@ -328,7 +326,6 @@ def autotune_model(
     max_trials: Optional[int] = None,
     seed: int = 0,
     device_memory_bytes: Optional[float] = None,
-    artifacts_dir: Optional[str] = None,
 ) -> Tuple[Optional[Dict[str, Any]], List[Trial]]:
     """Training entry: tune a named preset (models/presets.py); returns
     ``(winner config dict or None, trials)``.  The winner dict is a valid
@@ -355,7 +352,7 @@ def autotune_model(
         zero_stages=zero_stages, mesh_candidates=mesh_candidates,
         zero_quant=zero_quant,
     )
-    consts = roofline.RooflineConstants.calibrate(artifacts_dir)
+    consts = roofline.RooflineConstants()
     hbm = device_memory_bytes
     if hbm is None:
         from ..accelerator import get_accelerator
@@ -384,7 +381,6 @@ def autotune_model(
         "tokens_per_sec": winner.score,
         "metric": metric,
         "pruned_fraction": tuner.pruned_fraction,
-        "calibration_sources": list(consts.sources),
     }
     return cfg, trials
 
@@ -403,7 +399,6 @@ def autotune_serving(
     max_trials: Optional[int] = None,
     seed: int = 0,
     metric: str = "throughput",
-    artifacts_dir: Optional[str] = None,
     devices=None,
 ) -> Tuple[Optional[Trial], List[Trial], "Autotuner"]:
     """Serving entry: search engine/scheduler knobs over a shared-prefix
@@ -420,7 +415,7 @@ def autotune_serving(
     wl = workload or ServeWorkload()
     sp = space or serving_space()
     base = dict(base or {})
-    consts = roofline.RooflineConstants.calibrate(artifacts_dir)
+    consts = roofline.RooflineConstants()
     devs = list(devices if devices is not None else jax.devices())
     runner = ServeTrialRunner(params, model_cfg, wl, base=base, devices=devs)
     feas_base = {
@@ -438,6 +433,5 @@ def autotune_serving(
         metric=metric, rungs=rungs, eta=eta, top_k=top_k,
         max_trials=max_trials, seed=seed, incumbent=incumbent,
     )
-    tuner.consts = consts  # calibration provenance for the leaderboard
     winner, trials = tuner.search()
     return winner, trials, tuner

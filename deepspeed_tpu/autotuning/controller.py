@@ -425,8 +425,8 @@ class OnlineController:
     def inject_retune(self, _metric: str = "emitted_tokens_per_s",
                       _better: str = "higher", **knobs: Any) -> None:
         """Force the NEXT proposing epoch to apply ``knobs``, guarded on
-        ``_metric`` like any organic retune — the bench's
-        rollback-fires-on-a-bad-retune proof uses this."""
+        ``_metric`` like any organic retune
+        (``tests/test_adaptation.py``'s rollback-on-a-bad-retune case)."""
         self._injected = (dict(knobs), _metric, _better)
 
     def _log(self, decision: Dict[str, Any]) -> None:

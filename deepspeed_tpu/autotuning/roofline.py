@@ -3,34 +3,24 @@
 Predicts, per candidate, the dominant resource terms of one training step
 or one serving decode tick from first principles — HBM weight-stream
 bytes, wire bytes per collective (the ``comm/qcomm.wire_bytes``
-accounting the quantized-collective layer already uses for its bench
-A/Bs), and model FLOPs — and checks memory/structural feasibility so the
-search never compiles a candidate the hardware cannot run.  The
+accounting the quantized-collective layer's telemetry uses), and model
+FLOPs — and checks memory/structural feasibility so the search never
+compiles a candidate the hardware cannot run.  The
 prediction is a *ranking and pruning* signal: knobs with no roofline
 coordinate (``kv_watermark``, ``prefill_chunk``) rank flat here and are
 differentiated by the measured trials instead.
 
-Constants come from one of two places, in preference order:
-
-1. **Calibration from bench artifacts** (:meth:`RooflineConstants.calibrate`)
-   — ``BENCH_r0*.json`` / ``MULTICHIP_r0*.json`` artifacts in the directory
-   the caller names (the rounds <= 5 ``BENCH`` files left the repo) carry
-   measured tokens/s + param counts (-> achieved compute rate) and, where
-   present, ``effective_weight_gb_s`` (-> achieved HBM stream rate) and
-   ``tp_allreduce_ms`` (-> interconnect rate).  Using achieved rates
-   instead of datasheet peaks makes predicted step times land near
-   measured ones on the same box.
-2. **Analytic defaults** (v5e datasheet numbers derated to sustained
-   fractions) when no artifact parses.
+The constants (:class:`RooflineConstants`) are ANALYTIC DEFAULTS, not
+measurements: v5e datasheet numbers derated to sustained fractions, good
+for ordering candidates and nothing else.  The measured side lives with the
+benchmark (``benchmark/peaks.py`` for the device's peaks, ``benchmark/
+costs*.py`` for what a program needs); no rate in this module comes from a
+chip run.
 """
 from __future__ import annotations
 
-import glob
-import json
-import math
-import os
-from dataclasses import dataclass, field, replace
-from typing import Any, Dict, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
 
 # bytes one weight element costs on the wire/HBM per serving quant format
 _WEIGHT_BYTES = {None: 2.0, "none": 2.0, "bf16": 2.0,
@@ -50,78 +40,6 @@ class RooflineConstants:
     hbm_bytes: float = 16e9           # HBM capacity
     host_tick_s: float = 200e-6       # per-dispatch host overhead
     ici_hop_s: float = 1e-6           # per-collective-permute hop latency
-    sources: Tuple[str, ...] = ()     # artifact files that informed a rate
-
-    @classmethod
-    def calibrate(cls, artifact_dir: Optional[str],
-                  patterns: Sequence[str] = ("BENCH_*.json",
-                                             "MULTICHIP_*.json"),
-                  ) -> "RooflineConstants":
-        """Fit the rate constants from bench artifacts; every constant an
-        artifact does not inform keeps its analytic default.  Unreadable /
-        alien JSON files are skipped — absence of artifacts is the normal
-        fresh-checkout case, not an error."""
-        base = cls()
-        if not artifact_dir or not os.path.isdir(artifact_dir):
-            return base
-        compute, hbm, used = [], [], []
-
-        def walk(obj):
-            """Pull every (metric, value, extra) record out of one artifact
-            (the repo's artifacts nest the bench line under 'parsed')."""
-            if isinstance(obj, dict):
-                if "metric" in obj and "value" in obj:
-                    yield obj
-                for v in obj.values():
-                    yield from walk(v)
-            elif isinstance(obj, list):
-                for v in obj:
-                    yield from walk(v)
-
-        for pat in patterns:
-            for path in sorted(glob.glob(os.path.join(artifact_dir, pat))):
-                try:
-                    with open(path) as fh:
-                        doc = json.load(fh)
-                except (OSError, ValueError):
-                    continue
-                hit = False
-                for rec in walk(doc):
-                    extra = rec.get("extra") or {}
-                    metric = str(rec.get("metric", ""))
-                    val = rec.get("value")
-                    if not isinstance(val, (int, float)):
-                        continue
-                    if (metric.startswith("train_tokens_per_sec")
-                            and extra.get("params")):
-                        # achieved compute rate: tokens/s * ~6N FLOPs/token
-                        compute.append(val * 6.0 * float(extra["params"]))
-                        hit = True
-                    gbs = extra.get("effective_weight_gb_s")
-                    if isinstance(gbs, (int, float)) and gbs > 0:
-                        hbm.append(float(gbs))
-                        hit = True
-                    for row in (extra.get("batch_scaling") or []):
-                        g = row.get("effective_weight_gb_s")
-                        if isinstance(g, (int, float)) and g > 0:
-                            hbm.append(float(g))
-                            hit = True
-                    # NOTE: tp_allreduce_ms_median artifacts are not fitted
-                    # into ici_gbps — the measured chain's shapes are not
-                    # recorded in the artifact, so no rate is derivable;
-                    # ici keeps its analytic default (and such files are
-                    # not claimed as calibration sources)
-                if hit:
-                    used.append(os.path.basename(path))
-        out = base
-        if compute:
-            # best observed run = achievable on this box
-            out = replace(out, compute_flops=max(compute))
-        if hbm:
-            out = replace(out, hbm_gbps=max(hbm))
-        if used:
-            out = replace(out, sources=tuple(used))
-        return out
 
 
 # ---------------------------------------------------------------------------

@@ -9,16 +9,15 @@ and the search records the error and moves on).
 
 Two runners share the ``(candidate, budget) -> (score, metrics)``
 protocol the search engine calls (``budget`` is the successive-halving
-fraction in (0, 1]; ``score`` is higher-is-better in the bench's own
-units):
+fraction in (0, 1]; ``score`` is higher-is-better):
 
 - :class:`TrainTrialRunner` — a few fused train steps through
   ``ds.initialize``; score = ``tokens_per_sec`` (the flagship metric).
 - :class:`ServeTrialRunner` — a shared-prefix arrival workload through
   ``ServeScheduler`` on an engine built via the canonical
   ``build_serve_engine`` seam; score = ``serve_effective_tokens_per_sec``
-  (prompt + generated tokens per wall second — the serving bench's
-  headline), metrics carry the telemetry TTFT/TBT percentiles.  Every
+  (prompt + generated tokens per wall second), metrics carry the
+  telemetry TTFT/TBT percentiles.  Every
   trial runs a shape REHEARSAL first (compile time must not decide a
   search), resets the telemetry window, then measures; teardown goes
   through ``engine.close()`` and the zero-leak allocator audit — a trial
@@ -39,7 +38,7 @@ from ..utils.logging import log_dist
 
 @dataclass(frozen=True)
 class ServeWorkload:
-    """Shared-prefix arrival workload (the ``bench.py --serving`` shape):
+    """Shared-prefix arrival workload (``tests/test_autotuning.py``'s stub):
     ``n_req`` requests sharing a ``sys_len``-token system prompt with
     ``sfx_len``-token unique suffixes, Poisson-ish arrivals, greedy
     ``max_new`` continuations."""

@@ -3,9 +3,9 @@
 One place owns the knowledge of WHICH collectives a TP serving dispatch
 issues and at what shapes — previously duplicated (and drifting) between
 ``engine_v2._account_comm`` (telemetry wire bytes), ``engine_v2.
-measure_tp_collectives`` (the microbenchmark chain), ``autotuning.roofline.
-predict_serve_cost`` (the cost model's wire term) and the bench's A/B
-arithmetic.  The Graft Auditor's ``collective_budget`` checker compares the
+measure_tp_collectives`` (the microbenchmark chain) and ``autotuning.
+roofline.predict_serve_cost`` (the cost model's wire term).  The Graft
+Auditor's ``collective_budget`` checker compares the
 compiled program's enumerated collectives against exactly this plan, so a
 drift between the analytic model and what XLA actually emits fails a test
 instead of silently mis-reporting.
@@ -16,7 +16,7 @@ A plan is a list of :class:`PlannedCollective`; bytes follow the
 - ``row_psum`` — the per-layer row-parallel partial-sum transports (o +
   down projections), ``[n_tokens, hidden]`` each at the engine's transport
   format.  These are the ONLY format-dependent wires, and the ones the
-  ``comm/bytes_on_wire`` counter (and its bench A/B delta) accounts.
+  ``comm/bytes_on_wire`` counter accounts.
 - overhead — format-INDEPENDENT collectives GSPMD inserts around the
   sharded embedding/head and the residual stream: the vocab-sharded
   embedding-gather combine (``[n_tokens, hidden]`` all-reduce), one
@@ -179,8 +179,8 @@ def zero3_step_plan(n_params: int, fsdp: int, fmt: str = "none",
                     gather_bytes_per_el: int = 2) -> List[PlannedCollective]:
     """Per-micro-step ZeRO-3 wire plan: one parameter all-gather (bf16, or
     int8 under ZeRO++ qwZ) + one gradient reduce-scatter (fp32, or int8
-    under qgZ) over the full parameter count — the arithmetic the flagship
-    ``--quant-comm`` bench and ``roofline.predict_train_cost`` share."""
+    under qgZ) over the full parameter count — the arithmetic
+    ``roofline.predict_train_cost`` uses."""
     if fsdp <= 1:
         return []
     return [

@@ -126,7 +126,7 @@ def wire_bytes(op: str, n_elements: int, fmt: str, world: int,
                chunk: int = DEFAULT_CHUNK,
                none_bytes_per_el: int = 4) -> int:
     """Per-device payload bytes ONE call puts on the wire (payload + fp32
-    scales), for the telemetry/bench accounting.  ``op``: 'all_gather' |
+    scales), for the telemetry accounting.  ``op``: 'all_gather' |
     'reduce_scatter' | 'all_reduce' | 'all_to_all'.  ``n_elements`` is the
     FULL logical tensor (for all_to_all: this rank's local buffer).  Exact
     passthrough ('none') counts fp32 payload and no scales.  Counts what a

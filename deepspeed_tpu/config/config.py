@@ -273,14 +273,7 @@ class ServeConfig:
     speculation is disabled until the queue drains; None = never shed).
     ``watchdog_tick_ms``: tick-duration watchdog — this many milliseconds
     per tick, ``watchdog_grace_ticks`` ticks in a row, also enters shed
-    mode (None disables the watchdog).
-
-    ``fused_serving``: tri-state gate for the fused Pallas dequant-matmul
-    kernels (``ops/quantizer.serving_mm``) — None = auto (fused whenever
-    the local shapes qualify, single-chip AND under TP shard_map regions),
-    False = jnp bodies everywhere (the A/B lever), True = auto as well.
-    Per-ENGINE state: it replaced the process-global ``set_fused_serving``
-    switch that let one TP engine pin later engines to the jnp body."""
+    mode (None disables the watchdog)."""
 
     deadline_ms: Optional[float] = None
     ttft_deadline_ms: Optional[float] = None
@@ -289,7 +282,6 @@ class ServeConfig:
     shed_queue_depth: Optional[int] = None
     watchdog_tick_ms: Optional[float] = None
     watchdog_grace_ticks: int = 3
-    fused_serving: Optional[bool] = None
     # quantized-collective transport for TP serving's row-parallel partial
     # sums (comm/qcomm.py): 'none' (exact lax.psum — the default, token-
     # identical to pre-qcomm serving), 'int8' or 'fp8' (EQuARX-style
@@ -356,7 +348,7 @@ class ServeEngineConfig:
 
     One validated block capturing the serving-engine constructor surface
     (pool shape, scheduler knobs, quant format, parallelism), so the
-    autotuner's trials, the bench's winner-verification re-run, and any
+    autotuner's trials and any
     front end all construct engines through ONE path
     (``inference.engine_v2.build_serve_engine``) instead of re-spelling
     keyword soup.  ``tp``/``serve_replicas``/``seq_shards`` > 1 make the
@@ -433,7 +425,7 @@ class ServeEngineConfig:
 class RouterConfig:
     """Serve-front-end knobs (``serving/`` — the disaggregated request
     router over N engine workers).  Consumed by ``serving.Router`` /
-    ``serving.build_router``; one validated block so benches, tests and
+    ``serving.build_router``; one validated block so tests and
     launchers spell the routing policy the same way.
 
     ``n_workers``: engine workers the pool stamps out (each via
@@ -596,17 +588,15 @@ class RouterConfig:
 @dataclass
 class AutotuneConfig:
     """Autotuner knobs (``autotuning/`` — the roofline-seeded config
-    search).  Consumed by the offline entrypoints (``bench.py --autotune``,
-    ``autotuning.autotune_model``), never by the runtime engine — same
-    split as the reference's ds_autotuner.
+    search).  Consumed by the offline entrypoints
+    (``autotuning.autotune_model`` / ``autotune_serving``), never by the
+    runtime engine — same split as the reference's ds_autotuner.
 
     ``mode`` picks the workload (``training`` | ``serving``); ``rungs``
     are the successive-halving budget fractions (ascending, final must be
     1.0 = the full trial workload); ``top_k`` is the rung-0 cohort size
     taken from the roofline ranking; ``eta`` the halving divisor;
-    ``max_trials`` caps total measured runs.  ``artifacts_dir`` points the
-    roofline calibration at a directory of ``BENCH_r0*.json`` /
-    ``MULTICHIP_r0*.json`` bench artifacts (None = analytic defaults).
+    ``max_trials`` caps total measured runs.
     ``leaderboard_path`` is where the per-trial JSON leaderboard lands."""
 
     enabled: bool = False
@@ -617,7 +607,6 @@ class AutotuneConfig:
     eta: int = 2
     rungs: List[float] = field(default_factory=lambda: [0.25, 1.0])
     seed: int = 0
-    artifacts_dir: Optional[str] = None
     leaderboard_path: Optional[str] = None
 
     def __post_init__(self):

@@ -131,7 +131,6 @@ class InferenceEngineV2:
         telemetry=None,
         serve=None,
         faults=None,
-        fused_serving: Optional[bool] = None,
         serve_replicas: int = 1,
         seq_shards: int = 1,
         quant_comm: Optional[str] = None,
@@ -362,18 +361,6 @@ class InferenceEngineV2:
 
         self.serve = serve if isinstance(serve, ServeConfig) \
             else _coerce(ServeConfig, serve)
-        # per-ENGINE fused-kernel policy (serving_mm ServingContext): the
-        # old process-global set_fused_serving switch let one TP engine pin
-        # every later single-chip engine in the process to the jnp body.
-        # Constructor arg wins; else the serve config block; None = auto
-        # (fused kernel whenever local shapes qualify — including under TP,
-        # where the kernels now run inside manual shard_map regions).
-        # False additionally pins the packed-ctx attention (prefill/verify)
-        # to its jnp dense body instead of the Pallas ctx kernel
-        # (ops/pallas/ctx_attention.py) — the kernel-vs-dense A/B lever the
-        # serving bench and parity tests use.
-        self.fused_serving = (fused_serving if fused_serving is not None
-                              else self.serve.fused_serving)
         # quantized-collective transport for the row-parallel TP psums
         # (comm/qcomm.py): ctor arg wins, else the serve config block.
         # 'none' keeps decode token-identical to pre-qcomm serving; the
@@ -393,7 +380,6 @@ class InferenceEngineV2:
             axis=MODEL_AXIS,
             size=tp,
             kv_cols=(cfg.num_kv_heads % tp == 0),
-            fused=self.fused_serving,
             comm_fmt=self.quant_comm if tp > 1 else "none",
             comm_tiles=self.comm_tiles,
         )
@@ -488,8 +474,7 @@ class InferenceEngineV2:
         # comm/* telemetry: wire-byte accounting for this engine's TP
         # collectives (analytic — payload bytes the transport puts on the
         # wire per dispatch, from qcomm.wire_bytes; 0 without a TP mesh).
-        # The quant-comm bench diffs these across its passthrough/int8 twin
-        # runs (comm_bytes_on_wire delta is the headline wire saving).
+        # ``tests/test_qcomm.py`` diffs these across passthrough/int8 twins.
         self._comm_c = self.telemetry.counters(self._comm_ns, (
             "bytes_on_wire",  # transport payload + scale bytes per device
             # format-INDEPENDENT wire GSPMD inserts around the sharded
@@ -1314,7 +1299,7 @@ class InferenceEngineV2:
         same enumeration the Graft Auditor checks against the compiled
         HLO, so this accounting cannot silently drift from what XLA
         emits).  ``bytes_on_wire`` counts the row-parallel transports at
-        this engine's format (the quant-comm bench diffs it across
+        this engine's format (``tests/test_qcomm.py`` diffs it across
         passthrough/int8 twins); ``bytes_on_wire_overhead`` counts the
         format-independent GSPMD wire (embedding combine, block-input and
         head-input gathers).  ``reps``: identical dispatches to account at
@@ -1358,12 +1343,12 @@ class InferenceEngineV2:
         ``fmt``/``tiles`` default to this engine's transport policy
         (``quant_comm``/``comm_tiles``), so a passthrough engine measures
         the exact ``psum`` chain and a quant-comm engine measures the
-        quantized tiled transport it actually serves with — the bench's
-        ``--quant-comm`` A/B calls both explicitly.
+        quantized tiled transport it actually serves with
+        (``tests/test_qcomm.py`` calls both explicitly).
 
         This is the cost the quantized-collectives work attacks, so it is
         MEASURED here rather than guessed from link rooflines.  Explicit
-        call (bench ``--serve8b --tp N`` runs it; it is not part of the
+        call (only tests make it today; it is not part of the
         decode hot path — a per-tick in-graph measurement would perturb the
         tick it measures).  Returns the median ms, or None without a TP
         mesh."""
@@ -2078,8 +2063,8 @@ class InferenceEngineV2:
     def replica_stats(self) -> List[Dict[str, float]]:
         """Host-side per-replica serving stats: the allocator/hit-rate rows
         from the state manager plus this engine's speculation totals — the
-        exact figures ``update_replica_gauges`` publishes (benches and the
-        router's load surface read this directly; tests assert on it)."""
+        exact figures ``update_replica_gauges`` publishes (the
+        router's load surface reads this directly; tests assert on it)."""
         rows = self.mgr.replica_stats()
         for r, row in enumerate(rows):
             drafted, accepted = self._spec_by_replica[r]
@@ -2092,8 +2077,8 @@ class InferenceEngineV2:
         """Refresh the ``serve/replicaN/*`` gauges (prefix-hit rate, pool
         headroom fraction, spec accept rate) from ``replica_stats`` — cheap
         host math the paired scheduler runs once per tick on partitioned
-        engines, so cross-replica imbalance is visible to the bench, the
-        router's load surface, and the future online-tuning controller.
+        engines, so cross-replica imbalance is visible to the
+        router's load surface and the future online-tuning controller.
         The names ride this engine's claimed ``serve`` prefix, so
         ``release_prefix`` at close sweeps them with the rest."""
         if not self.telemetry.enabled:
@@ -2246,8 +2231,8 @@ def build_serve_engine(params, cfg, sec, *, telemetry=None, serve=None,
     """The canonical config -> engine seam: build an ``InferenceEngineV2``
     from a validated ``config.ServeEngineConfig`` (or a dict coerced into
     one).  ``tp``/``serve_replicas``/``seq_shards`` > 1 bring up the
-    batch x seq x model mesh here, so every caller — autotuner trials, the
-    bench's winner verification, front ends — constructs multi-chip
+    batch x seq x model mesh here, so every caller — autotuner trials,
+    front ends — constructs multi-chip
     engines through one path instead of re-deriving mesh arithmetic.
 
     ``devices`` restricts the mesh to a device subset (defaults to the

@@ -3,8 +3,8 @@
 The fault-tolerance layer (scheduler lifecycle states, per-request failure
 isolation, watchdog/shed degradation, crash-safe checkpoints) is only
 trustworthy if its failure paths run in CI — so this module provides the
-*scoped, seeded* injection points the chaos suite and ``bench.py --serving
---chaos`` drive:
+*scoped, seeded* injection points the chaos suite (``tests/test_faults.py``)
+drives:
 
     ============================  ==============================================
     point                         fires where
@@ -168,7 +168,7 @@ class FaultSpec:
 class FaultInjector:
     """Seeded, scoped fault injector.  ``arm()`` rules, hand the instance to
     an engine (``InferenceEngineV2(..., faults=inj)``) or ``scope()`` it for
-    checkpoint writes; every firing is appended to ``log`` so a bench can
+    checkpoint writes; every firing is appended to ``log`` so a caller can
     compute availability over the NON-injected population afterwards."""
 
     def __init__(self, seed: int = 0, enabled: bool = True):
@@ -205,7 +205,7 @@ class FaultInjector:
     @property
     def injected_uids(self) -> frozenset:
         """Uids explicitly TARGETED by any armed spec — the population a
-        chaos bench excludes from its availability denominator."""
+        chaos run excludes from its availability denominator."""
         out: set = set()
         for specs in self._specs.values():
             for s in specs:

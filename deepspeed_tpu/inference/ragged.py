@@ -471,8 +471,7 @@ class StateManager:
         self.cached_prompt_tokens = 0
         # per-replica splits of the two hit-rate counters above (replica r's
         # numbers only ever move with its own admissions/re-matches) — the
-        # serve/replicaN/* telemetry and the bench's imbalance report read
-        # these through ``replica_stats``
+        # serve/replicaN/* telemetry reads these through ``replica_stats``
         self.prompt_tokens_by_replica = [0] * replicas
         self.cached_tokens_by_replica = [0] * replicas
         self.cow_copies = 0
@@ -837,7 +836,7 @@ class StateManager:
     def replica_stats(self) -> List[Dict[str, float]]:
         """Per-replica serving-health rows (one dict per replica): pool
         occupancy and the prefix-hit split — the host-side source for the
-        ``serve/replicaN/*`` gauges and the bench's imbalance report."""
+        ``serve/replicaN/*`` gauges."""
         out: List[Dict[str, float]] = []
         for r, a in enumerate(self.allocators):
             pt = self.prompt_tokens_by_replica[r]

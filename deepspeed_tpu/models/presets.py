@@ -46,7 +46,7 @@ register("llama3_70b", TransformerConfig(
 # ~410M-param Llama-3-shaped proxy: same GQA ratio (4:1) and the real
 # Llama-3 head_dim of 128 (MXU-native: fills the 128-deep systolic array;
 # hd=64 halves attention-matmul efficiency), RMSNorm/SwiGLU/RoPE, fits one
-# v5e chip with fp32 masters + Adam state.  bench.py flagship workload.
+# v5e chip with fp32 masters + Adam state.
 register("llama3_proxy_410m", TransformerConfig(
     vocab_size=32128, hidden_size=1024, intermediate_size=4096, num_layers=24,
     num_heads=8, num_kv_heads=2, max_seq_len=4096, rope_theta=500_000.0,

@@ -145,8 +145,8 @@ class ServingContext(NamedTuple):
     ``num_kv_heads % size == 0`` — sub-head sharding is never produced; the
     model runner passes ``kind='rep'`` for wk/wv otherwise).  ``fused``:
     tri-state kernel gate — None = auto (fused kernel whenever the local
-    shapes qualify), False = jnp bodies everywhere (the A/B lever benches
-    use), True = same as auto (the kernel still refuses unsupported
+    shapes qualify), False = jnp bodies everywhere (how tests reach the
+    reference), True = same as auto (the kernel still refuses unsupported
     shapes).
 
     ``comm_fmt``/``comm_tiles``: the row-parallel partial-sum TRANSPORT

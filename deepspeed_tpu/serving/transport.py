@@ -32,8 +32,8 @@ router's availability and token-identity guarantees.  Four layers:
 * **Chaos** — :class:`ChaosLink` wires the network-scoped fault points
   (``conn_drop``, ``conn_delay``, ``partial_write``, ``partition``,
   ``heartbeat_loss`` — ``inference/faults.py``) into every send/recv, keyed
-  by worker index, so ``bench.py --serving --router --chaos`` can run a
-  seeded storm against real worker subprocesses.
+  by worker index, so a seeded storm can run over real sockets
+  (``tests/test_transport.py``).
 
 Concurrency model: the RPC channel is single-owner (the router thread); the
 heartbeat thread owns only the heartbeat channels and the monitor's state

@@ -395,7 +395,7 @@ class FleetCollector:
 
     def pull_once(self) -> int:
         """One synchronous pull pass over every current worker (the loop
-        body; also the test/bench seam).  Returns how many workers
+        body; also the test seam).  Returns how many workers
         answered."""
         ok = 0
         for name, w in list(self._workers_fn()):
@@ -454,7 +454,7 @@ def attach_fleet_collector(router, interval_s: Optional[float] = None,
                            start: bool = True) -> FleetCollector:
     """Wire the fleet plane onto a live ``serving.Router`` (the same
     attach-style seam as the adaptation controller: the router never
-    imports this module; the launcher/bench attaches, and
+    imports this module; the launcher attaches, and
     ``Router.signals()``/``Router.close()`` consume the attached objects
     by duck type).
 

@@ -15,7 +15,7 @@ shapes, feeding
 Design constraints, in order:
 
 1. **Counters are always live.**  The engines' ``stats`` compat views are
-   part of their correctness surface (tests and bench diff them), so a
+   part of their correctness surface (tests diff them), so a
    counter counts whether telemetry is enabled or not — its cost is one
    lock acquire + integer add.  The *observability* machinery (histograms,
    gauges, snapshot export, the JSONL sink, span/trace recording) is what
@@ -119,9 +119,8 @@ class Histogram:
     recent ``window_limit`` samples backs the ``window_*`` views — the
     drift-detection surface the online autotuning controller samples each
     epoch (a lifetime p90 over an hour of traffic cannot see a
-    five-minute-old phase shift) and the steady-state percentile tables
-    the bench reports.  The ring is always exact (nearest-rank over the
-    retained samples) and survives the ``exact_limit`` degradation of the
+    five-minute-old phase shift).  The ring is always exact (nearest-rank
+    over the retained samples) and survives the ``exact_limit`` degradation of the
     cumulative store.
     """
 
@@ -254,7 +253,7 @@ class Histogram:
                     if self._window else 0.0)
 
     def reset(self) -> None:
-        """Drop every observation (bench: discard the warmup/compile window
+        """Drop every observation (discard the warmup/compile window
         so percentiles describe only the measured run)."""
         with self._lock:
             self._counts = [0] * len(self._counts)

@@ -751,7 +751,7 @@ class Telemetry:
 
     def reset_window(self) -> None:
         """Start a fresh measurement window: settle pending spans, then drop
-        every histogram observation (bench: called after warmup so the
+        every histogram observation (called after warmup so the
         percentile tables exclude compile time).  Counters keep counting —
         callers baseline those by differencing."""
         self.flush()

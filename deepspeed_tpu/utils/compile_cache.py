@@ -1,5 +1,5 @@
 """Where the persistent XLA compilation cache lives — one rule, called from
-``deepspeed_tpu.initialize``, ``InferenceEngineV2.__init__``, ``bench.py`` and
+``deepspeed_tpu.initialize``, ``InferenceEngineV2.__init__`` and
 ``chip_smoke.py``.
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself and this

@@ -8,6 +8,8 @@ so every mesh shape up to 8 is testable in-process — same coverage philosophy
 (multi-node is never tested directly in CI; a local many-device world is the
 proxy).
 """
+import contextlib
+import functools
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -44,7 +46,7 @@ def pytest_collection_modifyitems(config, items):
     REPLACES the addopts expression rather than composing with it, which is
     how the tier-1 lane silently grew past its timeout (VERDICT r5 weak
     #7's creep curve).  Individually heavy default-lane tests carry an
-    explicit ``@pytest.mark.slow`` (budget table in README Testing)."""
+    explicit ``@pytest.mark.slow``."""
     heavy = [item for item in items
              if item.get_closest_marker("nightly") or item.get_closest_marker("perf")]
     for item in heavy:
@@ -76,6 +78,19 @@ def make_grid(**axes):
     from deepspeed_tpu.parallel.topology import initialize_mesh
 
     return initialize_mesh(**axes)
+
+
+@contextlib.contextmanager
+def dense_serving_context():
+    """An ``InferenceEngineV2`` built inside gets ``ServingContext(fused=
+    False)``: the jnp bodies everywhere.  How a test builds the dense
+    reference ENGINE; no user option selects it."""
+    from deepspeed_tpu.ops import quantizer
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(quantizer, "ServingContext",
+                  functools.partial(quantizer.ServingContext, fused=False))
+        yield
 
 
 @pytest.fixture

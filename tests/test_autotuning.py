@@ -1,6 +1,6 @@
 """Autotuner tests: deterministic search order, roofline pruning,
 successive-halving promotion, trial teardown hygiene, and the e2e smokes
-(`autotune_model` winner round-trip + the `--autotune --smoke` bench CLI).
+(`autotune_model` winner round-trip, `autotune_serving` under a trial cap).
 
 The search-engine tests run on a STUBBED trial runner (no jax work), so
 the promotion/determinism/skip logic is cheap to pin exactly; the real
@@ -62,32 +62,8 @@ def test_training_space_canonicalizes_zeropp_below_stage3():
 
 
 # ---------------------------------------------------------------------------
-# roofline: calibration + feasibility + cost ordering
+# roofline: feasibility + cost ordering
 # ---------------------------------------------------------------------------
-def test_roofline_calibration_from_artifacts(tmp_path):
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "parsed": {
-            "metric": "train_tokens_per_sec_per_chip_x", "value": 1000.0,
-            "extra": {"params": 1_000_000},
-        }
-    }))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-        "parsed": {
-            "metric": "serve_decode_tokens_per_sec_x", "value": 5.0,
-            "extra": {"effective_weight_gb_s": 123.0},
-        }
-    }))
-    (tmp_path / "BENCH_bad.json").write_text("{not json")
-    c = RooflineConstants.calibrate(str(tmp_path))
-    assert c.compute_flops == pytest.approx(1000.0 * 6 * 1_000_000)
-    assert c.hbm_gbps == pytest.approx(123.0)
-    assert "BENCH_r01.json" in c.sources and "BENCH_r02.json" in c.sources
-    # no artifacts -> analytic defaults, not an error
-    d = RooflineConstants.calibrate(None)
-    assert d == RooflineConstants()
-    assert RooflineConstants.calibrate(str(tmp_path / "absent")) == d
-
-
 def test_serving_feasibility_mirrors_engine_gates():
     from deepspeed_tpu.models import get_preset
 
@@ -446,39 +422,56 @@ def test_autotune_model_smoke_winner_roundtrips_config():
     assert cfg.zero_optimization.stage == meta["winner"]["zero_stage"]
 
 
-def test_bench_autotune_serving_smoke_inproc(tmp_path, capsys):
-    """The fast-lane `--autotune --smoke` CLI path: a bounded number of
-    measured trials on the stub-sized workload, leaderboard written, the
-    un-gated serve_replicas>1 x caching region actually measured, winner
-    >= the hand-tuned incumbent."""
-    import importlib.util
-    import os
+_SEARCH_BASE = dict(max_seqs=4, num_blocks=64, block_size=8, max_seq_len=256,
+                    prefill_buckets=[16, 32, 64, 128], prefill_budget=128)
 
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+
+def _search_space(serve_replicas):
+    return serving_space(
+        tp=(1,), serve_replicas=serve_replicas, quant=(None, "int8"),
+        prefill_chunk=(None, 32), kv_watermark=(0.0625, 0.25),
+        spec=(False, True), spec_max_draft=(4,), quant_comm=("none",),
+        comm_tiles=(1,),
+    )
+
+
+def test_autotune_serving_bounded_search_measures_replicated_caching(tmp_path):
+    """``autotune_serving`` on the stub-sized workload under a trial cap:
+    at most ``max_trials`` + the incumbent are measured, the static model
+    still prunes (``serve_replicas=3`` cannot split ``max_seqs=4``), every
+    candidate has a leaderboard row, and the ``serve_replicas > 1`` x prefix
+    caching region is among the measured.  Scores are CPU tokens/s: none is
+    compared with another here."""
+    from deepspeed_tpu.autotuning import ServeWorkload, autotune_serving
+
+    cfg, params = _tiny_serving()
+    space = _search_space(serve_replicas=(1, 2, 3))
+    incumbent = space.canonicalize(dict(
+        tp=1, serve_replicas=1, quant=None, prefix_caching=True,
+        prefill_chunk=32, kv_watermark=0.0625, spec=False, spec_max_draft=4,
+        quant_comm="none", comm_tiles=1))
+    winner, trials, tuner = autotune_serving(
+        params, cfg, workload=ServeWorkload(n_req=5, sys_len=48, sfx_len=16,
+                                            max_new=6),
+        base=_SEARCH_BASE, space=space, incumbent=incumbent, seed=0,
+        top_k=6, rungs=(1.0,), max_trials=6,
+    )
+    assert winner is not None and winner.measured
+    assert tuner.pruned_fraction > 0
     out = str(tmp_path / "board.json")
-    bench.autotune_serving_main(smoke=True, out=out)
-    line = [l for l in capsys.readouterr().out.splitlines()
-            if l.startswith("{")][-1]
-    payload = json.loads(line)
-    assert payload["metric"] == "autotune_serving_winner_effective_tokens_per_sec"
-    extra = payload["extra"]
-    assert extra["measured_trials"] <= 7  # max_trials=6 + the incumbent
-    # the static model still prunes (the R=3 indivisible-pool region)
-    assert extra["pruned_fraction"] > 0
-    assert payload["value"] >= extra["incumbent_tokens_per_sec"]
-    board = json.loads(open(out).read())
-    assert board["candidates"] == len(board["trials"])
+    board = write_leaderboard(out, trials)
+    assert board == json.loads(open(out).read())
+    assert 0 < board["measured"] <= 7  # max_trials=6 + the incumbent
+    assert board["candidates"] == len(board["trials"]) \
+        == len(space.candidates())
     for row in board["trials"]:
         assert set(row) >= {"candidate", "predicted_cost", "verdict", "score"}
-    # replica-affine serving opened the R>1 x caching/spec grid region:
-    # the smoke search must measure at least one such candidate
     assert any(row["score"] is not None
                and int(row["candidate"].get("serve_replicas", 1)) > 1
                and row["candidate"].get("prefix_caching")
                for row in board["trials"])
+    assert any(candidate_key(t.candidate) == candidate_key(incumbent)
+               and t.measured for t in trials)
 
 
 @pytest.mark.slow
@@ -488,17 +481,10 @@ def test_full_serving_search_with_halving():
     from deepspeed_tpu.autotuning import ServeWorkload, autotune_serving
 
     cfg, params = _tiny_serving()
-    base = dict(max_seqs=4, num_blocks=64, block_size=8, max_seq_len=256,
-                prefill_buckets=[16, 32, 64, 128], prefill_budget=128)
     wl = ServeWorkload(n_req=6, sys_len=48, sfx_len=16, max_new=6)
-    sp = serving_space(
-        tp=(1,), serve_replicas=(1, 2), quant=(None, "int8"),
-        prefill_chunk=(None, 32), kv_watermark=(0.0625, 0.25),
-        spec=(False, True), spec_max_draft=(4,), quant_comm=("none",),
-        comm_tiles=(1,),
-    )
+    sp = _search_space(serve_replicas=(1, 2))
     winner, trials, tuner = autotune_serving(
-        params, cfg, workload=wl, base=base, space=sp,
+        params, cfg, workload=wl, base=_SEARCH_BASE, space=sp,
         rungs=(0.5, 1.0), top_k=4, eta=2, seed=0,
     )
     assert winner is not None and winner.rung == 1

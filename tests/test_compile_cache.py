@@ -49,6 +49,5 @@ def test_entry_points_call_the_helper():
         InferenceEngineV2.__init__)
     root = os.path.dirname(os.path.dirname(os.path.abspath(
         deepspeed_tpu.__file__)))
-    for script in ("bench.py", "chip_smoke.py"):
-        with open(os.path.join(root, script)) as f:
-            assert "configure_compile_cache()" in f.read(), script
+    with open(os.path.join(root, "chip_smoke.py")) as f:
+        assert "configure_compile_cache()" in f.read()

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import dense_serving_context
 from deepspeed_tpu.inference import InferenceEngineV2, SamplingParams
 from deepspeed_tpu.inference import paged
 from deepspeed_tpu.inference.paged import (
@@ -435,11 +436,13 @@ def test_engine_token_identity_kernel_vs_dense(tiny_model, tp, monkeypatch):
 
     grid = initialize_mesh(devices=jax.devices()[:4 * tp],
                            batch=2, seq=2, model=tp)
-    dense_eng = InferenceEngineV2(params, model.cfg, grid=grid,
-                                  serve_replicas=2, seq_shards=2,
-                                  fused_serving=False, **ENGINE_KW)
+    with dense_serving_context():
+        dense_eng = InferenceEngineV2(params, model.cfg, grid=grid,
+                                      serve_replicas=2, seq_shards=2,
+                                      **ENGINE_KW)
+    assert dense_eng.serving_ctx.fused is False
     want = _serve_all(dense_eng, _workload())
-    assert not calls, "fused_serving=False engine must never trace the kernel"
+    assert not calls, "a fused=False context must never trace the kernel"
 
     grid = initialize_mesh(devices=jax.devices()[:4 * tp],
                            batch=2, seq=2, model=tp)

@@ -294,7 +294,7 @@ def test_block_sparse_kernel_wall_clock_beats_dense():
         set_block_sizes(None, None)
     # the deterministic >=2x contract is test_block_sparse_kernel_grid_scales
     # _with_sparsity; wall clock gets slack for loaded CI machines (measured
-    # 1.71x/3.18x on a real v5e at 78%/91% sparsity — README)
+    # 1.71x/3.18x on a v5e at 78%/91% sparsity in round 5; not re-measured)
     assert t_dense / t_sparse >= 1.4, (t_dense, t_sparse)
 
 

@@ -360,8 +360,8 @@ def test_tp_greedy_decode_int8_within_documented_tolerance():
 
 
 def test_tp_engine_comm_byte_accounting():
-    """comm/bytes_on_wire diffs across the passthrough/int8 twin exactly
-    like the bench A/B: int8 transport must report ~4x fewer wire bytes
+    """comm/bytes_on_wire diffs across the passthrough/int8 twin:
+    int8 transport must report ~4x fewer wire bytes
     per tick (fp32 compute dtype here), and the counter stays 0 without a
     TP mesh.  The accounting now models qcomm's tp*chunk payload padding
     (the Graft Auditor reconciliation — the counter matches the compiled
@@ -395,7 +395,7 @@ def test_tp_engine_comm_byte_accounting():
 
 
 def test_measure_tp_collectives_quant_ab():
-    """The bench's A/B: the same engine measures its exact psum chain AND
+    """One engine, both transports: it measures its exact psum chain AND
     the quantized tiled transport (telemetry-off engines still measure;
     the histogram feed is covered by test_tp_fused_serving)."""
     eng = _tp_engine("none")

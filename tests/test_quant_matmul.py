@@ -38,8 +38,8 @@ SHAPES_410M = [
     (1024, 32128), # lm_head
 ]
 # 8B layer shapes (d=4096, f=14336, GQA 32:8): the decode-roofline shapes.
-# (vocab head [4096, 128256] is exercised on-chip by bench.py; interpreted
-# block-by-block it alone takes minutes, so the lane stops at the MLP.)
+# (the vocab head [4096, 128256] interpreted block-by-block alone takes
+# minutes, so the lane stops at the MLP.)
 SHAPES_8B = [
     (4096, 4096),   # wq / wo
     (4096, 1024),   # wk / wv (GQA 4:1)

@@ -285,7 +285,7 @@ def test_r2_token_identity_quant_spec_caching(tiny):
 def test_r2_per_replica_telemetry_gauges(tiny):
     """serve/replicaN/* prefix-hit, pool-headroom and spec-accept gauges
     refresh at tick boundaries on partitioned engines (the imbalance
-    surface for the bench / router / future online controller)."""
+    surface for the router / future online controller)."""
     from deepspeed_tpu.parallel.topology import initialize_mesh
 
     cfg, params = tiny
@@ -310,34 +310,62 @@ def test_r2_per_replica_telemetry_gauges(tiny):
     eng.close()
 
 
-def test_bench_replica_twin_smoke_inproc():
-    """The CI smoke gate for `bench.py --serving --replicas 2 --smoke`:
-    replica-affine vs feature-gated twin on the shared-prefix workload —
-    nonzero prefix-hit rate at R=2, effective tokens/s >= the gated
-    baseline, greedy token identity between the twins, per-replica rows
-    present (the bench asserts these internally; the payload is checked
-    here too so a silent bench edit cannot weaken the gate)."""
-    import importlib.util
-    import os
+def test_r2_affine_dispatches_fewer_prompt_tokens_than_gated(tiny):
+    """Replica-affine serving against its feature-gated twin at R = 2 on one
+    shared-prefix arrival workload (caching, chunked prefill and speculation
+    on / all three off): the affine engine DISPATCHES strictly fewer prompt
+    tokens — a count, no clock — hits its prefix caches, emits the same
+    greedy tokens and reports a row per replica."""
+    from deepspeed_tpu.parallel.topology import initialize_mesh
 
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    payload = bench.replica_serve_main(replicas=2, smoke=True)
-    extra = payload["extra"]
-    assert extra["prefix_cache_hit_rate"] > 0.0
-    assert payload["value"] >= extra["gated_baseline_tokens_per_sec"]
-    assert extra["token_identical_to_gated"]
-    assert len(extra["per_replica"]) == 2
-    for row in extra["per_replica"]:
+    cfg, params = tiny
+    rng = np.random.default_rng(0)
+    sys_prompt = rng.integers(1, cfg.vocab_size, 48).tolist()
+    prompts = {u: sys_prompt + rng.integers(1, cfg.vocab_size, 8).tolist()
+               for u in range(1, 9)}
+    arrivals = np.cumsum(rng.poisson(2.0, len(prompts)))
+    samp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    got = {}
+    for affine in (True, False):
+        kw = (dict(enable_prefix_caching=True, prefill_chunk=32,
+                   enable_speculation=True, spec_max_draft=4) if affine else
+              dict(enable_prefix_caching=False, prefill_chunk=None,
+                   enable_speculation=False))
+        grid = initialize_mesh(devices=jax.devices()[:2], batch=2, model=1)
+        eng = InferenceEngineV2(
+            params, cfg, grid=grid, serve_replicas=2, max_seqs=4,
+            num_blocks=64, block_size=8, max_seq_len=128,
+            prefill_buckets=(16, 32, 64), prefill_budget=64, **kw)
+        sched = eng.scheduler
+        pending = sorted(prompts)
+        while pending or not sched.idle:
+            while pending and arrivals[len(prompts) - len(pending)] \
+                    <= sched.tick_no:
+                u = pending.pop(0)
+                sched.submit(u, prompts[u], samp)
+            sched.tick()
+        got[affine] = dict(
+            tokens={u: sched.pop_result(u) for u in prompts},
+            dispatched=eng.stats["prefill_tokens_dispatched"],
+            hit=eng.mgr.cached_prompt_tokens / eng.mgr.prompt_tokens_total,
+            rows=eng.replica_stats())
+        assert eng.close()["blocks_in_use"] == 0
+    aff, gated = got[True], got[False]
+    assert all(len(t) == 6 for t in aff["tokens"].values())
+    assert aff["tokens"] == gated["tokens"]
+    assert gated["dispatched"] == sum(len(p) for p in prompts.values())
+    assert aff["dispatched"] < gated["dispatched"], (aff["dispatched"],
+                                                     gated["dispatched"])
+    assert aff["hit"] > 0.0 and gated["hit"] == 0.0
+    assert len(aff["rows"]) == 2
+    for row in aff["rows"]:
         assert {"prefix_hit_rate", "headroom", "spec_accept_rate"} <= set(row)
 
 
 def test_replica_affine_schedviz_scenario():
     """The deterministic-interleaving bank entry: replica-affine admission
     vs cancel on a real replicas=2 StateManager survives a seed sweep
-    (and is part of the --audit bank)."""
+    (and is part of ``schedviz.SCENARIOS``)."""
     from deepspeed_tpu.analysis import schedviz
 
     assert schedviz.scenario_replica_affine_admission in schedviz.SCENARIOS

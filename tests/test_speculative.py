@@ -1,9 +1,7 @@
 """Speculative decoding on paged KV: prompt-lookup drafting, single-pass
 multi-token verify, distribution-preserving acceptance, allocator rollback
 invariants, scheduler preemption with in-flight drafts, KV-donation no-copy
-proof, and the CPU smoke bench invocation."""
-import json
-
+proof, and a repetitive-suffix workload through the scheduler."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -509,23 +507,34 @@ def test_decode_and_verify_donate_kv_no_copy(tiny):
 
 
 # ---------------------------------------------------------------------------
-# CI smoke: the --serving --spec --smoke bench lane (satellite)
+# speculation through the scheduler on a repetitive-suffix workload
 # ---------------------------------------------------------------------------
-def test_bench_serving_spec_smoke(capsys):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench.serving_main(spec=True, smoke=True)
-    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
-             if l.startswith("{")]
-    spec_lines = [l for l in lines if l["metric"].startswith("serve_spec")]
-    assert len(spec_lines) == 1
-    extra = spec_lines[0]["extra"]
-    assert extra["accept_rate"] > 0
-    assert extra["emitted_tokens_per_target_forward"] > 1.0
-    assert extra["allocator_leak_check"] == "pass"
-    assert extra["spec_vs_plain_token_identical"] is True
+def test_scheduler_spec_repetitive_suffix_amortizes_and_closes_clean(tiny):
+    """Random bases ending in a repeated 8-token pattern (the prompt-lookup
+    drafter's home turf), offered load above the pool: drafts are accepted,
+    a target forward emits more than one token, greedy output equals the
+    plain engine's, and ``close()`` audits every block back."""
+    cfg, params = tiny
+    rng = np.random.default_rng(0)
+    pattern = rng.integers(1, cfg.vocab_size, 8).tolist()
+    prompts = {u: rng.integers(1, cfg.vocab_size, 24).tolist() + pattern * 2
+               for u in range(1, 5)}
+    samp = SamplingParams(temperature=0.0, max_new_tokens=16)
+    res, stats = {}, {}
+    for speculate in (False, True):
+        eng = _engine(cfg, params, num_blocks=24, max_seq_len=128,
+                      prefill_budget=64, prefill_chunk=32,
+                      enable_prefix_caching=True,
+                      enable_speculation=speculate, spec_max_draft=4)
+        sched = eng.scheduler
+        for u, p in prompts.items():
+            sched.submit(u, p, samp)
+        res[speculate] = sched.run(wait_for=list(prompts))
+        stats[speculate] = dict(eng.stats)
+        assert eng.close()["blocks_in_use"] == 0
+    assert res[True] == res[False]
+    st = stats[True]
+    assert st["spec_accepted"] / max(1, st["spec_drafted"]) > 0
+    assert ((st["spec_emitted"] + st["decode_emitted"])
+            / (st["spec_seq_forwards"] + st["decode_emitted"])) > 1.0
+    assert stats[False]["spec_drafted"] == 0

@@ -25,7 +25,7 @@ from deepspeed_tpu.ops import quantizer as Q
 from deepspeed_tpu.ops.pallas import quant_matmul as qm
 from deepspeed_tpu.parallel.topology import MODEL_AXIS, initialize_mesh
 
-from conftest import make_grid
+from conftest import dense_serving_context, make_grid
 
 
 @pytest.fixture(autouse=True)
@@ -93,7 +93,7 @@ def test_shard_map_region_parity_410m_shapes(fmt, kind, bias, monkeypatch):
 @pytest.mark.parametrize("kind", ["col", "row"])
 def test_shard_map_region_parity_8b_shapes(fmt, kind):
     """8B-layer shapes: the attention (4096x4096) and MLP row (14336x4096)
-    projections at tp=2 — the shapes the serve8b bench actually runs."""
+    projections at tp=2 — the shapes an 8B model serves at."""
     if kind == "row":
         _region_parity(14336, 4096, fmt, "row", True, tp=2)
     else:
@@ -208,8 +208,10 @@ def test_tp_decode_token_identity_more_formats(fmt, tp):
     got = _generate(eng, prompt)
     assert got == solo, (fmt, tp, got, solo)
     # per-engine fused gate: a fused=False TP twin decodes identically too
-    off = InferenceEngineV2(params, cfg, grid=grid, quantize_weights=fmt,
-                            fused_serving=False, **kw)
+    with dense_serving_context():
+        off = InferenceEngineV2(params, cfg, grid=grid, quantize_weights=fmt,
+                                **kw)
+    assert off.serving_ctx.fused is False
     assert _generate(off, prompt) == solo
 
 
