@@ -210,6 +210,16 @@ def initialize(
     from .parallel.sharding import set_current_mesh
 
     set_current_mesh(mesh.mesh)
+    model_cfg_ = getattr(model, "cfg", None)
+    if getattr(model_cfg_, "latent", None) is not None:
+        from .models.latent import refuse
+        from .parallel.topology import MODEL_AXIS, STAGE_AXIS
+
+        for axis in (MODEL_AXIS, STAGE_AXIS):
+            if mesh.axis_size(axis) > 1:
+                refuse(f"a mesh with {axis}={mesh.axis_size(axis)}", "its layers are per-kind "
+                       "tuples of unlike trees: no tensor-parallel rule or pipeline stage "
+                       "is written for them; data and ZeRO axes shard them")
 
     if params is None:
         if model is None:

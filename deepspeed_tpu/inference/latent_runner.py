@@ -480,7 +480,7 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, prob
         return x + y.astype(x.dtype), cache
     y, routing = lm.ffn(fw, h, is_moe, cfg, valid)
     if routing is not None:
-        routed, picked = routing
+        routed, picked, _ = routing
         if probe is not None:
             probe.append({"experts_picked": picked})
         cache = {**cache, "stats": _routing_counted(
@@ -530,7 +530,7 @@ def _experts(cfg, i, w, h, valid, cache, track_groups, probe):
     """Expert layer ``i`` on normed rows ``h``: (y, cache with its routing and
     the held experts it touched counted, a pack's and a tick's apart)."""
     s = cfg.latent
-    y, (routed, picked) = lm.ffn(w, h, True, cfg, valid)
+    y, (routed, picked, _) = lm.ffn(w, h, True, cfg, valid)
     if probe is not None:
         probe.append({"experts_picked": picked})
     local = picked - s.held_offset

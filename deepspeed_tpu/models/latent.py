@@ -18,8 +18,9 @@ kernel is copied first: 6 GB a dispatch for the experts; the two norms are
 stacked ``[L, d]``); ``layer_params`` picks layer ``l``'s trees.  The per-token halves of a layer (``attn_inputs``,
 ``indexer_inputs``, ``attn_output``, ``ffn``) are shared by the uncached
 ``forward`` here and by the serving runner (``inference/latent_runner.py``),
-which differ only in where a layer's keys live.  Forward only: there is no
-backward, pipeline or tensor-parallel path for these layers yet.
+which differ only in where a layer's keys live.  There is no pipeline or
+tensor-parallel path for these layers yet, and a backward only where the note
+on training below says so.
 
 A model may instead be made of blocks that are ONE norm and ONE mixer
 (``SINGLE``: ``x <- x + mixer(norm(x))``): a Mamba-2 state-space mixer
@@ -32,22 +33,35 @@ kind; ``block_params`` picks block ``l``'s.
 The families meet in a third (``HYBRID``): blocks of TWO norms whose mixer is a
 recurrence with a matrix state (``gdn``: Gated DeltaNet, ``ops/gdn.py``) or
 gated grouped-query attention (``GatedGqa``: q / k norms, rotary positions on
-part of the head, a sigmoid output gate) of up to TWO kinds in one model, each
+part of the head, a sigmoid output gate or none) of up to TWO kinds in one model, each
 with its own head count and rotary table: ``gattn`` over every key (K / V
 pages) and ``wattn`` over the last ``window`` keys (a K / V ring a slot).  What
 differs between the models of this family is VALUES of the spec: the gate one
-value a channel or a head (``GatedGqa.gate``), YaRN on the rotary table
+value a channel, one a head or absent (``GatedGqa.gate``), YaRN on the rotary table
 (``GatedGqa.rope_scaling``), the feed-forward the expert layer in every block
 or a dense SwiGLU on the ``first_dense`` leading ones, routed by a softmax over
 all experts or by sigmoid scores with a selection bias and ``routed_scale``
 (``routing``), the shared expert behind a gate of its own or not
-(``shared_gate``), the norms' weights zero-centred, ``x^ (1 + w)``, or plain
+(``shared_gate``) or absent (no ``n_shared``, no ``shared_width``), the norms'
+weights zero-centred, ``x^ (1 + w)``, or plain
 (``unit_offset``).  Trees: ``layers/attn_norm``, ``layers/mlp_norm`` (stacked)
 and one tuple per kind (``gdn``, ``gattn``, ``wattn``, ``moe``, and ``mlp`` where
 ``first_dense`` > 0); ``hybrid_params`` picks block ``l``'s.
+
+TRAINING (``CausalLM.loss_fn`` -> ``forward`` under ``jax.grad``, through the
+train engine): the blocks whose mixer is attention over K / V (``gattn``,
+``wattn``, ``gqa``) and whose feed-forward is a SwiGLU or the held experts.
+They attend through the flash dispatcher (``ops/pallas/flash_attention.py``: a
+window is a band of blocks; off the chip the band-masked XLA body), each block
+under ``jax.checkpoint`` as ``cfg.remat`` says, the routers' scores handed out
+for the balance term (``LatentSpec.router_aux_loss_coef``), the blocks' counts
+noted for the step's metrics (``telemetry.count_in_step``).  A kind with no
+backward yet (key selection, the chunked scans, the delta rule, the latent
+bodies) refuses by its mechanism when its backward is traced (``_forward_only``).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -180,8 +194,8 @@ class Yarn:
 class GatedGqa:
     """Grouped-query attention with RMSNorm on each head's q and k, rotary
     positions (rotate-half) on the first ``rope_dim`` of ``head_dim`` and a
-    sigmoid gate on the output: one value a CHANNEL, projected beside q, or one
-    a HEAD, from a projection of its own (``w_g``)."""
+    sigmoid gate on the output: one value a CHANNEL, projected beside q, one a
+    HEAD, from a projection of its own (``w_g``), or NONE."""
 
     num_heads: int
     num_kv_heads: int
@@ -189,7 +203,7 @@ class GatedGqa:
     rope_dim: int
     rope_theta: float
     window: int = 0              # 0: every key; n: the last n positions, its own included
-    gate: str = "channel"        # 'channel' | 'head'
+    gate: str = "channel"        # 'channel' | 'head' | 'none'
     rope_scaling: Optional[Yarn] = None
 
 
@@ -227,6 +241,9 @@ class LatentSpec:
     every: Optional[LatentAttn] = None  # latent attention over every cached row
     n_group: int = 0
     topk_group: int = 0
+    # > 0: the training loss adds this times the routers' balance term, the mean over
+    # the expert layers of n_routed x sum_e (share of the pairs on e) x (mean score of e)
+    router_aux_loss_coef: float = 0.0
 
     @property
     def single(self) -> bool:
@@ -325,6 +342,9 @@ def _single_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
            "s_up": (d, fs), "s_down": (fs, d)}
     if gated:
         out.update(w_gate=(s.n_held, r, fm), s_gate=(d, fs))
+    if not fs:  # no shared expert
+        for name in ("s_up", "s_down", "s_gate"):
+            out.pop(name, None)
     if s.moe_latent:
         out.update(w_lat_down=(d, r), w_lat_up=(r, d))
     if s.routing == "softmax":
@@ -348,7 +368,7 @@ def _hybrid_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
            "wk": (d, ga.num_kv_heads * ga.head_dim),
            "wv": (d, ga.num_kv_heads * ga.head_dim), "q_norm": (ga.head_dim,),
            "k_norm": (ga.head_dim,), "wo": (ga.num_heads * ga.head_dim, d)}
-    if not per_channel:
+    if ga.gate == "head":
         out["w_g"] = (d, ga.num_heads)
     return out
 
@@ -377,6 +397,38 @@ def param_count(cfg) -> int:
             n += d * s.n_routed + (s.n_routed if s.routing == "sigmoid" else 0)
             n += 3 * d * s.moe_width * (s.n_held + s.n_shared)
     return n
+
+
+def flops_per_token(cfg, seq_len: int) -> float:
+    """Forward + backward FLOPs a token of a causal sequence of ``seq_len``
+    requires of what is HELD here (``CausalLM.flops_per_token``): 6 for each
+    parameter of a matmul the token passes (attention's and a dense layer's
+    matrices, the router, the head's slice, and of the routed experts the
+    ``experts_per_tok x n_held / n_routed`` it meets here in the mean, the
+    shared expert), and attention's (query, key) pairs under each layer's mask
+    at 12 x heads x head_dim a pair.  For the kinds that train (``forward``)."""
+    s, d = cfg.latent, cfg.hidden_size
+    if not s.stateful or any(k in ("mamba", "gdn") for k in s.layer_kinds):
+        refuse("CausalLM.flops_per_token", "only blocks of attention over K / V, SwiGLU and "
+               "held experts are counted (they are the kinds that train)")
+    size = lambda shapes, names: sum(int(np.prod(shapes[n])) for n in names if n in shapes)
+    ex = _single_shapes(d, s, "experts")
+    per_tok = s.experts_per_tok / s.n_routed  # of the held experts' parameters
+    experts = size(ex, ("router", "s_up", "s_gate", "s_down", "w_lat_down", "w_lat_up", "w_sg")) \
+        + per_tok * size(ex, ("w_up", "w_gate", "w_down"))
+    params, pairs = d * cfg.vocab_size, 0.0
+    for l, kind in enumerate(s.layer_kinds):
+        if kind == "experts":
+            params += experts
+            continue
+        mixer = s.mixer(kind)
+        shapes = _hybrid_shapes(d, s, kind) if s.hybrid else _single_shapes(d, s, kind)
+        params += size(shapes, ("wq", "wk", "wv", "wo", "w_g"))
+        pairs += mixer.num_heads * mixer.head_dim \
+            * allowed_pairs(seq_len, getattr(mixer, "window", 0)) / seq_len
+        if s.hybrid:
+            params += 3 * d * cfg.intermediate_size if l < s.first_dense else experts
+    return 6.0 * params + 12.0 * pairs
 
 
 def init_params(rng, cfg, dtype=jnp.float32) -> Params:
@@ -818,7 +870,8 @@ def _gdn_output(gw, o, z, gd: Gdn, eps: float, dtype):
 def gattn_inputs(aw, h, pos, ga: GatedGqa, eps: float, unit_offset: bool = True):
     """h [T, d] at positions ``pos`` [T] -> (q [T, Hq, hd], k, v [T, Hkv, hd],
     the output gate float32: [T, Hq * hd] where each query head's projection is
-    [q | gate], [T, Hq, 1] where the gate is one value a head, ``h @ w_g``); q
+    [q | gate], [T, Hq, 1] where the gate is one value a head, ``h @ w_g``, None
+    where the kind has none); q
     and k normed over the head (``unit_offset``: zero-centred weights) and
     rotated on their first ``rope_dim`` dims.  The barrier as in ``gqa_inputs``."""
     t = h.shape[0]
@@ -827,7 +880,8 @@ def gattn_inputs(aw, h, pos, ga: GatedGqa, eps: float, unit_offset: bool = True)
     if per_channel:
         q, gate = jnp.split(qg.reshape(t, ga.num_heads, 2 * ga.head_dim), 2, axis=-1)
     else:
-        q, gate = qg.reshape(t, ga.num_heads, ga.head_dim), (h @ aw["w_g"])[..., None]
+        q = qg.reshape(t, ga.num_heads, ga.head_dim)
+        gate = (h @ aw["w_g"])[..., None] if ga.gate == "head" else None
     heads = lambda a: a.reshape(t, ga.num_kv_heads, ga.head_dim)
     if ga.rope_dim == ga.head_dim:
         rot = lambda a: _rope(a, pos, ga.rope_theta, ga.rope_scaling)
@@ -840,12 +894,14 @@ def gattn_inputs(aw, h, pos, ga: GatedGqa, eps: float, unit_offset: bool = True)
     k, v = rot(normed(heads(k), aw["k_norm"], eps)), heads(v)
     if per_channel:
         gate = gate.reshape(t, -1)
-    return q, k, v, jax.nn.sigmoid(gate.astype(jnp.float32))
+    return q, k, v, None if gate is None else jax.nn.sigmoid(gate.astype(jnp.float32))
 
 
 def gattn_output(aw, o, gate):
     """Attention's output o [T, Hq, hd] times the gate (a channel's [T, Hq *
-    hd] or a head's [T, Hq, 1]), through ``W_o``."""
+    hd], a head's [T, Hq, 1] or None), through ``W_o``."""
+    if gate is None:
+        return o.reshape(o.shape[0], -1) @ aw["wo"]
     if gate.ndim == 3:
         y = (o.astype(jnp.float32) * gate).reshape(o.shape[0], -1)
     else:
@@ -855,7 +911,7 @@ def gattn_output(aw, o, gate):
 
 def ffn(fw, h, is_moe: bool, cfg, valid=None):
     """(output [T, d], and of an expert layer (routing stats, experts picked
-    [T, k]), else None)."""
+    [T, k], every expert's score [T, n_routed]), else None)."""
     if not is_moe:
         return (jax.nn.silu(h @ fw["w_gate"]) * (h @ fw["w_up"])) @ fw["w_down"], None
     from ..moe.layer import moe_block_held
@@ -867,14 +923,28 @@ def ffn(fw, h, is_moe: bool, cfg, valid=None):
 # the uncached forward: every sequence is its own keys
 # ---------------------------------------------------------------------------
 def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
-    """tokens [b, s] -> (logits [b, s, v] | hidden, None, 0.0)."""
+    """tokens [b, s] -> (logits [b, s, v] | hidden, the experts each expert
+    layer's router picked (two-norm blocks: a tuple of [b * s, k], one a layer;
+    None otherwise), the expert layers' balance terms summed: 0.0 unless the
+    spec has a ``router_aux_loss_coef``)."""
+    from ..telemetry import note_step_fact
+
     s_, (b, n) = cfg.latent, tokens.shape
+    if s_.router_aux_loss_coef and not s_.hybrid:
+        refuse("LatentSpec.router_aux_loss_coef", "only the two-norm blocks hand out "
+               "their routers' scores")
+    note_step_fact("tokens", b * n)
+    note_step_fact("layers_with_experts", len(s_.expert_layers))
     pos = jnp.tile(jnp.arange(n), b)
     x = params["embed"]["embedding"][tokens.reshape(-1)].astype(cfg.dtype)
     grouped = lambda a: a.reshape(b, n, *a.shape[1:])
-    if s_.stateful:
-        blocks = _single_blocks if s_.single else _hybrid_blocks
-        return _head(params, blocks(params["layers"], x, b, n, cfg), b, n, cfg, return_hidden)
+    if s_.single:
+        return _head(params, _single_blocks(params["layers"], x, b, n, cfg), b, n, cfg,
+                     return_hidden)
+    if s_.hybrid:
+        x, aux, picks = _hybrid_blocks(params["layers"], x, b, n, cfg)
+        out, _, aux = _head(params, x, b, n, cfg, return_hidden, aux)
+        return out, picks, aux
     for l in range(cfg.num_layers):
         kind, (n1, n2), aw, fw, is_moe = layer_params(params["layers"], l, s_)
         a = s_.attn(kind)
@@ -893,22 +963,25 @@ def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
 
             o = jax.vmap(group)(grouped(q_i), grouped(w), grouped(k_i), q_pos,
                                 q_abs, rows)
+            o = _forward_only(o, "attention over the keys an indexer SELECTS")
         else:
             # 'every' is a window no shorter than the sequence: every key s <= t
             o = la.window_attention(q_abs, q_pos, rows, q_pos, a.window or n, a.kv_rank,
                                     a.scale)
+            o = _forward_only(o, "the latent-attention bodies (ops/latent_attention.py)")
         x = x + attn_output(aw, o.reshape(b * n, *o.shape[2:]), gate, a).astype(x.dtype)
         h = rms(x, n2["scale"], cfg.norm_eps)
         x = x + ffn(fw, h, is_moe, cfg)[0].astype(x.dtype)
     return _head(params, x, b, n, cfg, return_hidden)
 
 
-def _head(params: Params, x, b: int, n: int, cfg, return_hidden: bool):
+def _head(params: Params, x, b: int, n: int, cfg, return_hidden: bool, aux=0.0):
     """The final norm and the head on token rows x [b * n, d]."""
     x = norm(x, params["final_norm"]["scale"], cfg).reshape(b, n, -1)
+    aux = jnp.asarray(aux, jnp.float32)
     if return_hidden:
-        return x, None, jnp.asarray(0.0, jnp.float32)
-    return x @ params["lm_head"]["kernel"], None, jnp.asarray(0.0, jnp.float32)
+        return x, None, aux
+    return x @ params["lm_head"]["kernel"], None, aux
 
 
 def _single_blocks(layers: Params, x, b: int, n: int, cfg):
@@ -920,46 +993,113 @@ def _single_blocks(layers: Params, x, b: int, n: int, cfg):
         h = rms(x, scale, cfg.norm_eps)
         if kind == "mamba":
             y = _chunked_uncached(mamba_chunks, w, grouped(h), s_.mamba, cfg.norm_eps)
-            y = y.reshape(b * n, -1)
+            y = _forward_only(y.reshape(b * n, -1), "the chunked state-space scan (ops/ssm.py)")
         elif kind == "gqa":
-            y = _causal_gqa(*gqa_inputs(w, h, s_.gqa), b, n).reshape(b * n, -1) @ w["wo"]
+            y = _attend(*gqa_inputs(w, h, s_.gqa), b, n, 0, cfg).reshape(b * n, -1) @ w["wo"]
         else:
             y = ffn(w, h, True, cfg)[0]
         x = x + y.astype(x.dtype)
     return x
 
 
-def _causal_gqa(q, k, v, b: int, n: int, window: int = 0):
-    """Dense causal attention of ``b`` sequences of ``n`` rows each: q [b * n,
-    Hq, hd], k and v [b * n, Hkv, hd] -> [b * n, Hq, hd]; with ``window``, over
-    the last ``window`` keys, a row's own included."""
-    hd, rep = q.shape[-1], q.shape[1] // k.shape[1]
-    q, k, v = (a.reshape(b, n, *a.shape[1:]) for a in (q, k, v))
-    sc = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, n, -1, rep, hd), k
-                    ).astype(jnp.float32) * hd ** -0.5
-    back = jnp.arange(n)[:, None] - jnp.arange(n)[None, :]
-    sc = jnp.where((back >= 0) & (back < window) if window else back >= 0, sc, -jnp.inf)
-    o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(sc, -1).astype(v.dtype), v)
-    return o.reshape(b * n, -1, hd)
+def _attend(q, k, v, b: int, n: int, window: int, cfg):
+    """Causal attention of ``b`` sequences of ``n`` rows each through the body
+    ``cfg.attn_impl`` names (the flash dispatcher: the Pallas kernels on the
+    chip where their gate takes the shape, the band-masked XLA body elsewhere):
+    q [b * n, Hq, hd], k and v [b * n, Hkv, hd] -> [b * n, Hq, hd]; with
+    ``window``, over the last ``window`` keys, a row's own included.  Inputs
+    and output are ``remat='selective'``'s save points."""
+    from ..ops.attention import get_attention_impl
+    from .transformer import _ckpt_name
+
+    q, k, v = (_ckpt_name(a.reshape(b, n, *a.shape[1:]), name)
+               for a, name in ((q, "save_q"), (k, "save_k"), (v, "save_v")))
+    with jax.named_scope("attn_window" if window else "attn_full"):
+        o = get_attention_impl(cfg.attn_impl)(q, k, v, causal=True,
+                                              **({"window": window} if window else {}))
+    return _ckpt_name(o, "save_attn").reshape(b * n, *o.shape[2:])
+
+
+def allowed_pairs(n: int, window: int = 0) -> int:
+    """(query, key) pairs a causal sequence of ``n`` rows attends: every key at
+    or before a row, the last ``window`` of them where there is a window."""
+    w = min(window, n) if window else n
+    return w * (w + 1) // 2 + (n - w) * w
 
 
 def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
-    """The uncached forward's two-norm blocks (``HYBRID``); x [b * n, d]."""
+    """The uncached forward's two-norm blocks (``HYBRID``); x [b * n, d] ->
+    (x, the expert layers' balance terms summed, their routers' picks, a
+    layer each).  Each block runs under
+    ``jax.checkpoint`` as ``cfg.remat`` says and hands its counts out, which
+    are noted for the step's metrics here, outside it."""
+    from ..telemetry import count_in_step
+    from .transformer import checkpointed
+
     s_, eps = cfg.latent, cfg.norm_eps
     pos = jnp.tile(jnp.arange(n), b)
-    for l in range(cfg.num_layers):
-        kind, (n1, n2), mw, fw, is_moe = hybrid_params(layers, l, s_)
+    balance = s_.router_aux_loss_coef > 0
+
+    def block(x, n1, n2, mw, fw, *, kind: str, is_moe: bool):
         h = norm(x, n1, cfg)
         if kind == "gdn":
             y = _chunked_uncached(gdn_chunks, mw, h.reshape(b, n, -1), s_.gdn, eps)
-            y = y.reshape(b * n, -1)
+            y = _forward_only(y.reshape(b * n, -1), "the chunked delta rule (ops/gdn.py)")
         else:
             ga = s_.mixer(kind)
             q, k, v, gate = gattn_inputs(mw, h, pos, ga, eps, s_.unit_offset)
-            y = gattn_output(mw, _causal_gqa(q, k, v, b, n, ga.window), gate)
+            y = gattn_output(mw, _attend(q, k, v, b, n, ga.window, cfg), gate)
         x = x + y.astype(x.dtype)
-        x = x + ffn(fw, norm(x, n2, cfg), is_moe, cfg)[0].astype(x.dtype)
-    return x
+        y, routing = ffn(fw, norm(x, n2, cfg), is_moe, cfg)
+        stats, picks, scores = routing if is_moe else (None, None, None)
+        term = None
+        if balance and is_moe:
+            # n_routed x sum_e (e's share of the (token, pick) pairs: no gradient)
+            #                  x (the mean over the tokens of e's score)
+            with jax.named_scope("router"):
+                share = jnp.mean(jnp.any(picks[..., None] == jnp.arange(s_.n_routed), axis=1),
+                                 axis=0, dtype=jnp.float32) / s_.experts_per_tok
+                term = s_.n_routed * jnp.sum(share * jnp.mean(scores, axis=0))
+        return x + y.astype(x.dtype), stats, term, picks
+
+    aux, handed = jnp.zeros((), jnp.float32), []
+    for l in range(cfg.num_layers):
+        kind, (n1, n2), mw, fw, is_moe = hybrid_params(layers, l, s_)
+        run = checkpointed(functools.partial(block, kind=kind, is_moe=is_moe), cfg.remat,
+                           prevent_cse=True)
+        x, stats, term, picks = run(x, n1, n2, mw, fw)
+        if picks is not None:
+            handed.append(picks)
+        if term is not None:
+            aux = aux + term
+        if stats is not None:
+            for name, value in zip(("expert_pairs_routed", "expert_pairs_held",
+                                    "expert_rows_max", "expert_rows_min"), stats):
+                count_in_step(name, value)
+        if kind != "gdn":
+            count_in_step("causal_keys", jnp.float32(b * allowed_pairs(n)))
+            count_in_step("window_keys_attended",
+                          jnp.float32(b * allowed_pairs(n, s_.mixer(kind).window)))
+    return x, aux, tuple(handed)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _forward_only(y, mechanism: str):
+    """``y``, through a mixer that has no backward yet: differentiating it
+    refuses by ``mechanism`` instead of handing back a gradient nobody checked."""
+    return y
+
+
+def _forward_only_fwd(y, mechanism):
+    return y, None
+
+
+def _forward_only_bwd(mechanism, _, g):
+    refuse("training (a gradient through this layer)",
+           f"{mechanism} has a forward and a serving path and no checked backward yet")
+
+
+_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
 
 
 def _chunked_uncached(chunks, w, h, mixer, eps: float):

@@ -111,7 +111,8 @@ class TransformerConfig:
     # feed-forward per layer, of which a share may be held here.  Set, it
     # replaces the one-kind layer the fields above describe (the widths they
     # share stay: hidden, dense intermediate, vocabulary, depth, norm_eps).
-    # Forward and serving only.
+    # Served through InferenceEngineV2; trained where models/latent.py's note
+    # on training says (attention over K / V, SwiGLU or held experts).
     latent: Optional[Any] = None
 
     @property
@@ -332,6 +333,44 @@ def _ckpt_name(x: jnp.ndarray, name: str) -> jnp.ndarray:
 # 2 × [b,s,f] + 5 × [b,s,d] (f = 4d), at ~18% extra matmul FLOPs vs
 # remat='none' (vs +33% for remat='full').
 _SELECTIVE_SAVE_NAMES = ("save_q", "save_k", "save_v", "save_attn")
+
+
+def checkpointed(body: Callable, remat: str, prevent_cse: bool = False) -> Callable:
+    """``body`` under ``jax.checkpoint`` as ``cfg.remat`` names it (``'none'``:
+    as it is).  ``prevent_cse=False`` is for a body that ``lax.scan`` runs."""
+    if remat == "full":
+        return jax.checkpoint(body, prevent_cse=prevent_cse)
+    if remat == "dots":
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+            prevent_cse=prevent_cse,
+        )
+    if remat == "selective":
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *_SELECTIVE_SAVE_NAMES
+            ),
+            prevent_cse=prevent_cse,
+        )
+    if remat == "offload":
+        # FPDT-style host offload (reference sequence/fpdt_layer.py:510
+        # _FPDTGPUOffloadingAttentionImpl_ / SequenceChunk:462): the
+        # per-layer save points move to pinned host memory, bounding
+        # device activation memory for multi-million-token sequences;
+        # XLA streams them back during backward
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.save_and_offload_only_these_names(
+                names_which_can_be_saved=[],
+                names_which_can_be_offloaded=list(_SELECTIVE_SAVE_NAMES),
+                offload_src="device",
+                offload_dst="pinned_host",
+            ),
+            prevent_cse=prevent_cse,
+        )
+    return body
 
 
 def attention_block(
@@ -716,38 +755,7 @@ def forward(
                 )
             return h_new, (new_cache, aux)
 
-        if cfg.remat == "full":
-            body = jax.checkpoint(body, prevent_cse=False)
-        elif cfg.remat == "dots":
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-                prevent_cse=False,
-            )
-        elif cfg.remat == "selective":
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *_SELECTIVE_SAVE_NAMES
-                ),
-                prevent_cse=False,
-            )
-        elif cfg.remat == "offload":
-            # FPDT-style host offload (reference sequence/fpdt_layer.py:510
-            # _FPDTGPUOffloadingAttentionImpl_ / SequenceChunk:462): the
-            # per-layer save points move to pinned host memory, bounding
-            # device activation memory for multi-million-token sequences;
-            # XLA streams them back during backward
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.save_and_offload_only_these_names(
-                    names_which_can_be_saved=[],
-                    names_which_can_be_offloaded=list(_SELECTIVE_SAVE_NAMES),
-                    offload_src="device",
-                    offload_dst="pinned_host",
-                ),
-                prevent_cse=False,
-            )
+        body = checkpointed(body, cfg.remat)
 
         layer_params = params["layers"]
         x, (new_caches, aux_losses) = jax.lax.scan(
@@ -847,11 +855,18 @@ class CausalLM:
         return inputs, labels, segment_ids, layer_keep
 
     def loss_fn(self, params, batch, rng=None):
+        return self.loss_and_picks(params, batch, rng)[0]
+
+    def loss_and_picks(self, params, batch, rng=None):
+        """(the loss ``loss_fn`` returns, the experts each expert layer's router
+        picked on the way: ``models/latent.py: forward``'s second result, None
+        for a model that has none).  Selection is discontinuous: who compares a
+        gradient with another implementation's holds the other to these picks."""
         inputs, labels, segment_ids, layer_keep = self.prepare_batch(batch, rng)
         if self.cfg.loss_chunk_size:
             from ..sequence.cross_entropy import chunked_cross_entropy
 
-            hidden, _, aux = forward(
+            hidden, picks, aux = forward(
                 params, inputs, self.cfg, segment_ids=segment_ids,
                 return_hidden=True, stack_apply=self.stack_apply,
                 layer_keep=layer_keep,
@@ -863,7 +878,7 @@ class CausalLM:
                     head_bias=head_bias_vec(params),
                 )
         else:
-            logits, _, aux = forward(
+            logits, picks, aux = forward(
                 params, inputs, self.cfg, segment_ids=segment_ids,
                 stack_apply=self.stack_apply, layer_keep=layer_keep,
             )
@@ -871,7 +886,11 @@ class CausalLM:
                 loss = cross_entropy_loss(logits, labels)
         if self.cfg.moe_num_experts > 0:
             loss = loss + self.cfg.moe_aux_loss_coef * aux / max(self.cfg.num_layers, 1)
-        return loss
+        spec = self.cfg.latent
+        if spec is not None and spec.router_aux_loss_coef:
+            # the routers' balance term, a mean over the layers that hold experts
+            loss = loss + spec.router_aux_loss_coef * aux / len(spec.expert_layers)
+        return loss, picks if spec is not None else None
 
     @property
     def tp_rules(self):
@@ -885,10 +904,9 @@ class CausalLM:
         """Approximate training FLOPs/token (6N + attention quadratic term)."""
         c = self.cfg
         if c.latent is not None:
-            from .latent import refuse
+            from .latent import flops_per_token
 
-            refuse("CausalLM.flops_per_token", "6N + 12Lds is not this model's cost "
-                   "(a token touches a share of the experts, attends selected keys)")
+            return flops_per_token(c, seq_len)
         n = c.param_count
         attn = 12 * c.num_layers * c.hidden_size * seq_len
         return 6.0 * n + attn
@@ -902,6 +920,8 @@ def tp_rules(cfg: TransformerConfig):
     shard the vocab dim.  The leading dim of layer weights is the layer dim
     (scanned), never sharded.  Replaces AutoTP (module_inject/auto_tp.py:193).
     """
+    if cfg.latent is not None:
+        return []  # no rule is written for layers of several kinds (initialize refuses model > 1)
     moe = cfg.moe_num_experts > 0
     rules = [
         (r"layers/attn/w[qkv]$", P(None, None, MODEL_AXIS)),

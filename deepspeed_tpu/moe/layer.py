@@ -68,8 +68,9 @@ def moe_block_dropless(lw: Any, x: jnp.ndarray, cfg) -> tuple[jnp.ndarray, jnp.n
     would otherwise make routing depend on batch padding — a packed/padded
     prefill would route REAL tokens differently than the same prompt alone.
     Dense-all-experts formulation (E× FFN flops, exact): fine at decode
-    shapes and tolerable at prefill; a grouped-matmul kernel is the
-    optimization path if MoE serving becomes hot.
+    shapes and tolerable at prefill; the grouped matmul over (token, expert)
+    pairs sorted by expert is ``moe_block_held`` below (``models/latent.py``'s
+    expert layer, served and trained).
     """
     from ..models.transformer import _activation
 
@@ -238,21 +239,65 @@ class MoE:
 
 
 # ---------------------------------------------------------------------------
-# a HELD share of routed experts (serving; models/latent.py)
+# a HELD share of routed experts (models/latent.py: served and trained)
 # ---------------------------------------------------------------------------
-_GMM_ROWS = 128  # the grouped matmul's row tile
+_GMM_ROWS = 128  # the grouped matmul's row tile (and what a group's rows are padded to)
 
 
 def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
-    """(tm, tk, tn) of the grouped-matmul kernel: 128 rows (a group is a few
-    dozen rows here, so a taller tile would be padding) and a weight tile of
-    up to 1.6 M elements (3 MB, double-buffered): (128, 1024, 1536) and (128,
+    """(tm, tk, tn) of the grouped-matmul kernel: 128 rows (a served group is a
+    few dozen rows, so a taller tile would be padding) and a weight tile of up
+    to 1.6 M elements (3 MB, double-buffered): (128, 1024, 1536) and (128,
     512, 2560) at d 5120 x f 1536, the fastest of six tried on the chip, all
     within 12% (0.89 and 0.90 ms a call against 0.61 ms for reading the
-    weights; my chip run, PR 29)."""
+    weights; my chip run, PR 29).  The backward's kernels take the same rule at
+    their own k and n (a training step hands a group thousands of rows: a
+    taller row tile there is ROADMAP S13's to measure)."""
     tk = next(t for t in (1024, 512, 256, 128) if k % t == 0)
     tn = max(t for t in range(128, n + 1, 128) if n % t == 0 and tk * t <= 1600 * 1024)
     return _GMM_ROWS, tk, tn
+
+
+def _interpreted() -> bool:
+    """Whether the ``cfg.latent`` kernels' one switch asks for interpret mode
+    (``ops/pallas/selected_attention.interpreted()``: the tests' way onto the
+    kernel path off the chip)."""
+    from ..ops.pallas.index_scores import interpret
+
+    return interpret()
+
+
+@jax.custom_vjp
+def gmm(xs, w, sizes):
+    """megablox ``gmm`` with this module's tilings in the backward too: the
+    stock VJP hands the forward's (tm, tk, tn) to both backward kernels, whose
+    k and n are the forward's n and k.  Rows of no group get NO gradient."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as kernel
+
+    return kernel(xs, w, sizes, preferred_element_type=xs.dtype,
+                  tiling=_gmm_tiling(*w.shape[1:]), interpret=_interpreted())
+
+
+def _gmm_fwd(xs, w, sizes):
+    return gmm(xs, w, sizes), (xs, w, sizes)
+
+
+def _gmm_bwd(res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as kernel, tgmm
+
+    xs, w, sizes = res
+    (m, k), n = xs.shape, w.shape[-1]
+    d_xs = kernel(g, w, sizes, preferred_element_type=xs.dtype,
+                  tiling=_gmm_tiling(n, k), transpose_rhs=True, interpret=_interpreted())
+    # the kernel leaves the rows past the last group as they lay in memory
+    d_xs = jnp.where((jnp.arange(m) < jnp.sum(sizes))[:, None], d_xs, 0)
+    d_w = tgmm(xs.swapaxes(0, 1), g, sizes, preferred_element_type=w.dtype,
+               tiling=_gmm_tiling(k, n), num_actual_groups=w.shape[0],
+               interpret=_interpreted())
+    return d_xs, d_w, None
+
+
+gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
@@ -262,34 +307,33 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.n
     Pallas kernel, which visits only the row tiles that hold a group's rows
     and reads each group's weights once per tile (``M`` padded up to a whole
     row tile); elsewhere, and for ``k`` / ``n`` its tiles do not divide,
-    ``lax.ragged_dot``."""
+    ``lax.ragged_dot``.  Differentiable either way (``gmm``'s VJP above: the
+    same kernel for the rows' gradient, ``tgmm`` for the weights')."""
     from ..ops.pallas import note_dispatch, on_tpu
 
     m, k = xs.shape
     n = w.shape[-1]
-    if not on_tpu():
+    if not (on_tpu() or _interpreted()):
         note_dispatch("expert_gmm", False, (m, k, n), reason="not on a TPU")
     elif k % 128 or n % 128:
         note_dispatch("expert_gmm", False, (m, k, n),
                       reason="k and n must be multiples of 128")
     else:
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
         note_dispatch("expert_gmm", True, (m, k, n))
         if m % _GMM_ROWS:  # rows of no group, up to a whole row tile
             xs = jnp.pad(xs, ((0, -m % _GMM_ROWS), (0, 0)))
-        return gmm(xs, w, sizes, preferred_element_type=xs.dtype,
-                   tiling=_gmm_tiling(k, n))[:m]
+        return gmm(xs, w, sizes)[:m]
     return jax.lax.ragged_dot(xs, w, sizes, preferred_element_type=xs.dtype)
 
 
-def held_routing(lw: Any, x: jnp.ndarray, spec) -> tuple[jnp.ndarray, jnp.ndarray]:
+def held_routing(lw: Any, x: jnp.ndarray, spec):
     """The routing in the form the spec names (``routing``, chosen when the
     program is traced).  'sigmoid': DeepSeek-V3's ``noaux_tc`` without groups:
     sigmoid scores in float32, the ``experts_per_tok`` largest of score + bias
     picked, weights the picked scores normalised (the bias selects, it does not
     weigh), times ``routed_scale``.  'softmax' and 'group_limited': below.
-    x [T, d] -> (experts [T, k], weights [T, k] float32)."""
+    x [T, d] -> (experts [T, k], weights [T, k] float32, every expert's score
+    [T, n_routed] float32: the softmax's or the sigmoid's)."""
     scores = x.astype(jnp.float32) @ lw["router"].astype(jnp.float32)
     if spec.routing == "group_limited":
         # DeepSeek-V2's ``group_limited_greedy``: softmax over ALL experts in
@@ -306,17 +350,17 @@ def held_routing(lw: Any, x: jnp.ndarray, spec) -> tuple[jnp.ndarray, jnp.ndarra
         kept = jnp.any(groups[:, :, None] == jnp.arange(spec.n_group)[None, None, :], axis=1)
         inside = jnp.repeat(kept, e // spec.n_group, axis=1)
         picked, idx = jax.lax.top_k(jnp.where(inside, s, -1.0), spec.experts_per_tok)
-        return idx, picked * spec.routed_scale
+        return idx, picked * spec.routed_scale, s
     if spec.routing == "softmax":
         # softmax over ALL experts in float32, the largest picked and
         # renormalised (``norm_topk_prob``); no bias, no scale
         s = jax.nn.softmax(scores, axis=-1)
         picked, idx = jax.lax.top_k(s, spec.experts_per_tok)
-        return idx, picked / jnp.sum(picked, -1, keepdims=True)
+        return idx, picked / jnp.sum(picked, -1, keepdims=True), s
     s = jax.nn.sigmoid(scores)
     _, idx = jax.lax.top_k(s + lw["bias"], spec.experts_per_tok)
     picked = jnp.take_along_axis(s, idx, axis=-1)
-    return idx, picked / jnp.sum(picked, -1, keepdims=True) * spec.routed_scale
+    return idx, picked / jnp.sum(picked, -1, keepdims=True) * spec.routed_scale, s
 
 
 def _padded_source(sizes: jnp.ndarray, rows: int, tile: int) -> jnp.ndarray:
@@ -347,8 +391,10 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     it, without the exchange: route every token over all ``n_routed`` experts,
     compute the picks that fall on the ``n_held`` experts held here by a
     grouped matmul over (token, expert) pairs sorted by expert, add the shared
-    expert.  The result is this member's PARTIAL sum; the members' results,
-    the shared expert counted once, add up to the uncut layer's output.
+    expert (a model with none has no ``s_up``).  The result is this member's
+    PARTIAL sum; the members' results, the shared expert counted once, add up
+    to the uncut layer's output.  Differentiable end to end (``grouped_matmul``;
+    the gathers' transposes scatter into live rows only).
 
     The experts' form comes from the spec: ``expert_form`` 'swiglu' (``w_gate``,
     ``w_up``, ``w_down``) or 'relu2' (``w_up``, ``relu(.)^2``, ``w_down``: no gate
@@ -361,12 +407,14 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     x [T, d]; ``valid`` [T] bool masks padding rows out of routing.  Returns
     (y [T, d], (stats int32 [4]: pairs routed, pairs on held experts, rows of
     the largest and of the smallest held expert's group; the experts picked
-    [T, k]))."""
+    [T, k]; every routed expert's score [T, n_routed] float32, what a
+    load-balancing loss is made of))."""
     t, d = x.shape
     k, g = spec.experts_per_tok, spec.n_held
     gated = spec.expert_form == "swiglu"
     relu2 = lambda a: jnp.square(jax.nn.relu(a))
-    idx, wts = held_routing(lw, x, spec)
+    with jax.named_scope("router"):
+        idx, wts, scores = held_routing(lw, x, spec)
     local = idx - spec.held_offset
     held = (local >= 0) & (local < g)
     if valid is not None:
@@ -399,14 +447,17 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     if spec.moe_latent:
         with jax.named_scope("latent_proj"):
             y = y.astype(x.dtype) @ lw["w_lat_up"]
-    if gated:
-        shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
-    else:
-        shared = relu2(x @ lw["s_up"]) @ lw["s_down"]
-    if spec.shared_gate:  # the shared expert behind a gate of its own
-        gate = jax.nn.sigmoid((x @ lw["w_sg"]).astype(jnp.float32))
-        shared = (shared.astype(jnp.float32) * gate).astype(x.dtype)
+    shared = None
+    if "s_up" in lw:
+        if gated:
+            shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
+        else:
+            shared = relu2(x @ lw["s_up"]) @ lw["s_down"]
+        if spec.shared_gate:  # the shared expert behind a gate of its own
+            gate = jax.nn.sigmoid((x @ lw["w_sg"]).astype(jnp.float32))
+            shared = (shared.astype(jnp.float32) * gate).astype(x.dtype)
     n_valid = t if valid is None else jnp.sum(valid, dtype=jnp.int32)
     stats = jnp.stack([jnp.asarray(n_valid * k, jnp.int32),
                        jnp.sum(held, dtype=jnp.int32), jnp.max(sizes), jnp.min(sizes)])
-    return (y.astype(x.dtype) + shared), (stats, idx)
+    y = y.astype(x.dtype) if shared is None else y.astype(x.dtype) + shared
+    return y, (stats, idx, scores)

@@ -59,6 +59,7 @@ def dot_product_attention(
     logits_soft_cap: Optional[float] = None,
     attn_mask: Optional[jnp.ndarray] = None,
     bias: Optional[jnp.ndarray] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Reference scaled-dot-product attention.
 
@@ -68,7 +69,9 @@ def dot_product_attention(
     ``attn_mask`` [sq, skv] bool composes with causal/segment masking
     (block-sparse layouts route through here, ops/sparse_attention.py).
     ``bias`` [hq, sq, skv] or per-batch-row [b, hq, sq, skv] adds to the
-    pre-softmax logits (ALiBi).
+    pre-softmax logits (ALiBi).  ``window`` (0: every key): a query sees
+    its last ``window`` keys, its own included (``q - k < window``; the lower
+    edge is ``causal``'s).
     """
     in_dtype = q.dtype
     hq, hkv = q.shape[2], k.shape[2]
@@ -92,6 +95,9 @@ def dot_product_attention(
         logits = jnp.where(allowed, logits, jnp.finfo(jnp.float32).min)
     if attn_mask is not None:
         logits = jnp.where(attn_mask[None, None], logits, jnp.finfo(jnp.float32).min)
+    if window:
+        back = jnp.arange(q.shape[1])[:, None] + q_offset - jnp.arange(k.shape[1])[None, :]
+        logits = jnp.where(back < window, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(in_dtype), v)
     return out

@@ -63,16 +63,20 @@ def flash_attention(
     scale: Optional[float] = None,
     logits_soft_cap: Optional[float] = None,
     mesh=None,
+    window: int = 0,
 ):
     """[b, s, h, d] flash attention: dispatches to the hand-tiled Pallas
-    kernel (flash_kernel.py — causal, GQA, packed segments, soft cap) when
-    ``supports()`` holds for the per-shard shape, else the fused-by-XLA
-    reference body.  ``mesh`` defaults to the ambient mesh
-    (``parallel.sharding.get_current_mesh``); serving passes its own."""
+    kernel (flash_kernel.py — causal, GQA, packed segments, soft cap, a
+    window) when ``supports()`` holds for the per-shard shape, else the
+    fused-by-XLA reference body.  ``mesh`` defaults to the ambient mesh
+    (``parallel.sharding.get_current_mesh``); serving passes its own.
+    ``window`` (0: every key): a query sees its last ``window`` keys, its own
+    included; the reference body masks the band."""
     def reference():
         return dot_product_attention(
             q, k, v, causal=causal, q_offset=q_offset, segment_ids=segment_ids,
             kv_segment_ids=kv_segment_ids, scale=scale, logits_soft_cap=logits_soft_cap,
+            window=window,
         )
 
     from . import flash_kernel as fk
@@ -98,7 +102,7 @@ def flash_attention(
     ok = fk.supports(
         jax.ShapeDtypeStruct(q_l, q.dtype), jax.ShapeDtypeStruct(k_l, k.dtype),
         jax.ShapeDtypeStruct(k_l, v.dtype), causal, q_offset, seg_l,
-        logits_soft_cap,
+        logits_soft_cap, window,
     )
     note_dispatch("flash_fwd", ok, q_l, interpret=fk._INTERPRET,
                   reason="" if ok else "flash_kernel.supports() declined")
@@ -108,7 +112,7 @@ def flash_attention(
     def kernel(q, k, v, seg, kv_seg):
         return fk.pallas_flash_attention(
             q, k, v, causal=causal, scale=scale, segment_ids=seg,
-            kv_segment_ids=kv_seg, logits_soft_cap=logits_soft_cap,
+            kv_segment_ids=kv_seg, logits_soft_cap=logits_soft_cap, window=window,
         )
 
     if mesh is None:

@@ -59,7 +59,7 @@ from ..utils.timer import (
 from . import precision, zero
 from .lr_schedules import LRScheduler, get_lr_schedule_fn
 from .prefetch import DevicePrefetcher, MetricsBuffer, host_scalar
-from ..telemetry import Telemetry, track_program
+from ..telemetry import Telemetry, step_counts, track_program
 
 
 def _now() -> float:
@@ -139,6 +139,9 @@ class StepMetrics(NamedTuple):
     lr: jnp.ndarray
     loss_scale: jnp.ndarray
     skipped: jnp.ndarray  # bool — fp16 overflow skipped the update
+    # what the traced loss counted of itself (telemetry.count_in_step): name ->
+    # scalar, summed over the step's micro-batches; None where it counted nothing
+    counts: Any = None
 
 
 class DeepSpeedTpuEngine:
@@ -357,6 +360,7 @@ class DeepSpeedTpuEngine:
 
         self._train_step = None  # built lazily (needs batch sharding)
         self._step_program = None  # its TrackedProgram (telemetry/programs.py)
+        self._step_facts: dict = {}  # what the traced loss noted of itself (span arguments)
         self._grad_fn = None
         self._apply_fn = None
         self._eval_step = None
@@ -487,12 +491,15 @@ class DeepSpeedTpuEngine:
     # the jitted train step
     # ------------------------------------------------------------------
     def _micro_value_and_grad(
-        self, master_params, micro_batch, rng, scale, step=None, loco_err=None
+        self, master_params, micro_batch, rng, scale, step=None, loco_err=None,
+        counted: Optional[list] = None,
     ):
         """Loss+grads for one micro-batch, w.r.t. fp32 masters, computed
         through compute-dtype casts (the BF16_Optimizer linkage, bf16_optimizer.py:34).
         With LoCo active, also takes/returns the error-feedback pytree:
-        ``(loss, grads, new_err)``."""
+        ``(loss, grads, new_err)``.  ``counted`` (a list) is handed what the
+        traced loss counted of itself (``telemetry.count_in_step``: a dict of
+        scalars, or None); what it noted as facts rides the ``train_batch`` span."""
         if self._zeropp_vag is not None:
             if loco_err is not None:
                 loss, grads, new_err = self._zeropp_vag(
@@ -520,11 +527,15 @@ class DeepSpeedTpuEngine:
                 # engine.py:1959 pld theta update)
                 batch_ = dict(batch_)
                 batch_["pld_theta"] = self.progressive_layer_drop.theta_at(step)
-            loss = self.loss_fn(cp, batch_, rng)
-            return loss * scale
+            with step_counts() as notes:
+                loss = self.loss_fn(cp, batch_, rng)
+            self._step_facts = dict(notes.facts)
+            return loss * scale, (notes.counts or None)
 
         with jax.named_scope("grad"):
-            loss, grads = jax.value_and_grad(scaled_loss)(master_params)
+            (loss, counts), grads = jax.value_and_grad(scaled_loss, has_aux=True)(master_params)
+        if counted is not None:
+            counted.append(counts)
         return loss / scale, grads
 
     def _apply_grads(self, state: TrainState, grad_sum, divisor):
@@ -591,19 +602,20 @@ class DeepSpeedTpuEngine:
                 )
 
             def one_micro(p, micro, r, err):
+                counted = []
                 out = self._micro_value_and_grad(
-                    p, micro, r, scale, state.step, loco_err=err
+                    p, micro, r, scale, state.step, loco_err=err, counted=counted
                 )
                 loss, grads = out[0], out[1]
                 # device-kind layout: grads live in HBM even when masters are
                 # offloaded (only the state pytree itself rides pinned_host)
                 grads = zero.constrain(grads, self.master_shardings_dev,
                                        scope="zero/reduce")
-                return loss, grads, (out[2] if loco else None)
+                return loss, grads, (out[2] if loco else None), (counted or [None])[0]
 
             if gas == 1:
                 micro = jax.tree_util.tree_map(lambda x: x[0], batch)
-                loss, grads, loco_err = one_micro(state.params, micro, rng, loco_err)
+                loss, grads, loco_err, counts = one_micro(state.params, micro, rng, loco_err)
             else:
                 # lax.scan over the gas dimension: grads accumulate in fp32 in
                 # the *master* (ZeRO-sharded) layout, so accumulation memory is
@@ -612,17 +624,18 @@ class DeepSpeedTpuEngine:
                 def body(carry, inp):
                     acc, lsum, err = carry
                     micro, r = inp
-                    loss, grads, err = one_micro(state.params, micro, r, err)
+                    loss, grads, err, counts = one_micro(state.params, micro, r, err)
                     acc = jax.tree_util.tree_map(jnp.add, acc, grads)
-                    return (acc, lsum + loss, err), None
+                    return (acc, lsum + loss, err), counts
 
                 zeros = jax.tree_util.tree_map(
                     lambda x: jnp.zeros(x.shape, jnp.float32), state.params
                 )
                 rngs = jax.random.split(rng, gas)
-                (grads, loss_sum, loco_err), _ = jax.lax.scan(
+                (grads, loss_sum, loco_err), counts = jax.lax.scan(
                     body, (zeros, jnp.asarray(0.0, jnp.float32), loco_err), (batch, rngs)
                 )
+                counts = jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), counts)
                 loss = loss_sum / gas
                 divisor = scale * gas  # fold GAS averaging into the unscale divisor
 
@@ -636,6 +649,7 @@ class DeepSpeedTpuEngine:
                 lr=jnp.asarray(self.lr_schedule_fn(state.step), jnp.float32),
                 loss_scale=scale,
                 skipped=jnp.logical_not(finite),
+                counts=counts,
             )
             if loco:
                 return new_state, metrics, loco_err
@@ -652,6 +666,7 @@ class DeepSpeedTpuEngine:
                 self._train_step = self._make_onebit_train_step(batch)
                 return self._train_step
             step_fn = self._make_train_step()
+            # (a prefix: ``counts`` is a dict of scalars the trace decides, or None)
             metrics_shardings = StepMetrics(
                 *([self._scalar_sharding] * len(StepMetrics._fields))
             )
@@ -1037,7 +1052,7 @@ class DeepSpeedTpuEngine:
             if self._step_program is not None:
                 self._step_program.note(args)
             del args  # the donated state is gone; hold no dead handle
-            tb_span.end(sync_obj=metrics.loss)
+            tb_span.end(sync_obj=metrics.loss, **self._step_facts)
         self._last_metrics = metrics
         self.global_steps += 1
         async_metrics = self.config.train_data.async_metrics
@@ -1053,7 +1068,8 @@ class DeepSpeedTpuEngine:
             self.global_steps,
             metrics,
             keep_history=self.config.fp16.enabled
-            or (self.monitor is not None and self.monitor.enabled),
+            or (self.monitor is not None and self.monitor.enabled)
+            or metrics.counts is not None,  # every step's counts are booked
         )
         self.lr_scheduler.step()
         if self.progressive_layer_drop is not None:
@@ -1381,6 +1397,8 @@ class DeepSpeedTpuEngine:
         emit = self.monitor is not None and self.monitor.enabled
         events = []
         for step, m in self._metrics_buffer.flush():
+            for name, n in (m.counts or {}).items():
+                self.telemetry.registry.counter(name).inc(int(n))
             if fp16 and m.skipped:
                 self.skipped_steps += 1
             if step % self.config.steps_per_print == 0:

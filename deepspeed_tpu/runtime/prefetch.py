@@ -253,12 +253,13 @@ class MetricsBuffer:
         items, self._items = self._items, []
         if not items:
             return []
+        import jax
+
         out = []
         for step, m in items:
             # one dispatch-ordered read per scalar; the first conversion
             # blocks until the step that produced it has executed, the rest
             # are already resident
-            out.append(
-                (step, type(m)(*[host_scalar(v) for v in m]))
-            )
+            # (tree_map: ``StepMetrics.counts`` is a dict of scalars, or None)
+            out.append((step, jax.tree_util.tree_map(host_scalar, m)))
         return out

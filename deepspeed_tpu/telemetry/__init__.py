@@ -41,7 +41,10 @@ from .tracing import (  # noqa: F401
 )
 from .programs import (  # noqa: F401
     collective_bytes_per_step,
+    count_in_step,
+    note_step_fact,
     program_scopes,
+    step_counts,
     track as track_program,
 )
 from .fleet import (  # noqa: F401

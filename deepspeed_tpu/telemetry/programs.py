@@ -15,12 +15,63 @@ after its window, never inside it: ``lower(*shapes).compile()`` then hits
 JAX's in-memory caches, because the shapes carry what the executed arguments
 had (dtype, weak type, and a sharding exactly where the argument was
 committed to one).
+
+A traced program may also say what it COUNTED: a model notes a step's counts
+(``count_in_step``: device scalars, summed by name: (token, expert) pairs
+routed, the keys a window let through) and facts (``note_step_fact``: Python
+numbers known while tracing: tokens, layers with experts) while its loss is
+traced; the training engine collects them (``step_counts``) around the loss it
+differentiates, hands the counts out in the step's ``StepMetrics.counts`` and
+books them into the registry's counters where it flushes its metrics buffer.
 """
 from __future__ import annotations
 
 import re
 import weakref
+import contextlib
+import contextvars
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+
+class StepNotes:
+    """What the program traced inside ``step_counts()`` noted."""
+
+    def __init__(self):
+        self.counts: Dict[str, Any] = {}  # name -> traced scalar, summed by name
+        self.facts: Dict[str, Any] = {}   # name -> a Python number
+
+
+_STEP_NOTES: "contextvars.ContextVar[Optional[StepNotes]]" = contextvars.ContextVar(
+    "step_notes", default=None)
+
+
+@contextlib.contextmanager
+def step_counts() -> Iterator[StepNotes]:
+    """Collect what the function traced inside notes with ``count_in_step`` /
+    ``note_step_fact`` (in this thread).  A value noted under an inner trace
+    (``jax.checkpoint``, ``lax.scan``) must be handed out of it first."""
+    notes = StepNotes()
+    token = _STEP_NOTES.set(notes)
+    try:
+        yield notes
+    finally:
+        _STEP_NOTES.reset(token)
+
+
+def count_in_step(name: str, value) -> None:
+    """Add ``value`` (a scalar, traced or not) to the step's count ``name``;
+    nothing where no step is collecting."""
+    notes = _STEP_NOTES.get()
+    if notes is not None:
+        notes.counts[name] = notes.counts[name] + value if name in notes.counts else value
+
+
+def note_step_fact(name: str, value) -> None:
+    """A Python number the traced step knows of itself (a span's argument)."""
+    notes = _STEP_NOTES.get()
+    if notes is not None:
+        notes.facts[name] = value
+
 
 _TRACKED: "weakref.WeakSet[TrackedProgram]" = weakref.WeakSet()
 

@@ -97,7 +97,7 @@ def test_the_four_members_shares_add_up_to_the_uncut_reference_layer(model):
     got, pairs = jnp.zeros_like(x), 0
     for off in range(0, total, held):
         mine = dict(lw, **{k: lw[k][off:off + held] for k in ("w_gate", "w_up", "w_down")})
-        y, (stats, _) = moe_block_held(mine, x, replace(cfg.latent, held_offset=off))
+        y, (stats, _, _) = moe_block_held(mine, x, replace(cfg.latent, held_offset=off))
         got += y - shared
         pairs += int(stats[1])
     assert pairs == 40 * m["num_experts_per_tok"]  # every pick fell on exactly one member

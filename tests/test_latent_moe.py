@@ -84,7 +84,7 @@ def _uncut(lw, x, spec):
         if spec.shared_gate:
             shared = jax.nn.sigmoid(x @ lw["w_sg"]) * shared
         return _dense_over_experts(lw, x, spec, shared), shared
-    idx, wts = held_routing(lw, x, spec)
+    idx, wts = held_routing(lw, x, spec)[:2]
     lat = x @ lw["w_lat_down"]
     y = jnp.zeros_like(lat)
     for e in range(spec.n_routed):
@@ -96,7 +96,7 @@ def _uncut(lw, x, spec):
 
 def _dense_over_experts(lw, x, spec, shared):
     """Every expert on every token, masked by the routing: the plain form."""
-    idx, wts = held_routing(lw, x, spec)
+    idx, wts = held_routing(lw, x, spec)[:2]
     y = jnp.zeros_like(x)
     for e in range(spec.n_routed):
         w_e = jnp.sum(jnp.where(idx == e, wts, 0.0), -1, keepdims=True)
@@ -122,7 +122,7 @@ def test_the_shares_add_up_to_the_uncut_layer(spec, make, members):
         g = E // members
         mine = dict(lw, **{k: lw[k][r * g:(r + 1) * g] for k in ("w_gate", "w_up", "w_down")
                            if k in lw})
-        y, (stats, _) = moe_block_held(mine, x, replace(spec, n_held=g, held_offset=r * g))
+        y, (stats, _, _) = moe_block_held(mine, x, replace(spec, n_held=g, held_offset=r * g))
         total += y - shared  # every member adds the shared expert: count it once
         held_pairs += int(stats[1])
         assert int(stats[0]) == 24 * K
@@ -137,11 +137,11 @@ def test_the_bias_selects_and_does_not_weigh(n_experts, k, scale):
     lw = {"router": jax.random.normal(jax.random.PRNGKey(2), (D, n_experts)) / np.sqrt(D),
           "bias": jnp.zeros(n_experts)}
     x = jax.random.normal(jax.random.PRNGKey(3), (64, D))
-    idx0, w0 = held_routing(lw, x, spec)
+    idx0, w0 = held_routing(lw, x, spec)[:2]
     assert idx0.shape == (64, k) and all(len(set(r)) == k for r in np.asarray(idx0).tolist())
     np.testing.assert_allclose(np.asarray(w0.sum(-1)), scale, rtol=1e-6)
     pushed = dict(lw, bias=jnp.zeros(n_experts).at[5].set(10.0))
-    idx1, w1 = held_routing(pushed, x, spec)
+    idx1, w1 = held_routing(pushed, x, spec)[:2]
     assert bool(jnp.all(jnp.any(idx1 == 5, -1)))  # the bias decides the selection
     s = jax.nn.sigmoid(x @ lw["router"])
     picked = jnp.take_along_axis(s, idx1, -1)
@@ -158,7 +158,7 @@ def test_softmax_routing_is_over_all_experts_and_renormalised(n_experts, k):
     spec = replace(SPEC_SOFTMAX, n_routed=n_experts, experts_per_tok=k, routed_scale=7.0)
     lw = {"router": jax.random.normal(jax.random.PRNGKey(8), (D, n_experts)) / np.sqrt(D) * 3}
     x = jax.random.normal(jax.random.PRNGKey(9), (64, D))
-    idx, w = held_routing(lw, x, spec)
+    idx, w = held_routing(lw, x, spec)[:2]
     logits = np.asarray(x, np.float64) @ np.asarray(lw["router"], np.float64)
     p = np.exp(logits - logits.max(-1, keepdims=True))
     p /= p.sum(-1, keepdims=True)
@@ -195,7 +195,7 @@ def test_group_limited_routing_against_the_formula_written_out(n_experts, n_grou
                    experts_per_tok=k)
     lw = {"router": jax.random.normal(jax.random.PRNGKey(4), (D, n_experts)) / np.sqrt(D) * 3}
     x = jax.random.normal(jax.random.PRNGKey(5), (96, D))
-    idx, w = held_routing(lw, x, spec)
+    idx, w = held_routing(lw, x, spec)[:2]
     logits = np.asarray(x, np.float64) @ np.asarray(lw["router"], np.float64)
     per = n_experts // n_group
     for t, (picks, scores, kept) in enumerate(_group_limited_by_hand(logits, n_group, topk_group, k)):
@@ -222,7 +222,7 @@ def test_group_limited_routing_under_ties_and_few_groups():
     rows[2, [6, 7]] = [50.0, 49.0]
     # token 3: the softmax underflows to exactly 0 outside group 4: still groups 4, 0, 1
     rows[3, [8, 9]] = [200.0, 199.0]
-    idx, w = held_routing({"router": eye}, jnp.asarray(rows), spec)
+    idx, w = held_routing({"router": eye}, jnp.asarray(rows), spec)[:2]
     assert sorted(np.asarray(idx[0]).tolist()) == [0, 1, 2, 3]
     assert sorted(np.asarray(idx[1]).tolist()) == [0, 10, 11, 14]
     assert sorted(np.asarray(idx[2]).tolist()) == [0, 1, 6, 7]
@@ -258,8 +258,8 @@ def test_held_layer_under_skewed_routing_and_padding():
     valid = jnp.arange(40) < 33
     spec = replace(SPEC, n_held=8, held_offset=0)
     mine = dict(lw, **{k: lw[k][:8] for k in ("w_gate", "w_up", "w_down")})
-    y, (stats, picked) = moe_block_held(mine, x, spec, valid)
-    idx, wts = held_routing(lw, x, SPEC)
+    y, (stats, picked, _) = moe_block_held(mine, x, spec, valid)
+    idx, wts = held_routing(lw, x, SPEC)[:2]
     want = _swiglu(x, lw["s_gate"], lw["s_up"], lw["s_down"])
     for e in range(8):
         w_e = jnp.sum(jnp.where(idx == e, wts, 0.0), -1, keepdims=True)
@@ -333,9 +333,9 @@ def test_held_layer_is_bit_equal_under_either_map(monkeypatch, spec, make, valid
         lw = dict(lw, **{k: lw[k][:8] for k in ("w_gate", "w_up", "w_down")})
         mask = jnp.arange(40) < 33
     x = jax.random.normal(jax.random.PRNGKey(11), (40, D))
-    y, (stats, picked) = moe_block_held(lw, x, spec, mask)
+    y, (stats, picked, _) = moe_block_held(lw, x, spec, mask)
     monkeypatch.setattr(layer, "_padded_source", _source_per_row)
-    y0, (stats0, picked0) = moe_block_held(lw, x, spec, mask)
+    y0, (stats0, picked0, _) = moe_block_held(lw, x, spec, mask)
     assert np.array_equal(np.asarray(y), np.asarray(y0))
     assert np.array_equal(np.asarray(stats), np.asarray(stats0))
     assert np.array_equal(np.asarray(picked), np.asarray(picked0))

@@ -1,0 +1,301 @@
+"""A model of two-norm blocks (``models/latent.py``: window and full GQA with no
+output gate, a held share of softmax-routed experts, no shared expert) TRAINED
+through ``deepspeed_tpu.initialize`` -> ``train_batch``: loss and every gradient
+against the benchmark's plain reference (``benchmark/models/mellum.py``), the
+held shares adding up to the uncut layer, the grouped matmul's gradients, the
+balance term, the step's counts, ZeRO over the per-kind tuples of trees on a
+mesh of four, and what still refuses to train.  CPU, toy widths, float32."""
+import dataclasses
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+import deepspeed_tpu as ds  # noqa: E402
+from benchmark import harness  # noqa: E402
+from deepspeed_tpu.models import CausalLM, latent  # noqa: E402
+from deepspeed_tpu.models.transformer import init_params  # noqa: E402
+from deepspeed_tpu.moe.layer import grouped_matmul, held_routing, moe_block_held  # noqa: E402
+from deepspeed_tpu.ops.pallas.selected_attention import interpreted  # noqa: E402
+from deepspeed_tpu.parallel.topology import initialize_mesh  # noqa: E402
+
+ARCH = harness.module("models", "mellum")
+M = harness.rehearsed(harness.load_json(
+    harness.HERE / "configs" / "mellum2_l4_e16_train_1chip.json"), True)
+SEQ, ROWS = 48, 8
+TOL = 2e-5  # float32 on both sides, O(1) losses and gradients
+
+
+def _cfg(m=M, **kw):
+    kw = {"max_seq_len": SEQ, "remat": "selective", "loss_chunk_size": 16, "attn_impl": "auto", **kw}
+    return ARCH.transformer_config(m, **kw)
+
+
+def _ids(rows=ROWS, seed=5):
+    return np.random.default_rng([2**31 + seed, 1]).integers(
+        0, M["vocab_size"], (rows, SEQ + 1)).astype(np.int32)
+
+
+def _engine(cfg, micro, mesh, optimizer=None):
+    config = {
+        "train_micro_batch_size_per_gpu": micro, "gradient_accumulation_steps": 1,
+        "optimizer": optimizer or {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}},
+        "zero_optimization": {"stage": 3, "param_persistence_threshold": 0},
+        "bf16": {"enabled": False}, "steps_per_print": 10**9, "seed": 7}
+    return ds.initialize(model=CausalLM(cfg), config=config, mesh=mesh)[0]
+
+
+def _leaves(tree):
+    return {jax.tree_util.keystr(k): np.asarray(v)
+            for k, v in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def test_train_batch_gives_the_references_loss_and_every_gradient():
+    """SGD at lr 1 without momentum: the step's update IS its gradient."""
+    cfg, ids = _cfg(), _ids()
+    engine = _engine(cfg, ROWS // 8, initialize_mesh(data=8),
+                     {"type": "sgd", "params": {"lr": 1.0}})
+    before = jax.device_get(engine.state.params)
+    loss = float(engine.train_batch({"input_ids": ids}))
+    after = jax.device_get(engine.state.params)
+    ref_loss, ref = jax.value_and_grad(lambda p: ARCH.loss_on(p, jnp.asarray(ids), M))(before)
+    assert abs(loss - float(ref_loss)) <= TOL
+    got, want = _leaves(jax.tree_util.tree_map(lambda a, b: a - b, before, after)), _leaves(ref)
+    assert set(got) == set(want) and len(got) > 40
+    for name, g in want.items():
+        assert np.isfinite(got[name]).all()
+        assert np.abs(got[name] - g).max() <= TOL * max(1.0, np.abs(g).max()), name
+        # every tensor trains (an expert no token met keeps a zero gradient)
+        assert np.abs(g).max() > 0 or "moe" in name, name
+
+
+@pytest.mark.parametrize("remat", ["none", "full", "selective"])
+def test_a_block_under_any_recomputation_gives_the_same_gradients(remat):
+    ids = jnp.asarray(_ids(2))
+    params = init_params(jax.random.PRNGKey(3), _cfg())
+    grads = lambda r: jax.grad(lambda p: CausalLM(_cfg(remat=r)).loss_fn(p, {"input_ids": ids}))(params)
+    for a, b in zip(jax.tree_util.tree_leaves(grads(remat)), jax.tree_util.tree_leaves(grads("none"))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-5)
+
+
+def test_the_four_shares_outputs_and_input_gradients_add_up_to_the_uncut_layers():
+    total, held, d = M["deployment"]["num_experts_total"], M["num_experts"], M["hidden_size"]
+    whole = dict(M, num_experts=total)
+    spec = _cfg(whole).latent
+    lw = latent.init_params(jax.random.PRNGKey(11), _cfg(whole))["layers"]["moe"][0]
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((64, d)), jnp.float32)
+    ct = jnp.asarray(np.random.default_rng(4).standard_normal((64, d)), jnp.float32)
+
+    def share(i, x):
+        part = {k: (v[i * held:(i + 1) * held] if k.startswith("w_") else v) for k, v in lw.items()}
+        one = _cfg(dict(M, deployment=dict(M["deployment"], expert_offset=i * held))).latent
+        return moe_block_held(part, x, one)[0]
+
+    summed = lambda x: sum(share(i, x) for i in range(total // held))
+    uncut = lambda x: ARCH.uncut_expert_layer(lw, x[None], whole)[0]
+    (y, dx), (y_ref, dx_ref) = (
+        (f(x), jax.vjp(f, x)[1](ct)[0]) for f in (summed, uncut))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_ref), atol=2e-5)
+    # ... and the whole layer in one share is the uncut layer too
+    np.testing.assert_allclose(np.asarray(moe_block_held(lw, x, spec)[0]), np.asarray(y_ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("sizes", [[5, 0, 11, 3], [0, 0, 19, 0], [0, 0, 0, 0], [7, 7, 7, 7]],
+                         ids=["uneven", "one_group", "all_empty", "even"])
+def test_grouped_matmuls_gradients_match_a_dense_einsum(sizes):
+    """Rows sorted by group, some groups EMPTY, rows past the last group:
+    values and both gradients of a dense one-hot einsum, finite everywhere, and
+    a row of no group gets no gradient."""
+    rng = np.random.default_rng(1)
+    m, k, n, g = 32, 16, 24, len(sizes)
+    xs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((g, k, n)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+    group = np.repeat(np.arange(g + 1), sizes + [m - sum(sizes)])  # g: no group
+    onehot = jnp.asarray(group[:, None] == np.arange(g)[None, :], jnp.float32)
+    live = jnp.asarray(group < g)[:, None]
+    dense = lambda xs, w: jnp.einsum("mg,mk,gkn->mn", onehot, xs, w, precision="highest")
+    ours = lambda xs, w: jnp.where(live, grouped_matmul(xs, w, jnp.asarray(sizes, jnp.int32)), 0.0)
+    for got, want in zip(jax.tree_util.tree_leaves((ours(xs, w), jax.vjp(ours, xs, w)[1](ct))),
+                         jax.tree_util.tree_leaves((dense(xs, w), jax.vjp(dense, xs, w)[1](ct)))):
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4)
+    d_xs = jax.vjp(ours, xs, w)[1](ct)[0]
+    assert not np.asarray(d_xs)[group == g].any()
+
+
+def test_the_balance_term_is_its_formula_and_rides_the_loss():
+    import dataclasses
+
+    cfg, ids = _cfg(), jnp.asarray(_ids(2))
+    params = init_params(jax.random.PRNGKey(5), cfg)
+    spec = cfg.latent
+    _, _, aux = latent.forward(params, ids[:, :-1], cfg, return_hidden=True)
+    # the reference's factors, layer by layer: f_e [L, E] (shares of the pairs), P_e [L, E]
+    _, (share, mean, _) = ARCH.hidden_states(params, ids[:, :-1], M)
+    np.testing.assert_allclose(np.asarray(share).sum(-1), 1.0, atol=1e-6)
+    want = spec.n_routed * np.sum(np.asarray(share) * np.asarray(mean), -1)  # a term a layer
+    assert float(aux) == pytest.approx(want.sum(), abs=1e-5)
+    assert (want > 0.9).all() and (want < spec.n_routed).all()  # 1 when even, E when all on one
+    with_term = float(CausalLM(cfg).loss_fn(params, {"input_ids": ids}))
+    bare = cfg.replace(latent=dataclasses.replace(spec, router_aux_loss_coef=0.0))
+    without = float(CausalLM(bare).loss_fn(params, {"input_ids": ids}))
+    assert with_term - without == pytest.approx(
+        spec.router_aux_loss_coef * want.mean(), abs=1e-6)
+    # a perfectly even router reads exactly 1 / E a score
+    lw = {"router": jnp.zeros((cfg.hidden_size, spec.n_routed))}
+    xs = jnp.ones((spec.n_routed * 4, cfg.hidden_size))
+    assert np.allclose(np.asarray(held_routing(lw, xs, spec)[2]), 1.0 / spec.n_routed)
+
+
+def test_the_block_hands_out_every_experts_score_and_the_loss_its_picks():
+    """What the balance term is made of (``moe_block_held``'s third routing
+    result: the softmax over ALL experts, a row a token) and what a reference
+    is held to (``CausalLM.loss_and_picks``: a layer's picks each, the loss
+    ``loss_fn``'s own)."""
+    cfg, ids = _cfg(), jnp.asarray(_ids(2))
+    spec = cfg.latent
+    params = init_params(jax.random.PRNGKey(2), cfg)
+    lw = params["layers"]["moe"][0]
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((40, cfg.hidden_size)), jnp.float32)
+    _, (stats, idx, scores) = moe_block_held(lw, x, spec)
+    np.testing.assert_allclose(np.asarray(scores), np.asarray(jax.nn.softmax(x @ lw["router"], -1)),
+                               atol=1e-6)
+    assert np.array_equal(np.sort(np.asarray(idx), -1),
+                          np.sort(np.argsort(-np.asarray(scores), -1)[:, :spec.experts_per_tok], -1))
+    assert int(stats[0]) == 40 * spec.experts_per_tok
+    model = CausalLM(cfg)
+    loss, picks = model.loss_and_picks(params, {"input_ids": ids})
+    assert float(loss) == float(model.loss_fn(params, {"input_ids": ids}))
+    assert len(picks) == len(spec.expert_layers)
+    assert all(p.shape == (2 * SEQ, spec.experts_per_tok) for p in picks)
+
+
+@pytest.mark.parametrize("sizes", [[1, 650, 0, 37, 128, 300], [0, 0, 5, 0, 0, 0], [256, 128, 384, 0, 128, 128]],
+                         ids=["1_to_650_rows", "five_rows", "tile_aligned"])
+@pytest.mark.parametrize("k,n", [(128, 256), (256, 128)])
+def test_the_kernel_paths_backward_matches_a_dense_einsum(sizes, k, n):
+    """The path the CHIP takes (megablox ``gmm`` and this module's VJP: ``gmm``
+    transposed for the rows, ``tgmm`` for the weights), interpreted: groups of
+    1 to 650 rows that share row tiles, empty groups, rows past the last group;
+    values and both gradients of a dense one-hot einsum, a group at a time."""
+    rng = np.random.default_rng(2)
+    g, m = len(sizes), 1280
+    xs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((g, k, n)) / np.sqrt(k), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+    group = np.repeat(np.arange(g + 1), sizes + [m - sum(sizes)])
+    onehot = jnp.asarray(group[:, None] == np.arange(g)[None, :], jnp.float32)
+    live = jnp.asarray(group < g)[:, None]
+    dense = lambda xs, w: jnp.einsum("mg,mk,gkn->mn", onehot, xs, w, precision="highest")
+    ours = lambda xs, w: jnp.where(live, grouped_matmul(xs, w, jnp.asarray(sizes, jnp.int32)), 0.0)
+    with interpreted():
+        from deepspeed_tpu.ops.pallas import record_dispatch
+
+        with record_dispatch() as log:
+            y, vjp = jax.vjp(ours, xs, w)
+            d_xs, d_w = vjp(ct)
+    assert [d["ran"] for d in log if d["kernel"] == "expert_gmm"] == [True]
+    y_ref, vjp_ref = jax.vjp(dense, xs, w)
+    d_xs_ref, d_w_ref = vjp_ref(ct)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=2e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(d_xs), np.asarray(d_xs_ref), atol=2e-4, rtol=1e-4)
+    for e in range(g):  # an expert of one row is held as closely as one of 650
+        np.testing.assert_allclose(np.asarray(d_w[e]), np.asarray(d_w_ref[e]), atol=5e-4, rtol=1e-4,
+                                   err_msg=f"group {e} of {sizes[e]} rows")
+    assert not np.asarray(d_xs)[group == g].any()
+
+
+def test_fsdp_4_on_a_cpu_mesh_equals_one_device():
+    cfg, ids = _cfg(), _ids(4)
+    losses = {}
+    for name, mesh, micro in (("one", initialize_mesh(data=1, devices=jax.devices()[:1]), 4),
+                              ("fsdp4", initialize_mesh(fsdp=4, devices=jax.devices()[:4]), 1)):
+        engine = _engine(cfg, micro, mesh)
+        losses[name] = [float(engine.train_batch({"input_ids": ids})) for _ in range(3)]
+        if name == "fsdp4":  # the plan shards the per-kind tuples' trees, experts among them
+            specs = {jax.tree_util.keystr(k): v.sharding.spec for k, v in
+                     jax.tree_util.tree_leaves_with_path(engine.state.params)}
+            assert any("fsdp" in str(s) for k, s in specs.items() if "moe" in k and "w_up" in k)
+            assert any("fsdp" in str(s) for k, s in specs.items() if "wattn" in k and "wq" in k)
+    assert losses["one"][0] > losses["one"][2]  # it trains
+    np.testing.assert_allclose(losses["fsdp4"], losses["one"], atol=2e-5)
+
+
+def test_the_steps_counts_are_booked_and_the_span_carries_tokens_and_layers():
+    cfg, ids = _cfg(), _ids()
+    engine = _engine(cfg, ROWS // 8, initialize_mesh(data=8))
+    steps = 3
+    for _ in range(steps):
+        engine.train_batch({"input_ids": ids})
+    engine.get_last_loss()
+    read = lambda k: engine.telemetry.registry.counter(k).value
+    k, layers, tokens = M["num_experts_per_tok"], M["num_hidden_layers"], ROWS * SEQ
+    assert read("expert_pairs_routed") == steps * tokens * k * layers
+    assert 0 < read("expert_pairs_held") < read("expert_pairs_routed")
+    assert read("expert_rows_min") * M["num_experts"] <= read("expert_pairs_held") \
+        <= read("expert_rows_max") * M["num_experts"]
+    w = M["sliding_window"]
+    assert read("causal_keys") == steps * ROWS * layers * SEQ * (SEQ + 1) // 2
+    assert read("window_keys_attended") == steps * ROWS * (
+        3 * latent.allowed_pairs(SEQ, w) + latent.allowed_pairs(SEQ))
+    assert latent.allowed_pairs(SEQ, w) == sum(min(i + 1, w) for i in range(SEQ))
+    assert engine._step_facts == {"tokens": tokens, "layers_with_experts": layers}
+    assert engine._last_metrics.counts.keys() >= {"expert_pairs_routed", "causal_keys"}
+
+
+def _other(model_type):
+    """A configuration of another architecture at its rehearsal size."""
+    arch = harness.module("models", model_type)
+    name = next(c["file"] for c in harness.manifest()["configs"]
+                if harness.load_json(ROOT / c["file"])["model_type"] == model_type)
+    m = harness.rehearsed(harness.load_json(ROOT / name), True)
+    return arch.transformer_config(m, max_seq_len=32), m
+
+
+@pytest.mark.parametrize("model_type,mechanism", [
+    ("dots3_note", "SELECTS|latent-attention"), ("deepseek_v2", "latent-attention bodies"),
+    ("nemotron_h", "state-space scan"), ("qwen3_next", "delta rule")])
+def test_a_kind_with_no_backward_refuses_by_its_mechanism(model_type, mechanism):
+    cfg, m = _other(model_type)
+    params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, m["vocab_size"], (1, 33)), jnp.int32)
+    lm = CausalLM(cfg)
+    # the forward is there (tests/benchmark holds it to its reference); tracing the backward refuses
+    assert jax.eval_shape(lambda p: lm.loss_fn(p, {"input_ids": ids}), params).shape == ()
+    with pytest.raises(NotImplementedError, match=mechanism):
+        jax.eval_shape(jax.grad(lambda p: lm.loss_fn(p, {"input_ids": ids})), params)
+
+
+def test_what_the_layers_of_several_kinds_still_refuse_and_what_they_answer():
+    cfg = _cfg()
+    with pytest.raises(NotImplementedError, match="model=2"):
+        _engine(cfg, 1, initialize_mesh(model=2, devices=jax.devices()[:2]))
+    with pytest.raises(NotImplementedError, match="stack_apply"):
+        CausalLM(cfg, stack_apply=lambda *a: a[1]).loss_fn(
+            init_params(jax.random.PRNGKey(0), cfg), {"input_ids": jnp.asarray(_ids(1))})
+    assert CausalLM(cfg).tp_rules == []
+    # flops_per_token answers from what is HELD here: the reference's count, to the FLOP
+    assert CausalLM(cfg).flops_per_token(SEQ) == pytest.approx(ARCH.train_flops_per_token(M, SEQ))
+    published = harness.load_json(harness.HERE / "configs" / "mellum2_l4_e16_train_1chip.json")
+    big = ARCH.transformer_config(harness.rehearsed(published, False), max_seq_len=8192)
+    assert CausalLM(big).flops_per_token(8192) == pytest.approx(
+        ARCH.train_flops_per_token(published, 8192))
+    with pytest.raises(NotImplementedError, match="flops_per_token"):
+        CausalLM(_other("qwen3_next")[0]).flops_per_token(32)
+
+
+def test_a_block_without_a_gate_or_a_shared_expert_has_neither_in_its_tree():
+    cfg = _cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    for kind in ("wattn", "gattn"):
+        assert set(params["layers"][kind][0]) == {"wq", "wk", "wv", "wo", "q_norm", "k_norm"}
+    assert set(params["layers"]["moe"][0]) == {"router", "w_gate", "w_up", "w_down"}
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params)) == cfg.param_count

@@ -229,7 +229,7 @@ def test_the_four_shares_add_up_to_the_reference_layer(model):
     got, pairs = jnp.zeros_like(x), 0
     for off in range(0, total, held):
         mine = dict(lw, **{k: lw[k][off:off + held] for k in ("w_gate", "w_up", "w_down")})
-        y, (st, picks) = moe_block_held(mine, x, replace(s, held_offset=off))
+        y, (st, picks, _) = moe_block_held(mine, x, replace(s, held_offset=off))
         got += y - shared
         pairs += int(st[1])
         # picks never leave the kept groups: at most 3 of the 8 groups of 4 experts
@@ -238,7 +238,7 @@ def test_the_four_shares_add_up_to_the_reference_layer(model):
     assert float(jnp.abs(got + shared - want).max()) <= 1e-5
     # this member's partial sum is the reference's for the share the file states
     mine = dict(lw, **{k: lw[k][:held] for k in ("w_gate", "w_up", "w_down")})
-    here, (st, _) = moe_block_held(mine, x, s)
+    here, (st, _, _) = moe_block_held(mine, x, s)
     part = arch._experts(mine, x[None], m, None, None)[0]
     assert float(jnp.abs(here - part).max()) <= 1e-5 and 0 < int(st[1]) < 40 * s.experts_per_tok
     # the weights are the scores x 16, not renormalised: either departure reads otherwise

@@ -284,14 +284,14 @@ def test_all_experts_held_is_the_sum_of_four_shares_is_the_reference_layer(model
     lw = params["layers"]["moe"][0]
     x = jax.random.normal(jax.random.PRNGKey(12), (40, cfg.hidden_size))
     want = arch.uncut_expert_layer(lw, x[None], m)[0]
-    whole, (stats, picks) = moe_block_held(lw, x, cfg.latent)
+    whole, (stats, picks, _) = moe_block_held(lw, x, cfg.latent)
     assert int(stats[0]) == int(stats[1]) == 40 * m["num_experts_per_tok"]
     assert float(jnp.abs(whole - want).max()) <= 1e-5
     shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
     got, pairs, held = jnp.zeros_like(x), 0, total // 4
     for off in range(0, total, held):
         mine = dict(lw, **{k: lw[k][off:off + held] for k in ("w_gate", "w_up", "w_down")})
-        y, (st, _) = moe_block_held(mine, x, replace(cfg.latent, n_held=held, held_offset=off))
+        y, (st, _, _) = moe_block_held(mine, x, replace(cfg.latent, n_held=held, held_offset=off))
         got += y - shared
         pairs += int(st[1])
     assert pairs == 40 * m["num_experts_per_tok"]  # every pick fell on exactly one member
