@@ -1033,6 +1033,7 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
     layer each).  Each block runs under
     ``jax.checkpoint`` as ``cfg.remat`` says and hands its counts out, which
     are noted for the step's metrics here, outside it."""
+    from ..moe.layer import held_rows_laid_out
     from ..telemetry import count_in_step
     from .transformer import checkpointed
 
@@ -1076,6 +1077,9 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
             for name, value in zip(("expert_pairs_routed", "expert_pairs_held",
                                     "expert_rows_max", "expert_rows_min"), stats):
                 count_in_step(name, value)
+            rows, bounded = held_rows_laid_out(b * n, s_, stats[1])
+            count_in_step("expert_rows_laid_out", rows)
+            count_in_step("expert_layers_bounded", bounded)
         if kind != "gdn":
             count_in_step("causal_keys", jnp.float32(b * allowed_pairs(n)))
             count_in_step("window_keys_attended",

@@ -242,6 +242,13 @@ class MoE:
 # a HELD share of routed experts (models/latent.py: served and trained)
 # ---------------------------------------------------------------------------
 _GMM_ROWS = 128  # the grouped matmul's row tile (and what a group's rows are padded to)
+# the held pairs one pass of the bounded layout has room for, over the member's
+# share under uniform routing (``held_rows_bound``): a share that drifts, or a
+# router that prefers the held experts by half again, still takes ONE pass
+_HELD_ROWS_FACTOR = 2
+# ... and the pairs a layer routes, over the groups' padding, from which the
+# bounded layout is built at all (``held_rows_bound``)
+_BOUNDED_MIN_PAIRS_PER_PADDING = 16
 
 
 def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
@@ -363,14 +370,52 @@ def held_routing(lw: Any, x: jnp.ndarray, spec):
     return idx, picked / jnp.sum(picked, -1, keepdims=True) * spec.routed_scale, s
 
 
-def _padded_source(sizes: jnp.ndarray, rows: int, tile: int) -> jnp.ndarray:
+def held_rows_bound(t: int, spec) -> Optional[int]:
+    """The held pairs ONE pass of ``moe_block_held``'s bounded layout has room
+    for at ``t`` tokens, None where it builds no such layout.  Twice the pairs a
+    member holds under uniform routing (``_HELD_ROWS_FACTOR``), up to a whole
+    row tile: from the spec and the shape alone, never tuned to a run.
+
+    The bound pays where the pairs a layer routes, ``t k``, outnumber the
+    groups' padding ``g x tile`` many times over: the padding stays whatever
+    the bound, and what the bound saves is about half of ``t k`` rows.  A
+    training step's 16 384 tokens stand at 64 x the padding; every served pack
+    and tick at 4 x or under (a few thousand rows of a scope that is a tenth of
+    a pack), and nothing was measured in between, so the threshold is the
+    geometric middle of the two, ``_BOUNDED_MIN_PAIRS_PER_PADDING`` = 16:
+    neither side stands near it.  Below it the function traces as it did before
+    there was a bound."""
+    pairs, padding = t * spec.experts_per_tok, spec.n_held * _GMM_ROWS
+    if pairs < _BOUNDED_MIN_PAIRS_PER_PADDING * padding:
+        return None
+    bound = _HELD_ROWS_FACTOR * -(-pairs * spec.n_held // spec.n_routed)
+    bound = -(-bound // _GMM_ROWS) * _GMM_ROWS
+    return bound if bound + padding < pairs else None  # a member of two holds them all
+
+
+def held_rows_laid_out(t: int, spec, pairs_held):
+    """What ``moe_block_held`` ran at ``t`` tokens with ``pairs_held`` (a traced
+    scalar) pairs on the held experts: (the rows it handed the grouped matmul,
+    over all its passes; 1 where ONE bounded pass held every pair), int32
+    scalars for the step's counts."""
+    padding, bound = spec.n_held * _GMM_ROWS, held_rows_bound(t, spec)
+    if bound is None:
+        return jnp.int32(t * spec.experts_per_tok + padding), jnp.int32(0)
+    passes = -(-pairs_held // bound)  # a pass that holds no pair is skipped
+    return (passes * (bound + padding)).astype(jnp.int32), (passes <= 1).astype(jnp.int32)
+
+
+def _padded_source(sizes: jnp.ndarray, rows: int, tile: int, with_live: bool = False):
     """The map from the rows handed to the grouped matmul to the (token, pick)
     pairs in sorted order.  Each group starts on a row tile of the kernel (its
     rows padded up to whole tiles): a group that straddled a tile boundary had
     its expert's weights streamed once per tile, half as many reads again at
     ~64 rows a group, and how many straddled followed the routing, so the
     layer's time did too.  sizes int32 [g], the groups' rows -> int32 [rows]:
-    the sorted pair a row holds, 0 for a padding row.
+    the sorted pair a row holds, 0 for a padding row; ``with_live`` also bool
+    [rows], the rows that hold a pair.  ``rows`` is what the caller lays out:
+    the groups' padded rows fit wherever ``sum(sizes) + g x tile <= rows`` (a
+    group's padding is under one tile); the rows past them are padding too.
 
     A tile lies in ONE group, so the group, its first pair and its size are
     looked up a TILE at a time and a row adds its place in the tile: a lookup
@@ -382,8 +427,10 @@ def _padded_source(sizes: jnp.ndarray, rows: int, tile: int) -> jnp.ndarray:
     tiles = jnp.arange(-(-rows // tile))
     of = jnp.maximum(jnp.sum(tiles[:, None] >= tstart[None, :], axis=1) - 1, 0)
     within = ((tiles - tstart[of]) * tile)[:, None] + jnp.arange(tile)
-    source = jnp.where(within < sizes[of][:, None], start[of][:, None] + within, 0)
-    return source.reshape(-1)[:rows]  # the last tile may reach past the rows
+    live = within < sizes[of][:, None]
+    source = jnp.where(live, start[of][:, None] + within, 0)
+    source = source.reshape(-1)[:rows]  # the last tile may reach past the rows
+    return (source, live.reshape(-1)[:rows]) if with_live else source
 
 
 def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
@@ -395,6 +442,21 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     PARTIAL sum; the members' results, the shared expert counted once, add up
     to the uncut layer's output.  Differentiable end to end (``grouped_matmul``;
     the gathers' transposes scatter into live rows only).
+
+    THE ROWS LAID OUT.  Pairs of no held expert sort last, so the held groups,
+    each padded up to whole row tiles, occupy the FIRST ``sum(sizes) + g x tile``
+    rows at most.  The worst case, every pair of the batch on this member, is
+    ``T k + g x tile`` rows, and that is what is laid out wherever
+    ``held_rows_bound`` gives no bound (every served pack and tick): the row
+    gather, the three products, and a combine that gathers each pair's row
+    ``[T, k, d]``.  Where it gives a bound ``C`` (a training step: the pairs
+    outnumber the padding many times over) the SAME body runs over ``C + g x
+    tile`` rows (67 584 for 133 120 at 16 384 tokens, top 8, 16 of 64 held) for
+    the first ``C`` sorted held pairs, and again for the next ``C`` while any
+    are left (one pass wherever ``sum(sizes) <= C``; ``ceil(T k / C)`` at most):
+    no pair is dropped and no capacity enforced, the result for any routing is
+    the unbounded function's.  Over fewer rows than pairs the combine walks the
+    ROWS: a live row's product, weighted in float32, adds into its pair's token.
 
     The experts' form comes from the spec: ``expert_form`` 'swiglu' (``w_gate``,
     ``w_up``, ``w_down``) or 'relu2' (``w_up``, ``relu(.)^2``, ``w_down``: no gate
@@ -424,26 +486,89 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
         order = jnp.argsort(key, stable=True)
         sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
         tile = _GMM_ROWS
-        padded = -(-sizes // tile) * tile
-        start, pstart = jnp.cumsum(sizes) - sizes, jnp.cumsum(padded) - padded
-        source = _padded_source(sizes, t * k + g * tile, tile)  # a pair, sorted order
-    x_in = x
-    if spec.moe_latent:
-        with jax.named_scope("latent_proj"):
-            x_in = x @ lw["w_lat_down"]
-    with jax.named_scope("expert_matmul"):
-        xs = x_in[order[source] // k]  # [T*k + g*tile, d]; padding rows repeat a live one
-        if gated:
-            h = jax.nn.silu(grouped_matmul(xs, lw["w_gate"], padded)) \
-                * grouped_matmul(xs, lw["w_up"], padded)
-        else:
-            h = relu2(grouped_matmul(xs, lw["w_up"], padded))
-        ys = grouped_matmul(h, lw["w_down"], padded)
-    at = jnp.argsort(order).reshape(t, k)  # where each pair sorted to
-    mine = jnp.clip(local, 0, g - 1)
-    dest = jnp.where(held, pstart[mine] + at - start[mine], 0)  # ... and its padded row
-    pairs = ys[dest].astype(jnp.float32)  # [T, k, d]
-    y = jnp.sum(jnp.where(held[..., None], pairs * wts[..., None], 0.0), axis=1)
+
+    def experts(rows: int, first=None, order=order, sizes=sizes, x=x, wts=wts, ew=lw):
+        """The products of ``sizes`` pairs a group, from sorted pair ``first`` on
+        (None: all the held pairs), over a layout of ``rows`` rows (static), and
+        their weighted sum a token, float32 [T, d'].  ``ew``: the routed experts'
+        matrices."""
+        walk_rows = rows < t * k  # the combine walks whichever is fewer
+        with jax.named_scope("expert_layout"):
+            padded = -(-sizes // tile) * tile
+            start, pstart = jnp.cumsum(sizes) - sizes, jnp.cumsum(padded) - padded
+            if walk_rows:
+                source, live = _padded_source(sizes, rows, tile, with_live=True)
+            else:
+                source = _padded_source(sizes, rows, tile)  # a pair, sorted order
+        x_in = x
+        if spec.moe_latent:
+            with jax.named_scope("latent_proj"):
+                x_in = x @ ew["w_lat_down"]
+        with jax.named_scope("expert_matmul"):
+            pair = order[source if first is None else first + source]
+            xs = x_in[pair // k]  # [rows, d]; padding rows repeat a live one
+            if gated:
+                h = jax.nn.silu(grouped_matmul(xs, ew["w_gate"], padded)) \
+                    * grouped_matmul(xs, ew["w_up"], padded)
+            else:
+                h = relu2(grouped_matmul(xs, ew["w_up"], padded))
+            ys = grouped_matmul(h, ew["w_down"], padded)
+        if walk_rows:  # a live row's product, weighted, adds into its pair's token
+            # (a padding row's product is undefined: masked BEFORE the weight meets
+            # it, so that neither the sum nor the weight's gradient sees it)
+            products = jnp.where(live[:, None], ys, 0).astype(jnp.float32)
+            return jnp.zeros((t, ys.shape[-1]), jnp.float32).at[pair // k].add(
+                products * wts.reshape(-1)[pair][:, None])
+        at = jnp.argsort(order).reshape(t, k)  # where each pair sorted to
+        mine = jnp.clip(local, 0, g - 1)
+        dest = jnp.where(held, pstart[mine] + at - start[mine], 0)  # ... and its padded row
+        pairs = ys[dest].astype(jnp.float32)  # [T, k, d]
+        return jnp.sum(jnp.where(held[..., None], pairs * wts[..., None], 0.0), axis=1)
+
+    bound = held_rows_bound(t, spec)
+    if bound is None:
+        y = experts(t * k + g * tile)
+    else:
+        # Exact for any routing: the sorted held pairs go through ``bound`` at a
+        # time, a group's pairs divided between two passes where the cut falls
+        # inside it, and a pass that holds none is skipped.  ONE body in ONE loop,
+        # forward and backward (a second body for the worst case's rows behind a
+        # ``cond`` made the step's program a quarter larger), and the loop's
+        # gradient is written out: differentiated by JAX, the ``cond`` hands out
+        # the skipped branch's residuals as zeros and the loop stacks every
+        # pass's (+1.2 to +5.7 GiB of a step's temporaries at 16 384 tokens: the
+        # step no longer loads beside the optimizer's state); here a pass keeps
+        # its inputs and its backward recomputes it, which under the block's own
+        # recomputation runs the products twice a step, as without a bound.
+        zeros = lambda tree: jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), tree)
+
+        def part(first, sizes):  # each group's pairs among the sorted pairs [first, first + bound)
+            ends = jnp.cumsum(sizes)
+            return jnp.clip(ends, first, first + bound) - jnp.clip(ends - sizes, first, first + bound)
+
+        def one_pass(first, order, sizes, *operands):
+            return experts(bound + g * tile, first, order, part(first, sizes), *operands)
+
+        def over_passes(run, like, sizes):
+            """``run(first)`` summed over the passes that hold a pair (a tree like ``like``)."""
+            def step(total, first):
+                new = jax.lax.cond(jnp.sum(part(first, sizes)) > 0, lambda: run(first), lambda: zeros(like))
+                return jax.tree.map(jnp.add, total, new), None
+            return jax.lax.scan(step, zeros(like), jnp.arange(-(-t * k // bound)) * bound)[0]
+
+        @jax.custom_vjp
+        def in_passes(order, sizes, *operands):
+            y = jax.ShapeDtypeStruct((t, lw["w_down"].shape[-1]), jnp.float32)
+            return over_passes(lambda first: one_pass(first, order, sizes, *operands), y, sizes)
+
+        def in_passes_bwd(kept, ct):
+            order, sizes, *operands = kept
+            pull = lambda first: jax.vjp(lambda *a: one_pass(first, order, sizes, *a), *operands)[1](ct)
+            return (None, None, *over_passes(pull, tuple(operands), sizes))
+
+        in_passes.defvjp(lambda *args: (in_passes(*args), args), in_passes_bwd)
+        routed = ("w_gate", "w_up", "w_down", "w_lat_down")
+        y = in_passes(order, sizes, x, wts, {name: lw[name] for name in routed if name in lw})
     if spec.moe_latent:
         with jax.named_scope("latent_proj"):
             y = y.astype(x.dtype) @ lw["w_lat_up"]

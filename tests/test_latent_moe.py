@@ -339,3 +339,120 @@ def test_held_layer_is_bit_equal_under_either_map(monkeypatch, spec, make, valid
     assert np.array_equal(np.asarray(y), np.asarray(y0))
     assert np.array_equal(np.asarray(stats), np.asarray(stats0))
     assert np.array_equal(np.asarray(picked), np.asarray(picked0))
+
+
+# -- the bounded layout (PR 50) --------------------------------------------------
+# over ``held_rows_bound``'s threshold: 2048 x 2 = 4096 pairs, 16 x the two held
+# groups' padding; 2 of 16 experts held, so the bound is twice an eighth of the
+# pairs: 1024 pairs a pass in 1280 rows where the worst case lays out 4352 at once
+T_B, K_B, G_B = 2048, 2, 2
+BOUND, ROWS_BOUNDED, ROWS_WORST = 1024, 1024 + G_B * 128, T_B * K_B + G_B * 128
+SPEC_B = replace(SPEC, n_held=G_B, experts_per_tok=K_B, n_shared=0, routing="softmax")
+SPEC_B_RELU2 = replace(SPEC_RELU2, n_held=G_B, experts_per_tok=K_B)
+
+
+def _held_share(lw, spec):
+    routed = ("w_gate", "w_up", "w_down")
+    return {k: (v[:spec.n_held] if k in routed else v) for k, v in lw.items()
+            if spec.n_shared or not k.startswith("s_")}
+
+
+def _rows_that_ran(monkeypatch):
+    """The rows of every ``grouped_matmul`` call that EXECUTED (a pass that
+    holds no pair is traced and skipped)."""
+    ran, inner = [], layer.grouped_matmul
+
+    def noting(xs, w, sizes):
+        jax.debug.callback(lambda: ran.append(xs.shape[0]))
+        return inner(xs, w, sizes)
+
+    monkeypatch.setattr(layer, "grouped_matmul", noting)
+    return ran
+
+
+def _value_and_gradients(lw, x, spec, valid, ct):
+    """((y, (d_lw, d_x)), the routing's stats)."""
+    y, pull = jax.vjp(lambda lw, x: moe_block_held(lw, x, spec, valid)[0], lw, x)
+    return (y, pull(ct)), moe_block_held(lw, x, spec, valid)[1][0]
+
+
+def _agree(got, want):
+    """Output and gradients, leaf by leaf (float32; the sums' order differs)."""
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5, rtol=1e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_bound_is_twice_the_uniform_share_and_engages_by_shape():
+    assert layer.held_rows_bound(T_B, SPEC_B) == BOUND
+    assert layer.held_rows_bound(T_B - 1, SPEC_B) is None  # under 16 x the padding
+    assert layer.held_rows_bound(T_B, replace(SPEC_B, n_held=8)) is None  # the padding grew
+    # a bound that is no bound: a member of two holds up to every pair
+    assert layer.held_rows_bound(64 * T_B, replace(SPEC_B, n_held=8)) is None
+    # up to a whole row tile: 3 of 16 experts of 4100 x 2 pairs: 2 x 1538 = 3076 -> 3200
+    assert layer.held_rows_bound(4100, replace(SPEC_B, n_held=3)) == 25 * 128
+    for pairs, want in ((0, (0, 1)), (BOUND, (ROWS_BOUNDED, 1)), (BOUND + 1, (2 * ROWS_BOUNDED, 0)),
+                        (T_B * K_B, (4 * ROWS_BOUNDED, 0))):
+        assert tuple(map(int, layer.held_rows_laid_out(T_B, SPEC_B, jnp.int32(pairs)))) == want
+    assert tuple(map(int, layer.held_rows_laid_out(40, SPEC_B, jnp.int32(3)))) == (
+        40 * K_B + G_B * 128, 0)
+
+
+@pytest.mark.parametrize("spec,make", [(SPEC_B, _weights_groups), (SPEC_B_RELU2, _weights_relu2)],
+                         ids=["softmax_swiglu_no_shared", "relu2_latent"])
+def test_the_bounded_layout_agrees_with_the_worst_cases(monkeypatch, spec, make):
+    """Uniform routing over the threshold: ONE bounded pass runs (1280 rows, the
+    three products forward and again in the backward's recomputation), and the
+    output, dx, the router's and every held expert's weight gradients are those
+    of the function that lays out the worst case (``held_rows_bound`` answering
+    None: no loop, the pairs' gather for a combine)."""
+    lw = _held_share(make(jax.random.PRNGKey(20)), spec)
+    x = jax.random.normal(jax.random.PRNGKey(21), (T_B, D))
+    ct = jax.random.normal(jax.random.PRNGKey(22), (T_B, D))
+    valid = jnp.arange(T_B) % 7 != 0
+    ran = _rows_that_ran(monkeypatch)
+    got, stats = _value_and_gradients(lw, x, spec, valid, ct)
+    jax.effects_barrier()
+    calls = 2 if spec.expert_form == "relu2" else 3  # a pass's products
+    assert ran == [ROWS_BOUNDED] * 3 * calls and 0 < int(stats[1]) <= BOUND  # y; y and its recomputation
+    del ran[:]
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    want, stats0 = _value_and_gradients(lw, x, spec, valid, ct)
+    jax.effects_barrier()
+    assert set(ran) == {ROWS_WORST} and np.array_equal(np.asarray(stats), np.asarray(stats0))
+    _agree(got, want)
+    for name, g in want[1][0].items():  # every tensor trains (the bias selects, it does not weigh)
+        assert np.abs(np.asarray(g)).max() > 0 or name == "bias", name
+
+
+@pytest.mark.parametrize("case,pairs,passes", [
+    ("every_pair_here", T_B * K_B, 4), ("one_over_the_bound", BOUND + 1, 2),
+    ("the_bound_exactly", BOUND, 1)])
+def test_past_the_bound_more_passes_run_and_no_pair_is_dropped(monkeypatch, case, pairs, passes):
+    """A router forced by its bias: every token picks held expert 0 and either
+    held expert 1 (all 4096 pairs fall here: four passes, the cuts inside both
+    groups) or expert 9 (a pair a valid token, 1025 or 1024 of them, and held
+    expert 1 has no rows).  One over the bound a second pass runs for the one
+    pair left, at the bound one pass; either way the result and the gradients
+    are the unbounded function's."""
+    spec = replace(SPEC_B, routing="sigmoid")
+    lw = _held_share(_weights(jax.random.PRNGKey(23)), spec)
+    other = 1 if case == "every_pair_here" else 9
+    lw["bias"] = jnp.zeros(E).at[0].set(10.0).at[other].set(9.0)
+    valid = None if case == "every_pair_here" else jnp.arange(T_B) % 3 != 1
+    if valid is not None:  # 1365 of 2048 rows: down to ``pairs`` valid tokens
+        valid &= jnp.cumsum(valid) <= pairs
+    x = jax.random.normal(jax.random.PRNGKey(24), (T_B, D))
+    ct = jax.random.normal(jax.random.PRNGKey(25), (T_B, D))
+    ran = _rows_that_ran(monkeypatch)
+    got, stats = _value_and_gradients(lw, x, spec, valid, ct)
+    jax.effects_barrier()
+    assert int(stats[1]) == pairs and ran == [ROWS_BOUNDED] * 3 * 3 * passes
+    assert tuple(map(int, layer.held_rows_laid_out(T_B, spec, stats[1]))) == (
+        passes * ROWS_BOUNDED, int(passes == 1))
+    if other == 9:
+        assert int(stats[2]) == pairs and int(stats[3]) == 0  # expert 1 holds no row
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    _agree(got, _value_and_gradients(lw, x, spec, valid, ct)[0])
+    if valid is not None:  # a masked row gets nothing from the experts
+        assert not np.asarray(got[0])[~np.asarray(valid)].any()

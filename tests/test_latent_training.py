@@ -21,6 +21,7 @@ import deepspeed_tpu as ds  # noqa: E402
 from benchmark import harness  # noqa: E402
 from deepspeed_tpu.models import CausalLM, latent  # noqa: E402
 from deepspeed_tpu.models.transformer import init_params  # noqa: E402
+from deepspeed_tpu.moe import layer  # noqa: E402
 from deepspeed_tpu.moe.layer import grouped_matmul, held_routing, moe_block_held  # noqa: E402
 from deepspeed_tpu.ops.pallas.selected_attention import interpreted  # noqa: E402
 from deepspeed_tpu.parallel.topology import initialize_mesh  # noqa: E402
@@ -51,20 +52,30 @@ def _engine(cfg, micro, mesh, optimizer=None):
     return ds.initialize(model=CausalLM(cfg), config=config, mesh=mesh)[0]
 
 
+def _a_quarter_held(monkeypatch):
+    """The rehearsal size with 4 of SIXTEEN experts held and a row tile of 8:
+    1152 pairs a layer, 36 x the groups' padding, so ``moe_block_held`` builds
+    its bounded layout (576 pairs a pass in 608 rows for the worst case's 1184)."""
+    monkeypatch.setattr(layer, "_GMM_ROWS", 8)
+    return dict(M, deployment=dict(M["deployment"], num_experts_total=16))
+
+
 def _leaves(tree):
     return {jax.tree_util.keystr(k): np.asarray(v)
             for k, v in jax.tree_util.tree_leaves_with_path(tree)}
 
 
-def test_train_batch_gives_the_references_loss_and_every_gradient():
+@pytest.mark.parametrize("bounded", [False, True], ids=["as_rehearsed", "a_quarter_held_bounded"])
+def test_train_batch_gives_the_references_loss_and_every_gradient(monkeypatch, bounded):
     """SGD at lr 1 without momentum: the step's update IS its gradient."""
-    cfg, ids = _cfg(), _ids()
+    m = _a_quarter_held(monkeypatch) if bounded else M
+    cfg, ids = _cfg(m), _ids()
     engine = _engine(cfg, ROWS // 8, initialize_mesh(data=8),
                      {"type": "sgd", "params": {"lr": 1.0}})
     before = jax.device_get(engine.state.params)
     loss = float(engine.train_batch({"input_ids": ids}))
     after = jax.device_get(engine.state.params)
-    ref_loss, ref = jax.value_and_grad(lambda p: ARCH.loss_on(p, jnp.asarray(ids), M))(before)
+    ref_loss, ref = jax.value_and_grad(lambda p: ARCH.loss_on(p, jnp.asarray(ids), m))(before)
     assert abs(loss - float(ref_loss)) <= TOL
     got, want = _leaves(jax.tree_util.tree_map(lambda a, b: a - b, before, after)), _leaves(ref)
     assert set(got) == set(want) and len(got) > 40
@@ -229,8 +240,10 @@ def test_fsdp_4_on_a_cpu_mesh_equals_one_device():
     np.testing.assert_allclose(losses["fsdp4"], losses["one"], atol=2e-5)
 
 
-def test_the_steps_counts_are_booked_and_the_span_carries_tokens_and_layers():
-    cfg, ids = _cfg(), _ids()
+@pytest.mark.parametrize("bounded", [False, True], ids=["as_rehearsed", "a_quarter_held_bounded"])
+def test_the_steps_counts_are_booked_and_the_span_carries_tokens_and_layers(monkeypatch, bounded):
+    m = _a_quarter_held(monkeypatch) if bounded else M
+    cfg, ids = _cfg(m), _ids()
     engine = _engine(cfg, ROWS // 8, initialize_mesh(data=8))
     steps = 3
     for _ in range(steps):
@@ -242,6 +255,14 @@ def test_the_steps_counts_are_booked_and_the_span_carries_tokens_and_layers():
     assert 0 < read("expert_pairs_held") < read("expert_pairs_routed")
     assert read("expert_rows_min") * M["num_experts"] <= read("expert_pairs_held") \
         <= read("expert_rows_max") * M["num_experts"]
+    # the rows the grouped matmul was handed, and the layers that took ONE bounded pass
+    if bounded:
+        assert read("expert_pairs_held") <= steps * layers * 576
+        assert read("expert_layers_bounded") == steps * layers
+        assert read("expert_rows_laid_out") == steps * layers * (576 + 4 * 8)
+    else:  # half the experts held, 2.25 x the padding: no bound is built
+        assert read("expert_layers_bounded") == 0
+        assert read("expert_rows_laid_out") == steps * layers * (tokens * k + 4 * 128)
     w = M["sliding_window"]
     assert read("causal_keys") == steps * ROWS * layers * SEQ * (SEQ + 1) // 2
     assert read("window_keys_attended") == steps * ROWS * (
@@ -299,3 +320,123 @@ def test_a_block_without_a_gate_or_a_shared_expert_has_neither_in_its_tree():
         assert set(params["layers"][kind][0]) == {"wq", "wk", "wv", "wo", "q_norm", "k_norm"}
     assert set(params["layers"]["moe"][0]) == {"router", "w_gate", "w_up", "w_down"}
     assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params)) == cfg.param_count
+
+
+# -- the bounded layout of the held experts (PR 50) ----------------------------
+def test_the_kernel_path_runs_the_bounded_layout_and_its_gradients_are_the_worst_cases(monkeypatch):
+    """The path the CHIP takes, interpreted, over ``held_rows_bound``'s threshold
+    (2048 tokens x 2 picks, 2 of 16 experts held: 1280 rows a pass for the worst
+    case's 4352): megablox ``gmm`` / ``tgmm`` are handed the bounded rows, whose
+    padding rows come back NaN from an interpreted kernel, and the output, dx
+    and each held expert's three weight gradients are those of the function that
+    lays out the worst case."""
+    from deepspeed_tpu.ops.pallas import record_dispatch
+
+    t, d, f, total, held, k = 2048, 128, 128, 16, 2, 2
+    spec = dataclasses.replace(_cfg().latent, n_routed=total, n_held=held, held_offset=0,
+                               experts_per_tok=k, moe_width=f)
+    rng = np.random.default_rng(6)
+    n = lambda *s: jnp.asarray(rng.standard_normal(s) / np.sqrt(s[-2]), jnp.float32)
+    lw = {"router": n(d, total), "w_gate": n(held, d, f), "w_up": n(held, d, f),
+          "w_down": n(held, f, d)}
+    x, ct = n(t, d) * np.sqrt(t), n(t, d)
+
+    def run():
+        with interpreted(), record_dispatch() as log:
+            y, pull = jax.vjp(lambda lw, x: moe_block_held(lw, x, spec)[0], lw, x)
+            return y, pull(ct), sorted({e["shape"][0] for e in log if e["kernel"] == "expert_gmm"
+                                        and e["ran"]})
+
+    y, (d_lw, d_x), rows = run()
+    assert rows == [1024 + held * 128]
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    y0, (d_lw0, d_x0), rows0 = run()
+    assert rows0 == [t * k + held * 128]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y0), atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(d_x), np.asarray(d_x0), atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(d_lw["router"]), np.asarray(d_lw0["router"]),
+                               atol=5e-5, rtol=1e-4)
+    for name in ("w_gate", "w_up", "w_down"):
+        for e in range(held):  # an expert at a time
+            got, want = np.asarray(d_lw[name])[e], np.asarray(d_lw0[name])[e]
+            assert np.abs(want).max() > 0
+            np.testing.assert_allclose(got, want, atol=5e-5, rtol=1e-4, err_msg=f"{name}[{e}]")
+
+
+def _held_layer_at_real_size(config: str, t: int):
+    """(the jaxpr of ``moe_block_held`` at ``t`` tokens of a benchmark
+    configuration's published widths, traced and not run; the rows each
+    ``grouped_matmul`` call was handed; the spec)."""
+    from deepspeed_tpu.ops.pallas import record_dispatch
+
+    m = harness.load_json(harness.HERE / "configs" / f"{config}.json")
+    cfg = harness.module("models", m["model_type"]).transformer_config(m)
+
+    def held(tree):  # the first expert layer's weights, wherever the model keeps them
+        if isinstance(tree, dict):
+            if "router" in tree:
+                return tree
+            tree = tuple(tree.values())
+        for v in tree if isinstance(tree, (list, tuple)) else ():
+            found = held(v)
+            if found is not None:
+                return found
+
+    lw = held(jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+    lead = lw["router"].ndim - 2  # a stack of layers keeps a leading axis
+    lw = {k: jax.ShapeDtypeStruct(v.shape[lead:], jnp.bfloat16) for k, v in lw.items()}
+    x = jax.ShapeDtypeStruct((t, cfg.hidden_size), jnp.bfloat16)
+    with record_dispatch() as log:
+        jaxpr = jax.make_jaxpr(lambda lw, x: moe_block_held(lw, x, cfg.latent)[0])(lw, x)
+    return jaxpr, [e["shape"][0] for e in log if e["kernel"] == "expert_gmm"], cfg.latent
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("config,t,rows", [
+    ("dots3_note_l5_e32_serve_1chip", 2048, 20480), ("dots3_note_l5_e32_serve_1chip", 16, 4224),
+    ("nemotron3_super_l11_e128_serve_1chip", 512, 27648),
+    ("nemotron3_super_l11_e128_serve_1chip", 128, 19200),
+    ("qwen3_next_l8_e128_serve_1chip", 512, 21504), ("qwen3_next_l8_e128_serve_1chip", 16, 16544),
+    ("laguna_xs2_l5_serve_1chip", 512, 36864), ("laguna_xs2_l5_serve_1chip", 32, 33024),
+    ("deepseek_v2_l5_e40_serve_1chip", 2048, 17408), ("deepseek_v2_l5_e40_serve_1chip", 24, 5264)],
+    ids=lambda v: str(v).split("_")[0])
+def test_a_served_pack_or_tick_lays_out_what_it_did_and_holds_no_cond(config, t, rows):
+    """Cells 5-9 at their pack's and their tick's tokens, published widths,
+    traced only: ``t k + g x 128`` rows to each of the layer's grouped matmuls
+    (the ``gmm`` shapes on the ledger's lines) and no ``cond`` anywhere: under
+    ``held_rows_bound``'s threshold the function is the one it was."""
+    jaxpr, handed, spec = _held_layer_at_real_size(config, t)
+    assert rows == t * spec.experts_per_tok + spec.n_held * 128
+    assert handed == [rows] * (3 if spec.expert_form == "swiglu" else 2)
+    assert not [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "cond"]
+    assert t * spec.experts_per_tok / (spec.n_held * 128) <= 4
+
+
+def test_cell_tens_step_lays_out_67584_rows_a_pass_and_holds_no_worst_case_array():
+    """Cell 10's layer (16 384 tokens x 2304, top 8, 16 of 64 held), traced
+    only: ONE loop of two passes (inside the function whose gradient the layer
+    writes out), each a ``cond`` that skips a pass of no pair, whose body hands
+    the grouped matmuls 67 584 rows; inside the loop nothing
+    has 133 120 or 131 072 ROWS (an array of two or more dimensions counted by
+    all but its last: the pairs' gather ``[T, k, d]`` and the padded rows ``[R,
+    d]`` are what the bound is there to shrink; the sort's order and the routing
+    weights, one NUMBER a pair, come in as they are), and no ``[R, d]`` of the
+    worst case stands outside it either."""
+    jaxpr, handed, spec = _held_layer_at_real_size("mellum2_l4_e16_train_1chip", 16384)
+    assert layer.held_rows_bound(16384, spec) == 65536
+    assert handed == [67584] * 3
+    (loop,) = [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "scan"]
+    assert loop.params["length"] == 2
+    assert [e.primitive.name for e in _equations(loop.params["jaxpr"].jaxpr)].count("cond") == 1
+    rows_of = lambda eqns: {int(np.prod(v.aval.shape[:-1])) for e in eqns
+                            for v in e.outvars if len(v.aval.shape) >= 2}
+    inside = rows_of(_equations(loop.params["jaxpr"].jaxpr))
+    assert 67584 in inside and not inside & {133120, 131072}
+    assert 133120 not in rows_of(_equations(jaxpr.jaxpr))
