@@ -1108,7 +1108,8 @@ class InferenceEngineV2:
             n_real = sum(end - start for _, start, end in entries)
             n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
             extra = self.runner.dispatched(
-                self._c, ((s.slot, start, end) for s, start, end in entries), pack=True)
+                self._c, ((s.slot, start, end) for s, start, end in entries), pack=True,
+                tokens=t_pad)
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
@@ -1675,7 +1676,7 @@ class InferenceEngineV2:
             self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
             bsp.mark("rows")  # what is left of the span: the runner's counts
             extra = self.runner.dispatched(
-                self._c, ((s.slot, s.cur_len - 1, s.cur_len) for s in active_seqs))
+                self._c, ((s.slot, s.cur_len - 1, s.cur_len) for s in active_seqs), tokens=B)
         # decode_tick_ms is uploads + dispatch + fetch: the argument uploads
         # (the tick's rows, and the tables when a page moved) belong inside
         # the span

@@ -86,6 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import latent as lm
+from ..moe.layer import held_rows_a_pass
 from ..ops import latent_attention as la
 from ..ops.pallas import index_scores as index_kernel
 from ..ops.pallas import latent_decode as decode_kernel
@@ -102,7 +103,8 @@ _NO_MIN = 1 << 30
 _CARRY = 30  # bits of the low word of cache["picks"]
 # extra ``stats`` keys of an engine that serves such a model: what its
 # selectors, windows and router did.  The causal keys, the ring rows and the
-# groups follow from positions and are counted on the host at dispatch; the keys SELECTED
+# groups follow from positions, the expert layers' laid-out rows from the program's
+# shape, and are counted on the host at dispatch; the keys SELECTED
 # and the routing four are counted on the device (``picks``, ROUTING_STATS)
 # and read by ``refresh_routing_stats()``, which ``close()`` calls.
 COUNTERS = (
@@ -113,6 +115,7 @@ COUNTERS = (
     "selected_groups_dense",  # ... whose context ends under DENSE_KEYS_MAX: walked, not gathered
     "expert_pairs_routed",    # (token, expert) pairs the routers picked
     "expert_pairs_held",      # ... that fell on experts held here
+    "expert_rows_laid_out",   # rows the held experts' layers handed their grouped matmuls
     "expert_group_rows_max",  # rows of the largest held expert's group in a pack
     "expert_group_rows_min",  # ... and of the smallest
 )
@@ -125,7 +128,7 @@ WINDOW_COUNTERS = (
     "window_keys_attended",   # ... of the layers over a window: min(position + 1, window)
     "causal_keys",            # what the window layers would attend if they were full
     "window_rows_discarded",  # ring rows that fell out of a window
-    "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
+    "expert_pairs_routed", "expert_pairs_held", "expert_rows_laid_out", "expert_group_rows_max",
     "expert_group_rows_min", "experts_touched", "experts_touched_decode",
     "expert_pairs_held_decode",
 )
@@ -137,7 +140,7 @@ MLA_COUNTERS = (
     "mla_keys_attended",         # (query, key) pairs of the layers over every row: causal keys
     "mla_keys_attended_decode",  # ... of them, those of decode ticks
     "mla_keys_decompressed",     # ... and those of a pack's runs from ``la.run_groups`` pages on
-    "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
+    "expert_pairs_routed", "expert_pairs_held", "expert_rows_laid_out", "expert_group_rows_max",
     "expert_group_rows_min", "experts_touched", "experts_touched_decode",
     "expert_pairs_held_decode",
 )
@@ -149,8 +152,8 @@ STATE_COUNTERS = (
     "ssm_states_reset",       # sequences that began from a zero state (admissions, resumes)
     "ssm_states_recomputed",  # states a preemption discarded: the resume scans them again
     "ssm_chunks_scanned",     # chunks (a page of one sequence) x state-space blocks, in packs
-    "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
-    "expert_group_rows_min",  # the routing four, as above
+    "expert_pairs_routed", "expert_pairs_held", "expert_rows_laid_out", "expert_group_rows_max",
+    "expert_group_rows_min",  # the routing four and the rows laid out, as above
     "experts_touched",        # held experts with at least one row, summed over dispatches
     "experts_touched_decode",    # ... in decode ticks alone,
     "expert_pairs_held_decode",  # and the pairs that fell on them there
@@ -900,6 +903,7 @@ class LatentRunner:
         self.cfg = cfg
         self._ring_rows = np.zeros(0, np.int64)
         self._block = 1
+        self._expert_layers = 0
         if cfg.latent.stateful:
             self.counters = WINDOW_COUNTERS if cfg.latent.ringed else STATE_COUNTERS
             self._discarded = 0  # states a preemption left behind since the last dispatch
@@ -912,7 +916,9 @@ class LatentRunner:
         # ring is not allocated, so this is what ``close()`` audits)
         # ... and of the state-space states: tokens each slot's state has taken in
         self._ring_rows = np.zeros(max_seqs, np.int64)
-        return init_cache(self.cfg, num_blocks, block_size, max_seqs, pack_tokens)
+        cache = init_cache(self.cfg, num_blocks, block_size, max_seqs, pack_tokens)
+        self._expert_layers = cache["stats"].shape[0]
+        return cache
 
     def prefill_packed(self, *args, **kw):
         lm.refuse("a cold pack's own program (prefill_packed)", "a pack reads its own "
@@ -935,19 +941,26 @@ class LatentRunner:
         _one_chip_only(ctx, mesh, dp, seq_shards)
         return decode_step(params, cfg, tokens, seq_lens, block_tables, active, kv_cache)
 
-    def dispatched(self, counters, work, pack: bool = False) -> Dict[str, int]:
+    def dispatched(self, counters, work, pack: bool = False, tokens: int = 0) -> Dict[str, int]:
         """What the selectors and windows are ASKED to do with queries at
         positions ``[start, end)`` of each (slot, start, end) of ``work``, all
-        layers: the dispatch's span arguments.  The causal keys and the ring
-        rows are counted into ``counters`` here; the keys selected are counted
-        where they are selected (``refresh_stats``), so that count moves if a
-        selector breaks, and a sound run's equals the sum of these arguments.
+        layers: the dispatch's span arguments.  ``tokens`` is the program's token
+        rows (a pack's padded tokens, a tick's slots), from which its expert
+        layers lay out ``t k + g x tile`` rows each whatever the routing
+        (``moe/layer.py:held_rows_a_pass``; ONE pass of a bounded layout, which
+        no served shape reaches): ``expert_rows_laid_out`` beside the device's
+        ``expert_pairs_held``, the rows of them that are live.  The causal keys
+        and the ring rows are counted into ``counters`` here too; the keys selected
+        are counted where they are selected (``refresh_stats``), so that count moves
+        if a selector breaks, and a sound run's equals the sum of these arguments.
         A pack's entry is one GROUP a page of queries, and a group whose last
         position is under ``DENSE_KEYS_MAX`` walks its pages instead of gathering
         rows (``_attend_selected``); a single position is a decode tick's row
         and no group (nor is a pack's entry of one token, which the program
         cannot tell apart here)."""
         s = self.cfg.latent
+        if tokens and self._expert_layers:
+            counters["expert_rows_laid_out"].inc(self._expert_layers * held_rows_a_pass(tokens, s))
         if s.count("wattn"):
             return self._windows_dispatched(counters, work)
         if s.stateful:

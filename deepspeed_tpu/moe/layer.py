@@ -15,6 +15,7 @@ cutlass_ops/moe_gemm).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -241,28 +242,78 @@ class MoE:
 # ---------------------------------------------------------------------------
 # a HELD share of routed experts (models/latent.py: served and trained)
 # ---------------------------------------------------------------------------
-_GMM_ROWS = 128  # the grouped matmul's row tile (and what a group's rows are padded to)
-# the held pairs one pass of the bounded layout has room for, over the member's
-# share under uniform routing (``held_rows_bound``): a share that drifts, or a
-# router that prefers the held experts by half again, still takes ONE pass
+# the grouped matmul's row tiles, and what a held group's rows are padded to
+# (``held_row_tile``)
+_ROW_TILES = (16, 32, 64, 128)
+# the rows a tile (and ONE pass of the bounded layout, ``held_rows_bound``) has
+# room for, over a group's (a member's) share under uniform routing: a share
+# that drifts, or a router that prefers some experts by half again, still takes
+# one tile (one pass)
 _HELD_ROWS_FACTOR = 2
 # ... and the pairs a layer routes, over the groups' padding, from which the
 # bounded layout is built at all (``held_rows_bound``)
 _BOUNDED_MIN_PAIRS_PER_PADDING = 16
+# a weight tile of the grouped matmul, elements (3 MB in bf16, double-buffered)
+_WEIGHT_TILE = 1600 * 1024
 
 
-def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
-    """(tm, tk, tn) of the grouped-matmul kernel: 128 rows (a served group is a
-    few dozen rows, so a taller tile would be padding) and a weight tile of up
-    to 1.6 M elements (3 MB, double-buffered): (128, 1024, 1536) and (128,
-    512, 2560) at d 5120 x f 1536, the fastest of six tried on the chip, all
-    within 12% (0.89 and 0.90 ms a call against 0.61 ms for reading the
-    weights; my chip run, PR 29).  The backward's kernels take the same rule at
-    their own k and n (a training step hands a group thousands of rows: a
-    taller row tile there is ROADMAP S13's to measure)."""
-    tk = next(t for t in (1024, 512, 256, 128) if k % t == 0)
-    tn = max(t for t in range(128, n + 1, 128) if n % t == 0 and tk * t <= 1600 * 1024)
-    return _GMM_ROWS, tk, tn
+def held_row_tile(t: int, spec) -> int:
+    """The row tile of the grouped matmuls of ``moe_block_held`` at ``t`` tokens,
+    which is also what each held group's rows are padded to.  Decided from the
+    rows a group EXPECTS under uniform routing, ``t x experts_per_tok /
+    n_routed``: a static number of the shape and the spec, never the padded rows
+    handed in, a run's routing or a model's name.
+
+    The smallest of 16, 32, 64, 128 that holds ``_HELD_ROWS_FACTOR`` = twice the
+    expectation, 128 where none does: a tick's group of under one row gets 16
+    (bf16's own sublane tile: nothing shorter is a whole tile of the chip's), a
+    512-token pack's group of 10-22 rows 32-64, a 2048-token pack's of 64-77
+    and a training step's of 2048 the 128 they always had.  What the padding
+    cost was not the products, which visit live tiles only (cell 7's pack: 0.534
+    ms at 9 216 rows for 0.529 at 21 504), but every XLA op over the laid-out
+    rows around them: the whole layer 1.85 for 2.23 ms there, 3.17 for 4.39 at
+    cell 8's pack, 2.08 for 3.14 at its tick (my chip run, PR 51,
+    ``tools/grouped_matmul_curves.py --layer``).
+
+    A group that outgrows its tile takes further tiles (``_padded_source``), so
+    nothing is dropped under any routing; each extra tile costs that expert's
+    weights streamed once more where k is walked in several steps, and one more
+    grid step where it is one (the weight block stays resident between
+    consecutive tiles of a group).  That is why the tile holds the expectation
+    TWICE: at 16 rows a tile cell 5's pack (64 expected rows: five tiles a
+    group) took 3.14 ms a product for 0.97, cell 6's pack (22 expected) 1.48 for
+    1.20 at 64; at the factor's two an overflow is rare (Poisson at 16 expected
+    rows passes 32 for one group in 10^4, at 5.5 expected passes 16 likewise).
+
+    The ladder ends at 128 for a group of thousands of rows too: at cell 10's
+    step (2048 rows a group) tiles of 256 and 512 made the products of a layer's
+    forward and backward 6% and 4% shorter over 3% and 9% more rows for the
+    gathers around them to walk; the layer's forward + backward read 46.5 and
+    47.6 ms for 47.7 at 128 (the same run): under 1% of a step for a second
+    ladder."""
+    expected = t * spec.experts_per_tok / spec.n_routed
+    return next((r for r in _ROW_TILES if r >= _HELD_ROWS_FACTOR * expected), _ROW_TILES[-1])
+
+
+def _gmm_tiling(tm: int, k: int, n: int) -> tuple[int, int, int]:
+    """(tm, tk, tn) of a grouped-matmul kernel from the shapes it is handed: the
+    caller's row tile (``held_row_tile``), and for the weights the tile (tk, tn)
+    of k's and n's own divisors (multiples of 128) that covers the most of the
+    matrix inside ``_WEIGHT_TILE`` = 1.6 M elements with ``tk`` <= 1024, the
+    taller ``tk`` of two that cover the same.  (128, 1024, 1536) and (128, 512,
+    2560) at d 5120 x f 1536, the fastest of six tried on the chip, all within
+    12% (0.89 and 0.90 ms a call against 0.61 ms for reading the weights; my chip
+    run, PR 29).  k 2688 = 21 x 128 and k 2304 = 18 x 128 get 896 and 768 where
+    a choice among powers of two gave them 128 and 256, i.e. 21 and 9
+    accumulator round trips over a 256 KB weight tile: cell 6's down projection
+    1.17 ms a call for 1.56 at the same 16-row tile (1.83 at the parent's 128),
+    cell 10's nine products of a layer's forward and backward 9.9 ms for 15.3 at
+    the same 128-row tile (my chip run, PR 51, ``tools/grouped_matmul_curves.py``).
+    The backward's kernels take the same rule at their own k and n."""
+    tiles = [(tk, tn) for tk in range(128, min(k, 1024) + 1, 128) if k % tk == 0
+             for tn in range(128, n + 1, 128) if n % tn == 0 and tk * tn <= _WEIGHT_TILE]
+    tk, tn = max(tiles, key=lambda tile: (tile[0] * tile[1], tile[0]))
+    return tm, tk, tn
 
 
 def _interpreted() -> bool:
@@ -274,32 +325,33 @@ def _interpreted() -> bool:
     return interpret()
 
 
-@jax.custom_vjp
-def gmm(xs, w, sizes):
-    """megablox ``gmm`` with this module's tilings in the backward too: the
-    stock VJP hands the forward's (tm, tk, tn) to both backward kernels, whose
-    k and n are the forward's n and k.  Rows of no group get NO gradient."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def gmm(xs, w, sizes, tm):
+    """megablox ``gmm`` at row tile ``tm`` with this module's tilings in the
+    backward too: the stock VJP hands the forward's (tm, tk, tn) to both backward
+    kernels, whose k and n are the forward's n and k.  Rows of no group get NO
+    gradient."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as kernel
 
     return kernel(xs, w, sizes, preferred_element_type=xs.dtype,
-                  tiling=_gmm_tiling(*w.shape[1:]), interpret=_interpreted())
+                  tiling=_gmm_tiling(tm, *w.shape[1:]), interpret=_interpreted())
 
 
-def _gmm_fwd(xs, w, sizes):
-    return gmm(xs, w, sizes), (xs, w, sizes)
+def _gmm_fwd(xs, w, sizes, tm):
+    return gmm(xs, w, sizes, tm), (xs, w, sizes)
 
 
-def _gmm_bwd(res, g):
+def _gmm_bwd(tm, res, g):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as kernel, tgmm
 
     xs, w, sizes = res
     (m, k), n = xs.shape, w.shape[-1]
     d_xs = kernel(g, w, sizes, preferred_element_type=xs.dtype,
-                  tiling=_gmm_tiling(n, k), transpose_rhs=True, interpret=_interpreted())
+                  tiling=_gmm_tiling(tm, n, k), transpose_rhs=True, interpret=_interpreted())
     # the kernel leaves the rows past the last group as they lay in memory
     d_xs = jnp.where((jnp.arange(m) < jnp.sum(sizes))[:, None], d_xs, 0)
     d_w = tgmm(xs.swapaxes(0, 1), g, sizes, preferred_element_type=w.dtype,
-               tiling=_gmm_tiling(k, n), num_actual_groups=w.shape[0],
+               tiling=_gmm_tiling(tm, k, n), num_actual_groups=w.shape[0],
                interpret=_interpreted())
     return d_xs, d_w, None
 
@@ -307,11 +359,13 @@ def _gmm_bwd(res, g):
 gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
-def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
+def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
+                   tile: int = _ROW_TILES[-1]) -> jnp.ndarray:
     """``xs[rows of group g] @ w[g]`` for rows sorted by group: xs [M, k],
     w [G, k, n], sizes [G] int32 (their sum may be under M: the rows past it
     belong to no group and come back undefined).  On a TPU the megablox
-    Pallas kernel, which visits only the row tiles that hold a group's rows
+    Pallas kernel at row tile ``tile`` (what the caller padded its groups to:
+    ``held_row_tile``), which visits only the row tiles that hold a group's rows
     and reads each group's weights once per tile (``M`` padded up to a whole
     row tile); elsewhere, and for ``k`` / ``n`` its tiles do not divide,
     ``lax.ragged_dot``.  Differentiable either way (``gmm``'s VJP above: the
@@ -327,9 +381,9 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.n
                       reason="k and n must be multiples of 128")
     else:
         note_dispatch("expert_gmm", True, (m, k, n))
-        if m % _GMM_ROWS:  # rows of no group, up to a whole row tile
-            xs = jnp.pad(xs, ((0, -m % _GMM_ROWS), (0, 0)))
-        return gmm(xs, w, sizes)[:m]
+        if m % tile:  # rows of no group, up to a whole row tile
+            xs = jnp.pad(xs, ((0, -m % tile), (0, 0)))
+        return gmm(xs, w, sizes, tile)[:m]
     return jax.lax.ragged_dot(xs, w, sizes, preferred_element_type=xs.dtype)
 
 
@@ -374,7 +428,8 @@ def held_rows_bound(t: int, spec) -> Optional[int]:
     """The held pairs ONE pass of ``moe_block_held``'s bounded layout has room
     for at ``t`` tokens, None where it builds no such layout.  Twice the pairs a
     member holds under uniform routing (``_HELD_ROWS_FACTOR``), up to a whole
-    row tile: from the spec and the shape alone, never tuned to a run.
+    row tile (``held_row_tile``'s): from the spec and the shape alone, never
+    tuned to a run.
 
     The bound pays where the pairs a layer routes, ``t k``, outnumber the
     groups' padding ``g x tile`` many times over: the padding stays whatever
@@ -383,14 +438,24 @@ def held_rows_bound(t: int, spec) -> Optional[int]:
     and tick at 4 x or under (a few thousand rows of a scope that is a tenth of
     a pack), and nothing was measured in between, so the threshold is the
     geometric middle of the two, ``_BOUNDED_MIN_PAIRS_PER_PADDING`` = 16:
-    neither side stands near it.  Below it the function traces as it did before
-    there was a bound."""
-    pairs, padding = t * spec.experts_per_tok, spec.n_held * _GMM_ROWS
+    neither side stands near it.  Below it the function traces ONE body over the
+    worst case's rows."""
+    pairs, tile = t * spec.experts_per_tok, held_row_tile(t, spec)
+    padding = spec.n_held * tile
     if pairs < _BOUNDED_MIN_PAIRS_PER_PADDING * padding:
         return None
     bound = _HELD_ROWS_FACTOR * -(-pairs * spec.n_held // spec.n_routed)
-    bound = -(-bound // _GMM_ROWS) * _GMM_ROWS
+    bound = -(-bound // tile) * tile
     return bound if bound + padding < pairs else None  # a member of two holds them all
+
+
+def held_rows_a_pass(t: int, spec) -> int:
+    """The rows ONE pass of ``moe_block_held`` hands the grouped matmuls at ``t``
+    tokens: the worst case's ``t k`` pairs, or the bound's where it has one, and a
+    tile of padding a held group."""
+    bound = held_rows_bound(t, spec)
+    pairs = t * spec.experts_per_tok if bound is None else bound
+    return pairs + spec.n_held * held_row_tile(t, spec)
 
 
 def held_rows_laid_out(t: int, spec, pairs_held):
@@ -398,11 +463,11 @@ def held_rows_laid_out(t: int, spec, pairs_held):
     scalar) pairs on the held experts: (the rows it handed the grouped matmul,
     over all its passes; 1 where ONE bounded pass held every pair), int32
     scalars for the step's counts."""
-    padding, bound = spec.n_held * _GMM_ROWS, held_rows_bound(t, spec)
+    rows, bound = held_rows_a_pass(t, spec), held_rows_bound(t, spec)
     if bound is None:
-        return jnp.int32(t * spec.experts_per_tok + padding), jnp.int32(0)
+        return jnp.int32(rows), jnp.int32(0)
     passes = -(-pairs_held // bound)  # a pass that holds no pair is skipped
-    return (passes * (bound + padding)).astype(jnp.int32), (passes <= 1).astype(jnp.int32)
+    return (passes * rows).astype(jnp.int32), (passes <= 1).astype(jnp.int32)
 
 
 def _padded_source(sizes: jnp.ndarray, rows: int, tile: int, with_live: bool = False):
@@ -445,8 +510,11 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
 
     THE ROWS LAID OUT.  Pairs of no held expert sort last, so the held groups,
     each padded up to whole row tiles, occupy the FIRST ``sum(sizes) + g x tile``
-    rows at most.  The worst case, every pair of the batch on this member, is
-    ``T k + g x tile`` rows, and that is what is laid out wherever
+    rows at most.  The tile is the grouped matmul's own and follows the rows a
+    group EXPECTS at this ``T`` (``held_row_tile``: twice the expectation, 16 to
+    128 rows; a group that outgrows it takes further tiles, at the price of its
+    weights streamed again).  The worst case, every pair of the batch on this
+    member, is ``T k + g x tile`` rows, and that is what is laid out wherever
     ``held_rows_bound`` gives no bound (every served pack and tick): the row
     gather, the three products, and a combine that gathers each pair's row
     ``[T, k, d]``.  Where it gives a bound ``C`` (a training step: the pairs
@@ -485,7 +553,7 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
         key = jnp.where(held, local, g).reshape(-1)  # pairs of no held expert sort last
         order = jnp.argsort(key, stable=True)
         sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
-        tile = _GMM_ROWS
+        tile = held_row_tile(t, spec)
 
     def experts(rows: int, first=None, order=order, sizes=sizes, x=x, wts=wts, ew=lw):
         """The products of ``sizes`` pairs a group, from sorted pair ``first`` on
@@ -508,11 +576,11 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
             pair = order[source if first is None else first + source]
             xs = x_in[pair // k]  # [rows, d]; padding rows repeat a live one
             if gated:
-                h = jax.nn.silu(grouped_matmul(xs, ew["w_gate"], padded)) \
-                    * grouped_matmul(xs, ew["w_up"], padded)
+                h = jax.nn.silu(grouped_matmul(xs, ew["w_gate"], padded, tile)) \
+                    * grouped_matmul(xs, ew["w_up"], padded, tile)
             else:
-                h = relu2(grouped_matmul(xs, ew["w_up"], padded))
-            ys = grouped_matmul(h, ew["w_down"], padded)
+                h = relu2(grouped_matmul(xs, ew["w_up"], padded, tile))
+            ys = grouped_matmul(h, ew["w_down"], padded, tile)
         if walk_rows:  # a live row's product, weighted, adds into its pair's token
             # (a padding row's product is undefined: masked BEFORE the weight meets
             # it, so that neither the sum nor the weight's gradient sees it)
@@ -525,9 +593,9 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
         pairs = ys[dest].astype(jnp.float32)  # [T, k, d]
         return jnp.sum(jnp.where(held[..., None], pairs * wts[..., None], 0.0), axis=1)
 
-    bound = held_rows_bound(t, spec)
+    bound, rows = held_rows_bound(t, spec), held_rows_a_pass(t, spec)
     if bound is None:
-        y = experts(t * k + g * tile)
+        y = experts(rows)
     else:
         # Exact for any routing: the sorted held pairs go through ``bound`` at a
         # time, a group's pairs divided between two passes where the cut falls
@@ -547,7 +615,7 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
             return jnp.clip(ends, first, first + bound) - jnp.clip(ends - sizes, first, first + bound)
 
         def one_pass(first, order, sizes, *operands):
-            return experts(bound + g * tile, first, order, part(first, sizes), *operands)
+            return experts(rows, first, order, part(first, sizes), *operands)
 
         def over_passes(run, like, sizes):
             """``run(first)`` summed over the passes that hold a pair (a tree like ``like``)."""

@@ -2,6 +2,7 @@
 (``moe/layer.py:moe_block_held``): routing over all experts (sigmoid + bias, a
 softmax, or a softmax limited to a few groups of experts), a grouped matmul
 over the pairs that fall on the experts held here."""
+import contextlib
 from dataclasses import replace
 
 import jax
@@ -307,7 +308,9 @@ def test_the_map_a_tile_at_a_time_is_the_map_a_row_at_a_time(t, k, g, case):
     """The sorted pair every padded row holds, and the (token, pick) pair
     gathered through it, element for element."""
     key = _keys(case, t, k, g, np.random.default_rng(t + k))
-    tile, rows = layer._GMM_ROWS, t * k + g * layer._GMM_ROWS
+    tile = layer.held_row_tile(t, replace(SPEC, n_routed=4 * g, n_held=g, experts_per_tok=k))
+    assert tile == {512: 32, 16: 16, 128: 16, 2048: 128, 3: 16}[t]
+    rows = t * k + g * tile
     order = jnp.argsort(key, stable=True)
     sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
     want = _source_per_row(sizes, rows, tile)
@@ -341,6 +344,46 @@ def test_held_layer_is_bit_equal_under_either_map(monkeypatch, spec, make, valid
     assert np.array_equal(np.asarray(picked), np.asarray(picked0))
 
 
+# -- a group that outgrows its row tile (PR 51) ------------------------------------
+@pytest.mark.parametrize("path", ["xla", "kernel_interpreted"])
+@pytest.mark.parametrize("t,tile,times", [(80, 16, 5), (384, 32, 12)],
+                         ids=["tick_80_rows", "pack_384_rows"])
+def test_a_group_of_several_tiles_and_empty_groups_give_the_plain_layers_output(path, t, tile, times):
+    """Routing skewed by the bias: EVERY token picks held expert 0 (a group of 5 and
+    of 12 x the row tile its expected 2.5 and 12 rows earned it: further tiles,
+    nothing dropped) and no token an odd one (half the groups hold nothing); the
+    output and dx are the plain layer's, every expert on every token masked by the
+    routing, on the XLA path and through the interpreted kernels."""
+    from deepspeed_tpu.ops.pallas.selected_attention import interpreted
+
+    d, f, e, g, k = 128, 128, 64, 16, 2
+    spec = replace(SPEC, n_routed=e, n_held=g, experts_per_tok=k, moe_width=f, n_shared=0)
+    assert layer.held_row_tile(t, spec) == tile and layer.held_rows_bound(t, spec) is None
+    ks = jax.random.split(jax.random.PRNGKey(30), 6)
+    n = lambda key, *s: jax.random.normal(key, s, jnp.float32) / np.sqrt(s[-2])
+    bias = jnp.zeros(e).at[0].set(10.0).at[jnp.arange(1, e, 2)].set(-10.0)
+    lw = {"router": n(ks[0], d, e), "bias": bias, "w_gate": n(ks[1], g, d, f),
+          "w_up": n(ks[2], g, d, f), "w_down": n(ks[3], g, f, d)}
+    x, ct = jax.random.normal(ks[4], (t, d)), jax.random.normal(ks[5], (t, d))
+
+    def plain(x):
+        idx, wts = held_routing(lw, x, spec)[:2]
+        y = jnp.zeros_like(x)
+        for i in range(g):
+            w_i = jnp.sum(jnp.where(idx == i, wts, 0.0), -1, keepdims=True)
+            y += w_i * _swiglu(x, lw["w_gate"][i], lw["w_up"][i], lw["w_down"][i])
+        return y
+
+    held = lambda x: moe_block_held(lw, x, spec)
+    with interpreted() if path == "kernel_interpreted" else contextlib.nullcontext():
+        y, pull, (stats, _, _) = jax.vjp(held, x, has_aux=True)
+        dx = pull(ct)[0]
+    y_ref, pull_ref = jax.vjp(plain, x)
+    assert int(stats[2]) == t == times * tile and int(stats[3]) == 0
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=5e-5, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(pull_ref(ct)[0]), atol=5e-5, rtol=1e-4)
+
+
 # -- the bounded layout (PR 50) --------------------------------------------------
 # over ``held_rows_bound``'s threshold: 2048 x 2 = 4096 pairs, 16 x the two held
 # groups' padding; 2 of 16 experts held, so the bound is twice an eighth of the
@@ -362,9 +405,9 @@ def _rows_that_ran(monkeypatch):
     holds no pair is traced and skipped)."""
     ran, inner = [], layer.grouped_matmul
 
-    def noting(xs, w, sizes):
+    def noting(xs, w, sizes, tile):
         jax.debug.callback(lambda: ran.append(xs.shape[0]))
-        return inner(xs, w, sizes)
+        return inner(xs, w, sizes, tile)
 
     monkeypatch.setattr(layer, "grouped_matmul", noting)
     return ran
@@ -394,8 +437,9 @@ def test_the_bound_is_twice_the_uniform_share_and_engages_by_shape():
     for pairs, want in ((0, (0, 1)), (BOUND, (ROWS_BOUNDED, 1)), (BOUND + 1, (2 * ROWS_BOUNDED, 0)),
                         (T_B * K_B, (4 * ROWS_BOUNDED, 0))):
         assert tuple(map(int, layer.held_rows_laid_out(T_B, SPEC_B, jnp.int32(pairs)))) == want
+    # under the threshold the worst case at the rule's tile: 40 x 2 / 16 = 5 rows a group -> 16
     assert tuple(map(int, layer.held_rows_laid_out(40, SPEC_B, jnp.int32(3)))) == (
-        40 * K_B + G_B * 128, 0)
+        40 * K_B + G_B * 16, 0)
 
 
 @pytest.mark.parametrize("spec,make", [(SPEC_B, _weights_groups), (SPEC_B_RELU2, _weights_relu2)],

@@ -56,7 +56,7 @@ def _a_quarter_held(monkeypatch):
     """The rehearsal size with 4 of SIXTEEN experts held and a row tile of 8:
     1152 pairs a layer, 36 x the groups' padding, so ``moe_block_held`` builds
     its bounded layout (576 pairs a pass in 608 rows for the worst case's 1184)."""
-    monkeypatch.setattr(layer, "_GMM_ROWS", 8)
+    monkeypatch.setattr(layer, "held_row_tile", lambda t, spec: 8)
     return dict(M, deployment=dict(M["deployment"], num_experts_total=16))
 
 
@@ -224,6 +224,80 @@ def test_the_kernel_paths_backward_matches_a_dense_einsum(sizes, k, n):
     assert not np.asarray(d_xs)[group == g].any()
 
 
+@pytest.mark.parametrize("tile", [16, 32, 64])
+@pytest.mark.parametrize("k,n", [(2688, 1024), (2304, 896), (896, 2304)],
+                         ids=["k2688_in_3_steps", "k2304_in_3_steps", "k896_in_1_step"])
+def test_the_kernels_at_a_short_row_tile_and_an_irregular_k_tile_match_a_dense_einsum(tile, k, n):
+    """megablox ``gmm``, its transposed form and ``tgmm`` INTERPRETED at the row
+    tiles the served programs get and the k tiles of cells 6 and 10 (896 and 768,
+    divisors of 21 x 128 and 18 x 128): empty groups, a group of 5 x its tile, groups
+    that share a tile, rows past the last group and ``M`` no whole tile, against a
+    dense one-hot einsum, a group at a time."""
+    from deepspeed_tpu.ops.pallas import record_dispatch
+
+    sizes = [0, 5 * tile, 3, 0, tile, 1]
+    rng = np.random.default_rng(tile + k)
+    g, m = len(sizes), sum(sizes) + tile + 5
+    xs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((g, k, n)) / np.sqrt(k), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+    group = np.repeat(np.arange(g + 1), sizes + [m - sum(sizes)])
+    onehot = jnp.asarray(group[:, None] == np.arange(g)[None, :], jnp.float32)
+    live = jnp.asarray(group < g)[:, None]
+    dense = lambda xs, w: jnp.einsum("mg,mk,gkn->mn", onehot, xs, w, precision="highest")
+    ours = lambda xs, w: jnp.where(
+        live, grouped_matmul(xs, w, jnp.asarray(sizes, jnp.int32), tile), 0.0)
+    assert layer._gmm_tiling(tile, k, n)[1] in (768, 896)
+    with interpreted(), record_dispatch() as log:
+        y, vjp = jax.vjp(ours, xs, w)
+        d_xs, d_w = vjp(ct)
+    assert [d["ran"] for d in log if d["kernel"] == "expert_gmm"] == [True]
+    y_ref, vjp_ref = jax.vjp(dense, xs, w)
+    d_xs_ref, d_w_ref = vjp_ref(ct)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=5e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(d_xs), np.asarray(d_xs_ref), atol=5e-4, rtol=1e-4)
+    for e in range(g):
+        np.testing.assert_allclose(np.asarray(d_w[e]), np.asarray(d_w_ref[e]), atol=1e-3, rtol=1e-4,
+                                   err_msg=f"group {e} of {sizes[e]} rows")
+    assert not np.asarray(d_xs)[group == g].any()
+
+
+def test_the_gradient_at_cell_tens_tile_and_k_tiles_is_the_worst_case_layouts(monkeypatch):
+    """The tiling cell 10 gets (a 128-row tile for a group that expects thousands of
+    rows, ``tk`` 768 of d 2304 and 896 of f 896), on the path the CHIP takes,
+    interpreted, through the bounded layout (1024 tokens x 2 picks over 4 experts, 1
+    held: 1024 pairs a pass in 1152 rows): the output, dx, the router's and the held
+    expert's three weight gradients are those of the function that lays out the
+    worst case's 2176 rows."""
+    from deepspeed_tpu.ops.pallas import record_dispatch
+
+    t, d, f = 1024, 2304, 896
+    spec = dataclasses.replace(_cfg().latent, n_routed=4, n_held=1, held_offset=0,
+                               experts_per_tok=2, moe_width=f)
+    assert layer.held_row_tile(t, spec) == 128 and layer.held_rows_bound(t, spec) == 1024
+    assert [layer._gmm_tiling(128, *kn)[1:] for kn in ((d, f), (f, d))] == [(768, 896), (896, 1152)]
+    rng = np.random.default_rng(8)
+    n = lambda *s: jnp.asarray(rng.standard_normal(s) / np.sqrt(s[-2]), jnp.float32)
+    lw = {"router": n(d, 4), "w_gate": n(1, d, f), "w_up": n(1, d, f), "w_down": n(1, f, d)}
+    x, ct = n(t, d) * np.sqrt(t), n(t, d)
+
+    def run():
+        with interpreted(), record_dispatch() as log:
+            y, pull = jax.vjp(lambda lw, x: moe_block_held(lw, x, spec)[0], lw, x)
+            return y, pull(ct), {e["shape"][0] for e in log if e["kernel"] == "expert_gmm" and e["ran"]}
+
+    y, grads, rows = run()
+    assert rows == {1024 + 128}
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    y0, grads0, rows0 = run()
+    assert rows0 == {2048 + 128}
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path((y, grads)),
+                                 jax.tree_util.tree_leaves((y0, grads0))):
+        assert np.abs(np.asarray(want)).max() > 0
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 def test_fsdp_4_on_a_cpu_mesh_equals_one_device():
     cfg, ids = _cfg(), _ids(4)
     losses = {}
@@ -260,8 +334,9 @@ def test_the_steps_counts_are_booked_and_the_span_carries_tokens_and_layers(monk
         assert read("expert_pairs_held") <= steps * layers * 576
         assert read("expert_layers_bounded") == steps * layers
         assert read("expert_rows_laid_out") == steps * layers * (576 + 4 * 8)
-    else:  # half the experts held, 2.25 x the padding: no bound is built
+    else:  # half the experts held, 144 rows a group in a 128-row tile: no bound is built
         assert read("expert_layers_bounded") == 0
+        assert layer.held_row_tile(tokens, cfg.latent) == 128
         assert read("expert_rows_laid_out") == steps * layers * (tokens * k + 4 * 128)
     w = M["sliding_window"]
     assert read("causal_keys") == steps * ROWS * layers * SEQ * (SEQ + 1) // 2
@@ -363,14 +438,19 @@ def test_the_kernel_path_runs_the_bounded_layout_and_its_gradients_are_the_worst
             np.testing.assert_allclose(got, want, atol=5e-5, rtol=1e-4, err_msg=f"{name}[{e}]")
 
 
+def _cfg_of(config: str):
+    """A benchmark configuration at its published widths."""
+    m = harness.load_json(harness.HERE / "configs" / f"{config}.json")
+    return harness.module("models", m["model_type"]).transformer_config(m)
+
+
 def _held_layer_at_real_size(config: str, t: int):
     """(the jaxpr of ``moe_block_held`` at ``t`` tokens of a benchmark
     configuration's published widths, traced and not run; the rows each
     ``grouped_matmul`` call was handed; the spec)."""
     from deepspeed_tpu.ops.pallas import record_dispatch
 
-    m = harness.load_json(harness.HERE / "configs" / f"{config}.json")
-    cfg = harness.module("models", m["model_type"]).transformer_config(m)
+    cfg = _cfg_of(config)
 
     def held(tree):  # the first expert layer's weights, wherever the model keeps them
         if isinstance(tree, dict):
@@ -399,24 +479,92 @@ def _equations(jaxpr):
             yield from _equations(sub)
 
 
-@pytest.mark.parametrize("config,t,rows", [
-    ("dots3_note_l5_e32_serve_1chip", 2048, 20480), ("dots3_note_l5_e32_serve_1chip", 16, 4224),
-    ("nemotron3_super_l11_e128_serve_1chip", 512, 27648),
-    ("nemotron3_super_l11_e128_serve_1chip", 128, 19200),
-    ("qwen3_next_l8_e128_serve_1chip", 512, 21504), ("qwen3_next_l8_e128_serve_1chip", 16, 16544),
-    ("laguna_xs2_l5_serve_1chip", 512, 36864), ("laguna_xs2_l5_serve_1chip", 32, 33024),
-    ("deepseek_v2_l5_e40_serve_1chip", 2048, 17408), ("deepseek_v2_l5_e40_serve_1chip", 24, 5264)],
-    ids=lambda v: str(v).split("_")[0])
-def test_a_served_pack_or_tick_lays_out_what_it_did_and_holds_no_cond(config, t, rows):
+# a pack's or a tick's tokens, the rows a held group expects there, the row tile the
+# rule gives it and the rows the layer lays out (ISSUE 51's table; the parent's rows,
+# ``t k + g x 128``, beside them)
+PROGRAMS = [
+    ("dots3_note_l5_e32_serve_1chip", 2048, 64, 128, 20480, 20480),
+    ("dots3_note_l5_e32_serve_1chip", 16, 0.5, 16, 640, 4224),
+    ("nemotron3_super_l11_e128_serve_1chip", 512, 22, 64, 19456, 27648),
+    ("nemotron3_super_l11_e128_serve_1chip", 128, 5.5, 16, 4864, 19200),
+    ("qwen3_next_l8_e128_serve_1chip", 512, 10, 32, 9216, 21504),
+    ("qwen3_next_l8_e128_serve_1chip", 16, 0.3125, 16, 2208, 16544),
+    ("laguna_xs2_l5_serve_1chip", 512, 16, 32, 12288, 36864),
+    ("laguna_xs2_l5_serve_1chip", 32, 1, 16, 4352, 33024),
+    ("deepseek_v2_l5_e40_serve_1chip", 2048, 76.8, 128, 17408, 17408),
+    ("deepseek_v2_l5_e40_serve_1chip", 24, 0.9, 16, 784, 5264)]
+_program_ids = lambda v: v.split("_")[0] if isinstance(v, str) else None
+
+
+@pytest.mark.parametrize("config,t,expected,tile,rows,before", PROGRAMS + [
+    ("mellum2_l4_e16_train_1chip", 16384, 2048, 128, 67584, 67584)], ids=_program_ids)
+def test_the_row_tile_is_the_smallest_that_holds_twice_a_groups_expected_rows(
+        config, t, expected, tile, rows, before):
+    """The rule's table at the eleven programs, from the benchmark's configuration
+    files alone (nothing traced): the rows a group expects under uniform routing,
+    the tile (16 for a tick, 32-64 for a 512-token pack, the 128 it had for a
+    2048-token pack and for a training step's 2048 rows a group) and the rows
+    laid out, ``t k + g x tile`` or the bounded pass's ``C + g x tile``."""
+    spec = _cfg_of(config).latent
+    assert t * spec.experts_per_tok / spec.n_routed == pytest.approx(expected)
+    assert layer.held_row_tile(t, spec) == tile
+    bound = layer.held_rows_bound(t, spec)
+    assert (bound is None) == (config != "mellum2_l4_e16_train_1chip")
+    pairs = t * spec.experts_per_tok if bound is None else bound
+    assert pairs + spec.n_held * tile == rows == layer.held_rows_a_pass(t, spec) <= before
+    assert int(layer.held_rows_laid_out(t, spec, jnp.int32(pairs // 2))[0]) == rows
+    if bound is None:
+        assert before == t * spec.experts_per_tok + spec.n_held * 128
+    # short of 2 x the expectation only where the ladder ends (cells 5, 9 and 10 stay as they were)
+    assert tile >= 2 * expected or tile == 128
+
+
+@pytest.mark.parametrize("k,n,tk,tn", [
+    (5120, 1536, 1024, 1536), (1536, 5120, 512, 2560),  # cells 5 and 9: as PR 29 measured them
+    (2048, 512, 1024, 512), (512, 2048, 512, 2048),     # cells 7 and 8: as they were
+    (1024, 2688, 512, 2688), (2688, 1024, 896, 1024),   # cell 6: k 2688 = 21 x 128 had tk 128
+    (2304, 896, 768, 896), (896, 2304, 896, 1152)])     # cell 10: had tk 256 and 128
+def test_the_weight_tile_comes_from_ks_and_ns_own_divisors(k, n, tk, tn):
+    for tm in (16, 128, 512):
+        assert layer._gmm_tiling(tm, k, n) == (tm, tk, tn)
+    assert k % tk == 0 and n % tn == 0 and tk <= 1024 and tk * tn <= 1600 * 1024
+
+
+@pytest.mark.parametrize("config,t,expected,tile,rows,before", PROGRAMS, ids=_program_ids)
+def test_a_served_pack_or_tick_lays_out_what_it_did_and_holds_no_cond(config, t, expected, tile,
+                                                                     rows, before):
     """Cells 5-9 at their pack's and their tick's tokens, published widths,
-    traced only: ``t k + g x 128`` rows to each of the layer's grouped matmuls
+    traced only: ``t k + g x tile`` rows to each of the layer's grouped matmuls
     (the ``gmm`` shapes on the ledger's lines) and no ``cond`` anywhere: under
-    ``held_rows_bound``'s threshold the function is the one it was."""
+    ``held_rows_bound``'s threshold the function lays out the worst case in one
+    body, as it always did."""
     jaxpr, handed, spec = _held_layer_at_real_size(config, t)
-    assert rows == t * spec.experts_per_tok + spec.n_held * 128
+    assert rows == t * spec.experts_per_tok + spec.n_held * tile
     assert handed == [rows] * (3 if spec.expert_form == "swiglu" else 2)
     assert not [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "cond"]
-    assert t * spec.experts_per_tok / (spec.n_held * 128) <= 4
+    assert t * spec.experts_per_tok / (spec.n_held * tile) <= 8
+
+
+# ``moe_block_held``'s jaxpr on the KERNEL path (addresses blanked) at cells 5 and 9's
+# packs, as the parent of PR 51 (d57c2c8) traced it: their row tile, their rows and
+# their weight tiles are what they were, character for character
+PARENTS_PACKS = {
+    "dots3_note_l5_e32_serve_1chip": "95d162ff7c6f792a2c51156da93098618b72560a8cdd66bc80a8c7ae61fadd8c",
+    "deepseek_v2_l5_e40_serve_1chip": "2de4aec240bc4c70e8e86b71a96c68703243be1a65241fb7bd5e6aeb9c50dbdf"}
+
+
+@pytest.mark.parametrize("config", sorted(PARENTS_PACKS), ids=_program_ids)
+def test_cells_5_and_9s_packs_trace_to_the_parents_jaxpr(monkeypatch, config):
+    import hashlib
+    import re
+
+    import deepspeed_tpu.ops.pallas as pallas_ops
+
+    monkeypatch.setattr(pallas_ops, "on_tpu", lambda: True)
+    jaxpr, handed, _ = _held_layer_at_real_size(config, 2048)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert "pallas_call" in text and len(handed) == 3
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_PACKS[config]
 
 
 def test_cell_tens_step_lays_out_67584_rows_a_pass_and_holds_no_worst_case_array():

@@ -469,7 +469,8 @@ def test_without_the_barrier_the_compiler_relays_wq_wk_wv(one_chip, as_on_tpu,
 def test_held_experts_layout_looks_its_groups_up_a_tile_at_a_time(one_chip, as_on_tpu):
     """``moe_block_held`` at the Qwen3-Next pack's shape (512 tokens x 2048,
     top 10, 128 held of 512) compiled for a v5e: ONE gather whose result is
-    an int32 a ROW of the padded layout (R = 21 504) is left, the pairs'
+    an int32 a ROW of the padded layout (R = 9 216 at the 32-row tile ten expected
+    rows a group get; 21 504 until PR 51) is left, the pairs'
     ``order[source]``.  The chip walks such a gather an index at a time, 0.17
     ms each, and the map from rows to sorted pairs had three more a layer
     (``pstart[of]``, ``sizes[of]``, ``start[of]``: now one index a TILE)."""
@@ -488,7 +489,8 @@ def test_held_experts_layout_looks_its_groups_up_a_tile_at_a_time(one_chip, as_o
     text = jax.jit(lambda lw, x: layer.moe_block_held(lw, x, spec)[0]).trace(
         lw, S(t, d)).lower(lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in text, "the grouped matmul's Mosaic body was not compiled"
-    rows = t * k + g * layer._GMM_ROWS
+    rows = t * k + g * layer.held_row_tile(t, spec)
+    assert rows == 9216
     assert len(re.findall(rf"= s32\[{rows}\]\S* gather\(", text)) <= 1
 
 

@@ -24,8 +24,8 @@ from deepspeed_tpu.models.transformer import init_params  # noqa: E402
 
 LATENT_COUNTERS = (
     "index_keys_scored", "index_keys_selected", "window_rows_discarded",
-    "selected_groups", "selected_groups_dense", "expert_pairs_routed", "expert_pairs_held", "expert_group_rows_max",
-    "expert_group_rows_min")
+    "selected_groups", "selected_groups_dense", "expert_pairs_routed", "expert_pairs_held", "expert_rows_laid_out",
+    "expert_group_rows_max", "expert_group_rows_min")
 
 
 @pytest.mark.parametrize("name", BODIES)

@@ -142,6 +142,16 @@ def test_chunked_prefill_shared_packs_and_unequal_ages_match_the_reference(model
     # the routers' device-side counts were read at close(): every expert is held
     assert eng.stats["expert_pairs_routed"] == eng.stats["expert_pairs_held"] > 0
     assert 0 < eng.stats["experts_touched_decode"] < eng.stats["experts_touched"]
+    # ... and the rows its expert layers laid out follow from the programs' shapes alone:
+    # ``t k + g x tile`` a layer, a pack of CHUNK tokens and a tick of 4 slots
+    from deepspeed_tpu.moe.layer import held_rows_a_pass
+
+    s = cfg.latent
+    a_pack, a_tick = held_rows_a_pass(CHUNK, s), held_rows_a_pass(4, s)
+    assert a_tick == 4 * s.experts_per_tok + s.n_held * 16 < a_pack
+    assert eng.stats["expert_rows_laid_out"] == len(s.expert_layers) * (
+        eng.stats["prefill_dispatches"] * a_pack + eng.stats["decode_ticks"] * a_tick)
+    assert eng.stats["expert_rows_laid_out"] > eng.stats["expert_pairs_held"]
 
 
 def test_a_slots_next_owner_overwrites_the_ring_from_zero(model):
