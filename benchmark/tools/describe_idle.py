@@ -23,6 +23,7 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import bisect  # noqa: E402
 import re  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -30,7 +31,7 @@ from pathlib import Path  # noqa: E402
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from benchmark import harness, xplane, xprograms  # noqa: E402
-from benchmark.readers import executions_per_span, idle_by_phase  # noqa: E402
+from benchmark.readers import idle_by_phase  # noqa: E402
 from benchmark.stats import percentile  # noqa: E402
 
 ENGINE = r"^jit_(packed|packed_ctx|decode|decode_burst|spec|cow)_impl$"  # the engine's own programs
@@ -71,8 +72,21 @@ def mark_table(spans, window):
     return rows
 
 
+def started_inside(progs, span, excluding, shift):
+    """For each mirrored ``span`` in the capture, the executions on device 0's
+    ``XLA Modules`` line that started inside it and whose module does NOT
+    match ``excluding`` (device times shifted onto the host clock by
+    ``shift``): the small programs a tick runs beside the engine's own, each
+    of which costs a launch and cuts an idle gap in two."""
+    rx = re.compile(excluding)
+    aux = [e for e in progs.of_module("") if not rx.search(e.module)]
+    starts = [e.start + shift for e in aux]
+    return [aux[bisect.bisect_left(starts, h.start):bisect.bisect_left(starts, h.end)]
+            for h in progs.mirrored(span)]
+
+
 def aux_table(progs, excluding, shift):
-    ticks = executions_per_span.inside(progs, "sched.tick", excluding, shift)
+    ticks = started_inside(progs, "sched.tick", excluding, shift)
     by = {}
     for e in (e for runs in ticks for e in runs):
         n, s = by.get(e.module, (0, 0.0))

@@ -29,8 +29,8 @@ def _entry():
     return [m for m in harness.metrics_of(MAN, CELL, True) if m["name"] == NAME]
 
 
-def test_the_entry_is_the_manifests_last_and_sits_in_the_cells_layer():
-    entry = MAN["per_layer"][-1]
+def test_the_entry_is_found_by_its_name_and_sits_in_the_cells_layer():
+    entry, = [m for m in MAN["per_layer"] if m["name"] == NAME]   # wherever in the list it stands
     assert [entry] == _entry()
     assert entry["source"] == "program_span" and entry["workloads"] == [CELL]
     assert (entry["unit"], entry["better"], entry["moves"]) == ("%", "higher", "serve_tokens_per_s")

@@ -1,7 +1,8 @@
 """The device's idle time charged to the program's phases instant by instant
 (``readers/idle_by_phase``), the causality interval's width with and without
 the ``upload`` mark (``idle_by_phase.causality``) and the small programs
-a tick runs beside the engine's own (``readers/executions_per_span``): on
+a tick runs beside the engine's own (``tools/describe_idle.started_inside``,
+the tool's since the metric that read it was retired in PR 52): on
 three hand-made scheduler ticks whose every idle interval is known."""
 import sys
 from pathlib import Path
@@ -13,7 +14,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import one_ahead_scenes as scenes  # noqa: E402
 from benchmark import xprograms  # noqa: E402
-from benchmark.readers import executions_per_span, idle_by_phase  # noqa: E402
+from benchmark.readers import idle_by_phase  # noqa: E402
+from benchmark.tools import describe_idle  # noqa: E402
 from benchmark.xplane import HostEvent  # noqa: E402
 from benchmark.xprograms import Execution, Programs, RawOp  # noqa: E402
 
@@ -170,23 +172,21 @@ def test_skew_width_with_and_without_the_tightened_bound():
 
 def test_small_programs_a_tick_are_counted_by_the_span_they_start_in():
     progs, spans = scene()
-    per_tick = executions_per_span.inside(progs, "sched.tick", AUX, D)
+    per_tick = describe_idle.started_inside(progs, "sched.tick", AUX, D)
     assert [len(r) for r in per_tick] == [2, 4, 2]
     assert {e.module for r in per_tick for e in r} == {"jit__threefry_split", "jit__unstack"}
-    obs = {"trace": object(), "_xprograms": progs, "spans": spans}
-    params = dict(span="sched.tick", excluding=AUX, dispatch="decode_tick",
-                  module="^jit_decode_impl$")
-    assert executions_per_span.read(obs, q=50, **params) == 2
-    assert executions_per_span.read(obs, q=100, **params) == 4
-    assert executions_per_span.read({"trace": None}, q=50, **params) is None
+    # cut at the looser shift the decode ticks' pairing alone allows (no upload
+    # mark: the span opened 5 ms before its execution), every tick counts the same
+    loose = xprograms.tight_edge(xprograms.skew(progs, "decode_tick", "^jit_decode_impl$", spans=spans))
+    assert loose == pytest.approx(D - 0.005, abs=1e-9)
+    assert [len(r) for r in describe_idle.started_inside(progs, "sched.tick", AUX, loose)] == [2, 4, 2]
+    assert describe_idle.started_inside(progs, "sched.tick", ".", D) == [[], [], []]
 
 
 def test_the_tool_prints_its_tables_of_the_same_scene():
     """``tools/describe_idle.py``'s tables on the three ticks: the phases in
     ms a tick, the marks' medians, the small programs by module, and a
     runtime TraceMe charged to the phase the scheduler's thread was in."""
-    from benchmark.tools import describe_idle
-
     progs, spans = scene()
     secs = idle_by_phase.seconds(progs, spans)
     rows = describe_idle.phase_table(secs, 1.0, 3)

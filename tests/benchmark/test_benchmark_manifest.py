@@ -118,44 +118,66 @@ def test_no_metric_file_is_left_without_an_entry():
     assert used == readers
 
 
-# PR 41 and PR 44 merged the families below: one entry ``<family>.serve`` whose
-# ``workloads`` are the cells that each had an entry ``<family>.<suffix>`` with
-# the same reader and parameters.  What the per-cell files held, kept here:
+# PR 41, PR 44 and PR 52 merged the families below: one entry ``<family>.serve``
+# (``.train``) whose ``workloads`` are the cells that each had an entry
+# ``<family>.<suffix>`` with the same reader and parameters.  What the per-cell
+# files held, kept here:
 SUFFIX = {"docs": "mistral7b_docs_closed", "dots": "dots3_note_longdocs_closed",
           "nemo": "nemotron3_super_reasoning_closed", "qnext": "qwen3_next_longctx_qa_closed",
-          "laguna": "laguna_xs2_mixed_len_closed"}
+          "laguna": "laguna_xs2_mixed_len_closed", "dsv2": "deepseek_v2_doc_qa_sessions_closed",
+          "mellum": "mellum2_train_8k_experts_1chip"}
 FOUR = ("docs", "dots", "nemo", "qnext")
 FIVE = FOUR + ("laguna",)
+SIX = FIVE + ("dsv2",)   # PR 52: cell 9's fourteen copies (PR 45 brought them)
 PACKS = "^jit_packed(_ctx)?_impl$"
 PACK_EXPERTS = {"num": "expert_pairs_held", "num_less": "expert_pairs_held_decode",
                 "den": "experts_touched", "den_less": "experts_touched_decode"}
 MERGED = [
-    ("kv_preemptions", FIVE, "counter", {"key": "preemptions"}),
-    ("kernel_fallbacks", FIVE, "kernel_fallbacks", {}),
-    ("compiles_in_window", FIVE, "field", {"key": "compiles_in_window"}),
-    ("device_idle_share", FIVE, "device_idle_share", {}),
-    ("peak_hbm_gib", FIVE, "peak_hbm_gib", {}),
-    ("prefill_pack_device_p50_ms", FIVE, "module_device_percentile", {"module": PACKS, "q": 50}),
+    ("kv_preemptions", SIX, "counter", {"key": "preemptions"}),
+    ("kernel_fallbacks", SIX, "kernel_fallbacks", {}),
+    ("compiles_in_window", SIX, "field", {"key": "compiles_in_window"}),
+    ("device_idle_share", SIX, "device_idle_share", {}),
+    ("peak_hbm_gib", SIX, "peak_hbm_gib", {}),
+    ("prefill_pack_device_p50_ms", SIX, "module_device_percentile", {"module": PACKS, "q": 50}),
     ("pack_build_p50_ms", FOUR[:3], "span_percentile", {"span": "engine.pack_build", "q": 50}),
-    ("host_device_skew_ms", FIVE, "host_device_skew",
+    ("host_device_skew_ms", SIX, "host_device_skew",
      {"span": "decode_tick", "module": "^jit_decode_impl$"}),
-    ("routed_here_share", FOUR[1:], "counter_ratio",
+    ("routed_here_share", FOUR[1:] + ("dsv2",), "counter_ratio",
      {"num": "expert_pairs_held", "den": "expert_pairs_routed", "scale": 100.0}),
-    ("decode_batch_mean", FIVE[2:], "counter_ratio",
+    ("decode_batch_mean", SIX[2:], "counter_ratio",
      {"num": "decode_emitted", "den": "decode_ticks"}),
-    ("decode_device_p50_ms", FIVE[2:], "module_device_percentile",
+    ("decode_device_p50_ms", SIX[2:], "module_device_percentile",
      {"module": "^jit_decode_impl$", "q": 50}),
     # PR 44: the expert layer of the cells whose PACK program runs it (cell 5's
     # files named ``jit_packed_ctx_impl`` alone: a ``cfg.latent`` runner packs
     # through no other program, so the wider pattern finds the same executions)
     ("expert_layout_call_ms", ("dots", "qnext", "laguna"), "scope_call_ms",
      {"module": PACKS, "scope": "(^|/)expert_layout(/|$)", "q": 50}),
-    ("expert_matmul_call_ms", ("dots", "qnext", "laguna"), "scope_call_ms",
+    ("expert_matmul_call_ms", ("dots", "qnext", "laguna", "dsv2"), "scope_call_ms",
      {"module": PACKS, "scope": "(^|/)expert_matmul(/|$)", "q": 50}),
-    ("expert_matmul_roofline", FIVE[3:], "gdn_roofline",
+    ("expert_matmul_roofline", SIX[3:], "gdn_roofline",
      {"module": PACKS, "scope": "(^|/)expert_matmul(/|$)", "cost": "expert_matmul"}),
-    ("expert_rows_mean", FIVE[3:], "counter_difference_ratio", PACK_EXPERTS),
+    ("expert_rows_mean", SIX[3:], "counter_difference_ratio", PACK_EXPERTS),
+    # born a ``.serve`` entry in PR 44 (no per-cell file but cell 9's ever held it)
+    ("host_slack_p50_ms", SIX, "span_sum_percentile",
+     {"outer": "sched.tick", "inner": "tick_collect", "q": 50}),
 ]
+# PR 52: cell 10's five copies of the ``.train`` readers (PR 49 brought them), the
+# cell appended behind the two cells the ``.train`` entries were born with.
+# ``train_mfu.mellum``'s file carried a ``note`` beside the same reader: its FLOPs
+# a token are ``models/mellum.py``'s ``train_flops_per_token``, what a token
+# REQUIRES at the deployment's nominal share of the experts (2 held a token), not
+# the pairs a step counted (``expert_train_roofline.mellum`` takes those).
+TRAIN_MERGED = [
+    ("device_idle_share", ("mellum",), "device_idle_share", {}),
+    ("peak_hbm_gib", ("mellum",), "peak_hbm_gib", {}),
+    ("kernel_fallbacks", ("mellum",), "kernel_fallbacks", {}),
+    ("compiles_in_window", ("mellum",), "field", {"key": "compiles_in_window"}),
+    ("train_mfu", ("mellum",), "train_mfu", {}),
+]
+BORN_WITH = {"serve": [], "train": ["mistral7b_train_1chip", "mistral7b_train_fsdp4"]}
+MOVES = {"serve": "serve_tokens_per_s", "train": "train_tokens_per_s_per_chip"}
+ROWS = [(into, *row) for into, rows in (("serve", MERGED), ("train", TRAIN_MERGED)) for row in rows]
 # a family's cells that keep an entry of their own on OTHER parameters (their
 # decode program runs the expert layer, or another reader counts its need)
 OTHER_PARAMETERS = {"expert_layout_call_ms": {"nemo"}, "expert_matmul_call_ms": {"nemo"},
@@ -164,16 +186,31 @@ IDLE = ("fetch_tail", "upload", "build_rng", "bookkeeping", "enqueue", "build_ro
 RETIRED = ["tick_p50_ms.docs", "host_enqueue_ms.train", "decode_dispatch_p50_ms.chat",   # PR 41
            # PR 44: what one-ahead dispatch left nothing to read, or something else
            "tick_host_gap_p50_ms", "decode_only_tick_p50_ms", "decode_tick_p50_ms.chat",
-           "prefill_pack_p50_ms.chat"] + [f"idle_{phase}_share" for phase in IDLE]
-FOLDED = [f"{family}.{suffix}" for family, suffixes, _, _ in MERGED for suffix in suffixes]
+           "prefill_pack_p50_ms.chat"] + [f"idle_{phase}_share" for phase in IDLE] + [
+           # PR 52, by the ledger: each read ONE value on both sides of all seven serving
+           # cells on every line since it was added (0.0 small programs a tick; 1.0 step
+           # ahead), and every new cell would have copied both.  A stray program shows in
+           # the line's ``breakdown`` and is held on the CPU by
+           # ``test_benchmark_tick_programs.py``; a tick no longer dispatched one ahead
+           # shows in ``host_slack_p50_ms`` and ``device_idle_share`` and is held on the
+           # CPU by ``tests/test_dispatch_ahead.py`` (``ahead == [1] * 11``)
+           "aux_programs_per_tick", "dispatch_ahead_p10"]
+FOLDED = [f"{family}.{suffix}" for _, family, suffixes, _, _ in ROWS for suffix in suffixes]
 
 
-@pytest.mark.parametrize("family,suffixes,reader,params", MERGED, ids=[m[0] for m in MERGED])
-def test_a_merged_family_reads_what_its_per_cell_files_read(family, suffixes, reader, params):
-    entry = next(m for m in MAN["per_layer"] if m["name"] == f"{family}.serve")
-    assert entry["workloads"] == [SUFFIX[s] for s in suffixes]
-    assert entry["moves"] == "serve_tokens_per_s"
-    spec = harness.load_json(harness.HERE / "metrics" / f"{family}.serve.json")
+def entry_named(man, name):
+    """The ``per_layer`` entry of that name, wherever in the list it stands."""
+    found, = [m for m in man["per_layer"] if m["name"] == name]
+    return found
+
+
+@pytest.mark.parametrize("into,family,suffixes,reader,params", ROWS,
+                         ids=[r[1] if r[0] == "serve" else f"{r[1]}.{r[0]}" for r in ROWS])
+def test_a_merged_family_reads_what_its_per_cell_files_read(into, family, suffixes, reader, params):
+    entry = entry_named(MAN, f"{family}.{into}")
+    assert entry["workloads"] == BORN_WITH[into] + [SUFFIX[s] for s in suffixes]
+    assert entry["moves"] == MOVES[into]
+    spec = harness.load_json(harness.HERE / "metrics" / f"{family}.{into}.json")
     assert spec["reader"] == reader and spec.get("params", {}) == params
     names = {m["name"] for m in MAN["per_layer"]}
     assert not names & {f"{family}.{s}" for s in suffixes}
@@ -186,15 +223,15 @@ def test_a_merged_family_reads_what_its_per_cell_files_read(family, suffixes, re
         assert (kept["reader"], kept.get("params", {})) == (reader, params)
 
 
-@pytest.mark.parametrize("family,suffix", [(f, s) for f, ss, _, _ in MERGED for s in ss],
+@pytest.mark.parametrize("into,family,suffix", [(i, f, s) for i, f, ss, _, _ in ROWS for s in ss],
                          ids=FOLDED)
-def test_a_folded_cell_loads_its_familys_one_file(family, suffix):
+def test_a_folded_cell_loads_its_familys_one_file(into, family, suffix):
     """What a traced run of the cell loads under the family's name is the
-    ``.serve`` entry, once, and no per-cell file of that family is left."""
+    ``.serve`` (``.train``) entry, once, and no per-cell file of that family is left."""
     loaded = [m["name"] for m in harness.metrics_of(MAN, SUFFIX[suffix], True)
               if m["name"].rpartition(".")[0] == family]
     keeps = suffix in OTHER_PARAMETERS.get(family, ())
-    assert loaded == [f"{family}.serve"] + ([f"{family}.{suffix}"] if keeps else [])
+    assert loaded == [f"{family}.{into}"] + ([f"{family}.{suffix}"] if keeps else [])
     assert not (harness.HERE / "metrics" / f"{family}.{suffix}.json").exists() or keeps
 
 
@@ -210,6 +247,60 @@ def test_the_retired_metrics_are_gone_from_the_manifest_and_the_harness(name):
         assert name not in text
 
 
+# What each copy PR 52 folded STATED in its own entry (unit, better, source, layer,
+# moves), recorded from the tree it was folded on: the namesake that reports the
+# cell now states the same, so the cell's ledger lines changed a name and nothing else.
+_KV, _DISP = "KV cache (inference/ragged.py)", "kernel dispatchers (ops/pallas)"
+_ENG = "engine and runner (inference/engine_v2.py, model_runner.py)"
+_SCHED, _HELD = "scheduler (inference/scheduler.py)", "held experts (moe/layer.py)"
+_S, _T = MOVES["serve"], MOVES["train"]
+COPIES = {
+    "kv_preemptions.dsv2": ("count", "lower", "program_counter", _KV, _S),
+    "kernel_fallbacks.dsv2": ("count", "lower", "program_counter", _DISP, _S),
+    "compiles_in_window.dsv2": ("count", "lower", "program_counter", "compile", _S),
+    "device_idle_share.dsv2": ("%", "lower", "device_trace", "device", _S),
+    "peak_hbm_gib.dsv2": ("GiB", "lower", "program_counter", "device", _S),
+    "prefill_pack_device_p50_ms.dsv2": ("ms", "lower", "device_trace", _ENG, _S),
+    "decode_device_p50_ms.dsv2": ("ms", "lower", "device_trace", _ENG, _S),
+    "decode_batch_mean.dsv2": ("count", "higher", "program_counter", _SCHED, _S),
+    "host_slack_p50_ms.dsv2": ("ms", "higher", "program_span", _SCHED, _S),
+    "host_device_skew_ms.dsv2": ("ms", "lower", "device_trace", "device", _S),
+    "routed_here_share.dsv2": ("%", "higher", "program_counter", _HELD, _S),
+    "expert_matmul_call_ms.dsv2": ("ms", "lower", "device_trace", _HELD, _S),
+    "expert_matmul_roofline.dsv2": ("%", "higher", "device_trace", _HELD, _S),
+    "expert_rows_mean.dsv2": ("count", "higher", "program_counter", _HELD, _S),
+    "device_idle_share.mellum": ("%", "lower", "device_trace", "device", _T),
+    "peak_hbm_gib.mellum": ("GiB", "lower", "host_clock", "device", _T),
+    "kernel_fallbacks.mellum": ("count", "lower", "program_counter", _DISP, _T),
+    "compiles_in_window.mellum": ("count", "lower", "host_clock", "compile", _T),
+    "train_mfu.mellum": ("%", "higher", "host_clock", "model (models/transformer.py)", _T),
+}
+# ... but for one label: PR 49 wrote ``host_clock`` as the ``source`` of a count of
+# compile requests and of the allocator's peak, on the readers (``field``,
+# ``peak_hbm_gib``) whose every other entry says ``program_counter``.  Same reader,
+# same parameters, same number: folded, the namesake's label standing (PERF.md section 7).
+MISLABELLED = {"peak_hbm_gib.mellum": ["source"], "compiles_in_window.mellum": ["source"]}
+KEYS = ("unit", "better", "source", "layer", "moves")
+
+
+@pytest.mark.parametrize("copy", sorted(COPIES))
+def test_a_folded_copy_kept_its_contract(copy):
+    family, _, suffix = copy.rpartition(".")
+    into = next(i for i, f, ss, _, _ in ROWS if f == family and suffix in ss)
+    namesake = entry_named(MAN, f"{family}.{into}")
+    assert SUFFIX[suffix] in namesake["workloads"]
+    differs = [k for k, said in zip(KEYS, COPIES[copy]) if namesake[k] != said]
+    assert differs == MISLABELLED.get(copy, [])
+    for key in differs:   # the label that stands is the one the family's other entries carry
+        siblings = {m[key] for m in MAN["per_layer"] if m["name"].rpartition(".")[0] == family}
+        assert siblings == {namesake[key]}
+
+
+def test_every_folded_copy_has_its_row_and_its_record():
+    assert sorted(COPIES) == sorted(n for n in FOLDED if n.rpartition(".")[2] in ("dsv2", "mellum"))
+    assert len(COPIES) == 19
+
+
 def test_the_readmes_count_is_one_the_list_has_reached():
     """``benchmark/README.md`` ("The cap") states what the last ``benchmark``
     PR left used and free; cells added since only add to it (they may not
@@ -218,6 +309,94 @@ def test_the_readmes_count_is_one_the_list_has_reached():
     stated, free = map(int, re.search(r"holds (\d+): (\d+) are free", text).groups())
     used = len(MAN["per_layer"])
     assert stated + free == CAP and stated <= used <= CAP, f"{used} of {CAP} used"
+
+
+# ---------------------------------------------------------------------------
+# the next cell: appended to a COPY of the manifest, it turns no lookup red
+# ---------------------------------------------------------------------------
+MADE_UP = "made_up_sessions_closed"
+# what a new serving architecture's cell brings today: a copy of each ``.serve``
+# family on its own suffix (it may not edit the lists) and readings of its own kernels
+ITS_COPIES = ["kv_preemptions", "kernel_fallbacks", "compiles_in_window", "device_idle_share",
+              "peak_hbm_gib", "prefill_pack_device_p50_ms", "decode_device_p50_ms",
+              "decode_batch_mean", "host_slack_p50_ms", "host_device_skew_ms",
+              "routed_here_share", "expert_matmul_call_ms", "expert_matmul_roofline"]
+ITS_OWN = [f"{kernel}_{what}" for kernel in ("scan", "step", "attn_pack", "attn_step")
+           for what in ("call_ms", "roofline")] + ["state_rows_share"]
+# the statements about ONE cell's entries, each in the file of that cell's tests
+LOOKUPS = {
+    "test_benchmark_mla": ["test_the_cell_is_one_chip_on_the_new_configuration_and_reports_throughput",
+                           "test_the_cells_why_states_the_sizes_its_traffic_file_runs",
+                           "test_the_cells_per_layer_entries_fit_under_the_cap"],
+    "test_benchmark_decompressed_keys": ["test_the_entry_is_found_by_its_name_and_sits_in_the_cells_layer"],
+    "test_benchmark_mellum": ["test_the_cell_trains_the_configuration_on_one_chip_and_reports_the_training_rate"],
+    "test_benchmark_manifest": ["test_top_level_keys_and_limits", "test_no_two_entries_share_a_name",
+                                "test_end_to_end_bounds_and_setup", "test_layers_of_one_module_are_spelled_alike",
+                                "test_the_readmes_count_is_one_the_list_has_reached",
+                                "test_every_folded_copy_has_its_row_and_its_record"],
+}
+
+
+def with_a_cell_appended(man):
+    """A deep copy of ``man`` with what the next ``model_config`` PR appends: a
+    configuration, a one-chip serving cell on it, the cell in its end-to-end
+    metric's list, and 22 entries of ``per_layer`` on a suffix of its own."""
+    import copy
+
+    man = copy.deepcopy(man)
+    man["configs"].append({
+        "name": "made_up_l4_serve_1chip", "source": "https://example.org/made-up/config.json",
+        "file": "benchmark/configs/made_up_l4_serve_1chip.json", "reduced": ["num_hidden_layers"],
+        "why": "an architecture nobody published: two mixers a block, each with a cache of its own"})
+    man["workloads"].append({
+        "name": MADE_UP, "config": "made_up_l4_serve_1chip", "traffic": "made_up_closed", "chips": 1,
+        "why": "16 closed-loop callers on contexts of 8k-32k: both caches of a block under one pack"})
+    next(m for m in man["end_to_end"] if m["name"] == MOVES["serve"])["workloads"].append(MADE_UP)
+    for family in ITS_COPIES:
+        man["per_layer"].append({**entry_named(man, f"{family}.serve"),
+                                 "name": f"{family}.made", "workloads": [MADE_UP]})
+    for name in ITS_OWN:
+        man["per_layer"].append({**entry_named(man, "kernel_fallbacks.serve"), "name": f"{name}.made",
+                                 "unit": "%" if name.endswith(("roofline", "share")) else "ms",
+                                 "source": "device_trace", "workloads": [MADE_UP]})
+    return man
+
+
+def test_a_cell_appended_to_the_manifest_turns_no_lookup_by_name_red(monkeypatch):
+    """Every statement ``tests/benchmark/`` makes about one cell's entries finds
+    them by NAME: with a made-up cell, its configuration and 22 entries behind
+    everything that is there, each still holds and the list stays under the cap."""
+    import importlib.util
+
+    grown = with_a_cell_appended(MAN)
+    assert len(ITS_COPIES) + len(ITS_OWN) == 22
+    assert len(grown["per_layer"]) == len(MAN["per_layer"]) + 22 <= CAP
+    assert grown["workloads"][-1]["name"] == MADE_UP and grown["per_layer"][-1]["workloads"] == [MADE_UP]
+    monkeypatch.setattr(harness, "manifest", lambda: grown)
+    # the copies' files, as the cell would bring them: what the ``.serve`` namesake's file holds
+    on_disk = harness.load_json
+    monkeypatch.setattr(harness, "load_json", lambda path: on_disk(
+        Path(str(path).replace(".made.json", ".serve.json"))))
+    for file, tests in LOOKUPS.items():
+        spec = importlib.util.spec_from_file_location(f"grown_{file}", Path(__file__).with_name(f"{file}.py"))
+        again = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(again)   # its ``MAN = harness.manifest()`` is the grown copy
+        assert again.MAN is grown
+        for name in tests:
+            getattr(again, name)()
+        if file == "test_benchmark_manifest":   # ... and its parametrised statements, row by row
+            for row in again.ROWS:
+                again.test_a_merged_family_reads_what_its_per_cell_files_read(*row)
+                for suffix in row[2]:
+                    again.test_a_folded_cell_loads_its_familys_one_file(row[0], row[1], suffix)
+            for copy in again.COPIES:
+                again.test_a_folded_copy_kept_its_contract(copy)
+            for cell in again.CELLS:
+                again.test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(cell)
+            assert again.CELLS[-1] == MADE_UP
+            for metric in grown["per_layer"]:
+                for cell in again.cells_of(metric):
+                    again.test_moves_names_an_end_to_end_metric_its_cells_report(metric, cell)
 
 
 def test_layers_of_one_module_are_spelled_alike():
@@ -346,7 +525,7 @@ def test_a_key_that_differs_from_its_published_file_must_be_in_reduced(key, valu
 def test_traffic_is_a_data_file_with_a_generator(cell):
     t = harness.traffic_of(cell["traffic"])
     harness.module("generators", t["kind"])
-    if cell["traffic"] == "chat_sessions":
+    if t["kind"] == "open_sessions":   # offered at a rate, told by the kind and not by a file's name
         assert isinstance(t["session_starts_per_s"], (int, float))
 
 

@@ -526,7 +526,9 @@ def test_a_stalled_gap_is_the_one_its_packs_execution_made_longer():
 
 
 def test_small_programs_are_counted_in_the_tick_they_start_in_one_ahead():
-    from benchmark.readers import executions_per_span
+    """What ``tools/describe_idle.py`` prints of a capture dispatched one ahead
+    (the metric that read the same count was retired in PR 52: 0 on every line)."""
+    from benchmark.tools.describe_idle import ENGINE, started_inside
 
     s = scenes.Scene()
     step = [("decode_tick", scenes.STEP, True)]
@@ -536,10 +538,8 @@ def test_small_programs_are_counted_in_the_tick_they_start_in_one_ahead():
             s.aux("jit__threefry_split")
         s.call(step)
     progs, spans = s.programs()
-    obs = {"trace": object(), "_xprograms": progs, "spans": spans}
-    params = dict(span="sched.tick", dispatch="decode_tick", module="^jit_decode_impl$",
-                  excluding="^jit_(packed|packed_ctx|decode|decode_burst|spec|cow)_impl$")
-    assert executions_per_span.read(obs, q=50, **params) == 0
-    assert executions_per_span.read(obs, q=100, **params) == 1
-    # with the record the parent's pairing saw (no collect known): nothing to read
-    assert executions_per_span.read(dict(obs, spans=[]), q=50, **params) is None
+    iv = xprograms.skew(progs, "decode_tick", "^jit_decode_impl$", spans=spans)
+    per_tick = [len(r) for r in started_inside(progs, "sched.tick", ENGINE, xprograms.tight_edge(iv))]
+    assert sorted(per_tick)[len(per_tick) // 2] == 0 and max(per_tick) == 1 and sum(per_tick) == 3
+    # with the record the parent's pairing saw (no collect known): no shift to cut at
+    assert xprograms.skew(progs, "decode_tick", "^jit_decode_impl$", spans=[]) is None
