@@ -175,17 +175,25 @@ class InferenceEngineV2:
             # them) are shared as a dense model's K / V pages are: a pack's
             # chunk that starts at a position > 0 reads the pages under it
             pages_alone = not (cfg.latent.ringed or cfg.latent.stateful or cfg.latent.indexed)
+            # pages that are given back while the sequence lives (EVA attention)
+            compacted = cfg.latent.eva is not None
             for option, on, why in (
                 ("grid (a tensor-parallel / replica / seq-shard serve mesh)",
                  grid is not None or int(serve_replicas) > 1 or int(seq_shards) > 1,
                  "its weights and caches have no sharding rules yet"),
                 ("enable_speculation", enable_speculation,
+                 "a rejected draft's share cannot be rolled back out of a chunk's summary, "
+                 "nor a window that closed under it be opened again" if compacted else
                  "a rejected draft's state-space state cannot be rolled back" if states
                  else "a rejected draft's rows cannot be rolled back out of a ring"),
                 ("quantize_weights (and int8 / fp8 KV)", quantize_weights is not None,
                  "its projections, states and pages have no quantized form yet" if states
                  else "its projections and latent rows have no quantized form yet"),
                 ("enable_prefix_caching", enable_prefix_caching and not pages_alone,
+                 "a block's key says which positions it holds, and a compacted table's "
+                 "page holds a closed window's summaries or an open window's rows by "
+                 "where its sequence stands: summary pages could be shared a window at "
+                 "a time, exact pages not" if compacted else
                  "a cached prefix would have to bring a state snapshot with its pages"
                  if states else
                  "a cached prefix would have to bring a window's ring with its pages"),
@@ -391,6 +399,8 @@ class InferenceEngineV2:
                                 enable_prefix_caching=enable_prefix_caching,
                                 replicas=dp, seq_shards=sq)
         self.mgr.faults = faults
+        # a runner whose tables give pages back (``ragged.WindowCompaction``)
+        self.mgr.compaction = getattr(self.runner, "compaction", None)
         # per-replica speculation totals [drafted, accepted] — the
         # spec-accept half of the serve/replicaN/* gauge group (drafts and
         # their accept-rate EMAs live on per-replica slots already; this
@@ -1003,10 +1013,18 @@ class InferenceEngineV2:
         bs = self.block_size
         dp = self.serve_replicas
         per_budget = self.mgr.per_replica_token_budget(self.prefill_budget)
+        # (a compacted table's window: a pack's page attends ONE window)
+        edge = self.mgr.compaction.window if self.mgr.compaction is not None else 0
         for seq, start, _end in entries:
             if start % bs:
                 raise ValueError(
                     f"prefill start {start} not page-aligned (bs {bs})"
+                )
+            if edge and _end > start and start // edge != (_end - 1) // edge:
+                raise ValueError(
+                    f"prefill chunk [{start}, {_end}) crosses a window's edge "
+                    f"(every {edge} positions): a pack's page attends ONE window; "
+                    f"the scheduler cuts chunks there"
                 )
         pending: List = list(entries)
         while pending:
@@ -1063,6 +1081,7 @@ class InferenceEngineV2:
         with tel.span("engine.pack_build", track=ns) as bsp:
             bs = self.block_size
             dp = self.serve_replicas
+            compaction = self.mgr.compaction
             groups: List[List] = [[] for _ in range(dp)]
             for e in entries:
                 groups[self.mgr.replica_of(e[0]) if dp > 1 else 0].append(e)
@@ -1093,6 +1112,8 @@ class InferenceEngineV2:
                     pos[cur : cur + n] = np.arange(start, end)
                     n_pages = -(-n // bs)
                     first_page = start // bs
+                    if compaction is not None:  # the column of the chunk's first page
+                        first_page = compaction.column(start, bs)
                     pack_pages[cur // bs : cur // bs + n_pages] = np.asarray(
                         s.blocks[first_page : first_page + n_pages]
                     )
@@ -1129,6 +1150,8 @@ class InferenceEngineV2:
             self._c["prefill_dispatches"].inc()
             self._c["dispatched_ahead"].inc(int(ahead))
             self._account_comm(t_pad, sample_rows=n_slots, ring=use_ctx)
+            if compaction is not None:
+                self._close_windows((s, end) for s, _, end in entries)
             done = Enqueued("prefill_pack", list(entries), finishing, split)
             if finishing and not split:
                 # host-complete: this fetch syncs the pack
@@ -1217,6 +1240,19 @@ class InferenceEngineV2:
                     self._set_block_table(s)
                     out[s.uid] = tok
                 self.mgr.update_hashes(s)
+
+    def _close_windows(self, written) -> None:
+        """``written``: (sequence, positions written so far) of the execution
+        just ENQUEUED.  A sequence whose window that execution filled has the
+        window's exact pages taken out of its table and given back to the pool
+        now: the execution is the last to read them, and whatever is handed
+        them next is enqueued after it."""
+        window = self.mgr.compaction.window
+        for s, n in written:
+            if n and n % window == 0:
+                self._c["eva_pages_returned"].inc(self.mgr.close_window(s, n))
+                self._c["eva_windows_closed"].inc()
+                self._set_block_table(s)
 
     def refresh_routing_stats(self) -> None:
         """Fetch the runner's device-side counts (a ``cfg.latent`` model's
@@ -1708,6 +1744,9 @@ class InferenceEngineV2:
                 done.sampled = sampled
             else:
                 done.tokens = np.asarray(sampled)  # the tick's host sync
+        if self.mgr.compaction is not None:
+            # (``cur_len - 1`` is the position this tick wrote)
+            self._close_windows((s, s.cur_len) for s in active_seqs)
         for s in active_seqs:
             s.pending += 1  # a token on the way: lengths count it from here
         return done
@@ -1817,6 +1856,12 @@ class InferenceEngineV2:
         keys are retracted.  A chaos-injected ``nan_logits`` poison applies
         at burst granularity: nothing commits, run = [-1].  Rows given no
         emission headroom return an empty run untouched."""
+        if self.mgr.compaction is not None:
+            from ..models.latent import refuse
+
+            refuse("serve.decode_megastep > 1 (a decode burst)", "a table that gives "
+                   "pages back is compacted by the host between two ticks, and a burst's "
+                   "ticks share one table")
         tel, ns = self.telemetry, self._ns
         with tel.span("engine.decode_build", track=ns) as bsp:
             B = self.mgr.max_seqs

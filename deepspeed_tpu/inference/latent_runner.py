@@ -58,6 +58,30 @@ beside the pages:
   is allocated or freed, a slot's next owner and a resume overwrite from 0, and
   the host mirror that ``close()`` audits is the ``sliding`` rings' own.
 
+A model of two-norm blocks whose mixer is EVA attention (``eva``,
+``ops/eva.py``) keeps K / V pages alone, and is the one kind whose TABLE SHRINKS
+while the sequence lives (``ragged.WindowCompaction`` has the layout: a page a
+closed window, the open window's summary page, the open window's exact pages):
+
+- a position's exact row goes to its page as a K / V row does; every chunk of
+  ``chunk`` positions that a pack or a tick COMPLETES is summarised there and
+  then (``eva_summarise``) into the open window's summary page, one row a chunk,
+  in a key's and a value's format.  When the window's last position is written
+  the host drops its exact pages from the table and gives them back to the pool
+  (``StateManager.close_window``: 16 of its 17 pages at 2048 / 16 / 128); nothing
+  moves on the device;
+- rotary positions follow the POSITION, cache addresses the ROW: position ``p``
+  is row ``p // window * (window / chunk) + p % window`` of what its sequence
+  attends, and both follow from ``p`` inside the program;
+- attention (``eva_attend``) is the paged kernels' own, over the table with the
+  open window's summary page taken out (a window's chunks are never summaries to
+  its own queries): a pack through the packed-ctx body with the ROWS before it as
+  its context, a tick through the paged decode body with its row count as its
+  length.  The heads are laid out ``eva_heads_a_pool`` a pool, as many as the
+  packed-ctx kernel's gate takes at the pack's size (32 K / V heads in one pool
+  are past its VMEM estimate), each pool attended by a call of its own;
+- a preemption drops the table and the resume recomputes from the tokens.
+
 One layer body (``_layer``) serves the pack and the tick; the kind chooses how
 the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
@@ -160,6 +184,18 @@ STATE_COUNTERS = (
 )
 
 
+# ... and of one whose layers are EVA attention (pages that are given back): all
+# counted on the host, ONE layer's count (every layer is of the kind and reads alike)
+EVA_COUNTERS = (
+    "eva_windows_closed",      # windows whose last position was written (the engine's count)
+    "eva_pages_returned",      # pages those closes gave back to the pool, their sequences living on
+    "eva_summary_rows_read",   # rows of closed windows' summaries the decode ticks' queries read
+    "eva_exact_rows_read",     # ... and exact rows of their own windows
+    "eva_rows_live",           # gauge: rows the live sequences attend next
+    "eva_context_tokens_live",  # gauge: positions those sequences have written
+)
+
+
 def _lanes(width: int) -> int:
     """A latent row as the pages keep it: padded with zeros to whole 128-lane
     rows, which a row gather moves at twice the speed (576 -> 640)."""
@@ -214,6 +250,8 @@ def _init_state_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
     from .paged import init_paged_cache
 
     s = cfg.latent
+    if s.eva is not None:
+        return _init_eva_cache(cfg, num_blocks, block_size, pack_tokens, dtype)
     (rec, mb), (att, g) = s.recurrence, s.attention
     k, v = init_paged_cache(s.count(att), num_blocks, block_size, g.num_kv_heads,
                             g.head_dim, dtype=dtype)
@@ -236,6 +274,43 @@ def _init_state_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
         # row, pairs on held experts), running sums
         "touched": jnp.zeros((n_moe, 2, 2), jnp.int32),
     }
+
+
+def eva_heads_a_pool(ev, block_size: int, pack_tokens: int, dtype) -> int:
+    """K / V heads a pool of an EVA layer's pages: all of them where the
+    packed-ctx kernel's gate takes a pack of ``pack_tokens`` queries at that many
+    heads with one query head a K / V head, else the largest halving it takes
+    (its VMEM estimate: 32 heads x 512 queries read 64 MB against a budget of 32,
+    16 heads 34.6, 8 heads 19.9).  From the shapes alone, so the chip and the CPU
+    lay the pools out alike."""
+    from ..ops.pallas import ctx_attention as ck
+
+    heads, isz = ev.num_heads, jnp.dtype(dtype).itemsize
+    pages = ev.window // block_size + 1  # a table wider than the kernel's key tile
+    while heads % 2 == 0 and not ck.fits_vmem(
+            pack_tokens, heads, heads, ev.head_dim, block_size, pages, isz):
+        heads //= 2
+    return heads
+
+
+def _init_eva_cache(cfg, num_blocks: int, block_size: int, pack_tokens: int, dtype) -> Cache:
+    """The cache of a model of EVA attention: K / V pages, ``eva_heads_a_pool``
+    heads a pool (layer ``i``'s pools are ``[i n, (i + 1) n)`` of the tuples)."""
+    from .paged import init_paged_cache
+
+    ev = cfg.latent.eva
+    if ev.window % block_size or ev.rows_a_closed_window != block_size:
+        raise ValueError(
+            f"a closed window of {ev.window} positions keeps {ev.rows_a_closed_window} "
+            f"summary rows: they are ONE page only at a block size of that many rows "
+            f"(got {block_size})")
+    if cfg.latent.count("eva") != cfg.num_layers:
+        raise ValueError("EVA attention beside another mixer has no cache layout yet")
+    hp = eva_heads_a_pool(ev, block_size, pack_tokens, dtype)
+    k, v = init_paged_cache(cfg.num_layers * (ev.num_heads // hp), num_blocks, block_size,
+                            hp, ev.head_dim, dtype=dtype)
+    return {"ssm": (), "conv": (), "k": k, "v": v,
+            "stats": jnp.zeros((0, len(ROUTING_STATS)), jnp.int32)}
 
 
 def _tally(picks, new):
@@ -557,6 +632,19 @@ def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_group
     h = lm.norm(x, n1, cfg)
     if kind == "gdn":
         y, cache = _recurrence(kind, i, mw, h, cache, write)
+    elif kind == "eva":
+        q, k, v = lm.eva_inputs(mw, h, pos, s.eva)
+        n = len(cache["k"]) // s.count("eva")  # pools a layer, some of the heads each
+        mine = slice(i * n, (i + 1) * n)
+        pools = write(kind, (cache["k"][mine], cache["v"][mine]), (k, v, mw["phi"], mw["mu"]))
+        cache = {**cache, "k": cache["k"][:mine.start] + pools[0] + cache["k"][mine.stop:],
+                 "v": cache["v"][:mine.start] + pools[1] + cache["v"][mine.stop:]}
+        with jax.named_scope("eva_attend"):
+            o = read(kind, pools, (q, k, v))
+        if probe is not None and i == 0:
+            # the first layer's rows as attention took them and what it made of them
+            probe.append({"eva_q": q, "eva_k": k, "eva_v": v, "eva_o": o})
+        y = o.reshape(x.shape[0], -1).astype(x.dtype) @ mw["wo"]
     else:
         q, k, v, gate = lm.gattn_inputs(mw, h, pos, s.mixer(kind), eps, s.unit_offset)
         # pages for the kind over every key, a ring a slot for the kind over a window
@@ -595,7 +683,7 @@ def _put(items: tuple, i: int, value) -> tuple:
 
 def _logits(params, cfg, x):
     x = lm.norm(x, params["final_norm"]["scale"], cfg)
-    return (x @ params["lm_head"]["kernel"]).astype(jnp.float32)
+    return lm.head_logits(x, params["lm_head"]["kernel"], cfg).astype(jnp.float32)
 
 
 def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_idx,
@@ -604,13 +692,14 @@ def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_i
     less ``ctx_lens``: a token's position says where its context ends).
     ``tables`` [N, P] are the block tables by slot, this pack's pages included.
     ``probe`` (a list) collects, layer by layer, what the indexers and the
-    routers picked, what a state-space block's recurrence consumed and what a
-    window's mask let each query see.
+    routers picked, what a state-space block's recurrence consumed, what a
+    window's mask let each query see, and the first EVA layer's rows and output.
     Returns (logits [N, vocab], cache)."""
     t = tokens.shape[0]
     valid = segment_ids > 0
     picked: list = []
-    seam = _state_pack_seam if cfg.latent.stateful else _latent_pack_seam
+    seam = _eva_pack_seam if cfg.latent.eva is not None else \
+        _state_pack_seam if cfg.latent.stateful else _latent_pack_seam
     write, read = seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache,
                        picked, probe)
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
@@ -753,6 +842,137 @@ def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cac
     return write, read
 
 
+def _eva_tables(ev, tables, w, bs: int):
+    """The tables EVA attention walks: each slot's with column ``w`` [N] (its
+    OPEN window's summary page) taken out, so that the closed windows' pages and
+    the open window's exact pages follow one another; cut to the columns a
+    sequence of the tables' longest can hold."""
+    p = tables.shape[1]
+    cols = jnp.arange(min(p, p * bs // ev.window + ev.window // bs))
+    src = jnp.where(cols[None, :] < w[:, None], cols[None, :],
+                    jnp.minimum(cols[None, :] + 1, p - 1))
+    return jnp.take_along_axis(tables, src, axis=1)
+
+
+def _head_pools(a, n: int):
+    """a [T, H, hd] as ``n`` pools' heads, in order."""
+    hp = a.shape[1] // n
+    return [a[:, j * hp:(j + 1) * hp] for j in range(n)]
+
+
+def _eva_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache, picked,
+                   probe):
+    """A pack's (write, read) for EVA attention (module docstring).  A page of the
+    pack is one sequence's and lies inside ONE window (the scheduler cuts a
+    prompt's chunks at a window's edge: ``ServeScheduler._plan_prefill``), so its
+    queries' context is every row the table holds before the sequence's first
+    page here, and the pack's own rows, causal."""
+    from ..ops import eva
+    from .paged import paged_attention_packed_ctx
+    from .ragged import WindowCompaction
+
+    ev, t = cfg.latent.eva, segment_ids.shape[0]
+    g = pack_pages.shape[0]
+    bs, n_slots = t // g, tables.shape[0]
+    per_page = bs // ev.chunk                     # chunks a page of the pack
+    slot = jnp.maximum(segment_ids[::bs] - 1, 0)  # a page of the pack is one sequence's
+    live = segment_ids[::bs] > 0
+    start = positions[::bs]
+    same = jnp.concatenate([jnp.zeros((1,), bool), (slot[1:] == slot[:-1]) & live[:-1]])
+    first = live & ~same                          # a sequence's first page in this pack
+    at = jnp.where(first, slot, n_slots)
+    began = jnp.zeros((n_slots,), jnp.int32).at[at].set(start, mode="drop")
+    ctx_rows = WindowCompaction(ev.window, ev.chunk).rows_live(began)
+    walked = _eva_tables(ev, tables, began // ev.window, bs)
+    grouped = lambda a: a.reshape(g, bs, *a.shape[1:])
+    # where a page's chunks' summaries go: the open window's summary page, from row
+    summary_page = jnp.where(live, tables[slot, start // ev.window], -1)
+    row0 = start % ev.window // ev.chunk
+    whole = valid.reshape(g, per_page, ev.chunk)[..., -1]  # the chunk's last row is here
+
+    def write(kind, pools, new):
+        (kp, vp), (k, v, phi, mu) = pools, new
+        n = len(kp)
+        split = lambda a: _head_pools(a, n)
+        kp, vp = (tuple(_write_pages(a, grouped(r), pack_pages) for a, r in zip(pool, split(x)))
+                  for pool, x in ((kp, k), (vp, v)))
+        with jax.named_scope("eva_summarise"):
+            ks, vs = eva.summarise(*(a.reshape(g * per_page, ev.chunk, *a.shape[1:])
+                                     for a in (k, v)), phi, mu)
+            return tuple(
+                tuple(_merge_rows(a, u.reshape(g, per_page, *u.shape[1:]), summary_page, row0,
+                                  whole) for a, u in zip(pool, split(x)))
+                for pool, x in ((kp, ks), (vp, vs)))
+
+    def read(kind, pools, qkv):
+        n = len(pools[0])
+        outs = [paged_attention_packed_ctx(q, k, v, segment_ids, kp, vp, walked, ctx_rows)
+                for q, k, v, kp, vp in zip(*(_head_pools(a, n) for a in qkv), *pools)]
+        return jnp.concatenate(outs, axis=1)
+
+    return write, read
+
+
+def _merge_rows(pool, rows, pages, row0, ok):
+    """rows [G, R, hkv, hd] into rows ``row0`` [G] .. + R of pages ``pages`` [G]
+    (-1: none) of ``pool``, those of ``ok`` [G, R] alone, IN PLACE a group at a
+    time (``_write_pages``' dynamic-update-slice: the pool keeps its layout)."""
+    for g in range(rows.shape[0]):
+        at = (jnp.maximum(pages[g], 0), row0[g], 0, 0)
+        old = jax.lax.dynamic_slice(pool, at, (1, *rows.shape[1:]))
+        take = (ok[g] & (pages[g] >= 0))[None, :, None, None]
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(take, rows[g][None].astype(pool.dtype), old), at)
+    return pool
+
+
+def _eva_tick_seam(cfg, pos, block_tables, active, picked, probe):
+    """A decode tick's (write, read) for EVA attention: one new exact row a live
+    slot; where it completes a chunk, the chunk's rows are read back out of their
+    page, summarised and put into the open window's summary page; then every
+    live row of the table (the open window's summary page apart) is attended."""
+    from ..ops import eva
+    from .paged import paged_attention_decode, write_decode_kv
+    from .ragged import WindowCompaction
+
+    ev, b = cfg.latent.eva, pos.shape[0]
+    w, inside = pos // ev.window, pos % ev.window
+    lens = jnp.where(active, WindowCompaction(ev.window, ev.chunk).rows_live(pos) + 1, 0)
+    rows = jnp.arange(b)
+
+    def write(kind, pools, new):
+        (kp, vp), (k, v, phi, mu) = pools, new
+        n, (nb, bs) = len(kp), kp[0].shape[:2]
+        # the open window's exact pages start one column past its summary page
+        at = (w + 1) * bs + inside
+        page = block_tables[rows, at // bs]
+        r0 = inside % bs // ev.chunk * ev.chunk
+        done = active & (inside % ev.chunk == ev.chunk - 1)
+        to = jnp.where(done & (block_tables[rows, w] >= 0), block_tables[rows, w], nb)
+        # a chunk's rows out of their page, by row of the pool's (page, row) rows
+        at_rows = (jnp.maximum(page, 0) * bs + r0)[:, None] + jnp.arange(ev.chunk)[None, :]
+        chunk_of = lambda a: a.reshape(nb * bs, *a.shape[2:])[at_rows]
+        kp, vp = (tuple(write_decode_kv(a, r, block_tables, at, active)
+                        for a, r in zip(pool, _head_pools(x, n))) for pool, x in ((kp, k), (vp, v)))
+        hp = k.shape[1] // n
+        with jax.named_scope("eva_summarise"):
+            ks, vs = zip(*(eva.summarise(chunk_of(a), chunk_of(c), phi[j * hp:(j + 1) * hp],
+                                         mu[j * hp:(j + 1) * hp])
+                           for j, (a, c) in enumerate(zip(kp, vp))))
+            put = lambda a, u: a.at[to, inside // ev.chunk].set(u.astype(a.dtype), mode="drop")
+            return (tuple(put(a, u) for a, u in zip(kp, ks)),
+                    tuple(put(a, u) for a, u in zip(vp, vs)))
+
+    def read(kind, pools, qkv):
+        n, bs = len(pools[0]), pools[0][0].shape[1]
+        walked = _eva_tables(ev, block_tables, w, bs)
+        outs = [paged_attention_decode(q, kp, vp, walked, lens)
+                for q, kp, vp in zip(_head_pools(qkv[0], n), *pools)]
+        return jnp.concatenate(outs, axis=1)
+
+    return write, read
+
+
 def _by_whole_groups(attend, q, hkv: int):
     """``attend(q)`` for q [T, Hq, hd] whose ``Hq / hkv`` query heads a K / V head
     are no power of two (6 = 4 + 2), as one call a power of two: the packed-ctx
@@ -777,7 +997,8 @@ def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cach
     """One batched decode tick (``model_runner.decode_step``'s arguments).
     Returns (logits [B, vocab], cache)."""
     picked: list = []
-    seam = _state_tick_seam if cfg.latent.stateful else _latent_tick_seam
+    seam = _eva_tick_seam if cfg.latent.eva is not None else \
+        _state_tick_seam if cfg.latent.stateful else _latent_tick_seam
     write, read = seam(cfg, seq_lens, block_tables, active, picked, probe)
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
     for l in range(cfg.num_layers):
@@ -904,7 +1125,16 @@ class LatentRunner:
         self._ring_rows = np.zeros(0, np.int64)
         self._block = 1
         self._expert_layers = 0
-        if cfg.latent.stateful:
+        # a table that gives pages back while its sequence lives (``ragged.
+        # WindowCompaction``), which the engine hands its block manager; None: pages only grow
+        self.compaction = None
+        ev = getattr(cfg.latent, "eva", None)
+        if ev is not None:
+            from .ragged import WindowCompaction
+
+            self.counters = EVA_COUNTERS
+            self.compaction = WindowCompaction(ev.window, ev.chunk)
+        elif cfg.latent.stateful:
             self.counters = WINDOW_COUNTERS if cfg.latent.ringed else STATE_COUNTERS
             self._discarded = 0  # states a preemption left behind since the last dispatch
         elif cfg.latent.every is not None:
@@ -934,7 +1164,7 @@ class LatentRunner:
     def verify_packed_ctx(self, *args, **kw):
         lm.refuse("enable_speculation (verify_packed_ctx)", "a rejected draft's rows "
                   "cannot be rolled back out of a sliding layer's ring, nor its tokens "
-                  "out of a recurrence's state")
+                  "out of a recurrence's state, nor its share out of a chunk's summary")
 
     def decode_step(self, params, cfg, tokens, seq_lens, block_tables, active, kv_cache,
                     ctx=None, mesh=None, dp: int = 1, seq_shards: int = 1):
@@ -961,6 +1191,8 @@ class LatentRunner:
         s = self.cfg.latent
         if tokens and self._expert_layers:
             counters["expert_rows_laid_out"].inc(self._expert_layers * held_rows_a_pass(tokens, s))
+        if self.compaction is not None:
+            return self._eva_dispatched(counters, work, pack)
         if s.count("wattn"):
             return self._windows_dispatched(counters, work)
         if s.stateful:
@@ -1012,6 +1244,28 @@ class LatentRunner:
         counters["mla_keys_decompressed"].inc(long * n)
         return {"mla_keys": keys * n, "mla_keys_decompressed_pct": 100.0 * long / max(keys, 1)}
 
+    def _eva_dispatched(self, counters, work, pack: bool) -> Dict[str, int]:
+        """EVA attention, ONE layer's count: a query at position ``p`` reads a
+        summary row per chunk of the ``p // window`` windows before its own and
+        the ``p % window + 1`` exact rows of its own up to itself.  The span's
+        ``rows_total`` is the rows the dispatch's sequences hold (read ONCE by
+        whatever implements it), ``eva_pairs`` the (query, row) pairs.  The
+        decode ticks' rows are counted into the counters by kind."""
+        c = self.compaction
+        rows = pairs = summary = exact = 0
+        for slot, a, b in work:
+            under, n = c.rows_live(a), b - a  # rows before the first query; queries
+            rows += under + n
+            pairs += n * under + n * (n + 1) // 2
+            if not pack:
+                summary += under - a % c.window
+                exact += a % c.window + 1
+            self._ring_rows[slot] = b
+        if not pack:
+            counters["eva_summary_rows_read"].inc(summary)
+            counters["eva_exact_rows_read"].inc(exact)
+        return {"rows_total": rows, "eva_pairs": pairs}
+
     def _windows_dispatched(self, counters, work) -> Dict[str, int]:
         """Gated GQA of two kinds: a query at position ``p`` attends ``p + 1``
         keys in each layer over every key and ``min(p + 1, window)`` in each
@@ -1059,7 +1313,8 @@ class LatentRunner:
 
     def released(self, seq) -> None:
         s = self.cfg.latent
-        if s.stateful and not s.ringed and seq.preempted and self._ring_rows[seq.slot]:
+        if s.stateful and not s.ringed and self.compaction is None and seq.preempted \
+                and self._ring_rows[seq.slot]:
             self._discarded += 1
         self._ring_rows[seq.slot] = 0
 
@@ -1068,6 +1323,8 @@ class LatentRunner:
         nobody's once its slot is released), or slots whose recurrence's state
         is a live sequence's."""
         s = self.cfg.latent
+        if self.compaction is not None:  # pages alone: the allocator's audit is all of it
+            return {}
         if s.stateful and not s.ringed:
             return {"ssm_states": int(np.count_nonzero(self._ring_rows))}
         return {"window_rows": int(self._ring_rows.sum())}
@@ -1075,6 +1332,11 @@ class LatentRunner:
     def refresh_stats(self, counters, kv: Cache) -> None:
         """The selectors' and routers' device-side counts into ``counters``
         (two small device->host copies)."""
+        if self.compaction is not None:
+            # gauges, from the host mirror of what each slot has written
+            n = self._ring_rows
+            counters["eva_context_tokens_live"].set(int(n.sum()))
+            counters["eva_rows_live"].set(int(self.compaction.rows_live(n).sum()))
         if self.cfg.latent.indexed:
             counters["index_keys_selected"].set(picks_total(kv["picks"]))
         if "touched" in kv:

@@ -340,6 +340,52 @@ class SequenceDescriptor:
         return len(self.tokens) + self.pending
 
 
+@dataclass(frozen=True)
+class WindowCompaction:
+    """A block table that SHRINKS while its sequence lives: the positions of an
+    aligned window of ``window`` keep one exact row each while the window is
+    open and one summary row per ``chunk`` positions once it has closed.  With
+    ``per`` = ``window / chunk`` rows a closed window (one page of them: the
+    manager checks), a sequence whose newest written position is ``n - 1`` holds,
+    in table order,
+
+    - one page per closed window (``w = (n - 1) // window`` of them),
+    - the OPEN window's summary page, filled chunk by chunk (column ``w``),
+    - the open window's exact pages (from column ``w + 1`` on),
+
+    and when position ``window (w + 1) - 1`` has been written the window closes:
+    its summary page stays where it is and every exact page goes back to the
+    pool (``StateManager.close_window``)."""
+
+    window: int
+    chunk: int
+
+    def column(self, pos: int, block_size: int) -> int:
+        """The table column of the exact page that holds position ``pos``."""
+        return pos // self.window + 1 + pos % self.window // block_size
+
+    def pages_for(self, n: int, block_size: int) -> int:
+        """Pages the table holds while position ``n - 1`` is the newest written
+        (its window still open)."""
+        if n <= 0:
+            return 0
+        last = n - 1
+        return self.column(last, block_size) + 1
+
+    def peak_pages(self, n: int, block_size: int) -> int:
+        """The most pages the table holds on the way to ``n`` written positions:
+        at ``n``, or while the last window that filled was filling."""
+        return max(self.pages_for(n, block_size),
+                   self.pages_for(n // self.window * self.window, block_size))
+
+    def rows_live(self, n):
+        """Rows a sequence of ``n`` written positions attends next (a window
+        that has just filled counts as closed): position ``p``'s exact row is
+        row ``rows_live(p)`` of what its sequence attends while its window is
+        open.  Plain arithmetic: ``n`` may be an array of positions."""
+        return n // self.window * (self.window // self.chunk) + n % self.window
+
+
 class _AllocatorGroupView:
     """Aggregate read view over the per-replica allocators of a partitioned
     pool (``StateManager(replicas > 1)``) — keeps every pre-existing
@@ -462,6 +508,10 @@ class StateManager:
         # ``release_hook(seq)``: the engine's per-SLOT state that is not blocks
         # (a sliding layer's ring) is let go with the sequence
         self.release_hook: Optional[Callable[[SequenceDescriptor], None]] = None
+        # ``compaction`` (the engine's, from its runner): the tables of this
+        # model give pages back while their sequences live, so a sequence of n
+        # tokens holds ``compaction.pages_for(n)`` pages, not ``ceil(n / block)``
+        self.compaction: Optional[WindowCompaction] = None
         # chaos-harness hook (inference/faults.py FaultInjector): when set,
         # ``ensure_capacity`` consults the ``alloc_exhaustion`` injection
         # point before touching the real pool — the scheduler's retry /
@@ -537,6 +587,12 @@ class StateManager:
                 lru.append(b)
         return matched, lru
 
+    def pages_for(self, n_tokens: int) -> int:
+        """Pages a sequence of ``n_tokens`` holds in its table."""
+        if self.compaction is not None:
+            return self.compaction.pages_for(n_tokens, self.block_size)
+        return -(-n_tokens // self.block_size)
+
     def _pick_replica(self, prompt_len: int,
                       tokens=None) -> Optional[int]:
         """Admission placement, replica-AFFINE for content: among replica
@@ -548,7 +604,7 @@ class StateManager:
         LRU blocks a revival pulls out of the available pool.  None when
         nobody fits — the scheduler's per-replica batch balancing and the
         prefix-affinity routing both ride on this single decision point."""
-        blocks = -(-prompt_len // self.block_size)
+        blocks = self.pages_for(prompt_len)
         probe = self.enable_prefix_caching and tokens is not None
         best, best_key = None, None
         for r in range(self.replicas):
@@ -600,7 +656,7 @@ class StateManager:
         probe = self.enable_prefix_caching and token_lists is not None
         revived: set = set()  # LRU blocks already charged this simulation
         for i, n in enumerate(prompt_lens):
-            blocks = -(-int(n) // self.block_size)
+            blocks = self.pages_for(int(n))
             toks = token_lists[i] if probe else None
             best, best_key, best_need, best_lru = -1, None, 0, ()
             for r in range(self.replicas):
@@ -630,6 +686,8 @@ class StateManager:
         return True
 
     def blocks_needed(self, seq: SequenceDescriptor, new_tokens: int) -> int:
+        if self.compaction is not None:
+            return max(0, self.pages_for(seq.cur_len + new_tokens) - len(seq.blocks))
         have = len(seq.blocks) * self.block_size
         need = seq.cur_len + new_tokens
         return max(0, -(-(need - have) // self.block_size))
@@ -700,6 +758,36 @@ class StateManager:
                 self.faults.maybe_raise("alloc_exhaustion", uids=(seq.uid,))
             seq.blocks.extend(self._alloc_of(seq).allocate(
                 n, first_pos=len(seq.blocks)))
+
+    def ensure_pages(self, seq: SequenceDescriptor, n_tokens: int) -> None:
+        """Grow ``seq``'s table to what it holds once ``n_tokens`` positions are
+        written (a prompt's chunk that ends there): under a ``compaction`` the
+        prompt's END holds fewer pages than a window in the filling, so
+        admission's reserve is not the chunks'."""
+        n = self.pages_for(n_tokens) - len(seq.blocks)
+        if n > 0:
+            if self.faults is not None:
+                self.faults.maybe_raise("alloc_exhaustion", uids=(seq.uid,))
+            seq.blocks.extend(self._alloc_of(seq).allocate(n, first_pos=len(seq.blocks)))
+
+    def close_window(self, seq: SequenceDescriptor, n_written: int) -> int:
+        """``seq`` has just had position ``n_written - 1`` written, the last of
+        its window: the window's exact pages leave the table and go BACK to the
+        pool while the sequence lives on (its summary page stays, now a closed
+        window's).  Call it once the execution that wrote the position is
+        ENQUEUED: that execution is the last to read the pages, and whatever is
+        handed them next is enqueued after it.  Returns the pages given back."""
+        c = self.compaction
+        if c is None or n_written <= 0 or n_written % c.window:
+            raise ValueError(f"no window closes at {n_written} written positions")
+        # the columns after the closed windows' pages (this window's summary page
+        # the last of them); pages reserved past the window's own move down
+        first, own = n_written // c.window, c.window // self.block_size
+        gone = seq.blocks[first:first + own]
+        del seq.blocks[first:first + own]
+        if gone:
+            self._alloc_of(seq).free(gone)
+        return len(gone)
 
     def ensure_writable(self, seq: SequenceDescriptor, pos: int) -> None:
         """Copy-on-write guard: the page holding token position ``pos`` must

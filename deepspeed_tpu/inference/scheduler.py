@@ -235,6 +235,10 @@ class ServeScheduler:
         bs = engine.block_size
         chunk = min(prefill_chunk or engine.prefill_budget, engine.prefill_budget)
         self.prefill_chunk = max(bs, (chunk // bs) * bs)
+        # a table that gives pages back while its sequence lives (``ragged.
+        # WindowCompaction``, the engine's runner's): its chunks end at a window's
+        # edge and reserve their pages when they are planned; None for every other
+        self._compaction = getattr(engine.mgr, "compaction", None)
         # watermark headroom is per REPLICA group: on a 2-D batch x model
         # serve mesh each replica grows its own decode batch against its own
         # block range, so aggregate headroom in another replica's pool is
@@ -390,7 +394,8 @@ class ServeScheduler:
         # now: continuation prefill packs are replica-local — the PR 12
         # REJECT_PROMPT_OVER_BUDGET gate is retired.)
         max_len = min(len(tokens) + sampling.max_new_tokens, eng.max_seq_len)
-        blocks = -(-max_len // eng.block_size)
+        blocks = -(-max_len // eng.block_size) if self._compaction is None \
+            else self._compaction.peak_pages(max_len, eng.block_size)
         # a sequence lives entirely inside ONE replica's block range, so the
         # feasibility bound is the per-replica pool, not the cross-replica
         # aggregate.  A replica's pool DOES aggregate its seq shards (the
@@ -824,7 +829,8 @@ class ServeScheduler:
         mgr = self.engine.mgr
         if not mgr.free_slots:
             return False
-        total_blocks = -(-len(req.tokens) // mgr.block_size)
+        total_blocks = -(-len(req.tokens) // mgr.block_size) if self._compaction is None \
+            else mgr.pages_for(len(req.tokens))
         # tentative admit performs the replica-affine placement AND the
         # prefix match (refs cached blocks); roll it — and its hit-rate
         # counters — back if the fresh remainder does not fit under the
@@ -985,7 +991,7 @@ class ServeScheduler:
         return out
 
     def _prefill_phase(self) -> Dict[int, int]:
-        entries = self._plan_prefill()
+        entries = self._plan_prefill(preempting=True)
         if not entries:
             return {}
         clock = self.telemetry.clock
@@ -994,10 +1000,12 @@ class ServeScheduler:
         self._note_chunks(entries, t0, clock(), self.tick_no)
         return self._book_first(first)
 
-    def _plan_prefill(self) -> List[tuple]:
+    def _plan_prefill(self, preempting: bool = False) -> List[tuple]:
         """This tick's prompt chunks, [(seq, start, end)], under the chunk
         budget.  A prompt whose last chunk is enqueued already (one ahead:
-        its first token is on the way) has nothing left to plan."""
+        its first token is on the way) has nothing left to plan.
+        ``preempting``: the back-to-back order, where a chunk whose pages the
+        pool cannot give (``_reserved``) may take them from a victim."""
         bs = self.engine.block_size
         mgr = self.engine.mgr
         R = mgr.replicas
@@ -1031,10 +1039,16 @@ class ServeScheduler:
                            nan=seq.error is not None)
                 continue
             take = min(remaining, budgets[r])
+            if self._compaction is not None:  # a chunk ends at its window's edge at the latest
+                edge = self._compaction.window
+                take = min(take, edge - start % edge)
             if take < remaining:
                 take -= take % bs  # chunk boundaries stay page-aligned
                 if take == 0:
                     continue
+            if self._compaction is not None \
+                    and not self._reserved(req, seq, start + take, preempting):
+                continue
             entries.append((seq, start, start + take))
             budgets[r] -= take
         # leftover chunk tokens become this tick's speculative-draft budget:
@@ -1044,7 +1058,36 @@ class ServeScheduler:
         # speculates less, an idle-prefill tick speculates up to the chunk.
         # Per replica, like the chunk budget it is the remainder of.
         self._spec_budget = {r: max(0, b) for r, b in budgets.items()}
+        if self._compaction is not None:
+            # (a chunk planned above may be a later chunk's victim: its table is gone)
+            entries = [e for e in entries if mgr.seqs.get(e[0].uid) is e[0]]
         return entries
+
+    def _reserved(self, req: ServeRequest, seq, end: int, preempting: bool) -> bool:
+        """The pages of ``seq``'s chunk that ends at ``end``, where the table
+        gives pages back (``mgr.compaction``): admission reserved what the
+        prompt holds at its END, and a window in the filling holds more.  A
+        pool that cannot give them is the decode rows' case (``_enqueue``,
+        ``_decode_phase``): one ahead the plan drains and falls back to this
+        tick's back-to-back order, which preempts the youngest other request
+        until the pages are there.  False: a transient refusal, the chunk waits
+        a tick."""
+        mgr = self.engine.mgr
+        while True:
+            try:
+                mgr.ensure_pages(seq, end)
+                return True
+            except RuntimeError as e:
+                if is_transient(e):
+                    return False  # an injected allocator race: the next tick plans it again
+                if not preempting:
+                    raise _Drain("pool") from e
+                victim = self._pick_victim(exclude=req)
+                if victim is None:
+                    raise RuntimeError(
+                        "KV pool cannot hold even one prompt's open window "
+                        f"({mgr.allocator.total_blocks} blocks)") from None
+                self._preempt(victim)
 
     def _note_chunks(self, entries, t0: float, t1: float, tick: int) -> None:
         """``tick``: the call that returns the tokens of the execution these

@@ -35,7 +35,11 @@ recurrence with a matrix state (``gdn``: Gated DeltaNet, ``ops/gdn.py``) or
 gated grouped-query attention (``GatedGqa``: q / k norms, rotary positions on
 part of the head, a sigmoid output gate or none) of up to TWO kinds in one model, each
 with its own head count and rotary table: ``gattn`` over every key (K / V
-pages) and ``wattn`` over the last ``window`` keys (a K / V ring a slot).  What
+pages) and ``wattn`` over the last ``window`` keys (a K / V ring a slot); or EVA
+attention (``eva``, ``ops/eva.py``: plain multi-head attention, rotary over the
+whole head, over the exact keys of the query's own window of positions and one
+learned summary row per chunk of every window before it: K / V pages that give
+all but one back when a window closes).  What
 differs between the models of this family is VALUES of the spec: the gate one
 value a channel, one a head or absent (``GatedGqa.gate``), YaRN on the rotary table
 (``GatedGqa.rope_scaling``), the feed-forward the expert layer in every block
@@ -45,8 +49,10 @@ all experts or by sigmoid scores with a selection bias and ``routed_scale``
 (``shared_gate``) or absent (no ``n_shared``, no ``shared_width``), the norms'
 weights zero-centred, ``x^ (1 + w)``, or plain
 (``unit_offset``).  Trees: ``layers/attn_norm``, ``layers/mlp_norm`` (stacked)
-and one tuple per kind (``gdn``, ``gattn``, ``wattn``, ``moe``, and ``mlp`` where
-``first_dense`` > 0); ``hybrid_params`` picks block ``l``'s.
+and one tuple per kind (``gdn``, ``gattn``, ``wattn``, ``eva``, ``moe``, and ``mlp``
+where ``first_dense`` > 0); ``hybrid_params`` picks block ``l``'s.  The head may
+hold ``pred_heads`` heads' columns side by side, of which the next token reads
+the first ``vocab_size``.
 
 TRAINING (``CausalLM.loss_fn`` -> ``forward`` under ``jax.grad``, through the
 train engine): the blocks whose mixer is attention over K / V (``gattn``,
@@ -57,7 +63,7 @@ under ``jax.checkpoint`` as ``cfg.remat`` says, the routers' scores handed out
 for the balance term (``LatentSpec.router_aux_loss_coef``), the blocks' counts
 noted for the step's metrics (``telemetry.count_in_step``).  A kind with no
 backward yet (key selection, the chunked scans, the delta rule, the latent
-bodies) refuses by its mechanism when its backward is traced (``_forward_only``).
+bodies, the chunk summaries) refuses by its mechanism when its backward is traced (``_forward_only``).
 """
 from __future__ import annotations
 
@@ -141,8 +147,9 @@ class Gqa:
     head_dim: int
 
 
-# mixers of a two-norm block: a recurrence, gated attention over every key, ... over a window
-HYBRID = ("gdn", "gattn", "wattn")
+# mixers of a two-norm block: a recurrence, gated attention over every key, ... over a
+# window, attention over a window's exact keys and the summaries of the windows before it
+HYBRID = ("gdn", "gattn", "wattn", "eva")
 
 
 @dataclass(frozen=True)
@@ -208,6 +215,27 @@ class GatedGqa:
 
 
 @dataclass(frozen=True)
+class Eva:
+    """EVA attention: ``num_heads`` heads of ``head_dim`` with keys and values of
+    their own (no grouping), rotary positions (rotate-half) over the whole head,
+    a learned pooling vector ``phi`` and key offset ``mu`` a head.  A query
+    attends the exact keys of its own aligned window of ``window`` positions and
+    one summary per ``chunk`` positions of every window before it
+    (``ops/eva.py``)."""
+
+    num_heads: int
+    head_dim: int
+    rope_theta: float
+    window: int
+    chunk: int
+    init_std: float = 0.02  # of ``phi`` and ``mu``
+
+    @property
+    def rows_a_closed_window(self) -> int:
+        return self.window // self.chunk
+
+
+@dataclass(frozen=True)
 class LatentSpec:
     layer_kinds: Tuple[str, ...]  # 'full' | 'sliding' | 'every', or SINGLE's, or HYBRID's: one per layer held
     full: LatentAttn
@@ -244,6 +272,11 @@ class LatentSpec:
     # > 0: the training loss adds this times the routers' balance term, the mean over
     # the expert layers of n_routed x sum_e (share of the pairs on e) x (mean score of e)
     router_aux_loss_coef: float = 0.0
+    eva: Optional[Eva] = None
+    # the head holds this many prediction heads' ``vocab_size`` columns side by side;
+    # the next token's logits are the first head's
+    pred_heads: int = 1
+    fp32_logits: bool = False  # the head's product accumulates AND leaves in float32
 
     @property
     def single(self) -> bool:
@@ -362,6 +395,11 @@ def _hybrid_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
         return {"w_qkvz": (d, gd.conv_width + gd.d_in), "w_ba": (d, 2 * gd.num_v_heads),
                 "conv_w": (gd.conv, gd.conv_width), "dt_bias": (gd.num_v_heads,),
                 "a_log": (gd.num_v_heads,), "norm": (gd.v_dim,), "w_out": (gd.d_in, d)}
+    if kind == "eva":
+        ev = s.eva
+        width = ev.num_heads * ev.head_dim
+        return {"wq": (d, width), "wk": (d, width), "wv": (d, width), "wo": (width, d),
+                "phi": (ev.num_heads, ev.head_dim), "mu": (ev.num_heads, ev.head_dim)}
     ga = s.mixer(kind)
     per_channel = ga.gate == "channel"  # each head's projection is then [q | gate]
     out = {"wq": (d, (1 + per_channel) * ga.num_heads * ga.head_dim),
@@ -378,7 +416,7 @@ def param_count(cfg) -> int:
     where the configuration says so)."""
     s, d = cfg.latent, cfg.hidden_size
     size = lambda shapes: sum(int(np.prod(v)) for v in shapes.values())
-    n = 2 * cfg.vocab_size * d + d
+    n = (1 + s.pred_heads) * cfg.vocab_size * d + d
     if s.single:
         return n + sum(d + size(_single_shapes(d, s, kind)) for kind in s.layer_kinds)
     if s.hybrid:
@@ -408,7 +446,7 @@ def flops_per_token(cfg, seq_len: int) -> float:
     shared expert), and attention's (query, key) pairs under each layer's mask
     at 12 x heads x head_dim a pair.  For the kinds that train (``forward``)."""
     s, d = cfg.latent, cfg.hidden_size
-    if not s.stateful or any(k in ("mamba", "gdn") for k in s.layer_kinds):
+    if not s.stateful or any(k in ("mamba", "gdn", "eva") for k in s.layer_kinds):
         refuse("CausalLM.flops_per_token", "only blocks of attention over K / V, SwiGLU and "
                "held experts are counted (they are the kinds that train)")
     size = lambda shapes, names: sum(int(np.prod(shapes[n])) for n in names if n in shapes)
@@ -447,7 +485,12 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         softplus lands log-uniform in [0.001, 0.1], ``A = -exp(a_log)`` in
         -U(1, 16), ``D`` ones, and those float32 as the recurrence reads them."""
         shapes = _hybrid_shapes(d, s, kind) if kind in HYBRID else _single_shapes(d, s, kind)
-        w = {name: dense(sh, sh[-2]) for name, sh in shapes.items() if len(sh) >= 2}
+        w = {name: dense(sh, sh[-2]) for name, sh in shapes.items()
+             if len(sh) >= 2 and name not in ("phi", "mu")}
+        if kind == "eva":  # the pooling vectors and key offsets: N(0, init_std^2), float32
+            for name in ("phi", "mu"):
+                w[name] = s.eva.init_std * jax.random.normal(next(keys), shapes[name],
+                                                             jnp.float32)
         if "bias" in shapes:
             w["bias"] = (0.02 * jax.random.normal(next(keys), (s.n_routed,))
                          ).astype(jnp.float32)
@@ -482,7 +525,7 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         "embed": {"embedding": dense((cfg.vocab_size, d), d)},
         "layers": layers,
         "final_norm": {"scale": norm},
-        "lm_head": {"kernel": dense((d, cfg.vocab_size), d)},
+        "lm_head": {"kernel": dense((d, s.pred_heads * cfg.vocab_size), d)},
     }
     if s.single:
         layers = {"norm": {"scale": jnp.ones((L, d), dtype)}}
@@ -494,7 +537,8 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
                   "mlp_norm": {"scale": norm_weight((L, d))},
                   "moe": tuple(single("experts") for _ in range(L - s.first_dense))}
         for kind in HYBRID:
-            layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
+            if kind != "eva" or s.count(kind):  # (the older kinds' trees are there, empty or not)
+                layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
         if s.first_dense:
             f = cfg.intermediate_size
             layers["mlp"] = tuple(
@@ -909,6 +953,27 @@ def gattn_output(aw, o, gate):
     return y.astype(o.dtype) @ aw["wo"]
 
 
+def eva_inputs(aw, h, pos, ev: Eva):
+    """h [T, d] at positions ``pos`` [T] -> (q, k, v [T, H, hd]); q and k rotated
+    over the whole head.  The barrier as in ``gqa_inputs``."""
+    q, k, v = jax.lax.optimization_barrier((h @ aw["wq"], h @ aw["wk"], h @ aw["wv"]))
+    heads = lambda a: a.reshape(a.shape[0], ev.num_heads, ev.head_dim)
+    rot = lambda a: _rope(heads(a), pos, ev.rope_theta)
+    return rot(q), rot(k), heads(v)
+
+
+def head_logits(x, kernel, cfg):
+    """Normed hidden rows x [..., d] through the head: the next token's logits
+    [..., vocab] (the first of ``pred_heads`` heads' columns; float32 out of the
+    product where the spec says ``fp32_logits``)."""
+    s = cfg.latent
+    if s.pred_heads > 1:
+        kernel = kernel[:, :cfg.vocab_size]
+    if s.fp32_logits:
+        return jnp.dot(x, kernel, preferred_element_type=jnp.float32)
+    return x @ kernel
+
+
 def ffn(fw, h, is_moe: bool, cfg, valid=None):
     """(output [T, d], and of an expert layer (routing stats, experts picked
     [T, k], every expert's score [T, n_routed]), else None)."""
@@ -981,7 +1046,7 @@ def _head(params: Params, x, b: int, n: int, cfg, return_hidden: bool, aux=0.0):
     aux = jnp.asarray(aux, jnp.float32)
     if return_hidden:
         return x, None, aux
-    return x @ params["lm_head"]["kernel"], None, aux
+    return head_logits(x, params["lm_head"]["kernel"], cfg), None, aux
 
 
 def _single_blocks(layers: Params, x, b: int, n: int, cfg):
@@ -1046,6 +1111,15 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
         if kind == "gdn":
             y = _chunked_uncached(gdn_chunks, mw, h.reshape(b, n, -1), s_.gdn, eps)
             y = _forward_only(y.reshape(b * n, -1), "the chunked delta rule (ops/gdn.py)")
+        elif kind == "eva":
+            from ..ops import eva
+
+            ev = s_.eva
+            q, k, v = (a.reshape(b, n, *a.shape[1:]) for a in eva_inputs(mw, h, pos, ev))
+            with jax.named_scope("eva_attend"):
+                o = eva.attend_uncached(q, k, v, mw["phi"], mw["mu"], ev.window, ev.chunk)
+            o = _forward_only(o, "the chunk summaries of EVA attention (ops/eva.py)")
+            y = o.reshape(b * n, -1) @ mw["wo"]
         else:
             ga = s_.mixer(kind)
             q, k, v, gate = gattn_inputs(mw, h, pos, ga, eps, s_.unit_offset)
@@ -1080,7 +1154,7 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
             rows, bounded = held_rows_laid_out(b * n, s_, stats[1])
             count_in_step("expert_rows_laid_out", rows)
             count_in_step("expert_layers_bounded", bounded)
-        if kind != "gdn":
+        if kind not in ("gdn", "eva"):
             count_in_step("causal_keys", jnp.float32(b * allowed_pairs(n)))
             count_in_step("window_keys_attended",
                           jnp.float32(b * allowed_pairs(n, s_.mixer(kind).window)))

@@ -131,6 +131,14 @@ def _vmem_estimate(t_pad, hq, hkv, hd, bs, p, isz):
     )
 
 
+def fits_vmem(t: int, hq: int, hkv: int, hd: int, bs: int, pages: int, isz: int) -> bool:
+    """``supports``' verdict on VMEM from the shapes alone: a pack of ``t`` rows
+    at ``hq`` query / ``hkv`` K / V heads of ``hd``, pages of ``bs`` rows of
+    ``isz``-byte elements, a context table ``pages`` wide.  For a caller that
+    lays its cache out before there is an array to show ``supports``."""
+    return _vmem_estimate(_pad_len(t), hq, hkv, hd, bs, pages, isz) <= _VMEM_BUDGET
+
+
 def supports(q, cache_k, ctx_tables) -> bool:
     """Shape/layout gate for kernel dispatch (soft cap is fused, so unlike
     the decode kernel a ``logits_soft_cap`` config stays on the kernel)."""
@@ -145,9 +153,7 @@ def supports(q, cache_k, ctx_tables) -> bool:
         return hd >= 8 and hd % 8 == 0
     if hd % 128:
         return False
-    isz = jnp.dtype(cache_k.dtype).itemsize
-    est = _vmem_estimate(_pad_len(t), hq, hkv, hd, bs, ctx_tables.shape[1], isz)
-    return est <= _VMEM_BUDGET
+    return fits_vmem(t, hq, hkv, hd, bs, ctx_tables.shape[1], jnp.dtype(cache_k.dtype).itemsize)
 
 
 def _ctx_kernel(
