@@ -60,6 +60,8 @@ HOT_PATHS: Dict[str, Set[str]] = {
         # split; the ONE designed fetch of a program is ``_fetched``'s
         "pack_dispatch", "pack_collect", "decode_dispatch", "decode_collect",
         "prefill_dispatch", "_packs_of", "_fetched",
+        # ... and what a tick's step shares with a pack that carries it (PR 54)
+        "_fill_step", "_emit_step",
         # the KV-handoff seam (PR 12): np.asarray is the designed host
         # copy; any OTHER sync primitive mid-migration stalls the tick
         "extract_kv_blocks", "inject_kv_blocks",

@@ -119,12 +119,14 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
         n_tokens=B, sample_rows=B,
     )
 
-    # a pack's ONE buffer, as the dispatch site lays it out
+    # a pack's ONE buffer, as the dispatch site lays it out (with the step's
+    # rows behind it where the engine's packs carry the tick's step)
     from ..inference.engine_v2 import new_pack
 
+    step = eng.packs_carry_step
     specs["prefill_packed"] = dict(
         jit=eng._packed_prefill_jit,
-        args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, False)[0]),
+        args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, False, step)[0]),
               eng.kv, key, chain, tr),
         donated={"kv": 2}, static=(5,),
         n_tokens=t_pad, sample_rows=B,
@@ -135,7 +137,7 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
 
     specs["prefill_packed_ctx"] = dict(
         jit=eng._packed_prefill_ctx_jit,
-        args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, True)[0]),
+        args=(eng.params, jnp.asarray(new_pack(t_pad, bs, B, eng.max_pages, True, step)[0]),
               eng.kv, key, chain, tr),
         donated={"kv": 2}, static=(5,),
         n_tokens=t_pad, sample_rows=B,

@@ -14,7 +14,9 @@ The engine chooses its RUNNER once, at construction (``self.runner``:
 ``model_runner.DenseRunner``, or ``latent_runner.LatentRunner`` for
 ``cfg.latent``), and asks it for everything that depends on the kind of
 layer state: the cache, the pack / tick / verify entries its jitted programs
-call, whether a cold pack has a program of its own, and the kind's host
+call, whether a cold pack has a program of its own, whether its packs take
+the tick's decode step beside their tokens (``packs_carry_step``: one program
+a tick where a pack and a step went out as two), and the kind's host
 accounting (extra ``stats`` counters, a dispatch's span arguments, what a
 released slot gives back, what ``close()`` audits).
 """
@@ -52,37 +54,49 @@ def _bucket(n: int, buckets: Sequence[int]) -> int:
 # of a buffer from ``new_pack`` (the build fills them in place), in a traced
 # program the same static slices of the uploaded array.
 def _pack_sizes(t_pad: int, block_size: int, max_seqs: int, max_pages: int,
-                ctx: bool) -> List[int]:
-    """Lengths of tokens, seg, pos, pack_pages, last_idx (a cold pack) and
-    ctx_tables, ctx_lens (a context pack), in the order they lie."""
+                ctx: bool, step: bool = False) -> List[int]:
+    """Lengths of tokens, seg, pos, pack_pages, last_idx (a cold pack), the
+    slots' block tables (a context pack's segments and a carried step's rows:
+    a slot is one or the other), ctx_lens (a context pack) and the tick's
+    ``[4, slots]`` rows as ``decode_impl`` takes them (``step``: a pack that
+    carries the tick's step), in the order they lie."""
     sizes = [t_pad, t_pad, t_pad, t_pad // block_size, max_seqs]
-    return sizes + [max_seqs * max_pages, max_seqs] if ctx else sizes
+    if ctx or step:
+        sizes.append(max_seqs * max_pages)
+    if ctx:
+        sizes.append(max_seqs)
+    return sizes + [4 * max_seqs] if step else sizes
 
 
-def unpack_pack(buf, block_size: int, max_seqs: int, max_pages: int, ctx: bool):
-    """(tokens, seg, pos, pack_pages, last_idx) of a cold pack's buffer, and
-    for a context pack also (ctx_tables, ctx_lens)."""
-    per_slot = sum(_pack_sizes(0, block_size, max_seqs, max_pages, ctx))
+def unpack_pack(buf, block_size: int, max_seqs: int, max_pages: int, ctx: bool,
+                step: bool = False):
+    """(tokens, seg, pos, pack_pages, last_idx) of a cold pack's buffer, for
+    a context pack also (ctx_tables, ctx_lens); with ``step`` the tables are
+    there for a cold pack too and the step's rows come last."""
+    per_slot = sum(_pack_sizes(0, block_size, max_seqs, max_pages, ctx, step))
     t_pad = (buf.shape[0] - per_slot) * block_size // (3 * block_size + 1)
-    sizes = _pack_sizes(t_pad, block_size, max_seqs, max_pages, ctx)
+    sizes = _pack_sizes(t_pad, block_size, max_seqs, max_pages, ctx, step)
     if sum(sizes) != buf.shape[0]:
         raise ValueError(f"no pack of {buf.shape[0]} int32 at block_size "
                          f"{block_size}, {max_seqs} slots, {max_pages} pages")
     at = np.cumsum([0] + sizes)
     parts = [buf[a:b] for a, b in zip(at[:-1], at[1:])]
-    if ctx:
+    if ctx or step:
         parts[5] = parts[5].reshape(max_seqs, max_pages)
+    if step:
+        parts[-1] = parts[-1].reshape(4, max_seqs)
     return tuple(parts)
 
 
 def new_pack(t_pad: int, block_size: int, max_seqs: int, max_pages: int,
-             ctx: bool):
+             ctx: bool, step: bool = False):
     """An empty pack of ``t_pad`` tokens: (buffer, its views by
-    ``unpack_pack``): no token, no page, no row to sample, no context."""
-    buf = np.zeros(
-        sum(_pack_sizes(t_pad, block_size, max_seqs, max_pages, ctx)), np.int32)
-    views = unpack_pack(buf, block_size, max_seqs, max_pages, ctx)
-    for v in views[3:6]:  # pack_pages, last_idx, ctx_tables: -1 = none
+    ``unpack_pack``): no token, no page, no row to sample, no context, no
+    live slot."""
+    buf = np.zeros(sum(_pack_sizes(
+        t_pad, block_size, max_seqs, max_pages, ctx, step)), np.int32)
+    views = unpack_pack(buf, block_size, max_seqs, max_pages, ctx, step)
+    for v in views[3:6]:  # pack_pages, last_idx, the tables: -1 = none
         v.fill(-1)
     return buf, views
 
@@ -91,16 +105,17 @@ class Enqueued:
     """One program of a tick, enqueued and not collected yet: what its
     collect needs.  ``rows``: a pack's [(seq, start, end)] or a step's
     sequences; ``finishing``: the sequences whose prompt a pack completes;
-    ``sampled``: the device's ``[slots]`` tokens while nobody has fetched
-    them (a split dispatch), ``tokens`` once somebody has; ``split``: the
-    fetch is not inside the dispatch span."""
+    ``step``: the sequences of the tick's step a pack carried (none: the pack
+    alone); ``sampled``: the device's ``[slots]`` tokens while nobody has
+    fetched them (a split dispatch), ``tokens`` once somebody has; ``split``:
+    the fetch is not inside the dispatch span."""
 
-    __slots__ = ("span", "rows", "finishing", "split", "sampled", "tokens")
+    __slots__ = ("span", "rows", "finishing", "step", "split", "sampled", "tokens")
 
     def __init__(self, span: str, rows: List, finishing: Optional[List],
-                 split: bool):
+                 split: bool, step: Sequence = ()):
         self.span, self.rows, self.finishing = span, rows, finishing
-        self.split = split
+        self.step, self.split = list(step), split
         self.sampled = self.tokens = None
 
 
@@ -439,6 +454,9 @@ class InferenceEngineV2:
             # (the two above among them): one a decode tick, one a pack
             "decode_ticks",
             "decode_emitted",  # tokens emitted by plain decode dispatches
+            "mixed_dispatches",  # packs that carried a step's live rows: ONE
+            # program where a pack and a step went out as two (each also
+            # counts as a prefill dispatch and as a decode tick)
             "decode_bursts",  # device-resident bursts (ONE host sync each)
             "burst_ticks",  # decode dispatches fused inside bursts
             "burst_emitted",  # tokens committed out of burst fetches
@@ -502,6 +520,13 @@ class InferenceEngineV2:
             prefill_budget or self.prefill_buckets[-1], self.prefill_buckets[-1]
         )
         runner = self.runner
+        # A pack CARRIES THE TICK'S STEP where the runner's packs take the
+        # step's rows and a program may queue (the scheduler then plans a pack
+        # and a step together): both pack programs are built in that form, so
+        # the set-up compiles what it always did, and a pack with no live slot
+        # is the pack alone.  A serve mesh lays a pack out in replica chunks
+        # and offloaded weights keep today's order: their packs stay as they are.
+        self.packs_carry_step = runner.packs_carry_step and self.programs_may_queue
         self.kv = runner.init_cache(
             num_blocks, block_size, max_seqs, self.prefill_buckets[-1])
         self.mgr.release_hook = runner.released
@@ -572,27 +597,55 @@ class InferenceEngineV2:
         # next step reads a slot's input token from it wherever the host has
         # not seen that token yet (a tick dispatched one ahead): like the
         # key, it goes from program to program and is uploaded by nobody.
+        carries_ = self.packs_carry_step
+
+        def step_inputs(rows, chain):
+            """A step's input tokens and live mask from its ``[4, slots]``
+            rows (``decode_impl``) and the chain it is handed."""
+            return (jnp.where(rows[3] != 0, jnp.maximum(chain, 0), rows[0]),
+                    rows[2] != 0)
+
+        def pack_program(entry, pack, is_ctx, params, kv, rng, chain,
+                         sampling_triple, **kw):
+            """What the two pack programs share.  Where a pack carries the
+            tick's step its buffer ends in the step's rows: they go through
+            ``entry`` beside the pack's tokens (ONE stream of the weights) and
+            the program then does what the pack's and the step's did in turn:
+            the pack samples and writes the slots it completed into the chain,
+            the step samples off the NEXT split and writes its live slots
+            (disjoint from the pack's: the step reads its input tokens from the
+            chain it was handed).  With no slot live the key is split once, as
+            by the pack alone."""
+            views = unpack_pack(pack, bs_, B_, mp_, is_ctx, carries_)
+            if not carries_:
+                logits, kv = entry(params, cfg_, *views, kv, ctx=ctx_,
+                                   mesh=mesh_, **kw)
+                sampled, rng = sampled_pack(logits, rng, sampling_triple)
+                return jnp.where(views[4] >= 0, sampled, chain), kv, rng
+            *views, rows = views
+            tokens, active = step_inputs(rows, chain)
+            (logits, step_logits), kv = entry(
+                params, cfg_, *(views if is_ctx else views[:5]), kv, ctx=ctx_,
+                mesh=mesh_, step=(tokens, rows[1], views[5], active), **kw)
+            sampled, rng = sampled_pack(logits, rng, sampling_triple)
+            chain = jnp.where(views[4] >= 0, sampled, chain)
+            sampled, step_rng = sampled_pack(step_logits, rng, sampling_triple)
+            return (jnp.where(active, sampled, chain), kv,
+                    jnp.where(active.any(), step_rng, rng))
+
         def packed_impl(params, pack, kv, rng, chain, sampling_triple):
             """A cold pack: ``pack`` is the ONE int32 buffer of
             ``new_pack(..., ctx=False)``."""
-            views = unpack_pack(pack, bs_, B_, mp_, False)
-            logits, kv = runner.prefill_packed(
-                params, cfg_, *views, kv, ctx=ctx_, mesh=mesh_,
-            )
-            sampled, rng = sampled_pack(logits, rng, sampling_triple)
-            return jnp.where(views[4] >= 0, sampled, chain), kv, rng
+            return pack_program(runner.prefill_packed, pack, False, params, kv,
+                                rng, chain, sampling_triple)
 
         def packed_ctx_impl(params, pack, kv, rng, chain, sampling_triple):
             """Context-aware variant: suffix tokens attend over each
             sequence's cached KV pages (prefix-cache hits, chunked-prefill
             continuation chunks).  Cold packs stay on ``packed_impl``."""
-            views = unpack_pack(pack, bs_, B_, mp_, True)
-            logits, kv = runner.prefill_packed_ctx(
-                params, cfg_, *views, kv,
-                ctx=ctx_, mesh=mesh_, dp=dp_, seq_shards=sq_,
-            )
-            sampled, rng = sampled_pack(logits, rng, sampling_triple)
-            return jnp.where(views[4] >= 0, sampled, chain), kv, rng
+            return pack_program(runner.prefill_packed_ctx, pack, True, params,
+                                kv, rng, chain, sampling_triple, dp=dp_,
+                                seq_shards=sq_)
 
         def cow_impl(kv, src, dst):
             """Copy-on-write page clone: dst pages get src's contents in
@@ -628,8 +681,7 @@ class InferenceEngineV2:
             ``-1`` read from the chain (the finite guard's sentinel) is
             clamped: that row's request has failed and its result is thrown
             away at collect."""
-            tokens = jnp.where(rows[3] != 0, jnp.maximum(chain, 0), rows[0])
-            active = rows[2] != 0
+            tokens, active = step_inputs(rows, chain)
             sampled, rng, kv = decode_sample(
                 params, tokens, rows[1], block_tables, active, kv, rng,
                 sampling_triple)
@@ -1001,11 +1053,16 @@ class InferenceEngineV2:
         return out
 
     def prefill_dispatch(self, entries, sampling: SamplingParams,
-                         ahead: bool = False) -> List["Enqueued"]:
+                         ahead: bool = False, step=()) -> List["Enqueued"]:
         """``prefill_entries``' packs ENQUEUED and nothing fetched: one
-        handle a pack, for ``pack_collect``, in dispatch order."""
-        return [self.pack_dispatch(pack, sampling, split=True, ahead=ahead)
-                for pack in self._packs_of(entries)]
+        handle a pack, for ``pack_collect``, in dispatch order.  ``step``:
+        the sequences of the tick's decode step, which the LAST pack carries
+        (``packs_carry_step``): the key is then split pack by pack and by the
+        step last, as by the programs in turn."""
+        packs = list(self._packs_of(entries))
+        return [self.pack_dispatch(pack, sampling, split=True, ahead=ahead,
+                                   step=step if pack is packs[-1] else ())
+                for pack in packs]
 
     def _packs_of(self, entries):
         """``entries`` cut into packs under ``prefill_budget`` (a generator:
@@ -1052,13 +1109,20 @@ class InferenceEngineV2:
         self.pack_collect(self.pack_dispatch(entries, sampling), out)
 
     def pack_dispatch(self, entries, sampling, split: bool = False,
-                      ahead: bool = False) -> "Enqueued":
+                      ahead: bool = False, step=()) -> "Enqueued":
         """Build, upload and ENQUEUE one packed prefill for ``entries`` =
         [(seq, start, end)]; ``pack_collect`` fetches and emits it.  With
         ``split`` the span closes at the enqueue and the fetch is the
         collect's (``ahead``: the execution before this one is not fetched
         yet; the span says so); without, the fetch lies inside the span as it
         always did.
+
+        ``step`` (``packs_carry_step`` only): the sequences of the tick's
+        decode step, none of them in ``entries``.  Their rows ride the pack's
+        buffer (``decode_dispatch``'s four, and their block tables in the rows
+        a context pack keeps free for them) and its ONE program: still one
+        upload, one ``prefill_pack`` span, one handle, one fetch, and a
+        dispatch counted as a pack AND as a tick (``mixed_dispatches``).
 
         Each suffix starts at a PAGE boundary of the pack buffer (segment-0
         gap padding between prompts): KV then writes as one page-granular
@@ -1097,11 +1161,17 @@ class InferenceEngineV2:
             t_pad = C * dp
             use_ctx = any(start > 0 for _, start, _ in entries) \
                 or self.runner.packs_are_one_program
+            carries = self.packs_carry_step
+            if step and (not carries or {id(s) for s in step}
+                         & {id(e[0]) for e in entries}):
+                raise ValueError("a pack carries a step only where "
+                                 "packs_carry_step, and no sequence twice")
             # the pack's ONE buffer, filled through its views
             pack, views = new_pack(t_pad, bs, self.mgr.max_seqs,
-                                   self.max_pages, use_ctx)
+                                   self.max_pages, use_ctx, carries)
             tokens, seg, pos, pack_pages, last_idx = views[:5]
-            ctx_tables, ctx_lens = views[5:] if use_ctx else (None, None)
+            ctx_tables = views[5] if use_ctx or carries else None
+            ctx_lens = views[6] if use_ctx else None
             ctx_pages = 0  # live context pages the ctx kernel walks: its time over this
             for r, group in enumerate(groups):
                 cur = r * C
@@ -1124,6 +1194,9 @@ class InferenceEngineV2:
                         ctx_lens[s.slot] = start
                         ctx_pages += first_page
                     cur += n_pages * bs  # next prompt starts page-aligned
+            step_ctx = self._fill_step(views[-1], step) if step else 0
+            for s in step:  # (its row of the mirror is current: ``_fill_step``)
+                ctx_tables[s.slot] = self._tables_np[s.slot]
             bsp.mark("rows")  # what is left of the span: the runner's counts
             triple = (sampling.temperature, sampling.top_k, sampling.top_p)
             n_real = sum(end - start for _, start, end in entries)
@@ -1131,6 +1204,8 @@ class InferenceEngineV2:
             extra = self.runner.dispatched(
                 self._c, ((s.slot, start, end) for s, start, end in entries), pack=True,
                 tokens=t_pad)
+            if carries:
+                extra.update(step_rows=len(step), ctx_tokens=step_ctx)
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
@@ -1149,11 +1224,16 @@ class InferenceEngineV2:
             self._c["prefill_tokens_dispatched"].inc(n_real)
             self._c["prefill_dispatches"].inc()
             self._c["dispatched_ahead"].inc(int(ahead))
+            if step:
+                self._c["mixed_dispatches"].inc()
+                self._c["decode_ticks"].inc()
+                self._c["decode_emitted"].inc(len(step))
             self._account_comm(t_pad, sample_rows=n_slots, ring=use_ctx)
             if compaction is not None:
                 self._close_windows((s, end) for s, _, end in entries)
-            done = Enqueued("prefill_pack", list(entries), finishing, split)
-            if finishing and not split:
+            done = Enqueued("prefill_pack", list(entries), finishing, split, step)
+            fetched = finishing or step  # (intermediate chunks alone: nothing is)
+            if fetched and not split:
                 # host-complete: this fetch syncs the pack
                 done.tokens = np.asarray(sampled)
             else:
@@ -1163,7 +1243,7 @@ class InferenceEngineV2:
                 # pack's device time is the trace's (one ``XLA Modules``
                 # event per execution)
                 sp.end(sync_obj=sampled)
-                if finishing:
+                if fetched:
                     # the device -> host copy starts HERE, behind the program:
                     # the collect waits for the program, not for a transfer
                     # started late
@@ -1179,6 +1259,8 @@ class InferenceEngineV2:
                 self.mgr.update_hashes(s)
             for s in finishing:
                 s.pending += 1
+        for s in step:
+            s.pending += 1  # (as ``decode_dispatch``'s rows)
         return done
 
     def _fetched(self, done: "Enqueued"):
@@ -1204,9 +1286,12 @@ class InferenceEngineV2:
         return True
 
     def pack_collect(self, done: "Enqueued", out: Dict[int, int],
-                     dead=()) -> None:
+                     dead=()) -> Dict[int, int]:
         """Fetch and emit a pack ``pack_dispatch`` enqueued: first tokens
-        into ``out`` ({uid: token}, -1 for a row the finite guard failed)."""
+        into ``out`` ({uid: token}, -1 for a row the finite guard failed).
+        Returns what the step it carried sampled, as ``decode_collect`` does
+        (booked inside the pack's emit: one booking a dispatch); {} for a
+        pack alone."""
         tel, ns = self.telemetry, self._ns
         entries, finishing = done.rows, done.finishing
         next_tokens = self._fetched(done)
@@ -1240,6 +1325,9 @@ class InferenceEngineV2:
                     self._set_block_table(s)
                     out[s.uid] = tok
                 self.mgr.update_hashes(s)
+            if not done.step:
+                return {}
+            return self._emit_step(done, done.step, next_tokens, dead)
 
     def _close_windows(self, written) -> None:
         """``written``: (sequence, positions written so far) of the execution
@@ -1681,6 +1769,27 @@ class InferenceEngineV2:
         sequence; stop/length handling is the caller's job."""
         return self.decode_collect(self.decode_dispatch(active_seqs, sampling))
 
+    def _fill_step(self, rows, active_seqs) -> int:
+        """A decode step's ``[4, slots]`` rows for ``active_seqs``, pages
+        grown and the tables' mirror brought up to date; returns the context
+        tokens the step attends."""
+        tokens, seq_lens, active, chained = rows
+        ctx_tokens = 0
+        for s in active_seqs:
+            # grow pages for the token being written this tick; the COW
+            # guard clones the target page first if it is somehow shared
+            self.mgr.ensure_capacity(s, 1)
+            self.mgr.ensure_writable(s, s.cur_len - 1)
+            self._set_block_table(s)
+            if s.pending:
+                chained[s.slot] = 1
+            else:
+                tokens[s.slot] = s.tokens[-1]
+            seq_lens[s.slot] = s.cur_len - 1  # KV position of the new token
+            active[s.slot] = 1
+            ctx_tokens += s.cur_len
+        return ctx_tokens
+
     def decode_dispatch(self, active_seqs, sampling: SamplingParams,
                         split: bool = False, ahead: bool = False) -> "Enqueued":
         """Build, upload and ENQUEUE one decode tick over ``active_seqs``;
@@ -1694,21 +1803,7 @@ class InferenceEngineV2:
             # the tick's ONE upload: tokens, KV positions, 0 / 1 for a live
             # slot, 0 / 1 for an input token that is the chain's
             rows = np.zeros((4, B), np.int32)
-            tokens, seq_lens, active, chained = rows
-            ctx_tokens = 0
-            for s in active_seqs:
-                # grow pages for the token being written this tick; the COW
-                # guard clones the target page first if it is somehow shared
-                self.mgr.ensure_capacity(s, 1)
-                self.mgr.ensure_writable(s, s.cur_len - 1)
-                self._set_block_table(s)
-                if s.pending:
-                    chained[s.slot] = 1
-                else:
-                    tokens[s.slot] = s.tokens[-1]
-                seq_lens[s.slot] = s.cur_len - 1  # KV position of the new token
-                active[s.slot] = 1
-                ctx_tokens += s.cur_len
+            ctx_tokens = self._fill_step(rows, active_seqs)
             self._maybe_fault("runner_exception", [s.uid for s in active_seqs])
             bsp.mark("rows")  # what is left of the span: the runner's counts
             extra = self.runner.dispatched(
@@ -1755,34 +1850,38 @@ class InferenceEngineV2:
         """Fetch and emit a tick ``decode_dispatch`` enqueued: {uid: token},
         -1 for a row the finite guard failed; a dead row (``dead``: its
         request ended while the tick was enqueued) is in it nowhere."""
-        tel, ns = self.telemetry, self._ns
-        active_seqs = done.rows
         next_tokens = self._fetched(done)
-        with tel.span("engine.decode_emit", track=ns):
-            poison = self._poisoned([s.uid for s in active_seqs])
-            out = {}
-            for s in active_seqs:
-                s.pending -= 1
-                if self._dropped(s, done, dead):
-                    continue
-                tok = int(next_tokens[s.slot])
-                if s.uid in poison:
-                    tok = -1
-                if tok < 0:
-                    # finite_guard sentinel: fail this row only — no token is
-                    # committed, the growth block reserved for it above is
-                    # returned, and the keys it published are retracted (its
-                    # written KV is suspect) so nothing leaks or pollutes
-                    s.error = "non-finite logits in decode"
-                    self.mgr.quarantine_written(s)
-                    if self.mgr.truncate_to_length(s):
-                        self._set_block_table(s)
-                    out[s.uid] = -1
-                    continue
-                s.tokens.append(tok)
-                s.seen_tokens = len(s.tokens) - 1
-                self.mgr.update_hashes(s)
-                out[s.uid] = tok
+        with self.telemetry.span("engine.decode_emit", track=self._ns):
+            return self._emit_step(done, done.rows, next_tokens, dead)
+
+    def _emit_step(self, done: "Enqueued", active_seqs, next_tokens,
+                   dead) -> Dict[int, int]:
+        """Book what a step sampled for ``active_seqs`` (a tick's rows, or
+        the rows a pack carried), inside the caller's emit span."""
+        poison = self._poisoned([s.uid for s in active_seqs])
+        out = {}
+        for s in active_seqs:
+            s.pending -= 1
+            if self._dropped(s, done, dead):
+                continue
+            tok = int(next_tokens[s.slot])
+            if s.uid in poison:
+                tok = -1
+            if tok < 0:
+                # finite_guard sentinel: fail this row only — no token is
+                # committed, the growth block reserved for it above is
+                # returned, and the keys it published are retracted (its
+                # written KV is suspect) so nothing leaks or pollutes
+                s.error = "non-finite logits in decode"
+                self.mgr.quarantine_written(s)
+                if self.mgr.truncate_to_length(s):
+                    self._set_block_table(s)
+                out[s.uid] = -1
+                continue
+            s.tokens.append(tok)
+            s.seen_tokens = len(s.tokens) - 1
+            self.mgr.update_hashes(s)
+            out[s.uid] = tok
         return out
 
     def step(self, sampling: SamplingParams = SamplingParams()) -> Dict[int, int]:

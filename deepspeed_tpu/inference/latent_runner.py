@@ -1118,6 +1118,7 @@ class LatentRunner:
 
     counters = COUNTERS
     packs_are_one_program = True  # a pack reads its own rows back from the cache
+    packs_carry_step = False  # a pack and a step are two programs: state, rings, a compacting table
     scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn; full_attn
 
     def __init__(self, cfg):

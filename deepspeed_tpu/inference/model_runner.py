@@ -12,6 +12,11 @@ The decoder block is written ONCE (``_layer``) and looped over ONCE
 embedded and positioned (``[1, T, d]`` for a pack, ``[B, 1, d]`` for the
 tick), how a layer's new K/V rows are written into its pools (``write``),
 which attention reads them (``read``), and which rows go to the head.
+A pack that CARRIES THE TICK'S STEP (``step=``) is the same loop over
+``[1, T + B, d]``: the pack's T token rows and the step's B slot rows go
+through every norm, projection, MLP and the head as one operand, so a weight
+crosses HBM once a tick, and the seam alone is per kind (``_carrying``: each
+kind of row keeps its own write and its own attention kernel).
 ``latent_runner.py`` has the same shape for ``cfg.latent``; the engine picks
 one of the two (``DenseRunner`` here, ``LatentRunner`` there) once.
 
@@ -195,6 +200,68 @@ def _write_pages(pack_pages, kv_cache):
                                write_pack_kv(kv_l[1], v[0], pages))
 
 
+def _step_seam(cfg, seq_lens, block_tables, active, mesh, dp, seq_shards, rows):
+    """A decode step's (write, read): one new K/V row a live slot, then the
+    paged kernel over each slot's pages.  ``rows`` takes the ``[B, heads, hd]``
+    rows out of the entry's layout (``[B, 1, ...]`` for the tick, ``[1, B,
+    ...]`` behind a pack)."""
+    def write(kv_l, k, v):
+        return (write_decode_kv(kv_l[0], rows(k), block_tables, seq_lens, active),
+                write_decode_kv(kv_l[1], rows(v), block_tables, seq_lens, active))
+
+    def read(q, k, v, kv_l):
+        # length 0 = no row in this slot: the kernel skips it
+        return paged_attention_decode(
+            rows(q), *kv_l, block_tables, jnp.where(active, seq_lens + 1, 0),
+            logits_soft_cap=cfg.logits_soft_cap, mesh=mesh, dp=dp,
+            seq_shards=seq_shards)
+
+    return write, read
+
+
+def _carrying(t, pack, step):
+    """The seam of a pack that carries the tick's step: rows ``[:t]`` are the
+    pack's, rows ``[t:]`` the step's slots, ``pack`` and ``step`` their
+    (write, read).  The two kinds hold disjoint sequences (the scheduler's
+    contract), hence disjoint pages to write; each attends with the kernel it
+    has alone."""
+    def write(kv_l, k, v):
+        kv_l = pack[0](kv_l, k[:, :t], v[:, :t])
+        return step[0](kv_l, k[:, t:], v[:, t:])
+
+    def read(q, k, v, kv_l):
+        # ([rows, heads * hd]: a flash pack attends as [1, T, ...], a context
+        # pack and the step as [rows, ...]; ``_layer`` reshapes either)
+        return jnp.concatenate(
+            [pack[1](q[:, :t], k[:, :t], v[:, :t], kv_l).reshape(t, -1),
+             step[1](q[:, t:], k[:, t:], v[:, t:], kv_l).reshape(q.shape[1] - t, -1)])
+
+    return write, read
+
+
+def _pack(params, cfg, tokens, positions, last_idx, kv_cache, seam, step, ctx,
+          mesh, dp=1, seq_shards=1):
+    """What the packs share: embed, every layer through the pack's ``seam``
+    (its write and read), the head over each prompt's last row.  With ``step`` (the tick's decode rows: tokens, KV positions,
+    block tables, live mask, each a row a slot) the step's B rows follow the
+    pack's T through the same layers and ONE head matmul scores both:
+    ((pack logits [N, v], step logits [B, v]), new caches)."""
+    t = tokens.shape[0]
+    if step is not None:
+        s_tokens, s_lens, s_tables, s_active = step
+        tokens = jnp.concatenate([tokens, s_tokens])
+        positions = jnp.concatenate([positions, s_lens])
+        seam = _carrying(t, seam, _step_seam(
+            cfg, s_lens, s_tables, s_active, mesh, dp, seq_shards, lambda a: a[0]))
+    x = _embed(params, cfg, tokens, positions, 0)  # [1,T(+B),d]
+    x, kv = _layers(params, cfg, x, positions[None], kv_cache, *seam, ctx)
+    last = x[0, jnp.clip(last_idx, 0, t - 1)]  # [N, d]
+    if step is None:
+        return _lm_logits(params, cfg, last, ctx), kv  # [N, v]
+    logits = _lm_logits(params, cfg, jnp.concatenate([last, x[0, t:]]), ctx)
+    return (logits[:last.shape[0]], logits[last.shape[0]:]), kv
+
+
 def prefill_packed(
     params, cfg: TransformerConfig,
     tokens,  # [T] int32 — prompts packed at PAGE-aligned starts
@@ -203,6 +270,8 @@ def prefill_packed(
     pack_pages,  # [T/bs] int32 — destination page per bs-chunk (-1 pad)
     last_idx,  # [N] int32 — buffer index of each prompt's last token (-1 pad)
     kv_cache, ctx=None, mesh=None,
+    step=None,  # the tick's decode rows, as ``decode_step`` takes them:
+    # (tokens [B], seq_lens [B], block_tables [B, P], active [B])
 ):
     """Batched multi-prompt prefill under one token budget (the Dynamic
     SplitFuse-shaped dispatch; reference ``inference/v2/ragged/
@@ -210,10 +279,9 @@ def prefill_packed(
     causal pass, cross-prompt attention blocked by ``segment_ids``.  Every
     prompt starts at a PAGE boundary of the pack (the engine pads with
     segment-0 gaps), so KV lands page by page (``_write_pages``).  Returns
-    (logits [N, vocab], new caches)."""
+    (logits [N, vocab], new caches); with ``step`` the tick's decode rows ride
+    the same stream of the weights (``_pack``) and the logits are a pair."""
     _dense_only(cfg, "model_runner.prefill_packed")
-    x = _embed(params, cfg, tokens, positions, 0)  # [1,T,d]
-    write = _write_pages(pack_pages, kv_cache)
     seg = segment_ids[None]  # [1, T]
 
     def read(q, k, v, kv_l):
@@ -223,9 +291,8 @@ def prefill_packed(
         return flash_attention(q, k, v, causal=True, segment_ids=seg,
                                logits_soft_cap=cfg.logits_soft_cap, mesh=mesh)
 
-    x, kv = _layers(params, cfg, x, positions[None], kv_cache, write, read, ctx)
-    last = x[0, jnp.clip(last_idx, 0, tokens.shape[0] - 1)]  # [N, d]
-    return _lm_logits(params, cfg, last, ctx), kv  # [N, v]
+    return _pack(params, cfg, tokens, positions, last_idx, kv_cache,
+                 (_write_pages(pack_pages, kv_cache), read), step, ctx, mesh)
 
 
 def prefill_packed_ctx(
@@ -236,6 +303,7 @@ def prefill_packed_ctx(
     ctx_tables,  # [N, P] int32 — block table per segment (-1 pad)
     ctx_lens,  # [N] int32 — cached-context length per segment
     kv_cache, ctx=None, mesh=None, dp: int = 1, seq_shards: int = 1,
+    step=None,  # as prefill_packed's
 ):
     """``prefill_packed`` generalized to token SUFFIXES: each segment starts
     at a per-sequence offset (``ctx_lens``) and attends over its cached pages
@@ -245,12 +313,10 @@ def prefill_packed_ctx(
     for speed).  Returns (logits [N, vocab], new caches); a ``last_idx`` row
     of -1 (mid-chunk) yields garbage logits the engine never consumes."""
     _dense_only(cfg, "model_runner.prefill_packed_ctx")
-    x = _embed(params, cfg, tokens, positions, 0)  # [1,T,d]
-    write = _write_pages(pack_pages, kv_cache)
     read = _read_ctx(cfg, segment_ids, ctx_tables, ctx_lens, ctx, mesh, dp, seq_shards)
-    x, kv = _layers(params, cfg, x, positions[None], kv_cache, write, read, ctx)
-    last = x[0, jnp.clip(last_idx, 0, tokens.shape[0] - 1)]  # [N, d]
-    return _lm_logits(params, cfg, last, ctx), kv  # [N, v]
+    return _pack(params, cfg, tokens, positions, last_idx, kv_cache,
+                 (_write_pages(pack_pages, kv_cache), read), step, ctx, mesh,
+                 dp, seq_shards)
 
 
 def verify_packed_ctx(
@@ -299,18 +365,8 @@ def decode_step(
     """One batched decode tick: returns (logits [B, v], new caches)."""
     _dense_only(cfg, "model_runner.decode_step")
     x = _embed(params, cfg, tokens, seq_lens, 1)  # [B,1,d]
-
-    def write(kv_l, k, v):
-        return (write_decode_kv(kv_l[0], k[:, 0], block_tables, seq_lens, active),
-                write_decode_kv(kv_l[1], v[:, 0], block_tables, seq_lens, active))
-
-    def read(q, k, v, kv_l):
-        # length 0 = no row in this slot: the kernel skips it
-        return paged_attention_decode(
-            q[:, 0], *kv_l, block_tables, jnp.where(active, seq_lens + 1, 0),
-            logits_soft_cap=cfg.logits_soft_cap, mesh=mesh, dp=dp,
-            seq_shards=seq_shards)
-
+    write, read = _step_seam(cfg, seq_lens, block_tables, active, mesh, dp,
+                             seq_shards, lambda a: a[:, 0])
     x, kv = _layers(params, cfg, x, seq_lens[:, None], kv_cache, write, read, ctx)
     return _lm_logits(params, cfg, x[:, 0], ctx), kv
 
@@ -323,6 +379,7 @@ class DenseRunner:
 
     counters = ()  # ``stats`` keys this kind adds
     packs_are_one_program = False  # a cold pack has a program of its own
+    packs_carry_step = True  # a pack takes the tick's decode rows (``step=``)
     scoped_programs = False  # no named scope a trace reader looks up
     prefill_packed = staticmethod(prefill_packed)
     prefill_packed_ctx = staticmethod(prefill_packed_ctx)

@@ -22,8 +22,11 @@ The FastGen serve-loop analogue (reference ``mii``/DeepSpeed-FastGen blog +
 * **Decode** runs one batched tick over the scheduler's running set only.
   When page growth finds the pool truly exhausted, the youngest running
   request is preempted by recompute.
-* **One ahead.**  An EXECUTION is one tick's programs on the device (a pack
-  and a step).  On an engine that can enqueue a program without fetching it
+* **One ahead.**  An EXECUTION is one tick's work on the device: a pack and
+  a step, which are ONE program where the engine's packs carry the step's
+  rows (``packs_carry_step``: the last pack of the execution takes them, and
+  a weight crosses HBM once a tick) and two programs elsewhere.  On an
+  engine that can enqueue a program without fetching it
   (``decode_dispatch`` / ``prefill_dispatch``), call k of ``tick()`` runs
   expire and admission, builds and ENQUEUES execution k + 1 from what the
   host knows without execution k's tokens (positions, pages and counts are
@@ -211,7 +214,8 @@ class _Drain(Exception):
 
 class _Execution:
     """One tick's programs, enqueued and not collected: the engine's handles
-    (``packs``, ``step``) and the requests riding the step."""
+    (``packs``, ``step``) and the requests riding the step (``step`` is None
+    where the last pack carried their rows)."""
 
     __slots__ = ("packs", "step", "decoding")
 
@@ -1729,19 +1733,24 @@ class ServeScheduler:
         ex = _Execution()
         samp = self._base_sampling()
         tel, track = self.telemetry, self._eng_ns
+        step = [mgr.seqs[r.uid] for r in decoding]
+        # ONE program where the engine's packs take the step's rows
+        carried = bool(entries and step
+                       and getattr(eng, "packs_carry_step", False))
         try:
             if entries:
                 with tel.span("sched.prefill", track=track):
                     clock = self.telemetry.clock
                     t0 = clock()
-                    ex.packs = eng.prefill_dispatch(entries, samp, ahead=ahead)
+                    ex.packs = eng.prefill_dispatch(
+                        entries, samp, ahead=ahead, **({"step": step} if carried else {}))
+                    if carried:
+                        ex.decoding = decoding
                     self._note_chunks(entries, t0, clock(), tick)
-            if decoding:
+            if decoding and not carried:
                 with tel.span("sched.decode", track=track, batch=len(decoding)):
                     ex.decoding = decoding
-                    ex.step = eng.decode_dispatch(
-                        [mgr.seqs[r.uid] for r in decoding], samp,
-                        split=True, ahead=ahead)
+                    ex.step = eng.decode_dispatch(step, samp, split=True, ahead=ahead)
         except Exception as e:  # noqa: BLE001 — retried where it is isolated
             if is_compile_error(e):
                 raise  # same for every request: stop the serve loop
@@ -1766,11 +1775,15 @@ class ServeScheduler:
 
         if ex.packs:
             first: Dict[int, int] = {}
+            toks: Dict[int, int] = {}  # of the step the last pack carried
             with tel.span("sched.prefill", track=track):
                 for pack in ex.packs:
-                    eng.pack_collect(pack, first, dead=ended(
-                        s.uid for s, _, _ in pack.rows))
+                    toks.update(eng.pack_collect(pack, first, dead=ended(
+                        [s.uid for s, _, _ in pack.rows] + [s.uid for s in pack.step])))
                 out.update(self._book_first(first))
+                if ex.step is None and ex.decoding:
+                    out.update(self._book_runs(
+                        ex.decoding, {u: [t] for u, t in toks.items()}))
         if ex.step is not None:
             with tel.span("sched.decode", track=track, batch=len(ex.decoding)):
                 toks = eng.decode_collect(

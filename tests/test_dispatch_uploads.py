@@ -1,7 +1,8 @@
 """A serving dispatch hands the device ONE host array and no key (PR 40).
 
 The key lives on the device (every program splits it inside and hands the
-carried key back), a decode tick's rows and a pack's seven arrays are one
+carried key back), a decode tick's rows and a pack's seven arrays (with the
+step's rows behind them where the pack carries the tick's step, PR 54) are one
 int32 buffer each, a dirty block table is a plain transfer, and
 ``stats["dispatch_uploads"]`` counts every host array a dispatch body hands
 over.  CPU, tiny sizes: what is called, what is counted, which tokens."""
@@ -122,35 +123,44 @@ def test_dispatch_uploads_counts_one_a_tick_one_a_pack_one_a_dirty_table(tiny):
 
 
 # -- (c) the pack's layout -----------------------------------------------------
-@pytest.mark.parametrize("t_pad,bs,slots,pages,ctx", [
-    (24, 8, 3, 5, True), (24, 8, 3, 5, False), (40, 4, 5, 7, True),
-    (48, 8, 4, 9, True),   # serve_replicas 2: two chunks of 24
-    (256, 32, 64, 128, True), (256, 32, 64, 128, False)])
-def test_a_pack_round_trips_through_its_one_buffer(t_pad, bs, slots, pages, ctx):
+@pytest.mark.parametrize("t_pad,bs,slots,pages,ctx,step", [
+    (24, 8, 3, 5, True, False), (24, 8, 3, 5, False, False), (40, 4, 5, 7, True, False),
+    (48, 8, 4, 9, True, False),   # serve_replicas 2: two chunks of 24
+    (256, 32, 64, 128, True, False), (256, 32, 64, 128, False, False),
+    # a pack that carries the tick's step (PR 54): the tables for a cold pack
+    # too, and the step's [4, slots] rows last
+    (24, 8, 3, 5, False, True), (256, 32, 64, 128, True, True),
+    (256, 32, 64, 128, False, True)])
+def test_a_pack_round_trips_through_its_one_buffer(t_pad, bs, slots, pages, ctx, step):
     """``new_pack``'s views tile the buffer without overlap, and the traced
     ``unpack_pack`` hands back what the host wrote, shapes and all."""
-    buf, views = new_pack(t_pad, bs, slots, pages, ctx)
+    buf, views = new_pack(t_pad, bs, slots, pages, ctx, step)
     names = ["tokens", "seg", "pos", "pack_pages", "last_idx"] + (
-        ["ctx_tables", "ctx_lens"] if ctx else [])
+        ["ctx_tables"] if ctx or step else []) + (["ctx_lens"] if ctx else []) + (
+        ["step_rows"] if step else [])
     shapes = [(t_pad,)] * 3 + [(t_pad // bs,), (slots,)] + (
-        [(slots, pages), (slots,)] if ctx else [])
+        [(slots, pages)] if ctx or step else []) + ([(slots,)] if ctx else []) + (
+        [(4, slots)] if step else [])
     assert [v.shape for v in views] == shapes and buf.dtype == np.int32
     assert sum(v.size for v in views) == buf.size
     assert all(np.shares_memory(v, buf) for v in views)
     empty = dict(zip(names, views))
-    for name in names:  # nothing to run: no token, page, sampled row or context
+    for name in names:  # nothing to run: no token, page, sampled row, context or live slot
         want = -1 if name in ("pack_pages", "last_idx", "ctx_tables") else 0
         assert (empty[name] == want).all(), name
     rng = np.random.default_rng(t_pad + pages)
     wrote = [rng.integers(-1, 1 << 20, v.shape).astype(np.int32) for v in views]
     for v, w in zip(views, wrote):
         v[...] = w
-    got = jax.jit(lambda b: unpack_pack(b, bs, slots, pages, ctx))(buf)
+    got = jax.jit(lambda b: unpack_pack(b, bs, slots, pages, ctx, step))(buf)
     assert len(got) == len(wrote)
     for name, g, w in zip(names, got, wrote):
         assert g.shape == w.shape and (np.asarray(g) == w).all(), name
     with pytest.raises(ValueError, match="no pack of"):
-        unpack_pack(buf[:-1], bs, slots, pages, ctx)
+        unpack_pack(buf[:-1], bs, slots, pages, ctx, step)
+    if step:  # ... and a buffer of one form is no buffer of the other
+        with pytest.raises(ValueError, match="no pack of"):
+            unpack_pack(buf, bs, slots, pages, ctx, False)
 
 
 # -- (d) greedy tokens are the parent's ---------------------------------------
@@ -177,8 +187,13 @@ def test_greedy_tokens_are_the_parents(kind, tiny):
                                 prefill_buckets=(32,), prefill_chunk=32, max_seq_len=256)
     got = _serve(eng, _prompts(min(cfg.vocab_size, 250)), SamplingParams(max_new_tokens=10))
     assert got == PARENT_GREEDY[kind]
+    # one upload a PROGRAM: a pack that carried a step (PR 54, the dense
+    # runner) counts as a pack and as a tick and hands over one buffer
+    mixed = eng.stats["mixed_dispatches"]
+    assert (mixed > 0) == (kind == "dense")
     assert eng.stats["dispatch_uploads"] == (
-        eng.stats["decode_ticks"] + eng.stats["prefill_dispatches"] + eng.stats["table_uploads"])
+        eng.stats["decode_ticks"] + eng.stats["prefill_dispatches"] - mixed
+        + eng.stats["table_uploads"])
     assert not any(eng.close().values())
 
 

@@ -839,11 +839,19 @@ def test_every_decode_tick_hangs_under_sched_decode_under_sched_tick(plain_serve
     by_id, ancestors = _tree(events)
     assert len(by_id) == len(events)  # ids are unique
     ticks = [e for e in events if e["name"] == "decode_tick"]
-    assert len(ticks) == eng.stats["decode_ticks"] > 0
+    # a step that a pack carried (PR 54: the chunks of request 2 while request
+    # 1 decodes) is that pack's span, under sched.prefill, with its rows on it
+    carried = [e for e in events if e["name"] == "prefill_pack" and e["args"]["step_rows"]]
+    assert len(carried) == eng.stats["mixed_dispatches"] > 0
+    assert len(ticks) == eng.stats["decode_ticks"] - len(carried) > 0
+    assert sum(e["args"]["batch"] for e in ticks) + sum(
+        e["args"]["step_rows"] for e in carried) == eng.stats["decode_emitted"]
     for e in ticks:
         names = [a["name"] for a in ancestors(e)]
         assert names == ["sched.decode", "sched.tick"], names
         assert e["args"]["ctx_tokens"] >= e["args"]["batch"] >= 1
+    for e in carried:
+        assert e["args"]["ctx_tokens"] >= e["args"]["step_rows"]
     for name, parent in (("engine.decode_build", "sched.decode"),
                          ("engine.decode_emit", "sched.decode"),
                          ("engine.pack_build", "sched.prefill"),
@@ -891,7 +899,9 @@ def test_children_lie_inside_parents_and_self_time_is_not_negative(plain_serve):
             assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3
                        for a, b in zip(kids, kids[1:]))
             halves.append((d["args"]["parent_id"], names[0]))
-    assert sum(n == "tick_collect" for _, n in halves) == eng.stats["decode_ticks"]
+    # (a step that a pack carried is collected in the pack's phase: PR 54)
+    assert sum(n == "tick_collect" for _, n in halves) == (
+        eng.stats["decode_ticks"] - eng.stats["mixed_dispatches"])
     by_tick = {}
     for tick, first in halves:
         by_tick.setdefault(tick, []).append(first)
