@@ -22,6 +22,7 @@ released slot gives back, what ``close()`` audits).
 """
 from __future__ import annotations
 
+import logging
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -40,6 +41,9 @@ from .sampling import SamplingParams, finite_guard, sample
 # distinct from the -1 finite_guard poison sentinel (which is a real
 # emission — always a row's LAST — that the host must see to quarantine)
 _BURST_PAD = -2
+# a ``tick_collect`` longer than this is logged with its ``ready`` split: the
+# stalls of seconds name their phase in every run's output, traced or not
+STALL_LOG_S = 1.0
 
 
 def _bucket(n: int, buckets: Sequence[int]) -> int:
@@ -108,14 +112,15 @@ class Enqueued:
     ``step``: the sequences of the tick's step a pack carried (none: the pack
     alone); ``sampled``: the device's ``[slots]`` tokens while nobody has
     fetched them (a split dispatch), ``tokens`` once somebody has; ``split``:
-    the fetch is not inside the dispatch span."""
+    the fetch is not inside the dispatch span; ``by``: the dispatch span
+    itself (``NULL_SPAN`` with telemetry off), whose id its collect names."""
 
-    __slots__ = ("span", "rows", "finishing", "step", "split", "sampled", "tokens")
+    __slots__ = ("span", "rows", "finishing", "step", "split", "sampled", "tokens", "by")
 
     def __init__(self, span: str, rows: List, finishing: Optional[List],
-                 split: bool, step: Sequence = ()):
+                 split: bool, by, step: Sequence = ()):
         self.span, self.rows, self.finishing = span, rows, finishing
-        self.step, self.split = list(step), split
+        self.step, self.split, self.by = list(step), split, by
         self.sampled = self.tokens = None
 
 
@@ -494,7 +499,7 @@ class InferenceEngineV2:
         self._h = {
             k: reg.histogram(f"{self._ns}/{k}")
             for k in ("prefill_pack_ms", "decode_tick_ms", "spec_tick_ms",
-                      "tp_allreduce_ms")
+                      "collect_wait_ms", "tp_allreduce_ms")
         }
         # eagerly register this engine's request-latency group so the
         # namespace's histograms exist (empty) before any request arrives
@@ -1231,7 +1236,7 @@ class InferenceEngineV2:
             self._account_comm(t_pad, sample_rows=n_slots, ring=use_ctx)
             if compaction is not None:
                 self._close_windows((s, end) for s, _, end in entries)
-            done = Enqueued("prefill_pack", list(entries), finishing, split, step)
+            done = Enqueued("prefill_pack", list(entries), finishing, split, sp, step)
             fetched = finishing or step  # (intermediate chunks alone: nothing is)
             if fetched and not split:
                 # host-complete: this fetch syncs the pack
@@ -1266,12 +1271,26 @@ class InferenceEngineV2:
     def _fetched(self, done: "Enqueued"):
         """The tokens of ``done``, waiting for its program where they were
         not fetched inside its dispatch span (``tick_collect``: the wait and
-        the fetch, named after what it collects)."""
+        the fetch, named after what it collects, ``of`` the dispatch span
+        that enqueued it; its ``ready`` mark is where the runtime called the
+        program's result defined, what follows is the copy landing and this
+        thread taking it)."""
         if done.sampled is not None:
+            of = {} if done.by.id is None else {"of": done.by.id}
             with self.telemetry.span("tick_collect", track=self._ns,
-                                     what=done.span):
+                                     hist=self._h["collect_wait_ms"],
+                                     what=done.span, **of) as sp:
+                # the tick's ONE wait, split from its fetch by the mark
+                done.sampled.block_until_ready()  # lint: allow(host-sync)
+                ready = sp.mark("ready")
                 done.tokens = np.asarray(done.sampled)
             done.sampled = None
+            if (sp.duration_ms or 0.0) > 1e3 * STALL_LOG_S:
+                log_dist(
+                    f"tick_collect of {done.span} #{done.by.id}: "
+                    f"{sp.duration_ms:.1f} ms, ready after "
+                    f"{(ready - sp.t0) * 1e3:.1f} ms",
+                    ranks=[-1], level=logging.WARNING)
         return done.tokens
 
     def _dropped(self, s, done: "Enqueued", dead) -> bool:
@@ -1831,7 +1850,7 @@ class InferenceEngineV2:
             self._c["decode_emitted"].inc(len(active_seqs))
             self._c["dispatched_ahead"].inc(int(ahead))
             self._account_comm(B)
-            done = Enqueued("decode_tick", list(active_seqs), None, split)
+            done = Enqueued("decode_tick", list(active_seqs), None, split, sp)
             if split:
                 # the fetch is the collect's: the span ends at the enqueue
                 sp.end(sync_obj=sampled)
