@@ -120,7 +120,9 @@ def serve_jit_specs(eng, sampling=None) -> Dict[str, dict]:
     )
 
     # a pack's ONE buffer, as the dispatch site lays it out (with the step's
-    # rows behind it where the engine's packs carry the tick's step)
+    # rows behind it where the engine's packs carry the tick's step: a dense
+    # engine's and, PR 56, the one pack program of a ``cfg.latent`` engine
+    # whose runner says ``packs_carry_step`` alike)
     from ..inference.engine_v2 import new_pack
 
     step = eng.packs_carry_step

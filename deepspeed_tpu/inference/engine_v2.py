@@ -1208,9 +1208,16 @@ class InferenceEngineV2:
             n_slots = self.mgr.max_seqs  # logits rows a pack dispatch scores
             extra = self.runner.dispatched(
                 self._c, ((s.slot, start, end) for s, start, end in entries), pack=True,
-                tokens=t_pad)
+                tokens=t_pad, carried=n_slots if carries else 0)
             if carries:
                 extra.update(step_rows=len(step), ctx_tokens=step_ctx)
+            if step:
+                # the step's rows as ``decode_dispatch`` counts them (the runner's
+                # host mirrors follow the rows; the program's rows are counted
+                # above, once), their span arguments apart from the pack's own
+                rode = self.runner.dispatched(
+                    self._c, ((s.slot, s.cur_len - 1, s.cur_len) for s in step))
+                extra.update({f"step_{k}": v for k, v in rode.items()})
         finishing = [s for s, _, end in entries if end == len(s.tokens)]
         with tel.span(
             "prefill_pack", track=ns, hist=self._h["prefill_pack_ms"],
@@ -1235,7 +1242,9 @@ class InferenceEngineV2:
                 self._c["decode_emitted"].inc(len(step))
             self._account_comm(t_pad, sample_rows=n_slots, ring=use_ctx)
             if compaction is not None:
-                self._close_windows((s, end) for s, _, end in entries)
+                # (a step's row that fills its window gives the pages back as a tick's)
+                self._close_windows([(s, end) for s, _, end in entries]
+                                    + [(s, s.cur_len) for s in step])
             done = Enqueued("prefill_pack", list(entries), finishing, split, sp, step)
             fetched = finishing or step  # (intermediate chunks alone: nothing is)
             if fetched and not split:

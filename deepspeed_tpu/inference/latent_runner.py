@@ -86,7 +86,26 @@ One layer body (``_layer``) serves the pack and the tick; the kind chooses how
 the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
 as are chunks of one prompt and several prompts in one pack: work is laid out
-in groups of one page of one sequence.  Plain XLA bodies
+in groups of one page of one sequence.
+
+A pack CARRIES THE TICK'S STEP (``prefill_pack(..., step=)``, PR 56;
+``model_runner._pack``'s contract): the pack's T token rows and the step's B
+slot rows are ONE ``[T + B, d]`` operand through every norm, projection,
+router and ONE head matmul, and every held-expert layer is ONE layout and ONE
+set of grouped products over both (on the PACK's row tile, counted on the
+pack's side: ``_row_tile``, ``_experts``), so a tick streams the weights
+once.  Each kind of row keeps its own cache write and its own attention or
+recurrence: ``_carrying`` sends rows ``[:T]`` through the pack's seam and rows
+``[T:]`` through the tick's, the cache arrays threaded pack first, then step.
+The scheduler never puts a sequence in both, so slots, states, rings, pages
+and table rows are disjoint and the program computes what the two computed in
+turn.  The step's bodies take sibling scope names there (``full_attn_step``
+beside ``full_attn``: ``la.carried_step``).  What still runs a kind of row: a
+recurrence's own projections, which live inside its ``write`` (``(w, h)``).
+Without ``step`` the entries trace what they always traced.  The entry takes a
+step for every family; which families' ENGINES hand it one is the runner's
+``packs_carry_step`` (``LatentRunner.__init__``: the two-norm blocks that keep
+no recurrence's state).  Plain XLA bodies
 (``ops/latent_attention.py``) but for five Pallas kernels on the chip: a
 pack's index scores (``ops/pallas/index_scores.py``), its shorter groups'
 attention over their picks (``ops/pallas/selected_attention.py``: an ``every``
@@ -110,7 +129,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import latent as lm
-from ..moe.layer import held_rows_a_pass
+from ..moe.layer import held_row_tile, held_rows_a_pass
 from ..ops import latent_attention as la
 from ..ops.pallas import index_scores as index_kernel
 from ..ops.pallas import latent_decode as decode_kernel
@@ -525,15 +544,19 @@ def _index_kernel_takes(c: int, j: int, d: int, bs: int) -> bool:
                          "queries, page and head size must be whole 128-lane tiles")
 
 
-def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, probe):
+def _layer(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, probe):
     """One layer on token rows ``x`` [T, d].  The seam: ``write(kind, arrays,
     rows)`` returns the kind's cache arrays with the new rows in, ``read(kind,
-    arrays, queries)`` attends over them.  Returns (x, cache)."""
+    arrays, queries)`` attends over them.  ``pack_rows``: how many of the rows,
+    the first, are a PACK's tokens (0: a tick's slot rows alone; fewer than T: the
+    tick's ride behind them); the expert layers count on the pack's side where
+    there is one (``_experts``) and lay out for its rows (``_row_tile``).
+    Returns (x, cache)."""
     s = cfg.latent
     if s.single:
-        return _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe)
+        return _block(cfg, l, layers, x, valid, cache, write, read, pack_rows, probe)
     if s.hybrid:
-        return _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_groups,
+        return _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows,
                              probe)
     kind, (n1, n2), aw, fw, is_moe = lm.layer_params(layers, l, s)
     a, i = s.attn(kind), s.layer_kinds[:l].count(kind)
@@ -554,16 +577,28 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, prob
     x = x + lm.attn_output(aw, o, gate, a, values=every).astype(x.dtype)
     h = lm.rms(x, n2["scale"], cfg.norm_eps)
     if is_moe and "touched" in cache:
-        y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, track_groups, probe)
+        y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, pack_rows, probe)
         return x + y.astype(x.dtype), cache
-    y, routing = lm.ffn(fw, h, is_moe, cfg, valid)
+    y, routing = lm.ffn(fw, h, is_moe, cfg, valid, _row_tile(cfg, h.shape[0], pack_rows))
     if routing is not None:
         routed, picked, _ = routing
         if probe is not None:
             probe.append({"experts_picked": picked})
         cache = {**cache, "stats": _routing_counted(
-            cache["stats"], l - s.first_dense, routed, track_groups)}
+            cache["stats"], l - s.first_dense, routed, pack_rows > 0)}
     return x + y.astype(x.dtype), cache
+
+
+def _row_tile(cfg, rows: int, pack_rows: int):
+    """The row tile an expert layer lays ``rows`` rows out on: its own rule at
+    its rows (None) for a pack or a tick alone.  Behind a pack's ``pack_rows``
+    tokens a tick's slot rows change what a group EXPECTS by a sixteenth at most
+    and are live only in part, so the groups keep the PACK's tile: 544 rows of
+    cell 8 (17 expected, twice that past 32) would take 64-row tiles for the
+    pack's 32 and lay out 20 736 rows for 12 288, which costs every op around
+    the products (``moe/layer.py:held_row_tile``).  A group that outgrows its
+    tile takes another, as ever."""
+    return held_row_tile(pack_rows, cfg.latent) if 0 < pack_rows < rows else None
 
 
 def _routing_counted(st, m: int, routed, track_groups: bool):
@@ -574,7 +609,7 @@ def _routing_counted(st, m: int, routed, track_groups: bool):
     return st.at[m].set(new)
 
 
-def _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe):
+def _block(cfg, l, layers, x, valid, cache, write, read, pack_rows, probe):
     """One single-mixer block on token rows ``x`` [T, d], through the same
     seam: a state-space block's ``write`` IS its read (the scan that carries
     the state on yields the outputs: (state, conv tail, y)), an attention
@@ -589,11 +624,10 @@ def _block(cfg, l, layers, x, valid, cache, write, read, track_groups, probe):
         q, k, v = lm.gqa_inputs(w, h, s.gqa)
         pools = write(kind, (cache["k"][i], cache["v"][i]), (k, v))
         cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
-        with jax.named_scope("gqa_attn"):
-            o = read(kind, pools, (q, k, v))
+        o = read(kind, pools, (q, k, v))  # (under ``gqa_attn``: ``_scoped``)
         y = o.reshape(x.shape[0], -1).astype(x.dtype) @ w["wo"]
     else:
-        y, cache = _experts(cfg, i, w, h, valid, cache, track_groups, probe)
+        y, cache = _experts(cfg, i, w, h, valid, cache, pack_rows, probe)
     return x + y.astype(x.dtype), cache
 
 
@@ -604,22 +638,26 @@ def _recurrence(kind, i, w, h, cache, write):
     return y, {**cache, "ssm": _put(cache["ssm"], i, ssm), "conv": _put(cache["conv"], i, conv)}
 
 
-def _experts(cfg, i, w, h, valid, cache, track_groups, probe):
+def _experts(cfg, i, w, h, valid, cache, pack_rows, probe):
     """Expert layer ``i`` on normed rows ``h``: (y, cache with its routing and
-    the held experts it touched counted, a pack's and a tick's apart)."""
+    the held experts it touched counted, a pack's and a tick's apart).  A pack
+    that carries a tick's rows is ONE layout and ONE set of products, counted on
+    the PACK's side whole: an expert that both kinds of row touch is read once
+    and counted once (the ``any`` over all the rows), as a reader that divides
+    the packs' touched experts by the pack program's time needs it."""
     s = cfg.latent
-    y, (routed, picked, _) = lm.ffn(w, h, True, cfg, valid)
+    y, (routed, picked, _) = lm.ffn(w, h, True, cfg, valid, _row_tile(cfg, h.shape[0], pack_rows))
     if probe is not None:
         probe.append({"experts_picked": picked})
     local = picked - s.held_offset
     rows = (local[..., None] == jnp.arange(s.n_held)) & valid[:, None, None]
     return y, {**cache,
-               "stats": _routing_counted(cache["stats"], i, routed, track_groups),
-               "touched": cache["touched"].at[i, 0 if track_groups else 1].add(jnp.stack(
+               "stats": _routing_counted(cache["stats"], i, routed, pack_rows > 0),
+               "touched": cache["touched"].at[i, 0 if pack_rows else 1].add(jnp.stack(
                    [jnp.sum(jnp.any(rows, axis=(0, 1)), dtype=jnp.int32), routed[1]]))}
 
 
-def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_groups, probe):
+def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, probe):
     """One two-norm block (``models/latent.py:HYBRID``) on token rows ``x``
     [T, d], through ``_block``'s seam: a Gated DeltaNet mixer's ``write`` IS its
     read, gated attention writes K / V rows (the keys normed and rotated) and
@@ -639,8 +677,7 @@ def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_group
         pools = write(kind, (cache["k"][mine], cache["v"][mine]), (k, v, mw["phi"], mw["mu"]))
         cache = {**cache, "k": cache["k"][:mine.start] + pools[0] + cache["k"][mine.stop:],
                  "v": cache["v"][:mine.start] + pools[1] + cache["v"][mine.stop:]}
-        with jax.named_scope("eva_attend"):
-            o = read(kind, pools, (q, k, v))
+        o = read(kind, pools, (q, k, v))  # (under ``eva_attend``: ``_scoped``)
         if probe is not None and i == 0:
             # the first layer's rows as attention took them and what it made of them
             probe.append({"eva_q": q, "eva_k": k, "eva_v": v, "eva_o": o})
@@ -651,13 +688,12 @@ def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, track_group
         kk, kv = ("wk", "wv") if kind == "wattn" else ("k", "v")
         pools = write(kind, (cache[kk][i], cache[kv][i]), (k, v))
         cache = {**cache, kk: _put(cache[kk], i, pools[0]), kv: _put(cache[kv], i, pools[1])}
-        with jax.named_scope(_attn_scope(s, kind)):
-            o = read(kind, pools, (q, k, v))
+        o = read(kind, pools, (q, k, v))  # (under ``_attn_scope``'s name: ``_scoped``)
         y = lm.gattn_output(mw, o.astype(x.dtype), gate)
     x = x + y.astype(x.dtype)
     h = lm.norm(x, n2, cfg)
     if is_moe:
-        y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, track_groups, probe)
+        y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, pack_rows, probe)
     else:
         y = lm.ffn(fw, h, False, cfg)[0]
     return x + y.astype(x.dtype), cache
@@ -670,6 +706,61 @@ def _attn_scope(s, kind: str) -> str:
     if s.wattn is None:
         return "gated_attn"
     return "window_attn" if kind == "wattn" else "full_attn"
+
+
+def _scoped(s, read):
+    """A seam's ``read`` under the named scope of its kind's attention, where the
+    blocks name it (``gqa_attn``, ``eva_attend``, ``_attn_scope``; the latent
+    kinds' bodies name themselves, ``ops/latent_attention.py``).  Opened through
+    ``la.scope``, so the step a pack carries has ``<name>_step`` beside it."""
+    def scoped(kind, arrays, queries):
+        name = {"gqa": "gqa_attn", "eva": "eva_attend"}.get(kind) or (
+            _attn_scope(s, kind) if kind in ("gattn", "wattn") else None)
+        if name is None:
+            return read(kind, arrays, queries)
+        with la.scope(name):
+            return read(kind, arrays, queries)
+
+    return scoped
+
+
+# the operands a seam is handed that are the layer's WEIGHTS and no row's, by kind:
+# positions in ``write``'s tuple and in ``read``'s (all else is [rows, ...]): a
+# recurrence's (w, h), EVA's (k, v, phi, mu), the queries (q, w_uk, w_uv) of ``every``
+_WEIGHTS = {"mamba": ((0,), ()), "gdn": ((0,), ()), "eva": ((2, 3), ()), "every": ((), (1, 2))}
+
+
+def _carrying(t: int, pack, tick):
+    """The seam of a pack that carries the tick's step: rows ``[:t]`` are the
+    pack's tokens and go through ``pack`` = its (write, read), rows ``[t:]`` the
+    tick's slot rows through ``tick``'s, the cache arrays threaded pack first,
+    then step (the two programs' order), the outputs concatenated.  The two
+    kinds hold disjoint sequences (the scheduler's contract; ``pack_dispatch``
+    raises otherwise), hence disjoint slots, states, rings, pages and table rows:
+    what this computes is what the pack and then the step computed in turn.  A
+    recurrence's ``write`` IS its read and returns (state, conv tail, y): the
+    pack's scan and the step's update touch different slots' states, ``y`` is
+    concatenated.  The step's bodies are traced inside ``la.carried_step()``."""
+    def split(kind, which, operands):
+        shared = _WEIGHTS.get(kind, ((), ()))[which]
+        return [tuple(a if i in shared else a[rows] for i, a in enumerate(operands))
+                for rows in (slice(None, t), slice(t, None))]
+
+    def write(kind, arrays, new):
+        ours, theirs = split(kind, 0, new)
+        n = len(arrays)
+        out = pack[0](kind, arrays, ours)
+        with la.carried_step():
+            step = tick[0](kind, tuple(out[:n]), theirs)
+        return (*step[:n], *(jnp.concatenate(ys) for ys in zip(out[n:], step[n:])))
+
+    def read(kind, arrays, queries):
+        ours, theirs = split(kind, 1, queries)
+        o = pack[1](kind, arrays, ours)
+        with la.carried_step():
+            return jnp.concatenate([o, tick[1](kind, arrays, theirs)])
+
+    return write, read
 
 
 def _fit(rows, pages):
@@ -686,29 +777,59 @@ def _logits(params, cfg, x):
     return lm.head_logits(x, params["lm_head"]["kernel"], cfg).astype(jnp.float32)
 
 
+def _seams(cfg):
+    """(a pack's seam, a tick's) of the model's family."""
+    if cfg.latent.eva is not None:
+        return _eva_pack_seam, _eva_tick_seam
+    if cfg.latent.stateful:
+        return _state_pack_seam, _state_tick_seam
+    return _latent_pack_seam, _latent_tick_seam
+
+
 def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_idx,
-                 tables, cache: Cache, probe=None):
+                 tables, cache: Cache, probe=None, step=None):
     """One prefill pack (the arguments of ``model_runner.prefill_packed_ctx``
     less ``ctx_lens``: a token's position says where its context ends).
     ``tables`` [N, P] are the block tables by slot, this pack's pages included.
     ``probe`` (a list) collects, layer by layer, what the indexers and the
     routers picked, what a state-space block's recurrence consumed, what a
     window's mask let each query see, and the first EVA layer's rows and output.
-    Returns (logits [N, vocab], cache)."""
+    Returns (logits [N, vocab], cache).
+
+    With ``step`` (the tick's decode rows as ``decode_step`` takes them: tokens,
+    positions, block tables, live mask, a row a slot) the step's B rows follow
+    the pack's T through every norm, projection, router and grouped product and
+    ONE head matmul: ONE stream of the weights.  Each kind of row keeps its own
+    cache write and its own attention or recurrence (``_carrying``), and the
+    result is ((pack logits [N, vocab], step logits [B, vocab]), cache):
+    ``model_runner._pack``'s contract."""
     t = tokens.shape[0]
     valid = segment_ids > 0
     picked: list = []
-    seam = _eva_pack_seam if cfg.latent.eva is not None else \
-        _state_pack_seam if cfg.latent.stateful else _latent_pack_seam
-    write, read = seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache,
-                       picked, probe)
+    pack_seam, tick_seam = _seams(cfg)
+    write, read = pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache,
+                            picked, probe)
+    read = _scoped(cfg.latent, read)
+    if step is not None:
+        s_tokens, s_pos, s_tables, s_active = step
+        stepped: list = []  # what the step's selectors took, a full layer
+        tick = tick_seam(cfg, s_pos, s_tables, s_active, stepped, probe)
+        write, read = _carrying(t, (write, read), (tick[0], _scoped(cfg.latent, tick[1])))
+        tokens, positions, valid = (jnp.concatenate(a) for a in (
+            (tokens, s_tokens), (positions, s_pos), (valid, s_active)))
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
     for l in range(cfg.num_layers):
         x, cache = _layer(cfg, l, params["layers"], x, positions, valid, cache,
-                          write, read, True, probe)
+                          write, read, t, probe)
     if picked:
         cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
-    return _logits(params, cfg, x[jnp.clip(last_idx, 0, t - 1)]), cache
+    last = x[jnp.clip(last_idx, 0, t - 1)]
+    if step is None:
+        return _logits(params, cfg, last), cache
+    if stepped:  # (tallied apart, as by the step's own program: each count under 2^30)
+        cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(stepped))}
+    logits = _logits(params, cfg, jnp.concatenate([last, x[t:]]))
+    return (logits[:last.shape[0]], logits[last.shape[0]:]), cache
 
 
 def _latent_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache, picked,
@@ -955,7 +1076,7 @@ def _eva_tick_seam(cfg, pos, block_tables, active, picked, probe):
         kp, vp = (tuple(write_decode_kv(a, r, block_tables, at, active)
                         for a, r in zip(pool, _head_pools(x, n))) for pool, x in ((kp, k), (vp, v)))
         hp = k.shape[1] // n
-        with jax.named_scope("eva_summarise"):
+        with la.scope("eva_summarise"):
             ks, vs = zip(*(eva.summarise(chunk_of(a), chunk_of(c), phi[j * hp:(j + 1) * hp],
                                          mu[j * hp:(j + 1) * hp])
                            for j, (a, c) in enumerate(zip(kp, vp))))
@@ -997,13 +1118,12 @@ def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cach
     """One batched decode tick (``model_runner.decode_step``'s arguments).
     Returns (logits [B, vocab], cache)."""
     picked: list = []
-    seam = _eva_tick_seam if cfg.latent.eva is not None else \
-        _state_tick_seam if cfg.latent.stateful else _latent_tick_seam
-    write, read = seam(cfg, seq_lens, block_tables, active, picked, probe)
+    write, read = _seams(cfg)[1](cfg, seq_lens, block_tables, active, picked, probe)
+    read = _scoped(cfg.latent, read)
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
     for l in range(cfg.num_layers):
         x, cache = _layer(cfg, l, params["layers"], x, seq_lens, active, cache,
-                          write, read, False, probe)
+                          write, read, 0, probe)
     if picked:
         cache = {**cache, "picks": _tally(cache["picks"], jnp.stack(picked))}
     return _logits(params, cfg, x), cache
@@ -1118,11 +1238,26 @@ class LatentRunner:
 
     counters = COUNTERS
     packs_are_one_program = True  # a pack reads its own rows back from the cache
-    packs_carry_step = False  # a pack and a step are two programs: state, rings, a compacting table
     scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn; full_attn
 
     def __init__(self, cfg):
         self.cfg = cfg
+        # A pack takes the tick's decode rows (``step=``): ONE stream of the weights
+        # and ONE grouped product an expert layer for both kinds of row, each kind
+        # keeping its own state, ring, page write and kernel (``_carrying``).  The
+        # entry carries a step for EVERY family (``tests/test_mixed_program.py``); the
+        # ENGINE is told so for the two-norm blocks that keep no recurrence's state
+        # (gated attention on pages and rings, EVA).  The others keep two programs,
+        # each for what the chip read (PERF.md §6, PR 56): a mixed program is a THIRD
+        # XLA program of the same bodies, a router's near tie may fall the other way
+        # in it, and the benchmark holds the scheduler's tokens to the UNMIXED replay:
+        # single-mixer blocks by 0.05 a token (read 0.2265 and 0.2042: not correct by
+        # the cell's own limit), Gated DeltaNet's by 0.2 (read 0.2663 on one seed of
+        # four), the selector's layers by 0.05 (read 0.0482, and the cell LOST 3.3%: a
+        # ~150 ms pack carries 16 rows' gathers); latent attention over every row
+        # gained nothing (-0.2%: its tick is a 123 ms pack).
+        s = cfg.latent
+        self.packs_carry_step = bool(getattr(s, "hybrid", False) and s.recurrence[1] is None)
         self._ring_rows = np.zeros(0, np.int64)
         self._block = 1
         self._expert_layers = 0
@@ -1157,10 +1292,10 @@ class LatentRunner:
 
     def prefill_packed_ctx(self, params, cfg, tokens, segment_ids, positions, pack_pages,
                            last_idx, ctx_tables, ctx_lens, kv_cache, ctx=None, mesh=None,
-                           dp: int = 1, seq_shards: int = 1):
+                           dp: int = 1, seq_shards: int = 1, step=None):
         _one_chip_only(ctx, mesh, dp, seq_shards)
         return prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages,
-                            last_idx, ctx_tables, kv_cache)
+                            last_idx, ctx_tables, kv_cache, step=step)
 
     def verify_packed_ctx(self, *args, **kw):
         lm.refuse("enable_speculation (verify_packed_ctx)", "a rejected draft's rows "
@@ -1172,11 +1307,14 @@ class LatentRunner:
         _one_chip_only(ctx, mesh, dp, seq_shards)
         return decode_step(params, cfg, tokens, seq_lens, block_tables, active, kv_cache)
 
-    def dispatched(self, counters, work, pack: bool = False, tokens: int = 0) -> Dict[str, int]:
+    def dispatched(self, counters, work, pack: bool = False, tokens: int = 0,
+                   carried: int = 0) -> Dict[str, int]:
         """What the selectors and windows are ASKED to do with queries at
         positions ``[start, end)`` of each (slot, start, end) of ``work``, all
         layers: the dispatch's span arguments.  ``tokens`` is the program's token
-        rows (a pack's padded tokens, a tick's slots), from which its expert
+        rows (a pack's padded tokens, a tick's slots; ``carried``: the slot rows a
+        pack's program holds behind them, whose own call passes no ``tokens``), from
+        which its expert
         layers lay out ``t k + g x tile`` rows each whatever the routing
         (``moe/layer.py:held_rows_a_pass``; ONE pass of a bounded layout, which
         no served shape reaches): ``expert_rows_laid_out`` beside the device's
@@ -1191,7 +1329,8 @@ class LatentRunner:
         cannot tell apart here)."""
         s = self.cfg.latent
         if tokens and self._expert_layers:
-            counters["expert_rows_laid_out"].inc(self._expert_layers * held_rows_a_pass(tokens, s))
+            counters["expert_rows_laid_out"].inc(self._expert_layers * held_rows_a_pass(
+                tokens + carried, s, held_row_tile(tokens, s) if carried else None))
         if self.compaction is not None:
             return self._eva_dispatched(counters, work, pack)
         if s.count("wattn"):

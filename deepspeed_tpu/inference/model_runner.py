@@ -394,10 +394,12 @@ class DenseRunner:
         return init_paged_cache(cfg.num_layers, num_blocks, block_size,
                                 cfg.num_kv_heads, cfg.hd, dtype=cfg.dtype)
 
-    def dispatched(self, counters, work, pack: bool = False, tokens: int = 0) -> Dict[str, int]:
+    def dispatched(self, counters, work, pack: bool = False, tokens: int = 0,
+                   carried: int = 0) -> Dict[str, int]:
         """Counts a dispatch (a prefill ``pack``, else a decode tick) over
         ``work`` = (slot, start, end) a sequence, in a program of ``tokens`` token
-        rows, into ``counters``; returns the dispatch span's extra arguments."""
+        rows (and ``carried`` slot rows of a step behind a pack's), into
+        ``counters``; returns the dispatch span's extra arguments."""
         return {}
 
     def released(self, seq) -> None:

@@ -855,7 +855,7 @@ def gdn_step(gw, h, active, conv_tail, state, gd: Gdn, eps: float, probe=None):
     from ..ops import gdn, ssm
 
     qkv, z, g, beta = _gdn_inputs(gw, h, gd)
-    with jax.named_scope("gdn_conv"):
+    with la.scope("gdn_conv"):  # (``gdn_conv_step`` where a pack carries the step)
         conv, tail = ssm.conv_step(conv_tail, qkv, gw["conv_w"], _no_bias(gd))
     q, k, v = _gdn_split(conv, gd)
     o, state = gdn.gdn_step(state, q, k, v, g, beta, active)
@@ -974,14 +974,15 @@ def head_logits(x, kernel, cfg):
     return x @ kernel
 
 
-def ffn(fw, h, is_moe: bool, cfg, valid=None):
+def ffn(fw, h, is_moe: bool, cfg, valid=None, tile=None):
     """(output [T, d], and of an expert layer (routing stats, experts picked
-    [T, k], every expert's score [T, n_routed]), else None)."""
+    [T, k], every expert's score [T, n_routed]), else None).  ``tile``: the
+    expert layer's row tile where the caller chooses it (``moe_block_held``)."""
     if not is_moe:
         return (jax.nn.silu(h @ fw["w_gate"]) * (h @ fw["w_up"])) @ fw["w_down"], None
     from ..moe.layer import moe_block_held
 
-    return moe_block_held(fw, h, cfg.latent, valid)
+    return moe_block_held(fw, h, cfg.latent, valid, tile)
 
 
 # ---------------------------------------------------------------------------

@@ -424,7 +424,7 @@ def held_routing(lw: Any, x: jnp.ndarray, spec):
     return idx, picked / jnp.sum(picked, -1, keepdims=True) * spec.routed_scale, s
 
 
-def held_rows_bound(t: int, spec) -> Optional[int]:
+def held_rows_bound(t: int, spec, tile: Optional[int] = None) -> Optional[int]:
     """The held pairs ONE pass of ``moe_block_held``'s bounded layout has room
     for at ``t`` tokens, None where it builds no such layout.  Twice the pairs a
     member holds under uniform routing (``_HELD_ROWS_FACTOR``), up to a whole
@@ -440,7 +440,7 @@ def held_rows_bound(t: int, spec) -> Optional[int]:
     geometric middle of the two, ``_BOUNDED_MIN_PAIRS_PER_PADDING`` = 16:
     neither side stands near it.  Below it the function traces ONE body over the
     worst case's rows."""
-    pairs, tile = t * spec.experts_per_tok, held_row_tile(t, spec)
+    pairs, tile = t * spec.experts_per_tok, tile or held_row_tile(t, spec)
     padding = spec.n_held * tile
     if pairs < _BOUNDED_MIN_PAIRS_PER_PADDING * padding:
         return None
@@ -449,13 +449,14 @@ def held_rows_bound(t: int, spec) -> Optional[int]:
     return bound if bound + padding < pairs else None  # a member of two holds them all
 
 
-def held_rows_a_pass(t: int, spec) -> int:
+def held_rows_a_pass(t: int, spec, tile: Optional[int] = None) -> int:
     """The rows ONE pass of ``moe_block_held`` hands the grouped matmuls at ``t``
-    tokens: the worst case's ``t k`` pairs, or the bound's where it has one, and a
-    tile of padding a held group."""
-    bound = held_rows_bound(t, spec)
+    tokens (on row tiles of ``tile``, where the caller chose one): the worst
+    case's ``t k`` pairs, or the bound's where it has one, and a tile of padding a
+    held group."""
+    bound = held_rows_bound(t, spec, tile)
     pairs = t * spec.experts_per_tok if bound is None else bound
-    return pairs + spec.n_held * held_row_tile(t, spec)
+    return pairs + spec.n_held * (tile or held_row_tile(t, spec))
 
 
 def held_rows_laid_out(t: int, spec, pairs_held):
@@ -498,7 +499,7 @@ def _padded_source(sizes: jnp.ndarray, rows: int, tile: int, with_live: bool = F
     return (source, live.reshape(-1)[:rows]) if with_live else source
 
 
-def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
+def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None, tile: Optional[int] = None):
     """The expert layer as ONE member of an expert-parallel deployment sees
     it, without the exchange: route every token over all ``n_routed`` experts,
     compute the picks that fall on the ``n_held`` experts held here by a
@@ -534,7 +535,10 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
     the router and the shared expert read ``x`` at full width.  With
     ``shared_gate`` the shared expert's output is scaled by ``sigmoid(x . w_sg)``.
 
-    x [T, d]; ``valid`` [T] bool masks padding rows out of routing.  Returns
+    x [T, d]; ``valid`` [T] bool masks padding rows out of routing; ``tile``: the
+    row tile where the caller knows the rows better than their count says (a
+    served pack that carries a tick's slot rows behind its tokens lays its groups
+    out as the pack alone does: ``latent_runner._row_tile``).  Returns
     (y [T, d], (stats int32 [4]: pairs routed, pairs on held experts, rows of
     the largest and of the smallest held expert's group; the experts picked
     [T, k]; every routed expert's score [T, n_routed] float32, what a
@@ -553,7 +557,7 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
         key = jnp.where(held, local, g).reshape(-1)  # pairs of no held expert sort last
         order = jnp.argsort(key, stable=True)
         sizes = jnp.sum(key[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32)
-        tile = held_row_tile(t, spec)
+        tile = tile or held_row_tile(t, spec)
 
     def experts(rows: int, first=None, order=order, sizes=sizes, x=x, wts=wts, ew=lw):
         """The products of ``sizes`` pairs a group, from sorted pair ``first`` on
@@ -593,7 +597,7 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None):
         pairs = ys[dest].astype(jnp.float32)  # [T, k, d]
         return jnp.sum(jnp.where(held[..., None], pairs * wts[..., None], 0.0), axis=1)
 
-    bound, rows = held_rows_bound(t, spec), held_rows_a_pass(t, spec)
+    bound, rows = held_rows_bound(t, spec, tile), held_rows_a_pass(t, spec, tile)
     if bound is None:
         y = experts(rows)
     else:

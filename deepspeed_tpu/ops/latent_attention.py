@@ -24,14 +24,39 @@ page of a prefill pack; one decode row), because a group shares its keys:
 positions)`` the flat rows of its latent keys.  Plain XLA bodies, each under a
 ``jax.named_scope`` (``indexer``, ``topk``, ``sparse_attn``, ``window_attn``,
 ``mla_prefill``, ``mla_decode``) so that a device trace can be attributed; gathers and scores exist one query
-block at a time.
+block at a time.  A body that a pack and a tick share takes its name through
+``scope()``: inside ``carried_step()``, a tick's rows traced INSIDE a pack's
+program, the name ends in ``_step``, so that a pack's time is never a step's.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+
+_SCOPE_SUFFIX = contextvars.ContextVar("latent_scope_suffix", default="")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``; ``name_step`` inside ``carried_step()``."""
+    return jax.named_scope(name + _SCOPE_SUFFIX.get())
+
+
+@contextlib.contextmanager
+def carried_step():
+    """While open, what is traced is a decode step's rows riding a prefill
+    pack's program (``latent_runner._carrying``): every ``scope()`` opened
+    meanwhile is a SIBLING of the pack's scope of that name (``full_attn_step``
+    beside ``full_attn``), never a child, so that a trace reader dividing a pack's
+    work by ``full_attn``'s time does not divide it by the step's too."""
+    token = _SCOPE_SUFFIX.set("_step")
+    try:
+        yield
+    finally:
+        _SCOPE_SUFFIX.reset(token)
 
 _MASKED = -1e30  # finite: a fully masked row softmaxes to uniform, not NaN
 Q_BLOCK = 64     # queries whose selected rows are gathered at once
@@ -76,7 +101,7 @@ def index_scores(q_i, w, q_pos, key_block: Callable, n_blocks, kb: int, k_pad: i
         return jax.lax.dynamic_update_slice(
             out, jnp.where(live, s, -jnp.inf), (0, b * kb))
 
-    with jax.named_scope("indexer"):
+    with scope("indexer"):
         return jax.lax.fori_loop(
             0, n_blocks, body, jnp.full((c, k_pad), -jnp.inf, jnp.float32))
 
@@ -96,7 +121,7 @@ def select_topk(scores, k: int, n_live=None):
     all fit in ``k``: the same selection, since what is cut off is ``-inf``."""
     width = scores.shape[-1]
     k = min(k, width)
-    with jax.named_scope("topk"):
+    with scope("topk"):
         if n_live is None or width <= max(k, TOPK_STEP):
             return jax.lax.top_k(scores, k)
         widths = [k] + [w for w in range(TOPK_STEP, width, TOPK_STEP) if w > k] + [width]
@@ -153,7 +178,7 @@ def sparse_attention(q_abs, idx, valid, rows_of: Callable, r_kv: int, scale: flo
         return jnp.einsum("chk,ckr->chr", p.astype(rows.dtype), rows[..., :r_kv],
                           preferred_element_type=jnp.float32).astype(q.dtype)
 
-    with jax.named_scope("sparse_attn"):
+    with scope("sparse_attn"):
         if qb == c:
             return block((q_abs, idx, valid))
         split = lambda a: a.reshape(c // qb, qb, *a.shape[1:])
@@ -165,7 +190,7 @@ def window_attention(q_abs, q_pos, keys, key_pos, window: int, r_kv: int, scale:
     """Groups' attention over the last ``window`` positions (the query's own
     included), absorbed form.  q_abs [G, C, H, W], q_pos [G, C], keys
     [G, K, W], key_pos [G, K] (negative = no key).  Returns [G, C, H, r_kv]."""
-    with jax.named_scope("window_attn"):
+    with scope("window_attn"):
         s = jnp.einsum("gchw,gkw->gchk", q_abs, keys,
                        preferred_element_type=jnp.float32) * scale
         d = q_pos[:, :, None] - key_pos[:, None, :]

@@ -188,7 +188,9 @@ def test_greedy_tokens_are_the_parents(kind, tiny):
     got = _serve(eng, _prompts(min(cfg.vocab_size, 250)), SamplingParams(max_new_tokens=10))
     assert got == PARENT_GREEDY[kind]
     # one upload a PROGRAM: a pack that carried a step (PR 54, the dense
-    # runner) counts as a pack and as a tick and hands over one buffer
+    # runner; of the latent families the two-norm blocks', PR 56: this one's
+    # engine keeps two programs) counts as a pack and as a tick and hands over
+    # one buffer
     mixed = eng.stats["mixed_dispatches"]
     assert (mixed > 0) == (kind == "dense")
     assert eng.stats["dispatch_uploads"] == (

@@ -294,7 +294,7 @@ def test_a_returned_page_is_handed_out_only_after_the_execution_that_last_read_i
     numbered("_decode_jit", lambda a: np.where(
         np.asarray(a[1])[2][:, None] != 0, np.asarray(a[2]), -1))
     numbered("_packed_prefill_ctx_jit", lambda a: np.asarray(
-        unpack_pack(np.asarray(a[1]), PAGE, 3, eng.max_pages, True)[5]))
+        unpack_pack(np.asarray(a[1]), PAGE, 3, eng.max_pages, True, eng.packs_carry_step)[5]))
     close = eng.mgr.close_window
 
     def closing(seq, n):

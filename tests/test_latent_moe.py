@@ -460,7 +460,7 @@ def test_the_bounded_layout_agrees_with_the_worst_cases(monkeypatch, spec, make)
     calls = 2 if spec.expert_form == "relu2" else 3  # a pass's products
     assert ran == [ROWS_BOUNDED] * 3 * calls and 0 < int(stats[1]) <= BOUND  # y; y and its recomputation
     del ran[:]
-    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec, tile=None: None)
     want, stats0 = _value_and_gradients(lw, x, spec, valid, ct)
     jax.effects_barrier()
     assert set(ran) == {ROWS_WORST} and np.array_equal(np.asarray(stats), np.asarray(stats0))
@@ -496,7 +496,7 @@ def test_past_the_bound_more_passes_run_and_no_pair_is_dropped(monkeypatch, case
         passes * ROWS_BOUNDED, int(passes == 1))
     if other == 9:
         assert int(stats[2]) == pairs and int(stats[3]) == 0  # expert 1 holds no row
-    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec, tile=None: None)
     _agree(got, _value_and_gradients(lw, x, spec, valid, ct)[0])
     if valid is not None:  # a masked row gets nothing from the experts
         assert not np.asarray(got[0])[~np.asarray(valid)].any()

@@ -288,7 +288,7 @@ def test_the_gradient_at_cell_tens_tile_and_k_tiles_is_the_worst_case_layouts(mo
 
     y, grads, rows = run()
     assert rows == {1024 + 128}
-    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec, tile=None: None)
     y0, grads0, rows0 = run()
     assert rows0 == {2048 + 128}
     for (path, got), want in zip(jax.tree_util.tree_leaves_with_path((y, grads)),
@@ -424,7 +424,7 @@ def test_the_kernel_path_runs_the_bounded_layout_and_its_gradients_are_the_worst
 
     y, (d_lw, d_x), rows = run()
     assert rows == [1024 + held * 128]
-    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec: None)
+    monkeypatch.setattr(layer, "held_rows_bound", lambda t, spec, tile=None: None)
     y0, (d_lw0, d_x0), rows0 = run()
     assert rows0 == [t * k + held * 128]
     np.testing.assert_allclose(np.asarray(y), np.asarray(y0), atol=2e-5, rtol=1e-5)

@@ -1,9 +1,14 @@
 """A pack that carries the tick's decode step (PR 54, S2): where the scheduler
 plans prompt chunks AND decoding rows one ahead on an engine whose packs take
-the step's rows (``packs_carry_step``: the dense runner, no mesh, no offload),
-ONE program runs both.  CPU, a tiny dense model: the tokens are those of the
-back-to-back order (a pack, then a step), the counts say which dispatches
-were mixed; never a time."""
+the step's rows (``packs_carry_step``: the dense runner and, PR 56, a
+``LatentRunner`` of every family; no mesh, no offload), ONE program runs both.
+CPU, a tiny dense model and one tiny model of each ``cfg.latent`` family: the
+tokens are those of the back-to-back order (a pack, then a step), the counts
+say which dispatches were mixed; never a time."""
+import functools
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,8 +30,8 @@ def model():
     return cfg, init_params(jax.random.PRNGKey(0), cfg, dtype=cfg.dtype)
 
 
-def _tokens(n, seed):
-    return [int(t) for t in np.random.default_rng(seed).integers(1, 255, n)]
+def _tokens(n, seed, vocab=255):
+    return [int(t) for t in np.random.default_rng(seed).integers(1, vocab, n)]
 
 
 class _Watch:
@@ -209,3 +214,155 @@ def test_a_step_rides_only_where_the_pack_programs_take_it(model):
     assert eng.stats["mixed_dispatches"] == 1 == eng.stats["decode_ticks"]
     eng.flush([1, 2, 3])
     _closed(eng)
+
+
+# -- a LatentRunner of each family (PR 56) ------------------------------------
+sys.path.insert(0, str(Path(__file__).resolve().parent))
+from test_mixed_program import FAMILIES, _latent_cfg, every_family_carries  # noqa: E402,F401
+
+LATENT_KW = dict(prefill_buckets=(32,), prefill_chunk=32, max_seq_len=256)
+
+
+@functools.lru_cache(maxsize=None)
+def _latent(family):
+    cfg = _latent_cfg(family)
+    return cfg, init_params(jax.random.PRNGKey(7), cfg)
+
+
+def _both_orders(family, schedule, **kw):
+    """``schedule`` served one ahead and in the back-to-back order: the same
+    tokens; ((tokens, engine, watch), the reference's)."""
+    model = _latent(family)
+    got = _serve(model, schedule, **{**LATENT_KW, **kw})
+    want = _serve(model, schedule, back_to_back=True, **{**LATENT_KW, **kw})
+    assert got[0] == want[0] and all(got[0].values())
+    assert got[1].packs_carry_step and want[1].packs_carry_step
+    assert want[1].stats["mixed_dispatches"] == 0 and not any(n for _, n in want[2].calls)
+    return got, want
+
+
+@pytest.mark.parametrize("scene", ["chunks", "dead_row"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_latent_engines_mixed_dispatches_give_the_back_to_back_orders_tokens(
+        family, scene, every_family_carries):
+    """``chunks``: while request 1 decodes, a prompt of three chunks arrives (a
+    cold chunk, a continuation chunk, and a last one that completes it: the
+    prompt joins the NEXT step) and then a prompt of one chunk; every pack
+    beside a decoding row carries it.  ``dead_row``: request 1 stops while a
+    step that a chunk carries holds its row."""
+    vocab = _latent(family)[0].vocab_size
+    a = (1, _tokens(6, 1, vocab), LONG)
+    if scene == "chunks":
+        schedule = {0: [a], 3: [(2, _tokens(70, 2, vocab), LONG)], 5: [(3, _tokens(20, 3, vocab), LONG)]}
+    else:
+        clean, eng, _ = _serve(_latent(family), {0: [a]}, **LATENT_KW)
+        _closed(eng)
+        # (the first of its tokens that did not come before: the stop must not fall earlier)
+        at = next(i for i in range(4, 14) if clean[1][i] not in clean[1][:i])
+        stop = SamplingParams(max_new_tokens=14, stop_token=clean[1][at])
+        schedule = {0: [(1, a[1], stop)], at - 3: [(2, _tokens(120, 2, vocab), LONG)]}
+    (got, eng, watch), (_, ref, _) = _both_orders(family, schedule)
+    s = eng.stats
+    assert s["mixed_dispatches"] == len(watch.mixed("_packed_prefill_ctx_jit")) > 0
+    assert not watch.mixed("_packed_prefill_jit")  # a latent engine has ONE pack program
+    assert s["decode_emitted"] == sum(len(h.step) for h in watch.handles) + _steps_alone(eng)
+    for h in watch.handles:
+        assert not {id(e[0]) for e in h.rows} & {id(q) for q in h.step}
+    spans = [e for e in eng.telemetry.recorder.chrome_events() if e.get("ph") == "X"]
+    packs = [e for e in spans if e["name"] == "prefill_pack"]
+    assert [e["args"]["step_rows"] for e in packs] == [n for _, n in watch.calls]
+    assert s["ahead_drains"] == 0
+    if scene == "chunks":
+        # every pack of requests 2 and 3 stood beside request 1's row, and carried it
+        assert all(n for _, n in watch.calls[1:])
+        assert any(h.finishing and h.step for h in watch.handles)
+        assert any(e[1] > 0 for h in watch.handles if h.step for e in h.rows)  # a continuation chunk
+    else:
+        assert s["ahead_rows_dropped"] == 1 == watch.dead_in_step
+        assert eng.scheduler.requests[1].state == S.FINISHED and len(got[1]) < 14
+    _closed(eng)
+    _closed(ref)
+
+
+def test_a_step_row_that_fills_its_window_inside_a_mixed_tick_gives_its_pages_back():
+    """EVA attention, window 32, pages of 8: request 1's decode crosses position
+    31 while request 2's chunks are packed, so the tick that writes the window's
+    last position is a MIXED one: the window's 4 exact pages go back to the pool
+    at that dispatch, the slot's table is rewritten, and the tokens are the
+    back-to-back order's."""
+    family = "eva"
+    vocab = _latent(family)[0].vocab_size
+    schedule = {0: [(1, _tokens(26, 1, vocab), LONG)],
+                2: [(2, _tokens(120, 2, vocab), SamplingParams(max_new_tokens=4))]}
+    closes = []
+
+    def served(back_to_back):
+        cfg, params = _latent(family)
+        eng = InferenceEngineV2(params, cfg, **{**KW, **LATENT_KW})
+        sched = eng.scheduler
+        if back_to_back:
+            sched._back_to_back = lambda: "test"
+        dispatch, close = eng.pack_dispatch, eng.mgr.close_window
+        riding = []
+
+        def dispatched(entries, *a, step=(), **kw):
+            riding[:] = [s.uid for s in step]
+            try:
+                done = dispatch(entries, *a, step=step, **kw)
+            finally:
+                riding.clear()
+            for seq in step:  # the table the NEXT program is handed is the shrunk one
+                row = eng._tables_np[seq.slot]
+                assert row[row >= 0].tolist() == list(seq.blocks)
+            return done
+
+        def closing(seq, n):
+            before = len(seq.blocks)
+            got = close(seq, n)
+            if not back_to_back:
+                closes.append((seq.uid, n, seq.uid in riding, got))
+            assert before - len(seq.blocks) == got
+            return got
+
+        eng.pack_dispatch, eng.mgr.close_window = dispatched, closing
+        n = 0
+        while not sched.idle or n <= 2:
+            for uid, prompt, samp in schedule.get(n, ()):
+                sched.submit(uid, prompt, samp)
+            sched.tick()
+            n += 1
+            assert n < 300
+        return {u: sched.result(u) for u in (1, 2)}, eng
+
+    got, eng = served(False)
+    want, ref = served(True)
+    assert got == want and len(got[1]) == 14
+    # request 1 (26 + 14 tokens) closed its first window as a STEP row of a mixed tick
+    assert (1, 32, True, 4) in closes
+    assert any(uid == 2 and not rode for uid, _, rode, _ in closes)  # and request 2's as chunks
+    assert eng.stats["eva_windows_closed"] == len(closes) == ref.stats["eva_windows_closed"]
+    assert eng.stats["eva_pages_returned"] == 4 * len(closes) == ref.stats["eva_pages_returned"]
+    assert eng.stats["mixed_dispatches"] > 0 == ref.stats["mixed_dispatches"]
+    _closed(eng)
+    _closed(ref)
+
+
+@pytest.mark.parametrize("family", ["single", "deltanet"])
+def test_a_recurrences_state_left_by_a_preemption_is_recomputed_under_mixed_ticks(
+        family, every_family_carries):
+    """A pool too small for three answers: growth drains the plan and preempts;
+    the state the victim's slot held is left behind (``_discarded``) and its
+    resume scans from position 0, in packs that carry the others' steps."""
+    vocab = _latent(family)[0].vocab_size
+    schedule = {0: [(u + 1, _tokens(n, u, vocab), SamplingParams(max_new_tokens=24))
+                    for u, n in enumerate((14, 15, 13))]}
+    (got, eng, watch), (_, ref, _) = _both_orders(
+        family, schedule, num_blocks=11, kv_watermark=0.0)
+    assert all(len(t) == 24 for t in got.values())
+    assert eng.scheduler.stats["preemptions"] == ref.scheduler.stats["preemptions"] >= 1
+    assert eng.scheduler.drains.get("pool", 0) >= 1
+    assert eng.stats["ssm_states_recomputed"] == ref.stats["ssm_states_recomputed"] >= 1
+    assert eng.stats["ssm_states_reset"] == ref.stats["ssm_states_reset"] >= 4
+    assert eng.stats["mixed_dispatches"] > 0 and eng.runner._discarded == 0
+    _closed(eng)
+    _closed(ref)

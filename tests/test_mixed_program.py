@@ -1,10 +1,11 @@
-"""The PROGRAM of a pack that carries the tick's step (PR 54, S2), beside
-``tests/test_dispatch_uploads.py``: each layer's weight stands in ONE
-``dot_general`` whose operand holds the pack's T rows and the step's B, the
-program does what the pack's and the step's did in turn (chain and key), a
-mixed tick makes ONE upload and ONE fetch, and an engine on a
-``LatentRunner`` keeps the parent's three programs.  CPU, tiny sizes: what is
-traced and what is counted, never a time."""
+"""The PROGRAM of a pack that carries the tick's step (PR 54, S2; PR 56 for a
+``LatentRunner``), beside ``tests/test_dispatch_uploads.py``: each layer's
+weight stands in ONE ``dot_general`` whose operand holds the pack's T rows and
+the step's B, the program does what the pack's and the step's did in turn
+(chain and key; a ``cfg.latent`` family's cache too), a mixed tick makes ONE
+upload and ONE fetch, and the programs WITHOUT a step are the parent's.  CPU,
+tiny sizes: what is traced and what is counted, never a time."""
+import collections
 import hashlib
 import re
 import sys
@@ -20,12 +21,13 @@ sys.path.insert(0, str(ROOT))
 
 from benchmark import harness  # noqa: E402
 
-from deepspeed_tpu.inference import model_runner  # noqa: E402
+from deepspeed_tpu.inference import latent_runner, model_runner  # noqa: E402
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2, new_pack  # noqa: E402
 from deepspeed_tpu.inference.paged import init_paged_cache  # noqa: E402
 from deepspeed_tpu.inference.sampling import SamplingParams  # noqa: E402
 from deepspeed_tpu.models import get_preset  # noqa: E402
 from deepspeed_tpu.models.transformer import TransformerConfig, init_params  # noqa: E402
+from deepspeed_tpu.moe.layer import held_row_tile, held_rows_a_pass  # noqa: E402
 
 # widths no activation shares: d 48, f 80, 4 query / 2 kv heads of 12, vocab 96
 ODD = dict(vocab_size=96, hidden_size=48, intermediate_size=80, num_layers=2, num_heads=4,
@@ -192,48 +194,250 @@ def test_a_mixed_tick_makes_one_upload_and_one_fetch(tiny):
     assert not any(eng.close().values())
 
 
-# -- a LatentRunner's engine keeps the parent's programs ----------------------
-def _latent_engine():
-    m = harness.rehearsed(harness.load_json(
-        ROOT / "benchmark/configs/dots3_note_l5_e32_serve_1chip.json"), True)
-    cfg = harness.module("models", m["model_type"]).transformer_config(
-        m, max_seq_len=m["engine"]["max_seq_len"])
-    return InferenceEngineV2(init_params(jax.random.PRNGKey(7), cfg), cfg, max_seqs=4,
-                             num_blocks=64, block_size=8, prefill_buckets=(32,),
-                             prefill_chunk=32, max_seq_len=256, telemetry=True)
-
-
-def latent_program_hashes(eng):
-    """sha256 of the jaxpr (addresses blanked) of the three programs a
-    ``cfg.latent`` engine runs: the pack, the tick, the burst's tick."""
-    slots, pages, bs = eng.mgr.max_seqs, eng.max_pages, eng.block_size
-    triple = (0.0, 0, 1.0)
-    pack, _ = new_pack(32, bs, slots, pages, True)
-    i32 = lambda *shape: np.zeros(shape, np.int32)
-    tables = np.full((slots, pages), -1, np.int32)
-    args = {
-        "_packed_prefill_ctx_jit": (eng.params, pack, eng.kv, eng._rng, eng._chain, triple),
-        "_decode_jit": (eng.params, i32(4, slots), tables, eng.kv, eng._rng, eng._chain, triple),
-        "_decode_burst_jit": (eng.params, i32(slots), i32(slots), tables, np.zeros(slots, bool),
-                              eng.kv, eng._rng, i32(9, slots), i32(), i32(slots), i32(slots),
-                              i32(slots), triple),
-    }
-    return {name: hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", str(
-        getattr(eng, name).trace(*a).jaxpr)).encode()).hexdigest() for name, a in args.items()}
-
-
-# as the parent of PR 54 (09c2032) traced them, character for character
-PARENTS_LATENT_PROGRAMS = {
-    "_packed_prefill_ctx_jit": "356aa17b20a7cb541e758210e96bb556e69e30fc112ff61f2a259ff6c74528fb",
-    "_decode_jit": "e45a8c0735c21aad134475476f384d55fc3d9cd86000c05d2e50adbd79a33b4c",
-    "_decode_burst_jit": "825e524e9b40a71dcea127850c0f1f80a032e4159ec478e23a1b704940c11c59",
+# -- a LatentRunner's pack carries the step too (PR 56) -----------------------
+# one tiny configuration of each ``cfg.latent`` family (the benchmark's, at its
+# rehearsal size): its kinds of layer, and the recurrence whose projections run
+# inside its ``write`` (the seam hands it ``(w, h)``: those weights alone still
+# stand in a dot a kind of row)
+FAMILIES = {
+    "indexed": ("dots3_note_l5_e32", None),      # full (selector) + sliding (rings) + experts
+    "every": ("deepseek_v2_l5_e40", None),       # latent attention over every row + experts
+    "single": ("nemotron3_super_l11_e128", "mamba"),  # mamba + gqa + latent-space experts
+    "deltanet": ("qwen3_next_l8_e128", "gdn"),   # gdn + gated attention on pages + experts
+    "windowed": ("laguna_xs2_l5", None),         # gated attention on pages beside rings + experts
+    "eva": ("evabyte_l8", None),                 # EVA attention on a table that shrinks
 }
 
 
-def test_a_latent_engine_never_mixes_and_runs_the_parents_three_programs():
-    eng = _latent_engine()
-    assert eng.runner.packs_carry_step is False and eng.packs_carry_step is False
-    assert latent_program_hashes(eng) == PARENTS_LATENT_PROGRAMS
+def _latent_cfg(family):
+    m = harness.rehearsed(harness.load_json(
+        ROOT / f"benchmark/configs/{FAMILIES[family][0]}_serve_1chip.json"), True)
+    return harness.module("models", m["model_type"]).transformer_config(
+        m, max_seq_len=m["engine"]["max_seq_len"])
+
+
+# the families whose ENGINE mixes (``LatentRunner.packs_carry_step``: the two-norm
+# blocks that keep no recurrence's state); the runner's entry carries a step for
+# every family, and the tests below hold every family to it through
+# ``every_family_carries``
+CARRIED = {"windowed", "eva"}
+
+
+@pytest.fixture
+def every_family_carries(monkeypatch):
+    """Engines built meanwhile mix whatever their family: the mechanism is one
+    algorithm, which families the engine is TOLD to mix is what the chip read."""
+    init = latent_runner.LatentRunner.__init__
+
+    def carrying(self, cfg):
+        init(self, cfg)
+        self.packs_carry_step = True
+
+    monkeypatch.setattr(latent_runner.LatentRunner, "__init__", carrying)
+
+
+def _latent_engine(family, **kw):
+    cfg = _latent_cfg(family)
+    kw = {**dict(max_seqs=4, num_blocks=64, block_size=8, prefill_buckets=(32,),
+                 prefill_chunk=32, max_seq_len=256, telemetry=True), **kw}
+    return InferenceEngineV2(init_params(jax.random.PRNGKey(7), cfg), cfg, **kw)
+
+
+def _latent_dots(cfg, step):
+    """{(primitive, the row operand's shape, the weight operand's shape): count}
+    of ``latent_runner.prefill_pack`` at T tokens (and B slot rows)."""
+    t, b = 32, 4
+    params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: latent_runner.init_cache(cfg, 24, BS, b, t))
+    S = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt)
+    args = [S((t,))] * 3 + [S((t // BS,)), S((b,)), S((b, PAGES))]
+    rows = (S((b,)), S((b,)), S((b, PAGES)), S((b,), jnp.bool_)) if step else None
+    jaxpr = jax.make_jaxpr(lambda p, c, st, *a: latent_runner.prefill_pack(
+        p, cfg, *a, c, step=st))(params, cache, rows, *args)
+    found = collections.Counter()
+    for eqn in _equations(jaxpr.jaxpr):
+        if eqn.primitive.name == "dot_general" or eqn.primitive.name.startswith("ragged_dot"):
+            lhs, rhs = (v.aval.shape for v in eqn.invars[:2])
+            found[eqn.primitive.name, lhs, rhs] += 1
+    return found, params
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_mixed_latent_pack_streams_each_weight_once(family):
+    """Each dense weight stands in as many dots as in the pack alone, over T + B
+    rows where it stood over T; each held-expert layer is ONE grouped product a
+    matrix over the rows of BOTH kinds, laid out on the PACK's row tile; ONE head
+    matmul scores the pack's last rows and the step's.  A recurrence's own
+    projections alone run inside its ``write`` and stay a dot a kind of row."""
+    t, b = 32, 4
+    cfg = _latent_cfg(family)
+    s, recurrence = cfg.latent, FAMILIES[family][1]
+    alone, params = _latent_dots(cfg, step=False)
+    mixed, _ = _latent_dots(cfg, step=True)
+    by_rows = lambda found, rows: collections.Counter({
+        rhs: n for (name, lhs, rhs), n in found.items()
+        if name == "dot_general" and len(lhs) == 2 and lhs[0] == rows and len(rhs) == 2})
+    head = (cfg.hidden_size, cfg.vocab_size)
+    assert by_rows(alone, b)[head] == 1 and by_rows(mixed, 2 * b)[head] == 1
+    assert head not in by_rows(mixed, b) and not by_rows(alone, t + b)
+    split = by_rows(mixed, t)  # what is still the pack's rows alone
+    assert by_rows(mixed, t + b) + split == by_rows(alone, t) and by_rows(mixed, t + b)
+    if recurrence is None:
+        assert not split
+    else:
+        own = {a.shape for a in jax.tree_util.tree_leaves(params["layers"][recurrence])}
+        assert split and set(split) <= own
+        assert all(by_rows(mixed, b)[w] >= n for w, n in split.items())
+    grouped = lambda found: {(lhs[0], rhs): n for (name, lhs, rhs), n in found.items()
+                             if name.startswith("ragged_dot")}
+    if family == "eva":  # (no experts)
+        assert not grouped(alone) and not grouped(mixed)
+        return
+    tile = held_row_tile(t, s)
+    rows, carrying = held_rows_a_pass(t, s), held_rows_a_pass(t + b, s, tile)
+    assert carrying == rows + b * s.experts_per_tok
+    assert {r for r, _ in grouped(alone)} == {rows} and {r for r, _ in grouped(mixed)} == {carrying}
+    assert sorted(grouped(alone).values()) == sorted(grouped(mixed).values())
+    assert sum(grouped(mixed).values()) % len(s.expert_layers or range(cfg.num_layers - s.first_dense)) == 0
+
+
+def _latent_decoding(family, temperature):
+    """An engine of the family with sequences 1 and 2 a few steps into their
+    answers and sequence 3's first chunk (a whole window of an EVA model)
+    written: ``(engine, sampling, sequence 3, its next chunk)``."""
+    eng = _latent_engine(family, seed=5)
+    samp = SamplingParams(temperature=temperature)
+    rng = np.random.default_rng(2)
+    draw = lambda n: [int(t) for t in rng.integers(1, eng.cfg.vocab_size, n)]
+    eng.put([1, 2], [draw(5), draw(11)], samp)
+    for _ in range(3):
+        eng.step(samp)
+    c = eng.mgr.admit(3, draw(41))
+    eng.mgr.ensure_pages(c, 32)
+    eng.prefill_entries([(c, 0, 32)], samp)
+    eng.mgr.ensure_pages(c, 41)
+    return eng, samp, c, (c, 32, 41)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_mixed_latent_program_leaves_what_the_pack_and_then_the_step_leave(
+        family, temperature, every_family_carries):
+    """Two engines of one family in the same state: one runs a continuation
+    chunk that carries the step, the other the pack and then the step.  The
+    same first token, step tokens, chain and key; the same CACHE (every state,
+    ring, page and pick count); the experts' counts by their rule: the routed
+    and held pairs add up, the mixed program counts on the pack's side alone and
+    an expert both kinds of row touch ONCE."""
+    results = []
+    for mixed in (True, False):
+        eng, samp, c, chunk = _latent_decoding(family, temperature)
+        step = [eng.mgr.seqs[1], eng.mgr.seqs[2]]
+        before = jax.tree_util.tree_map(np.asarray, eng.kv)
+        first = {}
+        if mixed:
+            toks = eng.pack_collect(eng.pack_dispatch([chunk], samp, step=step), first)
+        else:
+            eng.pack_collect(eng.pack_dispatch([chunk], samp), first)
+            toks = eng.decode_collect(eng.decode_dispatch(step, samp))
+        assert eng.stats["mixed_dispatches"] == int(mixed)
+        results.append((first, toks, np.asarray(eng._chain).tolist(),
+                        np.asarray(jax.random.key_data(eng._rng)).tolist(),
+                        before, jax.tree_util.tree_map(np.asarray, eng.kv)))
+        eng.flush([1, 2, 3])
+        assert not any(eng.close().values())
+    (*got, t0, kv), (*want, _, ref) = results
+    assert got == want
+    assert set(got[0]) == {3} and set(got[1]) == {1, 2}
+    for name in sorted(set(kv) - {"stats", "touched"}):
+        for a, b in zip(jax.tree_util.tree_leaves(kv[name]), jax.tree_util.tree_leaves(ref[name])):
+            np.testing.assert_allclose(a.astype(np.float32), b.astype(np.float32),
+                                       rtol=2e-5, atol=2e-6, err_msg=name)
+    assert (kv["stats"][:, :2] == ref["stats"][:, :2]).all()
+    if "touched" in kv:
+        mine, theirs = kv["touched"] - t0["touched"], ref["touched"] - t0["touched"]
+        assert not mine[:, 1].any() and theirs[:, 1].any()  # [layer, pack | tick, (experts, pairs)]
+        assert (mine[:, 0, 1] == theirs[:, :, 1].sum(1)).all()
+        assert (mine[:, 0, 0] >= theirs[:, :, 0].max(1)).all()
+        assert (mine[:, 0, 0] <= theirs[:, :, 0].sum(1)).all()
+
+
+def latent_program_hashes(eng):
+    """sha256 of the jaxpr (addresses blanked) of the programs of a ``cfg.latent``
+    engine that take NO step: the tick, the burst's tick, and the runner's pack
+    entry called as ``benchmark/drivers/serve.py:_runner_logits`` calls it."""
+    slots, pages, bs = eng.mgr.max_seqs, eng.max_pages, eng.block_size
+    triple = (0.0, 0, 1.0)
+    i32 = lambda *shape: np.zeros(shape, np.int32)
+    tables = np.full((slots, pages), -1, np.int32)
+    sha = lambda jaxpr: hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr)).encode()).hexdigest()
+    entry = lambda p, kv, *a: eng.runner.prefill_packed_ctx(p, eng.cfg, *a, kv)
+    return {
+        "_decode_jit": sha(eng._decode_jit.trace(
+            eng.params, i32(4, slots), tables, eng.kv, eng._rng, eng._chain, triple).jaxpr),
+        "_decode_burst_jit": sha(eng._decode_burst_jit.trace(
+            eng.params, i32(slots), i32(slots), tables, np.zeros(slots, bool), eng.kv, eng._rng,
+            i32(9, slots), i32(), i32(slots), i32(slots), i32(slots), triple).jaxpr),
+        "prefill_packed_ctx": sha(jax.make_jaxpr(entry)(
+            eng.params, eng.kv, i32(32), i32(32), i32(32), i32(32 // bs), i32(slots), tables,
+            i32(slots))),
+    }
+
+
+# as the parent of PR 56 (4ffe58e) traced them, character for character (the first
+# two of ``indexed`` are PR 54's pins of its own parent, 09c2032)
+PARENTS_LATENT_PROGRAMS = {
+    "indexed": {
+        "_decode_jit": "e45a8c0735c21aad134475476f384d55fc3d9cd86000c05d2e50adbd79a33b4c",
+        "_decode_burst_jit": "825e524e9b40a71dcea127850c0f1f80a032e4159ec478e23a1b704940c11c59",
+        "prefill_packed_ctx": "2def77d854a485c0757bc0effc6203465b5b8571748d1fbdba1350f49ac3c202",
+    },
+    "every": {
+        "_decode_jit": "6d278d0a3637bb218c8c317563c8d11e9ea8025c7d674f6eebc4fef98bfd5969",
+        "_decode_burst_jit": "0de0fc4d2b26d8abda78c67f9e96028ba5474f76f25a4407617ee0b1ed675c37",
+        "prefill_packed_ctx": "faa48f242aac6746211bc4d7063683967b99e32aed74c1e440e0bf27d1cd2611",
+    },
+    "single": {
+        "_decode_jit": "ab1f7ab1e80c07dbc384b33884125c31d78001d723d650e7baead39898657499",
+        "_decode_burst_jit": "ba1048a37c73818a911152b86c6b18dba5e17ea07d0a131593718100d0019d87",
+        "prefill_packed_ctx": "f42693bdfa6f489d6d8bac145161e7b3813b6356423db05a0b18b7e03aa0fcf2",
+    },
+    "deltanet": {
+        "_decode_jit": "cc1b7d62612a51c43875051740f788baf09cf32c781559479e8c9d5e5d517cb5",
+        "_decode_burst_jit": "064f122290516ac78e75ced34ccc0e8c8c1501f5a607bf3ede9120a09ebda833",
+        "prefill_packed_ctx": "c1c395d3678d0f6990d7cd558942a392f61e5cf3e63cf697fec09537fba8cfb3",
+    },
+    "windowed": {
+        "_decode_jit": "57cf619f6474a7091abc37dd4da39c487a71781c046b39865e85e9a021738339",
+        "_decode_burst_jit": "4153302ac7662e0c8077868a9005211ef14c557c5a7091ceb6d0edd5f8b514ea",
+        "prefill_packed_ctx": "b6d7330e059438b65942984437dc1004c5c28803e918e90acf798b251a5a23ef",
+    },
+    "eva": {
+        "_decode_jit": "350fb52bbe81c2268657bce556349f8cb152b71d70d7599f13a6cc2b8c21d942",
+        "_decode_burst_jit": "56fb9f00dc52c50efffa626d523db819a3e15d52f7cf362d7aa1a84968d53c75",
+        "prefill_packed_ctx": "0d4c20605549827b67a2b93e2d36e98e70b89b64b1e8421d2c99ce52ae3f580b",
+    },
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_latent_engines_programs_without_a_step_are_the_parents(family):
+    eng = _latent_engine(family)
+    assert eng.runner.packs_carry_step is eng.packs_carry_step is (family in CARRIED)
+    assert eng.runner.packs_are_one_program is True
+    assert latent_program_hashes(eng) == PARENTS_LATENT_PROGRAMS[family]
+    assert not any(eng.close().values())
+
+
+@pytest.mark.parametrize("family", ["indexed", "windowed"])
+def test_a_latent_engine_mixes_every_pack_beside_decoding_rows_where_its_family_carries(family):
+    """The scheduler's run of PR 54's pin, on the contract of PR 56: a prompt of
+    three chunks arrives while another request decodes.  Where the family
+    carries, every pack beside a decoding row carries it (ONE program, ONE
+    upload); where it does not, the parent's order: nothing mixes.  Nothing
+    drains either way."""
+    eng = _latent_engine(family)
+    mixes = family in CARRIED
     sched = eng.scheduler
     rng = np.random.default_rng(3)
     samp = SamplingParams(max_new_tokens=8)
@@ -244,9 +448,61 @@ def test_a_latent_engine_never_mixes_and_runs_the_parents_three_programs():
         sched.tick()
     assert sched.idle and len(sched.result(1)) == len(sched.result(2)) == 8
     s = eng.stats
-    assert s["mixed_dispatches"] == 0 and s["dispatched_ahead"] > 0 and s["ahead_drains"] == 0
+    assert s["mixed_dispatches"] == (3 if mixes else 0)
+    assert s["dispatched_ahead"] > 0 and s["ahead_drains"] == 0
     spans = [e for e in eng.telemetry.recorder.chrome_events() if e.get("ph") == "X"]
-    assert all("step_rows" not in e["args"] for e in spans if e["name"] == "prefill_pack")
-    # a pack and a step stay two programs, two uploads
-    assert s["dispatch_uploads"] == s["decode_ticks"] + s["prefill_dispatches"] + s["table_uploads"]
+    packs = [e["args"].get("step_rows") for e in spans if e["name"] == "prefill_pack"]
+    assert packs == ([0, 1, 1, 1] if mixes else [None] * 4)
+    # one upload a PROGRAM: a mixed tick's pack and step are one
+    assert s["dispatch_uploads"] == s["decode_ticks"] + s["prefill_dispatches"] \
+        - s["mixed_dispatches"] + s["table_uploads"]
     assert not any(eng.close().values())
+
+
+# the sibling scopes a carried step's bodies take, by family (``la.carried_step``)
+STEP_SCOPES = {
+    "indexed": {"indexer_step", "topk_step", "sparse_attn_step", "window_attn_step"},
+    "every": set(),  # (its tick's body is ``mla_decode``, opened on the chip alone)
+    "single": {"gqa_attn_step", "ssm_step"},
+    "deltanet": {"gated_attn_step", "gdn_conv_step", "gdn_step"},
+    "windowed": {"full_attn_step", "window_attn_step"},
+    "eva": {"eva_attend_step", "eva_summarise_step"},
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_carried_steps_scopes_are_siblings_of_the_packs_never_children(family):
+    """A trace reader divides a PACK's work by the time under ``full_attn``
+    (``(^|/)full_attn(/|$)`` in ``jit_packed_ctx_impl``): the step's rows must not
+    sit under that name.  In the mixed program every scope a tick's body opens
+    ends in ``_step`` (or was a name of its own), none lies under a pack's scope
+    and none holds one; the programs without a step hold no ``_step`` at all."""
+    cfg = _latent_cfg(family)
+    t, b = 32, 4
+    params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: latent_runner.init_cache(cfg, 24, BS, b, t))
+    S = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt)
+    args = [S((t,))] * 3 + [S((t // BS,)), S((b,)), S((b, PAGES))]
+    rows = (S((b,)), S((b,)), S((b, PAGES)), S((b,), jnp.bool_))
+
+    def stacks(step):
+        jaxpr = jax.make_jaxpr(lambda p, c, st, *a: latent_runner.prefill_pack(
+            p, cfg, *a, c, step=st))(params, cache, step, *args)
+        # (a body under ``jax.vmap`` reads ``vmap(<scope>)``: a selector's tick)
+        return {tuple(re.sub(r"^vmap\((.*)\)$", r"\1", c)
+                      for c in str(e.source_info.name_stack).split("/"))
+                for e in _equations(jaxpr.jaxpr)}
+
+    own = {"gdn_step", "ssm_step", "mla_decode"}  # a tick's names that no pack's body has
+    pack_names = {"full_attn", "window_attn", "gated_attn", "eva_attend", "gqa_attn",
+                  "eva_summarise", "gdn_conv", "gdn_scan", "ssm_scan", "indexer", "topk",
+                  "sparse_attn", "mla_prefill"}
+    alone, mixed = stacks(None), stacks(rows)
+    assert not any(c.endswith("_step") and c not in own for s in alone for c in s)
+    found = {c for s in mixed for c in s if c.endswith("_step") or c in own}
+    assert STEP_SCOPES[family] <= found, found
+    for s in mixed:
+        steps = [c for c in s if c in found]
+        assert not (steps and pack_names & set(s)), s
+    # and the pack's own scopes are all still there
+    assert {c for s in alone for c in s} & pack_names == {c for s in mixed for c in s} & pack_names

@@ -143,14 +143,18 @@ def test_chunked_prefill_shared_packs_and_unequal_ages_match_the_reference(model
     assert eng.stats["expert_pairs_routed"] == eng.stats["expert_pairs_held"] > 0
     assert 0 < eng.stats["experts_touched_decode"] < eng.stats["experts_touched"]
     # ... and the rows its expert layers laid out follow from the programs' shapes alone:
-    # ``t k + g x tile`` a layer, a pack of CHUNK tokens and a tick of 4 slots
-    from deepspeed_tpu.moe.layer import held_rows_a_pass
+    # ``t k + g x tile`` a layer: a tick of 4 slots, and a pack of CHUNK tokens with the 4
+    # slot rows it carries behind them (on the PACK's row tile), live or not
+    from deepspeed_tpu.moe.layer import held_row_tile, held_rows_a_pass
 
     s = cfg.latent
     a_pack, a_tick = held_rows_a_pass(CHUNK, s), held_rows_a_pass(4, s)
+    carrying = held_rows_a_pass(CHUNK + 4, s, held_row_tile(CHUNK, s))
     assert a_tick == 4 * s.experts_per_tok + s.n_held * 16 < a_pack
+    assert carrying == a_pack + 4 * s.experts_per_tok and eng.stats["mixed_dispatches"] > 0
     assert eng.stats["expert_rows_laid_out"] == len(s.expert_layers) * (
-        eng.stats["prefill_dispatches"] * a_pack + eng.stats["decode_ticks"] * a_tick)
+        eng.stats["prefill_dispatches"] * carrying
+        + (eng.stats["decode_ticks"] - eng.stats["mixed_dispatches"]) * a_tick)
     assert eng.stats["expert_rows_laid_out"] > eng.stats["expert_pairs_held"]
 
 
