@@ -82,6 +82,17 @@ closed window, the open window's summary page, the open window's exact pages):
   are past its VMEM estimate), each pool attended by a call of its own;
 - a preemption drops the table and the resume recomputes from the tokens.
 
+A model of two-norm blocks that each hold TWO PARALLEL MIXERS (``par``: a Mamba-2
+recurrence AND rotary GQA on one normed input, summed; a dense SwiGLU behind
+them) keeps BOTH of the above for EVERY layer of a sequence: ``ssm[i]`` / ``conv[i]``
+and ``k[i]`` / ``v[i]`` are block ``i``'s (``LatentSpec.count`` counts a block as one
+of each mixer), the block goes through the seam twice (``_recurrence``, then the
+K / V write and read), and what follows from position holds for both at once: a
+slot's next owner and a resume start the state from zeros AND overwrite the pages
+from position 0; a preemption frees the pages and leaves the state behind.  Its
+host mirror sets two gauges at every dispatch (``state_bytes_live``,
+``kv_page_bytes_in_use``: ``PARALLEL_COUNTERS``).
+
 One layer body (``_layer``) serves the pack and the tick; the kind chooses how
 the rows are written and read.  A pack reads its own rows back from the cache
 it just wrote, so a cold pack and a pack over cached context are one program,
@@ -200,6 +211,14 @@ STATE_COUNTERS = (
     "experts_touched",        # held experts with at least one row, summed over dispatches
     "experts_touched_decode",    # ... in decode ticks alone,
     "expert_pairs_held_decode",  # and the pairs that fell on them there
+)
+
+
+# ... and of one whose EVERY block keeps both (two parallel mixers, a dense SwiGLU behind
+# them): the state's three, and what a slot and a page hold, as gauges set at each dispatch
+PARALLEL_COUNTERS = STATE_COUNTERS[:3] + (
+    "state_bytes_live",      # gauge: slots holding a sequence x a slot's state, every block's
+    "kv_page_bytes_in_use",  # gauge: pages holding a live sequence's rows x a page, every block's
 )
 
 
@@ -625,7 +644,7 @@ def _block(cfg, l, layers, x, valid, cache, write, read, pack_rows, probe):
         pools = write(kind, (cache["k"][i], cache["v"][i]), (k, v))
         cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
         o = read(kind, pools, (q, k, v))  # (under ``gqa_attn``: ``_scoped``)
-        y = o.reshape(x.shape[0], -1).astype(x.dtype) @ w["wo"]
+        y = lm.gqa_output(w, o.astype(x.dtype), s.gqa)
     else:
         y, cache = _experts(cfg, i, w, h, valid, cache, pack_rows, probe)
     return x + y.astype(x.dtype), cache
@@ -670,6 +689,15 @@ def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, 
     h = lm.norm(x, n1, cfg)
     if kind == "gdn":
         y, cache = _recurrence(kind, i, mw, h, cache, write)
+    elif kind == "par":
+        # TWO mixers on the ONE normed input, summed: the recurrence through its seam
+        # (state and conv tail of block ``i``) AND block ``i``'s K / V write and read
+        y, cache = _recurrence("mamba", i, mw["mamba"], h, cache, write)
+        q, k, v = lm.gqa_inputs(mw["gqa"], h, s.gqa, pos)
+        pools = write("gqa", (cache["k"][i], cache["v"][i]), (k, v))
+        cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
+        o = read("gqa", pools, (q, k, v))  # (under ``gqa_attn``: ``_scoped``)
+        y = y.astype(x.dtype) + lm.gqa_output(mw["gqa"], o.astype(x.dtype), s.gqa)
     elif kind == "eva":
         q, k, v = lm.eva_inputs(mw, h, pos, s.eva)
         n = len(cache["k"]) // s.count("eva")  # pools a layer, some of the heads each
@@ -773,8 +801,9 @@ def _put(items: tuple, i: int, value) -> tuple:
 
 
 def _logits(params, cfg, x):
-    x = lm.norm(x, params["final_norm"]["scale"], cfg)
-    return lm.head_logits(x, params["lm_head"]["kernel"], cfg).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = lm.norm(x, params["final_norm"]["scale"], cfg)
+        return lm.head_logits(x, params["lm_head"]["kernel"], cfg).astype(jnp.float32)
 
 
 def _seams(cfg):
@@ -817,7 +846,7 @@ def prefill_pack(params, cfg, tokens, segment_ids, positions, pack_pages, last_i
         write, read = _carrying(t, (write, read), (tick[0], _scoped(cfg.latent, tick[1])))
         tokens, positions, valid = (jnp.concatenate(a) for a in (
             (tokens, s_tokens), (positions, s_pos), (valid, s_active)))
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    x = lm.embedded(params, tokens, cfg)
     for l in range(cfg.num_layers):
         x, cache = _layer(cfg, l, params["layers"], x, positions, valid, cache,
                           write, read, t, probe)
@@ -1120,7 +1149,7 @@ def decode_step(params, cfg, tokens, seq_lens, block_tables, active, cache: Cach
     picked: list = []
     write, read = _seams(cfg)[1](cfg, seq_lens, block_tables, active, picked, probe)
     read = _scoped(cfg.latent, read)
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    x = lm.embedded(params, tokens, cfg)
     for l in range(cfg.num_layers):
         x, cache = _layer(cfg, l, params["layers"], x, seq_lens, active, cache,
                           write, read, 0, probe)
@@ -1238,7 +1267,7 @@ class LatentRunner:
 
     counters = COUNTERS
     packs_are_one_program = True  # a pack reads its own rows back from the cache
-    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn; full_attn
+    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn; full_attn; lm_head
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -1271,8 +1300,10 @@ class LatentRunner:
             self.counters = EVA_COUNTERS
             self.compaction = WindowCompaction(ev.window, ev.chunk)
         elif cfg.latent.stateful:
-            self.counters = WINDOW_COUNTERS if cfg.latent.ringed else STATE_COUNTERS
+            self.counters = WINDOW_COUNTERS if cfg.latent.ringed else \
+                PARALLEL_COUNTERS if cfg.latent.par else STATE_COUNTERS
             self._discarded = 0  # states a preemption left behind since the last dispatch
+            self._slot_bytes = self._page_bytes = 0  # of a slot's state, of a page: all blocks
         elif cfg.latent.every is not None:
             self.counters = MLA_COUNTERS
 
@@ -1284,6 +1315,10 @@ class LatentRunner:
         self._ring_rows = np.zeros(max_seqs, np.int64)
         cache = init_cache(self.cfg, num_blocks, block_size, max_seqs, pack_tokens)
         self._expert_layers = cache["stats"].shape[0]
+        if self.cfg.latent.par:
+            size = lambda keys: sum(a.nbytes for k in keys for a in cache[k])
+            self._slot_bytes = size(("ssm", "conv")) // max_seqs
+            self._page_bytes = size(("k", "v")) // num_blocks
         return cache
 
     def prefill_packed(self, *args, **kw):
@@ -1447,6 +1482,10 @@ class LatentRunner:
         counters["ssm_chunks_scanned"].inc(chunks * n_ssm)
         counters["ssm_states_recomputed"].inc(self._discarded)
         self._discarded = 0
+        if self.cfg.latent.par:  # both kinds of cache, every layer: what they hold now
+            rows = self._ring_rows
+            counters["state_bytes_live"].set(int(np.count_nonzero(rows)) * self._slot_bytes)
+            counters["kv_page_bytes_in_use"].set(int((-(-rows // bs)).sum()) * self._page_bytes)
         if pack:
             return {"ssm_segments": segments, "ssm_chunks": chunks}
         return {"ssm_live_slots": steps}
@@ -1479,7 +1518,7 @@ class LatentRunner:
             counters["eva_rows_live"].set(int(self.compaction.rows_live(n).sum()))
         if self.cfg.latent.indexed:
             counters["index_keys_selected"].set(picks_total(kv["picks"]))
-        if "touched" in kv:
+        if "touched" in kv and self._expert_layers:
             touched = np.asarray(kv["touched"]).astype(np.int64).sum(0)  # [pack | tick, 2]
             counters["experts_touched"].set(int(touched[:, 0].sum()))
             counters["experts_touched_decode"].set(int(touched[1, 0]))

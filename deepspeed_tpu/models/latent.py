@@ -54,6 +54,20 @@ where ``first_dense`` > 0); ``hybrid_params`` picks block ``l``'s.  The head may
 hold ``pred_heads`` heads' columns side by side, of which the next token reads
 the first ``vocab_size``.
 
+A two-norm block may instead hold TWO PARALLEL MIXERS (``par``, Falcon-H1's
+block): a Mamba-2 recurrence (``LatentSpec.mamba``) AND grouped-query attention
+(``LatentSpec.gqa``, rotated over the whole head) read ONE normed input and
+their outputs are SUMMED into the residual, in EVERY block, so a sequence keeps
+a recurrence's state and K / V pages side by side for every layer; a dense
+SwiGLU follows.  Every projection carries a CONSTANT multiplier (muP's, as
+configuration: ``Mamba.in_multiplier`` / ``multipliers`` / ``out_multiplier``,
+``Gqa.in_multiplier`` / ``key_multiplier`` / ``out_multiplier``, ``LatentSpec``'s
+for the embedding, the logits and the SwiGLU's gate and output), applied as
+written: the product is scaled in float32 and rounded once (``_mm``,
+``scaled``), since a multiplier is no bfloat16 number and folding it into
+bfloat16 weights is not exact.  Trees: ``layers/par`` = a tuple of ``{"mamba":
+.., "gqa": ..}`` a block, ``layers/mlp``; such a model's blocks are all of the kind.
+
 TRAINING (``CausalLM.loss_fn`` -> ``forward`` under ``jax.grad``, through the
 train engine): the blocks whose mixer is attention over K / V (``gattn``,
 ``wattn``, ``gqa``) and whose feed-forward is a SwiGLU or the held experts.
@@ -120,6 +134,11 @@ class Mamba:
     state: int
     conv: int
     chunk: int  # tokens a chunk of the uncached forward's scan (serving: a page)
+    # constant multipliers: on the normed input before ``W_in``, on the five segments
+    # z | x | B | C | dt of ``W_in``'s output (empty: none), on ``W_out``'s output
+    in_multiplier: float = 1.0
+    multipliers: Tuple[float, ...] = ()
+    out_multiplier: float = 1.0
 
     @property
     def d_in(self) -> int:
@@ -128,6 +147,15 @@ class Mamba:
     @property
     def conv_width(self) -> int:  # x, B and C go through the convolution
         return self.d_in + 2 * self.n_groups * self.state
+
+    @property
+    def in_scale(self) -> Optional[np.ndarray]:
+        """``multipliers`` laid over ``W_in``'s ``in_width`` outputs, float32."""
+        if not self.multipliers:
+            return None
+        gn = self.n_groups * self.state
+        return np.repeat(np.asarray(self.multipliers, np.float32),
+                         [self.d_in, self.d_in, gn, gn, self.num_heads])
 
     @property
     def in_width(self) -> int:  # [z | xBC | dt]
@@ -140,16 +168,25 @@ class Mamba:
 
 @dataclass(frozen=True)
 class Gqa:
-    """Grouped-query attention with no positional embedding."""
+    """Grouped-query attention: no positional embedding (``rope_theta`` 0), or
+    rotary positions (rotate-half) over the whole head at ``rope_theta``.
+    Constant multipliers on the normed input, on the keys BEFORE their rotation
+    and on ``W_o``'s output."""
 
     num_heads: int
     num_kv_heads: int
     head_dim: int
+    rope_theta: float = 0.0
+    in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    out_multiplier: float = 1.0
 
 
 # mixers of a two-norm block: a recurrence, gated attention over every key, ... over a
 # window, attention over a window's exact keys and the summaries of the windows before it
-HYBRID = ("gdn", "gattn", "wattn", "eva")
+# ... and ``par``: TWO mixers side by side on one normed input, summed
+HYBRID = ("gdn", "gattn", "wattn", "eva", "par")
+PAR_MIXERS = ("mamba", "gqa")  # the mixers a ``par`` block holds, ONE of each
 
 
 @dataclass(frozen=True)
@@ -277,6 +314,17 @@ class LatentSpec:
     # the next token's logits are the first head's
     pred_heads: int = 1
     fp32_logits: bool = False  # the head's product accumulates AND leaves in float32
+    # constants that belong to no mixer (``Mamba`` and ``Gqa`` carry their own): on the
+    # embedding's rows, on the logits, on a SwiGLU's gate INSIDE its SiLU and on its output
+    embedding_multiplier: float = 1.0
+    logits_multiplier: float = 1.0
+    mlp_gate_multiplier: float = 1.0
+    mlp_down_multiplier: float = 1.0
+
+    @property
+    def par(self) -> bool:
+        """Blocks of two parallel mixers (``mamba`` + ``gqa``)."""
+        return "par" in self.layer_kinds
 
     @property
     def single(self) -> bool:
@@ -305,7 +353,7 @@ class LatentSpec:
     def recurrence(self):
         """(kind, mixer) of a ``stateful`` model's recurrence (the mixer None
         for a model of attention alone)."""
-        return ("mamba", self.mamba) if self.single else ("gdn", self.gdn)
+        return ("mamba", self.mamba) if self.single or self.par else ("gdn", self.gdn)
 
     def mixer(self, kind: str):
         """The spec of a ``stateful`` model's mixer of ``kind`` (SINGLE's, HYBRID's)."""
@@ -314,7 +362,7 @@ class LatentSpec:
     @property
     def attention(self):
         """(kind, mixer) of a ``stateful`` model's attention over K / V PAGES."""
-        kind = "gqa" if self.single else "gattn"
+        kind = "gqa" if self.single or self.par else "gattn"
         return kind, self.mixer(kind)
 
     @property
@@ -331,7 +379,9 @@ class LatentSpec:
         return getattr(self, kind)  # 'full' | 'sliding' | 'every'
 
     def count(self, kind: str) -> int:
-        return sum(k == kind for k in self.layer_kinds)
+        """Layers that hold a mixer of ``kind`` (a block of parallel mixers holds
+        one of each of its two)."""
+        return sum(k == kind or (k == "par" and kind in PAR_MIXERS) for k in self.layer_kinds)
 
     @property
     def index_scale(self) -> float:
@@ -395,6 +445,9 @@ def _hybrid_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
         return {"w_qkvz": (d, gd.conv_width + gd.d_in), "w_ba": (d, 2 * gd.num_v_heads),
                 "conv_w": (gd.conv, gd.conv_width), "dt_bias": (gd.num_v_heads,),
                 "a_log": (gd.num_v_heads,), "norm": (gd.v_dim,), "w_out": (gd.d_in, d)}
+    if kind == "par":  # both mixers' trees, by ``<mixer>/<name>``
+        return {f"{mixer}/{name}": shape for mixer in PAR_MIXERS
+                for name, shape in _single_shapes(d, s, mixer).items()}
     if kind == "eva":
         ev = s.eva
         width = ev.num_heads * ev.head_dim
@@ -446,7 +499,7 @@ def flops_per_token(cfg, seq_len: int) -> float:
     shared expert), and attention's (query, key) pairs under each layer's mask
     at 12 x heads x head_dim a pair.  For the kinds that train (``forward``)."""
     s, d = cfg.latent, cfg.hidden_size
-    if not s.stateful or any(k in ("mamba", "gdn", "eva") for k in s.layer_kinds):
+    if not s.stateful or any(k in ("mamba", "gdn", "eva", "par") for k in s.layer_kinds):
         refuse("CausalLM.flops_per_token", "only blocks of attention over K / V, SwiGLU and "
                "held experts are counted (they are the kinds that train)")
     size = lambda shapes, names: sum(int(np.prod(shapes[n])) for n in names if n in shapes)
@@ -484,6 +537,8 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         fan-in); the router's bias as below; a recurrence's ``dt_bias`` so that
         softplus lands log-uniform in [0.001, 0.1], ``A = -exp(a_log)`` in
         -U(1, 16), ``D`` ones, and those float32 as the recurrence reads them."""
+        if kind == "par":
+            return {mixer: single(mixer) for mixer in PAR_MIXERS}
         shapes = _hybrid_shapes(d, s, kind) if kind in HYBRID else _single_shapes(d, s, kind)
         w = {name: dense(sh, sh[-2]) for name, sh in shapes.items()
              if len(sh) >= 2 and name not in ("phi", "mu")}
@@ -533,11 +588,13 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
             layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
         return head(layers, jnp.ones((d,), dtype))
     if s.hybrid:
+        if 0 < s.count("par") < L:
+            raise ValueError("a model's blocks are all of two parallel mixers or none is")
         layers = {"attn_norm": {"scale": norm_weight((L, d))},
                   "mlp_norm": {"scale": norm_weight((L, d))},
                   "moe": tuple(single("experts") for _ in range(L - s.first_dense))}
         for kind in HYBRID:
-            if kind != "eva" or s.count(kind):  # (the older kinds' trees are there, empty or not)
+            if kind not in ("eva", "par") or s.count(kind):  # (the older kinds' trees are there, empty or not)
                 layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
         if s.first_dense:
             f = cfg.intermediate_size
@@ -639,6 +696,30 @@ def rms_centred(x, w, eps):
 def norm(x, scale, cfg):
     """The model's RMSNorm by its spec (``unit_offset``)."""
     return (rms_centred if cfg.latent.unit_offset else rms)(x, scale, cfg.norm_eps)
+
+
+def scaled(x, m):
+    """``x`` times the constant ``m`` (a float, or float32 values along the last
+    axis): the product in float32, rounded ONCE to ``x``'s dtype.  A multiplier
+    is no bfloat16 number (0.3536 rounds by 0.1%), so it is not cast to one."""
+    if np.ndim(m) == 0 and m == 1:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
+def _mm(h, w, m=1.0):
+    """``(h @ w) m``: with a constant multiplier the product leaves the matmul in
+    float32, is scaled there and rounded once (an epilogue of the matmul's
+    fusion); without one, ``h @ w`` as every other projection."""
+    if m is None or (np.ndim(m) == 0 and m == 1):
+        return h @ w
+    return (jnp.dot(h, w, preferred_element_type=jnp.float32) * m).astype(h.dtype)
+
+
+def embedded(params: Params, tokens, cfg):
+    """The tokens' embedding rows in the model's dtype, times their multiplier."""
+    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
+    return scaled(x, cfg.latent.embedding_multiplier)
 
 
 def yarn_ramp(r: int, theta: float, y: Yarn) -> np.ndarray:
@@ -789,7 +870,7 @@ def _probed(probe, t: int, x, b, c, dt) -> None:
 def _mamba_inputs(mw, h, mb: Mamba):
     """h [T, d] -> (gate z [T, d_in], xBC [T, C] before the convolution, the
     step sizes softplus(dt + dt_bias) [T, H] float32)."""
-    zxd = h @ mw["w_in"]
+    zxd = _mm(scaled(h, mb.in_multiplier), mw["w_in"], mb.in_scale)
     z, xbc, dt = jnp.split(zxd, [mb.d_in, mb.d_in + mb.conv_width], axis=-1)
     return z, xbc, jax.nn.softplus(dt.astype(jnp.float32) + mw["dt_bias"])
 
@@ -809,15 +890,30 @@ def _mamba_output(mw, y, x, z, mb: Mamba, eps: float, dtype):
     y = (y + mw["d_skip"][:, None] * x).reshape(t, mb.d_in) * jax.nn.silu(z.astype(jnp.float32))
     yg = y.reshape(t, mb.n_groups, -1)
     yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + eps)
-    return (yg.reshape(t, mb.d_in).astype(dtype) * mw["norm"]) @ mw["w_out"]
+    return _mm(yg.reshape(t, mb.d_in).astype(dtype) * mw["norm"], mw["w_out"], mb.out_multiplier)
 
 
-def gqa_inputs(aw, h, g: Gqa):
-    """h [T, d] -> (q [T, Hq, hd], k, v [T, Hkv, hd]); no positions.  The
+def gqa_inputs(aw, h, g: Gqa, pos=None):
+    """h [T, d] -> (q [T, Hq, hd], k, v [T, Hkv, hd]): no positions, or q and k
+    rotated over the whole head at ``pos`` [T] where the spec has a
+    ``rope_theta`` (the keys times their multiplier BEFORE the rotation).  The
     barrier keeps the head split out of the dots (``model_runner._qkv``)."""
-    q, k, v = jax.lax.optimization_barrier((h @ aw["wq"], h @ aw["wk"], h @ aw["wv"]))
+    h = scaled(h, g.in_multiplier)
+    q, k, v = jax.lax.optimization_barrier(
+        (h @ aw["wq"], _mm(h, aw["wk"], g.key_multiplier), h @ aw["wv"]))
     heads = lambda a, n: a.reshape(a.shape[0], n, g.head_dim)
-    return heads(q, g.num_heads), heads(k, g.num_kv_heads), heads(v, g.num_kv_heads)
+    q, k, v = heads(q, g.num_heads), heads(k, g.num_kv_heads), heads(v, g.num_kv_heads)
+    if g.rope_theta:
+        if pos is None:
+            raise ValueError("grouped-query attention with rotary positions needs the rows' "
+                             "positions (single-mixer blocks hand none)")
+        q, k = _rope(q, pos, g.rope_theta), _rope(k, pos, g.rope_theta)
+    return q, k, v
+
+
+def gqa_output(aw, o, g: Gqa):
+    """Attention's output o [T, Hq, hd] through ``W_o`` (times its multiplier)."""
+    return _mm(o.reshape(o.shape[0], -1), aw["wo"], g.out_multiplier)
 
 
 def gdn_chunks(gw, h, valid, cont, conv_prev, state_prev, gd: Gdn, eps: float, probe=None):
@@ -969,9 +1065,11 @@ def head_logits(x, kernel, cfg):
     s = cfg.latent
     if s.pred_heads > 1:
         kernel = kernel[:, :cfg.vocab_size]
+    m = s.logits_multiplier
     if s.fp32_logits:
-        return jnp.dot(x, kernel, preferred_element_type=jnp.float32)
-    return x @ kernel
+        out = jnp.dot(x, kernel, preferred_element_type=jnp.float32)
+        return out if m == 1.0 else out * m
+    return _mm(x, kernel, m)
 
 
 def ffn(fw, h, is_moe: bool, cfg, valid=None, tile=None):
@@ -979,7 +1077,9 @@ def ffn(fw, h, is_moe: bool, cfg, valid=None, tile=None):
     [T, k], every expert's score [T, n_routed]), else None).  ``tile``: the
     expert layer's row tile where the caller chooses it (``moe_block_held``)."""
     if not is_moe:
-        return (jax.nn.silu(h @ fw["w_gate"]) * (h @ fw["w_up"])) @ fw["w_down"], None
+        s = cfg.latent  # (multipliers of 1.0: the products as they were)
+        gate = _mm(h, fw["w_gate"], s.mlp_gate_multiplier)
+        return _mm(jax.nn.silu(gate) * (h @ fw["w_up"]), fw["w_down"], s.mlp_down_multiplier), None
     from ..moe.layer import moe_block_held
 
     return moe_block_held(fw, h, cfg.latent, valid, tile)
@@ -1002,7 +1102,7 @@ def forward(params: Params, tokens, cfg, *, return_hidden: bool = False):
     note_step_fact("tokens", b * n)
     note_step_fact("layers_with_experts", len(s_.expert_layers))
     pos = jnp.tile(jnp.arange(n), b)
-    x = params["embed"]["embedding"][tokens.reshape(-1)].astype(cfg.dtype)
+    x = embedded(params, tokens.reshape(-1), cfg)
     grouped = lambda a: a.reshape(b, n, *a.shape[1:])
     if s_.single:
         return _head(params, _single_blocks(params["layers"], x, b, n, cfg), b, n, cfg,
@@ -1061,7 +1161,7 @@ def _single_blocks(layers: Params, x, b: int, n: int, cfg):
             y = _chunked_uncached(mamba_chunks, w, grouped(h), s_.mamba, cfg.norm_eps)
             y = _forward_only(y.reshape(b * n, -1), "the chunked state-space scan (ops/ssm.py)")
         elif kind == "gqa":
-            y = _attend(*gqa_inputs(w, h, s_.gqa), b, n, 0, cfg).reshape(b * n, -1) @ w["wo"]
+            y = gqa_output(w, _attend(*gqa_inputs(w, h, s_.gqa), b, n, 0, cfg), s_.gqa)
         else:
             y = ffn(w, h, True, cfg)[0]
         x = x + y.astype(x.dtype)
@@ -1112,6 +1212,11 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
         if kind == "gdn":
             y = _chunked_uncached(gdn_chunks, mw, h.reshape(b, n, -1), s_.gdn, eps)
             y = _forward_only(y.reshape(b * n, -1), "the chunked delta rule (ops/gdn.py)")
+        elif kind == "par":  # the SUM of the recurrence's side and attention's, one input
+            y = _chunked_uncached(mamba_chunks, mw["mamba"], h.reshape(b, n, -1), s_.mamba, eps)
+            y = _forward_only(y.reshape(b * n, -1), "the chunked state-space scan (ops/ssm.py)")
+            q, k, v = gqa_inputs(mw["gqa"], h, s_.gqa, pos)
+            y = y + gqa_output(mw["gqa"], _attend(q, k, v, b, n, 0, cfg), s_.gqa)
         elif kind == "eva":
             from ..ops import eva
 
@@ -1157,8 +1262,8 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
             count_in_step("expert_layers_bounded", bounded)
         if kind not in ("gdn", "eva"):
             count_in_step("causal_keys", jnp.float32(b * allowed_pairs(n)))
-            count_in_step("window_keys_attended",
-                          jnp.float32(b * allowed_pairs(n, s_.mixer(kind).window)))
+            window = 0 if kind == "par" else s_.mixer(kind).window  # (0: every key)
+            count_in_step("window_keys_attended", jnp.float32(b * allowed_pairs(n, window)))
     return x, aux, tuple(handed)
 
 

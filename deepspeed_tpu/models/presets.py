@@ -147,3 +147,31 @@ register("tiny_alibi", TransformerConfig(
     norm="layernorm", activation="gelu", gated_mlp=False,
     qkv_bias=True, attn_out_bias=True, mlp_bias=True, embedding_norm=True,
     tie_embeddings=True, attn_impl="reference"))
+
+
+def _tiny_parallel_mixers() -> TransformerConfig:
+    """Blocks of TWO PARALLEL MIXERS on one normed input (``models/latent.py``:
+    ``par``; Falcon-H1's block, https://huggingface.co/tiiuae/Falcon-H1-34B-Instruct):
+    a Mamba-2 recurrence (2 groups, a state wider than its head) beside GQA at a
+    group of 5 with rotary over the whole head, a dense SwiGLU, a constant
+    multiplier on every projection.  For the CPU tests only: served through
+    ``InferenceEngineV2`` (a recurrence's state AND K / V pages for every layer),
+    forward through ``CausalLM``; no backward."""
+    from .latent import Gqa, LatentSpec, Mamba
+
+    n = 2
+    spec = LatentSpec(
+        layer_kinds=("par",) * n, full=None, sliding=None, index_heads=0, index_dim=0,
+        index_topk=0, first_dense=n, n_routed=0, n_held=0, held_offset=0, experts_per_tok=0,
+        moe_width=0, n_shared=0,
+        mamba=Mamba(num_heads=8, head_dim=8, n_groups=2, state=16, conv=4, chunk=8,
+                    in_multiplier=0.8, multipliers=(0.9, 0.8, 0.7, 0.6, 0.5), out_multiplier=0.5),
+        gqa=Gqa(num_heads=10, num_kv_heads=2, head_dim=16, rope_theta=10_000.0,
+                in_multiplier=0.9, key_multiplier=0.7, out_multiplier=0.5),
+        embedding_multiplier=4.0, mlp_gate_multiplier=0.9, mlp_down_multiplier=0.4, fp32_logits=True)
+    return TransformerConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=n, num_heads=10,
+        num_kv_heads=2, head_dim=16, max_seq_len=128, norm_eps=1e-5, latent=spec)
+
+
+register("tiny_parallel_mixers", _tiny_parallel_mixers())

@@ -151,3 +151,95 @@ def test_mixer_in_chunks_equals_the_mixer_in_steps():
     for i in range(n, n + 3):
         y, state, tail = lm.mamba_step(w, h[i:i + 1], jnp.asarray([True]), tail, state, MB, 1e-5)
         assert np.abs(np.asarray(y[0]) - np.asarray(whole[i])).max() <= 1e-4
+
+
+# (heads, channels a head, groups, state): two groups and a state WIDER than the head, as
+# the block of two parallel mixers runs them (32 x 128, 2 groups, state 256: N = 2 P, 16
+# heads a group), beside the other proportions that keep 2 groups
+WIDE_STATES = [(8, 4, 2, 8), (32, 2, 2, 4), (6, 2, 2, 16), (4, 8, 2, 32)]
+
+
+def _wide_inputs(seed, t, h, p, r, n):
+    g = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(g.standard_normal(s), jnp.float32)
+    dt = jnp.asarray(g.uniform(0.001, 0.3, (t, h)), jnp.float32)
+    return f(t, h, p), dt, -jnp.asarray(g.uniform(1.0, 16.0, (h,)), jnp.float32), f(t, r, n), f(t, r, n)
+
+
+def _wide_recurrence(x, dt, a, b, c, s0):
+    """``_recurrence`` at any (H, P, R, N): head ``h`` reads group ``h // (H / R)``."""
+    rep = x.shape[1] // b.shape[1]
+    bh, ch = jnp.repeat(b, rep, axis=1), jnp.repeat(c, rep, axis=1)
+
+    def token(s, t):
+        x_t, dt_t, b_t, c_t = t
+        s = jnp.exp(dt_t * a)[:, None, None] * s + (dt_t[:, None] * x_t)[..., None] * b_t[:, None]
+        return s, jnp.sum(s * c_t[:, None], -1)
+
+    s, y = jax.lax.scan(token, s0, (x, dt, bh, ch))
+    return y, s
+
+
+@pytest.mark.parametrize("shape", WIDE_STATES, ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("t", [L - 1, 2 * L + 3])
+def test_chunked_scan_at_two_groups_and_a_state_wider_than_the_head(shape, t):
+    h, p, r, n = shape
+    assert r == 2 and n > p
+    x, dt, a, b, c = _wide_inputs(t + h, t, *shape)
+    s0 = jnp.asarray(np.random.default_rng(1).standard_normal((h, p, n)), jnp.float32)
+    y_ref, s_ref = _wide_recurrence(x, dt, a, b, c, s0)
+    g = -(-t // L)
+    pad = lambda v: jnp.pad(v, ((0, g * L - t),) + ((0, 0),) * (v.ndim - 1)
+                            ).reshape(g, L, *v.shape[1:])
+    y, states = ssm.ssm_scan(pad(x), pad(dt), a, pad(b), pad(c),
+                             jnp.broadcast_to(s0, (g, h, p, n)), jnp.arange(g) > 0)
+    scale = lambda v: TOL * max(1.0, float(jnp.abs(v).max()))
+    assert np.abs(np.asarray(y.reshape(g * L, h, p)[:t]) - np.asarray(y_ref)).max() <= scale(y_ref)
+    assert np.abs(np.asarray(states[-1]) - np.asarray(s_ref)).max() <= scale(s_ref)
+
+
+@pytest.mark.parametrize("shape", WIDE_STATES, ids=lambda s: "x".join(map(str, s)))
+def test_step_at_two_groups_and_a_state_wider_than_the_head(shape):
+    h, p, r, n = shape
+    s = jnp.asarray(np.random.default_rng(2).standard_normal((3, h, p, n)), jnp.float32)
+    x, dt, a, b, c = _wide_inputs(7, 3, *shape)
+    active = jnp.asarray([False, True, True])
+    y, new = ssm.ssm_step(s, x, dt, a, b, c, active)
+    for i in range(3):
+        y_ref, s_ref = _wide_recurrence(x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], s[i])
+        assert np.abs(np.asarray(y[i]) - np.asarray(y_ref[0])).max() <= TOL * 10
+        want = s_ref if active[i] else s[i]
+        assert np.abs(np.asarray(new[i]) - np.asarray(want)).max() <= (TOL * 10 if active[i] else 0)
+
+
+def test_a_mixers_constant_multipliers_are_the_scaled_projections():
+    """``Mamba.in_multiplier`` on the input, ``multipliers`` over the five segments
+    z | x | B | C | dt of ``W_in``'s output and ``out_multiplier`` on ``W_out``'s:
+    the mixer with them equals the mixer without on weights scaled alike."""
+    import dataclasses
+
+    d, n, r = 16, L + 5, np.random.default_rng(8)
+    mup = (0.9, 0.8, 0.7, 0.6, 0.5)
+    scaled = dataclasses.replace(MB, in_multiplier=0.25, multipliers=mup, out_multiplier=0.4)
+    assert scaled.in_scale.shape == (MB.in_width,) and MB.in_scale is None
+    assert list(scaled.in_scale[[0, H * P, 2 * H * P, 2 * H * P + R * N, -1]]) == \
+        [np.float32(v) for v in mup]
+    spec = lm.LatentSpec(layer_kinds=("mamba",), full=None, sliding=None, index_heads=0,
+                         index_dim=0, index_topk=0, first_dense=0, n_routed=1, n_held=1,
+                         held_offset=0, experts_per_tok=1, moe_width=8, n_shared=1, mamba=MB)
+    w = {k: jnp.asarray(r.standard_normal(s) / np.sqrt(s[0] if len(s) > 1 else 1), jnp.float32)
+         for k, s in lm._single_shapes(d, spec, "mamba").items()}
+    folded = {**w, "w_in": w["w_in"] * 0.25 * scaled.in_scale, "w_out": w["w_out"] * 0.4}
+    h = jnp.asarray(r.standard_normal((2, L, d)), jnp.float32)
+    valid = (jnp.arange(2 * L) < n).reshape(2, L)
+    args = (valid, jnp.asarray([False, True]), jnp.zeros((2, K - 1, MB.conv_width)),
+            jnp.zeros((2, H, P, N)))
+    got = lm.mamba_chunks(w, h, *args, scaled, 1e-5)
+    want = lm.mamba_chunks(folded, h, *args, MB, 1e-5)
+    for a, b in zip(got, want):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() <= 1e-5
+    tail, state = got[2][-1:], got[1][-1:]
+    y1 = lm.mamba_step(w, h[1, :1], jnp.asarray([True]), tail, state, scaled, 1e-5)
+    y2 = lm.mamba_step(folded, h[1, :1], jnp.asarray([True]), tail, state, MB, 1e-5)
+    for a, b in zip(y1, y2):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() <= 1e-5
