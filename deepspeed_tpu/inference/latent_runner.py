@@ -115,8 +115,18 @@ beside ``full_attn``: ``la.carried_step``).  What still runs a kind of row: a
 recurrence's own projections, which live inside its ``write`` (``(w, h)``).
 Without ``step`` the entries trace what they always traced.  The entry takes a
 step for every family; which families' ENGINES hand it one is the runner's
-``packs_carry_step`` (``LatentRunner.__init__``: the two-norm blocks that keep
-no recurrence's state).  Plain XLA bodies
+``packs_carry_step``, ONE expression of ``LatentSpec``'s own fields
+(``LatentRunner.__init__``): two-norm blocks (``hybrid``) that keep no
+recurrence's state (gated attention on pages and rings, EVA) OR hold no routed
+layer (``expert_layers`` empty: two parallel mixers beside a dense SwiGLU in
+every block, PR 59).  The mixed program is a THIRD XLA program whose rows differ
+from the pack's and the step's by rounding; a dense model's logits move by
+rounding's own size, a router's near tie behind a recurrence's state may fall
+the other way.  So the families with a recurrence AND routed experts
+(single-mixer blocks, Gated DeltaNet) keep two programs until their cells'
+token margins allow a flip (ROADMAP S2 (0)), and the selector's and the
+every-row latent families keep two for what a mixed tick cost them on the chip
+(PERF.md §6, PR 56).  Plain XLA bodies
 (``ops/latent_attention.py``) but for five Pallas kernels on the chip: a
 pack's index scores (``ops/pallas/index_scores.py``), its shorter groups'
 attention over their picks (``ops/pallas/selected_attention.py``: an ``every``
@@ -1276,17 +1286,25 @@ class LatentRunner:
         # keeping its own state, ring, page write and kernel (``_carrying``).  The
         # entry carries a step for EVERY family (``tests/test_mixed_program.py``); the
         # ENGINE is told so for the two-norm blocks that keep no recurrence's state
-        # (gated attention on pages and rings, EVA).  The others keep two programs,
-        # each for what the chip read (PERF.md §6, PR 56): a mixed program is a THIRD
-        # XLA program of the same bodies, a router's near tie may fall the other way
-        # in it, and the benchmark holds the scheduler's tokens to the UNMIXED replay:
-        # single-mixer blocks by 0.05 a token (read 0.2265 and 0.2042: not correct by
-        # the cell's own limit), Gated DeltaNet's by 0.2 (read 0.2663 on one seed of
-        # four), the selector's layers by 0.05 (read 0.0482, and the cell LOST 3.3%: a
-        # ~150 ms pack carries 16 rows' gathers); latent attention over every row
-        # gained nothing (-0.2%: its tick is a 123 ms pack).
+        # (gated attention on pages and rings, EVA) OR hold no routed layer (two
+        # parallel mixers beside a dense SwiGLU in every block: PR 59).  A mixed
+        # program is a THIRD XLA program of the same bodies whose rows differ from
+        # the other two's by rounding, and the benchmark holds the scheduler's tokens
+        # to the UNMIXED replay: rounding moves a dense model's logits by its own
+        # size and no more, but where a ROUTER sits between a recurrence's state and
+        # the logits a near tie may fall the other way and a token move by tenths.
+        # So the families with a recurrence AND routed experts keep two programs
+        # until their cells' token margins say what such a flip may cost (ROADMAP
+        # S2 (0), a ``benchmark`` PR), each for what the chip read (PERF.md §6, PR
+        # 56): single-mixer blocks by 0.05 a token (read 0.2265 and 0.2042: not
+        # correct by the cell's own limit), Gated DeltaNet's by 0.2 (read 0.2663 on
+        # one seed of four).  The other two stay for what they would pay: the
+        # selector's layers LOST 3.3% (a ~150 ms pack carries 16 rows' gathers; read
+        # 0.0482 of its 0.05), latent attention over every row gained nothing
+        # (-0.2%: its tick is a 123 ms pack).
         s = cfg.latent
-        self.packs_carry_step = bool(getattr(s, "hybrid", False) and s.recurrence[1] is None)
+        self.packs_carry_step = bool(getattr(s, "hybrid", False) and (
+            s.recurrence[1] is None or not s.expert_layers))
         self._ring_rows = np.zeros(0, np.int64)
         self._block = 1
         self._expert_layers = 0

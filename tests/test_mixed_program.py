@@ -206,21 +206,25 @@ FAMILIES = {
     "deltanet": ("qwen3_next_l8_e128", "gdn"),   # gdn + gated attention on pages + experts
     "windowed": ("laguna_xs2_l5", None),         # gated attention on pages beside rings + experts
     "eva": ("evabyte_l8", None),                 # EVA attention on a table that shrinks
+    "parallel": ("falcon_h1_34b_l6", "mamba"),   # mamba AND gqa in every block, a dense SwiGLU
 }
 
 
-def _latent_cfg(family):
-    m = harness.rehearsed(harness.load_json(
-        ROOT / f"benchmark/configs/{FAMILIES[family][0]}_serve_1chip.json"), True)
+def _config_of(path, rehearse):
+    m = harness.rehearsed(harness.load_json(path), rehearse)
     return harness.module("models", m["model_type"]).transformer_config(
         m, max_seq_len=m["engine"]["max_seq_len"])
 
 
+def _latent_cfg(family):
+    return _config_of(ROOT / f"benchmark/configs/{FAMILIES[family][0]}_serve_1chip.json", True)
+
+
 # the families whose ENGINE mixes (``LatentRunner.packs_carry_step``: the two-norm
-# blocks that keep no recurrence's state); the runner's entry carries a step for
-# every family, and the tests below hold every family to it through
-# ``every_family_carries``
-CARRIED = {"windowed", "eva"}
+# blocks that keep no recurrence's state, or hold no routed layer: PR 59); the
+# runner's entry carries a step for every family, and the tests below hold every
+# family to it through ``every_family_carries``
+CARRIED = {"windowed", "eva", "parallel"}
 
 
 @pytest.fixture
@@ -285,12 +289,16 @@ def test_a_mixed_latent_pack_streams_each_weight_once(family):
     if recurrence is None:
         assert not split
     else:
-        own = {a.shape for a in jax.tree_util.tree_leaves(params["layers"][recurrence])}
+        # (a block of two parallel mixers keeps its recurrence's weights beside its
+        # attention's, a level down: ``layers["par"][l]["mamba"]``)
+        own = {a.shape for path, a in jax.tree_util.tree_leaves_with_path(params["layers"])
+               if any(getattr(k, "key", None) == recurrence for k in path)}
         assert split and set(split) <= own
         assert all(by_rows(mixed, b)[w] >= n for w, n in split.items())
     grouped = lambda found: {(lhs[0], rhs): n for (name, lhs, rhs), n in found.items()
                              if name.startswith("ragged_dot")}
-    if family == "eva":  # (no experts)
+    if not s.expert_layers:  # (a dense feed-forward in every block)
+        assert family in ("eva", "parallel")
         assert not grouped(alone) and not grouped(mixed)
         return
     tile = held_row_tile(t, s)
@@ -354,7 +362,11 @@ def test_the_mixed_latent_program_leaves_what_the_pack_and_then_the_step_leave(
             np.testing.assert_allclose(a.astype(np.float32), b.astype(np.float32),
                                        rtol=2e-5, atol=2e-6, err_msg=name)
     assert (kv["stats"][:, :2] == ref["stats"][:, :2]).all()
-    if "touched" in kv:
+    if family == "parallel":  # what a slot keeps for EVERY layer: state, conv tail, K / V pages
+        assert {"ssm", "conv", "k", "v"} <= set(kv), sorted(kv)
+        assert all((a != b).any() for name in ("ssm", "conv", "k", "v") for a, b in zip(
+            jax.tree_util.tree_leaves(kv[name]), jax.tree_util.tree_leaves(t0[name])))  # every layer's moved
+    if "touched" in kv and kv["touched"].size:
         mine, theirs = kv["touched"] - t0["touched"], ref["touched"] - t0["touched"]
         assert not mine[:, 1].any() and theirs[:, 1].any()  # [layer, pack | tick, (experts, pairs)]
         assert (mine[:, 0, 1] == theirs[:, :, 1].sum(1)).all()
@@ -385,7 +397,8 @@ def latent_program_hashes(eng):
 
 
 # as the parent of PR 56 (4ffe58e) traced them, character for character (the first
-# two of ``indexed`` are PR 54's pins of its own parent, 09c2032)
+# two of ``indexed`` are PR 54's pins of its own parent, 09c2032; ``parallel`` as the
+# parent of PR 59, 0fd9cb0, traced them)
 PARENTS_LATENT_PROGRAMS = {
     "indexed": {
         "_decode_jit": "e45a8c0735c21aad134475476f384d55fc3d9cd86000c05d2e50adbd79a33b4c",
@@ -417,6 +430,11 @@ PARENTS_LATENT_PROGRAMS = {
         "_decode_burst_jit": "56fb9f00dc52c50efffa626d523db819a3e15d52f7cf362d7aa1a84968d53c75",
         "prefill_packed_ctx": "0d4c20605549827b67a2b93e2d36e98e70b89b64b1e8421d2c99ce52ae3f580b",
     },
+    "parallel": {
+        "_decode_jit": "85099938ecc1ea2c71cc7722ad75bde6d9b5ff3665d990319411558a12245917",
+        "_decode_burst_jit": "4508e456dea3e4b9d02f4703ac4488fda3d7e772e162221cef365af938925c49",
+        "prefill_packed_ctx": "01f7e54bd89b0f2da9fd59c786cabf4b4b95d1506910abef822686a10440c655",
+    },
 }
 
 
@@ -429,7 +447,7 @@ def test_a_latent_engines_programs_without_a_step_are_the_parents(family):
     assert not any(eng.close().values())
 
 
-@pytest.mark.parametrize("family", ["indexed", "windowed"])
+@pytest.mark.parametrize("family", ["indexed", "windowed", "parallel"])
 def test_a_latent_engine_mixes_every_pack_beside_decoding_rows_where_its_family_carries(family):
     """The scheduler's run of PR 54's pin, on the contract of PR 56: a prompt of
     three chunks arrives while another request decodes.  Where the family
@@ -467,6 +485,7 @@ STEP_SCOPES = {
     "deltanet": {"gated_attn_step", "gdn_conv_step", "gdn_step"},
     "windowed": {"full_attn_step", "window_attn_step"},
     "eva": {"eva_attend_step", "eva_summarise_step"},
+    "parallel": {"gqa_attn_step", "ssm_step"},
 }
 
 
@@ -506,3 +525,41 @@ def test_a_carried_steps_scopes_are_siblings_of_the_packs_never_children(family)
         assert not (steps and pack_names & set(s)), s
     # and the pack's own scopes are all still there
     assert {c for s in alone for c in s} & pack_names == {c for s in mixed for c in s} & pack_names
+
+
+# which ``cfg.latent`` serving configurations' ENGINES mix, as a table (PR 59): two-norm
+# blocks (``hybrid``) that keep no recurrence's state OR hold no routed layer
+PACKS_CARRY_STEP = {
+    "laguna_xs2_l5": True,              # gated attention on pages and rings: no recurrence
+    "evabyte_l8": True,                 # EVA attention: no recurrence (and no experts)
+    "falcon_h1_34b_l6": True,           # a recurrence, and a dense SwiGLU in every block
+    "qwen3_next_l8_e128": False,        # a recurrence AND routed experts (ROADMAP S2 (0))
+    "nemotron3_super_l11_e128": False,  # single-mixer blocks, a recurrence AND routed experts
+    "dots3_note_l5_e32": False,         # latent pages and rings: -3.3% on the chip (PR 56)
+    "deepseek_v2_l5_e40": False,        # latent attention over every row: -0.2% (PR 56)
+}
+
+
+@pytest.fixture(scope="module")
+def serving_configs():
+    """{name: the model's configuration at its REAL size} of every serving
+    configuration under ``benchmark/configs/`` that a ``LatentRunner`` serves."""
+    suffix = "_serve_1chip.json"
+    found = {path.name[:-len(suffix)]: _config_of(path, False)
+             for path in sorted((ROOT / "benchmark/configs").glob("*" + suffix))}
+    return {name: cfg for name, cfg in found.items() if getattr(cfg, "latent", None) is not None}
+
+
+@pytest.mark.parametrize("name", sorted(PACKS_CARRY_STEP))
+def test_which_latent_engines_mix_is_what_the_spec_says(serving_configs, name):
+    """The rule reads ``LatentSpec``'s own fields and nothing else: no model's
+    name, no knob.  The table names every latent serving configuration there is."""
+    configs = serving_configs
+    assert sorted(configs) == sorted(PACKS_CARRY_STEP)
+    s = configs[name].latent
+    said = bool(s.hybrid and (s.recurrence[1] is None or not s.expert_layers))
+    assert latent_runner.LatentRunner(configs[name]).packs_carry_step is said is PACKS_CARRY_STEP[name]
+    if name == "falcon_h1_34b_l6":  # what sets it apart from cell 7's family
+        assert s.par and s.recurrence[0] == "mamba" and not s.expert_layers and not s.n_held
+    if name == "qwen3_next_l8_e128":
+        assert s.hybrid and s.recurrence[0] == "gdn" and s.expert_layers and s.n_held

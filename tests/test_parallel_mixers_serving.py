@@ -352,3 +352,74 @@ def test_the_tiny_preset_serves_what_its_forward_computes():
     for u, p in prompts.items():
         assert _short(forward, params, p, sched.pop_result(u)) <= TOL, u
     assert eng.close() == {"blocks_in_use": 0, "cached_blocks": 0, "ssm_states": 0}
+
+
+def test_a_chunk_beside_decoding_rows_is_one_program_and_leaves_what_two_left(monkeypatch):
+    """PR 59: this family's ENGINE mixes (a recurrence, but no routed layer).  A
+    prompt of two chunks arrives while two requests decode: the tick that holds
+    its first chunk makes ONE upload for ONE program (the pack, the step's two
+    rows inside it) and that program is fetched ONCE, where an engine told not to
+    mix makes a pack and a step; after it the two gauges read what the two
+    programs left, and the requests end on the same tokens."""
+    from deepspeed_tpu.models import get_preset
+
+    cfg = get_preset("tiny_parallel_mixers")
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    init = latent_runner.LatentRunner.__init__
+
+    def told_not_to(self, cfg):
+        init(self, cfg)
+        self.packs_carry_step = False
+
+    def run(mixes):
+        with monkeypatch.context() as mp:
+            if not mixes:
+                mp.setattr(latent_runner.LatentRunner, "__init__", told_not_to)
+            eng = _engine(cfg, params, max_seq_len=128, telemetry=True)
+        assert eng.runner.packs_carry_step is eng.packs_carry_step is mixes
+        sched = eng.scheduler
+        rng = np.random.default_rng(6)
+        draw = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()
+        sched.submit(1, draw(9), GREEDY(12))
+        sched.submit(2, draw(5), GREEDY(12))
+        for _ in range(3):
+            sched.tick()
+        sched.submit(3, draw(41), GREEDY(4))  # chunks of 32 + 9, beside rows 1 and 2
+        ticks = []
+        for _ in range(2):  # the first chunk's tick; the second's, which fetches the first's program
+            before, seen = dict(eng.stats), len(eng.telemetry.recorder.chrome_events())
+            sched.tick()
+            spans = [e for e in eng.telemetry.recorder.chrome_events()[seen:] if e.get("ph") == "X"]
+            ticks.append(dict(
+                dispatched=sorted((e["name"], e["args"].get("step_rows")) for e in spans
+                                  if e["name"] in ("prefill_pack", "decode_tick")),
+                fetched=[e["args"]["what"] for e in spans if e["name"] == "tick_collect"],
+                delta={k: eng.stats[k] - before[k] for k in (
+                    "dispatch_uploads", "prefill_dispatches", "decode_ticks", "decode_emitted",
+                    "mixed_dispatches")},
+                gauges=(eng.stats["state_bytes_live"], eng.stats["kv_page_bytes_in_use"])))
+        kv = eng.kv
+        slot = sum(a[0].nbytes for name in ("ssm", "conv") for a in kv[name])
+        page = sum(a[0].nbytes for name in ("k", "v") for a in kv[name])
+        sched.run()
+        out = {u: sched.pop_result(u) for u in (1, 2, 3)}
+        assert eng.close() == {"blocks_in_use": 0, "cached_blocks": 0, "ssm_states": 0}
+        return ticks, out, slot, page
+
+    (first, second), out, slot, page = run(True)
+    assert first["dispatched"] == [("prefill_pack", 2)] and len(first["fetched"]) == 1
+    assert first["delta"] == dict(dispatch_uploads=1, prefill_dispatches=1, decode_ticks=1,
+                                  decode_emitted=2, mixed_dispatches=1)
+    assert second["fetched"] == ["prefill_pack"]  # ONE fetch brings the pack's and the step's tokens
+    (first2, second2), out2, _, _ = run(False)
+    assert first2["dispatched"] == [("decode_tick", None), ("prefill_pack", None)]
+    assert first2["delta"]["mixed_dispatches"] == 0 and first2["delta"]["dispatch_uploads"] >= 2
+    for k in ("prefill_dispatches", "decode_ticks", "decode_emitted"):
+        assert first2["delta"][k] == first["delta"][k]
+    # three slots' states; 32 prompt rows' pages beside the pages of the two decoding rows
+    for mixed, two in ((first, first2), (second, second2)):
+        assert mixed["gauges"] == two["gauges"]
+    assert first["gauges"][0] == second["gauges"][0] == 3 * slot
+    assert first["gauges"][1] == (32 // PAGE + -(-(9 + 4) // PAGE) + -(-(5 + 4) // PAGE)) * page
+    assert second["gauges"][1] == (-(-41 // PAGE) + -(-(9 + 5) // PAGE) + -(-(5 + 5) // PAGE)) * page
+    assert out == out2 and [len(out[u]) for u in (1, 2, 3)] == [12, 12, 4]
