@@ -84,6 +84,12 @@ class Programs:
                        if h.name == name and h.start >= w0 and h.end <= w1),
                       key=lambda h: h.start)
 
+    def holds_whole(self, e: "Execution") -> bool:
+        """Not at either edge of the capture: the profiler's session begins and
+        ends in the middle of a program, whose stamp is cut."""
+        w0, w1 = self.window
+        return e.start >= w0 + COLLECT_SLACK_S and e.end <= w1 - COLLECT_SLACK_S
+
     def of_module(self, pattern: str, device: Optional[int] = None) -> List[Execution]:
         rx = re.compile(pattern)
         if device is None:
@@ -354,7 +360,7 @@ def dispatched(progs: Programs, spans: Sequence[tuple], names: Dict[str, str],
     for name, module in names.items():
         inside = [h for h in hosts if h.name == name and h.start >= w0 and h.end <= w1]
         for h, e in pair(inside, progs.of_module(module), slack_s, at):
-            if e.start < w0 + COLLECT_SLACK_S or e.end > w1 - COLLECT_SLACK_S:
+            if not progs.holds_whole(e):
                 continue
             synced = h.stats.get("synced", True)
             out.append((h, e, h.end if synced else at.get(int(h.stats[SPAN_ID]), math.inf)))

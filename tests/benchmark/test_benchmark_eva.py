@@ -25,7 +25,7 @@ CONFIG = next(c for c in MAN["configs"] if c["name"] == ENTRY["config"])
 M = harness.load_json(ROOT / CONFIG["file"])
 PUBLISHED = harness.load_json(harness.HERE / "published" / f"{M['published']}.json")
 TRAFFIC = harness.traffic_of(ENTRY["traffic"])
-MINE = [m for m in MAN["per_layer"] if m.get("workloads") == [CELL]]
+MINE = [m for m in MAN["per_layer"] if m["name"].endswith(".evabyte")]   # a later cell may join one
 # what PR 53 brought as ``<family>.evabyte`` copies of the ``.serve`` readers and PR 57 folded
 # into the ``.serve`` lists (the manifest's tests keep what each stated; the three guards
 # among them are ONE entry, their sum, since), and the ``.serve`` families born with the cell
@@ -47,7 +47,9 @@ def test_the_manifest_holds_the_cell_and_its_entries_under_the_cap():
     assert len(MAN["per_layer"]) <= 128, f"{len(MAN['per_layer'])} of 128 used"
     assert ENTRY["chips"] == 1 and CONFIG["file"].endswith(f"{ENTRY['config']}.json")
     rate = next(m for m in MAN["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert CELL in rate["workloads"] and rate["bound"] == 0.03
+    # the cell joined the rate that was there and brought no bound of its own: the value is
+    # the manifest's to state (a `benchmark` PR refits it: 0.02, 0.03, 0.06 since PR 41)
+    assert CELL in rate["workloads"] and 0.01 <= rate["bound"] <= 0.1
     assert sorted(m["name"] for m in MINE) == sorted(f"{name}.evabyte" for name in OWN)
     loaded = [m["name"] for m in harness.metrics_of(MAN, CELL, True)]
     assert sorted(loaded) == sorted([m["name"] for m in MINE] + [f"{f}.serve" for f in SHARED])
@@ -66,7 +68,7 @@ def test_a_serve_family_lists_the_cell_and_no_copy_of_it_is_left(family):
 @pytest.mark.parametrize("name", OWN)
 def test_an_entry_of_its_own_is_found_by_its_name_with_a_file_and_a_reader(name):
     mine = next(m for m in MINE if m["name"] == f"{name}.evabyte")
-    assert mine["moves"] == "serve_tokens_per_s" and mine["workloads"] == [CELL]
+    assert mine["moves"] == "serve_tokens_per_s" and CELL in mine["workloads"]
     spec = harness.load_json(harness.HERE / "metrics" / f"{mine['name']}.json")
     assert spec["unit"] == mine["unit"]
     assert callable(harness.module("readers", spec["reader"]).read)

@@ -236,12 +236,28 @@ def entry_named(man, name):
     return found
 
 
+def kind_of(man, cell):
+    """``serve`` or ``train``: what the ``driver`` of the cell's configuration does."""
+    driver = harness.config_of(man, harness.find_cell(man, cell)["config"])["driver"]
+    return "train" if "train" in driver else "serve"
+
+
 @pytest.mark.parametrize("into,family,suffixes,reader,params", ROWS,
                          ids=[r[1] if r[0] == "serve" else f"{r[1]}.{r[0]}" for r in ROWS])
 def test_a_merged_family_reads_what_its_per_cell_files_read(into, family, suffixes, reader, params):
+    """The list BEGINS with the cells whose per-cell files were folded into it, in the
+    order they were folded (the parity with those files is history, held on them);
+    every cell behind them JOINED by being appended: it is of the list's kind by its
+    configuration's driver, reports the end-to-end metric the entry moves, and stands
+    there once."""
     entry = entry_named(MAN, f"{family}.{into}")
-    assert entry["workloads"] == BORN_WITH[into] + [SUFFIX[s] for s in suffixes]
+    folded = BORN_WITH[into] + [SUFFIX[s] for s in suffixes]
+    cells = entry["workloads"]
+    assert cells[:len(folded)] == folded and len(set(cells)) == len(cells)
     assert entry["moves"] == MOVES[into]
+    moved, = [m for m in MAN["end_to_end"] if m["name"] == MOVES[into]]
+    for joined in cells[len(folded):]:
+        assert kind_of(MAN, joined) == into and joined in cells_of(moved), joined
     spec = harness.load_json(harness.HERE / "metrics" / f"{family}.{into}.json")
     assert spec["reader"] == reader and spec.get("params", {}) == params
     names = {m["name"] for m in MAN["per_layer"]}
@@ -374,101 +390,176 @@ def test_the_readmes_count_is_one_the_list_has_reached():
 
 
 # ---------------------------------------------------------------------------
-# the next cell: appended to a COPY of the manifest, it turns no lookup red
+# the next cell: appended to a COPY of the manifest, it turns no statement red
 # ---------------------------------------------------------------------------
 MADE_UP = "made_up_sessions_closed"
-# what a new serving architecture's cell brings today: a copy of each ``.serve``
-# family on its own suffix (it may not edit the lists): the serving loop's ten and
-# the expert layer's where it holds experts; and readings of its own kernels
-LOOP = ["window_faults", "device_idle_share", "peak_hbm_gib", "prefill_pack_device_p50_ms",
-        "decode_device_p50_ms", "decode_batch_mean", "host_slack_p50_ms", "host_device_skew_ms",
-        "late_collect_lost_ms", "fetch_tail_max_ms"]
-ITS_COPIES = LOOP + ["routed_here_share", "expert_matmul_call_ms", "expert_matmul_roofline"]
+# The made-up cell is a serving cell of a model with a Mamba-2 recurrence, as the cell it
+# STANDS IN for is: its configuration and traffic files are read as that cell's (a PR would
+# bring its own), and it JOINS, appended last, every list that cell's kind of cell reads:
+# each ``.serve`` list that names it (the serving loop's, the two that name every serving
+# cell, the expert layer's where its reader and parameters fit) and these of the
+# recurrence and of a step's attention
+STANDS_IN = "nemotron3_super_reasoning_closed"
+ITS_KERNELS = ["ssm_step_call_ms.nemo", "ssm_step_roofline.nemo", "ssm_scan_call_ms.nemo",
+               "ssm_scan_roofline.nemo", "gqa_attn_call_ms.nemo"]
+# ... and BRINGS readings of its own, on a suffix of its own, at the END of ``per_layer``
 ITS_OWN = [f"{kernel}_{what}" for kernel in ("scan", "step", "attn_pack", "attn_step")
-           for what in ("call_ms", "roofline")] + ["state_rows_share"]
-# the statements about ONE cell's entries, each in the file of that cell's tests
-LOOKUPS = {
-    "test_benchmark_mla": ["test_the_cell_is_one_chip_on_the_new_configuration_and_reports_throughput",
-                           "test_the_cells_why_states_the_sizes_its_traffic_file_runs",
-                           "test_the_cells_per_layer_entries_fit_under_the_cap"],
-    "test_benchmark_decompressed_keys": ["test_the_entry_is_found_by_its_name_and_sits_in_the_cells_layer"],
-    "test_benchmark_mellum": ["test_the_cell_trains_the_configuration_on_one_chip_and_reports_the_training_rate"],
-    "test_benchmark_eva": ["test_the_manifest_holds_the_cell_and_its_entries_under_the_cap"],
-    "test_benchmark_manifest": ["test_top_level_keys_and_limits", "test_no_two_entries_share_a_name",
-                                "test_end_to_end_bounds_and_setup", "test_layers_of_one_module_are_spelled_alike",
-                                "test_the_readmes_count_is_one_the_list_has_reached",
-                                "test_every_folded_copy_has_its_row_and_its_record"],
+           for what in ("call_ms", "roofline")] + ["state_rows_share", "experts_step_share"]
+# What is NOT re-run on the copy, by what it is (the ONE list; the names each rule leaves
+# out are counted in the test's message): rule -> why
+NOT_ON_A_COPY = {
+    "takes a fixture": "pytest hands it a recorded trace, a model built once, a temporary "
+                       "directory or a monkeypatch: it cannot be called as a function",
+    "starts a process": "the child reads the BENCHMARK.json on disk, not the copy, and a "
+                        "made-up cell has no files a rehearsal could run",
+    "test_benchmark_manifest.py::test_no_metric_file_is_left_without_an_entry":
+        "compares the entries with the DIRECTORY of metric files: the made-up cell's own "
+        "files are not on disk (a PR brings them with its entries)",
+    "test_benchmark_models.py": "holds every architecture's reference to the program by "
+                                "running both (minutes); of the manifest it reads which "
+                                "configuration is an architecture's first, not an entry",
 }
 
 
+def joins_of(man):
+    """The names of the entries a cell of ``STANDS_IN``'s kind appends itself to."""
+    return [m["name"] for m in man["per_layer"]
+            if m["name"].endswith(".serve") and STANDS_IN in m.get("workloads", ())] + ITS_KERNELS
+
+
 def with_a_cell_appended(man):
-    """A deep copy of ``man`` with what the next ``model_config`` PR appends: a
-    configuration, a one-chip serving cell on it, the cell in its end-to-end
-    metric's list, and its entries of ``per_layer`` on a suffix of its own: the
-    copies, and as many of its own readings as the room that is free takes (all
-    nine while 22 entries are free)."""
+    """A deep copy of ``man`` with what the next ``model_config`` PR appends, and
+    nothing inserted: a configuration and a one-chip serving cell on it at the end of
+    their lists, the cell's name at the end of its end-to-end metric's list and of the
+    ``workloads`` of every entry it joins (``joins_of``), and its own entries at the end
+    of ``per_layer`` (as many of ``ITS_OWN`` as the room that is free takes)."""
     import copy
 
-    its_own = ITS_OWN[:max(CAP - len(man["per_layer"]) - len(ITS_COPIES), 0)]
-
     man = copy.deepcopy(man)
-    man["configs"].append({
-        "name": "made_up_l4_serve_1chip", "source": "https://example.org/made-up/config.json",
-        "file": "benchmark/configs/made_up_l4_serve_1chip.json", "reduced": ["num_hidden_layers"],
-        "why": "an architecture nobody published: two mixers a block, each with a cache of its own"})
+    stands_in = next(c for c in man["configs"]
+                     if c["name"] == harness.find_cell(man, STANDS_IN)["config"])
+    man["configs"].append({**stands_in, "name": "made_up_l4_serve_1chip",
+                           "file": "benchmark/configs/made_up_l4_serve_1chip.json",
+                           "why": "an architecture nobody published: a recurrence or attention by block"})
     man["workloads"].append({
         "name": MADE_UP, "config": "made_up_l4_serve_1chip", "traffic": "made_up_closed", "chips": 1,
-        "why": "16 closed-loop callers on contexts of 8k-32k: both caches of a block under one pack"})
+        "why": "64 closed-loop sessions on contexts of 2k-16k: state and pages of a slot under one pack"})
     next(m for m in man["end_to_end"] if m["name"] == MOVES["serve"])["workloads"].append(MADE_UP)
-    for family in ITS_COPIES:
-        man["per_layer"].append({**entry_named(man, f"{family}.serve"),
-                                 "name": f"{family}.made", "workloads": [MADE_UP]})
-    for name in its_own:
+    for name in joins_of(man):
+        entry_named(man, name)["workloads"].append(MADE_UP)
+    for name in ITS_OWN[:max(CAP - len(man["per_layer"]), 0)]:
         man["per_layer"].append({**entry_named(man, "window_faults.serve"), "name": f"{name}.made",
                                  "unit": "%" if name.endswith(("roofline", "share")) else "ms",
                                  "source": "device_trace", "workloads": [MADE_UP]})
     return man
 
 
+def files_of_the_made_up_cell(path):
+    """Where the files the cell would bring are read from: its configuration's and its
+    traffic's from the cell's it stands in for, each reading's from an accepted file of
+    the same unit."""
+    path = str(path)
+    if path.endswith(".made.json"):
+        share = path.endswith(("roofline.made.json", "share.made.json"))
+        return harness.HERE / "metrics" / ("device_idle_share.serve.json" if share
+                                           else "decode_device_p50_ms.serve.json")
+    cell = harness.find_cell(MAN, STANDS_IN)
+    return Path(path.replace("made_up_l4_serve_1chip", cell["config"])
+                .replace("made_up_closed", cell["traffic"]))
+
+
+def statements_of(module):
+    """(id, call) for every test function of ``module``, a parametrised one once a
+    row of its marks as the module built them, and the names left out by a rule of
+    ``NOT_ON_A_COPY``."""
+    import inspect
+    import itertools
+
+    run, left_out = [], {}
+    for name, fn in sorted(vars(module).items()):
+        if not (name.startswith("test_") and inspect.isfunction(fn)
+                and fn.__module__ == module.__name__):
+            continue
+        marks = [m for m in getattr(fn, "pytestmark", ()) if m.name == "parametrize"]
+        columns, given = [], set()
+        for mark in marks:
+            names, rows = mark.args[0], mark.args[1]
+            names = [n.strip() for n in names.split(",")] if isinstance(names, str) else list(names)
+            rows = [row.values if isinstance(row, type(pytest.param()))
+                    else row if len(names) > 1 else (row,) for row in rows]
+            columns.append([dict(zip(names, row)) for row in rows])
+            given.update(names)
+        if f"{Path(module.__file__).name}::{name}" in NOT_ON_A_COPY:
+            left_out[name] = f"{Path(module.__file__).name}::{name}"
+        elif set(inspect.signature(fn).parameters) - given:
+            left_out[name] = "takes a fixture"
+        elif "subprocess." in inspect.getsource(fn):
+            left_out[name] = "starts a process"
+        else:
+            for k, rows in enumerate(itertools.product(*columns)):
+                kwargs = {key: value for row in rows for key, value in row.items()}
+                case = "-".join(str(v.get("name", k) if isinstance(v, dict) else v)
+                                for v in kwargs.values() if isinstance(v, (str, int, dict)))
+                run.append((f"{name}[{case or k}]", lambda fn=fn, kwargs=kwargs: fn(**kwargs)))
+    return run, left_out
+
+
 def test_a_cell_appended_to_the_manifest_turns_no_lookup_by_name_red(monkeypatch):
-    """Every statement ``tests/benchmark/`` makes about one cell's entries finds
-    them by NAME: with a made-up cell, its configuration and its entries (22 while
-    the room takes them; the copies and one reading of its own at the least) behind
-    everything that is there, each still holds and the list stays under the cap."""
+    """Every statement ``tests/benchmark/`` makes of the manifest is made by NAME and
+    by KIND, never by place or by today's membership: with a made-up cell appended the
+    way the next PR appends it (JOINED to the lists its kind reads, its own entries
+    behind everything that is there), every test function of every ``test_*.py`` here
+    that reads ``harness.manifest()`` (found on disk, parametrised cases rebuilt from
+    the grown copy) still holds, and the list stays under the cap.  A statement that
+    pins a place or a membership fails here and is named."""
     import importlib.util
+    import traceback
 
     used = len(MAN["per_layer"])
-    assert len(LOOP) == 10 and len(ITS_COPIES) + len(ITS_OWN) == 22
-    assert used + len(ITS_COPIES) + 1 <= CAP, \
-        f"{used} of {CAP} used: no room for a serving cell's {len(ITS_COPIES)} copies and a reading of its own"
+    assert used + 1 <= CAP, f"{used} of {CAP} used: no room for one reading of a new cell's own"
     grown = with_a_cell_appended(MAN)
-    assert len(grown["per_layer"]) == min(used + 22, CAP), f"{used} of {CAP} used"
-    assert grown["workloads"][-1]["name"] == MADE_UP and grown["per_layer"][-1]["workloads"] == [MADE_UP]
+    joined = joins_of(MAN)
+    assert len(grown["per_layer"]) == min(used + len(ITS_OWN), CAP), f"{used} of {CAP} used"
+    assert {"late_collect_lost_ms.serve", "fetch_tail_max_ms.serve", "window_faults.serve",
+            "decode_device_p50_ms.serve", "host_slack_p50_ms.serve"} <= set(joined)
+    for name in joined:   # joined: the cells that were there as they were, the cell behind them
+        was, now = entry_named(MAN, name)["workloads"], entry_named(grown, name)["workloads"]
+        assert now == was + [MADE_UP]
+    assert [m["name"] for m in grown["per_layer"][:used]] == [m["name"] for m in MAN["per_layer"]]
     monkeypatch.setattr(harness, "manifest", lambda: grown)
-    # the copies' files, as the cell would bring them: what the ``.serve`` namesake's file holds
     on_disk = harness.load_json
-    monkeypatch.setattr(harness, "load_json", lambda path: on_disk(
-        Path(str(path).replace(".made.json", ".serve.json"))))
-    for file, tests in LOOKUPS.items():
-        spec = importlib.util.spec_from_file_location(f"grown_{file}", Path(__file__).with_name(f"{file}.py"))
+    monkeypatch.setattr(harness, "load_json", lambda path: on_disk(files_of_the_made_up_cell(path)))
+    here = Path(__file__).parent
+    files = [p for p in sorted(here.glob("test_*.py")) if "harness.manifest()" in p.read_text()]
+    assert Path(__file__) in files and len(files) >= 9
+    red, ran, left_out = [], {}, {}
+    for file in files:
+        if file.name in NOT_ON_A_COPY:
+            left_out[file.name] = file.name
+            continue
+        spec = importlib.util.spec_from_file_location(f"grown_{file.stem}", file)
         again = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(again)   # its ``MAN = harness.manifest()`` is the grown copy
-        assert again.MAN is grown
-        for name in tests:
-            getattr(again, name)()
-        if file == "test_benchmark_manifest":   # ... and its parametrised statements, row by row
-            for row in again.ROWS:
-                again.test_a_merged_family_reads_what_its_per_cell_files_read(*row)
-                for suffix in row[2]:
-                    again.test_a_folded_cell_loads_its_familys_one_file(row[0], row[1], suffix)
-            for copy in again.COPIES:
-                again.test_a_folded_copy_kept_its_contract(copy)
-            for cell in again.CELLS:
-                again.test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(cell)
-            assert again.CELLS[-1] == MADE_UP
-            for metric in grown["per_layer"]:
-                for cell in again.cells_of(metric):
-                    again.test_moves_names_an_end_to_end_metric_its_cells_report(metric, cell)
+        sys.modules[spec.name] = again   # ``inspect`` finds a function's source by its module
+        try:
+            spec.loader.exec_module(again)   # its ``harness.manifest()`` is the grown copy
+            run, skipped = statements_of(again)
+            left_out.update({f"{file.name}::{name}": why for name, why in skipped.items()})
+            ran[file.name] = len(run)
+            for case, call in run:
+                try:
+                    call()
+                except Exception as e:   # noqa: BLE001 - every red statement is named, not the first
+                    at = traceback.extract_tb(e.__traceback__)[-1]   # (no assertion rewriting here)
+                    red.append(f"{file.name}::{case}: {Path(at.filename).name}:{at.lineno}: "
+                               f"{at.line} {type(e).__name__} {str(e)[:120]}")
+        finally:
+            del sys.modules[spec.name]
+    assert set(left_out.values()) <= set(NOT_ON_A_COPY)
+    total = sum(ran.values())
+    assert not red, f"{len(red)} of {total} statements pin a place or a membership:\n" + "\n".join(red)
+    # the net is not empty: every file re-run makes statements, this one its rows' too
+    assert all(ran.values()) and ran[Path(__file__).name] > len(ROWS) + len(COPIES), \
+        f"{ran} statements re-run, {len(left_out)} left out: {sorted(left_out)}"
 
 
 def test_layers_of_one_module_are_spelled_alike():

@@ -33,10 +33,10 @@ def test_the_cell_trains_the_configuration_on_one_chip_and_reports_the_training_
     assert entry["file"].endswith("mellum2_l4_e16_train_1chip.json") and M["driver"] == "train_experts"
     rate = next(m for m in MAN["end_to_end"] if m["name"] == "train_tokens_per_s_per_chip")
     assert CELL in rate["workloads"] and rate["bound"] == 0.01
-    mine = [m for m in MAN["per_layer"] if m.get("workloads") == [CELL]]
+    mine = [m for m in MAN["per_layer"] if m["name"].endswith(".mellum")]
     assert 1 <= len(mine) <= 12 and len(MAN["per_layer"]) <= 128
     assert {m["moves"] for m in mine} == {"train_tokens_per_s_per_chip"}
-    assert all(m["name"].endswith(".mellum") for m in mine)
+    assert all(CELL in m["workloads"] for m in mine)   # a later cell may join one, behind it
     t = harness.traffic_of(cell["traffic"])
     assert (t["seq_len"], t["micro_batch_per_chip"], t["distinct_batches"]) == (8192, 2, 4)
 
@@ -137,7 +137,9 @@ def test_the_flash_readers_need_comes_from_positions_not_from_the_implementation
 def test_the_expert_reader_needs_a_trace_and_the_programs_count_of_held_pairs():
     assert expert_train_roofline.read(_obs(trace=None, counters={"expert_pairs_held": 5}),
                                       "train_step_experts", "expert_matmul") is None
-    assert expert_train_roofline.read(_obs(trace=_Trace({}), counters={}),
+    # (``_xprograms``: the capture's programs as read; without the key the reader looks for
+    # the newest trace file under ``.bench_out`` and, in a checkout that has none, raises)
+    assert expert_train_roofline.read(_obs(trace=_Trace({}), counters={}, _xprograms=None),
                                       "train_step_experts", "expert_matmul") is None
 
 

@@ -323,6 +323,51 @@ def test_recorded_ops_classify_by_scope_and_name_the_kernel(recorded):
     assert sum(shares) == pytest.approx(100.0)
 
 
+def test_a_bodys_share_of_its_program_on_the_recorded_trace(recorded):
+    """``readers/scope_share_of_program.py``: a scope's self time over the device time
+    of ITS program's executions that the capture holds whole, by hand from the ops."""
+    from benchmark.readers import scope_share_of_program
+
+    progs, _, scopes = recorded
+    obs = {"_xprograms": progs, "_scopes": scopes, "trace": object()}
+    read = lambda scope, module="^jit_serve_step$": scope_share_of_program.read(obs, module, scope)
+    runs = progs.of_module("^jit_serve_step$")
+    # the first execution starts 0.4 ms into the capture: the session's edge, left out
+    assert runs[0].start - progs.window[0] < xprograms.COLLECT_SLACK_S < runs[1].start - progs.window[0]
+    whole = runs[1:]
+    under = lambda word: sum(
+        o.self_s for o in progs.ops[0] for e in whole if e.start <= o.start < e.end
+        and f"/{word}/" in scopes["jit_serve_step"].get(o.name, ""))
+    took = sum(e.end - e.start for e in whole)
+    mlp, attn = read("(^|/)mlp(/|$)"), read("(^|/)attn(/|$)")
+    assert mlp == pytest.approx(100 * under("mlp") / took) and attn == pytest.approx(100 * under("attn") / took)
+    assert 0 < attn < mlp < 100   # the toy's matmul is most of its step, the Pallas call a little
+    assert read("(^|/)(mlp|attn)(/|$)") == pytest.approx(mlp + attn)   # an alternation: several bodies
+    # the other program's ``mlp`` is its own: a share is of ONE program's time
+    assert read("(^|/)jvp\\(mlp\\)(/|$)", "^jit_train_step$") != pytest.approx(mlp)
+    # nothing to read: a body the program has not, a program the capture has not
+    assert read("(^|/)no_such_body(/|$)") is None and read("(^|/)mlp(/|$)", "^jit_absent$") is None
+
+
+@pytest.mark.parametrize("runs,want", [
+    # four executions of 10 ms, the body 2 ms of each: the two at the capture's edges left out
+    ([(0.001, 0.011), (0.020, 0.030), (0.040, 0.050), (0.0895, 0.0995)], 20.0),
+    ([(0.001, 0.011), (0.0895, 0.0995)], None),    # none held whole: nothing, never a 0
+    ([(0.020, 0.030), (0.040, 0.060)], 100 * 4 / 30),   # a longer execution weighs by its time
+])
+def test_a_bodys_share_leaves_out_the_executions_at_the_captures_edges(runs, want):
+    from benchmark.readers import scope_share_of_program
+
+    ops = [RawOp(name, a + at, a + at + 0.002, 0.002) for a, _ in runs
+           for name, at in (("body.1", 0.001), ("other.2", 0.004))]
+    progs = Programs((0.0, 0.1), {0: [Execution("jit_step", i, a, b) for i, (a, b) in enumerate(runs)]},
+                     {0: ops}, {})
+    obs = {"_xprograms": progs, "trace": object(),
+           "_scopes": {"jit_step": {"body.1": "jit(step)/body/dot_general", "other.2": "jit(step)/other/add"}}}
+    got = scope_share_of_program.read(obs, "^jit_step$", "(^|/)body(/|$)")
+    assert got == (None if want is None else pytest.approx(want))
+
+
 def test_readers_have_nothing_to_read_without_a_trace_or_a_device_plane(monkeypatch):
     from benchmark.readers import (collective_gib, kernel_call_ms,
                                    module_device_percentile, scope_share)
