@@ -86,12 +86,18 @@ A model of two-norm blocks that each hold TWO PARALLEL MIXERS (``par``: a Mamba-
 recurrence AND rotary GQA on one normed input, summed; a dense SwiGLU behind
 them) keeps BOTH of the above for EVERY layer of a sequence: ``ssm[i]`` / ``conv[i]``
 and ``k[i]`` / ``v[i]`` are block ``i``'s (``LatentSpec.count`` counts a block as one
-of each mixer), the block goes through the seam twice (``_recurrence``, then the
-K / V write and read), and what follows from position holds for both at once: a
-slot's next owner and a resume start the state from zeros AND overwrite the pages
-from position 0; a preemption frees the pages and leaves the state behind.  Its
-host mirror sets two gauges at every dispatch (``state_bytes_live``,
-``kv_page_bytes_in_use``: ``PARALLEL_COUNTERS``).
+of each mixer), the block goes through the seam twice (``_mixer``: the recurrence,
+then the K / V write and read), and what follows from position holds for both at
+once: a slot's next owner and a resume start the state from zeros AND overwrite the
+pages from position 0; a preemption frees the pages and leaves the state behind.
+
+A model of two-norm blocks whose mixer is chosen BY BLOCK (``LatentSpec.two_norms``:
+``mamba`` in most blocks, position-free ``gqa`` in the rest, the expert layer behind
+each) keeps what the KINDS PRESENT need: a state and a convolution's tail for each
+recurrent block, a layer of pages for each attention block (9 and 1 of ten at
+Granite 4.0-H's 9 : 1), through the same ``_mixer``.  Wherever a slot keeps both
+kinds of cache, in one block or in different ones, the host mirror sets two gauges
+at every dispatch (``state_bytes_live``, ``kv_page_bytes_in_use``: ``CACHE_GAUGES``).
 
 One layer body (``_layer``) serves the pack and the tick; the kind chooses how
 the rows are written and read.  A pack reads its own rows back from the cache
@@ -224,9 +230,9 @@ STATE_COUNTERS = (
 )
 
 
-# ... and of one whose EVERY block keeps both (two parallel mixers, a dense SwiGLU behind
-# them): the state's three, and what a slot and a page hold, as gauges set at each dispatch
-PARALLEL_COUNTERS = STATE_COUNTERS[:3] + (
+# ... and where a slot keeps BOTH a recurrence's state and K / V pages (in one block or in
+# different ones), what a slot and a page hold, as gauges set at each dispatch
+CACHE_GAUGES = (
     "state_bytes_live",      # gauge: slots holding a sequence x a slot's state, every block's
     "kv_page_bytes_in_use",  # gauge: pages holding a live sequence's rows x a page, every block's
 )
@@ -300,9 +306,11 @@ def _init_state_cache(cfg, num_blocks: int, block_size: int, max_seqs: int,
     s = cfg.latent
     if s.eva is not None:
         return _init_eva_cache(cfg, num_blocks, block_size, pack_tokens, dtype)
+    # by the KINDS PRESENT: a state and a conv tail a recurrent block, a layer of pages
+    # an attention block over every key (a model may hold either, both, or both in one block)
     (rec, mb), (att, g) = s.recurrence, s.attention
     k, v = init_paged_cache(s.count(att), num_blocks, block_size, g.num_kv_heads,
-                            g.head_dim, dtype=dtype)
+                            g.head_dim, dtype=dtype) if g else ((), ())
     per = lambda shape, dt: tuple(jnp.zeros((max_seqs, *shape), dt)
                                   for _ in range(s.count(rec)))
     n_moe = len(s.expert_layers)
@@ -583,7 +591,7 @@ def _layer(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, probe):
     Returns (x, cache)."""
     s = cfg.latent
     if s.single:
-        return _block(cfg, l, layers, x, valid, cache, write, read, pack_rows, probe)
+        return _block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, probe)
     if s.hybrid:
         return _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows,
                              probe)
@@ -638,33 +646,43 @@ def _routing_counted(st, m: int, routed, track_groups: bool):
     return st.at[m].set(new)
 
 
-def _block(cfg, l, layers, x, valid, cache, write, read, pack_rows, probe):
+def _block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, probe):
     """One single-mixer block on token rows ``x`` [T, d], through the same
-    seam: a state-space block's ``write`` IS its read (the scan that carries
-    the state on yields the outputs: (state, conv tail, y)), an attention
-    block writes K / V rows and reads the pools, an expert block keeps nothing."""
+    seam (``_mixer``); an expert block keeps nothing."""
     s = cfg.latent
     kind, scale, w = lm.block_params(layers, l, s)
     i = s.layer_kinds[:l].count(kind)
     h = lm.rms(x, scale, cfg.norm_eps)
-    if kind == "mamba":
-        y, cache = _recurrence(kind, i, w, h, cache, write)
-    elif kind == "gqa":
-        q, k, v = lm.gqa_inputs(w, h, s.gqa)
-        pools = write(kind, (cache["k"][i], cache["v"][i]), (k, v))
-        cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
-        o = read(kind, pools, (q, k, v))  # (under ``gqa_attn``: ``_scoped``)
-        y = lm.gqa_output(w, o.astype(x.dtype), s.gqa)
-    else:
+    if kind == "experts":
         y, cache = _experts(cfg, i, w, h, valid, cache, pack_rows, probe)
+    else:
+        y, cache = _mixer(s, kind, i, w, h, pos, cache, write, read)
     return x + y.astype(x.dtype), cache
 
 
-def _recurrence(kind, i, w, h, cache, write):
-    """A recurrence's block ``i`` of its kind through the seam: (y, cache with
-    the state and the convolution's tail it leaves)."""
-    ssm, conv, y = write(kind, (cache["ssm"][i], cache["conv"][i]), (w, h))
-    return y, {**cache, "ssm": _put(cache["ssm"], i, ssm), "conv": _put(cache["conv"], i, conv)}
+def _mixer(s, kind, i, w, h, pos, cache, write, read):
+    """Block ``i`` of its kind's mixer on normed rows ``h``, through the seam, for
+    every block that holds a recurrence or position-free / rotary GQA (a
+    single-mixer block, a two-norm block's one mixer, either side of two parallel
+    ones): a recurrence's ``write`` IS its read (the scan that carries the state
+    on yields the outputs: (state, conv tail, y)), attention writes K / V rows and
+    reads the pools.  Returns (y, cache)."""
+    if kind in lm.RECURRENCES:
+        ssm, conv, y = write(kind, (cache["ssm"][i], cache["conv"][i]), (w, h))
+        return y, {**cache, "ssm": _put(cache["ssm"], i, ssm),
+                   "conv": _put(cache["conv"], i, conv)}
+    o, cache = _through_pools(kind, i, ("k", "v"), lm.gqa_inputs(w, h, s.gqa, pos), cache,
+                              write, read)  # (under ``gqa_attn``: ``_scoped``)
+    return lm.gqa_output(w, o.astype(h.dtype), s.gqa), cache
+
+
+def _through_pools(kind, i, names, qkv, cache, write, read):
+    """Block ``i``'s new K / V rows into its kind's pools (``names``: the cache's
+    keys of the pages, or of the rings) and its queries over them: (o, cache)."""
+    kk, kv = names
+    pools = write(kind, (cache[kk][i], cache[kv][i]), qkv[1:])
+    cache = {**cache, kk: _put(cache[kk], i, pools[0]), kv: _put(cache[kv], i, pools[1])}
+    return read(kind, pools, qkv), cache
 
 
 def _experts(cfg, i, w, h, valid, cache, pack_rows, probe):
@@ -697,17 +715,14 @@ def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, 
     kind, (n1, n2), mw, fw, is_moe = lm.hybrid_params(layers, l, s)
     i = s.layer_kinds[:l].count(kind)
     h = lm.norm(x, n1, cfg)
-    if kind == "gdn":
-        y, cache = _recurrence(kind, i, mw, h, cache, write)
+    if kind in lm.RECURRENCES or kind == "gqa":  # ONE mixer, chosen by block
+        y, cache = _mixer(s, kind, i, mw, h, pos, cache, write, read)
     elif kind == "par":
         # TWO mixers on the ONE normed input, summed: the recurrence through its seam
         # (state and conv tail of block ``i``) AND block ``i``'s K / V write and read
-        y, cache = _recurrence("mamba", i, mw["mamba"], h, cache, write)
-        q, k, v = lm.gqa_inputs(mw["gqa"], h, s.gqa, pos)
-        pools = write("gqa", (cache["k"][i], cache["v"][i]), (k, v))
-        cache = {**cache, "k": _put(cache["k"], i, pools[0]), "v": _put(cache["v"], i, pools[1])}
-        o = read("gqa", pools, (q, k, v))  # (under ``gqa_attn``: ``_scoped``)
-        y = y.astype(x.dtype) + lm.gqa_output(mw["gqa"], o.astype(x.dtype), s.gqa)
+        y, cache = _mixer(s, "mamba", i, mw["mamba"], h, pos, cache, write, read)
+        o, cache = _mixer(s, "gqa", i, mw["gqa"], h, pos, cache, write, read)
+        y = y.astype(x.dtype) + o
     elif kind == "eva":
         q, k, v = lm.eva_inputs(mw, h, pos, s.eva)
         n = len(cache["k"]) // s.count("eva")  # pools a layer, some of the heads each
@@ -721,20 +736,18 @@ def _hybrid_block(cfg, l, layers, x, pos, valid, cache, write, read, pack_rows, 
             probe.append({"eva_q": q, "eva_k": k, "eva_v": v, "eva_o": o})
         y = o.reshape(x.shape[0], -1).astype(x.dtype) @ mw["wo"]
     else:
-        q, k, v, gate = lm.gattn_inputs(mw, h, pos, s.mixer(kind), eps, s.unit_offset)
+        *qkv, gate = lm.gattn_inputs(mw, h, pos, s.mixer(kind), eps, s.unit_offset)
         # pages for the kind over every key, a ring a slot for the kind over a window
-        kk, kv = ("wk", "wv") if kind == "wattn" else ("k", "v")
-        pools = write(kind, (cache[kk][i], cache[kv][i]), (k, v))
-        cache = {**cache, kk: _put(cache[kk], i, pools[0]), kv: _put(cache[kv], i, pools[1])}
-        o = read(kind, pools, (q, k, v))  # (under ``_attn_scope``'s name: ``_scoped``)
+        o, cache = _through_pools(kind, i, ("wk", "wv") if kind == "wattn" else ("k", "v"),
+                                  qkv, cache, write, read)  # (under ``_attn_scope``'s name)
         y = lm.gattn_output(mw, o.astype(x.dtype), gate)
-    x = x + y.astype(x.dtype)
+    x = lm.residual(x, y, s.residual_multiplier)
     h = lm.norm(x, n2, cfg)
     if is_moe:
         y, cache = _experts(cfg, l - s.first_dense, fw, h, valid, cache, pack_rows, probe)
     else:
         y = lm.ffn(fw, h, False, cfg)[0]
-    return x + y.astype(x.dtype), cache
+    return lm.residual(x, y, s.residual_multiplier), cache
 
 
 def _attn_scope(s, kind: str) -> str:
@@ -813,7 +826,7 @@ def _put(items: tuple, i: int, value) -> tuple:
 def _logits(params, cfg, x):
     with jax.named_scope("lm_head"):
         x = lm.norm(x, params["final_norm"]["scale"], cfg)
-        return lm.head_logits(x, params["lm_head"]["kernel"], cfg).astype(jnp.float32)
+        return lm.head_logits(x, params, cfg).astype(jnp.float32)
 
 
 def _seams(cfg):
@@ -997,9 +1010,17 @@ def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cac
             return o.reshape(t, *o.shape[2:])
         return _by_whole_groups(
             lambda q: paged_attention_packed_ctx(q, *qkv[1:], segment_ids, *pools, tables,
-                                                 ctx_lens), qkv[0], qkv[1].shape[1])
+                                                 ctx_lens, scale=_softmax_scale(s, kind)),
+            qkv[0], qkv[1].shape[1])
 
     return write, read
+
+
+def _softmax_scale(s, kind: str):
+    """The softmax scale of a kind of attention over K / V pages where it is the
+    configuration's own constant (``Gqa.scale``); None: ``head_dim ** -0.5``, the
+    kernels' and their XLA fallbacks' own default."""
+    return getattr(s.mixer(kind), "scale", None)
 
 
 def _eva_tables(ev, tables, w, bs: int):
@@ -1251,10 +1272,12 @@ def _state_tick_seam(cfg, pos, block_tables, active, picked, probe):
             # tick is a pack of one-row segments over their cached context
             segment_ids = jnp.where(active, jnp.arange(pos.shape[0]) + 1, 0)
             return paged_attention_packed_ctx(*qkv, segment_ids, *pools, block_tables,
-                                              jnp.where(active, pos, 0))
+                                              jnp.where(active, pos, 0),
+                                              scale=_softmax_scale(s, kind))
         # length 0 = no row in this slot: the kernel skips it
         return paged_attention_decode(qkv[0], *pools, block_tables,
-                                      jnp.where(active, pos + 1, 0))
+                                      jnp.where(active, pos + 1, 0),
+                                      scale=_softmax_scale(s, kind))
 
     return write, read
 
@@ -1277,7 +1300,7 @@ class LatentRunner:
 
     counters = COUNTERS
     packs_are_one_program = True  # a pack reads its own rows back from the cache
-    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn; full_attn; lm_head
+    scoped_programs = True  # indexer topk sparse_attn window_attn expert_matmul; ssm_* gqa_attn latent_proj; gdn_* gated_attn; full_attn; lm_head; shared_expert
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -1318,8 +1341,11 @@ class LatentRunner:
             self.counters = EVA_COUNTERS
             self.compaction = WindowCompaction(ev.window, ev.chunk)
         elif cfg.latent.stateful:
-            self.counters = WINDOW_COUNTERS if cfg.latent.ringed else \
-                PARALLEL_COUNTERS if cfg.latent.par else STATE_COUNTERS
+            both = s.recurrence[0] is not None and s.attention[0] is not None
+            # (a model with no expert layer counts the states' three alone)
+            self.counters = WINDOW_COUNTERS if s.ringed else \
+                (STATE_COUNTERS if s.expert_layers else STATE_COUNTERS[:3]) \
+                + (CACHE_GAUGES if both else ())
             self._discarded = 0  # states a preemption left behind since the last dispatch
             self._slot_bytes = self._page_bytes = 0  # of a slot's state, of a page: all blocks
         elif cfg.latent.every is not None:
@@ -1333,7 +1359,7 @@ class LatentRunner:
         self._ring_rows = np.zeros(max_seqs, np.int64)
         cache = init_cache(self.cfg, num_blocks, block_size, max_seqs, pack_tokens)
         self._expert_layers = cache["stats"].shape[0]
-        if self.cfg.latent.par:
+        if "state_bytes_live" in self.counters:
             size = lambda keys: sum(a.nbytes for k in keys for a in cache[k])
             self._slot_bytes = size(("ssm", "conv")) // max_seqs
             self._page_bytes = size(("k", "v")) // num_blocks
@@ -1500,7 +1526,7 @@ class LatentRunner:
         counters["ssm_chunks_scanned"].inc(chunks * n_ssm)
         counters["ssm_states_recomputed"].inc(self._discarded)
         self._discarded = 0
-        if self.cfg.latent.par:  # both kinds of cache, every layer: what they hold now
+        if self._slot_bytes:  # a slot keeps both kinds of cache: what they hold now
             rows = self._ring_rows
             counters["state_bytes_live"].set(int(np.count_nonzero(rows)) * self._slot_bytes)
             counters["kv_page_bytes_in_use"].set(int((-(-rows // bs)).sum()) * self._page_bytes)

@@ -68,6 +68,18 @@ written: the product is scaled in float32 and rounded once (``_mm``,
 bfloat16 weights is not exact.  Trees: ``layers/par`` = a tuple of ``{"mamba":
 .., "gqa": ..}`` a block, ``layers/mlp``; such a model's blocks are all of the kind.
 
+A two-norm block's mixer may also be ONE of those two, chosen BY BLOCK
+(``LatentSpec.two_norms`` with the kinds ``mamba`` / ``gqa``; Granite 4.0-H's
+block): a Mamba-2 recurrence in most blocks and position-free GQA in the rest,
+each followed by the expert layer (or a leading dense SwiGLU) as every two-norm
+block is, so a sequence keeps a state for each recurrent block and K / V pages
+for each attention block.  Both residual branches carry ONE constant
+(``residual_multiplier``: ``x + c mixer(norm(x))``, ``x + c ffn(norm(x))``), the
+softmax scale may be the configuration's own constant (``Gqa.scale``), and the
+head may be TIED: with ``cfg.tie_embeddings`` the tree holds no ``lm_head`` and
+the logits are the normed rows against the embedding's held rows.  Trees:
+``layers/mamba`` and ``layers/gqa``, a tuple a kind, beside ``layers/moe``.
+
 TRAINING (``CausalLM.loss_fn`` -> ``forward`` under ``jax.grad``, through the
 train engine): the blocks whose mixer is attention over K / V (``gattn``,
 ``wattn``, ``gqa``) and whose feed-forward is a SwiGLU or the held experts.
@@ -171,7 +183,8 @@ class Gqa:
     """Grouped-query attention: no positional embedding (``rope_theta`` 0), or
     rotary positions (rotate-half) over the whole head at ``rope_theta``.
     Constant multipliers on the normed input, on the keys BEFORE their rotation
-    and on ``W_o``'s output."""
+    and on ``W_o``'s output; ``scale``: the softmax scale where it is the
+    configuration's own constant (None: ``head_dim ** -0.5``)."""
 
     num_heads: int
     num_kv_heads: int
@@ -180,13 +193,16 @@ class Gqa:
     in_multiplier: float = 1.0
     key_multiplier: float = 1.0
     out_multiplier: float = 1.0
+    scale: Optional[float] = None
 
 
 # mixers of a two-norm block: a recurrence, gated attention over every key, ... over a
 # window, attention over a window's exact keys and the summaries of the windows before it
 # ... and ``par``: TWO mixers side by side on one normed input, summed
 HYBRID = ("gdn", "gattn", "wattn", "eva", "par")
-PAR_MIXERS = ("mamba", "gqa")  # the mixers a ``par`` block holds, ONE of each
+# the mixers a ``par`` block holds, ONE of each; with ``LatentSpec.two_norms`` also a
+# two-norm block's kinds: ONE of the two a block
+PAR_MIXERS = ("mamba", "gqa")
 
 
 @dataclass(frozen=True)
@@ -320,6 +336,11 @@ class LatentSpec:
     logits_multiplier: float = 1.0
     mlp_gate_multiplier: float = 1.0
     mlp_down_multiplier: float = 1.0
+    # the kinds ``mamba`` / ``gqa`` name a block of ONE norm and that mixer (False:
+    # ``SINGLE``) or of TWO norms, that mixer and a feed-forward behind it (True);
+    # every other kind is of one family by its name
+    two_norms: bool = False
+    residual_multiplier: float = 1.0  # on BOTH branches of a two-norm block, before the sum
 
     @property
     def par(self) -> bool:
@@ -328,13 +349,16 @@ class LatentSpec:
 
     @property
     def single(self) -> bool:
-        """Blocks of one mixer each (``SINGLE``)."""
-        return bool(self.layer_kinds) and all(k in SINGLE for k in self.layer_kinds)
+        """Blocks of one norm and one mixer each (``SINGLE``)."""
+        return bool(self.layer_kinds) and not self.two_norms \
+            and all(k in SINGLE for k in self.layer_kinds)
 
     @property
     def hybrid(self) -> bool:
-        """Two-norm blocks of a recurrence or gated attention and experts (``HYBRID``)."""
-        return bool(self.layer_kinds) and all(k in HYBRID for k in self.layer_kinds)
+        """Blocks of two norms: a mixer (``HYBRID``'s, or with ``two_norms`` one of
+        ``PAR_MIXERS`` a block), then a dense SwiGLU or the expert layer."""
+        kinds = HYBRID + (PAR_MIXERS if self.two_norms else ())
+        return bool(self.layer_kinds) and all(k in kinds for k in self.layer_kinds)
 
     @property
     def stateful(self) -> bool:
@@ -349,11 +373,17 @@ class LatentSpec:
             return tuple(l for l, k in enumerate(self.layer_kinds) if k == "experts")
         return tuple(range(self.first_dense, len(self.layer_kinds)))
 
+    def _held(self, kinds):
+        """(kind, mixer) of the one of ``kinds`` that some block holds, (None, None)
+        where none does: what a slot keeps follows the KINDS PRESENT."""
+        kind = next((k for k in kinds if self.count(k)), None)
+        return kind, kind and self.mixer(kind)
+
     @property
     def recurrence(self):
-        """(kind, mixer) of a ``stateful`` model's recurrence (the mixer None
-        for a model of attention alone)."""
-        return ("mamba", self.mamba) if self.single or self.par else ("gdn", self.gdn)
+        """(kind, mixer) of a ``stateful`` model's recurrence (None, None for a
+        model of attention alone)."""
+        return self._held(tuple(RECURRENCES))
 
     def mixer(self, kind: str):
         """The spec of a ``stateful`` model's mixer of ``kind`` (SINGLE's, HYBRID's)."""
@@ -362,8 +392,7 @@ class LatentSpec:
     @property
     def attention(self):
         """(kind, mixer) of a ``stateful`` model's attention over K / V PAGES."""
-        kind = "gqa" if self.single or self.par else "gattn"
-        return kind, self.mixer(kind)
+        return self._held(("gqa", "gattn"))
 
     @property
     def ringed(self) -> bool:
@@ -445,6 +474,8 @@ def _hybrid_shapes(d: int, s: LatentSpec, kind: str) -> Dict[str, tuple]:
         return {"w_qkvz": (d, gd.conv_width + gd.d_in), "w_ba": (d, 2 * gd.num_v_heads),
                 "conv_w": (gd.conv, gd.conv_width), "dt_bias": (gd.num_v_heads,),
                 "a_log": (gd.num_v_heads,), "norm": (gd.v_dim,), "w_out": (gd.d_in, d)}
+    if kind in PAR_MIXERS:  # ONE of the two a block: the single-mixer block's tree
+        return _single_shapes(d, s, kind)
     if kind == "par":  # both mixers' trees, by ``<mixer>/<name>``
         return {f"{mixer}/{name}": shape for mixer in PAR_MIXERS
                 for name, shape in _single_shapes(d, s, mixer).items()}
@@ -469,7 +500,7 @@ def param_count(cfg) -> int:
     where the configuration says so)."""
     s, d = cfg.latent, cfg.hidden_size
     size = lambda shapes: sum(int(np.prod(v)) for v in shapes.values())
-    n = (1 + s.pred_heads) * cfg.vocab_size * d + d
+    n = (1 + (0 if cfg.tie_embeddings else s.pred_heads)) * cfg.vocab_size * d + d
     if s.single:
         return n + sum(d + size(_single_shapes(d, s, kind)) for kind in s.layer_kinds)
     if s.hybrid:
@@ -576,11 +607,15 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         """A two-norm block's norm by the spec's ``unit_offset``: plain weights are 1."""
         return centred(shape) if s.unit_offset else jnp.ones(shape, dtype)
 
+    if cfg.tie_embeddings and s.pred_heads > 1:
+        raise ValueError("a tied head is the embedding's rows: one prediction head")
+    # (a tied head IS the embedding's held rows: the tree holds no ``lm_head``)
     head = lambda layers, norm: {
         "embed": {"embedding": dense((cfg.vocab_size, d), d)},
         "layers": layers,
         "final_norm": {"scale": norm},
-        "lm_head": {"kernel": dense((d, s.pred_heads * cfg.vocab_size), d)},
+        **({} if cfg.tie_embeddings else
+           {"lm_head": {"kernel": dense((d, s.pred_heads * cfg.vocab_size), d)}}),
     }
     if s.single:
         layers = {"norm": {"scale": jnp.ones((L, d), dtype)}}
@@ -593,9 +628,10 @@ def init_params(rng, cfg, dtype=jnp.float32) -> Params:
         layers = {"attn_norm": {"scale": norm_weight((L, d))},
                   "mlp_norm": {"scale": norm_weight((L, d))},
                   "moe": tuple(single("experts") for _ in range(L - s.first_dense))}
-        for kind in HYBRID:
-            if kind not in ("eva", "par") or s.count(kind):  # (the older kinds' trees are there, empty or not)
-                layers[kind] = tuple(single(kind) for _ in range(s.count(kind)))
+        for kind in HYBRID + PAR_MIXERS:
+            # (the older kinds' trees are there, empty or not)
+            if kind not in ("eva", "par") + PAR_MIXERS or kind in s.layer_kinds:
+                layers[kind] = tuple(single(kind) for _ in range(s.layer_kinds.count(kind)))
         if s.first_dense:
             f = cfg.intermediate_size
             layers["mlp"] = tuple(
@@ -705,6 +741,12 @@ def scaled(x, m):
     if np.ndim(m) == 0 and m == 1:
         return x
     return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
+def residual(x, y, m: float = 1.0):
+    """``x + m y``: a block's branch ``y`` times the constant on it (``scaled``: the
+    product in float32, rounded once), added into the stream ``x``."""
+    return x + scaled(y, m).astype(x.dtype)
 
 
 def _mm(h, w, m=1.0):
@@ -1058,14 +1100,24 @@ def eva_inputs(aw, h, pos, ev: Eva):
     return rot(q), rot(k), heads(v)
 
 
-def head_logits(x, kernel, cfg):
+def head_logits(x, params: Params, cfg):
     """Normed hidden rows x [..., d] through the head: the next token's logits
     [..., vocab] (the first of ``pred_heads`` heads' columns; float32 out of the
-    product where the spec says ``fp32_logits``)."""
+    product where the spec says ``fp32_logits``).  A TIED head
+    (``cfg.tie_embeddings``) is the embedding's held rows, contracted over their
+    width as they lie: no second array and no transposed copy."""
     s = cfg.latent
+    m = s.logits_multiplier
+    if cfg.tie_embeddings:
+        rows = params["embed"]["embedding"]  # [vocab, d]
+        wide = s.fp32_logits or m != 1.0  # (a constant scales the product in float32, as ``_mm``)
+        out = jax.lax.dot_general(x, rows, (((x.ndim - 1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32 if wide else None)
+        out = out if m == 1.0 else out * m
+        return out if s.fp32_logits else out.astype(x.dtype)
+    kernel = params["lm_head"]["kernel"]
     if s.pred_heads > 1:
         kernel = kernel[:, :cfg.vocab_size]
-    m = s.logits_multiplier
     if s.fp32_logits:
         out = jnp.dot(x, kernel, preferred_element_type=jnp.float32)
         return out if m == 1.0 else out * m
@@ -1147,33 +1199,39 @@ def _head(params: Params, x, b: int, n: int, cfg, return_hidden: bool, aux=0.0):
     aux = jnp.asarray(aux, jnp.float32)
     if return_hidden:
         return x, None, aux
-    return head_logits(x, params["lm_head"]["kernel"], cfg), None, aux
+    return head_logits(x, params, cfg), None, aux
 
 
 def _single_blocks(layers: Params, x, b: int, n: int, cfg):
     """The uncached forward's blocks of one mixer each; x [b * n, d]."""
     s_ = cfg.latent
-    grouped = lambda a: a.reshape(b, n, *a.shape[1:])
+    pos = jnp.tile(jnp.arange(n), b)
     for l in range(cfg.num_layers):
         kind, scale, w = block_params(layers, l, s_)
         h = rms(x, scale, cfg.norm_eps)
-        if kind == "mamba":
-            y = _chunked_uncached(mamba_chunks, w, grouped(h), s_.mamba, cfg.norm_eps)
-            y = _forward_only(y.reshape(b * n, -1), "the chunked state-space scan (ops/ssm.py)")
-        elif kind == "gqa":
-            y = gqa_output(w, _attend(*gqa_inputs(w, h, s_.gqa), b, n, 0, cfg), s_.gqa)
-        else:
-            y = ffn(w, h, True, cfg)[0]
+        y = ffn(w, h, True, cfg)[0] if kind == "experts" else _mixer(kind, w, h, b, n, pos, cfg)
         x = x + y.astype(x.dtype)
     return x
 
 
-def _attend(q, k, v, b: int, n: int, window: int, cfg):
+def _mixer(kind: str, w, h, b: int, n: int, pos, cfg):
+    """The uncached forward's Mamba-2 recurrence or grouped-query attention
+    (``PAR_MIXERS``), whichever block holds it, on normed rows h [b * n, d]."""
+    s_ = cfg.latent
+    if kind == "mamba":
+        y = _chunked_uncached(mamba_chunks, w, h.reshape(b, n, -1), s_.mamba, cfg.norm_eps)
+        return _forward_only(y.reshape(b * n, -1), "the chunked state-space scan (ops/ssm.py)")
+    q, k, v = gqa_inputs(w, h, s_.gqa, pos)
+    return gqa_output(w, _attend(q, k, v, b, n, 0, cfg, s_.gqa.scale), s_.gqa)
+
+
+def _attend(q, k, v, b: int, n: int, window: int, cfg, scale=None):
     """Causal attention of ``b`` sequences of ``n`` rows each through the body
     ``cfg.attn_impl`` names (the flash dispatcher: the Pallas kernels on the
     chip where their gate takes the shape, the band-masked XLA body elsewhere):
     q [b * n, Hq, hd], k and v [b * n, Hkv, hd] -> [b * n, Hq, hd]; with
-    ``window``, over the last ``window`` keys, a row's own included.  Inputs
+    ``window``, over the last ``window`` keys, a row's own included; ``scale``:
+    the softmax scale where it is not ``hd ** -0.5``.  Inputs
     and output are ``remat='selective'``'s save points."""
     from ..ops.attention import get_attention_impl
     from .transformer import _ckpt_name
@@ -1181,8 +1239,9 @@ def _attend(q, k, v, b: int, n: int, window: int, cfg):
     q, k, v = (_ckpt_name(a.reshape(b, n, *a.shape[1:]), name)
                for a, name in ((q, "save_q"), (k, "save_k"), (v, "save_v")))
     with jax.named_scope("attn_window" if window else "attn_full"):
-        o = get_attention_impl(cfg.attn_impl)(q, k, v, causal=True,
-                                              **({"window": window} if window else {}))
+        o = get_attention_impl(cfg.attn_impl)(
+            q, k, v, causal=True, **({"window": window} if window else {}),
+            **({} if scale is None else {"scale": scale}))
     return _ckpt_name(o, "save_attn").reshape(b * n, *o.shape[2:])
 
 
@@ -1213,10 +1272,10 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
             y = _chunked_uncached(gdn_chunks, mw, h.reshape(b, n, -1), s_.gdn, eps)
             y = _forward_only(y.reshape(b * n, -1), "the chunked delta rule (ops/gdn.py)")
         elif kind == "par":  # the SUM of the recurrence's side and attention's, one input
-            y = _chunked_uncached(mamba_chunks, mw["mamba"], h.reshape(b, n, -1), s_.mamba, eps)
-            y = _forward_only(y.reshape(b * n, -1), "the chunked state-space scan (ops/ssm.py)")
-            q, k, v = gqa_inputs(mw["gqa"], h, s_.gqa, pos)
-            y = y + gqa_output(mw["gqa"], _attend(q, k, v, b, n, 0, cfg), s_.gqa)
+            y = _mixer("mamba", mw["mamba"], h, b, n, pos, cfg) \
+                + _mixer("gqa", mw["gqa"], h, b, n, pos, cfg)
+        elif kind in PAR_MIXERS:  # ONE of the two, chosen by block
+            y = _mixer(kind, mw, h, b, n, pos, cfg)
         elif kind == "eva":
             from ..ops import eva
 
@@ -1230,7 +1289,7 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
             ga = s_.mixer(kind)
             q, k, v, gate = gattn_inputs(mw, h, pos, ga, eps, s_.unit_offset)
             y = gattn_output(mw, _attend(q, k, v, b, n, ga.window, cfg), gate)
-        x = x + y.astype(x.dtype)
+        x = residual(x, y, s_.residual_multiplier)
         y, routing = ffn(fw, norm(x, n2, cfg), is_moe, cfg)
         stats, picks, scores = routing if is_moe else (None, None, None)
         term = None
@@ -1241,7 +1300,7 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
                 share = jnp.mean(jnp.any(picks[..., None] == jnp.arange(s_.n_routed), axis=1),
                                  axis=0, dtype=jnp.float32) / s_.experts_per_tok
                 term = s_.n_routed * jnp.sum(share * jnp.mean(scores, axis=0))
-        return x + y.astype(x.dtype), stats, term, picks
+        return residual(x, y, s_.residual_multiplier), stats, term, picks
 
     aux, handed = jnp.zeros((), jnp.float32), []
     for l in range(cfg.num_layers):
@@ -1260,9 +1319,9 @@ def _hybrid_blocks(layers: Params, x, b: int, n: int, cfg):
             rows, bounded = held_rows_laid_out(b * n, s_, stats[1])
             count_in_step("expert_rows_laid_out", rows)
             count_in_step("expert_layers_bounded", bounded)
-        if kind not in ("gdn", "eva"):
+        if kind not in ("gdn", "eva", "mamba"):
             count_in_step("causal_keys", jnp.float32(b * allowed_pairs(n)))
-            window = 0 if kind == "par" else s_.mixer(kind).window  # (0: every key)
+            window = 0 if kind in ("par", "gqa") else s_.mixer(kind).window  # (0: every key)
             count_in_step("window_keys_attended", jnp.float32(b * allowed_pairs(n, window)))
     return x, aux, tuple(handed)
 

@@ -175,3 +175,33 @@ def _tiny_parallel_mixers() -> TransformerConfig:
 
 
 register("tiny_parallel_mixers", _tiny_parallel_mixers())
+
+
+def _tiny_block_mixers() -> TransformerConfig:
+    """Two-norm blocks whose mixer is chosen BY BLOCK (``models/latent.py``:
+    ``LatentSpec.two_norms``; Granite 4.0-H's block,
+    https://huggingface.co/ibm-granite/granite-4.0-h-small): a Mamba-2 recurrence
+    (one group) in three blocks of four and position-free GQA in the fourth, held
+    SwiGLU experts (4 of 8, softmax top-3) and a shared one behind every mixer, one
+    constant on both residual branches, a softmax scale that is no ``head_dim **
+    -0.5``, a tied head.  For the CPU tests only: served through
+    ``InferenceEngineV2`` (three states and one layer of pages a slot), forward
+    through ``CausalLM``; no backward."""
+    from .latent import Gqa, LatentSpec, Mamba
+
+    kinds = ("mamba", "mamba", "gqa", "mamba")
+    spec = LatentSpec(
+        layer_kinds=kinds, full=None, sliding=None, index_heads=0, index_dim=0, index_topk=0,
+        first_dense=0, n_routed=8, n_held=4, held_offset=0, experts_per_tok=3, moe_width=32,
+        n_shared=1, shared_width=48, routing="softmax", two_norms=True,
+        mamba=Mamba(num_heads=8, head_dim=8, n_groups=1, state=16, conv=4, chunk=8),
+        gqa=Gqa(num_heads=4, num_kv_heads=2, head_dim=16, scale=0.11),
+        embedding_multiplier=3.0, logits_multiplier=0.25, residual_multiplier=0.6,
+        fp32_logits=True)
+    return TransformerConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=len(kinds),
+        num_heads=4, num_kv_heads=2, head_dim=16, max_seq_len=128, norm_eps=1e-5,
+        tie_embeddings=True, latent=spec)
+
+
+register("tiny_block_mixers", _tiny_block_mixers())

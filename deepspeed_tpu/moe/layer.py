@@ -646,13 +646,14 @@ def moe_block_held(lw: Any, x: jnp.ndarray, spec, valid=None, tile: Optional[int
             y = y.astype(x.dtype) @ lw["w_lat_up"]
     shared = None
     if "s_up" in lw:
-        if gated:
-            shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
-        else:
-            shared = relu2(x @ lw["s_up"]) @ lw["s_down"]
-        if spec.shared_gate:  # the shared expert behind a gate of its own
-            gate = jax.nn.sigmoid((x @ lw["w_sg"]).astype(jnp.float32))
-            shared = (shared.astype(jnp.float32) * gate).astype(x.dtype)
+        with jax.named_scope("shared_expert"):
+            if gated:
+                shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
+            else:
+                shared = relu2(x @ lw["s_up"]) @ lw["s_down"]
+            if spec.shared_gate:  # the shared expert behind a gate of its own
+                gate = jax.nn.sigmoid((x @ lw["w_sg"]).astype(jnp.float32))
+                shared = (shared.astype(jnp.float32) * gate).astype(x.dtype)
     n_valid = t if valid is None else jnp.sum(valid, dtype=jnp.int32)
     stats = jnp.stack([jnp.asarray(n_valid * k, jnp.int32),
                        jnp.sum(held, dtype=jnp.int32), jnp.max(sizes), jnp.min(sizes)])

@@ -535,6 +535,7 @@ PACKS_CARRY_STEP = {
     "falcon_h1_34b_l6": True,           # a recurrence, and a dense SwiGLU in every block
     "qwen3_next_l8_e128": False,        # a recurrence AND routed experts (ROADMAP S2 (0))
     "nemotron3_super_l11_e128": False,  # single-mixer blocks, a recurrence AND routed experts
+    "granite4_h_small_l10_e36": False,  # a mixer by block: nine recurrences AND routed experts
     "dots3_note_l5_e32": False,         # latent pages and rings: -3.3% on the chip (PR 56)
     "deepseek_v2_l5_e40": False,        # latent attention over every row: -0.2% (PR 56)
 }
@@ -561,5 +562,7 @@ def test_which_latent_engines_mix_is_what_the_spec_says(serving_configs, name):
     assert latent_runner.LatentRunner(configs[name]).packs_carry_step is said is PACKS_CARRY_STEP[name]
     if name == "falcon_h1_34b_l6":  # what sets it apart from cell 7's family
         assert s.par and s.recurrence[0] == "mamba" and not s.expert_layers and not s.n_held
+    if name == "granite4_h_small_l10_e36":  # two-norm blocks under the single-mixer kinds' names
+        assert s.hybrid and s.two_norms and s.recurrence[0] == "mamba" and len(s.expert_layers) == 10
     if name == "qwen3_next_l8_e128":
         assert s.hybrid and s.recurrence[0] == "gdn" and s.expert_layers and s.n_held

@@ -212,6 +212,40 @@ def test_step_at_two_groups_and_a_state_wider_than_the_head(shape):
         assert np.abs(np.asarray(new[i]) - np.asarray(want)).max() <= (TOL * 10 if active[i] else 0)
 
 
+# ONE group: every head reads the same B and C (Granite 4.0-H's 128 heads x 64 at state 128)
+ONE_GROUP = (8, 4, 1, 16)  # (heads, channels, groups, state)
+
+
+@pytest.mark.parametrize("t", [L - 1, 2 * L + 3])
+def test_chunked_scan_at_one_group(t):
+    h, p, r, n = ONE_GROUP
+    x, dt, a, b, c = _wide_inputs(t + 3, t, *ONE_GROUP)
+    assert b.shape == (t, 1, n)
+    s0 = jnp.asarray(np.random.default_rng(4).standard_normal((h, p, n)), jnp.float32)
+    y_ref, s_ref = _wide_recurrence(x, dt, a, b, c, s0)
+    g = -(-t // L)
+    pad = lambda v: jnp.pad(v, ((0, g * L - t),) + ((0, 0),) * (v.ndim - 1)
+                            ).reshape(g, L, *v.shape[1:])
+    y, states = ssm.ssm_scan(pad(x), pad(dt), a, pad(b), pad(c),
+                             jnp.broadcast_to(s0, (g, h, p, n)), jnp.arange(g) > 0)
+    scale = lambda v: TOL * max(1.0, float(jnp.abs(v).max()))
+    assert np.abs(np.asarray(y.reshape(g * L, h, p)[:t]) - np.asarray(y_ref)).max() <= scale(y_ref)
+    assert np.abs(np.asarray(states[-1]) - np.asarray(s_ref)).max() <= scale(s_ref)
+
+
+def test_step_at_one_group():
+    h, p, r, n = ONE_GROUP
+    s = jnp.asarray(np.random.default_rng(6).standard_normal((3, h, p, n)), jnp.float32)
+    x, dt, a, b, c = _wide_inputs(8, 3, *ONE_GROUP)
+    active = jnp.asarray([True, False, True])
+    y, new = ssm.ssm_step(s, x, dt, a, b, c, active)
+    for i in range(3):
+        y_ref, s_ref = _wide_recurrence(x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], s[i])
+        assert np.abs(np.asarray(y[i]) - np.asarray(y_ref[0])).max() <= TOL * 10
+        want = s_ref if active[i] else s[i]
+        assert np.abs(np.asarray(new[i]) - np.asarray(want)).max() <= (TOL * 10 if active[i] else 0)
+
+
 def test_a_mixers_constant_multipliers_are_the_scaled_projections():
     """``Mamba.in_multiplier`` on the input, ``multipliers`` over the five segments
     z | x | B | C | dt of ``W_in``'s output and ``out_multiplier`` on ``W_out``'s:
