@@ -948,6 +948,22 @@ def _write_pages(pool, rows, pages):
     return pool
 
 
+def _slot_states(ssm, slot):
+    """``ssm[slot]``: the states [G, H, P, N] of slots ``slot`` [G] (each in range)
+    out of a block's ``ssm`` [slots, H, P, N], in the dtype it is kept in.  A state
+    whose minor dimension is wider than one 128-lane tile is read a slot at a time:
+    XLA:TPU lowers the gather of such an operand to a ``mini-gather`` that first
+    slices the WHOLE operand into 128-lane halves (Falcon-H1's ``f32[48, 32, 128,
+    256]``: 201 MB read and written a block to fetch 16 MiB, in the device's trace),
+    and a dynamic slice names one slot and reads one.  A state of one tile or less
+    keeps the gather, which reads its slots in place: the pack programs of those
+    models compile to what they were, instruction for instruction."""
+    if ssm.shape[-1] <= 128:
+        return ssm[slot]
+    return jnp.concatenate([jax.lax.dynamic_slice_in_dim(ssm, slot[g], 1)
+                            for g in range(slot.shape[0])])
+
+
 def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cache, picked,
                      probe):
     """A pack's (write, read) for blocks that keep a recurrence's state, K / V
@@ -987,8 +1003,8 @@ def _state_pack_seam(cfg, segment_ids, valid, positions, pack_pages, tables, cac
             zero = lambda a: jnp.where(fresh.reshape(g, *(1,) * (a.ndim - 1)), 0, a)
             chunks, _ = lm.RECURRENCES[kind]
             y, states, tails = chunks(
-                w, grouped(h), grouped(valid), cont, zero(conv[slot]), zero(ssm[slot]),
-                s.recurrence[1], cfg.norm_eps, probe)
+                w, grouped(h), grouped(valid), cont, zero(conv[slot]),
+                zero(_slot_states(ssm, slot)), s.recurrence[1], cfg.norm_eps, probe)
             return (ssm.at[keep].set(states.astype(ssm.dtype), mode="drop"),
                     conv.at[keep].set(tails.astype(conv.dtype), mode="drop"),
                     y.reshape(t, -1))

@@ -6,6 +6,7 @@ the step's B, the program does what the pack's and the step's did in turn
 upload and ONE fetch, and the programs WITHOUT a step are the parent's.  CPU,
 tiny sizes: what is traced and what is counted, never a time."""
 import collections
+import dataclasses
 import hashlib
 import re
 import sys
@@ -207,6 +208,7 @@ FAMILIES = {
     "windowed": ("laguna_xs2_l5", None),         # gated attention on pages beside rings + experts
     "eva": ("evabyte_l8", None),                 # EVA attention on a table that shrinks
     "parallel": ("falcon_h1_34b_l6", "mamba"),   # mamba AND gqa in every block, a dense SwiGLU
+    "by_block": ("granite4_h_small_l10_e36", "mamba"),  # two norms, mamba OR gqa by block + experts
 }
 
 
@@ -282,17 +284,20 @@ def test_a_mixed_latent_pack_streams_each_weight_once(family):
         rhs: n for (name, lhs, rhs), n in found.items()
         if name == "dot_general" and len(lhs) == 2 and lhs[0] == rows and len(rhs) == 2})
     head = (cfg.hidden_size, cfg.vocab_size)
+    if cfg.tie_embeddings:  # the embedding's rows, contracted over their width
+        head = head[::-1]
     assert by_rows(alone, b)[head] == 1 and by_rows(mixed, 2 * b)[head] == 1
-    assert head not in by_rows(mixed, b) and not by_rows(alone, t + b)
+    # (a block of two parallel mixers keeps its recurrence's weights beside its
+    # attention's, a level down: ``layers["par"][l]["mamba"]``)
+    own = {a.shape for path, a in jax.tree_util.tree_leaves_with_path(params["layers"])
+           if recurrence and any(getattr(k, "key", None) == recurrence for k in path)}
+    # (``by_block``'s tied head [128, 64] has the shape of its recurrence's ``w_out``)
+    assert (head in own or head not in by_rows(mixed, b)) and not by_rows(alone, t + b)
     split = by_rows(mixed, t)  # what is still the pack's rows alone
     assert by_rows(mixed, t + b) + split == by_rows(alone, t) and by_rows(mixed, t + b)
     if recurrence is None:
         assert not split
     else:
-        # (a block of two parallel mixers keeps its recurrence's weights beside its
-        # attention's, a level down: ``layers["par"][l]["mamba"]``)
-        own = {a.shape for path, a in jax.tree_util.tree_leaves_with_path(params["layers"])
-               if any(getattr(k, "key", None) == recurrence for k in path)}
         assert split and set(split) <= own
         assert all(by_rows(mixed, b)[w] >= n for w, n in split.items())
     grouped = lambda found: {(lhs[0], rhs): n for (name, lhs, rhs), n in found.items()
@@ -398,7 +403,13 @@ def latent_program_hashes(eng):
 
 # as the parent of PR 56 (4ffe58e) traced them, character for character (the first
 # two of ``indexed`` are PR 54's pins of its own parent, 09c2032; ``parallel`` as the
-# parent of PR 59, 0fd9cb0, traced them)
+# parent of PR 59, 0fd9cb0, traced them; ``by_block`` as the parent of PR 64, bce27ec).
+# PR 64 changed how a pack READS a state wider than one 128-lane tile
+# (``latent_runner._slot_states``): at this size every family's state is 8 or 16
+# wide, so ``single``, ``deltanet``, ``parallel`` and ``by_block`` keep the gather and
+# the ``prefill_packed_ctx`` hashes of PR 64's parent stand, as the one-tile states of
+# the benchmark's cells 6, 7 and 13 keep theirs at the real size
+# (``test_a_real_size_pack_is_the_parents_where_its_state_is_one_tile``)
 PARENTS_LATENT_PROGRAMS = {
     "indexed": {
         "_decode_jit": "e45a8c0735c21aad134475476f384d55fc3d9cd86000c05d2e50adbd79a33b4c",
@@ -434,6 +445,11 @@ PARENTS_LATENT_PROGRAMS = {
         "_decode_jit": "85099938ecc1ea2c71cc7722ad75bde6d9b5ff3665d990319411558a12245917",
         "_decode_burst_jit": "4508e456dea3e4b9d02f4703ac4488fda3d7e772e162221cef365af938925c49",
         "prefill_packed_ctx": "01f7e54bd89b0f2da9fd59c786cabf4b4b95d1506910abef822686a10440c655",
+    },
+    "by_block": {
+        "_decode_jit": "3f8f86b03615d3251d383a9188dca2d6cd29ffff11bb34698659ecaf65acae44",
+        "_decode_burst_jit": "417d675b7824f52dcc2db988ca7b667a9376c0618d0ce52ce336d24edb863983",
+        "prefill_packed_ctx": "5516172e6249b4012f0b4eb035e14a1e308d7104fbd71266799e84c329db17a7",
     },
 }
 
@@ -486,6 +502,7 @@ STEP_SCOPES = {
     "windowed": {"full_attn_step", "window_attn_step"},
     "eva": {"eva_attend_step", "eva_summarise_step"},
     "parallel": {"gqa_attn_step", "ssm_step"},
+    "by_block": {"gqa_attn_step", "ssm_step"},
 }
 
 
@@ -566,3 +583,165 @@ def test_which_latent_engines_mix_is_what_the_spec_says(serving_configs, name):
         assert s.hybrid and s.two_norms and s.recurrence[0] == "mamba" and len(s.expert_layers) == 10
     if name == "qwen3_next_l8_e128":
         assert s.hybrid and s.recurrence[0] == "gdn" and s.expert_layers and s.n_held
+
+
+# -- a pack fetches its own chunks' states (PR 64) ----------------------------
+# ``_state_pack_seam.write`` read ``ssm[slot]`` by a gather, which XLA:TPU turns into
+# a copy of EVERY slot's state where the state is wider than one 128-lane tile
+# (``latent_runner._slot_states``).  The families that keep a recurrence's state, each
+# at its rehearsal width (8 or 16: the gather stays) and 256 wide (a slice a chunk)
+STATEFUL = sorted(f for f, (_, recurrence) in FAMILIES.items() if recurrence)
+
+
+def _state_width(cfg, width):
+    """``cfg`` with its recurrence's state ``width`` wide in its minor dimension."""
+    s = cfg.latent
+    kind, mixer = s.recurrence
+    wide = dataclasses.replace(mixer, **{"state" if kind == "mamba" else "v_dim": width})
+    return dataclasses.replace(cfg, latent=dataclasses.replace(s, **{kind: wide}))
+
+
+def _parents_read(ssm, slot):
+    return ssm[slot]  # the parent's expression (bce27ec, ``_state_pack_seam.write``)
+
+
+def _state_gathers(cfg, slots=B, t=32, bs=BS, num_blocks=24, pages=PAGES):
+    """(how many ``gather``s of the pack's jaxpr read an operand of a state's shape
+    ``[slots, H, P, N]``, how many blocks keep such a state, the jaxpr)."""
+    params = jax.eval_shape(lambda k: init_params(k, cfg, dtype=cfg.dtype), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: latent_runner.init_cache(cfg, num_blocks, bs, slots, t))
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, c, *a: latent_runner.prefill_pack(p, cfg, *a, c))(
+        params, cache, S(t), S(t), S(t), S(t // bs), S(slots), S(slots, pages))
+    state = cache["ssm"][0].shape
+    assert state == (slots, *cfg.latent.recurrence[1].state_shape)
+    found = sum(eqn.primitive.name == "gather" and eqn.invars[0].aval.shape == state
+                for eqn in _equations(jaxpr.jaxpr))
+    return found, len(cache["ssm"]), str(jaxpr)
+
+
+@pytest.mark.parametrize("family", STATEFUL)
+def test_a_pack_reads_no_state_wider_than_a_lane_tile_through_a_gather(family, monkeypatch):
+    """The jaxpr's side of it: a state of one tile or less is read by the parent's
+    gather, one a block, and the jaxpr IS the parent's expression's; a wider one by
+    no gather at all, whatever the slot count."""
+    cfg = _latent_cfg(family)
+    assert cfg.latent.recurrence[1].state_shape[-1] <= 128
+    found, blocks, mine = _state_gathers(cfg)
+    assert found == blocks > 0
+    for slots in (B, 6):
+        assert _state_gathers(_state_width(cfg, 256), slots)[:2] == (0, blocks)
+    assert _state_gathers(_state_width(cfg, 128))[:2] == (blocks, blocks)  # one tile: the gather
+    monkeypatch.setattr(latent_runner, "_slot_states", _parents_read)
+    assert _state_gathers(cfg)[2] == mine
+    assert _state_gathers(_state_width(cfg, 256))[:2] == (blocks, blocks)
+
+
+# the benchmark's cells that run this seam, by the minor dimension of the state they keep
+# at the REAL size: 6, 7 and 13 keep one tile, 12 two
+STATE_LANES = {"nemotron3_super_l11_e128": 128, "qwen3_next_l8_e128": 128,
+               "granite4_h_small_l10_e36": 128, "falcon_h1_34b_l6": 256}
+
+
+@pytest.mark.parametrize("name", sorted(STATE_LANES))
+def test_a_real_size_pack_is_the_parents_where_its_state_is_one_tile(
+        serving_configs, name, monkeypatch):
+    """At the cell's own widths, slots and pack: the pack's jaxpr is the parent's
+    expression's character for character where the state is one lane tile wide (so
+    the chip's program is the parent's), and reads no state by a gather where it is
+    wider.  The table names every serving configuration that keeps such a state."""
+    assert sorted(STATE_LANES) == sorted(
+        n for n, c in serving_configs.items() if c.latent.recurrence[1] is not None)
+    cfg = serving_configs[name]
+    e = harness.load_json(ROOT / f"benchmark/configs/{name}_serve_1chip.json")["engine"]
+    assert cfg.latent.recurrence[1].state_shape[-1] == STATE_LANES[name]
+
+    traced = lambda: _state_gathers(cfg, e["max_seqs"], e["prefill_chunk"], e["block_size"],
+                                    e["num_blocks"], -(-e["max_seq_len"] // e["block_size"]))
+    gathers, blocks, mine = traced()
+    monkeypatch.setattr(latent_runner, "_slot_states", _parents_read)
+    theirs, _, parents = traced()
+    assert theirs == blocks
+    if STATE_LANES[name] <= 128:
+        assert mine == parents and gathers == blocks
+    else:
+        assert mine != parents and gathers == 0
+
+
+def _pack_of_every_kind_of_chunk(cfg, slots, state_as=None):
+    """A pack of five chunks over a cache of ``slots`` slots whose every state, tail
+    and page holds noise, live sequences on ODD slots only (the drivers' replays):
+    two chunks of ONE sequence (slot 1: the first loads the kept state, the second
+    takes the first's inside the scan), a FRESH chunk (slot 3: zeroed after the
+    read), a chunk loading the kept state of the LAST slot, a DEAD chunk."""
+    assert slots % 2 == 0 and slots >= 6
+    g, t = 5, 5 * BS
+    rng = np.random.default_rng(11)
+    params = init_params(jax.random.PRNGKey(7), cfg)
+    cache = latent_runner.init_cache(cfg, slots * PAGES + 1, BS, slots, t)
+    noise = lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+    cache = {k: jax.tree_util.tree_map(noise, v) if k in ("ssm", "conv", "k", "v") else v
+             for k, v in cache.items()}
+    if state_as is not None:  # a control of the drivers: the state kept in another precision
+        cache = {**cache, "ssm": tuple(a.astype(state_as) for a in cache["ssm"])}
+    tables = np.arange(slots * PAGES, dtype=np.int32).reshape(slots, PAGES)
+    # (slot, first position) a chunk; a dead chunk is segment 0
+    chunks = [(1, 2 * BS), (1, 3 * BS), (3, 0), (slots - 1, BS), None]
+    seg, pos = np.zeros(t, np.int32), np.zeros(t, np.int32)
+    pages, last = np.full(g, -1, np.int32), np.full(slots, -1, np.int32)
+    for i, c in enumerate(chunks):
+        if c is not None:
+            slot, start = c
+            seg[i * BS:(i + 1) * BS] = slot + 1
+            pos[i * BS:(i + 1) * BS] = start + np.arange(BS)
+            pages[i] = tables[slot, start // BS]
+            last[slot] = (i + 1) * BS - 1
+    tok = rng.integers(1, cfg.vocab_size, t).astype(np.int32)
+    ends_in = sorted({c[0] for c in chunks if c})
+    return params, cache, (tok, seg, pos, pages, last, tables), ends_in
+
+
+# (family, the state's minor dimension, slots, the dtype the state is kept in)
+READS = [(f, w, 8, None) for f in STATEFUL for w in (None, 256)] + [
+    ("parallel", 256, 8, jnp.bfloat16),   # ``ssm_state_bf16``: the drivers re-cast the state
+    ("by_block", 256, 8, jnp.bfloat16),
+    ("parallel", 256, 6, None),           # a replay's cache: another slot count than the engine's
+    ("by_block", 256, 6, None),
+]
+
+
+@pytest.mark.parametrize("family,width,slots,state_as", READS, ids=[
+    f"{f}-{w or 'own'}-{n}slots-{jnp.dtype(d).name if d else 'f32'}" for f, w, n, d in READS])
+def test_a_pack_reads_its_chunks_states_bit_for_bit_as_the_parents_gather_did(
+        family, width, slots, state_as, monkeypatch):
+    """Data movement only: logits, every block's state and tail come out BIT FOR BIT
+    what the parent's ``ssm[slot]`` gives, in the dtype the cache holds, for a
+    repeated slot, a fresh chunk, the last slot and a dead chunk; a slot no chunk of
+    the pack ends in keeps every bit it had."""
+    cfg = _latent_cfg(family)
+    if width:
+        cfg = _state_width(cfg, width)
+    params, cache, args, ends_in = _pack_of_every_kind_of_chunk(cfg, slots, state_as)
+    before = jax.tree_util.tree_map(np.asarray, cache)
+
+    def run():
+        logits, after = jax.jit(lambda p, c, *a: latent_runner.prefill_pack(p, cfg, *a, c))(
+            params, cache, *args)
+        return np.asarray(logits), jax.tree_util.tree_map(np.asarray, after)
+
+    mine, kept = run()
+    monkeypatch.setattr(latent_runner, "_slot_states", _parents_read)
+    theirs, ref = run()
+    bits = lambda a: np.ascontiguousarray(a).view(np.uint8)
+    assert np.isfinite(mine[ends_in]).all() and (bits(mine) == bits(theirs)).all()
+    assert len(kept["ssm"]) == len(kept["conv"]) > 0
+    others = [n for n in range(slots) if n not in ends_in]
+    for name in ("ssm", "conv"):
+        for a, b, a0 in zip(kept[name], ref[name], before[name]):
+            assert a.dtype == a0.dtype and a.shape == a0.shape  # the stored layout and dtype
+            assert (bits(a) == bits(b)).all(), name
+            assert (bits(a[others]) == bits(a0[others])).all(), name
+            assert all((bits(a[n]) != bits(a0[n])).any() for n in ends_in), name
+    for name in set(kept) - {"ssm", "conv"}:
+        for a, b in zip(jax.tree_util.tree_leaves(kept[name]), jax.tree_util.tree_leaves(ref[name])):
+            assert (bits(a) == bits(b)).all(), name
