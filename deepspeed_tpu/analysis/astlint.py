@@ -140,7 +140,6 @@ GLOBAL_BASELINE: Set[Tuple[str, str]] = {
     ("ops/pallas/flash_kernel.py", "_BLOCK_Q_BWD"),
     ("ops/pallas/flash_kernel.py", "_BLOCK_K_BWD"),
     ("ops/pallas/ctx_attention.py", "_INTERPRET"),
-    ("ops/pallas/fused_adam.py", "_INTERPRET"),
     ("ops/pallas/paged_attention.py", "_INTERPRET"),
     ("ops/pallas/quant_kernel.py", "_INTERPRET"),
     ("ops/pallas/quant_matmul.py", "_INTERPRET"),
@@ -158,7 +157,6 @@ LAX_COLLECTIVE_BASELINE: Set[str] = {
     "comm/qcomm.py",
     "models/transformer.py",
     "moe/layer.py",
-    "ops/sparse_grads.py",
     "runtime/onebit.py",
     "runtime/pipeline/pipelined.py",
     "runtime/zeropp.py",

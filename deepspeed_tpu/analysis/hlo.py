@@ -23,7 +23,12 @@ A thin StableHLO scanner (:func:`stablehlo_collectives`) covers the
 pre-partitioning view (``lowered.as_text()``) the quantization tests use.
 The parser is text-shape tolerant: both ``replica_groups={{0,1}}`` and the
 iota form ``replica_groups=[2,2]<=[4]`` parse, and unknown ops simply do
-not produce records.
+not produce records.  Two printers are read: the one that puts
+``source_file="..." source_line=N`` into an instruction's metadata and types
+before operand names, and the one (jax 0.9) that prints ``stack_frame_id=N``
+against four tables at the head of the module (``FileNames`` /
+``FunctionNames`` / ``FileLocations`` / ``StackFrames``) and operands by bare
+name.  Which branch runs is decided by what the text holds.
 """
 from __future__ import annotations
 
@@ -202,25 +207,25 @@ class ProgramFacts:
 
     def wire_bytes_total(self, source_file: Optional[Sequence[str]] = None,
                          kinds: Optional[Sequence[str]] = None) -> int:
-        """Sum of per-device sent bytes over the module's collectives,
-        deduplicated by channel id (an async pair and the collective inside
-        its wrapper fusion share the channel — one transfer, one count).
+        """Sum of per-device sent bytes over the module's collectives, each
+        transfer once: a ``done`` half reports 0, and the collective inside
+        the computation an async ``-start`` op ``calls=`` IS that start's
+        transfer.  (Channel ids cannot tell transfers apart: jax 0.9 prints
+        ``channel_id=1`` on every collective of every ``shard_map`` body.)
         NOTE: collectives inside ``while`` bodies are counted ONCE; byte
         budgets are only exact for unrolled (serving-style) programs."""
-        seen = set()
+        started = set()
+        for c in self.collectives:
+            if c.phase == "start":
+                started.update(_CALLS_RE.findall(c.line))
         total = 0
         for c in self.collectives:
-            if c.phase == "done":
+            if c.phase == "done" or c.computation in started:
                 continue
             if source_file is not None and c.source_file not in source_file:
                 continue
             if kinds is not None and c.kind not in kinds:
                 continue
-            key = ("ch", c.channel_id) if c.channel_id is not None else (
-                "at", c.computation, c.index)
-            if key in seen:
-                continue
-            seen.add(key)
             total += c.bytes_on_wire
         return total
 
@@ -243,6 +248,11 @@ _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[\d+,(\d+)\]<=\[\d+\]")
 _SOURCE_RE = re.compile(r'source_file="([^"]+)"')
 _SOURCE_LINE_RE = re.compile(r"source_line=(\d+)")
 _OP_NAME_RE = re.compile(r'op_name="([^"]+)"')
+_FRAME_ID_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RE = re.compile(r"^(\d+) (.+)$")
+_FRAME_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+_OPERAND_TYPE_RE = re.compile(r"\w+\[[0-9,]*\](?:\{[^}]*\})?")
+_SSA_NAME_RE = re.compile(r"%([\w.\-]+)")
 _CALLS_RE = re.compile(r"calls=(%[\w.\-]+)")
 _COMPUTE_RE = re.compile(r"convolution|\bdot\(")
 
@@ -258,6 +268,53 @@ def _split_computations(text: str) -> Dict[str, List[str]]:
         elif name is not None and re.match(r"^  (ROOT )?%", line):
             comps[name].append(line)
     return comps
+
+
+def _frame_sources(text: str) -> Dict[int, Tuple[str, int]]:
+    """``stack_frame_id`` -> (file basename, line) from the module header's
+    tables.  A frame row names a file location, a location row a file name
+    and a line; the instruction's id is the innermost frame outside jax (the
+    call site of the primitive), so no ``parent_frame_id`` walk is needed."""
+    rows: Dict[str, Dict[int, str]] = {t: {} for t in _FRAME_TABLES}
+    table = None
+    for line in text.splitlines():
+        if line in rows:
+            table = rows[line]
+        elif _COMP_RE.match(line):
+            break  # first computation: the header is over
+        elif table is not None:
+            m = _TABLE_ROW_RE.match(line)
+            if m:
+                table[int(m.group(1))] = m.group(2)
+            elif line:
+                table = None
+
+    def field_of(row: str, key: str) -> int:
+        m = re.search(key + r"=(\d+)", row)
+        return int(m.group(1)) if m else 0
+
+    out: Dict[int, Tuple[str, int]] = {}
+    for fid, frame in rows["StackFrames"].items():
+        loc = rows["FileLocations"].get(field_of(frame, "file_location_id"), "")
+        name = rows["FileNames"].get(field_of(loc, "file_name_id"), "")
+        out[fid] = (name.strip('"').rsplit("/", 1)[-1], field_of(loc, "line"))
+    return out
+
+
+def _source_of(line: str, frames: Dict[int, Tuple[str, int]]
+               ) -> Tuple[str, Optional[int]]:
+    """(file basename, line) an instruction came from: the inline
+    ``source_file=`` form where the text has it, else its ``stack_frame_id``
+    through the header's tables; ``('', None)`` where the text has neither."""
+    src = _SOURCE_RE.search(line)
+    if src:
+        sl = _SOURCE_LINE_RE.search(line)
+        return (src.group(1).rsplit("/", 1)[-1],
+                int(sl.group(1)) if sl else None)
+    fid = _FRAME_ID_RE.search(line)
+    if fid and int(fid.group(1)) in frames:
+        return frames[int(fid.group(1))]
+    return "", None
 
 
 def _group_size(line: str) -> int:
@@ -350,6 +407,7 @@ def parse_scheduled_hlo(text: str) -> ProgramFacts:
                 kind=kind,
             ))
     comps = _split_computations(text)
+    frames = _frame_sources(text)
 
     # pass 1: classify each computation — async wrapper? contains compute?
     is_async_start: Dict[str, bool] = {}
@@ -366,14 +424,15 @@ def parse_scheduled_hlo(text: str) -> ProgramFacts:
     comp_payload: Dict[str, str] = {}  # fused comp -> payload dtype
     for name, lines in comps.items():
         wrapped = is_async_start[name] or is_async_done[name]
+        instrs = []
+        defined: Dict[str, list] = {}  # SSA name -> its result types
         for idx, line in enumerate(lines):
             m = _INSTR_RE.match(line)
-            if not m:
-                continue
-            parsed = _instr_rhs(m.group(2))
-            if parsed is None:
-                continue
-            results, op, rest = parsed
+            parsed = _instr_rhs(m.group(2)) if m else None
+            if parsed is not None:
+                instrs.append((idx, line, parsed))
+                defined[m.group(1)] = parsed[0]
+        for idx, line, (results, op, rest) in instrs:
             kindphase = _op_kind(op)
             if kindphase is None:
                 continue
@@ -381,23 +440,25 @@ def parse_scheduled_hlo(text: str) -> ProgramFacts:
             operands_str, _ = _operand_section(rest)
             operands = [t for t in
                         (_parse_type(tok) for tok in
-                         re.findall(r"\w+\[[0-9,]*\](?:\{[^}]*\})?",
-                                    operands_str))
+                         _OPERAND_TYPE_RE.findall(operands_str))
                         if t is not None]
+            if not operands:
+                # operands printed by bare name: each one's type is its
+                # defining instruction's, in the same computation
+                operands = [t for n in _SSA_NAME_RE.findall(operands_str)
+                            for t in defined.get(n, ())]
             ch = _CHANNEL_RE.search(line)
             channel = int(ch.group(1)) if ch else None
             picks = results if phase != "done" else (operands or results)
             dtype, shape = (picks[0] if picks else ("f32", ()))
-            src = _SOURCE_RE.search(line)
-            sl = _SOURCE_LINE_RE.search(line)
+            source_file, source_line = _source_of(line, frames)
             opn = _OP_NAME_RE.search(line)
             collectives.append(Collective(
                 kind=kind, phase=phase, dtype=dtype, shape=shape,
                 result_types=tuple(results), operand_types=tuple(operands),
                 channel_id=channel, group_size=_group_size(line),
                 computation=name, index=idx, async_wrapped=wrapped,
-                source_file=(src.group(1).rsplit("/", 1)[-1] if src else ""),
-                source_line=int(sl.group(1)) if sl else None,
+                source_file=source_file, source_line=source_line,
                 op_name=opn.group(1) if opn else "", line=line.strip(),
             ))
             if wrapped and channel is not None and name not in comp_channel:
@@ -452,7 +513,7 @@ def parse_scheduled_hlo(text: str) -> ProgramFacts:
                     events.append(("start", ("%" + iname,),
                                    c.dtype if c else "f32", kind, idx))
                 elif phase == "done":
-                    opnames = re.findall(r"%([\w.\-]+)", parsed[2])
+                    opnames = _SSA_NAME_RE.findall(parsed[2])
                     events.append(("done", tuple("%" + n for n in opnames),
                                    c.dtype if c else "f32", kind, idx))
                 continue

@@ -2,8 +2,7 @@
 
 One place owns the knowledge of WHICH collectives a TP serving dispatch
 issues and at what shapes — previously duplicated (and drifting) between
-``engine_v2._account_comm`` (telemetry wire bytes), ``engine_v2.
-measure_tp_collectives`` (the microbenchmark chain) and ``autotuning.
+``engine_v2._account_comm`` (telemetry wire bytes) and ``autotuning.
 roofline.predict_serve_cost`` (the cost model's wire term).  The Graft
 Auditor's ``collective_budget`` checker compares the
 compiled program's enumerated collectives against exactly this plan, so a
@@ -84,8 +83,7 @@ def serving_tick_plan(
     axis.  Empty without TP and without seq sharding.
 
     - 2 row-parallel transports per layer (o + down), ``n_tokens x hidden``
-      at the engine's ``fmt`` (the exact set ``_account_comm`` counts and
-      ``measure_tp_collectives`` replays).  With ``tiles`` > 1 each
+      at the engine's ``fmt`` (the exact set ``_account_comm`` counts).  With ``tiles`` > 1 each
       projection splits into free-dim tiles reduced independently, and a
       QUANTIZED tile pads to a ``tp * chunk`` multiple before it ships —
       at small widths that padding is real extra wire (the Graft Auditor

@@ -1098,10 +1098,7 @@ _REFERENCE_PASSTHROUGH_KEYS = {
     # array dtype; quantized wire formats are the zero++ knobs
     # (zero_quantized_weights/gradients), which ARE consumed
     "communication_data_type",
-    # torch sparse embedding gradients — XLA has no sparse gradient type.
-    # The opt-in TPU equivalent is ops/sparse_grads.py embedding_lookup
-    # (sparse-communication custom VJP under shard_map); models choose it at
-    # construction, not via this runtime flag, so the key stays accepted
+    # torch sparse embedding gradients — XLA has no sparse gradient type
     "sparse_gradients",
     # NVIDIA apex mixed precision — bf16/fp16 configs are the path here
     "amp",

@@ -499,7 +499,7 @@ class InferenceEngineV2:
         self._h = {
             k: reg.histogram(f"{self._ns}/{k}")
             for k in ("prefill_pack_ms", "decode_tick_ms", "spec_tick_ms",
-                      "collect_wait_ms", "tp_allreduce_ms")
+                      "collect_wait_ms")
         }
         # eagerly register this engine's request-latency group so the
         # namespace's histograms exist (empty) before any request arrives
@@ -1482,94 +1482,6 @@ class InferenceEngineV2:
         # wire-op count: the plan's row group is already per-tile
         n_ops = sum(p.count for p in plan if p.label == "row_psum")
         self._comm_c["collectives"].inc(reps * n_ops)
-
-    def measure_tp_collectives(self, reps: int = 8,
-                               fmt: Optional[str] = None,
-                               tiles: Optional[int] = None) -> Optional[float]:
-        """Microbenchmark THIS engine's per-decode-tick TP collective cost
-        at the served shapes — the sequential row-parallel transport chain
-        (two per layer: o-projection + down-projection partial products,
-        [B, hidden] fp32 each) plus the vocab-sharded logits all-gather —
-        and observe every rep into the ``serve/tp_allreduce_ms`` histogram
-        with a span on the engine's ``comm`` trace track.
-
-        ``fmt``/``tiles`` default to this engine's transport policy
-        (``quant_comm``/``comm_tiles``), so a passthrough engine measures
-        the exact ``psum`` chain and a quant-comm engine measures the
-        quantized tiled transport it actually serves with
-        (``tests/test_qcomm.py`` calls both explicitly).
-
-        This is the cost the quantized-collectives work attacks, so it is
-        MEASURED here rather than guessed from link rooflines.  Explicit
-        call (only tests make it today; it is not part of the
-        decode hot path — a per-tick in-graph measurement would perturb the
-        tick it measures).  Returns the median ms, or None without a TP
-        mesh."""
-        import time as _time
-
-        if self._mesh is None or self.serving_ctx.size <= 1:
-            return None
-        from jax.sharding import PartitionSpec as P
-
-        from ..comm import qcomm
-        from ..parallel.sharding import shard_map_compat
-        from ..parallel.topology import MODEL_AXIS
-
-        from ..comm import budget as _budget
-
-        cfg, tp = self.cfg, self.serving_ctx.size
-        fmt = fmt if fmt is not None else self.serving_ctx.comm_fmt
-        tiles = tiles if tiles is not None else self.serving_ctx.comm_tiles
-        B, d = self.mgr.max_seqs, cfg.hidden_size
-        v = (cfg.vocab_size // tp) * tp  # sharded-head rows, pad-free
-        # the measured chain replays the budget plan's row-parallel group
-        # (comm/budget.py) — the same enumeration _account_comm and the
-        # Graft Auditor use, so the microbenchmark and the accounting
-        # cannot drift apart
-        n_red = sum(p.count for p in _budget.serving_tick_plan(
-            cfg, B, tp, fmt) if p.label == "row_psum")
-
-        def body(xs, lg):
-            def step(c, x):
-                # the carry feeds each transport's operand, so XLA cannot
-                # fuse the chain into one batched collective — a decode
-                # tick issues its row-parallel reductions sequentially too
-                c = c + qcomm.q_psum_tiled(
-                    x + 0.0 * c, MODEL_AXIS, fmt, tiles=tiles, world=tp,
-                    out_dtype=jnp.float32,
-                )
-                return c, jnp.float32(0)
-            c, _ = jax.lax.scan(step, jnp.zeros_like(xs[0]), xs)
-            full = qcomm.q_all_gather(
-                lg, MODEL_AXIS, fmt, axis=1, tiled=True,
-                out_dtype=jnp.float32,
-            )
-            return c, full
-
-        f = jax.jit(shard_map_compat(
-            body, self._mesh,
-            in_specs=(P(None, None, None), P(None, MODEL_AXIS)),
-            out_specs=(P(None, None), P(None, None)),
-        ))
-        xs = jnp.zeros((n_red, B, d), jnp.float32)
-        lg = jnp.zeros((B, v), jnp.float32)
-        jax.block_until_ready(f(xs, lg))  # compile outside the window
-        times = []
-        for _ in range(reps):
-            sp = self.telemetry.recorder.start(
-                "tp_allreduce", track=self._comm_ns,
-                hist=self._h["tp_allreduce_ms"],
-                reductions=n_red, gather_rows=v, tp=tp, fmt=fmt,
-                tiles=tiles,
-            )
-            t0 = _time.perf_counter()
-            out = f(xs, lg)
-            sp.dispatched()
-            jax.block_until_ready(out)
-            times.append(1e3 * (_time.perf_counter() - t0))
-            sp.end()
-        times.sort()
-        return times[len(times) // 2]
 
     # -- fault hooks ---------------------------------------------------------
     def _maybe_fault(self, point: str, uids) -> None:

@@ -6,9 +6,7 @@ TPU-native counterpart of the reference's optimizer zoo
 ``runtime/engine.py:1405 _configure_basic_optimizer``).  On TPU "fused" is the
 default: XLA fuses the whole optax update chain into a handful of kernels, so
 the CUDA multi-tensor-apply machinery (csrc/adam/multi_tensor_adam.cu) has no
-translation — the per-param lax ops below compile to the same fused form.  A
-Pallas fused kernel path exists in ``ops/pallas/fused_adam.py`` for the cases
-where hand-tiling beats XLA (benchmarked, not assumed).
+translation — the per-param lax ops below compile to the same fused form.
 
 1-bit optimizers (OnebitAdam ``runtime/fp16/onebit/adam.py:14``, OnebitLamb,
 ZeroOneAdam) are provided via the error-feedback sign-compression wrapper in

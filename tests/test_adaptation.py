@@ -20,6 +20,7 @@ The contract under test, layer by layer:
   transport with typed refusals;
 - lint: importing the controller from a hot path is an astlint violation.
 """
+import itertools
 import threading
 import time
 
@@ -224,7 +225,12 @@ def test_engine_apply_knobs_all_or_nothing(tiny):
 # ---------------------------------------------------------------------------
 def test_controller_rolls_back_injected_bad_retune(tiny):
     cfg, params = tiny
-    eng = _engine(cfg, params, telemetry=Telemetry(True),
+    # time is COUNTED, a millisecond a reading of the clock: a request's TTFT
+    # is then the work between its submit and its first token (three times
+    # the ticks at a chunk of 8 as at 32), whatever else the machine runs
+    readings = itertools.count()
+    eng = _engine(cfg, params,
+                  telemetry=Telemetry(True, clock=lambda: next(readings) * 1e-3),
                   serve=ServeConfig(adaptation=AdaptationConfig(
                       enabled=True, min_window=2, guard_epochs=1,
                       cooldown_epochs=1, regress_tolerance=1.3,

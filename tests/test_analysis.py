@@ -2,7 +2,7 @@
 
 Three layers of coverage, all in the tier-1 fast lane (this file IS the
 CI gate — a lint violation or a failed audit over the repo's real hot
-jits fails here, same pattern as conftest's MARKER_AUDIT):
+jits fails here):
 
 1. parser unit tests — real CPU-compiled scheduled HLO plus synthetic
    fixtures reproducing the TPU printer quirks the old regex tests broke
@@ -57,6 +57,78 @@ def test_parser_real_psum_program_typed_records():
     # ring convention matches the qcomm accounting exactly
     assert c.bytes_on_wire == qcomm.wire_bytes("all_reduce", 32, "none", 2)
     assert facts.wire_bytes_total() == c.bytes_on_wire
+
+
+# the two-device psum below as the printer before jax 0.9 wrote it: source
+# inline in the metadata, each operand's type before its name
+_INLINE_SOURCE_PSUM_HLO = """\
+HloModule jit_body, is_scheduled=true, entry_computation_layout={(f32[1,8]{1,0})->f32[1,8]{1,0}}, num_partitions=2
+
+%region_0.4 (Arg_0.5: f32[], Arg_1.6: f32[]) -> f32[] {
+  %Arg_0.5 = f32[] parameter(0)
+  %Arg_1.6 = f32[] parameter(1)
+  ROOT %add.7 = f32[] add(f32[] %Arg_0.5, f32[] %Arg_1.6), metadata={op_name="jit(body)/jit(main)/shard_map/psum" source_file="/root/repo/tests/test_analysis.py" source_line=99}
+}
+
+ENTRY %main.10_spmd (param: f32[1,8]) -> f32[1,8] {
+  %param = f32[1,8]{1,0} parameter(0), sharding={devices=[2,1]<=[2]}
+  ROOT %all-reduce = f32[1,8]{1,0} all-reduce(f32[1,8]{1,0} %param), channel_id=1, replica_groups={{0,1}}, use_global_device_ids=true, to_apply=%region_0.4, metadata={op_name="jit(body)/jit(main)/shard_map/psum" source_file="/root/repo/tests/test_analysis.py" source_line=99}
+}
+"""
+
+
+def test_parser_reads_both_printed_forms_to_equal_records():
+    """The same collective from the text with ``source_file=`` inline and
+    typed operands (a literal) and from the installed JAX's text (compiled
+    here: ``stack_frame_id`` against the header's tables, operands by bare
+    name) parses to records equal in kind, source and operand types."""
+    mesh = make_grid(model=2).mesh
+
+    def body(x):
+        return jax.lax.psum(x, "model")
+
+    f = jax.jit(shard_map_compat(
+        body, mesh, in_specs=(P("model", None),), out_specs=P(None, None)))
+    live_facts = ahlo.program_facts(f, jnp.zeros((2, 8)))
+    inline_facts = ahlo.parse_scheduled_hlo(_INLINE_SOURCE_PSUM_HLO)
+    (live,), (inline,) = live_facts.collectives, inline_facts.collectives
+    for name in ("kind", "phase", "source_file", "operand_types",
+                 "result_types", "group_size", "bytes_on_wire"):
+        assert getattr(live, name) == getattr(inline, name), name
+    assert live.source_file == "test_analysis.py"
+    assert live.operand_types == (("f32", (1, 8)),)
+    assert live.source_line == body.__code__.co_firstlineno + 1
+    assert live_facts.wire_bytes_total() == inline_facts.wire_bytes_total()
+
+
+_SHARED_CHANNEL_HLO = """\
+HloModule jit_two, is_scheduled=true
+
+%async_computation.1 (param_0: f32[4,8]) -> f32[4,8] {
+  %param_0 = f32[4,8]{1,0} parameter(0)
+  ROOT %all-to-all.9 = f32[4,8]{1,0} all-to-all(%param_0), channel_id=1, replica_groups={{0,1}}, dimensions={0}
+}
+
+ENTRY %main.3 (x: f32[4,8]) -> f32[4,8] {
+  %x = f32[4,8]{1,0} parameter(0)
+  %all-reduce.1 = f32[4,8]{1,0} all-reduce(%x), channel_id=1, replica_groups={{0,1}}, to_apply=%add
+  %all-reduce.2 = f32[4,8]{1,0} all-reduce(%all-reduce.1), channel_id=1, replica_groups={{0,1}}, to_apply=%add
+  %all-to-all-start.1 = (f32[4,8]{1,0}, f32[4,8]{1,0}) all-to-all-start(%all-reduce.2), channel_id=1, replica_groups={{0,1}}, dimensions={0}, calls=%async_computation.1
+  ROOT %all-to-all-done.1 = f32[4,8]{1,0} all-to-all-done(%all-to-all-start.1)
+}
+"""
+
+
+def test_wire_bytes_count_each_transfer_once_without_channel_ids():
+    """jax 0.9 prints ``channel_id=1`` on every ``shard_map`` collective:
+    two all-reduces of one computation are two transfers, while an async
+    start and the collective inside the computation it calls are one."""
+    facts = ahlo.parse_scheduled_hlo(_SHARED_CHANNEL_HLO)
+    ar, a2a = 2 * 128 * 1 // 2, 128 * 1 // 2  # ring convention, W=2
+    assert facts.wire_bytes_total(kinds=("all-reduce",)) == 2 * ar
+    assert facts.wire_bytes_total() == 2 * ar + a2a
+    done = facts.find(kind="all-to-all", phase="done")[0]
+    assert done.operand_types == (("f32", (4, 8)), ("f32", (4, 8)))
 
 
 def test_parser_real_donation_header():

@@ -474,6 +474,7 @@ def test_autotune_serving_bounded_search_measures_replicated_caching(tmp_path):
                and t.measured for t in trials)
 
 
+# slow: 42 s: builds and serves a real engine a candidate over two rungs; the bounded search beside it is in the lane
 @pytest.mark.slow
 def test_full_serving_search_with_halving():
     """A larger (slow-lane) search exercising two rungs + promotion on

@@ -31,7 +31,6 @@ def test_eigenvalue_quadratic_exact():
     assert abs(est - true) / true < 1e-2, (est, true)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_eigenvalue_on_model_loss_runs():
     from deepspeed_tpu.models import CausalLM, get_preset
 

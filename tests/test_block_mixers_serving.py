@@ -137,11 +137,17 @@ def _through_the_runner(cfg, params, prompt, steps, state_as=None):
     return np.stack(rows), fed, cache, consumed, picks
 
 
+def _probe(arch, m):
+    """The reference's probe as ONE program (op by op, each block's scans compile
+    again at every call): ``(params, tokens, forced picks or None) -> (logits, seen)``."""
+    return jax.jit(lambda p, t, forced: arch.probe(p, t, m, forced=forced))
+
+
 def _on_the_programs_picks(arch, params, m, prompt, fed, picks):
     """The reference's rows [1 + len(fed), vocab] on the experts the program picked."""
     tokens = np.asarray([prompt + fed], np.int32)
     forced = [jnp.asarray(p[None]) for p in picks]
-    lg, _ = arch.probe(params, tokens, m, forced=forced)
+    lg, _ = _probe(arch, m)(params, tokens, forced)
     return np.asarray(lg)[0][len(prompt) - 1:]
 
 
@@ -184,7 +190,7 @@ def test_the_runners_logits_match_the_references_full_forward(model):
     assert len(consumed) == 9 and len(picks) == 10 and picks[0].shape == (85, 3)
     want = _on_the_programs_picks(arch, params, m, prompt, fed, picks)
     assert want.std() > 0.1 and np.abs(rows - want).max() <= TOL
-    _, seen = arch.probe(params, np.asarray([prompt + fed], np.int32), m)
+    _, seen = _probe(arch, m)(params, np.asarray([prompt + fed], np.int32), None)
     for ex, r in zip(picks, seen):
         theirs = np.take_along_axis(np.asarray(r["router_biased"])[0], ex, axis=1)
         assert (np.asarray(r["router_cutoff"])[0][:, None] - theirs).max() <= TOL
@@ -211,11 +217,12 @@ def test_each_constant_decides_a_logit(model, key):
 def test_each_departure_of_the_reference_decides_a_logit(model, name):
     m, arch, cfg, params = model
     ids = np.random.default_rng(11).integers(0, m["vocab_size"], (1, 60)).astype(np.int32)
-    want = np.asarray(arch.logits(params, ids, m))[0]
+    logits = lambda: np.asarray(jax.jit(lambda p, t: arch.logits(p, t, m))(params, ids))[0]  # traced anew a call
+    want = logits()
     with arch.departure(name):
-        got = np.asarray(arch.logits(params, ids, m))[0]
+        got = logits()
     assert np.abs(got - want).max() > 2e-3
-    assert np.array_equal(np.asarray(arch.logits(params, ids, m))[0], want)  # gone with its context
+    assert np.array_equal(logits(), want)  # gone with its context
 
 
 def test_the_reference_in_blocks_is_the_reference(model):
@@ -223,7 +230,7 @@ def test_the_reference_in_blocks_is_the_reference(model):
     has room for), on forced picks, gives the whole forward's rows and scores."""
     m, arch, cfg, params = model
     ids = np.random.default_rng(5).integers(0, m["vocab_size"], (1, 40)).astype(np.int32)
-    whole, seen = arch.probe(params, ids, m)
+    whole, seen = _probe(arch, m)(params, ids, None)
     picks = [np.asarray(jax.lax.top_k(r["router_biased"], m["num_experts_per_tok"])[1]) for r in seen]
     rows = [0, 17, 39]
     got, scores = arch.logits_in_blocks(params, ids, m, rows, picks, cols=50)
@@ -251,7 +258,7 @@ def test_the_shares_add_up_to_the_uncut_layer(model):
     x = jnp.asarray(rng.standard_normal((1, 24, 64)), jnp.float32)
     for l in (0, 5):  # a Mamba block and the attention block
         kind = arch._kinds(whole)[l]
-        run = lambda p, mm: arch.block(x, *arch._block_weights(p, mm, l), mm, kind)
+        run = lambda p, mm: jax.jit(lambda p: arch.block(x, *arch._block_weights(p, mm, l), mm, kind))(p)
         want = run(uncut, whole)
         parts = [run(*arch.cut_to_share(uncut, whole, s)) for s in shares]
         alike = run(*arch.cut_to_share(uncut, whole, {"experts": (0, 0)}))  # mixer + shared expert
@@ -260,15 +267,16 @@ def test_the_shares_add_up_to_the_uncut_layer(model):
         # the program's expert layer as each member runs it, on the block's normed input
         n1, n2, mw, fw = arch._block_weights(uncut, whole, l)
         h = jnp.asarray(rng.standard_normal((24, 64)), jnp.float32)
-        full, _ = moe_block_held(fw, h, cfg_w.latent)
+        held_layer = lambda spec: jax.jit(lambda w, h: moe_block_held(w, h, spec))
+        full, _ = held_layer(cfg_w.latent)(fw, h)
         held = []
         for s in shares:
             p_s, m_s = arch.cut_to_share(uncut, whole, s)
             spec = arch.transformer_config(m_s, max_seq_len=64).latent
             assert (spec.n_routed, spec.n_held, spec.held_offset) == (8, 4, s["experts"][0])
-            y, (stats, picked, _) = moe_block_held(p_s["layers"]["moe"][l], h, spec)
+            y, (stats, picked, _) = held_layer(spec)(p_s["layers"]["moe"][l], h)
             assert 0 < int(stats[1]) < int(stats[0]) == 24 * 3  # some picks fall on the other member
-            routed, shared = arch.ffn_parts(p_s["layers"]["moe"][l], h[None], m_s)
+            routed, shared = jax.jit(lambda w, h: arch.ffn_parts(w, h, m_s))(p_s["layers"]["moe"][l], h[None])
             assert np.abs(y - (routed + shared)[0]).max() <= TOL
             held.append(y - shared[0])
         assert np.abs(sum(held) + shared[0] - full).max() <= TOL
@@ -278,11 +286,12 @@ def test_the_shares_add_up_to_the_uncut_layer(model):
     p_s, m_s = arch.cut_to_share(uncut, whole, share)
     cfg_s = arch.transformer_config(m_s, max_seq_len=64)
     assert cfg_s.vocab_size == 64 and p_s["embed"]["embedding"].shape == (64, 64)
-    got = np.asarray(forward(p_s, ids, cfg_s)[0])
-    assert np.abs(got - np.asarray(arch.logits(uncut, ids, whole, share=share))).max() <= TOL
+    logits = lambda **kw: np.asarray(jax.jit(lambda p, t: arch.logits(p, t, whole, **kw))(uncut, ids))
+    got = np.asarray(jax.jit(lambda p, t: forward(p, t, cfg_s)[0])(p_s, ids))
+    assert np.abs(got - logits(share=share)).max() <= TOL
     # ... and a slice of the vocabulary alone is the uncut model's columns
-    rows = np.asarray(arch.logits(uncut, ids, whole, share={"vocab_rows": (0, 64)}))
-    assert np.abs(rows - np.asarray(arch.logits(uncut, ids, whole))[..., :64]).max() <= TOL
+    rows = logits(share={"vocab_rows": (0, 64)})
+    assert np.abs(rows - logits()[..., :64]).max() <= TOL
 
 
 def test_the_cache_holds_nine_states_and_one_layer_of_pages(model):
@@ -418,6 +427,7 @@ def test_the_toy_preset_serves_through_the_engine_and_the_uncached_forward():
     assert s.two_norms and (s.count("mamba"), s.count("gqa")) == (3, 1) and cfg.tie_embeddings
     params = init_params(jax.random.PRNGKey(3), cfg)
     assert "lm_head" not in params
+    uncached = jax.jit(lambda p, t: forward(p, t, cfg)[0])
     eng = _engine(cfg, params)
     sched = eng.scheduler
     rng = np.random.default_rng(0)
@@ -427,7 +437,7 @@ def test_the_toy_preset_serves_through_the_engine_and_the_uncached_forward():
     sched.run(wait_for=list(prompts))
     for u, p in prompts.items():
         out = sched.pop_result(u)
-        lg = np.asarray(forward(params, np.asarray([p + out], np.int32), cfg)[0])[0]
+        lg = np.asarray(uncached(params, np.asarray([p + out], np.int32)))[0]
         lg = lg[len(p) - 1: len(p) + len(out) - 1]
         assert lg.std() > 0.1 and (lg.max(-1) - lg[np.arange(len(out)), out]).max() <= TOL, u
     assert len(eng.kv["ssm"]) == 3 and len(eng.kv["k"]) == 1

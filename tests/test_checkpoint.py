@@ -212,7 +212,8 @@ def test_corrupt_shard_falls_back_to_previous_tag(tmp_path):
         e3.load_checkpoint(str(tmp_path), tag="newer")
 
 
-@pytest.mark.nightly  # slow e2e
+# slow: 8-21 s: waits on orbax's background writer thread, whose time swings with the machine's load
+@pytest.mark.slow
 def test_async_checkpoint_save_and_resume(tmp_path):
     """checkpoint.async_save: save returns immediately, 'latest' appears only
     after commit, and the checkpoint restores exactly (reference

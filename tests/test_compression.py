@@ -135,7 +135,8 @@ def _train(config_extra, steps=30, lr=5e-3):
     return np.asarray(losses)
 
 
-@pytest.mark.nightly  # slow e2e
+# slow: 16 s: a dense and a quantization-aware engine trained 30 steps each to compare the losses they reach
+@pytest.mark.slow
 def test_qat_trains_to_near_parity():
     """VERDICT item-7 'done' criterion: a tiny model under 8-bit QAT reaches
     near-parity loss with the uncompressed run."""
@@ -146,7 +147,6 @@ def test_qat_trains_to_near_parity():
     assert qat[-1] < base[-1] + 0.35, (qat[-1], base[-1])
 
 
-@pytest.mark.nightly  # slow e2e
 def test_pruned_training_and_export():
     prune_cfg = {
         "sparse_pruning": {
@@ -179,7 +179,6 @@ def test_pruned_training_and_export():
     assert 0.25 < zero_frac < 0.35, zero_frac
 
 
-@pytest.mark.nightly  # slow e2e
 def test_activation_quantization_wires_into_model():
     from deepspeed_tpu.models import CausalLM, get_preset
 
@@ -209,7 +208,6 @@ def test_activation_quantization_wires_into_model():
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
 
 
-@pytest.mark.nightly  # slow e2e
 def test_init_compression_on_engine():
     from deepspeed_tpu.models import CausalLM, get_preset
 
@@ -296,7 +294,6 @@ def test_head_pruning_masks_whole_heads():
     np.testing.assert_array_equal(per_head_dead_q, per_head_dead_o)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_redundancy_clean_exports_shrunk_tree_same_loss():
     """Masked model and physically-shrunk model must compute the SAME loss
     (the dead units contribute exactly zero), with smaller arrays."""
@@ -326,7 +323,6 @@ def test_redundancy_clean_exports_shrunk_tree_same_loss():
     assert abs(l_masked - l_clean) < 2e-3, (l_masked, l_clean)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_head_pruning_trains_and_recovers():
     """e2e 'done' criterion: prune half the proxy's heads mid-training and
     keep training — loss recovers to a decreasing trajectory."""
@@ -353,7 +349,6 @@ def test_head_pruning_trains_and_recovers():
     assert losses[-1] < losses[0] * 0.6
 
 
-@pytest.mark.nightly  # slow e2e
 def test_layer_reduction_and_kd():
     from deepspeed_tpu.compression import layer_reduction_init, make_kd_loss_fn
     from deepspeed_tpu.models import CausalLM, get_preset

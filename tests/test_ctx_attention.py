@@ -69,19 +69,13 @@ def _setup(segs, hq=8, hkv=2, hd=16, nb=32, bs=8, pad=0, seed=0,
 SEGS = [(10, 13), (6, 0), (6, 37)]  # mid-page, cold, multi-page
 
 
-# tier-1 keeps every shape uncapped plus ONE soft-capped shape; the other
-# capped combinations ride the slow lane (the lane runs near its limit now
-# that these run in the interpreter instead of failing at trace)
-_SLOW = pytest.mark.slow
-
-
 @pytest.mark.parametrize("hq,hkv,hd,cap", [
     (8, 8, 64, None),     # 410M-proxy: MHA, hd 64
     (8, 2, 128, None),    # 8B-proxy: GQA-narrow (hkv < tp at tp=4), hd 128
     (4, 1, 16, None),     # MQA corner
     (8, 2, 128, 20.0),
-    pytest.param(8, 8, 64, 20.0, marks=_SLOW),
-    pytest.param(4, 1, 16, 20.0, marks=_SLOW),
+    (8, 8, 64, 20.0),
+    (4, 1, 16, 20.0),
 ])
 def test_kernel_parity_vs_dense(hq, hkv, hd, cap):
     q, k, v, seg, ckl, cvl, tb, ln = _setup(SEGS, hq=hq, hkv=hkv, hd=hd,
@@ -245,7 +239,6 @@ def test_kernel_ignores_garbage_in_dead_pages():
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=2e-5)
 
 
-@_SLOW  # engine-level, 5 s; chip_smoke.py checks prefix hits on the chip
 def test_prefix_hit_identical_to_cold_prefill():
     """A suffix prefill over cached context must be numerically the SAME
     reduction as the cold full-prompt prefill — the invariant prefix
@@ -278,7 +271,6 @@ def test_prefix_hit_identical_to_cold_prefill():
                                atol=2e-5)
 
 
-@_SLOW  # 3.5 s; the kernel's partial=True mode stays in the nightly lane
 def test_partial_mode_striped_ring_merge():
     """Seq-shard contract: stripe the pool over 2 shards, run the kernel in
     ``partial=True`` on each shard's locally-translated tables (pack keys
@@ -375,7 +367,7 @@ def test_dense_clamp_scales_with_true_context():
 
 
 # ---------------------------------------------------------------------------
-# engine: greedy token identity, kernel vs pinned-dense (nightly lane)
+# engine: greedy token identity, kernel vs pinned-dense
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def tiny_model():
@@ -418,7 +410,9 @@ def _workload():
             4: shared + [12, 22, 32]}
 
 
-@pytest.mark.nightly  # serve compiles on the virtual mesh (~1-2 min/case)
+# slow: 42 s a case (tp 1, 2): two engines with prefix caching, chunked prefill, speculation and int8 weights
+# compile every hot jit on a dp2 x seq2 x tp mesh; the mesh and the feature set are what is tested
+@pytest.mark.slow
 @pytest.mark.parametrize("tp", [1, 2])
 def test_engine_token_identity_kernel_vs_dense(tiny_model, tp, monkeypatch):
     """The acceptance bar: the ctx kernel is greedy token-identical to the
@@ -456,7 +450,8 @@ def test_engine_token_identity_kernel_vs_dense(tiny_model, tp, monkeypatch):
 # ---------------------------------------------------------------------------
 # compiled memory proof: temporaries no longer scale O(T * P * bs)
 # ---------------------------------------------------------------------------
-@pytest.mark.nightly  # compile-only, but heavy enough for the nightly lane
+# slow: 27 s: four compiles, two of them of the dense gather at a 384-page table; the width is the assertion
+@pytest.mark.slow
 def test_memory_analysis_pack_temps_bounded():
     """The compiler's own accounting: widen the block table 12x (P=32 ->
     P=384, the dense gather's O(T * P * bs) axis) and the dense program's

@@ -147,7 +147,8 @@ def test_truncate_to_seqlen():
 # ---------------------------------------------------------------------------
 # engine integration: seqlen curriculum ramps, loss still trains
 # ---------------------------------------------------------------------------
-@pytest.mark.nightly  # slow e2e
+# slow: 16 s: the sequence-length ramp recompiles the step at every length it reaches
+@pytest.mark.slow
 def test_engine_curriculum_seqlen_ramp():
     from deepspeed_tpu.models import CausalLM, get_preset
 
@@ -208,7 +209,8 @@ def _make(tmpdir, ds):
     )
 
 
-@pytest.mark.nightly  # slow e2e
+# slow: 12 s: two engines and an orbax checkpoint round trip
+@pytest.mark.slow
 def test_dataloader_position_rides_checkpoint(tmp_path):
     ds = _TokDataset()
     engine, _, loader, _ = _make(tmp_path, ds)

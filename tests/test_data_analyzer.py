@@ -189,7 +189,6 @@ def test_cli(corpus, tmp_path, capsys):
     np.testing.assert_array_equal(np.asarray(idx.index_to_metric), np.sort(lengths))
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_analysis_path_wires_into_initialize(tmp_path, monkeypatch):
     """Config-level loop closure (reference data_sampling): a
     ``data_analysis_path`` in the curriculum config makes initialize()'s

@@ -26,11 +26,6 @@ ELASTIC_CFG = {
 }
 
 
-
-# full-area e2e coverage: nightly lane (r4 VERDICT weak #5 — the
-# default lane must gate commits in <5 min)
-pytestmark = pytest.mark.nightly
-
 def test_compute_world_scales_down():
     agent = ElasticAgent(ELASTIC_CFG, ["true"])
     w4 = agent.compute_world(4)
@@ -128,6 +123,8 @@ WORKER = textwrap.dedent("""
 """)
 
 
+# slow: 26 s: the agent spawns worker processes, kills one and restarts the group at a smaller world
+@pytest.mark.slow
 def test_agent_resumes_at_smaller_world_with_loss_continuity(tmp_path):
     """Kill a worker mid-training: the agent must re-form a smaller valid
     world and the relaunched rank 0 must RESUME from the checkpoint (steps

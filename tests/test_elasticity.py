@@ -24,11 +24,6 @@ BASE = {
 }
 
 
-
-# full-area e2e coverage: nightly lane (r4 VERDICT weak #5 — the
-# default lane must gate commits in <5 min)
-pytestmark = pytest.mark.nightly
-
 def test_v01_batch_and_valid_gpus_deterministic():
     """The reference's own doc example: this config resolves to 9792 with
     a fixed valid-gpu list (tests/unit/elasticity values)."""
@@ -150,6 +145,8 @@ def test_engine_adopts_elastic_batch():
     assert np.isfinite(float(engine.train_batch(batch)))
 
 
+# slow: 19 s: trains, checkpoints and restarts two engines at different world sizes
+@pytest.mark.slow
 def test_elastic_restart_different_world_size(tmp_path):
     """Save at dp=8, resume at dp=4 with the SAME global batch (gas doubles):
     the elastic-restart contract (reference: elastic ZeRO checkpoint merge;

@@ -146,7 +146,7 @@ def test_the_kept_summaries_are_the_references_and_rotary_follows_the_position(m
     n, steps = 70, 9
     seq = rng.integers(0, cfg.vocab_size, n + steps).astype(np.int32)
     *_, (p, lg, mgr, s, cache) = _through_the_bodies(cfg, params, seq, n, (32, 64, 70))
-    _, seen = arch.probe(params, seq[None], m)
+    _, seen = jax.jit(lambda p, t: arch.probe(p, t, m))(params, seq[None])
     chunks = (n + steps) // SUMMARY
     for layer, r in enumerate(seen):
         for mine, theirs in ((cache["k"][layer], r["eva_k"]), (cache["v"][layer], r["eva_v"])):
@@ -163,7 +163,7 @@ def test_the_kept_summaries_are_the_references_and_rotary_follows_the_position(m
     assert np.abs(np.asarray(cache["k"][0])[page, 77 % PAGE] - np.asarray(k77)[0]).max() <= 1e-5
     want = np.asarray(ref(params, seq[None]))[0]
     with arch.departure("row_for_position"):
-        other = np.asarray(arch.logits(params, seq[None], m))[0]
+        other = np.asarray(jax.jit(lambda p, t: arch.logits(p, t, m))(params, seq[None]))[0]
     assert np.abs(other[:32] - want[:32]).max() <= 1e-5   # row = position inside window 0
     assert np.abs(other[40:] - want[40:]).max() > 1e-2
 

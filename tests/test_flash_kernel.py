@@ -58,7 +58,6 @@ def test_supports_gating():
     assert not flash_kernel.supports(q2, q2, q2, True, 0, None, None)  # head dim
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_flash_segment_ids_parity():
     """Packed-sequence masking: kernel matches the dense body fwd + grads."""
     from deepspeed_tpu.ops.pallas import flash_kernel as fk
@@ -204,7 +203,6 @@ def _sparse_qkv(b, s, hq, hkv, d, seed=9):
             _rand((b, s, hkv, d), seed + 2))
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_block_sparse_kernel_matches_masked_dense():
     """Local-window layout at kernel granularity: the sparse kernel must
     equal the element-masked dense body (values AND grads), GQA included."""
@@ -255,7 +253,9 @@ def test_block_sparse_kernel_grid_scales_with_sparsity():
     assert sum(counts) <= 0.3 * dense_grid
 
 
-@pytest.mark.perf
+# slow: 4 s: a ratio of two CPU wall clocks, which wobbles under six workers; what it times is
+# counted by test_block_sparse_kernel_grid_scales_with_sparsity, in the lane
+@pytest.mark.slow
 def test_block_sparse_kernel_wall_clock_beats_dense():
     """Interpret-mode wall clock at 75% block sparsity: >= 2x over the dense
     flash kernel on the same shapes (the reference's ~6x axis at its scale,
@@ -382,6 +382,7 @@ def test_flash_dispatcher_replicates_what_does_not_divide(devices):
     assert _mesh_case(dict(model=8), 1, 8, 2, grads=False) == {(1, 128, 8, 64)}
 
 
+# slow: 12 s: the interpreted kernel under four more mesh shapes; two meshes are in the lane
 @pytest.mark.slow
 def test_flash_dispatcher_partitions_more_meshes(devices):
     assert _mesh_case(dict(data=2, fsdp=2, model=2), 4, 4, 4, grads=True) == {

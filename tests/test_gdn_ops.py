@@ -40,6 +40,12 @@ def _inputs(key, n, alike: float = 0.0, dims=(HK, HV, DK, DV), identical: bool =
     return q, k, v, g, beta
 
 
+# the two forms and the reference as ONE program a shape each: called op by op,
+# every slice, pad and scan of theirs compiles on its own
+_inputs = jax.jit(_inputs, static_argnames=("n", "alike", "dims", "identical"))
+gdn_scan, gdn_step, recurrence = jax.jit(gdn.gdn_scan), jax.jit(gdn.gdn_step), jax.jit(recurrence)
+
+
 def _chunked(seqs, chunk, loaded=None):
     """Sequences (tuples of per-token inputs) laid out as ``gdn_scan`` takes
     them: chunks of ``chunk`` rows, each sequence starting on a chunk, the last
@@ -64,7 +70,7 @@ def _scan_against_the_recurrence(seqs, chunk):
     (_, hv, dv), dk = seqs[0][2].shape, seqs[0][1].shape[-1]
     arrays, valid, cont, ends = _chunked(seqs, chunk)
     zeros = jnp.zeros((len(cont), hv, dk, dv))
-    o, states = gdn.gdn_scan(*arrays, zeros, cont)
+    o, states = gdn_scan(*arrays, zeros, cont)
     o = np.asarray(o).reshape(-1, hv, dv)
     at, far_o, far_s = 0, 0.0, 0.0
     for seq, end in zip(seqs, ends):
@@ -119,11 +125,11 @@ def test_a_state_handed_from_pack_to_pack():
     seq = _inputs(jax.random.PRNGKey(3), 48)
     arrays, _, cont, _ = _chunked([seq], 8)
     zeros = jnp.zeros((6, HV, DK, DV))
-    o_all, s_all = gdn.gdn_scan(*arrays, zeros, cont)
+    o_all, s_all = gdn_scan(*arrays, zeros, cont)
     first = [a[:2] for a in arrays]
-    _, s1 = gdn.gdn_scan(*first, zeros[:2], cont[:2])
+    _, s1 = gdn_scan(*first, zeros[:2], cont[:2])
     loaded = zeros[:4].at[0].set(s1[-1])
-    o2, s2 = gdn.gdn_scan(*[a[2:] for a in arrays], loaded, jnp.asarray([False, True, True, True]))
+    o2, s2 = gdn_scan(*[a[2:] for a in arrays], loaded, jnp.asarray([False, True, True, True]))
     assert np.abs(np.asarray(s2[-1] - s_all[-1])).max() <= TOL
     assert np.abs(np.asarray(o2 - o_all[2:])).max() <= TOL
 
@@ -135,7 +141,7 @@ def test_padding_rows_leave_the_state_as_it_was():
     arrays = [a[None] for a in seq]
     arrays[3], arrays[4] = jnp.zeros_like(arrays[3]), jnp.zeros_like(arrays[4])
     loaded = jax.random.normal(jax.random.PRNGKey(5), (1, HV, DK, DV))
-    _, states = gdn.gdn_scan(*arrays, loaded, jnp.asarray([False]))
+    _, states = gdn_scan(*arrays, loaded, jnp.asarray([False]))
     assert np.abs(np.asarray(states - loaded)).max() <= 1e-6
 
 
@@ -152,7 +158,7 @@ def test_the_step_token_by_token_is_the_recurrence_and_spares_idle_slots():
         outs = []
         for t in range(19):
             rows = [jnp.broadcast_to(a[t], (3, *a.shape[1:])) for a in seq]
-            o, s = gdn.gdn_step(s, *rows, active)
+            o, s = gdn_step(s, *rows, active)
             outs.append(o[1])
         assert s.dtype == dtype
         assert np.array_equal(np.asarray(s[0::2].astype(jnp.float32)),
@@ -167,10 +173,10 @@ def test_the_scan_and_the_step_agree_across_a_pack_and_its_ticks():
     seq = _inputs(jax.random.PRNGKey(8), 29)
     head = [a[:24] for a in seq]
     arrays, _, cont, _ = _chunked([head], 8)
-    _, states = gdn.gdn_scan(*arrays, jnp.zeros((3, HV, DK, DV)), cont)
+    _, states = gdn_scan(*arrays, jnp.zeros((3, HV, DK, DV)), cont)
     s = states[-1][None]
     for t in range(24, 29):
-        o, s = gdn.gdn_step(s, *(a[t][None] for a in seq), jnp.asarray([True]))
+        o, s = gdn_step(s, *(a[t][None] for a in seq), jnp.asarray([True]))
     want_o, want_s = recurrence(*(a[None] for a in seq))
     assert np.abs(np.asarray(o[0] - want_o[0, -1])).max() <= TOL
     assert np.abs(np.asarray(s[0] - want_s[0])).max() <= TOL

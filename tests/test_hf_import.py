@@ -24,11 +24,6 @@ from deepspeed_tpu.checkpoint.hf_import import (
 from deepspeed_tpu.models.transformer import CausalLM, forward
 
 
-
-# full-area e2e coverage: nightly lane (r4 VERDICT weak #5 — the
-# default lane must gate commits in <5 min)
-pytestmark = pytest.mark.nightly
-
 def _tiny_llama_dir(tmp_path, tie=False):
     cfg = transformers.LlamaConfig(
         vocab_size=128,
@@ -50,6 +45,8 @@ def _tiny_llama_dir(tmp_path, tie=False):
     return d, model
 
 
+# slow: 11-17 s (50 s on a loaded machine): imports torch and transformers and runs HF's own Llama as the reference
+@pytest.mark.slow
 def test_llama_logits_parity(tmp_path):
     d, hf_model = _tiny_llama_dir(tmp_path)
     params, cfg = load_hf_checkpoint(d)

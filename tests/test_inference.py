@@ -105,7 +105,8 @@ def test_v2_paged_matches_v1_dense(tiny_model):
     assert dense == paged, (dense, paged)
 
 
-@pytest.mark.nightly  # slow e2e
+# slow: 12 s: the v1 engine's generate, a prompt at a time, as the reference of the v2 scheduler
+@pytest.mark.slow
 def test_v2_continuous_batching_parity(tiny_model):
     """Two concurrent sequences must decode exactly as they do alone."""
     model, params = tiny_model
@@ -127,7 +128,6 @@ def test_v2_continuous_batching_parity(tiny_model):
     assert gen[1] == solo[1] and gen[2] == solo[2], (gen, solo)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_v2_block_growth_across_pages(tiny_model):
     """Generation crossing block boundaries stays consistent."""
     model, params = tiny_model
@@ -157,7 +157,6 @@ def test_v2_admission_control(tiny_model):
 # ---------------------------------------------------------------------------
 # r4: serving prefill runs the Pallas flash kernel (VERDICT r3 #6)
 # ---------------------------------------------------------------------------
-@pytest.mark.nightly  # slow e2e
 def test_packed_prefill_dispatches_flash_kernel(monkeypatch):
     """With the kernel backend 'available' (forced + interpret mode), a
     kernel-sized packed prefill must run pallas_flash_attention — with
@@ -229,7 +228,6 @@ def test_small_bucket_prefill_falls_back_dense(monkeypatch):
     assert 1 in out and not calls.get("hit")
 
 
-@pytest.mark.nightly  # slow e2e
 def test_step_n_matches_per_tick_decode():
     """Pipelined burst decode (tokens stay on device) must produce the same
     greedy tokens as per-tick step(), including stop-token truncation."""
@@ -260,7 +258,6 @@ def test_step_n_matches_per_tick_decode():
     assert run(False) == run(True)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_step_n_stop_token_truncates():
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.inference.sampling import SamplingParams

@@ -20,11 +20,6 @@ from deepspeed_tpu.parallel.topology import MODEL_AXIS, initialize_mesh
 from conftest import make_grid
 
 
-
-# full-area e2e coverage: nightly lane (r4 VERDICT weak #5 — the
-# default lane must gate commits in <5 min)
-pytestmark = pytest.mark.nightly
-
 @pytest.fixture(scope="module")
 def gqa_model():
     # fp32: greedy parity across different reduction orders (TP psum of

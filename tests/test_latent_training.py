@@ -90,7 +90,7 @@ def test_train_batch_gives_the_references_loss_and_every_gradient(monkeypatch, b
 def test_a_block_under_any_recomputation_gives_the_same_gradients(remat):
     ids = jnp.asarray(_ids(2))
     params = init_params(jax.random.PRNGKey(3), _cfg())
-    grads = lambda r: jax.grad(lambda p: CausalLM(_cfg(remat=r)).loss_fn(p, {"input_ids": ids}))(params)
+    grads = lambda r: jax.jit(jax.grad(lambda p: CausalLM(_cfg(remat=r)).loss_fn(p, {"input_ids": ids})))(params)
     for a, b in zip(jax.tree_util.tree_leaves(grads(remat)), jax.tree_util.tree_leaves(grads("none"))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-5)
 
@@ -111,7 +111,7 @@ def test_the_four_shares_outputs_and_input_gradients_add_up_to_the_uncut_layers(
     summed = lambda x: sum(share(i, x) for i in range(total // held))
     uncut = lambda x: ARCH.uncut_expert_layer(lw, x[None], whole)[0]
     (y, dx), (y_ref, dx_ref) = (
-        (f(x), jax.vjp(f, x)[1](ct)[0]) for f in (summed, uncut))
+        jax.jit(lambda x, f=f: (f(x), jax.vjp(f, x)[1](ct)[0]))(x) for f in (summed, uncut))
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=2e-5)
     np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_ref), atol=2e-5)
     # ... and the whole layer in one share is the uncut layer too
@@ -148,16 +148,17 @@ def test_the_balance_term_is_its_formula_and_rides_the_loss():
     cfg, ids = _cfg(), jnp.asarray(_ids(2))
     params = init_params(jax.random.PRNGKey(5), cfg)
     spec = cfg.latent
-    _, _, aux = latent.forward(params, ids[:, :-1], cfg, return_hidden=True)
+    _, _, aux = jax.jit(lambda p, t: latent.forward(p, t, cfg, return_hidden=True))(params, ids[:, :-1])
     # the reference's factors, layer by layer: f_e [L, E] (shares of the pairs), P_e [L, E]
-    _, (share, mean, _) = ARCH.hidden_states(params, ids[:, :-1], M)
+    _, (share, mean, _) = jax.jit(lambda p, t: ARCH.hidden_states(p, t, M))(params, ids[:, :-1])
     np.testing.assert_allclose(np.asarray(share).sum(-1), 1.0, atol=1e-6)
     want = spec.n_routed * np.sum(np.asarray(share) * np.asarray(mean), -1)  # a term a layer
     assert float(aux) == pytest.approx(want.sum(), abs=1e-5)
     assert (want > 0.9).all() and (want < spec.n_routed).all()  # 1 when even, E when all on one
-    with_term = float(CausalLM(cfg).loss_fn(params, {"input_ids": ids}))
+    loss = lambda c: float(jax.jit(lambda p: CausalLM(c).loss_fn(p, {"input_ids": ids}))(params))
+    with_term = loss(cfg)
     bare = cfg.replace(latent=dataclasses.replace(spec, router_aux_loss_coef=0.0))
-    without = float(CausalLM(bare).loss_fn(params, {"input_ids": ids}))
+    without = loss(bare)
     assert with_term - without == pytest.approx(
         spec.router_aux_loss_coef * want.mean(), abs=1e-6)
     # a perfectly even router reads exactly 1 / E a score

@@ -121,7 +121,6 @@ def test_megastep_identity_quantized(tiny):
     _close_leakfree(eng4)
 
 
-@pytest.mark.nightly  # tp=2 compile on the virtual mesh (~1 min)
 def test_megastep_identity_tp2(tiny):
     """Megastep under tensor parallelism: the burst jit carries the same
     out-sharding pins as per-tick decode, so tp=2 greedy results stay

@@ -7,6 +7,7 @@ upload and ONE fetch, and the programs WITHOUT a step are the parent's.  CPU,
 tiny sizes: what is traced and what is counted, never a time."""
 import collections
 import dataclasses
+import functools
 import hashlib
 import re
 import sys
@@ -242,11 +243,19 @@ def every_family_carries(monkeypatch):
     monkeypatch.setattr(latent_runner.LatentRunner, "__init__", carrying)
 
 
-def _latent_engine(family, **kw):
+@functools.lru_cache(maxsize=None)
+def _latent_model(family):
+    """A family's configuration and weights, made ONCE: every engine below reads
+    them and none writes them (an engine donates its pool, never its weights)."""
     cfg = _latent_cfg(family)
+    return cfg, init_params(jax.random.PRNGKey(7), cfg)
+
+
+def _latent_engine(family, **kw):
+    cfg, params = _latent_model(family)
     kw = {**dict(max_seqs=4, num_blocks=64, block_size=8, prefill_buckets=(32,),
                  prefill_chunk=32, max_seq_len=256, telemetry=True), **kw}
-    return InferenceEngineV2(init_params(jax.random.PRNGKey(7), cfg), cfg, **kw)
+    return InferenceEngineV2(params, cfg, **kw)
 
 
 def _latent_dots(cfg, step):

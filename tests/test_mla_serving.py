@@ -223,13 +223,16 @@ def test_the_four_shares_add_up_to_the_reference_layer(model):
     lw.update(w_gate=wide(ks[0], total, d, s.moe_width), w_up=wide(ks[1], total, d, s.moe_width),
               w_down=wide(ks[2], total, s.moe_width, d))
     x = jax.random.normal(ks[3], (40, d))
-    want = arch.uncut_expert_layer(lw, x[None], m)[0]
+    # (each call ONE program, traced anew: op by op the layer's scans compile again a call)
+    uncut = lambda: jax.jit(lambda w, x: arch.uncut_expert_layer(w, x, m))(lw, x[None])[0]
+    held_layer = lambda spec: jax.jit(lambda w, x: moe_block_held(w, x, spec))
+    want = uncut()
     shared = (jax.nn.silu(x @ lw["s_gate"]) * (x @ lw["s_up"])) @ lw["s_down"]
     assert lw["s_gate"].shape == (d, 2 * s.moe_width)  # n_shared_experts 2: one SwiGLU twice as wide
     got, pairs = jnp.zeros_like(x), 0
     for off in range(0, total, held):
         mine = dict(lw, **{k: lw[k][off:off + held] for k in ("w_gate", "w_up", "w_down")})
-        y, (st, picks, _) = moe_block_held(mine, x, replace(s, held_offset=off))
+        y, (st, picks, _) = held_layer(replace(s, held_offset=off))(mine, x)
         got += y - shared
         pairs += int(st[1])
         # picks never leave the kept groups: at most 3 of the 8 groups of 4 experts
@@ -238,13 +241,13 @@ def test_the_four_shares_add_up_to_the_reference_layer(model):
     assert float(jnp.abs(got + shared - want).max()) <= 1e-5
     # this member's partial sum is the reference's for the share the file states
     mine = dict(lw, **{k: lw[k][:held] for k in ("w_gate", "w_up", "w_down")})
-    here, (st, _, _) = moe_block_held(mine, x, s)
-    part = arch._experts(mine, x[None], m, None, None)[0]
+    here, (st, _, _) = held_layer(s)(mine, x)
+    part = jax.jit(lambda w, x: arch._experts(w, x, m, None, None))(mine, x[None])[0]
     assert float(jnp.abs(here - part).max()) <= 1e-5 and 0 < int(st[1]) < 40 * s.experts_per_tok
     # the weights are the scores x 16, not renormalised: either departure reads otherwise
     for name in ("routing_renormalised", "routing_not_scaled"):
         with arch.departure(name):
-            other = arch.uncut_expert_layer(lw, x[None], m)[0]
+            other = uncut()
         assert float(jnp.abs(other - want).max()) > 1e-2, name
 
 

@@ -35,7 +35,6 @@ def _parity(d, hf_model, rtol=2e-4, atol=2e-4):
     return cfg
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_gpt2_parity(tmp_path):
     torch.manual_seed(0)
     m = transformers.GPT2LMHeadModel(transformers.GPT2Config(
@@ -45,7 +44,6 @@ def test_gpt2_parity(tmp_path):
     assert cfg.position == "learned" and cfg.tie_embeddings
 
 
-@pytest.mark.nightly  # slow e2e
 def test_opt_parity(tmp_path):
     torch.manual_seed(0)
     m = transformers.OPTForCausalLM(transformers.OPTConfig(
@@ -77,7 +75,6 @@ def test_falcon_parity(tmp_path):
     assert cfg.parallel_block and cfg.num_kv_heads == 1  # MQA
 
 
-@pytest.mark.nightly  # slow e2e
 def test_gptj_parity(tmp_path):
     torch.manual_seed(0)
     m = transformers.GPTJForCausalLM(transformers.GPTJConfig(
@@ -87,7 +84,6 @@ def test_gptj_parity(tmp_path):
     assert cfg.parallel_block and cfg.rotary_dim == 8 and cfg.head_bias
 
 
-@pytest.mark.nightly  # slow e2e
 def test_phi_parity(tmp_path):
     torch.manual_seed(0)
     m = transformers.PhiForCausalLM(transformers.PhiConfig(
@@ -99,7 +95,6 @@ def test_phi_parity(tmp_path):
 
 
 @pytest.mark.parametrize("preset", ["tiny_parallel", "tiny_alibi"])
-@pytest.mark.nightly  # slow e2e
 def test_new_family_presets_train(preset):
     cfg = get_preset(preset)
     model = CausalLM(cfg)

@@ -44,7 +44,6 @@ def test_gpt2_architecture():
     assert logits.shape == (1, 8, cfg.vocab_size)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_kv_cache_decode_matches_full(tiny):
     cfg, params = tiny
     rng = np.random.default_rng(0)
@@ -86,7 +85,6 @@ def test_presets_registered():
     assert abs(cfg.param_count - 8.03e9) / 8.03e9 < 0.01
 
 
-@pytest.mark.nightly  # slow e2e
 def test_tiny_model_trains():
     cfg = get_preset("tiny")
     model = CausalLM(cfg)
@@ -116,7 +114,6 @@ def test_remat_matches_no_remat(tiny):
     np.testing.assert_allclose(np.asarray(base), np.asarray(rem), atol=1e-5)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_graft_entry_compiles():
     import sys
 
@@ -128,7 +125,6 @@ def test_graft_entry_compiles():
     assert np.isfinite(np.asarray(out)).all()
 
 
-@pytest.mark.nightly  # slow e2e
 def test_remat_offload_policy_trains():
     """remat='offload': activation save points ride pinned host memory
     (FPDT host-offload analogue, reference sequence/fpdt_layer.py:510)."""
@@ -170,7 +166,8 @@ def test_remat_offload_policy_trains():
     np.testing.assert_allclose(losses, ref, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.nightly  # slow e2e
+# slow: 13 s: two TP training engines (domino_chunks 1 and 2) compiled and stepped
+@pytest.mark.slow
 def test_domino_chunks_numerical_parity():
     """domino_chunks=2 splits layer compute into independent chunks; the
     math must be identical to the single-chunk body (values and grads)."""
@@ -192,7 +189,6 @@ def test_domino_chunks_numerical_parity():
                                    np.asarray(b, np.float32), atol=2e-2)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_domino_chunks_config_wiring():
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import CausalLM, get_preset

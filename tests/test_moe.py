@@ -87,7 +87,8 @@ def test_aux_loss_uniform_vs_skewed():
     assert float(g_u.aux_loss) == pytest.approx(1.0, abs=0.05)  # balanced -> E*(1/E^2)*E = 1
 
 
-@pytest.mark.nightly  # slow e2e
+# slow: 12 s: an expert-parallel engine's step on the 8-device mesh
+@pytest.mark.slow
 def test_moe_model_forward_and_train():
     cfg = get_preset("tiny_moe")
     model = CausalLM(cfg)

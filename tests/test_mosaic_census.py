@@ -8,16 +8,12 @@ hands out abstract v5e devices and ``.lower(lowering_platforms=("tpu",))
 This is a PRE-FLIGHT for a chip run (it catches API drift, layout refusals and
 VMEM overflows in seconds instead of chip-minutes) — it is never evidence that
 a kernel runs or computes the right numbers; ``chip_smoke.py`` on the chip is.
-
-``slow``-marked: a kernel takes up to ten seconds a shape here.
 """
 import os
 
 import jax
 import jax.numpy as jnp
 import pytest
-
-pytestmark = pytest.mark.slow
 
 HQ, HKV, HD, D, FFN = 32, 8, 128, 4096, 14336
 BS = 32  # KV page size the smoke serves with

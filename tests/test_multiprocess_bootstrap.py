@@ -115,7 +115,8 @@ def _reference_tokens(prompt, max_new):
     return want
 
 
-@pytest.mark.nightly  # spawns a fresh jax worker process (~30 s)
+# slow: 13 s: spawns a serving worker process and drives it over its pipes
+@pytest.mark.slow
 def test_two_process_router_worker_round_trip():
     """Router process + worker process over the ``DSTPU_*`` env protocol:
     the worker bootstraps through ``comm.init_distributed`` (the same env
@@ -184,7 +185,8 @@ def test_two_process_router_worker_round_trip():
     assert popped["result"]["tokens"] == want, (popped, want)
 
 
-@pytest.mark.nightly  # spawns two fresh jax worker processes (~60 s)
+# slow: 15 s: spawns two socket workers, binds ports and waits for their reaping
+@pytest.mark.slow
 def test_two_process_socket_round_trip_and_reap():
     """The full out-of-process spawn path: ``spawn_worker`` launches real
     worker subprocesses serving the SOCKET protocol, a ``RemoteWorker``
@@ -245,7 +247,9 @@ def test_two_process_socket_round_trip_and_reap():
             h.reap()
 
 
-@pytest.mark.nightly  # spawns two fresh jax processes (~30 s)
+# slow: 5 s: spawns two jax processes that rendezvous over a localhost port; stays out of the
+# six-worker lane with the file's other two
+@pytest.mark.slow
 @needs_cpu_multiprocess
 def test_two_process_bootstrap_and_collective(tmp_path):
     port = 9731 + (os.getpid() % 500)

@@ -184,7 +184,6 @@ def _tp_row_transport_facts(topo, fmt, tiles, kd=4096, nd=4096, B=64):
     return parse_scheduled_hlo(txt)
 
 
-@pytest.mark.slow
 def test_tp_row_transport_int8_payload_on_wire(topo):
     """(a)-criterion, TP half: with ``comm_fmt='int8'`` the row-parallel
     partial-sum transport's wire ops — the EQuARX reduce-scatter
@@ -205,7 +204,6 @@ def test_tp_row_transport_int8_payload_on_wire(topo):
     assert res.passed, [str(v) for v in res.violations]
 
 
-@pytest.mark.slow
 def test_zeropp_quantized_payloads_on_wire(topo):
     """(a)-criterion, ZeRO-3 half: the ZeRO++ step's weight all-gathers
     (qwZ) and gradient reduce all_to_alls (qgZ), routed through
@@ -260,7 +258,6 @@ def test_zeropp_quantized_payloads_on_wire(topo):
     assert len(s8_a2a) >= 4, f"qgZ reduces not s8 on the wire ({len(s8_a2a)})"
 
 
-@pytest.mark.slow
 def test_tp_tiled_matmul_collectives_overlap_compute(topo):
     """(b)-criterion, TP half: with ``comm_tiles=4`` the row-parallel
     matmul decomposes into per-tile GEMMs with independent transports, and
@@ -333,7 +330,6 @@ def _domino_compile_stats(topo, domino):
     }
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_domino_chunks_shrink_synchronous_allreduce_footprint(topo):
     """Domino evidence (r4 VERDICT next #8), RE-MEASURED honestly by the
     typed parser: with domino_chunks=2 the per-chunk dataflows are

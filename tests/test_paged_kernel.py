@@ -191,7 +191,8 @@ def _engine(**kw):
     ), cfg
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
+# slow: 22 s: the v1 engine's packed prefill against one sequential prefill a prompt, each its own compile
+@pytest.mark.slow
 def test_packed_prefill_matches_sequential():
     """N prompts in ONE packed dispatch produce the same first tokens and
     the same decode continuations as one-prefill-per-prompt."""

@@ -186,7 +186,8 @@ def test_every_multiplier_moves_the_logits(model, key, at):
     rows, fed, _, _ = _through_the_runner(cfg2, params, prompt, 3)
     tokens = np.asarray([prompt + fed], np.int32)
     sound = np.asarray(ref(params, tokens))[0][len(prompt) - 1: len(prompt) + 3]
-    follows = np.asarray(arch.logits(params, tokens, moved))[0][len(prompt) - 1: len(prompt) + 3]
+    follows = np.asarray(jax.jit(lambda p, t: arch.logits(p, t, moved))(params, tokens))[0]
+    follows = follows[len(prompt) - 1: len(prompt) + 3]
     assert np.abs(rows - sound).max() > 100 * TOL, "the multiplier moved nothing"
     assert np.abs(rows - follows).max() <= TOL
 
@@ -205,11 +206,12 @@ def test_the_blocks_output_is_the_sum_of_the_two_sides(model, side, dropped):
     rows, fed, _, _ = _through_the_runner(cfg2, params, prompt, 4)
     tokens = np.asarray([prompt + fed], np.int32)
     cut = slice(len(prompt) - 1, len(prompt) + 4)
+    logits = lambda mm: np.asarray(jax.jit(lambda p, t: arch.logits(p, t, mm))(params, tokens))[0][cut]
     if dropped == "gqa":  # the reference's own departure: the attention side never computed
         with arch.departure("attention_dropped"):
-            want = np.asarray(arch.logits(params, tokens, m))[0][cut]
+            want = logits(m)  # (traced inside the departure)
     else:
-        want = np.asarray(arch.logits(params, tokens, one_sided))[0][cut]
+        want = logits(one_sided)
     whole = np.asarray(ref(params, tokens))[0][cut]
     assert np.abs(rows - want).max() <= TOL
     assert np.abs(rows - whole).max() > 100 * TOL

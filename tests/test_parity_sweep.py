@@ -103,7 +103,6 @@ def _loco_zero(reset_T=1024):
     }
 
 
-@pytest.mark.nightly  # slow e2e
 def test_loco_trains_and_tracks_dense():
     ref = [
         float(_engine(zero={"stage": 3, "param_persistence_threshold": 0}).train_batch(b))
@@ -248,56 +247,3 @@ def test_ds_io_registered():
     assert callable(bench.main)
     pyproject = pathlib.Path(__file__).resolve().parents[1] / "pyproject.toml"
     assert 'ds_io = "deepspeed_tpu.nvme.bench:main"' in pyproject.read_text()
-
-
-# ---------------------------------------------------------------------------
-# sparse embedding gradients (runtime/sparse_tensor.py)
-# ---------------------------------------------------------------------------
-def test_sparse_embedding_grad_matches_dense_local():
-    from deepspeed_tpu.ops.sparse_grads import embedding_lookup
-
-    table = jax.random.normal(jax.random.PRNGKey(0), (32, 8))
-    ids = jnp.array([[1, 5, 1], [0, 31, 5]])
-
-    def loss_sparse(t):
-        return jnp.sum(embedding_lookup(t, ids, None) ** 2)
-
-    def loss_dense(t):
-        return jnp.sum(jnp.take(t, ids, axis=0) ** 2)
-
-    gs = jax.grad(loss_sparse)(table)
-    gd = jax.grad(loss_dense)(table)
-    np.testing.assert_allclose(np.asarray(gs), np.asarray(gd), rtol=1e-5, atol=1e-6)
-
-
-def test_sparse_embedding_grad_dp_reduction():
-    """Under shard_map over a DP axis the sparse path must equal the dense
-    pmean'd gradient while shipping only rows+ids on the wire."""
-    from jax.sharding import PartitionSpec as P
-
-    from deepspeed_tpu.ops.sparse_grads import embedding_lookup
-
-    mesh = jax.make_mesh((8,), ("data",))
-    table = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
-    ids = jax.random.randint(jax.random.PRNGKey(1), (8, 4), 0, 64)
-
-    def body(t, i):
-        def loss(tt):
-            return jnp.mean(embedding_lookup(tt, i, "data") ** 2)
-
-        return jax.grad(loss)(t)
-
-    g_sparse = jax.jit(
-        shard_map_compat(
-            body, mesh=mesh, in_specs=(P(), P("data")), out_specs=P(),
-            check_vma=False,
-        )
-    )(table, ids)
-
-    def dense_loss(t):
-        return jnp.mean(jnp.take(t, ids, axis=0) ** 2)
-
-    g_dense = jax.grad(dense_loss)(table)
-    np.testing.assert_allclose(
-        np.asarray(g_sparse), np.asarray(g_dense), rtol=1e-5, atol=1e-6
-    )

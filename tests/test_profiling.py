@@ -15,11 +15,6 @@ from deepspeed_tpu.profiling import (
 )
 
 
-
-# full-area e2e coverage: nightly lane (r4 VERDICT weak #5 — the
-# default lane must gate commits in <5 min)
-pytestmark = pytest.mark.nightly
-
 def test_model_tree_params_match_real_param_tree():
     """Tree param counts are exact vs the actual initialized pytree."""
     for name in ("tiny", "tiny_gpt2", "tiny_moe"):

@@ -394,17 +394,6 @@ def test_tp_engine_comm_byte_accounting():
     assert oh(e_none) == oh(e_q) > 0
 
 
-def test_measure_tp_collectives_quant_ab():
-    """One engine, both transports: it measures its exact psum chain AND
-    the quantized tiled transport (telemetry-off engines still measure;
-    the histogram feed is covered by test_tp_fused_serving)."""
-    eng = _tp_engine("none")
-    med_none = eng.measure_tp_collectives(reps=2)
-    med_q = eng.measure_tp_collectives(reps=2, fmt="int8", tiles=2)
-    assert med_none is not None and med_none > 0
-    assert med_q is not None and med_q > 0
-
-
 @pytest.mark.parametrize("fmt_w", ["int8", "fp6"])
 def test_tiled_row_region_parity(mesh, fmt_w):
     """The T3 tile decomposition (per-tile GEMM + independent transport)

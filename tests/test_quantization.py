@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu
 from deepspeed_tpu.ops import quantizer
-from deepspeed_tpu.ops.pallas import fused_adam, quant_kernel
+from deepspeed_tpu.ops.pallas import quant_kernel
 from simple_model import init_mlp, mlp_loss, random_batches
 
 
@@ -72,28 +72,6 @@ def test_fp8_pallas_matches_jnp():
         quant_kernel.set_interpret(False)
 
 
-def test_fused_adam_matches_optax():
-    import optax
-
-    params = {"a": jnp.ones((128,), jnp.float32), "b": jnp.full((128,), 0.5)}
-    grads = {"a": jnp.full((128,), 0.1), "b": jnp.full((128,), -0.2)}
-    opt = optax.adamw(1e-2, weight_decay=0.01)
-    state = opt.init(params)
-    upd, _ = opt.update(grads, state, params)
-    ref = optax.apply_updates(params, upd)
-
-    fused_adam.set_interpret(True)
-    try:
-        m0 = {k: jnp.zeros_like(v) for k, v in params.items()}
-        got, m, v = fused_adam.fused_adamw_tree(
-            params, grads, m0, m0, lr=1e-2, step=1, wd=0.01
-        )
-    finally:
-        fused_adam.set_interpret(False)
-    for k in params:
-        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(ref[k]), rtol=1e-5)
-
-
 # ---------------------------------------------------------------------------
 # ZeRO++ training
 # ---------------------------------------------------------------------------
@@ -122,7 +100,6 @@ def _train(engine, steps=6):
 
 
 @pytest.mark.parametrize("qw,qg", [(True, False), (False, True), (True, True)])
-@pytest.mark.nightly  # slow e2e
 def test_zeropp_trains_and_tracks_dense(qw, qg):
     zero = {
         "stage": 3,

@@ -102,7 +102,6 @@ def test_quantized_prefill_logits_track_dense(tiny_model, fmt):
 
 
 @pytest.mark.parametrize("fmt", ["int8", "fp8"])
-@pytest.mark.nightly  # slow e2e
 def test_quantized_generation_runs(tiny_model, fmt):
     model, params = tiny_model
     eng = InferenceEngineV2(
@@ -113,7 +112,6 @@ def test_quantized_generation_runs(tiny_model, fmt):
     assert len(out) == 6 and all(0 <= int(t) < model.cfg.vocab_size for t in out)
 
 
-@pytest.mark.nightly  # slow e2e
 def test_quantized_continuous_batching(tiny_model):
     model, params = tiny_model
     eng = InferenceEngineV2(
@@ -195,7 +193,6 @@ def test_fp6_serving_mm_accuracy_and_size():
     assert rel < 0.06, rel
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_fp6_generation_runs(tiny_model):
     model, params = tiny_model
     eng = InferenceEngineV2(

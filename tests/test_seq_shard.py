@@ -129,7 +129,6 @@ def test_over_one_pool_prompt_typed_reject(gqa_model):
     assert res.budget_scope == "replica_pool"
 
 
-@pytest.mark.nightly  # S=2 serve compile on the virtual mesh (~1 min)
 def test_over_one_pool_prompt_served_at_s2(gqa_model):
     """The same per-slice capacity with a seq axis to borrow from: the
     80-token prompt (over one slice's 64-token budget, under the 128-token
@@ -195,9 +194,8 @@ def _serve_all(eng, prompts, max_new=8):
     return out, stats
 
 
-# full-area e2e coverage: nightly lane (the default lane must gate
-# commits in <5 min; same split as tests/test_inference_tp.py)
-@pytest.mark.nightly
+# slow: 12-15 s a case: a solo and a seq-sharded engine served side by side on the virtual mesh
+@pytest.mark.slow
 @pytest.mark.parametrize("seq,tp", [(2, 1), (2, 2)])
 def test_seq_sharded_token_parity(gqa_model, seq, tp):
     """Greedy token identity vs the single-chip engine through the whole
@@ -225,7 +223,8 @@ def test_seq_sharded_token_parity(gqa_model, seq, tp):
     assert stats["decode_bursts"] > 0, "megastep burst path never ran"
 
 
-@pytest.mark.nightly  # compiles every hot jit at S=2 x tp=2 (~2 min)
+# slow: 16 s: compiles every hot jit of a speculating int8 engine on a seq2 x tp2 mesh
+@pytest.mark.slow
 def test_audit_green_at_s2_tp2(gqa_model):
     """The collective-budget audit holds on the 3-D mesh: every hot jit's
     HLO wire bytes match the analytical plan, with the decode/verify ring

@@ -230,7 +230,6 @@ def test_scheduler_serves_prompt_longer_than_max_bucket(tiny):
     assert len(out) == 4
 
 
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_concurrent_shared_prefix_rematches_late(tiny):
     """Requests submitted TOGETHER still share the prefix: followers are
     admitted while the cold request is writing it, and extend_match swaps
@@ -254,7 +253,6 @@ def test_concurrent_shared_prefix_rematches_late(tiny):
 # ---------------------------------------------------------------------------
 # scheduler: overload, preemption, starvation, compat
 # ---------------------------------------------------------------------------
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_scheduler_overload_completes_all(tiny):
     """Submitted load far beyond pool capacity: zero failures — every
     request completes via queueing + preemption-by-recompute, with tokens
@@ -347,7 +345,6 @@ def test_generate_does_not_side_drive_put_sequences(tiny):
 # test — refcounts return to baseline, the prefix LRU stays consistent,
 # no block leaks, from ANY release point
 # ---------------------------------------------------------------------------
-@pytest.mark.slow  # heaviest in its area; nightly lane still runs it
 def test_abort_path_allocator_invariants_randomized_storm(tiny):
     """Randomized cancel / deadline-timeout / injected-failure storm over
     the refcounted COW pool: after every step the allocator audits clean and

@@ -10,14 +10,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.comm import qcomm
 from deepspeed_tpu.config.config import ConfigError, RouterConfig
 from deepspeed_tpu.inference import scheduler as sched_mod
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2, build_serve_engine
 from deepspeed_tpu.inference.faults import FaultInjector
 from deepspeed_tpu.inference.sampling import SamplingParams
 from deepspeed_tpu.models import get_preset
-from deepspeed_tpu.models.transformer import init_params
+from deepspeed_tpu.models.transformer import forward, init_params
 from deepspeed_tpu.serving import build_router
+from deepspeed_tpu.serving import handoff as handoff_mod
 
 
 @pytest.fixture(scope="module")
@@ -99,8 +101,20 @@ def test_router_storm_affinity_beats_least_loaded(tiny):
 # ---------------------------------------------------------------------------
 # prefill/decode disaggregation: the paged-KV handoff
 # ---------------------------------------------------------------------------
+# what a served token may fall short of the reference's best logit after
+# its pages crossed the wire as int8 (the tiny model's logits have std ~1;
+# the benchmark's serving cells judge their tokens by 0.05 to 0.2)
+INT8_TOKEN_MARGIN = 0.05
+
+
 @pytest.mark.parametrize("fmt", ["none", "int8"])
-def test_kv_handoff_token_identity(tiny, fmt):
+def test_kv_handoff_token_identity(tiny, fmt, monkeypatch):
+    """``none`` ships exact pages: greedy token identity.  ``int8`` is a
+    lossy wire and the seeded tiny model's top two logits lie 0.0015 apart
+    at the 7th token, so it is held to what such a wire can promise: every
+    page element within one quantisation step of the exact one, and every
+    served token the reference's best at its position or within
+    ``INT8_TOKEN_MARGIN`` of it, scored on the served sequence."""
     cfg, params = tiny
     samp = SamplingParams(temperature=0.0, max_new_tokens=8)
     rng = np.random.default_rng(3)
@@ -112,6 +126,16 @@ def test_kv_handoff_token_identity(tiny, fmt):
     want_short = ref.generate(short, samp)
     ref.close()
 
+    shipped = []
+    extract = handoff_mod.extract_request
+
+    def extract_beside_exact(engine, uid, fmt="none"):
+        # extraction is a read: the exact pages beside the ones that ship
+        shipped.append((extract(engine, uid, fmt="none"),
+                        extract(engine, uid, fmt=fmt)))
+        return shipped[-1][1]
+
+    monkeypatch.setattr(handoff_mod, "extract_request", extract_beside_exact)
     router = build_router(
         params, cfg, SEC,
         router=dict(n_workers=3, prefill_workers=1, disagg_threshold=32,
@@ -121,6 +145,17 @@ def test_kv_handoff_token_identity(tiny, fmt):
     router.submit(2, short, samp)
     out = router.run()
     stats = dict(router.stats)
+    # the pages that were injected, against the exact ones: identical on the
+    # exact wire, within one step of their chunk's scale on the int8 one
+    (exact, wire), = shipped
+    for (page, _, _, _), (q, s, shape, dtype) in zip(exact.payloads,
+                                                     wire.payloads):
+        got = qcomm.dequantize_payload(q, s, shape, dtype, fmt)
+        if fmt == "none":
+            np.testing.assert_array_equal(got, page)
+        else:
+            step = np.repeat(s, qcomm.DEFAULT_CHUNK)[:page.size]
+            assert (np.abs(got - page).reshape(-1) <= step).all()
     # the long prompt went prefill-worker -> migrated at first token
     assert stats["routed_prefill"] == 1
     assert stats["handoffs"] == 1
@@ -137,9 +172,23 @@ def test_kv_handoff_token_identity(tiny, fmt):
     assert dict(src.scheduler.stats)["migrated"] == 1
     assert sum(dict(w.scheduler.stats)["adopted"]
                for w in router.pool.workers[1:]) == 1
-    # greedy token identity through the handoff, both wire formats
-    assert out[1] == ("finished", want_long)
-    assert out[2] == ("finished", want_short)
+    assert out[2] == ("finished", want_short)  # never left its worker
+    state, served = out[1]
+    assert state == "finished" and len(served) == len(want_long)
+    if fmt == "none":
+        assert served == want_long  # greedy token identity through the handoff
+    else:
+        logits = np.asarray(forward(
+            params, jnp.asarray([long_prompt + served]), cfg)[0][0])
+        at = logits[len(long_prompt) - 1:-1]
+        short_of_best = at.max(axis=-1) - at[np.arange(len(served)), served]
+        assert (short_of_best <= INT8_TOKEN_MARGIN).all(), short_of_best
+        # and where it first leaves the reference's sequence, the
+        # reference's own top two lie that close
+        forks = [i for i, (a, b) in enumerate(zip(served, want_long)) if a != b]
+        if forks:
+            best, second = np.sort(at[forks[0]])[:-3:-1]
+            assert best - second <= INT8_TOKEN_MARGIN
     for audit in router.close():
         assert audit["blocks_in_use"] == 0, audit
 

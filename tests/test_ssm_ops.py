@@ -22,6 +22,12 @@ def _inputs(seed, t):
     return f(t, H, P), dt, a, f(t, R, N), f(t, R, N)
 
 
+# the kernels' plain bodies and the references as ONE program a shape each (op by
+# op, every pad, slice and scan of theirs compiles on its own)
+ssm_scan, ssm_step = jax.jit(ssm.ssm_scan), jax.jit(ssm.ssm_step)
+
+
+@jax.jit
 def _recurrence(x, dt, a, b, c, s0):
     """One token at a time: (y [T, H, P], the state after the last token)."""
     bh, ch = jnp.repeat(b, H // R, axis=1), jnp.repeat(c, H // R, axis=1)
@@ -41,7 +47,7 @@ def _chunked(x, dt, a, b, c, s0, t):
     pad = lambda v: jnp.pad(v, ((0, g * L - t),) + ((0, 0),) * (v.ndim - 1)
                             ).reshape(g, L, *v.shape[1:])
     loaded = jnp.broadcast_to(s0, (g, H, P, N))
-    y, states = ssm.ssm_scan(pad(x), pad(dt), a, pad(b), pad(c), loaded, jnp.arange(g) > 0)
+    y, states = ssm_scan(pad(x), pad(dt), a, pad(b), pad(c), loaded, jnp.arange(g) > 0)
     return y.reshape(g * L, H, P)[:t], states[-1]
 
 
@@ -75,7 +81,7 @@ def test_several_segments_in_one_call_each_from_its_own_state():
         loaded += [s0] * g
         cont += [False] + [True] * (g - 1)
     x, dt, b, c = (jnp.concatenate(v).reshape(-1, L, *v[0].shape[1:]) for v in cat)
-    y, states = ssm.ssm_scan(x, dt, a, b, c, jnp.stack(loaded), jnp.asarray(cont))
+    y, states = ssm_scan(x, dt, a, b, c, jnp.stack(loaded), jnp.asarray(cont))
     y, at = y.reshape(-1, H, P), 0
     for (xi, dti, _, bi, ci), n, s0 in zip(parts, lens, starts):
         g = -(-n // L)
@@ -90,7 +96,7 @@ def test_step_is_one_step_of_the_recurrence_and_idle_states_keep_their_bits():
     s = jnp.asarray(r.standard_normal((3, H, P, N)), jnp.float32)
     x, dt, a, b, c = _inputs(5, 3)
     active = jnp.asarray([True, False, True])
-    y, new = ssm.ssm_step(s, x, dt, a, b, c, active)
+    y, new = ssm_step(s, x, dt, a, b, c, active)
     for i in range(3):
         y_ref, s_ref = _recurrence(x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], s[i])
         assert np.abs(np.asarray(y[i]) - np.asarray(y_ref[0])).max() <= TOL * 10
@@ -166,6 +172,7 @@ def _wide_inputs(seed, t, h, p, r, n):
     return f(t, h, p), dt, -jnp.asarray(g.uniform(1.0, 16.0, (h,)), jnp.float32), f(t, r, n), f(t, r, n)
 
 
+@jax.jit
 def _wide_recurrence(x, dt, a, b, c, s0):
     """``_recurrence`` at any (H, P, R, N): head ``h`` reads group ``h // (H / R)``."""
     rep = x.shape[1] // b.shape[1]
@@ -191,8 +198,8 @@ def test_chunked_scan_at_two_groups_and_a_state_wider_than_the_head(shape, t):
     g = -(-t // L)
     pad = lambda v: jnp.pad(v, ((0, g * L - t),) + ((0, 0),) * (v.ndim - 1)
                             ).reshape(g, L, *v.shape[1:])
-    y, states = ssm.ssm_scan(pad(x), pad(dt), a, pad(b), pad(c),
-                             jnp.broadcast_to(s0, (g, h, p, n)), jnp.arange(g) > 0)
+    y, states = ssm_scan(pad(x), pad(dt), a, pad(b), pad(c),
+                         jnp.broadcast_to(s0, (g, h, p, n)), jnp.arange(g) > 0)
     scale = lambda v: TOL * max(1.0, float(jnp.abs(v).max()))
     assert np.abs(np.asarray(y.reshape(g * L, h, p)[:t]) - np.asarray(y_ref)).max() <= scale(y_ref)
     assert np.abs(np.asarray(states[-1]) - np.asarray(s_ref)).max() <= scale(s_ref)
@@ -204,7 +211,7 @@ def test_step_at_two_groups_and_a_state_wider_than_the_head(shape):
     s = jnp.asarray(np.random.default_rng(2).standard_normal((3, h, p, n)), jnp.float32)
     x, dt, a, b, c = _wide_inputs(7, 3, *shape)
     active = jnp.asarray([False, True, True])
-    y, new = ssm.ssm_step(s, x, dt, a, b, c, active)
+    y, new = ssm_step(s, x, dt, a, b, c, active)
     for i in range(3):
         y_ref, s_ref = _wide_recurrence(x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], s[i])
         assert np.abs(np.asarray(y[i]) - np.asarray(y_ref[0])).max() <= TOL * 10
@@ -226,8 +233,8 @@ def test_chunked_scan_at_one_group(t):
     g = -(-t // L)
     pad = lambda v: jnp.pad(v, ((0, g * L - t),) + ((0, 0),) * (v.ndim - 1)
                             ).reshape(g, L, *v.shape[1:])
-    y, states = ssm.ssm_scan(pad(x), pad(dt), a, pad(b), pad(c),
-                             jnp.broadcast_to(s0, (g, h, p, n)), jnp.arange(g) > 0)
+    y, states = ssm_scan(pad(x), pad(dt), a, pad(b), pad(c),
+                         jnp.broadcast_to(s0, (g, h, p, n)), jnp.arange(g) > 0)
     scale = lambda v: TOL * max(1.0, float(jnp.abs(v).max()))
     assert np.abs(np.asarray(y.reshape(g * L, h, p)[:t]) - np.asarray(y_ref)).max() <= scale(y_ref)
     assert np.abs(np.asarray(states[-1]) - np.asarray(s_ref)).max() <= scale(s_ref)
@@ -238,7 +245,7 @@ def test_step_at_one_group():
     s = jnp.asarray(np.random.default_rng(6).standard_normal((3, h, p, n)), jnp.float32)
     x, dt, a, b, c = _wide_inputs(8, 3, *ONE_GROUP)
     active = jnp.asarray([True, False, True])
-    y, new = ssm.ssm_step(s, x, dt, a, b, c, active)
+    y, new = ssm_step(s, x, dt, a, b, c, active)
     for i in range(3):
         y_ref, s_ref = _wide_recurrence(x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], s[i])
         assert np.abs(np.asarray(y[i]) - np.asarray(y_ref[0])).max() <= TOL * 10

@@ -495,7 +495,7 @@ def test_heartbeat_loss_injection_expires_live_worker(stub_server):
 # ---------------------------------------------------------------------------
 class _RemoteTestPool:
     """Pool shim over directly-constructed RemoteWorkers (the subprocess
-    spawn path is exercised nightly in test_multiprocess_bootstrap)."""
+    spawn path is test_multiprocess_bootstrap's, ``slow``)."""
 
     def __init__(self, workers, telemetry, monitor):
         self.workers = workers

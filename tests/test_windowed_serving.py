@@ -103,7 +103,7 @@ def test_the_logits_of_the_runners_bodies_match_the_reference_past_the_rings_wra
         lg, cache = dec(t1, lens, table, active, cache)
         assert np.abs(np.asarray(lg)[slot] - want[n + j]).max() <= 1e-4, j
     # the KEPT ring: row p % 48 of the slot's ring holds position p's key and value
-    _, seen = arch.probe(params, seq[None], m)
+    _, seen = jax.jit(lambda p, t: arch.probe(p, t, m))(params, seq[None])
     rings = [r for r in seen if "ring_k" in r]
     at = np.arange(n + steps - m["sliding_window"], n + steps)
     for layer, r in enumerate(rings):

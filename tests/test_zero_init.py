@@ -134,7 +134,6 @@ def test_streamed_hf_import_matches_dense(tmp_path):
     assert _shard_fraction(emb) <= 1 / 8 + 1e-6
 
 
-@pytest.mark.nightly  # slow e2e
 def test_streamed_import_through_initialize(tmp_path):
     """initialize(model=<hf dir>) end-to-end: streamed weights, trains."""
     from deepspeed_tpu.checkpoint.hf_import import export_hf_checkpoint
